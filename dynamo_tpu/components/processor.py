@@ -84,6 +84,10 @@ class ProcessorService:
                 # the chosen worker's, attach it so the worker can PULL the
                 # pages over the dataplane instead of recomputing them — the
                 # same OverlapScores the placement used, no second radix walk
+                log.debug(
+                    "routed %d tokens to worker %x (%d cached blocks there)",
+                    len(token_ids), instance_id, overlap.scores.get(instance_id, 0),
+                )
                 holder = self.router.best_remote_holder(overlap, instance_id)
                 if holder is not None:
                     addr = self.router.pull_address(holder[0])

@@ -87,6 +87,7 @@ class WorkerService:
         else:
             inner = AsyncJaxEngine(self.engine_config, kv_event_sink=self._kv_publisher.publish)
             await inner.start()
+            log.info("worker %x engine on %s", worker_id, inner.device_info())
         if self.engine_config.prefix_fetch and isinstance(inner, AsyncJaxEngine):
             from dynamo_tpu.disagg.prefix_fetch import KvPullServer, PrefixFetchClient
 

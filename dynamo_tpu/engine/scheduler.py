@@ -13,7 +13,7 @@ Policy (deliberately simple admission; aggressive latency hiding):
     syncs between windows. Results are reconciled in dispatch order; EOS is
     therefore discovered up to (pipeline_depth * K) steps late, and the device
     wastes at most that much work per finished sequence — the price of hiding
-    per-call dispatch/transfer latency, which dominates on tunneled platforms.
+    per-call dispatch/transfer latency.
   - on page exhaustion mid-decode the pipeline is drained, then the
     most-recently-admitted sequence is preempted back to the waiting queue
     (prompt = original + generated so far)

@@ -41,7 +41,15 @@ def engine_readiness(engine):
             return True, {}
         snap = health.snapshot()
         ok = snap["state"] in ("ready", "degraded")
-        return ok, {"engine": snap}
+        detail = {"engine": snap}
+        if ok and hasattr(engine, "device_info"):
+            # what it runs on and where its compiles are cached: a probe (or
+            # chip_smoke.py) reads the device from here, it does not assume it
+            from dynamo_tpu.utils.xla_cache import cache_stats
+
+            detail["device"] = engine.device_info()
+            detail["xla_cache"] = cache_stats()
+        return ok, detail
 
     return provider
 

@@ -3,13 +3,16 @@
 Drop-in replacement for the pure-Python RadixTree used by KvIndexer when the
 native library is available (DYNTPU_NATIVE=0 disables). Same event semantics;
 hashes are computed in Python (xxh3 via the C-backed xxhash wheel) and passed
-as u64 arrays.
+as u64 arrays. The library is built from native/src on first use
+(native/build.py); where there is no compiler the Python tree is the stated
+alternative, and which one was loaded is logged once.
 """
 
 from __future__ import annotations
 
 import ctypes
 import os
+import subprocess
 from typing import Optional, Sequence
 
 from dynamo_tpu.llm.kv_router.indexer import OverlapScores, RouterEvent, WorkerId
@@ -38,7 +41,8 @@ def _load() -> Optional[ctypes.CDLL]:
             import build as native_build  # native/build.py
         finally:
             sys.path.pop(0)
-        lib = ctypes.CDLL(str(native_build.build()))
+        path = native_build.build()
+        lib = ctypes.CDLL(str(path))
         lib.rtree_new.restype = ctypes.c_void_p
         lib.rtree_free.argtypes = [ctypes.c_void_p]
         lib.rtree_apply_stored.argtypes = [
@@ -58,8 +62,10 @@ def _load() -> Optional[ctypes.CDLL]:
             ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_int64), ctypes.c_int64,
         ]
         _lib = lib
-    except Exception as e:  # toolchain missing etc. — fall back to Python
-        log.warning("native radix tree unavailable (%s); using Python tree", e)
+        log.info("radix index: native (%s)", path)
+    except (OSError, subprocess.CalledProcessError) as e:
+        # no compiler, a failed build, or a library that does not load
+        log.warning("radix index: python (native library unavailable: %s)", e)
         _load_failed = True
     return _lib
 

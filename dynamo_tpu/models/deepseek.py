@@ -56,12 +56,9 @@ def _use_pallas_mla() -> bool:
     """Trace-time choice of the Pallas latent-page decode kernel: same
     DYNTPU_PALLAS override semantics as the GQA kernel (shared pallas_flag);
     default on for real TPU backends."""
-    from dynamo_tpu.ops.attention import _on_tpu, pallas_flag
+    from dynamo_tpu.ops.attention import _pallas_enabled
 
-    flag = pallas_flag()
-    if flag is not None:
-        return flag
-    return _on_tpu()
+    return _pallas_enabled(True)
 
 
 @dataclass(frozen=True)
@@ -440,6 +437,34 @@ class DeepseekModel:
         )  # [T, H, dv]
         return out.astype(self.config.dtype).reshape(out.shape[0], -1)
 
+    def _latent_kernel(self, op, variant, kernel, q_cat, pool, tables, positions):
+        """Run a latent-space Pallas kernel, per head shard under tensor
+        parallelism (GSPMD cannot partition a pallas_call; attention is
+        head-parallel, the latent pool and page tables are replicated), and
+        log once which path that was."""
+        from dynamo_tpu.ops.attention import _log_path, _on_tpu, _tp_shard_map
+
+        mesh = self.attn_mesh
+        tp = 1 if mesh is None else mesh.shape.get("tp", 1)
+        sharded = tp > 1 and q_cat.shape[1] % tp == 0
+        _log_path(
+            op,
+            f"pallas:{variant}" + ("" if _on_tpu() else " interpret")
+            + (f" shard_map tp={tp}" if sharded else ""),
+            (f"T={q_cat.shape[0]} " if tables.ndim == 1 else "")
+            + f"H={q_cat.shape[1]} latent={q_cat.shape[2]} ps={pool.shape[1]}"
+            + (f": tp={tp} does not divide the heads, kernel runs unsharded"
+               if tp > 1 and not sharded else ""),
+        )
+        if not sharded:
+            return kernel(q_cat, pool, tables, positions)
+        return _tp_shard_map(
+            kernel,
+            mesh,
+            in_specs=(P(None, "tp", None), P(None, None, None), P(*[None] * tables.ndim), P(None)),
+            out_specs=P(None, "tp", None),
+        )(q_cat, pool, tables, positions)
+
     def _mla_decode_pallas(
         self, lp, q_nope, q_rope, pool, page_tables, positions
     ) -> jnp.ndarray:
@@ -458,27 +483,16 @@ class DeepseekModel:
         # kernel choice resolved HERE (dispatch level, like ops/attention.py's
         # GQA dispatcher) and passed as a static argument — not read inside
         # the jitted kernel where it would freeze at first trace per shape
+        lookahead = os.environ.get("DYNTPU_DECODE_KERNEL") == "lookahead"
+        interpret = not _on_tpu()
         kernel = functools.partial(
             paged_mla_decode_attention_pallas, d_c=dc,
-            lookahead=os.environ.get("DYNTPU_DECODE_KERNEL") == "lookahead",
-            interpret=not _on_tpu(),
+            lookahead=lookahead, interpret=interpret,
         )
-        mesh = self.attn_mesh
-        tp = 1 if mesh is None else mesh.shape.get("tp", 1)
-        if tp > 1 and q_cat.shape[1] % tp == 0:
-            # GSPMD cannot partition a pallas_call: run per-head-shard under
-            # shard_map (attention is head-parallel; the latent pool and page
-            # tables are replicated)
-            from dynamo_tpu.ops.attention import _tp_shard_map
-
-            a_lat = _tp_shard_map(
-                kernel,
-                mesh,
-                in_specs=(P(None, "tp", None), P(None, None, None), P(None, None), P(None)),
-                out_specs=P(None, "tp", None),
-            )(q_cat, pool, page_tables, positions)
-        else:
-            a_lat = kernel(q_cat, pool, page_tables, positions)
+        a_lat = self._latent_kernel(
+            "mla decode", "lookahead" if lookahead else "classic", kernel,
+            q_cat, pool, page_tables, positions,
+        )
         out = jnp.einsum(
             "bhc,chv->bhv", a_lat.astype(jnp.float32), lp["w_vb"].astype(jnp.float32)
         )
@@ -514,24 +528,15 @@ class DeepseekModel:
         q_cat = self._fold_q(lp, q_nope, q_rope)
         import functools
 
+        interpret = not _on_tpu()
         kernel = functools.partial(
             paged_mla_prefill_attention_pallas,
             d_c=c.kv_lora_rank,
-            interpret=not _on_tpu(),
+            interpret=interpret,
         )
-        mesh = self.attn_mesh
-        tp = 1 if mesh is None else mesh.shape.get("tp", 1)
-        if tp > 1 and q_cat.shape[1] % tp == 0:
-            from dynamo_tpu.ops.attention import _tp_shard_map
-
-            a_lat = _tp_shard_map(
-                kernel,
-                mesh,
-                in_specs=(P(None, "tp", None), P(None, None, None), P(None), P(None)),
-                out_specs=P(None, "tp", None),
-            )(q_cat, pool, page_table, positions)
-        else:
-            a_lat = kernel(q_cat, pool, page_table, positions)
+        a_lat = self._latent_kernel(
+            "mla prefill", "flash", kernel, q_cat, pool, page_table, positions
+        )
         out = jnp.einsum(
             "thc,chv->thv", a_lat.astype(jnp.float32), lp["w_vb"].astype(jnp.float32)
         )
@@ -582,6 +587,13 @@ class DeepseekModel:
                     lp, q_nope, q_rope, pool, gather_tables, positions
                 )
             else:
+                from dynamo_tpu.ops.attention import _log_path
+
+                _log_path(
+                    "mla prefill", "reference",
+                    f"T={T}: no Pallas kernel for this backend, or chunk is "
+                    "not a multiple of 128",
+                )
                 ps = pool.shape[1]
                 ctx = pool[gather_tables].reshape(
                     gather_tables.shape[0] * ps, c.latent_dim_padded

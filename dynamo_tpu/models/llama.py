@@ -191,6 +191,23 @@ class LlamaModel:
         # under shard_map (GSPMD cannot partition a pallas_call)
         self.attn_mesh = None
 
+    @property
+    def kv_folded(self) -> bool:
+        """The pools' layout for THIS engine: config.kv_folded (sub-128
+        head_dim), or too few kv heads per tensor-parallel shard. Mosaic
+        tiles a page's [Hkv, D] minor dims in sublane packs (2 rows for
+        bf16, 4 for int8) and refuses to DMA-slice a page whose per-device
+        head count is not a whole pack — Qwen2.5-7B's 4 kv heads at tp=4
+        leave one per chip (asked of the chip's compiler:
+        tests/test_tpu_compile.py). Folded, the shard's page is
+        [ps, (Hkv/tp)*D] and the folded kernels serve it."""
+        c = self.config
+        if c.kv_folded or self.attn_mesh is None:
+            return c.kv_folded
+        pack = 4 // (1 if c.kv_quantized else jnp.dtype(c.dtype).itemsize)
+        tp = self.attn_mesh.shape.get("tp", 1)
+        return pack > 1 and tp > 1 and (c.num_kv_heads // tp) % pack != 0
+
     # ---------------- params ----------------
 
     def quantize_params(self, params: dict) -> dict:
@@ -300,9 +317,9 @@ class LlamaModel:
 
     def kv_cache_shape(self, num_pages: int, page_size: int) -> tuple[int, ...]:
         """Shape of each of the two flat page pools (the "k" and "v" leaves).
-        See LlamaConfig.kv_folded for the folded (sub-128 head_dim) layout."""
+        See kv_folded for the folded layout."""
         c = self.config
-        if c.kv_folded:
+        if self.kv_folded:
             return (c.num_layers * num_pages, page_size, c.num_kv_heads * c.head_dim)
         return (c.num_layers * num_pages, page_size, c.num_kv_heads, c.head_dim)
 
@@ -338,7 +355,7 @@ class LlamaModel:
 
     def kv_cache_sharding(self, mesh: Mesh, tp_axis: str = "tp") -> dict:
         tp_axis = _resolve_tp_axis(mesh, tp_axis)
-        if self.config.kv_folded:
+        if self.kv_folded:
             # folded lane dim is head-major, so a tp split that divides Hkv
             # stays head-aligned
             ns = NamedSharding(mesh, P(None, None, tp_axis))
@@ -362,8 +379,8 @@ class LlamaModel:
     wire_n_axis = 2
 
     def gather_pages_wire(self, kv: dict, flat_ids: jnp.ndarray):
-        """-> [L, 2, n, page_size, Hkv, D] ([..., Hkv*D] when kv_folded —
-        both disagg sides share the model config, so the layouts agree).
+        """-> [L, 2, n, page_size, Hkv, D] ([..., Hkv*D] when kv_folded;
+        scatter_pages_wire takes either layout from a peer).
 
         Int8 caches return ``{"q": int8 [L, 2, n, ps, ...], "s": f32
         [L, 2, n, ps]}`` — the scale plane travels WITH the pages (half the
@@ -377,6 +394,13 @@ class LlamaModel:
         return jnp.stack([kv["k"][flat_ids], kv["v"][flat_ids]], axis=1)
 
     def scatter_pages_wire(self, kv: dict, flat_ids: jnp.ndarray, data) -> dict:
+        # a peer at another tp degree may hold the other pool layout (see
+        # kv_folded): fold or unfold its blocks to ours
+        tail = (kv["k"].q if isinstance(kv["k"], QuantizedPages) else kv["k"]).shape[2:]
+        if isinstance(data, dict):
+            data = dict(data, q=data["q"].reshape(data["q"].shape[:4] + tail))
+        else:
+            data = data.reshape(data.shape[:4] + tail)
         if isinstance(kv["k"], QuantizedPages):
             if isinstance(data, dict):
                 q = data["q"].astype(jnp.int8)
@@ -413,7 +437,7 @@ class LlamaModel:
 
     def wire_sharding(self, mesh: Mesh, tp_axis: str = "tp"):
         tp_axis = _resolve_tp_axis(mesh, tp_axis)
-        if self.config.kv_folded:
+        if self.kv_folded:
             ns = NamedSharding(mesh, P(None, None, None, None, tp_axis))
         else:
             ns = NamedSharding(mesh, P(None, None, None, None, tp_axis, None))

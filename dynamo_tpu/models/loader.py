@@ -61,10 +61,13 @@ def _finish(arrays, shapes, model=None):
     quantize (quantize="int8_wo" checkpoints: weight-only int8 conversion
     happens HERE, at load time — the serving stack never sees bf16 copies of
     the quantized weights)."""
-    params = jax.tree.map(lambda a, s: jnp.asarray(a, s.dtype), arrays, shapes)
-    if model is not None:
-        params = model.quantize_params(params)
-    return params
+    if model is not None and getattr(model.config, "quantize", None):
+        params = jax.tree.map(lambda a, s: jnp.asarray(a, s.dtype), arrays, shapes)
+        return model.quantize_params(params)
+    # stay on the HOST: ModelRunner's device_put then moves each leaf straight
+    # to its shards. jnp.asarray here would stage the whole tree on the
+    # default device first — a 7B model at tp=4 does not fit one chip
+    return jax.tree.map(lambda a, s: a.astype(s.dtype), arrays, shapes)
 
 
 def _set_layer(group: dict, key: str, layer: int, tensor: np.ndarray, transpose: bool):

@@ -139,8 +139,7 @@ def _load_model_uncached(model_id: str, seed: int = 0, quantize: str | None = No
             overrides = json.loads(model_id.split(":", 1)[1])
         cfg = with_quant(LlamaConfig.tiny(**overrides))
         model = LlamaModel(cfg)
-        # single jitted init: one compile for the whole tree (matters on TPU
-        # backends where every compile round-trips a remote-compile service)
+        # single jitted init: one compile for the whole tree
         params = jax.jit(lambda key: model.init_params(key))(jax.random.key(seed))
         jax.block_until_ready(params)
         return model, params

@@ -12,30 +12,31 @@ query block: block b only loops over tiles up to its last query position.
 
 Two scheduling variants share the math:
 
-  - ``_kernel`` (basic): the r4 design point — per-program double buffer
-    only. Every grid program (query block) pays the full first-tile DMA
-    latency at its boundary before any compute can start.
+  - ``_kernel`` (basic): per-program double buffer only. Every grid program
+    (query block) pays the full first-tile DMA latency at its boundary
+    before any compute can start.
   - ``_kernel_lookahead`` (default on TPU): the decode ``_kernel_lookahead``
     insight ported to prefill. Grid programs run serially on the core and
     scratch PERSISTS across them; the page table and positions are
     scalar-prefetched, so query block b issues block b+1's first
     ``lookahead`` context-tile DMAs into the opposite parity's window while
-    it runs its own online softmax — the same cross-program pipelining that
-    put the decode kernel AT ideal KV-read bandwidth (r5 A/B,
-    paged_attention.py). Prefill re-reads the context from tile 0 for every
-    query block, so the boundary exposure repeats T/block_q times per chunk
-    per layer; hiding it matters most exactly on the prefill-bound
-    ref-workload shape (3K ISL). Tiles >= lookahead stream through the
-    classic in-program double buffer. DYNTPU_PREFILL_KERNEL=basic is the
-    escape hatch.
+    it runs its own online softmax — the decode lookahead kernel's
+    cross-program pipelining (paged_attention.py). Prefill re-reads the
+    context from tile 0 for every query block, so the boundary exposure
+    repeats T/block_q times per chunk per layer. Tiles >= lookahead stream
+    through the classic in-program double buffer. What the window costs and
+    buys at head_dim 128 has not been measured on the current chip (ROADMAP).
+    DYNTPU_PREFILL_KERNEL=basic selects the basic variant.
 
 Int8 KV (quant/kv.py QuantizedPages): the pools arrive as int8 plus a
-per-row f32 scale plane reshaped to ``[P, 1, ps]``. Scale rows ride their
-own tiny DMAs next to the page DMAs (HBM reads stay int8 — that is the
-point: the context stream halves), and dequantization happens on the score/
-prob TILES in VMEM: ``scores *= k_scale_row`` and ``probs *= v_scale_row``
-are exact per-column algebra (see quant/kv.py) and touch only lane-axis
-broadcasts/concats — the same Mosaic-legal idioms the folded kernels use.
+per-row f32 scale plane. The scale rows a chunk needs are gathered by XLA
+into one lane-aligned [1, S] row per context TILE before the kernel runs
+(paged_attention.gather_scale_rows — Mosaic refuses to DMA-slice the raw
+[P, ps] plane when ps < 128) and ride one tiny DMA per tile next to the page
+DMAs (HBM reads stay int8 — that is the point: the context stream halves).
+Dequantization happens on the score/prob TILES in VMEM: ``scores *=
+k_scale_row`` and ``probs *= v_scale_row`` are exact per-column algebra (see
+quant/kv.py) and touch only lane-axis broadcasts.
 
 Contract: q [T, Hq, D] (bucket-padded chunk), k/v pages [P, ps, Hkv, D],
 page_table [max_pages] (this sequence's logical pages, trash page 0 padding),
@@ -54,56 +55,72 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from dynamo_tpu.ops.pallas.paged_attention import gather_scale_rows
 from dynamo_tpu.quant.kv import QuantizedPages
 
 _NEG_INF = -1e30
 
-
-def _unpack_pools(k_pages, v_pages):
-    """(k, v, k_scale [P,1,ps] | None, v_scale | None, quantized) from plain
-    or QuantizedPages pools. The [P, ps] -> [P, 1, ps] scale reshape is a
-    zero-cost leading-dim split; it gives the per-page DMA slice a 2D
-    ([1, ps]) destination."""
-    if isinstance(k_pages, QuantizedPages):
-        P, ps = k_pages.s.shape
-        return (
-            k_pages.q, v_pages.q,
-            k_pages.s.reshape(P, 1, ps), v_pages.s.reshape(P, 1, ps),
-            True,
-        )
-    return k_pages, v_pages, None, None, False
+#: scoped VMEM the prefill kernels ask for: half of the v5e core's 128 MiB.
+#: Mosaic's default scoped limit is 16 MiB, and the compiler's own stack for
+#: the f32 query/accumulator/score tiles at serving widths does not fit it.
+#: Measured by compiling for a described v5e (tests/test_tpu_compile.py): the
+#: stack grows with the q heads per device, about 0.7 MiB per head at
+#: block_q 128 — 19 MiB at 16q/8kv and 28q/4kv, 33 MiB at 32q/8kv, 50 MiB at
+#: 64q/8kv with the lookahead window, 17 MiB for MLA at 16 heads and a
+#: 640-wide latent — and it also grows with the limit it is given, so the
+#: window arithmetic below, which budgets only the kernels' own scratch,
+#: cannot size it. A geometry past this limit fails to compile at engine
+#: start, loudly; there is no fallback.
+PREFILL_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
 
-def _scale_tile_row(scratch_tile):
-    """[TP, 1, ps] VMEM scale tiles -> one [1, S] row via lane-axis concat
-    (the folded kernels' q lane-tiling idiom; leading/lane ops only)."""
-    TP = scratch_tile.shape[0]
-    if TP == 1:
-        return scratch_tile[0]
-    return jnp.concatenate([scratch_tile[p] for p in range(TP)], axis=-1)
+def _unpack_pools(k_pages, v_pages, page_table):
+    """(k, v, k_scale tiles | None, v_scale tiles | None, tile_pages) from
+    plain or QuantizedPages pools; a context tile is ``tile_pages`` pages
+    (128 rows for small pages). Scale tiles are ``gather_scale_rows`` over
+    the page table (edge-padded to whole tiles: the kernels clamp their page
+    indices the same way and mask what lies beyond the table): row t is
+    context tile t's [1, S] scale row."""
+    if not isinstance(k_pages, QuantizedPages):
+        return k_pages, v_pages, None, None, max(1, 128 // k_pages.shape[1])
+    tile_pages = max(1, 128 // k_pages.q.shape[1])
+    table = jnp.pad(page_table, (0, -page_table.shape[0] % tile_pages), mode="edge")
+    return (
+        k_pages.q, v_pages.q,
+        gather_scale_rows(k_pages.s, table, tile_pages),
+        gather_scale_rows(v_pages.s, table, tile_pages),
+        tile_pages,
+    )
 
 
-def _tile_dma_helpers(page_table_ref, hbm_scratch_pairs, sems,
+def _tile_dma_helpers(page_table_ref, page_pairs, scale_pairs, sems,
                       tile_pages: int, max_pages: int):
     """Shared double-buffered context-tile DMA scaffolding for the prefill
-    kernels: ``hbm_scratch_pairs`` is [(hbm_pool, scratch)] — k/v and, when
-    quantized, their scale planes — each scratch indexed ``[buf, p]`` and
-    ``sems`` channel c matching pair c (``[2, C, TP]``). Returns (start,
-    wait), each taking (buf, tile). The final tile clamps page indices to
-    max_pages - 1 (aliased content is masked by the callers' ctx-bound
-    check)."""
+    kernels: ``page_pairs`` is [(hbm_pool, scratch)] for k/v, each scratch
+    indexed ``[buf, p]``; ``scale_pairs`` (int8 pools only) is [(scale tiles,
+    scratch)], one [1, S] row per tile, scratch indexed ``[buf]``. ``sems``
+    is ``[2, C, TP]``: channel c < 2 page p for the pages, channel 2 + c
+    slot 0 for the scale rows. Returns (start, wait), each taking (buf,
+    tile). The final tile clamps page indices to max_pages - 1 (aliased
+    content is masked by the callers' ctx-bound check)."""
 
     def tile_dma(buf, tile):
         copies = []
         for p in range(tile_pages):
             idx = jnp.minimum(tile * tile_pages + p, max_pages - 1)
-            for c, (hbm, scratch) in enumerate(hbm_scratch_pairs):
+            for c, (hbm, scratch) in enumerate(page_pairs):
                 copies.append(
                     pltpu.make_async_copy(
                         hbm.at[page_table_ref[idx]], scratch.at[buf, p],
                         sems.at[buf, c, p],
                     )
                 )
+        for c, (hbm, scratch) in enumerate(scale_pairs):
+            copies.append(
+                pltpu.make_async_copy(
+                    hbm.at[tile], scratch.at[buf], sems.at[buf, 2 + c, 0]
+                )
+            )
         return copies
 
     def start(buf, tile):
@@ -152,17 +169,17 @@ def _kernel(
     """Basic (in-program double buffer) flash prefill; see module docstring.
 
     refs layout: page_table, positions (scalar prefetch) | q, k_hbm, v_hbm
-    [, ks_hbm, vs_hbm] | out | k_scratch, v_scratch [, ks_scratch,
+    [, ks_tiles, vs_tiles] | out | k_scratch, v_scratch [, ks_scratch,
     vs_scratch], sems."""
     if quantized:
         (page_table_ref, positions_ref, q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm,
          out_ref, k_scratch, v_scratch, ks_scratch, vs_scratch, sems) = refs
-        pairs = [(k_hbm, k_scratch), (v_hbm, v_scratch),
-                 (ks_hbm, ks_scratch), (vs_hbm, vs_scratch)]
+        scale_pairs = [(ks_hbm, ks_scratch), (vs_hbm, vs_scratch)]
     else:
         (page_table_ref, positions_ref, q_ref, k_hbm, v_hbm,
          out_ref, k_scratch, v_scratch, sems) = refs
-        pairs = [(k_hbm, k_scratch), (v_hbm, v_scratch)]
+        scale_pairs = []
+    pairs = [(k_hbm, k_scratch), (v_hbm, v_scratch)]
 
     qb = pl.program_id(0)
     Bq, Hq, D = q_ref.shape
@@ -190,7 +207,9 @@ def _kernel(
     )
     scale = 1.0 / jnp.sqrt(jnp.float32(D))
 
-    start, wait = _tile_dma_helpers(page_table_ref, pairs, sems, TP, max_pages)
+    start, wait = _tile_dma_helpers(
+        page_table_ref, pairs, scale_pairs, sems, TP, max_pages
+    )
     start(0, 0)
 
     # causal mask geometry, built directly in 2D [G*Bq, S] (Mosaic rejects 1D
@@ -222,8 +241,8 @@ def _kernel(
             .reshape(S, Hkv, D)
             .transpose(1, 0, 2)
         )
-        ks_row = _scale_tile_row(ks_scratch[buf]) if quantized else None
-        vs_row = _scale_tile_row(vs_scratch[buf]) if quantized else None
+        ks_row = ks_scratch[buf][:, :S] if quantized else None
+        vs_row = vs_scratch[buf][:, :S] if quantized else None
 
         ctx_idx = t * S + iota_col
         # causal, and never beyond the page table (the final tile clamps its
@@ -252,19 +271,19 @@ def _kernel_dmaonly(
 ):
     """Null-hypothesis prefill kernel: ``_kernel``'s exact grid, causal tile
     bound, and double-buffered context-tile DMA stream with NO attention
-    math — the decode ``dmaonly`` methodology (tools/profile_attn.py, r5)
+    math — the decode ``dmaonly`` methodology (tools/profile_attn.py)
     ported to the prefill grid. Its wall time is the irreducible per-chunk
     HBM context traffic; the gap to the real kernel is compute not hidden
     under DMA. Computes garbage by design — timing only."""
     if quantized:
         (page_table_ref, positions_ref, q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm,
          out_ref, k_scratch, v_scratch, ks_scratch, vs_scratch, sems) = refs
-        pairs = [(k_hbm, k_scratch), (v_hbm, v_scratch),
-                 (ks_hbm, ks_scratch), (vs_hbm, vs_scratch)]
+        scale_pairs = [(ks_hbm, ks_scratch), (vs_hbm, vs_scratch)]
     else:
         (page_table_ref, positions_ref, q_ref, k_hbm, v_hbm,
          out_ref, k_scratch, v_scratch, sems) = refs
-        pairs = [(k_hbm, k_scratch), (v_hbm, v_scratch)]
+        scale_pairs = []
+    pairs = [(k_hbm, k_scratch), (v_hbm, v_scratch)]
 
     qb = pl.program_id(0)
     Bq = q_ref.shape[0]
@@ -277,7 +296,9 @@ def _kernel_dmaonly(
         pl.cdiv(last_pos + 1, S), pl.cdiv(jnp.int32(max_pages * page_size), S)
     )
 
-    start, wait = _tile_dma_helpers(page_table_ref, pairs, sems, TP, max_pages)
+    start, wait = _tile_dma_helpers(
+        page_table_ref, pairs, scale_pairs, sems, TP, max_pages
+    )
     start(0, 0)
 
     def body(t, acc):
@@ -318,22 +339,22 @@ def _kernel_lookahead(
     lookahead kernel's scheduling applied to the query-block grid; see the
     module docstring for why the boundary exposure matters more here).
 
-    refs layout: page_table, positions | q, k_hbm, v_hbm [, ks_hbm, vs_hbm]
-    | out | k_pre, v_pre [, ks_pre, vs_pre], k_tail, v_tail [, ks_tail,
-    vs_tail], sems_pre, sems_tail."""
+    refs layout: page_table, positions | q, k_hbm, v_hbm [, ks_tiles,
+    vs_tiles] | out | k_pre, v_pre [, ks_pre, vs_pre], k_tail, v_tail
+    [, ks_tail, vs_tail], sems_pre, sems_tail. Semaphore channel c < 2 page p
+    carries the pages, channel 2 + c slot 0 the tile's scale row."""
     if quantized:
         (page_table_ref, positions_ref, q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm,
          out_ref, k_pre, v_pre, ks_pre, vs_pre, k_tail, v_tail, ks_tail,
          vs_tail, sems_pre, sems_tail) = refs
-        pre_pools = [(k_hbm, k_pre), (v_hbm, v_pre),
-                     (ks_hbm, ks_pre), (vs_hbm, vs_pre)]
-        tail_pairs = [(k_hbm, k_tail), (v_hbm, v_tail),
-                      (ks_hbm, ks_tail), (vs_hbm, vs_tail)]
+        pre_scales = [(ks_hbm, ks_pre), (vs_hbm, vs_pre)]
+        tail_scales = [(ks_hbm, ks_tail), (vs_hbm, vs_tail)]
     else:
         (page_table_ref, positions_ref, q_ref, k_hbm, v_hbm,
          out_ref, k_pre, v_pre, k_tail, v_tail, sems_pre, sems_tail) = refs
-        pre_pools = [(k_hbm, k_pre), (v_hbm, v_pre)]
-        tail_pairs = [(k_hbm, k_tail), (v_hbm, v_tail)]
+        pre_scales = tail_scales = []
+    pre_pools = [(k_hbm, k_pre), (v_hbm, v_pre)]
+    tail_pairs = [(k_hbm, k_tail), (v_hbm, v_tail)]
 
     qb = pl.program_id(0)
     nb = pl.num_programs(0)
@@ -363,23 +384,39 @@ def _kernel_lookahead(
     )
     scale = 1.0 / jnp.sqrt(jnp.float32(D))
 
-    def pre_dma(parity, j, p, c):
-        hbm, scratch = pre_pools[c]
-        idx = jnp.minimum(j * TP + p, max_pages - 1)
-        return pltpu.make_async_copy(
-            hbm.at[page_table_ref[idx]],
-            scratch.at[parity, j, p],
-            sems_pre.at[parity, j, c, p],
-        )
+    def pre_dmas(parity, j):
+        """Every copy of window tile j: TP pages of k and v [+ scale rows]."""
+        copies = []
+        for p in range(TP):
+            idx = jnp.minimum(j * TP + p, max_pages - 1)
+            for c, (hbm, scratch) in enumerate(pre_pools):
+                copies.append(pltpu.make_async_copy(
+                    hbm.at[page_table_ref[idx]],
+                    scratch.at[parity, j, p],
+                    sems_pre.at[parity, j, c, p],
+                ))
+        for c, (hbm, scratch) in enumerate(pre_scales):
+            copies.append(pltpu.make_async_copy(
+                hbm.at[j], scratch.at[parity, j], sems_pre.at[parity, j, 2 + c, 0]
+            ))
+        return copies
 
-    def tail_dma(slot, tile, p, c):
-        hbm, scratch = tail_pairs[c]
-        idx = jnp.minimum(tile * TP + p, max_pages - 1)
-        return pltpu.make_async_copy(
-            hbm.at[page_table_ref[idx]],
-            scratch.at[slot, p],
-            sems_tail.at[slot, c, p],
-        )
+    def tail_dmas(slot, tile):
+        """Every copy of in-program double-buffer tile ``tile``."""
+        copies = []
+        for p in range(TP):
+            idx = jnp.minimum(tile * TP + p, max_pages - 1)
+            for c, (hbm, scratch) in enumerate(tail_pairs):
+                copies.append(pltpu.make_async_copy(
+                    hbm.at[page_table_ref[idx]],
+                    scratch.at[slot, p],
+                    sems_tail.at[slot, c, p],
+                ))
+        for c, (hbm, scratch) in enumerate(tail_scales):
+            copies.append(pltpu.make_async_copy(
+                hbm.at[tile], scratch.at[slot], sems_tail.at[slot, 2 + c, 0]
+            ))
+        return copies
 
     def issue_pre(block_idx, parity):
         # context pages are shared by every query block of the chunk, so the
@@ -390,9 +427,8 @@ def _kernel_lookahead(
 
             @pl.when(j < npg)
             def _(j=j):
-                for p in range(TP):
-                    for c in range(len(pre_pools)):
-                        pre_dma(parity, j, p, c).start()
+                for cp in pre_dmas(parity, j):
+                    cp.start()
 
     # program 0 has no predecessor: prefetch its own window
     @pl.when(qb == 0)
@@ -407,9 +443,8 @@ def _kernel_lookahead(
     # long-context tail: warm the in-program double buffer for tile W
     @pl.when(W < n_tiles)
     def _():
-        for p in range(TP):
-            for c in range(len(tail_pairs)):
-                tail_dma(W % 2, W, p, c).start()
+        for cp in tail_dmas(W % 2, W):
+            cp.start()
 
     pos0 = positions_ref[qb * block_q]
     iota_row = jax.lax.broadcasted_iota(jnp.int32, (G * Bq, S), 0)
@@ -419,16 +454,15 @@ def _kernel_lookahead(
     def merge_tile(carry, t, k_tile, v_tile, ks_tile, vs_tile):
         kt = k_tile.astype(jnp.float32).reshape(S, Hkv, D).transpose(1, 0, 2)
         vt = v_tile.astype(jnp.float32).reshape(S, Hkv, D).transpose(1, 0, 2)
-        ks_row = _scale_tile_row(ks_tile) if quantized else None
-        vs_row = _scale_tile_row(vs_tile) if quantized else None
+        ks_row = ks_tile[:, :S] if quantized else None
+        vs_row = vs_tile[:, :S] if quantized else None
         ctx_idx = t * S + iota_col
         mask = (ctx_idx <= q_pos_2d) & (ctx_idx < ctx_cap)
         return _flash_merge(carry, q, kt, vt, scale, mask, ks_row, vs_row)
 
     def pre_body(j, carry):
-        for p in range(TP):
-            for c in range(len(pre_pools)):
-                pre_dma(par, j, p, c).wait()
+        for cp in pre_dmas(par, j):
+            cp.wait()
         return merge_tile(
             carry, j, k_pre[par, j], v_pre[par, j],
             ks_pre[par, j] if quantized else None,
@@ -441,13 +475,11 @@ def _kernel_lookahead(
 
         @pl.when(t + 1 < n_tiles)
         def _():
-            for p in range(TP):
-                for c in range(len(tail_pairs)):
-                    tail_dma(next_slot, t + 1, p, c).start()
+            for cp in tail_dmas(next_slot, t + 1):
+                cp.start()
 
-        for p in range(TP):
-            for c in range(len(tail_pairs)):
-                tail_dma(slot, t, p, c).wait()
+        for cp in tail_dmas(slot, t):
+            cp.wait()
         return merge_tile(
             carry, t, k_tail[slot], v_tail[slot],
             ks_tail[slot] if quantized else None,
@@ -488,12 +520,12 @@ def _kernel_folded(
     if quantized:
         (page_table_ref, positions_ref, q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm,
          out_ref, k_scratch, v_scratch, ks_scratch, vs_scratch, sems) = refs
-        pairs = [(k_hbm, k_scratch), (v_hbm, v_scratch),
-                 (ks_hbm, ks_scratch), (vs_hbm, vs_scratch)]
+        scale_pairs = [(ks_hbm, ks_scratch), (vs_hbm, vs_scratch)]
     else:
         (page_table_ref, positions_ref, q_ref, k_hbm, v_hbm,
          out_ref, k_scratch, v_scratch, sems) = refs
-        pairs = [(k_hbm, k_scratch), (v_hbm, v_scratch)]
+        scale_pairs = []
+    pairs = [(k_hbm, k_scratch), (v_hbm, v_scratch)]
 
     qb = pl.program_id(0)
     Bq, Hq, D = q_ref.shape
@@ -520,7 +552,9 @@ def _kernel_folded(
     qf = (qtile * own).astype(q_ref.dtype)
     scale = 1.0 / jnp.sqrt(jnp.float32(D))
 
-    start, wait = _tile_dma_helpers(page_table_ref, pairs, sems, TP, max_pages)
+    start, wait = _tile_dma_helpers(
+        page_table_ref, pairs, scale_pairs, sems, TP, max_pages
+    )
     start(0, 0)
 
     # causal geometry: row r's query position = positions[q_start] + r // Hq
@@ -550,7 +584,7 @@ def _kernel_folded(
             (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale
         if quantized:
-            scores = scores * _scale_tile_row(ks_scratch[buf])  # [1, S]
+            scores = scores * ks_scratch[buf][:, :S]  # [1, S]
         ctx_idx = t * S + iota_col
         mask = (ctx_idx <= q_pos_2d) & (ctx_idx < max_pages * page_size)
         scores = jnp.where(mask, scores, _NEG_INF)
@@ -562,7 +596,7 @@ def _kernel_folded(
         new_l = l * corr + jnp.sum(probs, axis=-1)
         # [R, F] = [R, S] x [S, F]
         if quantized:
-            probs = probs * _scale_tile_row(vs_scratch[buf])
+            probs = probs * vs_scratch[buf][:, :S]
             chunk_out = jax.lax.dot_general(
                 probs, vf.astype(jnp.float32), (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -589,14 +623,56 @@ def _kernel_folded(
     out_ref[...] = out2.reshape(Bq, Hq, D).astype(out_ref.dtype)  # leading split
 
 
-def _pool_in_specs(quantized: bool):
-    """in_specs for [q_block, k_pool, v_pool (, k_scale, v_scale)]."""
-    pools = 4 if quantized else 2
-    return [pl.BlockSpec(memory_space=pl.ANY) for _ in range(pools)]
+def _tile_scratch(lead: tuple, tile_shape: tuple, kq, vq, ks, vs):
+    """VMEM scratch + DMA semaphores for context tiles buffered ``lead``
+    deep: k/v tiles ``[*lead, *tile_shape]`` [, int8 scale rows ``[*lead, 1,
+    S]``], then sems ``[*lead, C, TP]`` (the layout _tile_dma_helpers and
+    _kernel_lookahead index)."""
+    shapes = [pltpu.VMEM((*lead, *tile_shape), kq.dtype),
+              pltpu.VMEM((*lead, *tile_shape), vq.dtype)]
+    if ks is not None:
+        shapes += [pltpu.VMEM((*lead, 1, ks.shape[-1]), jnp.float32),
+                   pltpu.VMEM((*lead, 1, vs.shape[-1]), jnp.float32)]
+    sems = pltpu.SemaphoreType.DMA((*lead, 2 if ks is None else 4, tile_shape[0]))
+    return shapes, sems
 
 
-#: scoped-VMEM budget for the lookahead prefill window (the decode kernel's
-#: rationale, prefill tile sizes; ~16 MB/core scoped limit)
+def _prefill_call(body, scratch_shapes, q, page_table, positions, pools,
+                  block_q: int, interpret: bool, serial_grid: bool = False):
+    """One pallas_call over the query-block grid shared by every prefill
+    kernel: page table + positions scalar-prefetched, q/out blocked by
+    query block, pools (and int8 scale tiles) left in HBM for manual DMA."""
+    T, Hq, D = q.shape
+    assert T % block_q == 0, f"chunk {T} % block_q {block_q}"
+    q_spec = pl.BlockSpec((block_q, Hq, D), lambda qb, *_: (qb, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(T // block_q,),
+        in_specs=[q_spec, *[pl.BlockSpec(memory_space=pl.ANY) for _ in pools]],
+        out_specs=q_spec,
+        scratch_shapes=scratch_shapes,
+    )
+    kernel = pl.pallas_call(
+        body,
+        out_shape=jax.ShapeDtypeStruct((T, Hq, D), q.dtype),
+        grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(
+            # cross-program scratch persistence (query block b prefetches
+            # b+1's context tiles into the opposite parity) requires the
+            # grid to run SERIALLY — pin it, as the decode lookahead kernel
+            # does
+            dimension_semantics=("arbitrary",) if serial_grid else None,
+            vmem_limit_bytes=PREFILL_VMEM_LIMIT_BYTES,
+        ),
+        interpret=interpret,
+    )
+    return kernel(
+        page_table.astype(jnp.int32), positions.astype(jnp.int32), q, *pools
+    )
+
+
+#: VMEM budget for the lookahead prefill window's own scratch (the compiler's
+#: stack comes on top of it: see PREFILL_VMEM_LIMIT_BYTES)
 _PREFILL_LOOKAHEAD_SCRATCH_BYTES = 8 * 1024 * 1024
 
 
@@ -621,7 +697,7 @@ def paged_prefill_attention_pallas_folded(
     block_q: int = 128,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    T, Hq, D = q.shape
+    D = q.shape[-1]
     if k_pages.ndim == 4:  # direct-call convenience (tests)
         P, ps, Hkv, _ = k_pages.shape
         if isinstance(k_pages, QuantizedPages):
@@ -630,53 +706,22 @@ def paged_prefill_attention_pallas_folded(
         else:
             k_pages = k_pages.reshape(P, ps, Hkv * D)
             v_pages = v_pages.reshape(P, ps, Hkv * D)
-    kq, vq, ks, vs, quantized = _unpack_pools(k_pages, v_pages)
-    P, ps, F = kq.shape
-    Hkv = F // D
-    max_pages = page_table.shape[0]
-    assert T % block_q == 0, f"chunk {T} % block_q {block_q}"
-    tile_pages = max(1, 128 // ps)
-
-    scratch_shapes = [
-        pltpu.VMEM((2, tile_pages, ps, F), kq.dtype),
-        pltpu.VMEM((2, tile_pages, ps, F), vq.dtype),
-    ]
-    if quantized:
-        scratch_shapes += [
-            pltpu.VMEM((2, tile_pages, 1, ps), jnp.float32),
-            pltpu.VMEM((2, tile_pages, 1, ps), jnp.float32),
-        ]
-    scratch_shapes.append(
-        pltpu.SemaphoreType.DMA((2, 4 if quantized else 2, tile_pages))
+    kq, vq, ks, vs, tile_pages = _unpack_pools(k_pages, v_pages, page_table)
+    _, ps, F = kq.shape
+    shapes, sems = _tile_scratch((2,), (tile_pages, ps, F), kq, vq, ks, vs)
+    body = functools.partial(
+        _kernel_folded,
+        page_size=ps,
+        max_pages=page_table.shape[0],
+        tile_pages=tile_pages,
+        block_q=block_q,
+        num_kv_heads=F // D,
+        head_dim=D,
+        quantized=ks is not None,
     )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(T // block_q,),
-        in_specs=[
-            pl.BlockSpec((block_q, Hq, D), lambda qb, *_: (qb, 0, 0)),
-            *_pool_in_specs(quantized),
-        ],
-        out_specs=pl.BlockSpec((block_q, Hq, D), lambda qb, *_: (qb, 0, 0)),
-        scratch_shapes=scratch_shapes,
-    )
-    kernel = pl.pallas_call(
-        functools.partial(
-            _kernel_folded,
-            page_size=ps,
-            max_pages=max_pages,
-            tile_pages=tile_pages,
-            block_q=block_q,
-            num_kv_heads=Hkv,
-            head_dim=D,
-            quantized=quantized,
-        ),
-        out_shape=jax.ShapeDtypeStruct((T, Hq, D), q.dtype),
-        grid_spec=grid_spec,
-        interpret=interpret,
-    )
-    args = (kq, vq, ks, vs) if quantized else (kq, vq)
-    return kernel(
-        page_table.astype(jnp.int32), positions.astype(jnp.int32), q, *args
+    pools = (kq, vq) if ks is None else (kq, vq, ks, vs)
+    return _prefill_call(
+        body, [*shapes, sems], q, page_table, positions, pools, block_q, interpret
     )
 
 
@@ -694,102 +739,35 @@ def paged_prefill_attention_pallas(
     lookahead: bool = True,
 ) -> jnp.ndarray:
     """Flash prefill dispatcher: lookahead (cross-program tile prefetch)
-    when the window fits VMEM, else the basic in-program double buffer."""
-    T, Hq, D = q.shape
-    kq, vq, ks, vs, quantized = _unpack_pools(k_pages, v_pages)
-    P, ps, Hkv, _ = kq.shape
-    max_pages = page_table.shape[0]
-    assert T % block_q == 0, f"chunk {T} % block_q {block_q}"
-    tile_pages = max(1, 128 // ps)
+    when the window fits its scratch budget — a trace-time choice by shape —
+    else the basic in-program double buffer."""
+    kq, vq, ks, vs, tile_pages = _unpack_pools(k_pages, v_pages, page_table)
+    _, ps, Hkv, D = kq.shape
+    tile = (tile_pages, ps, Hkv, D)
     W = (
         prefill_lookahead_window(ps, tile_pages, Hkv, D, kq.dtype.itemsize)
         if lookahead
         else 0
     )
-
+    common = dict(
+        page_size=ps,
+        max_pages=page_table.shape[0],
+        tile_pages=tile_pages,
+        block_q=block_q,
+        quantized=ks is not None,
+    )
+    tail_shapes, tail_sems = _tile_scratch((2,), tile, kq, vq, ks, vs)
     if W >= 1:
-        scratch_shapes = [
-            pltpu.VMEM((2, W, tile_pages, ps, Hkv, D), kq.dtype),
-            pltpu.VMEM((2, W, tile_pages, ps, Hkv, D), vq.dtype),
-        ]
-        if quantized:
-            scratch_shapes += [
-                pltpu.VMEM((2, W, tile_pages, 1, ps), jnp.float32),
-                pltpu.VMEM((2, W, tile_pages, 1, ps), jnp.float32),
-            ]
-        scratch_shapes += [
-            pltpu.VMEM((2, tile_pages, ps, Hkv, D), kq.dtype),
-            pltpu.VMEM((2, tile_pages, ps, Hkv, D), vq.dtype),
-        ]
-        if quantized:
-            scratch_shapes += [
-                pltpu.VMEM((2, tile_pages, 1, ps), jnp.float32),
-                pltpu.VMEM((2, tile_pages, 1, ps), jnp.float32),
-            ]
-        C = 4 if quantized else 2
-        scratch_shapes += [
-            pltpu.SemaphoreType.DMA((2, W, C, tile_pages)),
-            pltpu.SemaphoreType.DMA((2, C, tile_pages)),
-        ]
-        body = functools.partial(
-            _kernel_lookahead,
-            page_size=ps,
-            max_pages=max_pages,
-            tile_pages=tile_pages,
-            block_q=block_q,
-            lookahead=W,
-            quantized=quantized,
-        )
-        # cross-program scratch persistence (query block b prefetches b+1's
-        # context tiles into the opposite parity) requires the grid to run
-        # SERIALLY — pin it, as the decode lookahead kernel does
-        compiler_params = pltpu.TPUCompilerParams(dimension_semantics=("arbitrary",))
+        pre_shapes, pre_sems = _tile_scratch((2, W), tile, kq, vq, ks, vs)
+        scratch = [*pre_shapes, *tail_shapes, pre_sems, tail_sems]
+        body = functools.partial(_kernel_lookahead, lookahead=W, **common)
     else:
-        scratch_shapes = [
-            pltpu.VMEM((2, tile_pages, ps, Hkv, D), kq.dtype),
-            pltpu.VMEM((2, tile_pages, ps, Hkv, D), vq.dtype),
-        ]
-        if quantized:
-            scratch_shapes += [
-                pltpu.VMEM((2, tile_pages, 1, ps), jnp.float32),
-                pltpu.VMEM((2, tile_pages, 1, ps), jnp.float32),
-            ]
-        scratch_shapes.append(
-            pltpu.SemaphoreType.DMA((2, 4 if quantized else 2, tile_pages))
-        )
-        body = functools.partial(
-            _kernel,
-            page_size=ps,
-            max_pages=max_pages,
-            tile_pages=tile_pages,
-            block_q=block_q,
-            quantized=quantized,
-        )
-        compiler_params = None
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(T // block_q,),
-        in_specs=[
-            pl.BlockSpec((block_q, Hq, D), lambda qb, *_: (qb, 0, 0)),
-            *_pool_in_specs(quantized),
-        ],
-        out_specs=pl.BlockSpec((block_q, Hq, D), lambda qb, *_: (qb, 0, 0)),
-        scratch_shapes=scratch_shapes,
-    )
-    kwargs = {}
-    if compiler_params is not None:
-        kwargs["compiler_params"] = compiler_params
-    kernel = pl.pallas_call(
-        body,
-        out_shape=jax.ShapeDtypeStruct((T, Hq, D), q.dtype),
-        grid_spec=grid_spec,
-        interpret=interpret,
-        **kwargs,
-    )
-    args = (kq, vq, ks, vs) if quantized else (kq, vq)
-    return kernel(
-        page_table.astype(jnp.int32), positions.astype(jnp.int32), q, *args
+        scratch = [*tail_shapes, tail_sems]
+        body = functools.partial(_kernel, **common)
+    pools = (kq, vq) if ks is None else (kq, vq, ks, vs)
+    return _prefill_call(
+        body, scratch, q, page_table, positions, pools, block_q, interpret,
+        serial_grid=W >= 1,
     )
 
 
@@ -808,49 +786,18 @@ def paged_prefill_dmaonly(
     ``tools/profile_prefill.py`` differences this against the real kernel to
     split a prefill call's cost into DMA floor vs exposed compute. Output is
     garbage by design — never dispatch it for serving."""
-    T, Hq, D = q.shape
-    kq, vq, ks, vs, quantized = _unpack_pools(k_pages, v_pages)
-    P, ps, Hkv, _ = kq.shape
-    max_pages = page_table.shape[0]
-    assert T % block_q == 0, f"chunk {T} % block_q {block_q}"
-    tile_pages = max(1, 128 // ps)
-
-    scratch_shapes = [
-        pltpu.VMEM((2, tile_pages, ps, Hkv, D), kq.dtype),
-        pltpu.VMEM((2, tile_pages, ps, Hkv, D), vq.dtype),
-    ]
-    if quantized:
-        scratch_shapes += [
-            pltpu.VMEM((2, tile_pages, 1, ps), jnp.float32),
-            pltpu.VMEM((2, tile_pages, 1, ps), jnp.float32),
-        ]
-    scratch_shapes.append(
-        pltpu.SemaphoreType.DMA((2, 4 if quantized else 2, tile_pages))
+    kq, vq, ks, vs, tile_pages = _unpack_pools(k_pages, v_pages, page_table)
+    _, ps, Hkv, D = kq.shape
+    shapes, sems = _tile_scratch((2,), (tile_pages, ps, Hkv, D), kq, vq, ks, vs)
+    body = functools.partial(
+        _kernel_dmaonly,
+        page_size=ps,
+        max_pages=page_table.shape[0],
+        tile_pages=tile_pages,
+        block_q=block_q,
+        quantized=ks is not None,
     )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(T // block_q,),
-        in_specs=[
-            pl.BlockSpec((block_q, Hq, D), lambda qb, *_: (qb, 0, 0)),
-            *_pool_in_specs(quantized),
-        ],
-        out_specs=pl.BlockSpec((block_q, Hq, D), lambda qb, *_: (qb, 0, 0)),
-        scratch_shapes=scratch_shapes,
-    )
-    kernel = pl.pallas_call(
-        functools.partial(
-            _kernel_dmaonly,
-            page_size=ps,
-            max_pages=max_pages,
-            tile_pages=tile_pages,
-            block_q=block_q,
-            quantized=quantized,
-        ),
-        out_shape=jax.ShapeDtypeStruct((T, Hq, D), q.dtype),
-        grid_spec=grid_spec,
-        interpret=interpret,
-    )
-    args = (kq, vq, ks, vs) if quantized else (kq, vq)
-    return kernel(
-        page_table.astype(jnp.int32), positions.astype(jnp.int32), q, *args
+    pools = (kq, vq) if ks is None else (kq, vq, ks, vs)
+    return _prefill_call(
+        body, [*shapes, sems], q, page_table, positions, pools, block_q, interpret
     )

@@ -156,7 +156,13 @@ class ServedEndpoint:
     async def start(self) -> None:
         client = self._drt.cplane
         await client.subscribe(self.info.subject, self._on_request)
-        await client.subscribe(self._stats_subject, self._on_stats)
+        if self.stats_fn is not None:
+            # the scrape subject is per COMPONENT and a client holds one
+            # handler per subject: an endpoint with nothing to report must
+            # not take it from the sibling endpoint that has (the worker's
+            # `migrate` endpoint used to silence `generate`'s stats, and the
+            # KV router, seeing no loads, placed every request at random)
+            await client.subscribe(self._stats_subject, self._on_stats)
         await self._register()
         # broker outage or lease expiry: re-register once the connection (and
         # the lease, under its original id) is healed — subscriptions are

@@ -7,9 +7,12 @@ instead of CUDA devices: each worker process gets a disjoint chip set via
 CUDA_VISIBLE_DEVICES); services that request no TPU are pinned to
 `JAX_PLATFORMS=cpu` so importing jax in them never grabs the chips.
 
-Fractional requests (e.g. {"tpu": 0.5}) co-locate workers on a shared chip —
-the workers see the same TPU_VISIBLE_DEVICES and must coordinate HBM use
-(time-sliced; there is no TPU MIG equivalent).
+A chip belongs to one process at a time: a second process that opens it fails
+or hangs. So a request is for whole chips, each chip is assigned once, and a
+service that asks for chips where none (or too few) are detected fails at
+start-up with a message instead of leaving its workers to contend for
+whatever is visible. To run a chip-requesting graph on the CPU, give the
+service ``resources: {tpu: 0}`` in its YAML section.
 
 Set DYNTPU_DISABLE_TPU_ALLOCATION=1 to manage visibility manually, and
 DYNTPU_DEPLOYMENT_ENV for K8s replica mode (every replica gets the same
@@ -21,7 +24,6 @@ from __future__ import annotations
 
 import glob
 import os
-import warnings
 
 DISABLE_TPU_ALLOCATION_ENV = "DYNTPU_DISABLE_TPU_ALLOCATION"
 DEPLOYMENT_ENV = "DYNTPU_DEPLOYMENT_ENV"
@@ -40,55 +42,48 @@ def detect_tpu_chips() -> int:
     return len(vfio)
 
 
+def chip_env(chips: list[int]) -> dict[str, str]:
+    """Environment that confines one process to ``chips``. libtpu opens every
+    chip of the host unless told otherwise; a one-chip worker also needs the
+    1x1x1 process bounds, or it waits for the host's other chips."""
+    env = {"TPU_VISIBLE_DEVICES": ",".join(map(str, chips))}
+    if len(chips) == 1:
+        env["TPU_CHIPS_PER_PROCESS_BOUNDS"] = "1,1,1"
+        env["TPU_PROCESS_BOUNDS"] = "1,1,1"
+    return env
+
+
 class ResourceAllocator:
     """Splits the host's TPU chips across service workers."""
 
     def __init__(self, total_chips: int | None = None) -> None:
         self.total_chips = detect_tpu_chips() if total_chips is None else total_chips
-        self.remaining_chips: float = float(self.total_chips)
-        # each entry: (remaining_fraction, fragment_unit)
-        self._chips: list[tuple[float, float]] = [(1.0, 1.0)] * self.total_chips
+        self._next_chip = 0
 
-    def assign_chips(self, count: float) -> list[int]:
-        """Assign `count` chips (fractional => shared chip). Returns chip ids."""
-        if count > 1 and int(count) != count:
-            raise ValueError("fractional TPU requests above 1 chip are not supported")
-        if count > self.remaining_chips:
-            warnings.warn(
-                f"Requested {count} TPU chips, but only {self.remaining_chips} remain. "
-                f"Serving may fail; set {DISABLE_TPU_ALLOCATION_ENV}=1 to manage "
-                "chip visibility manually.",
-                ResourceWarning,
-                stacklevel=3,
+    @property
+    def remaining_chips(self) -> int:
+        return self.total_chips - self._next_chip
+
+    def assign_chips(self, count) -> list[int]:
+        """Assign `count` whole chips, each to one worker only. Returns ids."""
+        if count < 1 or int(count) != count:
+            raise ValueError(
+                f"TPU request {count!r}: a chip cannot be shared (one process "
+                "opens it, the next fails or hangs); ask for whole chips"
             )
-        self.remaining_chips = max(0.0, self.remaining_chips - count)
-        if count < 1:  # fractional: co-locate on a chip already split this way
-            try:
-                chip = next(
-                    i for i, (rem, unit) in enumerate(self._chips)
-                    if rem > 0 and unit == count
-                )
-            except StopIteration:
-                try:
-                    chip = next(i for i, (rem, _) in enumerate(self._chips) if rem == 1.0)
-                except StopIteration:
-                    chip = len(self._chips)
-                    self._chips.append((1.0, count))
-            remaining = self._chips[chip][0] - count
-            self._chips[chip] = (remaining if remaining >= count else 0.0, count)
-            return [chip]
         count = int(count)
-        free = [i for i, (rem, unit) in enumerate(self._chips) if rem > 0 and unit == 1.0]
-        if len(free) < count:
-            warnings.warn(
-                f"Not enough TPU chips: {count} requested", ResourceWarning, stacklevel=3
+        if count > self.remaining_chips:
+            raise RuntimeError(
+                f"{count} TPU chip(s) requested but {self.remaining_chips} of "
+                f"{self.total_chips} detected remain unassigned "
+                f"(/dev/accel*, /dev/vfio/*; override with {NUM_CHIPS_ENV}). "
+                "Give the service `resources: {tpu: 0}` to run it on the CPU, "
+                f"or set {DISABLE_TPU_ALLOCATION_ENV}=1 to manage chip "
+                "visibility yourself."
             )
-            while len(free) < count:
-                free.append(len(self._chips))
-                self._chips.append((1.0, 1.0))
-        for chip in free[:count]:
-            self._chips[chip] = (0.0, 1.0)
-        return free[:count]
+        chips = list(range(self._next_chip, self._next_chip + count))
+        self._next_chip += count
+        return chips
 
     def get_worker_env(self, meta, config: dict) -> tuple[int, list[dict[str, str]]]:
         """(num_workers, per-worker env) for a service.
@@ -110,21 +105,11 @@ class ResourceAllocator:
             env = {"JAX_PLATFORMS": "cpu"} if not num_chips else {}
             return num_workers, [dict(env) for _ in range(num_workers)]
 
-        if self.total_chips == 0:
-            # No local chips detected (dev box, or TPU attached via a tunnel
-            # that /dev scanning can't see): leave visibility untouched.
-            return num_workers, [{} for _ in range(num_workers)]
-
         if os.environ.get(DEPLOYMENT_ENV):
             # K8s replicas: every replica pod gets the same visible set.
-            assigned = self.assign_chips(num_chips)
-            vis = ",".join(map(str, assigned))
-            return num_workers, [
-                {"TPU_VISIBLE_DEVICES": vis} for _ in range(num_workers)
-            ]
+            env = chip_env(self.assign_chips(num_chips))
+            return num_workers, [dict(env) for _ in range(num_workers)]
 
-        worker_env = []
-        for _ in range(num_workers):
-            assigned = self.assign_chips(num_chips)
-            worker_env.append({"TPU_VISIBLE_DEVICES": ",".join(map(str, assigned))})
-        return num_workers, worker_env
+        return num_workers, [
+            chip_env(self.assign_chips(num_chips)) for _ in range(num_workers)
+        ]

@@ -216,6 +216,15 @@ class Supervisor:
         for cls_name, (cls, meta, envs) in self._class_info.items():
             key = f"planner/{meta.namespace}/desired/{meta.component}"
             want = desired_by_key.get(key)
+            if want is not None and envs and "TPU_VISIBLE_DEVICES" in envs[0]:
+                # a chip belongs to one process: never more replicas than
+                # chip assignments (see allocator)
+                if want > len(envs):
+                    log.warning(
+                        "planner wants %d x %s but only %d chip assignment(s) "
+                        "exist; holding at %d", want, cls_name, len(envs), len(envs),
+                    )
+                    want = len(envs)
             if want is None or want == self.desired.get(cls_name):
                 continue
             have = self.desired[cls_name]
@@ -232,8 +241,7 @@ class Supervisor:
                     except subprocess.TimeoutExpired:
                         old.kill()
                         old.wait()
-                # replicas beyond the initial allocation share its chip
-                # assignments round-robin (time-sliced on chip; see allocator)
+                # chipless services reuse the (cpu-pinning) envs round-robin
                 env = envs[i % len(envs)] if envs else None
                 self.spawn(cls, i, env)
             for i in range(want, have):  # scale down, highest index first

@@ -68,6 +68,11 @@ def main(argv=None) -> None:
     parser.add_argument("service", help="module.path:ClassName")
     args = parser.parse_args(argv)
     cls = load_class(args.service)
+    if (cls.__dynamo_service__.resources or {}).get("tpu"):
+        # this process will start an engine: restarts reload executables
+        from dynamo_tpu.utils.xla_cache import enable_compilation_cache
+
+        enable_compilation_cache()
     Worker.execute(lambda runtime: run_service(runtime, cls))
 
 

@@ -59,7 +59,7 @@ from dynamo_tpu.utils import tracing
 #: Every event kind this system may emit — the conformance surface.
 #: graftlint's event-conformance detector pins emitting-site literals against
 #: this tuple in both directions (mirror of DECLARED_METRIC_FAMILIES). Keep
-#: one kind per line. Taxonomy: ``<plane>.<decision>``.
+#: one kind per line. Naming scheme: ``<plane>.<decision>``.
 DECLARED_EVENT_KINDS: tuple = (
     "request.enqueued",
     "request.first_token",

@@ -562,7 +562,7 @@ def _sample_surfaces() -> list[tuple[str, str]]:
     anat = eng.scheduler.anatomy
     anat.roofline = RooflineModel(
         param_bytes=2_600_000_000, page_bytes=4096, page_size=4,
-        param_count=1_300_000_000,
+        param_count=1_300_000_000, device_kind="TPU v5 lite",
     )
     rec = anat.begin("decode_window")
     anat.add_phase(rec, "host_prep", 0.0004)

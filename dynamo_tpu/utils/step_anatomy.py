@@ -29,17 +29,18 @@ The roofline estimator prices the bytes-moved floor of a decode step from
 live state: every step re-reads the full parameter set plus each live
 sequence's KV pages (``quant/kv.kv_page_bytes`` at the ACTUAL cache dtype,
 so int8 KV lowers the floor exactly as it lowers HBM traffic). Dividing by
-the device's HBM bandwidth (``DYNTPU_HBM_GBPS``, default v5e's 819) gives a
-floor time; ``roofline_fraction`` = floor / measured decode seconds — the
-69.8% number as a gauge (``dynamo_engine_roofline_fraction``). On CPU the
-bandwidth constant is fiction, but the *bytes* are exact and the fraction
-still moves with the same code changes, so CPU smoke runs record it labeled
-with the platform.
+the device's HBM bandwidth (``DEVICE_PEAKS``, keyed by ``device_kind``;
+``DYNTPU_HBM_GBPS`` overrides) gives a floor time; ``roofline_fraction`` =
+floor / measured decode seconds, as a gauge
+(``dynamo_engine_roofline_fraction``). A device that is not in the table —
+the CPU of a test run, a part nobody has entered — yields no fraction at
+all: the *bytes* are exact and still reported, a fraction against another
+part's bandwidth would be fiction.
 
 Prefill gets the same treatment (PR 19): a prefill dispatch is
 compute-bound once the chunk is wide enough, so its floor is
 ``max(FLOP bound, bytes bound)`` — ~2·param_count FLOPs per prompt row
-against the MXU peak (``DYNTPU_MXU_TFLOPS``, default v5e's 197 bf16), vs
+against the MXU peak (``DEVICE_PEAKS``; ``DYNTPU_MXU_TFLOPS`` overrides), vs
 one weight read plus the KV the chunk writes against HBM bandwidth. Each
 ``prefill_packed``/``prefill_chunk`` record prices its floor at dispatch
 (``note_prefill_floor``); ``prefill_roofline_fraction`` = summed floors /
@@ -64,8 +65,12 @@ import os
 import threading
 import time
 from collections import deque
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Optional
+
+from dynamo_tpu.utils.logging import get_logger
+
+log = get_logger("utils.step_anatomy")
 
 #: dispatch kinds (the label vocabulary of dynamo_step_seconds_total{kind=})
 KINDS = (
@@ -90,29 +95,47 @@ PREFILL_KINDS = ("prefill_packed", "prefill_chunk")
 #: history — enough for dynotop/debug inspection without unbounded growth
 DEFAULT_RING = 512
 
-#: v5e HBM bandwidth; override with DYNTPU_HBM_GBPS for other parts
-DEFAULT_HBM_GBPS = 819.0
+#: published per-chip peaks, keyed by the ``device_kind`` JAX reports. A
+#: device that is not in this table yields no roofline fraction — never
+#: another part's. ``DYNTPU_HBM_GBPS`` / ``DYNTPU_MXU_TFLOPS`` override (or
+#: supply) a value for any device.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {
+        "hbm_gbps": 819.0,
+        "mxu_tflops": 197.0,  # bf16
+        "source": "Google Cloud documentation, 'TPU v5e': 819 GB/s HBM, "
+                  "197 TFLOP/s bf16 per chip",
+    },
+}
 
-#: v5e bf16 MXU peak; override with DYNTPU_MXU_TFLOPS for other parts (the
-#: FLOP-bound side of the prefill floor — decode never touches it because a
-#: single-token step is bytes-bound by orders of magnitude)
-DEFAULT_MXU_TFLOPS = 197.0
+_unknown_logged: set = set()
 
 
-def hbm_bandwidth_bytes_s() -> float:
-    try:
-        return float(os.environ.get("DYNTPU_HBM_GBPS", DEFAULT_HBM_GBPS)) * 1e9
-    except ValueError:
-        return DEFAULT_HBM_GBPS * 1e9
+def device_peaks(device_kind: Optional[str] = None) -> tuple:
+    """(HBM bytes/s | None, MXU FLOP/s | None) for ``device_kind`` (default:
+    the first device JAX reports): the env override where set, else the
+    table's entry, else None — and the reason is logged once per device."""
+    if device_kind is None:
+        import jax
 
-
-def mxu_flops_s() -> float:
-    try:
-        return float(
-            os.environ.get("DYNTPU_MXU_TFLOPS", DEFAULT_MXU_TFLOPS)
-        ) * 1e12
-    except ValueError:
-        return DEFAULT_MXU_TFLOPS * 1e12
+        device_kind = jax.devices()[0].device_kind
+    entry = DEVICE_PEAKS.get(device_kind, {})
+    out = []
+    for env, key, scale in (("DYNTPU_HBM_GBPS", "hbm_gbps", 1e9),
+                            ("DYNTPU_MXU_TFLOPS", "mxu_tflops", 1e12)):
+        try:
+            value = float(os.environ[env])
+        except (KeyError, ValueError):
+            value = entry.get(key)
+        out.append(None if value is None else value * scale)
+    if None in out and device_kind not in _unknown_logged:
+        _unknown_logged.add(device_kind)
+        log.info(
+            "no published peaks for device kind %r (known: %s): roofline "
+            "fractions are not reported; bytes and FLOP counts still are",
+            device_kind, sorted(DEVICE_PEAKS),
+        )
+    return tuple(out)
 
 
 @dataclass
@@ -130,18 +153,29 @@ class RooflineModel:
     param_bytes: int
     page_bytes: int
     page_size: int
-    hbm_bw: float = field(default_factory=hbm_bandwidth_bytes_s)
+    # peaks of the device the engine runs on (``device_peaks``); None = not
+    # known for this device, and every floor in SECONDS is then None too
+    hbm_bw: Optional[float] = None
     # parameter COUNT (not bytes): the FLOP side of the prefill floor is
     # ~2 FLOPs per parameter per row regardless of storage dtype
     param_count: int = 0
-    mxu_flops: float = field(default_factory=mxu_flops_s)
+    mxu_flops: Optional[float] = None
+    device_kind: Optional[str] = None
+
+    def __post_init__(self):
+        if self.hbm_bw is None or self.mxu_flops is None:
+            bw, flops = device_peaks(self.device_kind)
+            self.hbm_bw = bw if self.hbm_bw is None else self.hbm_bw
+            self.mxu_flops = flops if self.mxu_flops is None else self.mxu_flops
 
     def step_floor_bytes(self, live_pages: int) -> int:
         """Bytes one decode step must move: weights + the live KV pages the
         batch's attention re-reads."""
         return self.param_bytes + live_pages * self.page_bytes
 
-    def step_floor_seconds(self, live_pages: int) -> float:
+    def step_floor_seconds(self, live_pages: int) -> Optional[float]:
+        if self.hbm_bw is None:
+            return None
         return self.step_floor_bytes(live_pages) / max(1.0, self.hbm_bw)
 
     def prefill_floor_bytes(self, rows: int) -> int:
@@ -152,11 +186,14 @@ class RooflineModel:
         pages = -(-max(0, rows) // max(1, self.page_size))
         return self.param_bytes + pages * self.page_bytes
 
-    def prefill_floor_seconds(self, rows: int) -> float:
+    def prefill_floor_seconds(self, rows: int) -> Optional[float]:
         """max(MXU-FLOP bound, bytes-moved bound) for a dispatch computing
         ``rows`` prompt rows: a dense forward pass is ~2·param_count FLOPs
         per row, so wide chunks are compute-bound and narrow ones fall back
-        to the same weight-read floor decode pays."""
+        to the same weight-read floor decode pays. None where the device's
+        peaks are not known."""
+        if self.hbm_bw is None or self.mxu_flops is None:
+            return None
         bytes_s = self.prefill_floor_bytes(rows) / max(1.0, self.hbm_bw)
         flops_s = (
             2.0 * self.param_count * max(0, rows) / max(1.0, self.mxu_flops)
@@ -171,6 +208,7 @@ class RooflineModel:
             "hbm_bw_bytes_s": self.hbm_bw,
             "param_count": self.param_count,
             "mxu_flops_s": self.mxu_flops,
+            "device_kind": self.device_kind,
         }
 
 
@@ -200,9 +238,11 @@ def roofline_for_runner(runner, config) -> Optional[RooflineModel]:
         return None
     if param_bytes <= 0 or page_bytes <= 0:
         return None
+    mesh = getattr(runner, "mesh", None)
     return RooflineModel(
         param_bytes=param_bytes, page_bytes=page_bytes,
         page_size=config.page_size, param_count=param_count,
+        device_kind=mesh.devices.flat[0].device_kind if mesh is not None else None,
     )
 
 
@@ -360,6 +400,8 @@ class StepAnatomy:
         if self.roofline is None or rec is None or rows <= 0:
             return
         floor_s = self.roofline.prefill_floor_seconds(rows)
+        if floor_s is None:  # unknown device: no peaks, no floor in seconds
+            return
         with self._lock:
             rec.floor_s += floor_s
             self.prefill_floor_s_total += floor_s
@@ -394,7 +436,7 @@ class StepAnatomy:
         windows; spec verify rounds on spec engines): the fraction of the
         decode regime's engine time the HBM floor accounts for. None until a
         priced dispatch completes."""
-        if self.roofline is None:
+        if self.roofline is None or self.roofline.hbm_bw is None:
             return None
         with self._lock:
             floor_bytes = self.floor_bytes_total

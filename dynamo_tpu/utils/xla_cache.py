@@ -1,40 +1,78 @@
 """Persistent XLA compilation cache bootstrap for engine processes.
 
-An engine restart otherwise re-pays every executable's compile (~25 s per
-executable on remote-compile platforms); with the cache, executables
-deserialize from disk. One shared helper so every long-lived engine
-entrypoint (run CLI, worker, prefill worker) behaves the same.
+An engine restart otherwise re-pays every executable's compile; with the
+cache, executables deserialize from disk. Every entry point that starts an
+engine (run CLI, worker, prefill worker, SDK service worker, bench,
+chip_smoke's children) goes through this one helper, and no other site sets
+a cache directory.
 """
 
 from __future__ import annotations
 
 import os
+from pathlib import Path
 
 from dynamo_tpu.utils.logging import get_logger
 
 log = get_logger("utils.xla_cache")
 
+#: where the cache goes when ``JAX_COMPILATION_CACHE_DIR`` is not set: one
+#: fixed, git-ignored path inside the checkout. The path is part of what a
+#: cache entry is found by, so it is never made from a temporary name, a pid
+#: or the time — a directory that moves never hits.
+DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[2] / ".xla_cache"
 
-def enable_compilation_cache() -> None:
-    """Point JAX at a persistent compilation cache directory.
+_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "hits",
+    "/jax/compilation_cache/cache_misses": "misses",
+}
+_stats: dict = {"dir": None, "hits": 0, "misses": 0}
 
-    ``JAX_COMPILATION_CACHE_DIR`` overrides the default (set it empty to
-    disable). The default is per-user: a fixed path in shared /tmp would be
-    unwritable for the second user on a host — and poisonable by the first.
-    """
-    default = os.path.join(
-        os.environ.get("XDG_CACHE_HOME", os.path.expanduser("~/.cache")),
-        "dynamo_tpu", "xla_cache",
-    )
-    path = os.environ.get("JAX_COMPILATION_CACHE_DIR", default)
-    if not path:
-        return
+
+def _count(event: str, **_kw) -> None:
+    key = _EVENTS.get(event)
+    if key is not None:
+        _stats[key] += 1
+
+
+def cache_stats() -> dict:
+    """{"dir", "hits", "misses"} of this process's persistent cache since
+    ``enable_compilation_cache()``; dir is None where it was never enabled.
+    (/ready of a colocated engine carries it; chip_smoke.py prints it.)"""
+    return dict(_stats)
+
+
+def enable_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already honours it and
+    nothing is overridden here. Unset, the cache goes to
+    ``DEFAULT_CACHE_DIR``. A directory that cannot be created or written is
+    an error at start-up, not a warning: an engine that silently recompiles
+    everything on every restart is a different deployment from the one asked
+    for."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    from_env = bool(path)
+    if not from_env:
+        path = str(DEFAULT_CACHE_DIR)
     try:
-        import jax
+        os.makedirs(path, exist_ok=True)
+        probe = os.path.join(path, f".writable.{os.getpid()}")
+        with open(probe, "w"):
+            pass
+        os.remove(probe)
+    except OSError as e:
+        raise RuntimeError(
+            f"XLA compilation cache directory {path!r} is not usable: {e} "
+            "(set JAX_COMPILATION_CACHE_DIR to a writable directory)"
+        ) from e
+    import jax
 
+    if not from_env:
         jax.config.update("jax_compilation_cache_dir", path)
-    except Exception:
-        log.warning(
-            "persistent compilation cache unavailable (path %s); engine "
-            "restarts will recompile every executable", path, exc_info=True,
-        )
+    if _stats["dir"] is None:
+        jax.monitoring.register_event_listener(_count)
+    _stats["dir"] = path
+    log.info("XLA compilation cache: %s (%s)", path,
+             "JAX_COMPILATION_CACHE_DIR" if from_env else "default, in the checkout")
+    return path

@@ -147,7 +147,8 @@ def test_planner_service_publishes_desired_replicas():
 
 def test_supervisor_applies_planner_scaling(monkeypatch):
     """The serve supervisor consumes the planner's desired-replica keys:
-    scale-up spawns new replicas (chip envs reused round-robin), scale-down
+    scale-up spawns new replicas, each on its own chip assignment and never
+    more than there are chips (a chip belongs to one process), scale-down
     terminates the highest indices and the restart loop leaves them dead."""
     from dynamo_tpu.sdk.serve import Supervisor
 
@@ -158,19 +159,19 @@ def test_supervisor_applies_planner_scaling(monkeypatch):
         component = "worker"
 
     cls = type("Worker", (), {})
-    envs = [{"TPU_VISIBLE_DEVICES": "0"}, {"TPU_VISIBLE_DEVICES": "1"}]
+    envs = [{"TPU_VISIBLE_DEVICES": str(i)} for i in range(4)]
     sup._class_info["Worker"] = (cls, Meta, envs)
     sup.desired["Worker"] = 2
 
     spawned = []
     monkeypatch.setattr(sup, "spawn", lambda c, i, env=None: spawned.append((i, env)))
     monkeypatch.setattr(
-        sup, "_read_planner_desired", lambda: {"planner/pl/desired/worker": 4}
+        sup, "_read_planner_desired", lambda: {"planner/pl/desired/worker": 6}
     )
     sup._apply_planner_scaling()
+    # six wanted, four chips: held at four, replicas 2,3 on chips 2,3
     assert sup.desired["Worker"] == 4
-    # replicas 2,3 spawned; envs reused round-robin beyond the initial pool
-    assert spawned == [(2, envs[0]), (3, envs[1])]
+    assert spawned == [(2, envs[2]), (3, envs[3])]
 
     class FakeProc:
         def __init__(self):

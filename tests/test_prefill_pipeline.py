@@ -11,7 +11,7 @@ import pytest
 
 from dynamo_tpu.engine.config import EngineConfig
 from dynamo_tpu.utils.step_anatomy import (
-    DEFAULT_MXU_TFLOPS,
+    DEVICE_PEAKS,
     RooflineModel,
     StepAnatomy,
 )
@@ -46,11 +46,15 @@ def test_chunk_len_for_backlog_promotion():
 # ---------------- prefill floor arithmetic ----------------
 
 
+V5E = "TPU v5 lite"
+DEFAULT_MXU_TFLOPS = DEVICE_PEAKS[V5E]["mxu_tflops"]
+
+
 def test_prefill_floor_hand_computed(monkeypatch):
     monkeypatch.delenv("DYNTPU_MXU_TFLOPS", raising=False)
     roof = RooflineModel(
         param_bytes=1_000_000, page_bytes=2048, page_size=16,
-        hbm_bw=1e9, param_count=500_000,
+        hbm_bw=1e9, param_count=500_000, device_kind=V5E,
     )
     # bytes bound: params + ceil(48/16)=3 pages; FLOP bound: 2*N*rows/MXU
     rows = 48
@@ -63,7 +67,7 @@ def test_prefill_floor_hand_computed(monkeypatch):
     # a big enough model goes FLOP-bound; the env knob moves the bound
     big = RooflineModel(
         param_bytes=10, page_bytes=1, page_size=16,
-        hbm_bw=1e15, param_count=10**12,
+        hbm_bw=1e15, param_count=10**12, device_kind=V5E,
     )
     assert big.prefill_floor_seconds(512) == pytest.approx(
         2.0 * 10**12 * 512 / (DEFAULT_MXU_TFLOPS * 1e12)
@@ -80,7 +84,7 @@ def test_prefill_floor_hand_computed(monkeypatch):
 
 def test_prefill_plane_accumulation_and_gauge():
     roof = RooflineModel(param_bytes=1000, page_bytes=10, page_size=4,
-                         hbm_bw=1000.0, param_count=100)
+                         hbm_bw=1000.0, param_count=100, device_kind=V5E)
     a = StepAnatomy(roofline=roof)
     assert a.prefill_roofline_fraction() is None  # no priced prefill yet
     assert a.prefill_fixed_ms() is None

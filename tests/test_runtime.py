@@ -181,6 +181,25 @@ def test_stats_scrape():
     run(with_cluster(body))
 
 
+def test_stats_scrape_survives_a_sibling_endpoint_without_stats():
+    """One process serving two endpoints of a component (the worker's
+    `generate` + `migrate`): the sibling that reports nothing must not take
+    the component's scrape subject from the one that does — it used to, the
+    KV router saw no loads and placed every request at random."""
+    async def body(drt):
+        worker, caller = await drt(), await drt()
+        await serve_doubler(worker)
+
+        async def quiet(request):
+            yield {}
+
+        await worker.namespace("test").component("worker").endpoint("migrate").serve_endpoint(quiet)
+        stats = await collect_service_stats(caller.cplane, "test", "worker", timeout=0.3)
+        assert [(e.endpoint, e.data) for e in stats.endpoints] == [("generate", {"load": 0.5})]
+
+    run(with_cluster(body))
+
+
 def test_dyn_endpoint_address():
     async def body(drt):
         worker, caller = await drt(), await drt()

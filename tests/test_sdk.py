@@ -82,7 +82,8 @@ def test_serve_supervisor_agg_graph(tmp_path):
     conf.write_text(
         f"Frontend:\n  model: tiny\n  host: 127.0.0.1\n  port: {http_port}\n"
         "Processor:\n  routing: kv\n  kv_block_size: 4\n"
-        "TpuWorker:\n  model: tiny\n"
+        # no chip here: run the chip-requesting worker on the CPU by name
+        "TpuWorker:\n  model: tiny\n  resources:\n    tpu: 0\n"
     )
     proc = subprocess.Popen(
         [
@@ -157,15 +158,18 @@ def test_resource_allocator_whole_chips():
     assert envs[0]["TPU_VISIBLE_DEVICES"] == "2,3"
 
 
-def test_resource_allocator_fractional_shares_chip():
+def test_resource_allocator_refuses_a_shared_chip():
+    """A chip belongs to one process: a fractional request, which used to put
+    two workers on chip 0, is refused (the second could not open the chip)."""
     from dynamo_tpu.sdk.allocator import ResourceAllocator
     from dynamo_tpu.sdk.decorators import ServiceMeta
 
     alloc = ResourceAllocator(total_chips=2)
-    meta = ServiceMeta(workers=2, resources={"tpu": 0.5})
-    _, envs = alloc.get_worker_env(meta, {})
-    # both half-chip workers co-locate on chip 0
-    assert envs[0]["TPU_VISIBLE_DEVICES"] == envs[1]["TPU_VISIBLE_DEVICES"] == "0"
+    with pytest.raises(ValueError, match="cannot be shared"):
+        alloc.get_worker_env(ServiceMeta(workers=2, resources={"tpu": 0.5}), {})
+    with pytest.raises(ValueError, match="whole chips"):
+        alloc.assign_chips(1.5)
+    assert alloc.remaining_chips == 2  # nothing was handed out
 
 
 def test_resource_allocator_cpu_service_pinned_off_tpu():
@@ -183,15 +187,32 @@ def test_resource_allocator_cpu_service_pinned_off_tpu():
     assert len({e["TPU_VISIBLE_DEVICES"] for e in envs}) == 3
 
 
-def test_resource_allocator_overcommit_warns():
-    import warnings as _w
-
+@pytest.mark.parametrize("detected, workers", [(0, 1), (1, 2)])
+def test_resource_allocator_fails_without_enough_chips(detected, workers, monkeypatch):
+    """A service that asks for chips where none (or too few) are detected
+    fails at start-up with a message that says what to do; it used to leave
+    every worker to contend for whatever was visible."""
     from dynamo_tpu.sdk.allocator import ResourceAllocator
     from dynamo_tpu.sdk.decorators import ServiceMeta
 
-    alloc = ResourceAllocator(total_chips=1)
-    with _w.catch_warnings(record=True) as caught:
-        _w.simplefilter("always")
-        _, envs = alloc.get_worker_env(ServiceMeta(workers=2, resources={"tpu": 1}), {})
-    assert any(issubclass(c.category, ResourceWarning) for c in caught)
-    assert len(envs) == 2
+    alloc = ResourceAllocator(total_chips=detected)
+    meta = ServiceMeta(workers=workers, resources={"tpu": 1})
+    with pytest.raises(RuntimeError, match=r"tpu: 0.*DYNTPU_DISABLE_TPU_ALLOCATION"):
+        alloc.get_worker_env(meta, {})
+    # the two ways out the message names
+    _, envs = alloc.get_worker_env(meta, {"resources": {"tpu": 0}})
+    assert envs == [{"JAX_PLATFORMS": "cpu"}] * workers
+    monkeypatch.setenv("DYNTPU_DISABLE_TPU_ALLOCATION", "1")
+    _, envs = alloc.get_worker_env(meta, {})
+    assert envs == [{}] * workers
+
+
+def test_one_chip_worker_env_sets_process_bounds():
+    from dynamo_tpu.sdk.allocator import chip_env
+
+    assert chip_env([2]) == {
+        "TPU_VISIBLE_DEVICES": "2",
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+    }
+    assert chip_env([2, 3]) == {"TPU_VISIBLE_DEVICES": "2,3"}

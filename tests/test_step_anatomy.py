@@ -138,6 +138,44 @@ def test_roofline_fraction_and_dispatch_gap():
     assert a.dispatch_gap_ms("offload_drain") is None
 
 
+@pytest.mark.parametrize("kind", ["cpu", "TPU v99 imaginary"])
+def test_unknown_device_kind_yields_no_roofline_fraction(kind, monkeypatch):
+    """Peaks come from one table keyed by device_kind; a device that is not
+    in it gets no fraction (it used to get the v5e's), while the bytes stay
+    exact. The env overrides still supply a value for any device."""
+    from dynamo_tpu.utils.step_anatomy import DEVICE_PEAKS, device_peaks
+
+    monkeypatch.delenv("DYNTPU_HBM_GBPS", raising=False)
+    monkeypatch.delenv("DYNTPU_MXU_TFLOPS", raising=False)
+    assert kind not in DEVICE_PEAKS
+    assert device_peaks(kind) == (None, None)
+    assert device_peaks("TPU v5 lite") == (819e9, 197e12)
+    assert "Google Cloud" in DEVICE_PEAKS["TPU v5 lite"]["source"]
+
+    roof = RooflineModel(param_bytes=1000, page_bytes=10, page_size=4,
+                         param_count=100, device_kind=kind)
+    assert roof.step_floor_bytes(5) == 1050
+    assert roof.step_floor_seconds(5) is None
+    assert roof.prefill_floor_seconds(64) is None
+    a = StepAnatomy(roofline=roof)
+    rec = a.begin("decode_window", ts=1.0)
+    a.add_phase(rec, "dispatch", 1.0)
+    a.note_steps(rec, steps=2, floor_bytes=a.decode_floor_bytes(5, 2))
+    prec = a.begin("prefill_packed", ts=2.0)
+    a.add_phase(prec, "dispatch", 1.0)
+    a.note_prefill_floor(prec, 64)
+    snap = a.snapshot()
+    assert snap["floor_bytes_total"] == 2100
+    assert snap["roofline_frac"] is None and snap["prefill_roofline_frac"] is None
+    assert snap["roofline"]["device_kind"] == kind
+    assert "roofline_fraction" not in a.render_metrics()
+
+    monkeypatch.setenv("DYNTPU_HBM_GBPS", "1")
+    assert device_peaks(kind) == (1e9, None)
+    roof2 = RooflineModel(param_bytes=1000, page_bytes=10, page_size=4, device_kind=kind)
+    assert roof2.step_floor_seconds(5) == pytest.approx(1050 / 1e9)
+
+
 def test_decode_floor_without_roofline_is_zero():
     a = StepAnatomy()
     assert a.decode_floor_bytes(100, 4) == 0

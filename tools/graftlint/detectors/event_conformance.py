@@ -39,7 +39,7 @@ RULE = "event-conformance"
 DECLARATION_NAME = "DECLARED_EVENT_KINDS"
 DECLARING_MODULE = "dynamo_tpu/utils/events.py"
 
-#: the taxonomy shape: ``<plane>.<decision>`` (one dot, snake_case halves)
+#: the shape of a kind: ``<plane>.<decision>`` (one dot, snake_case halves)
 _KIND_RE = re.compile(r"^[a-z][a-z0-9_]*\.[a-z][a-z0-9_]*$")
 
 

@@ -16,5 +16,5 @@ class _Journal:
 def instrument(journal: _Journal, signals: _Journal):
     journal.emit("fixture.admitted")  # exact reference
     journal.emit("fixture.preempted", generated=7)  # exact reference
-    signals.emit("plain text, not a kind")  # no taxonomy shape: skipped
+    signals.emit("plain text, not a kind")  # not the kind shape: skipped
     signals.emit("topic.changed")  # graftlint: event-ok pubsub topic, not a journal kind

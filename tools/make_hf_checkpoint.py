@@ -34,6 +34,22 @@ TINYLLAMA_GEOMETRY = dict(
     vocab_size=32000,
 )
 
+#: Qwen2.5-7B's published widths (config.json of Qwen/Qwen2.5-7B-Instruct),
+#: qkv bias included; depth is the caller's to cut (28 published)
+QWEN25_7B_GEOMETRY = dict(
+    architectures=["Qwen2ForCausalLM"],
+    model_type="qwen2",
+    hidden_size=3584,
+    intermediate_size=18944,
+    num_hidden_layers=28,
+    num_attention_heads=28,
+    num_key_value_heads=4,
+    vocab_size=152064,
+    rope_theta=1000000.0,
+    rms_norm_eps=1e-6,
+    max_position_embeddings=32768,
+)
+
 TINY_GEOMETRY = dict(
     hidden_size=64,
     intermediate_size=128,
@@ -134,6 +150,10 @@ def make_checkpoint(out_dir: str, geometry: dict | None = None, seed: int = 0) -
         tensors[pre + "self_attn.k_proj.weight"] = w(Hkv * head_dim, D)
         tensors[pre + "self_attn.v_proj.weight"] = w(Hkv * head_dim, D)
         tensors[pre + "self_attn.o_proj.weight"] = w(D, Hq * head_dim)
+        if "qwen" in config["model_type"]:  # Qwen2-family qkv biases
+            tensors[pre + "self_attn.q_proj.bias"] = w(Hq * head_dim)
+            tensors[pre + "self_attn.k_proj.bias"] = w(Hkv * head_dim)
+            tensors[pre + "self_attn.v_proj.bias"] = w(Hkv * head_dim)
         tensors[pre + "mlp.gate_proj.weight"] = w(I, D)
         tensors[pre + "mlp.up_proj.weight"] = w(I, D)
         tensors[pre + "mlp.down_proj.weight"] = w(D, I)

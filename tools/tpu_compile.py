@@ -1,0 +1,340 @@
+"""Ask the TPU's compiler, without a TPU, whether the serving kernels and steps
+compile for one v5e chip (and a tp=4 step for a 2x2 mesh).
+
+libtpu compiles for a chip that is described and not attached
+(``jax.experimental.topologies``), so what Mosaic or XLA would refuse on the
+chip is refused here, at no chip time: a DMA slice not aligned to the tiling,
+a kernel that overruns scoped VMEM, a step that does not fit HBM, a kernel
+that cannot be partitioned. Nothing runs, so this says nothing about results
+or times — ``chip_smoke.py`` is the run.
+
+The attention dispatchers ask ``jax.default_backend()``, which is still the
+CPU here, so ``on_chip_dispatch()`` steers them the way the chip would
+(kernels on, interpret off) around each lowering; the program itself has no
+option for this.
+
+    JAX_PLATFORMS=cpu python tools/tpu_compile.py            # every case
+    JAX_PLATFORMS=cpu python tools/tpu_compile.py --steps    # whole steps only
+
+``tests/test_tpu_compile.py`` runs ``kernel_cases(full=False)`` in tier-1.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import os
+import sys
+import time
+from dataclasses import dataclass
+from pathlib import Path
+from typing import Callable
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+from tools.make_hf_checkpoint import QWEN25_7B_GEOMETRY, TINYLLAMA_GEOMETRY  # noqa: E402
+
+TOPOLOGY = "v5e:2x2"
+
+#: published head geometries: (name, q heads, kv heads, head_dim)
+GQA_GEOMETRIES = (
+    ("tinyllama-1.1b", 32, 4, 64),  # folded pools
+    ("qwen2.5-7b", 28, 4, 128),
+    ("mixtral-8x7b", 32, 8, 128),
+)
+#: DeepSeek-V2-Lite: 16 heads, kv_lora_rank 512 + rope 64, latent padded to 640
+MLA_HEADS, MLA_DC, MLA_LATENT = 16, 512, 640
+
+
+def topology():
+    """The described v5e host; raises where libtpu cannot describe one."""
+    from jax.experimental import topologies
+
+    return topologies.get_topology_desc(platform="tpu", topology_name=TOPOLOGY)
+
+
+@contextlib.contextmanager
+def on_chip_dispatch():
+    """Make the attention dispatchers choose what they choose on the chip,
+    with the persistent compile cache off (an entry written for a described
+    chip cannot be read back without one, and warns)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from dynamo_tpu.ops import attention
+
+    real, attention._on_tpu = attention._on_tpu, lambda: True
+    flag = os.environ.pop("DYNTPU_PALLAS", None)
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        attention._on_tpu = real
+        if flag is not None:
+            os.environ["DYNTPU_PALLAS"] = flag
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+
+
+@dataclass(frozen=True)
+class Case:
+    name: str
+    build: Callable  # (struct) -> (fn, args); struct(shape, dtype) places on the chip
+
+
+def _pools(S, num_pages, ps, hkv, d, int8: bool, folded: bool):
+    from dynamo_tpu.quant.kv import QuantizedPages
+
+    shape = (num_pages, ps, hkv * d) if folded else (num_pages, ps, hkv, d)
+    if int8:
+        return QuantizedPages(S(shape, jnp.int8), S((num_pages, ps), jnp.float32))
+    return S(shape, jnp.bfloat16)
+
+
+def _decode_case(geo, ps, int8, kernel=None):
+    name, hq, hkv, d = geo
+    B, num_pages, max_pages = 16, 256, 2048 // ps
+
+    def build(S):
+        from dynamo_tpu.ops import attention
+
+        fn = kernel or attention.dispatch_paged_decode_attention
+        folded = d < 128
+        return fn, (
+            S((B, hq, d), jnp.bfloat16),
+            _pools(S, num_pages, ps, hkv, d, int8, folded),
+            _pools(S, num_pages, ps, hkv, d, int8, folded),
+            S((B, max_pages), jnp.int32), S((B,), jnp.int32),
+        )
+
+    tag = f"-{kernel.__name__}" if kernel else ""
+    return Case(f"decode{tag}-{name}-ps{ps}-{'int8' if int8 else 'bf16'}", build)
+
+
+def _prefill_case(geo, ps, T, int8, lookahead=None):
+    name, hq, hkv, d = geo
+    num_pages, max_pages = 256, 2048 // ps
+
+    def build(S):
+        from dynamo_tpu.ops import attention
+        from dynamo_tpu.ops.pallas.prefill_attention import paged_prefill_attention_pallas
+
+        folded = d < 128
+        if lookahead is None:
+            fn = attention.dispatch_paged_prefill_attention
+        else:
+            def fn(*a):
+                return paged_prefill_attention_pallas(*a, lookahead=lookahead)
+        return fn, (
+            S((T, hq, d), jnp.bfloat16),
+            _pools(S, num_pages, ps, hkv, d, int8, folded),
+            _pools(S, num_pages, ps, hkv, d, int8, folded),
+            S((max_pages,), jnp.int32), S((T,), jnp.int32),
+        )
+
+    tag = "" if lookahead is None else ("-lookahead" if lookahead else "-basic")
+    return Case(f"prefill{tag}-{name}-ps{ps}-T{T}-{'int8' if int8 else 'bf16'}", build)
+
+
+def _mla_decode_case(ps, lookahead):
+    def build(S):
+        from dynamo_tpu.ops.pallas.mla_attention import paged_mla_decode_attention_pallas
+
+        def fn(*a):
+            return paged_mla_decode_attention_pallas(*a, d_c=MLA_DC, lookahead=lookahead)
+        # the model hands the kernels an f32 folded query (deepseek._fold_q)
+        return fn, (
+            S((16, MLA_HEADS, MLA_LATENT), jnp.float32),
+            S((256, ps, MLA_LATENT), jnp.bfloat16),
+            S((16, 2048 // ps), jnp.int32), S((16,), jnp.int32),
+        )
+
+    return Case(f"mla-decode-{'lookahead' if lookahead else 'classic'}-ps{ps}", build)
+
+
+def _mla_prefill_case(ps, T):
+    def build(S):
+        from dynamo_tpu.ops.pallas.mla_attention import paged_mla_prefill_attention_pallas
+
+        def fn(*a):
+            return paged_mla_prefill_attention_pallas(*a, d_c=MLA_DC)
+        return fn, (
+            S((T, MLA_HEADS, MLA_LATENT), jnp.float32),
+            S((256, ps, MLA_LATENT), jnp.bfloat16),
+            S((2048 // ps,), jnp.int32), S((T,), jnp.int32),
+        )
+
+    return Case(f"mla-prefill-ps{ps}-T{T}", build)
+
+
+def kernel_cases(full: bool) -> list[Case]:
+    """Every Pallas kernel the default dispatch can reach, at published head
+    geometries. ``full``: each page size (16 engine default, 64, 128) x each
+    prefill bucket x bf16/int8; otherwise the tier-1 subset — one or two
+    cases per kernel and geometry, the shapes the seed's kernels were refused
+    at among them (marked)."""
+    from dynamo_tpu.ops.pallas.paged_attention import paged_decode_attention_pallas
+
+    tiny, qwen, mixtral = GQA_GEOMETRIES
+    bench = ("bench-16q8kv", 16, 8, 128)
+    if full:
+        cases = []
+        for geo in (*GQA_GEOMETRIES, bench):
+            for ps in (16, 64, 128):
+                for int8 in (False, True):
+                    cases.append(_decode_case(geo, ps, int8))
+                    buckets = (64, 128, 256, 512, 1024) if geo[3] < 128 else (128, 256, 512, 1024)
+                    cases += [_prefill_case(geo, ps, T, int8) for T in buckets]
+        cases += [_prefill_case(mixtral, 16, 512, False, lookahead=False),
+                  _decode_case(qwen, 16, False, kernel=paged_decode_attention_pallas),
+                  _decode_case(qwen, 16, True, kernel=paged_decode_attention_pallas)]
+        for ps in (16, 64, 128):
+            cases += [_mla_decode_case(ps, False), _mla_decode_case(ps, True)]
+            cases += [_mla_prefill_case(ps, T) for T in (128, 256, 512, 1024)]
+        return cases
+    return [
+        # decode: folded, lookahead, and the per-sequence kernel lookahead
+        # falls back to; int8 at page size < 128 was refused (scale-plane
+        # slice not aligned to the 128 tiling)
+        _decode_case(tiny, 16, False), _decode_case(tiny, 16, True),
+        _decode_case(qwen, 16, False), _decode_case(qwen, 16, True),
+        _decode_case(mixtral, 128, False), _decode_case(mixtral, 64, True),
+        _decode_case(qwen, 16, True, kernel=paged_decode_attention_pallas),
+        # folded flash prefill (TinyLlama): smallest and largest bucket
+        _prefill_case(tiny, 16, 64, False), _prefill_case(tiny, 16, 1024, True),
+        # lookahead flash prefill: refused at every head_dim-128 shape
+        # (scoped VMEM 18-23 MiB against the 16 MiB default)
+        _prefill_case(bench, 128, 512, False),
+        _prefill_case(qwen, 16, 1024, False),
+        _prefill_case(mixtral, 16, 512, True),
+        # the basic variant was refused at 32q/8kv too
+        _prefill_case(mixtral, 16, 512, False, lookahead=False),
+        # MLA: decode both variants; prefill refused with the f32 query the
+        # model passes (17.3 MiB)
+        _mla_decode_case(16, False), _mla_decode_case(128, True),
+        _mla_prefill_case(16, 512),
+    ]
+
+
+def compile_case(case: Case, topo=None):
+    """Lower and compile one case for the first described chip; raises what
+    the chip's compiler raises."""
+    from jax.sharding import SingleDeviceSharding
+
+    topo = topo or topology()
+    chip = SingleDeviceSharding(topo.devices[0])
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    with on_chip_dispatch():
+        fn, args = case.build(S)
+        return jax.jit(fn).lower(*args).compile()
+
+
+# ---------------- whole steps ----------------
+
+
+def _llama_step_structs(geometry: dict, mesh, num_pages: int, page_size: int):
+    """(model, params, kv) as ShapeDtypeStructs sharded the way ModelRunner
+    shards them on ``mesh``; ``geometry`` is a config.json dict
+    (tools/make_hf_checkpoint.py)."""
+    from dynamo_tpu.models.llama import LlamaConfig, LlamaModel
+
+    model = LlamaModel(LlamaConfig.from_hf_config(geometry))
+    if mesh.shape.get("tp", 1) > 1:
+        model.attn_mesh = mesh
+
+    def place(tree, shardings):
+        return jax.tree.map(
+            lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s), tree, shardings
+        )
+
+    params = place(jax.eval_shape(model.init_params, jax.random.key(0)),
+                   model.param_shardings(mesh))
+    kv = place(jax.eval_shape(lambda: model.init_kv_cache(num_pages, page_size)),
+               model.kv_cache_sharding(mesh))
+    return model, params, kv
+
+
+def compile_steps(geometry: dict, tp: int, num_pages: int, page_size: int = 16,
+                  max_seqs: int = 16, lanes: int = 2, bucket: int = 512,
+                  max_model_len: int = 2048, topo=None) -> dict:
+    """Compile one decode step and one packed prefill step of a llama-family
+    model on ``tp`` described chips; returns {step: compiled}."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    topo = topo or topology()
+    mesh = Mesh(np.array(topo.devices[:tp]), ("tp",))
+    rep = NamedSharding(mesh, P())
+    mp = max_model_len // page_size
+
+    def R(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
+
+    out = {}
+    with on_chip_dispatch():
+        model, params, kv = _llama_step_structs(geometry, mesh, num_pages, page_size)
+        out["decode"] = jax.jit(model.decode, donate_argnums=(1,)).lower(
+            params, kv, R((max_seqs,), jnp.int32), R((max_seqs,), jnp.int32),
+            R((max_seqs, mp), jnp.int32), R((max_seqs,), jnp.bool_),
+        ).compile()
+        out["prefill_packed"] = jax.jit(model.prefill_packed, donate_argnums=(1,)).lower(
+            params, kv, R((lanes, bucket), jnp.int32), R((lanes, bucket), jnp.int32),
+            R((lanes, mp), jnp.int32), R((lanes, bucket), jnp.bool_), R((lanes,), jnp.int32),
+        ).compile()
+    return out
+
+
+def _report_steps(title: str, steps: dict) -> None:
+    for name, compiled in steps.items():
+        m = compiled.memory_analysis()
+        text = compiled.as_text()
+        print(f"{title} {name}: args {m.argument_size_in_bytes / 2**30:.2f} GiB, "
+              f"temp {m.temp_size_in_bytes / 2**30:.2f} GiB, "
+              f"output {m.output_size_in_bytes / 2**30:.2f} GiB, "
+              f"alias {m.alias_size_in_bytes / 2**30:.2f} GiB per device; "
+              f"tpu_custom_call x{text.count('tpu_custom_call')}, "
+              f"all-reduce x{text.count('all-reduce(')}", flush=True)
+
+
+def main(argv=None) -> int:
+    import argparse
+
+    ap = argparse.ArgumentParser(description=__doc__)
+    ap.add_argument("--kernels", action="store_true", help="only the kernel sweep")
+    ap.add_argument("--steps", action="store_true", help="only the whole-step compiles")
+    ap.add_argument("--tp4-layers", type=int, default=4,
+                    help="depth of the Qwen2.5-7B-width model in the tp=4 step")
+    args = ap.parse_args(argv)
+    topo = topology()
+    print(f"compiling for {topo.devices[0].device_kind} x{len(topo.devices)} "
+          f"({TOPOLOGY}, described, not attached)", flush=True)
+    failed = 0
+    if not args.steps:
+        for case in kernel_cases(full=True):
+            t0 = time.monotonic()
+            try:
+                compile_case(case, topo)
+                verdict = "ok"
+            except Exception as e:  # report every refusal, then fail
+                failed += 1
+                verdict = "REFUSED " + " ".join(str(e).split())[:240]
+            print(f"{case.name}: {verdict} ({time.monotonic() - t0:.1f}s)", flush=True)
+    if not args.kernels:
+        _report_steps("tinyllama-1.1b tp=1", compile_steps(TINYLLAMA_GEOMETRY, 1, num_pages=2048, topo=topo))
+        qwen = dict(QWEN25_7B_GEOMETRY, num_hidden_layers=args.tp4_layers)
+        for tp in (1, 4):
+            _report_steps(f"qwen2.5-7b-width L={args.tp4_layers} tp={tp}",
+                          compile_steps(qwen, tp, num_pages=512, topo=topo))
+    print(f"{'FAILED' if failed else 'ok'}: {failed} refused", flush=True)
+    return 1 if failed else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
