@@ -1691,7 +1691,11 @@ class AsyncJaxEngine:
             did_work = self._drain_inboxes()
             if self.scheduler.has_work():
                 try:
-                    outputs = self.scheduler.step()
+                    # the parent of this step's anatomy phases; what the
+                    # engine thread does outside every phase shows as its
+                    # uncovered part in a profiler trace
+                    with tracing.span("engine.step", step=self.step_count):
+                        outputs = self.scheduler.step()
                     self.step_count += 1
                 except Exception as e:  # engine-step failure: fail all running
                     log.exception("engine step failed")
@@ -1712,13 +1716,18 @@ class AsyncJaxEngine:
                         log.exception("post-mortem dump failed")
                     self._fail_all(e)
                     continue
-                self._post_grouped(outputs)
+                with tracing.span("engine.post", outputs=len(outputs)):
+                    self._post_grouped(outputs)
             elif not did_work:
-                try:
-                    req = self._inbox.get(timeout=0.02)
+                # nothing to run: a device gap under this span is "no
+                # request", not time the host took
+                with tracing.span("engine.wait_for_work"):
+                    try:
+                        req = self._inbox.get(timeout=0.02)
+                    except thread_queue.Empty:
+                        req = None
+                if req is not None:
                     self.scheduler.add_request(req)
-                except thread_queue.Empty:
-                    pass
 
     def _drain_inboxes(self) -> bool:
         got = False

@@ -263,41 +263,54 @@ class ModelRunner:
 
         self.compile_monitor = CompileMonitor()
 
-        def _mjit(label, fn):
-            return monitored_jit(fn, label, self.compile_monitor)
+        def _mjit(label, impl, **jit_args):
+            """``jax.jit(impl)`` under the compile monitor, compiled as the
+            module ``dynamo_<label>``: a profiler's `XLA Modules` line names
+            the step by what it is, whatever the method is called. jit takes
+            the module's name from the function's ``__name__`` (a bound
+            method's is its function's), so that is what is set; the name is
+            fixed text, since the persistent compile cache keys on it. (A
+            wrapper function with the name cost 1.7 s more at the first call
+            of every prefill variant on the chip: PERF.md, PR 25.)"""
+            fn = getattr(impl, "__func__", impl)
+            fn.__name__ = fn.__qualname__ = f"dynamo_{label}"
+            return monitored_jit(
+                jax.jit(impl, **jit_args), label, self.compile_monitor
+            )
 
-        self._prefill = _mjit("prefill", jax.jit(
-            self._prefill_impl, donate_argnums=(1, 2),
+        self._prefill = _mjit(
+            "prefill", self._prefill_impl, donate_argnums=(1, 2),
             static_argnames=("want_lp", "want_pen", "want_seed", "want_eos_mask", "mp"),
-        ))
+        )
         # cross-request packed prefill (one weight pass for N lanes); one
         # executable per (N, bucket, table width) actually used
-        self._prefill_packed = _mjit("prefill_packed", jax.jit(
-            self._prefill_packed_impl, donate_argnums=(1, 2),
+        self._prefill_packed = _mjit(
+            "prefill_packed", self._prefill_packed_impl, donate_argnums=(1, 2),
             static_argnames=("want_lp", "want_pen", "want_seed", "want_eos_mask", "mp"),
-        ))
+        )
         # multimodal vision encode (compiled lazily; text-only models never
         # pay for it — the mm prefill variant is _prefill traced with embeds)
-        self._encode_images = _mjit("encode_images", jax.jit(
+        self._encode_images = _mjit(
+            "encode_images",
             lambda params, patches, rows, cols, valid, segments: self.model.encode_images(
                 params, patches, rows, cols, valid, segments=segments
-            )
-        ))
+            ),
+        )
         if config.sp > 1:
             # sequence-parallel whole-prompt prefill (ring attention over sp)
-            self._prefill_sp = _mjit("prefill_sp", jax.jit(
-                self._prefill_sp_impl, donate_argnums=(1, 2),
+            self._prefill_sp = _mjit(
+                "prefill_sp", self._prefill_sp_impl, donate_argnums=(1, 2),
                 static_argnames=("want_lp", "want_pen", "want_seed", "want_eos_mask", "mp"),
-            ))
-        self._decode_window = _mjit("decode_window", jax.jit(
-            self._decode_window_impl, donate_argnums=(1, 2),
+            )
+        self._decode_window = _mjit(
+            "decode_window", self._decode_window_impl, donate_argnums=(1, 2),
             static_argnames=("num_steps", "want_lp", "want_pen", "want_seed", "want_eos_mask"),
-        ))
+        )
         # speculative verify step (spec subsystem): ONE trace regardless of
         # sampling features — seeds/filters are neutral-input no-ops, and
         # penalties/logprobs requests never ride this path (the scheduler
         # routes them through classic windows)
-        self._verify = _mjit("verify", jax.jit(self._verify_impl, donate_argnums=(1,)))
+        self._verify = _mjit("verify", self._verify_impl, donate_argnums=(1,))
         # draft-model speculation: a second model with its own paged KV pool
         # and a batched k-token drafting dispatch (spec/draft.py). Loaded
         # through the registry with THIS engine's quantize/kv_cache_dtype so
@@ -336,13 +349,15 @@ class ModelRunner:
         def _flat_ids(ids):  # [n] logical -> [L, n] flat
             return ids[None, :] + (jnp.arange(L, dtype=jnp.int32) * Pn)[:, None]
 
-        self._gather_pages = _mjit("gather_pages", jax.jit(
-            lambda kv, ids: model.gather_pages_wire(kv, _flat_ids(ids))
-        ))
-        self._scatter_pages = _mjit("scatter_pages", jax.jit(
+        self._gather_pages = _mjit(
+            "gather_pages",
+            lambda kv, ids: model.gather_pages_wire(kv, _flat_ids(ids)),
+        )
+        self._scatter_pages = _mjit(
+            "scatter_pages",
             lambda kv, ids, data: model.scatter_pages_wire(kv, _flat_ids(ids), data),
             donate_argnums=(0,),
-        ))
+        )
 
     # ---------------- jitted bodies ----------------
 

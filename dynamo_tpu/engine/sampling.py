@@ -118,6 +118,7 @@ def filter_keep_mask(
     return keep
 
 
+@jax.named_scope("sample")
 def sample_tokens(
     logits: jnp.ndarray,  # [B, V] float32
     key: jax.Array,
@@ -180,6 +181,7 @@ def sample_tokens(
 LOGPROBS_K = 20  # top alternatives computed on device (= the OpenAI API max)
 
 
+@jax.named_scope("sample")
 def sample_tokens_with_logprobs(
     logits: jnp.ndarray,  # [B, V] float32, possibly penalized/masked
     key: jax.Array,

@@ -27,6 +27,7 @@ exactly without reading anything back.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from collections import deque
@@ -177,6 +178,14 @@ class RunningSeq:
     queue_wait_s: Optional[float] = None
     first_token_wall: float = 0.0
     last_token_wall: float = 0.0
+    # the chain of a first token, on time.monotonic(): this admission
+    # (_start_sequence), and the end of the runner call that dispatched the
+    # prefill chunk ending at prompt_len. With req.enqueue_ts before them and
+    # first_token_wall after, they split the engine's ttft into queue_wait +
+    # prefill_hold + first_token_wait (_emit_token). 0.0 = not stamped: a
+    # sequence adopted with its prompt prefilled elsewhere has neither.
+    admitted_ts: float = 0.0
+    prefill_dispatched_ts: float = 0.0
     itl_gaps: list = field(default_factory=list)
 
     @property
@@ -351,6 +360,22 @@ def _stage_histograms() -> dict[str, Histogram]:
             "time from engine submission to first materialized token",
             _WAIT_BUCKETS,
         ),
+        # the two stages between admission and the first token; with
+        # queue_wait they add up to ttft, request by request (_emit_token)
+        "prefill_hold": Histogram(
+            "dynamo_engine_prefill_hold_seconds",
+            "time from scheduler admission to the dispatch of the prefill "
+            "chunk that ends the prompt (chunking, the prefill pipeline "
+            "gate, prefix fetch)",
+            _WAIT_BUCKETS,
+        ),
+        "first_token_wait": Histogram(
+            "dynamo_engine_first_token_wait_seconds",
+            "time from the dispatch of the last prefill chunk to the first "
+            "materialized token (device backlog, prefill compute, reconcile "
+            "order)",
+            _WAIT_BUCKETS,
+        ),
         "prefill": Histogram(
             "dynamo_engine_prefill_seconds",
             "per-request prefill dispatch time across all chunks",
@@ -413,6 +438,7 @@ class Scheduler:
             roofline=roofline_for_runner(runner, config) if runner is not None
             else None,
         )
+        self.anatomy.stage_sink = self._feed_stage
         store = getattr(runner, "lora_store", None) if runner is not None else None
         if store is not None:
             # slot loads (device scatters) record as lora_slot_load dispatches
@@ -532,6 +558,27 @@ class Scheduler:
                 self.waiting.remove(req)
                 return True
         return False
+
+    #: which StageStats fields and stage histogram an anatomy phase feeds:
+    #: (seconds, count, histogram), by (kind, phase) or by phase alone
+    _STAGE_FEEDS = {
+        ("decode_window", "dispatch"): ("decode_dispatch_s", "decode_windows", "decode_window"),
+        ("prefill_packed", "dispatch"): ("prefill_s", "prefill_calls", "prefill"),
+        ("prefill_chunk", "dispatch"): ("prefill_s", "prefill_calls", "prefill"),
+        "device_wait": ("reconcile_wait_s", "reconcile_waits", "reconcile"),
+    }
+
+    def _feed_stage(self, kind: str, phase: str, dt: float) -> None:
+        """``StepAnatomy.stage_sink``: the interval a ``phase`` block timed,
+        into the always-on aggregates and the Prometheus histogram."""
+        feed = self._STAGE_FEEDS.get((kind, phase)) or self._STAGE_FEEDS.get(phase)
+        if feed is None:
+            return
+        seconds, count, hist = feed
+        stage = self.stage
+        setattr(stage, seconds, getattr(stage, seconds) + dt)
+        setattr(stage, count, getattr(stage, count) + 1)
+        self.stage_hist[hist].observe(dt)
 
     # ---------------- main loop step ----------------
 
@@ -918,8 +965,8 @@ class Scheduler:
 
     def _start_sequence(self, req: EngineRequest, slot: int, lora_slot: int = 0) -> None:
         wait = None
+        now = time.monotonic()
         if req.enqueue_ts:
-            now = time.monotonic()
             wait = max(0.0, now - req.enqueue_ts)
             self.stage.queue_wait_s += wait
             self.stage.queue_wait_n += 1
@@ -958,6 +1005,7 @@ class Scheduler:
             spec_mode=self._spec_eligible(req),
             lora_slot=lora_slot,
             queue_wait_s=wait,
+            admitted_ts=now,
         )
         self._admit_counter += 1
         # decode windows read each slot's adapter id from the device-resident
@@ -996,6 +1044,7 @@ class Scheduler:
         )
         tok_dev, lp = result if isinstance(result, tuple) else (result, None)
         self.allocator.commit_prefilled(req.request_id, prompt_len)
+        seq.prefill_dispatched_ts = time.monotonic()
         self.slots[slot] = seq
         self.in_flight.append(
             _InFlight(kind="first", dev=tok_dev, seqs=[seq], cached_len=cached_len,
@@ -1303,6 +1352,7 @@ class Scheduler:
         tok_dev, lp = result if isinstance(result, tuple) else (result, None)
         self.allocator.commit_prefilled(req.request_id, seq.prompt_len)
         seq.prefill_pos = None
+        seq.prefill_dispatched_ts = time.monotonic()
         self.in_flight.append(_InFlight(
             kind="first", dev=tok_dev, seqs=[seq], cached_len=seq.cached_len,
             lp=lp, rec=self._last_prefill_rec,
@@ -1414,17 +1464,21 @@ class Scheduler:
                 max(len(s.page_table) for s, _, _ in chunks)
             ))
             N = min(lanes_max, 1 << (len(chunks) - 1).bit_length())
-            t0 = time.monotonic()
             rec = self.anatomy.begin(
                 "prefill_packed", ts=t_prep,
                 # cost split: each sequence pays for its own rows in the pack
                 bill=[self._bill(s.req, end - start) for s, start, end in chunks],
             )
-            self.anatomy.add_phase(rec, "host_prep", t0 - t_prep)
+            self.anatomy.add_phase(rec, "host_prep", time.monotonic() - t_prep)
             try:
-                result = self.runner.prefill_chunk_batch(
-                    lanes, N=N, want_logprobs=want_lp
-                )
+                with self.anatomy.phase(
+                    rec, "dispatch", request_id=chunks[0][0].req.request_id,
+                    trace_id=chunks[0][0].req.trace_id,
+                    rows=rows, lanes=N, packed=True, finals=len(finals),
+                ) as ph:
+                    result = self.runner.prefill_chunk_batch(
+                        lanes, N=N, want_logprobs=want_lp
+                    )
             except Exception:
                 log.exception(
                     "packed prefill failed for %s",
@@ -1433,28 +1487,14 @@ class Scheduler:
                 for seq, _, _ in chunks:
                     outputs.extend(self._finish(seq, "error"))
                 continue
-            dt = time.monotonic() - t0
-            self.stage.prefill_s += dt
-            self.stage.prefill_calls += 1
             self.stage.prefill_rows += rows
-            self.stage_hist["prefill"].observe(dt)
-            self.anatomy.add_phase(rec, "dispatch", dt)
             self.anatomy.note_steps(rec, tokens=rows, participants=len(chunks))
             self.anatomy.note_prefill_floor(rec, rows)
-            if tracing.enabled():
-                tracing.record_span(
-                    "engine.prefill", t0, duration=dt,
-                    request_id=chunks[0][0].req.request_id,
-                    trace_id=chunks[0][0].req.trace_id,
-                    attrs={
-                        "rows": rows, "lanes": N, "packed": True,
-                        "requests": [s.req.request_id for s, _, _ in chunks],
-                    },
-                )
             for j, (seq, start, end) in enumerate(chunks):
                 if end == seq.prompt_len:
                     self.allocator.commit_prefilled(seq.req.request_id, seq.prompt_len)
                     seq.prefill_pos = None
+                    seq.prefill_dispatched_ts = ph.t1
                 else:
                     seq.prefill_pos = end
             toks_dev, lp = result if want_lp else (result, None)
@@ -1560,53 +1600,47 @@ class Scheduler:
         if prep:
             self._prep_prefill(req, slot, prompt_len, cached_len=cached_len)
         self.anatomy.add_phase(rec, "host_prep", time.monotonic() - t0)
-        while start < prompt_len:
-            # depth-aware chunk sizing: shrink the chunk as the context
-            # deepens so per-chunk latency stays roughly flat at depth
-            end = min(start + self.config.chunk_len_for(start), prompt_len)
-            is_last = end == prompt_len
-            cb = self.config.bucket_for(end - start)
-            self.chunk_dispatches[cb] = self.chunk_dispatches.get(cb, 0) + 1
-            embeds, embeds_mask = _mm_chunk_overrides(req, start, end)
-            rope_pos = req.mrope_pos[start:end] if req.mrope_pos is not None else None
-            tok = self.runner.prefill_chunk(
-                np.asarray(req.token_ids[start:end], np.int32),
-                start_pos=start,
-                page_table=page_table,
-                sample=is_last,
-                temperature=s.temperature,
-                top_k=s.top_k,
-                top_p=s.top_p,
-                slot=slot if is_last else -1,
-                sync=sync,
-                embeds=embeds,
-                embeds_mask=embeds_mask,
-                rope_pos=rope_pos,
-                want_logprobs=want_logprobs and not sync,
-                sampling=s,
-                eos_ids=() if s.ignore_eos else req.eos_token_ids,
-                lora_slot=lora_slot,
-            )
-            if is_last:
-                first_token = tok
-            if on_chunk is not None:
-                on_chunk(start, end)
-            start = end
-        dt = time.monotonic() - t0
-        self.stage.prefill_s += dt
-        self.stage.prefill_calls += 1
-        self.stage.prefill_rows += rows
-        self.stage_hist["prefill"].observe(dt)
         # everything past host_prep is dispatch time (sync=True chains block
         # per chunk, so device wait folds into the same phase here)
-        self.anatomy.add_phase(rec, "dispatch", dt - rec.host_prep_s)
+        with self.anatomy.phase(
+            rec, "dispatch", request_id=req.request_id, trace_id=req.trace_id,
+            rows=rows, cached=cached_len, sync=sync,
+        ):
+            while start < prompt_len:
+                # depth-aware chunk sizing: shrink the chunk as the context
+                # deepens so per-chunk latency stays roughly flat at depth
+                end = min(start + self.config.chunk_len_for(start), prompt_len)
+                is_last = end == prompt_len
+                cb = self.config.bucket_for(end - start)
+                self.chunk_dispatches[cb] = self.chunk_dispatches.get(cb, 0) + 1
+                embeds, embeds_mask = _mm_chunk_overrides(req, start, end)
+                rope_pos = req.mrope_pos[start:end] if req.mrope_pos is not None else None
+                tok = self.runner.prefill_chunk(
+                    np.asarray(req.token_ids[start:end], np.int32),
+                    start_pos=start,
+                    page_table=page_table,
+                    sample=is_last,
+                    temperature=s.temperature,
+                    top_k=s.top_k,
+                    top_p=s.top_p,
+                    slot=slot if is_last else -1,
+                    sync=sync,
+                    embeds=embeds,
+                    embeds_mask=embeds_mask,
+                    rope_pos=rope_pos,
+                    want_logprobs=want_logprobs and not sync,
+                    sampling=s,
+                    eos_ids=() if s.ignore_eos else req.eos_token_ids,
+                    lora_slot=lora_slot,
+                )
+                if is_last:
+                    first_token = tok
+                if on_chunk is not None:
+                    on_chunk(start, end)
+                start = end
+        self.stage.prefill_rows += rows
         self.anatomy.note_steps(rec, tokens=rows, participants=1)
         self.anatomy.note_prefill_floor(rec, rows)
-        tracing.record_span(
-            "engine.prefill", t0, duration=dt,
-            request_id=req.request_id, trace_id=req.trace_id,
-            attrs={"rows": rows, "cached": cached_len, "sync": sync},
-        )
         return first_token
 
     def adopt_prefilled(
@@ -2195,38 +2229,26 @@ class Scheduler:
             # cost split: each participant pays for its scheduled steps
             bill=[self._bill(s.req, max(1, n)) for s, _, n in snapshot],
         )
-        t0 = time.monotonic()
-        self.anatomy.add_phase(rec, "host_prep", t0 - t_prep)
-        result = self.runner.dispatch_decode_window(
-            positions, page_tables, active, limits, temps, top_ks, top_ps, K,
-            want_logprobs=want_lp, rope_deltas=rope_deltas, min_ps=min_ps,
-            penalties=penalties if want_pen else None,
-            seeds=seeds if np.any(seeds) else None,
-            eos_allowed_from=eos_allowed_from if any_eos_mask else None,
-            eos_ids=eos_rows if any_eos_mask else None,
-        )
-        dt = time.monotonic() - t0
         steps_total = sum(steps for _, _, steps in snapshot)
-        self.stage.decode_dispatch_s += dt
-        self.stage.decode_windows += 1
+        self.anatomy.add_phase(rec, "host_prep", time.monotonic() - t_prep)
+        with self.anatomy.phase(
+            rec, "dispatch", request_id=snapshot[0][0].req.request_id,
+            trace_id=snapshot[0][0].req.trace_id,
+            participants=len(snapshot), k=K, steps_total=steps_total,
+        ):
+            result = self.runner.dispatch_decode_window(
+                positions, page_tables, active, limits, temps, top_ks, top_ps, K,
+                want_logprobs=want_lp, rope_deltas=rope_deltas, min_ps=min_ps,
+                penalties=penalties if want_pen else None,
+                seeds=seeds if np.any(seeds) else None,
+                eos_allowed_from=eos_allowed_from if any_eos_mask else None,
+                eos_ids=eos_rows if any_eos_mask else None,
+            )
         self.stage.decode_steps += K
-        self.stage_hist["decode_window"].observe(dt)
-        self.anatomy.add_phase(rec, "dispatch", dt)
         self.anatomy.note_steps(
             rec, steps=K, tokens=steps_total, participants=len(snapshot),
             floor_bytes=self.anatomy.decode_floor_bytes(live_pages, K),
         )
-        if tracing.enabled():
-            tracing.record_span(
-                "engine.decode.window", t0, duration=dt,
-                request_id=snapshot[0][0].req.request_id,
-                trace_id=snapshot[0][0].req.trace_id,
-                attrs={
-                    "participants": len(snapshot), "k": K,
-                    "steps_total": steps_total,
-                    "requests": [s.req.request_id for s, _, _ in snapshot],
-                },
-            )
         toks_dev, lp = result if want_lp else (result, None)
         self.in_flight.append(_InFlight(
             kind="window", dev=toks_dev, seqs=snapshot, lp=lp, rec=rec,
@@ -2244,62 +2266,57 @@ class Scheduler:
             if not (block or drain) and not ready:
                 break
             self.in_flight.popleft()
-            t0 = time.monotonic()
-            data = np.asarray(entry.dev)  # graftlint: sync-ok THE priced reconcile point: step_anatomy device_wait source
-            if not ready:
-                # host actually blocked on the device: the sync wait the
-                # dispatch-ahead pipeline exists to hide
-                dt = time.monotonic() - t0
-                self.stage.reconcile_wait_s += dt
-                self.stage.reconcile_waits += 1
-                self.stage_hist["reconcile"].observe(dt)
-                self.anatomy.add_phase(entry.rec, "device_wait", dt)
-                if tracing.enabled():
-                    tracing.record_span(
-                        "engine.decode.sync", t0, duration=dt,
-                        attrs={"kind": entry.kind, "drain": drain},
-                    )
-            t_rec = time.monotonic()
-            lp = None
-            if entry.lp is not None:
-                lp = tuple(np.asarray(a) for a in entry.lp)
+            # not ready: the host blocks on the device here, the sync wait
+            # the dispatch-ahead pipeline exists to hide
+            with contextlib.nullcontext() if ready else self.anatomy.phase(
+                entry.rec, "device_wait", kind=entry.kind, drain=drain,
+            ):
+                data = np.asarray(entry.dev)  # graftlint: sync-ok THE priced reconcile point: step_anatomy device_wait source
             block = False
-            if entry.kind == "first":
-                seq = entry.seqs[0]
-                if seq.finished:
-                    continue
+            # host-side materialization (token emission, stop scanning) of
+            # this entry attributes back to the dispatch that produced it
+            with self.anatomy.phase(entry.rec, "reconcile", kind=entry.kind):
+                outputs.extend(self._emit_entry(entry, data))
+        return outputs
+
+    def _emit_entry(self, entry: "_InFlight", data: np.ndarray) -> list[StepOutput]:
+        """The tokens of one materialized in-flight entry, emitted."""
+        outputs: list[StepOutput] = []
+        lp = None
+        if entry.lp is not None:
+            lp = tuple(np.asarray(a) for a in entry.lp)
+        if entry.kind == "first":
+            seq = entry.seqs[0]
+            if not seq.finished:
                 outputs.extend(
                     self._emit_token(
                         seq, int(data), cached=entry.cached_len,
                         lp=(lp[0][()], lp[1], lp[2]) if lp is not None else None,
                     )
                 )
-            elif entry.kind == "first_batch":
-                for seq, lane, cached in entry.seqs:
-                    if seq.finished:
-                        continue
+        elif entry.kind == "first_batch":
+            for seq, lane, cached in entry.seqs:
+                if seq.finished:
+                    continue
+                step_lp = None
+                if lp is not None and seq.req.logprobs is not None:
+                    step_lp = (lp[0][lane], lp[1][lane], lp[2][lane])
+                outputs.extend(
+                    self._emit_token(seq, int(data[lane]), cached=cached, lp=step_lp)
+                )
+        else:
+            for seq, slot_idx, steps in entry.seqs:
+                if seq.finished:
+                    continue  # EOS/cancel discovered earlier; zombie tokens
+                for j in range(min(steps, data.shape[0])):
                     step_lp = None
-                    if lp is not None and seq.req.logprobs is not None:
-                        step_lp = (lp[0][lane], lp[1][lane], lp[2][lane])
+                    if lp is not None:
+                        step_lp = (lp[0][j, slot_idx], lp[1][j, slot_idx], lp[2][j, slot_idx])
                     outputs.extend(
-                        self._emit_token(seq, int(data[lane]), cached=cached, lp=step_lp)
+                        self._emit_token(seq, int(data[j, slot_idx]), lp=step_lp)
                     )
-            else:
-                for seq, slot_idx, steps in entry.seqs:
                     if seq.finished:
-                        continue  # EOS/cancel discovered earlier; zombie tokens
-                    for j in range(min(steps, data.shape[0])):
-                        step_lp = None
-                        if lp is not None:
-                            step_lp = (lp[0][j, slot_idx], lp[1][j, slot_idx], lp[2][j, slot_idx])
-                        outputs.extend(
-                            self._emit_token(seq, int(data[j, slot_idx]), lp=step_lp)
-                        )
-                        if seq.finished:
-                            break
-            # host-side materialization (token emission, stop scanning) of
-            # this entry attributes back to the dispatch that produced it
-            self.anatomy.add_phase(entry.rec, "reconcile", time.monotonic() - t_rec)
+                        break
         return outputs
 
     # ---------------- helpers ----------------
@@ -2329,6 +2346,7 @@ class Scheduler:
                     request_id=req.request_id, trace_id=req.trace_id,
                     attrs={"cached": cached} if cached else None,
                 )
+                self._observe_first_token_chain(seq, now)
                 events.emit(
                     "request.first_token",
                     request_id=req.request_id, trace_id=req.trace_id,
@@ -2376,6 +2394,32 @@ class Scheduler:
             self._record_outcome(seq, finish)
             self._release(seq)
         return [out]
+
+    def _observe_first_token_chain(self, seq: RunningSeq, now: float) -> None:
+        """Where this first token's time went after admission, observed where
+        ``ttft`` is: ``prefill_hold`` (admitted -> the last prefill chunk
+        dispatched) and ``first_token_wait`` (that dispatch -> the token
+        materialized, at ``now``). For the request, queue_wait + prefill_hold
+        + first_token_wait == ttft: the four share their clock reads.
+
+        A preempted request is admitted again as a new sequence whose
+        ``enqueue_ts`` is still the client's submission, and each admission
+        observes all four from there: the identity holds admission by
+        admission, and the later admission's queue_wait holds the first run.
+        A sequence adopted with its prompt prefilled elsewhere
+        (``adopt_prefilled``) has no stamps and observes neither stage."""
+        if not (seq.admitted_ts and seq.prefill_dispatched_ts):
+            return
+        req = seq.req
+        for name, start, end in (
+            ("prefill_hold", seq.admitted_ts, seq.prefill_dispatched_ts),
+            ("first_token_wait", seq.prefill_dispatched_ts, now),
+        ):
+            self.stage_hist[name].observe(end - start)
+            tracing.record_span(
+                f"engine.{name}", start, end=end,
+                request_id=req.request_id, trace_id=req.trace_id,
+            )
 
     def _finish(self, seq: RunningSeq, reason: str) -> list[StepOutput]:
         self._record_outcome(seq, reason, error=(reason == "error"))

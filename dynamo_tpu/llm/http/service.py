@@ -546,6 +546,7 @@ class HttpService:
                     echo_text=echo_text,
                     tenant=pre.tenant,
                     priority=priority,
+                    t_arrival=t0,
                 )
                 if req.stream:
                     return await self._stream_response(request, chunks, model, endpoint, t0)
@@ -583,6 +584,8 @@ class HttpService:
         echo_text: Optional[str] = None,
         tenant: str = "",
         priority: str = "",
+        *,
+        t_arrival: float,
     ) -> AsyncIterator[dict]:
         gen = (
             ChatDeltaGenerator(model) if kind == "chat" else CompletionDeltaGenerator(model)
@@ -612,7 +615,10 @@ class HttpService:
             usage.completion_tokens = out.cumulative_tokens
             if t_first is None and out.token_ids:
                 t_first = t_prev = time.monotonic()
-                self.metrics.observe_ttft(model, t_first - t_start)
+                # the histogram's help says "from request arrival": parsing,
+                # preprocessing and admission are part of it (t_arrival is the
+                # handler's first line; t_start is after them)
+                self.metrics.observe_ttft(model, t_first - t_arrival)
                 self.slo.observe(
                     "ttft", t_first - t_start, tenant=tenant, priority=priority
                 )

@@ -450,13 +450,14 @@ class LlamaModel:
 
     def _unembed(self, params: dict, hidden: jnp.ndarray) -> jnp.ndarray:
         c = self.config
-        h = rms_norm(hidden, params["final_norm"], c.rms_norm_eps)
-        head = params["embed"] if c.tie_word_embeddings else params["lm_head"]
-        # bf16 MXU matmul with f32 accumulation — no materialized f32 cast of
-        # the [V, D] head (bf16 products are exact in the f32 accumulator)
-        return jax.lax.dot_general(
-            h, head, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
+        with jax.named_scope("lm_head"):
+            h = rms_norm(hidden, params["final_norm"], c.rms_norm_eps)
+            head = params["embed"] if c.tie_word_embeddings else params["lm_head"]
+            # bf16 MXU matmul with f32 accumulation — no materialized f32 cast
+            # of the [V, D] head (bf16 products are exact in the f32 accumulator)
+            return jax.lax.dot_general(
+                h, head, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            )
 
     def _layer(
         self,
@@ -489,74 +490,78 @@ class LlamaModel:
         all-gather GSPMD inserts on the pure-sp path for the same scatter."""
         c = self.config
         T = hidden.shape[0]
-        h = rms_norm(hidden, lp["input_norm"], c.rms_norm_eps)
-        # qlinear == `h @ w` for full-precision weights; int8 weight-only
-        # leaves dequantize inside the fused dot (dynamo_tpu/quant/int8.py)
-        q_flat = qlinear(h, lp["wq"])
-        k_flat = qlinear(h, lp["wk"])
-        v_flat = qlinear(h, lp["wv"])
-        if lora_mods is not None:
-            # the adapter delta rides ON TOP of qlinear unchanged (int8 base
-            # weights compose: dequant-in-matmul below, f32 delta here); k/v
-            # deltas land BEFORE rope + the pool scatter, so cached pages are
-            # adapter-specific — which the lora-salted block identity encodes
-            q_flat = q_flat + lora_delta(h, lora_mods["wq"], lora_ids, lora_scales)
-            k_flat = k_flat + lora_delta(h, lora_mods["wk"], lora_ids, lora_scales)
-            v_flat = v_flat + lora_delta(h, lora_mods["wv"], lora_ids, lora_scales)
-        if c.attention_bias:
-            q_flat = q_flat + lp["bq"]
-            k_flat = k_flat + lp["bk"]
-            v_flat = v_flat + lp["bv"]
-        # head counts from the weight shard, not the config: inside a tp
-        # shard_map each device sees num_heads / tp of them
-        q = q_flat.reshape(T, -1, c.head_dim)
-        k = k_flat.reshape(T, -1, c.head_dim)
-        v = v_flat.reshape(T, -1, c.head_dim)
-        if c.mrope_section is not None:
-            pos3 = (
-                rope_positions
-                if rope_positions is not None
-                else jnp.stack([positions] * 3, axis=-1)
-            )
-            q = apply_mrope(q, pos3, tuple(c.mrope_section), c.rope_theta)
-            k = apply_mrope(k, pos3, tuple(c.mrope_section), c.rope_theta)
-        else:
-            q = apply_rope(q, positions, c.rope_theta)
-            k = apply_rope(k, positions, c.rope_theta)
-        # scatter_kv folds the new rows itself when the pool is lane-folded
-        if sp_axis is not None:
-            k_all = jax.lax.all_gather(k, sp_axis, axis=0, tiled=True)
-            v_all = jax.lax.all_gather(v, sp_axis, axis=0, tiled=True)
-            phys_all = jax.lax.all_gather(flat_phys, sp_axis, axis=0, tiled=True)
-            off_all = jax.lax.all_gather(offsets, sp_axis, axis=0, tiled=True)
-            k_pool, v_pool = scatter_kv(k_pool, v_pool, k_all, v_all, phys_all, off_all)
-        else:
-            k_pool, v_pool = scatter_kv(k_pool, v_pool, k, v, flat_phys, offsets)
-        # attn_fn sees both the updated pools (paged paths) and the chunk's
-        # fresh rows (ring/SP path, which never reads the pool)
-        attn = attn_fn(q, k, v, k_pool, v_pool)
-        attn_flat = attn.reshape(T, -1)
-        attn_out = qlinear(attn_flat, lp["wo"])
-        if lora_mods is not None:
-            attn_out = attn_out + lora_delta(
-                attn_flat, lora_mods["wo"], lora_ids, lora_scales
-            )
-        if tp_axis is not None:
-            attn_out = jax.lax.psum(attn_out, tp_axis)
-        hidden = hidden + attn_out
-        h = rms_norm(hidden, lp["post_norm"], c.rms_norm_eps)
-        g = qlinear(h, lp["gate"])
-        u = qlinear(h, lp["up"])
-        if lora_mods is not None:
-            g = g + lora_delta(h, lora_mods["gate"], lora_ids, lora_scales)
-            u = u + lora_delta(h, lora_mods["up"], lora_ids, lora_scales)
-        prod = jax.nn.silu(g) * u
-        mlp = qlinear(prod, lp["down"])
-        if lora_mods is not None:
-            mlp = mlp + lora_delta(prod, lora_mods["down"], lora_ids, lora_scales)
-        if tp_axis is not None:
-            mlp = jax.lax.psum(mlp, tp_axis)
-        hidden = hidden + mlp
+        # named scopes: an operation's metadata in a profiler trace says which
+        # part of the step it belongs to (they change no computation)
+        with jax.named_scope("attn"):
+            h = rms_norm(hidden, lp["input_norm"], c.rms_norm_eps)
+            # qlinear == `h @ w` for full-precision weights; int8 weight-only
+            # leaves dequantize inside the fused dot (dynamo_tpu/quant/int8.py)
+            q_flat = qlinear(h, lp["wq"])
+            k_flat = qlinear(h, lp["wk"])
+            v_flat = qlinear(h, lp["wv"])
+            if lora_mods is not None:
+                # the adapter delta rides ON TOP of qlinear unchanged (int8 base
+                # weights compose: dequant-in-matmul below, f32 delta here); k/v
+                # deltas land BEFORE rope + the pool scatter, so cached pages are
+                # adapter-specific — which the lora-salted block identity encodes
+                q_flat = q_flat + lora_delta(h, lora_mods["wq"], lora_ids, lora_scales)
+                k_flat = k_flat + lora_delta(h, lora_mods["wk"], lora_ids, lora_scales)
+                v_flat = v_flat + lora_delta(h, lora_mods["wv"], lora_ids, lora_scales)
+            if c.attention_bias:
+                q_flat = q_flat + lp["bq"]
+                k_flat = k_flat + lp["bk"]
+                v_flat = v_flat + lp["bv"]
+            # head counts from the weight shard, not the config: inside a tp
+            # shard_map each device sees num_heads / tp of them
+            q = q_flat.reshape(T, -1, c.head_dim)
+            k = k_flat.reshape(T, -1, c.head_dim)
+            v = v_flat.reshape(T, -1, c.head_dim)
+            if c.mrope_section is not None:
+                pos3 = (
+                    rope_positions
+                    if rope_positions is not None
+                    else jnp.stack([positions] * 3, axis=-1)
+                )
+                q = apply_mrope(q, pos3, tuple(c.mrope_section), c.rope_theta)
+                k = apply_mrope(k, pos3, tuple(c.mrope_section), c.rope_theta)
+            else:
+                q = apply_rope(q, positions, c.rope_theta)
+                k = apply_rope(k, positions, c.rope_theta)
+            # scatter_kv folds the new rows itself when the pool is lane-folded
+            if sp_axis is not None:
+                k_all = jax.lax.all_gather(k, sp_axis, axis=0, tiled=True)
+                v_all = jax.lax.all_gather(v, sp_axis, axis=0, tiled=True)
+                phys_all = jax.lax.all_gather(flat_phys, sp_axis, axis=0, tiled=True)
+                off_all = jax.lax.all_gather(offsets, sp_axis, axis=0, tiled=True)
+                k_pool, v_pool = scatter_kv(k_pool, v_pool, k_all, v_all, phys_all, off_all)
+            else:
+                k_pool, v_pool = scatter_kv(k_pool, v_pool, k, v, flat_phys, offsets)
+            # attn_fn sees both the updated pools (paged paths) and the chunk's
+            # fresh rows (ring/SP path, which never reads the pool)
+            attn = attn_fn(q, k, v, k_pool, v_pool)
+            attn_flat = attn.reshape(T, -1)
+            attn_out = qlinear(attn_flat, lp["wo"])
+            if lora_mods is not None:
+                attn_out = attn_out + lora_delta(
+                    attn_flat, lora_mods["wo"], lora_ids, lora_scales
+                )
+            if tp_axis is not None:
+                attn_out = jax.lax.psum(attn_out, tp_axis)
+            hidden = hidden + attn_out
+        with jax.named_scope("mlp"):
+            h = rms_norm(hidden, lp["post_norm"], c.rms_norm_eps)
+            g = qlinear(h, lp["gate"])
+            u = qlinear(h, lp["up"])
+            if lora_mods is not None:
+                g = g + lora_delta(h, lora_mods["gate"], lora_ids, lora_scales)
+                u = u + lora_delta(h, lora_mods["up"], lora_ids, lora_scales)
+            prod = jax.nn.silu(g) * u
+            mlp = qlinear(prod, lp["down"])
+            if lora_mods is not None:
+                mlp = mlp + lora_delta(prod, lora_mods["down"], lora_ids, lora_scales)
+            if tp_axis is not None:
+                mlp = jax.lax.psum(mlp, tp_axis)
+            hidden = hidden + mlp
         return hidden, k_pool, v_pool
 
     def _prefill_common(

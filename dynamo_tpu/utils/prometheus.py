@@ -46,6 +46,7 @@ DECLARED_METRIC_FAMILIES: tuple = (
     "dynamo_engine_disk_restore_seconds",
     "dynamo_engine_disk_restores_total",
     "dynamo_engine_disk_spills_total",
+    "dynamo_engine_first_token_wait_seconds",
     "dynamo_engine_goodput_itl_p99_seconds",
     "dynamo_engine_goodput_ratio",
     "dynamo_engine_goodput_requests_total",
@@ -58,6 +59,7 @@ DECLARED_METRIC_FAMILIES: tuple = (
     "dynamo_engine_offload_bytes_resident",
     "dynamo_engine_offload_pressure_blocks_total",
     "dynamo_engine_preemptions_total",
+    "dynamo_engine_prefill_hold_seconds",
     "dynamo_engine_prefill_roofline_fraction",
     "dynamo_engine_prefill_seconds",
     "dynamo_engine_prefix_cache_blocks_total",
@@ -487,7 +489,8 @@ def _sample_surfaces() -> list[tuple[str, str]]:
     eng = AsyncJaxEngine(cfg)
     eng.allocator = PageAllocator(cfg.num_pages, cfg.page_size)
     eng.scheduler = Scheduler(cfg, None, eng.allocator)
-    for name in ("queue_wait", "ttft", "prefill", "decode_window", "reconcile"):
+    for name in ("queue_wait", "ttft", "prefill_hold", "first_token_wait",
+                 "prefill", "decode_window", "reconcile"):
         eng.scheduler.stage_hist[name].observe(0.01)
     eng.scheduler.stage.prefill_s = 0.5
     eng.scheduler.stage.spec_proposed = 8

@@ -18,8 +18,20 @@ LoRA slot load, prefix-fetch scatter, offload drain) records one
   reconcile    host time materializing results back into scheduler state
                (token emission, EOS/stop scanning, stream posting)
 
-Since the engine loop is single-threaded, the sum of all phases over all
-kinds is the engine thread's wall time; ``host_frac`` (everything except
+Each boundary is timed once, by ``StepAnatomy.phase``: one
+``time.monotonic()`` pair that adds to the record, feeds the scheduler's
+stage counters (``stage_sink``) and is a ``tracing.span`` named
+``engine.<kind>.<phase>`` with the record's ``seq`` — so in a
+``jax.profiler`` trace the phase stands on the engine thread's line of the
+host plane, on the device's clock, and ``/debug/steps`` names the same
+dispatch by the same ``seq``.
+
+The phases are the engine thread's time spent on dispatches. Admission, the
+polls between dispatches, ``_drain_inboxes``, ``_post_grouped`` and the wait
+for work lie outside every phase: the engine loop times those as spans of its
+own (``engine.step``, ``engine.post``, ``engine.wait_for_work`` in
+``engine/engine.py``), and a profiler trace says how much of the thread no
+phase covers. ``host_frac`` (everything except
 device_wait, over the total) is the fraction of a serving step the host
 spends NOT waiting on the chip — the overhead the planned multi-step fused
 decode (ROADMAP item 3) must drive down, and this plane is its before/after
@@ -68,6 +80,7 @@ from collections import deque
 from dataclasses import dataclass
 from typing import Optional
 
+from dynamo_tpu.utils import tracing
 from dynamo_tpu.utils.logging import get_logger
 
 log = get_logger("utils.step_anatomy")
@@ -85,6 +98,17 @@ KINDS = (
 )
 
 PHASES = ("host_prep", "dispatch", "device_wait", "reconcile")
+
+#: names under which the Chrome recorder (``DYNTPU_TRACE``) has always
+#: written three of the phases; ``tools/trace_view.py`` and the README's
+#: tracing section read them, so the recorder keeps them. The profiler's
+#: annotation is ``engine.<kind>.<phase>`` for every phase.
+_RECORDER_NAMES = {
+    ("decode_window", "dispatch"): "engine.decode.window",
+    ("prefill_packed", "dispatch"): "engine.prefill",
+    ("prefill_chunk", "dispatch"): "engine.prefill",
+    "device_wait": "engine.decode.sync",
+}
 
 #: the prefill-regime dispatch kinds (the packed serving path and the
 #: per-request chain) — the label set prefill_roofline_fraction and the
@@ -295,6 +319,25 @@ class StepRecord:
         }
 
 
+class _Phase:
+    """The block ``StepAnatomy.phase`` times; entering it gives the
+    ``tracing.span`` whose ``.t0`` / ``.t1`` / ``.dt`` are the one clock pair."""
+
+    __slots__ = ("anatomy", "rec", "kind", "name", "span")
+
+    def __init__(self, anatomy, rec, kind, name, span):
+        self.anatomy, self.rec, self.kind, self.name, self.span = anatomy, rec, kind, name, span
+
+    def __enter__(self) -> tracing.span:
+        return self.span.__enter__()
+
+    def __exit__(self, *exc) -> None:
+        self.span.__exit__(*exc)
+        self.anatomy.add_phase(self.rec, self.name, self.span.dt)
+        if self.anatomy.stage_sink is not None:
+            self.anatomy.stage_sink(self.kind, self.name, self.span.dt)
+
+
 class StepAnatomy:
     """Bounded ring of StepRecords + cumulative per-(phase, kind) counters.
 
@@ -325,6 +368,10 @@ class StepAnatomy:
         #: forwarded to it with the record's bill, so the attributed cost
         #: plane shares this plane's samples (the conservation identity)
         self.meter = None
+        #: optional ``fn(kind, phase, seconds)``, called once per ``phase``
+        #: block: the scheduler's StageStats and stage histograms take the
+        #: intervals they report from here, not from a clock pair of their own
+        self.stage_sink = None
 
     # ---------------- recording (engine thread) ----------------
 
@@ -354,6 +401,22 @@ class StepAnatomy:
                 setattr(rec, phase + "_s", getattr(rec, phase + "_s") + dt)
         if self.meter is not None and dt > 0:
             self.meter.on_phase(rec, phase, dt)
+
+    def phase(self, rec: Optional[StepRecord], name: str,
+              request_id: Optional[str] = None, trace_id: Optional[str] = None,
+              **attrs) -> _Phase:
+        """Time a block as ``name`` (one of ``PHASES``) of ``rec``: one clock
+        pair, fed to ``add_phase``, to ``stage_sink`` and to a
+        ``tracing.span("engine.<kind>.<phase>", seq=rec.seq, **attrs)``.
+        ``with anatomy.phase(...) as ph`` gives the span, for a site that
+        needs the interval's end (``ph.t1``). ``rec`` None is an untracked
+        dispatch, charged to ``decode_window`` as ``add_phase`` does."""
+        kind = rec.kind if rec is not None else "decode_window"
+        return _Phase(self, rec, kind, name, tracing.span(
+            f"engine.{kind}.{name}", request_id=request_id, trace_id=trace_id,
+            alias=_RECORDER_NAMES.get((kind, name)) or _RECORDER_NAMES.get(name),
+            seq=rec.seq if rec is not None else 0, **attrs,
+        ))
 
     def record(self, kind: str, dispatch_s: float, host_prep_s: float = 0.0,
                device_wait_s: float = 0.0, reconcile_s: float = 0.0,

@@ -9,19 +9,30 @@ that trace id — so one request's spans from the HTTP frontend, the
 processor/router, the prefill worker, and the decode worker stitch into a
 single timeline keyed by ``trace_id``.
 
-Off by default: ``span()`` costs one attribute read when disabled, so the hot
-paths (scheduler windows, reconcile) pay nothing. Enable with
+Two sinks, one timed block. The recorder is off by default: a ``span()``
+then costs one ``time.monotonic()`` pair and appends nothing. Enable it with
 ``DYNTPU_TRACE=<path>`` (spans append to the file as JSONL, one Chrome trace
 event per line) or programmatically via :func:`enable` (in-memory ring only
 when no path is given). ``tools/trace_view.py`` summarizes a capture;
 the HTTP service's ``/trace`` endpoint serves the in-memory ring as a
 Perfetto-loadable ``{"traceEvents": [...]}`` document.
 
+Whether or not the recorder is on, a ``span()`` in a process that has
+imported JAX is also a ``jax.profiler.TraceAnnotation`` of the same name with
+the span's scalar attributes: it costs under a microsecond while no profiler
+session runs, and in any ``jax.profiler`` trace it lands on its thread's line
+of the host plane, on the same clock as the device's operations. That is
+what lets a reduction of the trace say what the host did while the device
+waited (``benchmark/trace_steps.py``). A process that never imports JAX (a
+frontend or a processor of a multi-process deployment) gets the recorder's
+span and nothing else.
+
 Event shape (Chrome trace event format, complete-event ``ph: "X"``)::
 
     {"name": "engine.prefill", "ph": "X", "cat": "dyntpu",
      "ts": <epoch µs>, "dur": <µs>, "pid": <os pid>, "tid": <thread id>,
-     "args": {"trace_id": ..., "request_id": ..., "thread": ..., ...}}
+     "args": {"trace_id": ..., "request_id": ..., "thread": ...,
+              "parent": <name of the enclosing span, or None>, ...}}
 
 ``ts`` is epoch-anchored (one monotonic->epoch offset captured at import), so
 events from different processes line up on a shared timeline.
@@ -29,13 +40,14 @@ events from different processes line up on a shared timeline.
 
 from __future__ import annotations
 
-import contextlib
+import contextvars
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
-from typing import Iterator, Optional
+from typing import Optional
 
 
 def _ambient_context():
@@ -50,6 +62,13 @@ MAX_EVENTS = 65536
 
 # monotonic->epoch anchor: span timers use monotonic, exported ts is epoch µs
 _EPOCH_OFFSET = time.time() - time.monotonic()
+
+# the span open around the running code: per thread and per asyncio task, so
+# that tasks interleaving on one loop never become each other's parent
+_open_span: contextvars.ContextVar = contextvars.ContextVar("dyntpu_span", default=None)
+# jax.profiler.TraceAnnotation, once this process has imported JAX
+_annotation = None
+_SCALARS = (bool, int, float, str)
 
 _lock = threading.Lock()
 _events: deque = deque(maxlen=MAX_EVENTS)
@@ -126,7 +145,10 @@ def record_span(
     """Record one complete span. ``start``/``end`` are time.monotonic() values;
     pass ``duration`` instead of ``end`` when more convenient. request/trace
     ids default to the ambient context's — pass them explicitly on threads
-    that run outside the request context (the engine loop)."""
+    that run outside the request context (the engine loop). ``parent`` is the
+    ``span()`` block this call was made in, if any. An interval that is over
+    cannot be put on the profiler's clock: only ``span()`` blocks reach a
+    ``jax.profiler`` trace."""
     if not _enabled:
         return
     if duration is None:
@@ -141,7 +163,8 @@ def record_span(
     if trace_id is None:
         trace_id = request_id
     thread = threading.current_thread()
-    args = {"trace_id": trace_id, "request_id": request_id, "thread": thread.name}
+    args = {"trace_id": trace_id, "request_id": request_id, "thread": thread.name,
+            "parent": _open_span.get()}
     if attrs:
         args.update(attrs)
     ev = {
@@ -159,26 +182,69 @@ def record_span(
         _write_line(ev)
 
 
-@contextlib.contextmanager
-def span(
-    name: str,
-    request_id: Optional[str] = None,
-    trace_id: Optional[str] = None,
-    **attrs,
-) -> Iterator[None]:
-    """Time a block as one span. No-op (one bool read) when tracing is off.
-    Works across awaits: it measures wall time of the enclosed block."""
-    if not _enabled:
-        yield
-        return
-    t0 = time.monotonic()
-    try:
-        yield
-    finally:
-        record_span(
-            name, t0, end=time.monotonic(),
-            request_id=request_id, trace_id=trace_id, attrs=attrs or None,
-        )
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation`` once this process has imported JAX,
+    None until then: a span never makes a process import JAX."""
+    global _annotation
+    if _annotation is None and "jax" in sys.modules:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:  # JAX is still being imported on another thread
+            return None
+        _annotation = TraceAnnotation
+    return _annotation
+
+
+class span:
+    """Time a block as one span: ONE ``time.monotonic()`` pair, read back as
+    ``.t0`` / ``.t1`` / ``.dt`` by a caller that feeds the same interval to
+    counters of its own. The block is a ``jax.profiler.TraceAnnotation`` named
+    ``name`` with the scalar ``attrs`` (see the module docstring) and, when
+    the recorder is on, one Chrome event named ``alias or name`` whose
+    ``parent`` is the enclosing span. Works across awaits: it measures the
+    wall time of the enclosed block."""
+
+    __slots__ = ("name", "alias", "request_id", "trace_id", "attrs",
+                 "t0", "t1", "_annotation", "_token")
+
+    def __init__(self, name: str, request_id: Optional[str] = None,
+                 trace_id: Optional[str] = None, alias: Optional[str] = None,
+                 **attrs):
+        self.name = name
+        self.alias = alias or name  # the recorder's name for the event
+        self.request_id = request_id
+        self.trace_id = trace_id
+        self.attrs = attrs
+        self.t0 = self.t1 = 0.0
+
+    @property
+    def dt(self) -> float:
+        return self.t1 - self.t0
+
+    def __enter__(self) -> "span":
+        cls = _trace_annotation()
+        if cls is None:
+            self._annotation = None
+        else:
+            self._annotation = cls(self.name, **{
+                k: v for k, v in self.attrs.items() if isinstance(v, _SCALARS)
+            })
+            self._annotation.__enter__()
+        self._token = _open_span.set(self.alias)
+        self.t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.t1 = time.monotonic()
+        _open_span.reset(self._token)
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        if _enabled:
+            record_span(
+                self.alias, self.t0, end=self.t1,
+                request_id=self.request_id, trace_id=self.trace_id,
+                attrs=self.attrs or None,
+            )
 
 
 def events(

@@ -431,3 +431,165 @@ def test_live_engine_records_step_anatomy(served_engine):
     text = eng.render_stage_metrics()
     assert check_exposition(text) == []
     assert "dynamo_step_seconds_total" in text
+
+
+# ---------------- one timed block per boundary; the first token's chain ----------------
+
+
+def _serve(eng, loop, requests):
+    """Run EngineRequests to their end, all at once."""
+    async def one(req):
+        async for _ in eng.generate(req):
+            pass
+
+    async def run_all():
+        await asyncio.gather(*[one(r) for r in requests])
+
+    loop.run_until_complete(run_all())
+
+
+def _request(rid, n_prompt, max_tokens, seed=0):
+    from dynamo_tpu.engine.sampling import SamplingParams
+    from dynamo_tpu.engine.scheduler import EngineRequest
+
+    rng = np.random.default_rng(seed)
+    return EngineRequest(
+        request_id=rid, token_ids=rng.integers(1, 200, n_prompt).tolist(),
+        sampling=SamplingParams(temperature=0.0, max_tokens=max_tokens, ignore_eos=True),
+    )
+
+
+def _chain_closes(sched, spans_by_request):
+    """queue_wait + prefill_hold + first_token_wait == ttft: over the
+    histograms' sums, and request by request (admission by admission) on the
+    recorder's spans, whose durations are whole microseconds."""
+    h = sched.stage_hist
+    n = h["ttft"].count
+    assert n and h["queue_wait"].count == h["prefill_hold"].count == h["first_token_wait"].count == n
+    parts = h["queue_wait"].sum + h["prefill_hold"].sum + h["first_token_wait"].sum
+    assert parts == pytest.approx(h["ttft"].sum, abs=1e-6 * n)
+    for rid, spans in spans_by_request.items():
+        chain = {k: [e["dur"] for e in spans if e["name"] == f"engine.{k}"]
+                 for k in ("queue_wait", "prefill_hold", "first_token_wait", "ttft")}
+        assert len({len(v) for v in chain.values()}) == 1 and chain["ttft"], (rid, chain)
+        for qw, hold, wait, ttft in zip(*chain.values()):
+            assert abs(qw + hold + wait - ttft) <= 3, (rid, chain)
+            assert hold >= 0 and wait > 0
+
+
+@pytest.mark.parametrize("case", ["chunked_and_packed", "preempted"])
+def test_first_token_chain_adds_up_to_ttft(case, served_engine):
+    from dynamo_tpu.engine.engine import AsyncJaxEngine
+    from dynamo_tpu.utils import tracing
+
+    from tests.test_engine import tiny_engine_config
+
+    tracing.clear()
+    tracing.enable()
+    try:
+        if case == "chunked_and_packed":
+            eng, loop = served_engine
+            before = eng.scheduler.stage_hist["ttft"].count
+            # 150 tokens take three chunks of the widest bucket (64); the
+            # three short prompts arrive together and share packed calls
+            reqs = [_request("chain-long", 150, 6, seed=1)] + [
+                _request(f"chain-{i}", 20 + i, 6, seed=2 + i) for i in range(3)]
+            _serve(eng, loop, reqs)
+            assert eng.scheduler.stage_hist["ttft"].count == before + 4
+            assert eng.scheduler.anatomy.dispatch_counts.get("prefill_packed", 0) \
+                + eng.scheduler.anatomy.dispatch_counts.get("prefill_chunk", 0) >= 3
+        else:
+            # 8 usable pages, two sequences that grow to 6 pages each: the
+            # younger is preempted and admitted a second time
+            loop = asyncio.new_event_loop()
+            eng = AsyncJaxEngine(tiny_engine_config(
+                num_pages=9, max_seqs=2, max_model_len=32, watermark=0.0))
+            loop.run_until_complete(eng.start())
+            try:
+                reqs = [_request(f"chain-p{i}", 8, 16, seed=10 + i) for i in range(2)]
+                for r in reqs:
+                    r.sampling.ignore_eos = False
+                _serve(eng, loop, reqs)
+                assert eng.scheduler.preempt_count >= 1
+                # the preempted request observed a first token per admission
+                assert eng.scheduler.stage_hist["ttft"].count == 2 + eng.scheduler.preempt_count
+            finally:
+                loop.run_until_complete(eng.shutdown())
+                loop.close()
+        spans = {r.request_id: tracing.events(request_id=r.request_id) for r in reqs}
+        _chain_closes(eng.scheduler, spans)
+    finally:
+        tracing.disable()
+        tracing.clear()
+
+
+def test_recorder_events_of_one_request_name_their_parent(served_engine):
+    """The Chrome events a request had before this plane's phases were folded
+    into one block are still there under their names, and each now says which
+    span it was recorded in."""
+    from dynamo_tpu.utils import tracing
+
+    eng, loop = served_engine
+    tracing.clear()
+    tracing.enable()
+    try:
+        _serve(eng, loop, [_request("parent-1", 24, 10, seed=7)])
+        evs = tracing.events(request_id="parent-1")
+    finally:
+        tracing.disable()
+        tracing.clear()
+    by_name = {e["name"]: e for e in evs}
+    assert {"engine.queue_wait", "engine.prefill", "engine.decode.window", "engine.ttft",
+            "engine.prefill_hold", "engine.first_token_wait"} <= set(by_name)
+    assert all("parent" in e["args"] for e in evs)
+    for name in ("engine.queue_wait", "engine.prefill", "engine.decode.window"):
+        assert by_name[name]["args"]["parent"] == "engine.step", by_name[name]
+    # the first token is materialized in the reconcile phase of its prefill
+    assert by_name["engine.ttft"]["args"]["parent"].endswith(".reconcile")
+    assert by_name["engine.decode.window"]["args"]["k"] == eng.config.decode_steps
+    assert by_name["engine.prefill"]["args"]["rows"] == 24
+
+
+def test_phases_stand_on_the_profilers_host_plane(served_engine, tmp_path):
+    """Under a real `jax.profiler` session (the options the benchmark's entry
+    uses) the engine thread's line of the host plane holds the dispatch and
+    device_wait phases with the `seq` of their `/debug/steps` records, inside
+    `engine.step` spans; `benchmark/trace_steps.load` is what reads them."""
+    import importlib.util
+    from pathlib import Path
+
+    import jax
+
+    spec = importlib.util.spec_from_file_location(
+        "trace_steps", Path(__file__).resolve().parents[1] / "benchmark" / "trace_steps.py")
+    trace_steps = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(trace_steps)
+
+    eng, loop = served_engine
+    stage = eng.scheduler.stage
+    windows0, waits0 = stage.decode_windows, stage.reconcile_waits
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        _serve(eng, loop, [_request(f"prof-{i}", 24, 12, seed=20 + i) for i in range(2)])
+    finally:
+        jax.profiler.stop_trace()
+    (trace,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    host = trace_steps.load(str(trace))["host"]
+    steps = [(s, s + d) for name, s, d, _ in host if name == "engine.step"]
+    dispatch = [(s, s + d, st) for name, s, d, st in host if name == "engine.decode_window.dispatch"]
+    waits = [(s, s + d, st) for name, s, d, st in host if name.endswith(".device_wait")]
+    assert len(dispatch) == stage.decode_windows - windows0 > 0
+    assert len(waits) == stage.reconcile_waits - waits0
+    recorded = {r["seq"] for r in eng.debug_steps(limit=512)["records"] if r["kind"] == "decode_window"}
+    assert {st["seq"] for _, _, st in dispatch} <= recorded
+    assert all(st["k"] == eng.config.decode_steps for _, _, st in dispatch)
+    for s, e, st in dispatch + waits:
+        assert st["seq"] > 0 and any(a <= s and e <= b for a, b in steps), (s, e, st)
+    names = {name for name, *_ in host}
+    assert {"engine.post", "engine.decode_window.reconcile"} <= names
+    # the reduction's view of the engine thread: the phases lie inside the steps
+    thread = trace_steps.reduce({"modules": {}, "ops": {}, "host": host})["thread"]
+    assert 0 < thread["phases_s"] <= thread["step_s"] <= thread["seconds"]

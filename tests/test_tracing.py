@@ -69,6 +69,64 @@ def test_span_records_chrome_events(tmp_path):
     assert [e["name"] for e in doc["traceEvents"]] == ["stage.b"]
 
 
+def test_span_names_its_parent_and_records_under_its_alias():
+    tracing.enable()
+    with tracing.span("outer", a=1):
+        with tracing.span("engine.decode_window.dispatch", alias="engine.decode.window", k=8) as inner:
+            tracing.record_span("point", inner.t0, duration=0.0)
+    names = [(e["name"], e["args"]["parent"]) for e in tracing.events()]
+    # the alias is the recorder's name, for the event and for its children
+    assert names == [("point", "engine.decode.window"), ("engine.decode.window", "outer"),
+                     ("outer", None)]
+    assert inner.dt == inner.t1 - inner.t0 >= 0
+    # a span on another thread has no parent here
+    import threading
+
+    def other():
+        with tracing.span("elsewhere"):
+            pass
+
+    with tracing.span("outer2"):
+        t = threading.Thread(target=other)
+        t.start()
+        t.join(5)
+    assert {e["name"]: e["args"]["parent"] for e in tracing.events()}["elsewhere"] is None
+
+
+def test_phase_block_is_one_clock_pair_and_silent_when_off():
+    """No profiler session, DYNTPU_TRACE unset: a `phase()` block appends
+    nothing to the ring, and what it adds to the record and hands to the
+    stage sink is the one interval that `add_phase` would have been given."""
+    import time
+
+    from dynamo_tpu.utils.step_anatomy import StepAnatomy
+
+    assert not tracing.enabled()
+    fed = []
+    a = StepAnatomy()
+    a.stage_sink = lambda kind, phase, dt: fed.append((kind, phase, dt))
+    rec = a.begin("decode_window")
+    with a.phase(rec, "dispatch", k=4, requests=["not", "a", "scalar"]) as ph:
+        time.sleep(0.002)
+    with a.phase(None, "device_wait"):  # an untracked entry: charged as add_phase charges it
+        pass
+    assert tracing.events() == []
+    assert ph.dt >= 0.002 and rec.dispatch_s == ph.dt
+    assert fed[0] == ("decode_window", "dispatch", ph.dt) and fed[1][:2] == ("decode_window", "device_wait")
+    b = StepAnatomy()
+    rec_b = b.begin("decode_window")
+    b.add_phase(rec_b, "dispatch", ph.dt)
+    b.add_phase(None, "device_wait", fed[1][2])
+    assert a.phase_seconds == b.phase_seconds and rec_b.dispatch_s == rec.dispatch_s
+    # on: the recorder gets the phase under the name it always had
+    tracing.enable()
+    with a.phase(rec, "dispatch", request_id="r1", k=4):
+        pass
+    (ev,) = tracing.events()
+    assert ev["name"] == "engine.decode.window" and ev["args"]["seq"] == rec.seq
+    assert ev["args"]["request_id"] == "r1" and ev["args"]["k"] == 4
+
+
 def test_span_ids_default_to_ambient_context():
     tracing.enable()
     ctx = new_context(request_id="req-9", metadata={"trace_id": "trace-9"})
@@ -312,6 +370,13 @@ def test_http_service_ttft_metrics_and_trace_endpoint():
     assert check_exposition(metrics_text) == [], check_exposition(metrics_text)
     # TTFT histogram is non-empty after one served request
     assert 'llm_http_service_time_to_first_token_seconds_count{model="echo"} 1' in metrics_text
+    # ... and runs from the request's arrival, as its help says: it holds the
+    # preprocessing that ended before the stream began
+    pre = next(e for e in trace_doc["traceEvents"] if e["name"] == "http.preprocess")
+    ttft_sum = float(next(
+        ln.split()[-1] for ln in metrics_text.splitlines()
+        if ln.startswith('llm_http_service_time_to_first_token_seconds_sum{model="echo"}')))
+    assert ttft_sum >= pre["dur"] / 1e6
     # /trace serves a Perfetto-loadable document with the request's spans
     names = {e["name"] for e in trace_doc["traceEvents"]}
     assert "http.request" in names and "http.preprocess" in names
