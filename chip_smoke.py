@@ -793,16 +793,33 @@ def child_parity(sizes: Sizes, args) -> int:
 
     def ragged_batch(ps):
         """(B, max_pages, tables, positions): one token, a page boundary,
-        past the lookahead window (4 pages), the whole table."""
+        a few pages, the whole table (24 pages: three tiles of the decode
+        kernel at page size 16)."""
         B, maxp = (8, 24) if full else (4, 6)
         tables = jnp.asarray(1 + rng.permutation(B * maxp).reshape(B, maxp), jnp.int32)
         lengths = [1, ps, ps + 1, 4 * ps + 3, 5 * ps, maxp * ps - 1, 2 * ps - 1, maxp * ps]
         return B, maxp, tables, jnp.asarray([n - 1 for n in lengths[:B]], jnp.int32)
 
-    def decode(hq, hkv, d, ps, int8, kernel=None):
+    def cell_batch(ps):
+        """The benchmark cell's decode batch: 64 slots (6 in the rehearsal),
+        a third of them empty (one token on the trash page's row), the rest
+        ragged across the tiled kernel's boundaries: a tile is 128 tokens,
+        the cross-program window two tiles, then the double-buffered tail."""
+        B = 64 if full else 6
+        edges = [127, 128, 129, 255, 256, 257, 383, 384 + ps + 1, 641, 897, 130, 2 * ps - 1]
+        lengths = [1 if b % 3 == 0 else edges[b % len(edges)] for b in range(B)]
+        needed = [-(-n // ps) for n in lengths]
+        maxp = max(needed) + 3  # a table wider than any context, padded with page 0
+        order = iter(1 + rng.permutation(sum(needed)))
+        tables = np.zeros((B, maxp), np.int32)
+        for b, n in enumerate(needed):
+            tables[b, :n] = [next(order) for _ in range(n)]
+        return B, maxp, jnp.asarray(tables), jnp.asarray([n - 1 for n in lengths], jnp.int32)
+
+    def decode(hq, hkv, d, ps, int8, kernel=None, batch=ragged_batch):
         folded = d < 128 or kernel == "folded"
-        B, maxp, tables, pos = ragged_batch(ps)
-        k, v = pools(B * maxp + 1, ps, hkv, d, int8, folded)
+        B, maxp, tables, pos = batch(ps)
+        k, v = pools(int(tables.max()) + 1, ps, hkv, d, int8, folded)
         q = normal(B, hq, d)
         if kernel == "perseq":
             got = paged_decode_attention_pallas(q, k, v, tables, pos, interpret=interpret)
@@ -868,9 +885,11 @@ def child_parity(sizes: Sizes, args) -> int:
 
     if full:
         tiny, qwen, mixtral, bench, shard = (32, 4, 64), (28, 4, 128), (32, 8, 128), (16, 8, 128), (7, 1, 128)
+        qwen3b = (16, 2, 128)  # the benchmark's configuration
         T, prefix = 512, 1000
     else:  # the CPU rehearsal: same code paths, interpret-mode sizes
         tiny, qwen, mixtral, bench, shard = (8, 2, 64), (4, 2, 128), (4, 2, 128), (4, 2, 128), (2, 1, 128)
+        qwen3b = (4, 2, 128)
         T, prefix = 128, 200
     cases = [
         ("decode folded tinyllama ps16 bf16", lambda: decode(*tiny, 16, False)),
@@ -878,6 +897,8 @@ def child_parity(sizes: Sizes, args) -> int:
         ("decode lookahead qwen2.5-7b ps16 bf16", lambda: decode(*qwen, 16, False)),
         ("decode lookahead mixtral ps16 int8", lambda: decode(*mixtral, 16, True)),
         ("decode lookahead mixtral ps128 bf16", lambda: decode(*mixtral, 128, False)),
+        ("decode lookahead qwen2.5-3b cell batch ps16 bf16", lambda: decode(*qwen3b, 16, False, batch=cell_batch)),
+        ("decode lookahead qwen2.5-7b cell batch ps16 int8", lambda: decode(*qwen, 16, True, batch=cell_batch)),
         ("decode perseq qwen2.5-7b ps16 int8", lambda: decode(*qwen, 16, True, kernel="perseq")),
         ("decode folded qwen2.5-7b tp4-shard ps16 bf16", lambda: decode(*shard, 16, False, kernel="folded")),
         ("prefill folded tinyllama ps16 bf16", lambda: prefill(*tiny, 16, T, prefix, False)),

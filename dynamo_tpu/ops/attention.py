@@ -313,6 +313,8 @@ def dispatch_paged_decode_attention(q, k_pages, v_pages, page_tables, positions,
         return paged_decode_attention(q, k_pages, v_pages, page_tables, positions)
 
     from dynamo_tpu.ops.pallas.paged_attention import (
+        decode_tile_pages,
+        lookahead_window,
         paged_decode_attention_pallas,
         paged_decode_attention_pallas_chunked,
         paged_decode_attention_pallas_folded,
@@ -320,12 +322,17 @@ def dispatch_paged_decode_attention(q, k_pages, v_pages, page_tables, positions,
         paged_decode_attention_pallas_lookahead,
     )
 
-    # lookahead (default): perseq's per-sequence program + double buffer,
-    # plus cross-program DMA prefetch; falls back to perseq internally when
-    # the prefetch window would blow the VMEM budget. perseq: the classic
-    # in-program-only double buffer. chunked/grouped: selectable, bf16 only.
+    # lookahead (default): one sequence per grid program, a TILE of pages
+    # (128 context tokens) per loop iteration, cross-program prefetch of the
+    # next sequence's first tiles; tile width and window follow from the
+    # shapes (decode_tile_pages, lookahead_window), and it falls back to
+    # perseq internally when not even one window tile fits the VMEM budget.
+    # perseq: a page per iteration, in-program double buffer only.
+    # chunked/grouped: selectable, bf16 only; on this chip chunked ran 2.3x
+    # faster than the page-at-a-time lookahead and 1.3x slower than the tiled
+    # one (design record in ops/pallas/paged_attention.py).
     # folded: head_dim < 128 shapes (Mosaic can't DMA-slice sub-128-lane
-    # pools; heads live folded into the lane dim).
+    # pools; heads live folded into the lane dim); still a page at a time.
     quantized = isinstance(k_pages, QuantizedPages)
     kernel_choice = os.environ.get("DYNTPU_DECODE_KERNEL", "lookahead")
     if quantized and kernel_choice in ("chunked", "grouped"):
@@ -341,8 +348,16 @@ def dispatch_paged_decode_attention(q, k_pages, v_pages, page_tables, positions,
         "grouped": paged_decode_attention_pallas_grouped,
     }.get(kernel_choice, paged_decode_attention_pallas)
     interpret = not _on_tpu()
-    path = f"pallas:{kernel.__name__}" + (" interpret" if interpret else "")
     tp = 1 if mesh is None else mesh.shape.get("tp", 1)
+    path = f"pallas:{kernel.__name__}"
+    if kernel_choice == "lookahead" and num_kv_heads % tp == 0:
+        # the geometry one head shard's kernel derives, so a server log says
+        # which tile width ran
+        geometry = (k_pages.shape[1], num_kv_heads // tp, D, k_pages.dtype.itemsize)
+        window = lookahead_window(*geometry)
+        path += (f" tile={decode_tile_pages(*geometry)}x{geometry[0]} window={window}"
+                 if window else " window=0:perseq")
+    path += " interpret" if interpret else ""
     if tp == 1:
         _log_path("decode", path, shape)
         return kernel(q, k_pages, v_pages, page_tables, positions, interpret=interpret)

@@ -4,41 +4,76 @@ One query token per sequence attends over its paged KV context. The page
 table rides in as scalar-prefetch (available before the kernel body, so page
 DMAs can be issued from dynamic indices), K/V page pools stay in HBM, and
 pages stream through a double-buffered VMEM scratch overlapping DMA with
-compute (pallas_guide.md: PrefetchScalarGridSpec + double buffering).
+compute (pallas_guide.md: PrefetchScalarGridSpec + double buffering): a tile
+of pages at a time in the default kernel, a page at a time in the others.
 
 Contract matches the pure-JAX reference (dynamo_tpu/ops/attention.py
 paged_decode_attention): q [B, Hq, D], pages [P, ps, Hkv, D],
 page_tables [B, max_pages], positions [B] (query position; context length =
 position + 1). GQA folded as [Hkv, G, D] per-kv-head batched matmuls.
 
-Design record. The variants below were A/B'd on an earlier v5e machine that
-is gone; none of its numbers is quoted here, and nothing has been timed on
-the current chip yet (PERF.md; ROADMAP S0/D3). What the record taught, and
-what the kernels still encode:
+Design record (PR 26; every time below is my chip run on a TPU v5e, 36
+chained calls in one jit, best of 5, at the benchmark cells' shapes: B 64,
+Hq 16, Hkv 2, D 128, page 16, pool 13312 pages, bf16; "45 rows" is 45 live
+contexts of 200-900 tokens and 19 one-token slots, 1688 pages, as
+qwen2.5-3b.chat-over has them; "15 rows" 15 live and 49 empty, 582 pages, as
+qwen2.5-3b.chat; the HBM floor of the two is 33 and 11 us).
 
-  - perseq (one sequence per grid program, one-page-ahead double buffer) is
-    the design point. The [ps, Hkv, D] leading-index page DMA it issues is
-    the layout Mosaic moves fastest; fused-pool and row-flat prototypes that
-    issued half the DMAs were several times slower and were deleted.
-  - Mosaic pipelines ACROSS grid programs, so B one-sequence programs
-    overlap each other's DMAs and compute for free; any within-program
-    grouping (grouped, chunked, a concat-context prototype) trades that away
-    for a serialized group body and lost every comparison.
-  - The f32 casts are load-bearing: Mosaic relayouts
-    ([ps,Hkv,D]->[Hkv,ps,D]) are far cheaper in 32-bit than bf16, and the
-    no-transpose dot_general variants (batch dim in K's middle position) are
-    Mosaic-illegal outright (tpu.matmul requires leading batch dims).
-  - The gap between perseq and a null kernel (same grid, same DMA stream, no
-    math) is the per-program DMA-latency exposure at every grid-program
-    boundary, and the page table being scalar-prefetched means program b can
-    issue program b+1's DMAs — see _kernel_lookahead below, the default for
-    head_dim 128.
+  us per call (ns per page)          45 rows        15 rows
+  null, a page per iteration         373 (221)      150 (259)   DMA stream only
+  perseq                             643 (381)      253 (435)
+  lookahead, a page per iteration    630 (373)      243 (417)   the default until PR 26
+  chunked (16 pages, no prefetch)    267 (158)      171 (293)
+  null, a tile per iteration         123  (73)       74 (127)
+  lookahead, a tile per iteration    203 (120)      120 (207)   the default now
+
+  - A page at a time is bound by the DMA stream, not the arithmetic: the
+    null kernel (same grid, same two 8 KiB DMAs a page, one page in flight
+    behind the one in use, no math) takes 59% of the real kernel's time.
+    With a tile's 2 x TP page DMAs started together and waited together the
+    same stream takes a third of that, and from there the arithmetic counts.
+  - TP: a tile is 128 context tokens (one full lane row of scores; 8 pages of
+    16, one page of 128 and more). 64 and 256 timed 5-10% worse at 45 rows.
+    decode_tile_pages halves it while four tiles overrun the VMEM budget.
+  - W: program b starts program b+1's first W tiles (Mosaic runs the grid
+    serially and scratch persists; the page table is scalar-prefetched).
+    W = 1, 2, 4, 8, 16 timed 245-264 us, the small ones best, so W is 2
+    (lookahead_window), less where the VMEM budget holds fewer, and the 4
+    pages it was where a tile is one page. The page-at-a-time window barely
+    paid at page 16 (630 against perseq's 643): it covered 64 tokens of a
+    300-700 token context.
+  - Operands stay f32. bf16 operands (q, K, V, probs to the MXU in bf16) ran
+    17% SLOWER at [.., 2, 128] pages: the relayout [tokens, Hkv, D] ->
+    [Hkv, tokens, D] is cheaper in 32-bit, and casting f32 rows back to bf16
+    for the MXU doubled the kernel (428 against 212).
+  - With two bf16 kv heads the relayout is not needed at all (_heads_major):
+    203 against 248 us. With four or eight heads picking head pairs out of
+    the words timed slower than the 32-bit transpose, which they keep.
+  - Rows past the length in a sequence's last tile are stale VMEM. They are
+    zeroed in place, on that tile only: as a select on every tile's V it
+    cost 5%, and as a lax.cond around the select 20% more than that.
+  - Not gained: a fast path for full tiles (one branch, one wait a tile) and
+    q/out resident in VMEM for the whole grid gave 4% together and were left
+    out; an empty slot still costs 1.3 us (0.7 of it in the null kernel),
+    which is half of the 15-row time.
+  - Other geometries sharing this kernel, old -> new at 45 rows (final
+    tree): Hkv 4 and 8 at page 16 623 -> 256 and 621 -> 259 us; int8 at page
+    16 723 -> 396 and 711 -> 405; Hkv 8 at page 64 262 -> 239; at page 128,
+    where a tile is a page and the kernel is the old one but for the word
+    split, Hkv 4 and 8 195 -> 190 and 223 -> 223, int8 200 -> 206. (With the
+    stale-row zeroing and a window of 2 there, Hkv 8 read 223 -> 239: both
+    were taken out again for a tile of one page, which is fetched whole.)
+  - The no-transpose dot_general variants (batch dim in K's middle position)
+    are Mosaic-illegal outright (tpu.matmul requires leading batch dims).
+  - perseq stays as the fallback for a geometry whose window does not fit;
+    folded (head_dim < 128) is still a page at a time and has no cell.
 
 Int8 KV (quant/kv.py QuantizedPages): perseq, lookahead, and folded accept
 int8 pools plus their per-row f32 scales, which arrive as lane-aligned rows
 gathered by XLA in page-table order (gather_scale_rows — Mosaic refuses to
-DMA-slice the raw [P, ps] plane when ps < 128). Scale rows ride their own
-tiny DMAs beside the page DMAs (the HBM context stream halves — that is the
+DMA-slice the raw [P, ps] plane when ps < 128), one row per page, or per tile
+of pages in lookahead. Scale rows ride their own tiny DMAs beside the page
+DMAs (the HBM context stream halves — that is the
 win) and dequantization is applied to the score/prob tiles in VMEM:
 ``scores *= k_s`` / ``probs *= v_s`` is the exact per-column algebra, and
 both are lane-axis broadcasts (Mosaic-legal; no sub-128 minor-dim reshapes).
@@ -68,8 +103,8 @@ def gather_scale_rows(scales, tables, pages_per_row: int = 1):
     by physical page. Instead the rows a call will need are gathered here
     (a few bytes per context token — noise next to the int8 page stream),
     ``pages_per_row`` consecutive logical pages are laid side by side on the
-    lane axis (1 for decode's page-at-a-time loop, the tile width for
-    prefill), and the row is zero-padded to a multiple of 128 lanes. Row r of
+    lane axis (1 for the page-at-a-time decode kernels, the tile width for
+    prefill and the tiled decode kernel), and the row is zero-padded to a multiple of 128 lanes. Row r of
     the result covers logical pages [r * pages_per_row, (r+1) * pages_per_row)
     of the flattened ``tables``; the kernel DMAs ``rows.at[r]`` -> [1, W] and
     reads its first pages_per_row * ps lanes."""
@@ -82,15 +117,18 @@ def gather_scale_rows(scales, tables, pages_per_row: int = 1):
     return rows[:, None, :]
 
 
-def _decode_unpack_pools(k_pages, v_pages, page_tables):
+def _decode_unpack_pools(k_pages, v_pages, page_tables, pages_per_row: int = 1):
     """(k, v, k_scale rows | None, v_scale rows | None, quantized): int8
-    pools carry their scales as ``gather_scale_rows`` over the page tables,
-    one [1, W] row per (sequence, logical page)."""
+    pools carry their scales as ``gather_scale_rows`` over the page tables
+    (padded to whole rows), one [1, W] row per (sequence, ``pages_per_row``
+    consecutive logical pages)."""
     if isinstance(k_pages, QuantizedPages):
+        pad = -page_tables.shape[1] % pages_per_row
+        tables = jnp.pad(page_tables, ((0, 0), (0, pad)))
         return (
             k_pages.q, v_pages.q,
-            gather_scale_rows(k_pages.s, page_tables),
-            gather_scale_rows(v_pages.s, page_tables),
+            gather_scale_rows(k_pages.s, tables, pages_per_row),
+            gather_scale_rows(v_pages.s, tables, pages_per_row),
             True,
         )
     return k_pages, v_pages, None, None, False
@@ -349,49 +387,98 @@ def paged_decode_attention_pallas_grouped(
     return kernel(page_tables.astype(jnp.int32), lengths, q, k_pages, v_pages)
 
 
+def _heads_major(tile_ref):
+    """One context tile, ref ``[TP, ps, Hkv, D]`` -> value ``[Hkv, TP*ps, D]``
+    f32, the batched-matmul operand layout.
+
+    A bf16 pool of two kv heads never goes through a relayout: Mosaic keeps
+    such a page as one 32-bit word per token and lane, head 0 in its low half
+    and head 1 in its high half, and a bf16 is the top half of its f32, so a
+    shift or a mask of the word IS the head's f32 row. Every other pool is
+    cast and transposed in 32-bit, where the relayout is cheapest (with four
+    or eight kv heads the words would have to be picked apart by head pair,
+    which timed slower than the transpose: design record)."""
+    TP, ps, Hkv, D = tile_ref.shape
+    if tile_ref.dtype == jnp.bfloat16 and Hkv == 2:
+        words = tile_ref.bitcast(jnp.uint32)[...].reshape(TP * ps, D)
+        return jnp.stack([
+            pltpu.bitcast(words << 16, jnp.float32),
+            pltpu.bitcast(words & jnp.uint32(0xFFFF0000), jnp.float32),
+        ])
+    tile = tile_ref[...].astype(jnp.float32)
+    return jnp.transpose(tile.reshape(TP * ps, Hkv, D), (1, 0, 2))
+
+
+def _zero_tokens_from(tile_ref, first):
+    """Zero tokens ``first`` and beyond of a tile ``[TP, ps, Hkv, D]`` in
+    place, as whole 32-bit words where the heads fill them."""
+    ps, Hkv = tile_ref.shape[1:3]
+    per_word = 4 // tile_ref.dtype.itemsize
+    view = tile_ref.bitcast(jnp.uint32) if Hkv % per_word == 0 else tile_ref
+    token = (jax.lax.broadcasted_iota(jnp.int32, view.shape, 0) * ps
+             + jax.lax.broadcasted_iota(jnp.int32, view.shape, 1))
+    kept = jnp.where(token < first, view[...], jnp.zeros((), view.dtype))
+    tile_ref[...] = kept if view is tile_ref else pltpu.bitcast(kept, tile_ref.dtype)
+
+
 def _kernel_lookahead(
     *refs,
     page_size: int,
-    max_pages: int,
+    tile_pages: int,
+    tiles_per_seq: int,
     lookahead: int,
     quantized: bool = False,
 ):
-    """perseq with CROSS-PROGRAM DMA pipelining.
+    """Decode attention over TILES of pages, with CROSS-PROGRAM DMA
+    pipelining.
+
+    A loop iteration handles one tile: ``tile_pages`` consecutive logical
+    pages (128 context tokens at small page sizes). All of the tile's page
+    DMAs are started together and waited together, and the online softmax
+    takes one score product, one mask, one max / exp / sum over a full lane
+    row and one ``probs x V`` product per tile. Only pages below the
+    sequence's page count are fetched; what the rest of a tile's scratch
+    holds (stale VMEM) is masked out of the scores and zeroed in V, so
+    zero-weight garbage cannot reach the accumulator.
 
     Grid programs execute serially on the core, and scratch PERSISTS across
-    them; the page table is scalar-prefetched, so program b can issue program
-    b+1's first ``lookahead`` page DMAs into the opposite parity's slot pair
-    while it computes on its own pages (prefetched by b-1). The per-program
-    DMA-latency exposure at every program boundary — the entire gap between
-    perseq and the measured DMA floor — collapses to one program's worth for
-    the whole grid. Pages >= lookahead (long contexts) stream through the
-    classic in-program double buffer.
+    them; the page table is scalar-prefetched, so program b issues program
+    b+1's first ``lookahead`` tiles into the opposite parity's window while it
+    computes on its own (prefetched by b-1). Tiles >= lookahead (long
+    contexts) stream through the in-program double buffer: tile t+1 in flight
+    while tile t is merged.
 
     refs: page_tables + lengths (scalar prefetch) | q, k/v pools [, k/v
-    scale rows [B*max_pages, 1, Ws], see gather_scale_rows] | out | k_pre,
-    v_pre [2, W, ps, Hkv, D] [, scale windows [2, W, 1, Ws]], k_tail, v_tail
-    [2, ps, Hkv, D] [, scale tails [2, 1, Ws]], sems_pre [2, W, 2|4],
-    sems_tail [2, 2|4]."""
+    scale rows [B*tiles_per_seq, 1, Ws], one per tile, see
+    gather_scale_rows] | out | k_pre, v_pre [2, W, TP, ps, Hkv, D] [, scale
+    windows [2, W, 1, Ws]], k_tail, v_tail [2, TP, ps, Hkv, D] [, scale tails
+    [2, 1, Ws]], sems_pre [2, W, 2|4], sems_tail [2, 2|4]. The copies of one
+    tile and pool share a semaphore: each wait takes one page's bytes off it."""
     if quantized:
         (page_tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm,
          out_ref, k_pre, v_pre, ks_pre, vs_pre, k_tail, v_tail, ks_tail,
          vs_tail, sems_pre, sems_tail) = refs
-        pre_pools = [(k_hbm, k_pre), (v_hbm, v_pre),
-                     (ks_hbm, ks_pre), (vs_hbm, vs_pre)]
-        tail_pools = [(k_hbm, k_tail), (v_hbm, v_tail),
-                      (ks_hbm, ks_tail), (vs_hbm, vs_tail)]
+        pre_scales = [(ks_hbm, ks_pre), (vs_hbm, vs_pre)]
+        tail_scales = [(ks_hbm, ks_tail), (vs_hbm, vs_tail)]
     else:
         (page_tables_ref, lengths_ref, q_ref, k_hbm, v_hbm,
          out_ref, k_pre, v_pre, k_tail, v_tail, sems_pre, sems_tail) = refs
-        pre_pools = [(k_hbm, k_pre), (v_hbm, v_pre)]
-        tail_pools = [(k_hbm, k_tail), (v_hbm, v_tail)]
+        pre_scales = tail_scales = []
+    pre_pools = [(k_hbm, k_pre), (v_hbm, v_pre)]
+    tail_pools = [(k_hbm, k_tail), (v_hbm, v_tail)]
 
     b = pl.program_id(0)
     nb = pl.num_programs(0)
     par = jax.lax.rem(b, 2)
-    W = lookahead
+    W, TP = lookahead, tile_pages
+    S = TP * page_size  # context tokens per tile
     length = lengths_ref[b]
-    n_pages = jnp.maximum(1, pl.cdiv(length, page_size))
+
+    def pages_of(seq_idx):
+        return jnp.maximum(1, pl.cdiv(lengths_ref[seq_idx], page_size))
+
+    n_pages = pages_of(b)
+    n_tiles = pl.cdiv(n_pages, TP)
 
     Hq, D = q_ref.shape[1], q_ref.shape[2]
     Hkv = k_hbm.shape[2]
@@ -399,34 +486,43 @@ def _kernel_lookahead(
     q = q_ref[0].astype(jnp.float32).reshape(Hkv, G, D)
     scale = 1.0 / jnp.sqrt(jnp.float32(D))
 
-    def src(seq_idx, i, c):
-        # pages by physical id; scale rows by (sequence, logical page)
-        return page_tables_ref[seq_idx, i] if c < 2 else seq_idx * max_pages + i
+    def tile_dmas(op, seq_idx, t, npg, pools, scales, at, sems):
+        """Start or wait (``op``) every copy of tile t of ``seq_idx``: its
+        pages below ``npg`` and, for int8 pools, the tile's scale rows.
+        ``at(scratch)`` is the tile's slot in a scratch buffer."""
 
-    def pre_dma(parity, j, seq_idx, c):
-        hbm, scratch = pre_pools[c]
-        return pltpu.make_async_copy(
-            hbm.at[src(seq_idx, j, c)],
-            scratch.at[parity, j],
-            sems_pre.at[parity, j, c],
-        )
+        def page(p, _):
+            for c, (hbm, scratch) in enumerate(pools):
+                copy = pltpu.make_async_copy(
+                    hbm.at[page_tables_ref[seq_idx, t * TP + p]],
+                    at(scratch).at[p], sems.at[c],
+                )
+                getattr(copy, op)()
+            return 0
 
-    def tail_dma(slot, i, c):
-        hbm, scratch = tail_pools[c]
-        return pltpu.make_async_copy(
-            hbm.at[src(b, i, c)],
-            scratch.at[slot],
-            sems_tail.at[slot, c],
-        )
+        jax.lax.fori_loop(0, jnp.minimum(TP, npg - t * TP), page, 0)
+        for c, (hbm, scratch) in enumerate(scales):
+            copy = pltpu.make_async_copy(
+                hbm.at[seq_idx * tiles_per_seq + t], at(scratch), sems.at[2 + c]
+            )
+            getattr(copy, op)()
+
+    def pre_dmas(op, parity, j, seq_idx, npg):
+        tile_dmas(op, seq_idx, j, npg, pre_pools, pre_scales,
+                  lambda scratch: scratch.at[parity, j], sems_pre.at[parity, j])
+
+    def tail_dmas(op, slot, t):
+        tile_dmas(op, b, t, n_pages, tail_pools, tail_scales,
+                  lambda scratch: scratch.at[slot], sems_tail.at[slot])
 
     def issue_pre(seq_idx, parity):
-        npg = jnp.maximum(1, pl.cdiv(lengths_ref[seq_idx], page_size))
-        for j in range(W):  # static unroll: DMA issues only
+        npg = pages_of(seq_idx)
 
-            @pl.when(j < npg)
-            def _(j=j):
-                for c in range(len(pre_pools)):
-                    pre_dma(parity, j, seq_idx, c).start()
+        def issue(j, _):
+            pre_dmas("start", parity, j, seq_idx, npg)
+            return 0
+
+        jax.lax.fori_loop(0, jnp.minimum(W, pl.cdiv(npg, TP)), issue, 0)
 
     # program 0 has no predecessor: prefetch its own window
     @pl.when(b == 0)
@@ -438,63 +534,67 @@ def _kernel_lookahead(
     def _():
         issue_pre(b + 1, 1 - par)
 
-    # long-context tail: warm the in-program double buffer for page W
-    @pl.when(W < n_pages)
+    # long-context tail: warm the in-program double buffer for tile W
+    @pl.when(W < n_tiles)
     def _():
-        for c in range(len(tail_pools)):
-            tail_dma(W % 2, W, c).start()
+        tail_dmas("start", W % 2, W)
 
-    def merge(carry, k_page, v_page, j, k_s, v_s):
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, 1, S), 2)
+
+    def merge(carry, t, k_tile, v_tile, k_s, v_s):
         m, l, acc = carry
-        kt = jnp.transpose(k_page, (1, 0, 2))  # [Hkv, ps, D]
-        vt = jnp.transpose(v_page, (1, 0, 2))
+
+        if TP > 1:
+            # a sequence's last tile holds pages that were never fetched:
+            # stale VMEM. Their weights are zero, and zero times a stale NaN
+            # would still poison acc. (A tile of one page is fetched whole.)
+            @pl.when((t + 1) * S > length)
+            def _():
+                _zero_tokens_from(v_tile, length - t * S)
+
+        kt = _heads_major(k_tile)  # [Hkv, S, D] f32
+        vt = _heads_major(v_tile)
+        # [Hkv, G, S] = [Hkv, G, D] x [Hkv, S, D]
         scores = jax.lax.dot_general(
             q, kt, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
         ) * scale
         if quantized:
-            scores = scores * k_s[:, :page_size][None]  # [1, 1, ps] per-row K scales
-        idx = j * page_size + jax.lax.broadcasted_iota(jnp.int32, (1, 1, page_size), 2)
-        scores = jnp.where(idx < length, scores, _NEG_INF)
+            scores = scores * k_s[:, :S][None]  # [1, 1, S] per-row K scales
+        valid = t * S + col < length
+        scores = jnp.where(valid, scores, _NEG_INF)
         chunk_max = jnp.max(scores, axis=-1)
         new_m = jnp.maximum(m, chunk_max)
         corr = jnp.exp(m - new_m)
         probs = jnp.exp(scores - new_m[..., None])
         new_l = l * corr + jnp.sum(probs, axis=-1)
         if quantized:
-            probs = probs * v_s[:, :page_size][None]
+            # V scales fold into probs (masked: a scale row's unused lanes
+            # belong to whatever page the table's padding names)
+            probs = jnp.where(valid, probs * v_s[:, :S][None], 0.0)
+        # [Hkv, G, D] = [Hkv, G, S] x [Hkv, S, D]
         chunk_out = jax.lax.dot_general(
             probs, vt, (((2,), (1,)), ((0,), (0,))), preferred_element_type=jnp.float32
         )
         return new_m, new_l, acc * corr[..., None] + chunk_out
 
     def pre_body(j, carry):
-        for c in range(len(pre_pools)):
-            pre_dma(par, j, b, c).wait()
+        pre_dmas("wait", par, j, b, n_pages)
         return merge(
-            carry,
-            k_pre[par, j].astype(jnp.float32),
-            v_pre[par, j].astype(jnp.float32),
-            j,
+            carry, j, k_pre.at[par, j], v_pre.at[par, j],
             ks_pre[par, j] if quantized else None,
             vs_pre[par, j] if quantized else None,
         )
 
-    def tail_body(j, carry):
-        slot = jax.lax.rem(j, 2)
-        next_slot = jax.lax.rem(j + 1, 2)
+    def tail_body(t, carry):
+        slot = jax.lax.rem(t, 2)
 
-        @pl.when(j + 1 < n_pages)
+        @pl.when(t + 1 < n_tiles)
         def _():
-            for c in range(len(tail_pools)):
-                tail_dma(next_slot, j + 1, c).start()
+            tail_dmas("start", 1 - slot, t + 1)
 
-        for c in range(len(tail_pools)):
-            tail_dma(slot, j, c).wait()
+        tail_dmas("wait", slot, t)
         return merge(
-            carry,
-            k_tail[slot].astype(jnp.float32),
-            v_tail[slot].astype(jnp.float32),
-            j,
+            carry, t, k_tail.at[slot], v_tail.at[slot],
             ks_tail[slot] if quantized else None,
             vs_tail[slot] if quantized else None,
         )
@@ -502,24 +602,45 @@ def _kernel_lookahead(
     m0 = jnp.full((Hkv, G), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((Hkv, G), jnp.float32)
     acc0 = jnp.zeros((Hkv, G, D), jnp.float32)
-    carry = jax.lax.fori_loop(0, jnp.minimum(W, n_pages), pre_body, (m0, l0, acc0))
-    m, l, acc = jax.lax.fori_loop(W, n_pages, tail_body, carry)
+    carry = jax.lax.fori_loop(0, jnp.minimum(W, n_tiles), pre_body, (m0, l0, acc0))
+    m, l, acc = jax.lax.fori_loop(W, n_tiles, tail_body, carry)
 
     out = acc / jnp.maximum(l, 1e-20)[..., None]
     out_ref[0] = out.reshape(Hq, D).astype(out_ref.dtype)
 
 
-#: scratch budget for the lookahead window (VMEM is ~16 MB/core scoped)
+#: scratch budget for the lookahead kernel (VMEM is ~16 MB/core scoped)
 _LOOKAHEAD_SCRATCH_BYTES = 6 * 1024 * 1024
+#: context tokens per tile: one full 128-lane row of scores
+_TILE_TOKENS = 128
+#: tiles a program prefetches for its successor: (where a tile is several
+#: pages, where it is one page). Of 1, 2, 4, 8 and 16 tiles of 8 pages the
+#: small windows timed best, within 7%; a tile of one page keeps the 4 it had
+#: as the page-at-a-time kernel, where 4 timed 2% better than 2 (design record)
+_LOOKAHEAD_MAX_TILES = (2, 4)
+
+
+def decode_tile_pages(page_size: int, num_kv_heads: int, head_dim: int,
+                      itemsize: int = 2) -> int:
+    """Pages per tile TP: ``_TILE_TOKENS`` of context (1 at page sizes of 128
+    and more), halved while the four tiles the kernel cannot do without (one
+    window tile per parity and the two tail slots) overrun the budget."""
+    page_bytes = 2 * page_size * num_kv_heads * head_dim * itemsize  # k + v
+    tp = max(1, _TILE_TOKENS // page_size)
+    while tp > 1 and 4 * tp * page_bytes > _LOOKAHEAD_SCRATCH_BYTES:
+        tp //= 2
+    return tp
 
 
 def lookahead_window(page_size: int, num_kv_heads: int, head_dim: int,
                      itemsize: int = 2) -> int:
-    """Prefetch window W that fits the scratch budget (0 = kernel not
-    applicable). Scratch = 2 parities x W pages x (k+v) + the 2-slot tail."""
-    page_bytes = page_size * num_kv_heads * head_dim * itemsize
-    budget = _LOOKAHEAD_SCRATCH_BYTES - 2 * 2 * page_bytes  # tail buffers
-    return max(0, min(4, budget // (2 * 2 * page_bytes)))
+    """Prefetch window W in TILES that fits the scratch budget (0 = kernel
+    not applicable). Scratch = 2 parities x W tiles x (k+v) + the 2-slot
+    tail; int8 scale rows are noise."""
+    tp = decode_tile_pages(page_size, num_kv_heads, head_dim, itemsize)
+    tile_bytes = 2 * tp * page_size * num_kv_heads * head_dim * itemsize
+    budget = _LOOKAHEAD_SCRATCH_BYTES - 2 * tile_bytes  # tail buffers
+    return max(0, min(_LOOKAHEAD_MAX_TILES[tp == 1], budget // (2 * tile_bytes)))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -532,39 +653,26 @@ def paged_decode_attention_pallas_lookahead(
     interpret: bool = False,
 ) -> jnp.ndarray:
     B, Hq, D = q.shape
-    kq, vq, ks, vs, quantized = _decode_unpack_pools(k_pages, v_pages, page_tables)
-    P, ps, Hkv, _ = kq.shape
-    max_pages = page_tables.shape[1]
-    lengths = positions.astype(jnp.int32) + 1
-    W = lookahead_window(ps, Hkv, D, kq.dtype.itemsize)
+    P, ps, Hkv, _ = k_pages.shape
+    itemsize = k_pages.dtype.itemsize
+    W = lookahead_window(ps, Hkv, D, itemsize)
     if W < 1:
         return paged_decode_attention_pallas(
             q, k_pages, v_pages, page_tables, positions, interpret=interpret
         )
+    TP = decode_tile_pages(ps, Hkv, D, itemsize)
+    kq, vq, ks, vs, quantized = _decode_unpack_pools(k_pages, v_pages, page_tables, TP)
+    lengths = positions.astype(jnp.int32) + 1
 
-    scratch_shapes = [
-        pltpu.VMEM((2, W, ps, Hkv, D), kq.dtype),
-        pltpu.VMEM((2, W, ps, Hkv, D), vq.dtype),
-    ]
-    if quantized:
-        scratch_shapes += [
-            pltpu.VMEM((2, W, 1, ks.shape[-1]), jnp.float32),
-            pltpu.VMEM((2, W, 1, vs.shape[-1]), jnp.float32),
-        ]
-    scratch_shapes += [
-        pltpu.VMEM((2, ps, Hkv, D), kq.dtype),
-        pltpu.VMEM((2, ps, Hkv, D), vq.dtype),
-    ]
-    if quantized:
-        scratch_shapes += [
-            pltpu.VMEM((2, 1, ks.shape[-1]), jnp.float32),
-            pltpu.VMEM((2, 1, vs.shape[-1]), jnp.float32),
-        ]
+    def tile_scratch(*lead):
+        shapes = [pltpu.VMEM((*lead, TP, ps, Hkv, D), kq.dtype),
+                  pltpu.VMEM((*lead, TP, ps, Hkv, D), vq.dtype)]
+        if quantized:
+            shapes += [pltpu.VMEM((*lead, 1, ks.shape[-1]), jnp.float32),
+                       pltpu.VMEM((*lead, 1, vs.shape[-1]), jnp.float32)]
+        return shapes
+
     C = 4 if quantized else 2
-    scratch_shapes += [
-        pltpu.SemaphoreType.DMA((2, W, C)),
-        pltpu.SemaphoreType.DMA((2, C)),
-    ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B,),
@@ -573,16 +681,22 @@ def paged_decode_attention_pallas_lookahead(
             *[pl.BlockSpec(memory_space=pl.ANY) for _ in range(C)],
         ],
         out_specs=pl.BlockSpec((1, Hq, D), lambda b, *_: (b, 0, 0)),
-        scratch_shapes=scratch_shapes,
+        scratch_shapes=[
+            *tile_scratch(2, W),
+            *tile_scratch(2),
+            pltpu.SemaphoreType.DMA((2, W, C)),
+            pltpu.SemaphoreType.DMA((2, C)),
+        ],
     )
     kernel = pl.pallas_call(
         functools.partial(
-            _kernel_lookahead, page_size=ps, max_pages=max_pages, lookahead=W,
+            _kernel_lookahead, page_size=ps, tile_pages=TP,
+            tiles_per_seq=pl.cdiv(page_tables.shape[1], TP), lookahead=W,
             quantized=quantized,
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
         grid_spec=grid_spec,
-        # cross-program scratch persistence (program b prefetches b+1's pages
+        # cross-program scratch persistence (program b prefetches b+1's tiles
         # into the opposite parity's slots) requires the grid to run SERIALLY
         # — pin it rather than relying on the implicit default
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),

@@ -82,7 +82,7 @@ def test_one_chip_rehearsal_runs_every_phase(smoke):
     assert "native" in phases["environment"]["radix_index"] or "python" in phases["environment"]["radix_index"]
 
     cases = [r for r in rows if "case" in r]
-    assert len(cases) >= 17 and all(c["ok"] for c in cases), cases
+    assert len(cases) >= 19 and all(c["ok"] for c in cases), cases
     assert max(c["max_abs_err"] for c in cases) <= chip_smoke.PARITY_ATOL
 
     # the contract's last line: what the serving child reported it ran on
