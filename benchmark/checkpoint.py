@@ -1,12 +1,17 @@
-"""Seeded HF-format checkpoint for a benchmark configuration.
+"""Seeded HF-format checkpoint for a benchmark configuration: the writer,
+the tokenizer and the table of normal quantiles. WHICH tensors a checkpoint
+holds is not decided here: a configuration names its tensor plan
+(`benchmark.checkpoint`, a file in `checkpoints/`; `dense` where it names
+none), and `ensure_checkpoint` writes what that plan lists.
 
 Copied from `tools/make_hf_checkpoint.py` (config.json + model.safetensors +
-tokenizer files, random-normal weights at 0.02, Qwen2 qkv biases), so that a
-later PR may change the program's tool and not the yardstick. Corrected:
+tokenizer files, random-normal weights at 0.02), so that a later PR may change
+the program's tool and not the yardstick. Corrected:
 
-- tied embeddings are written once (`tie_word_embeddings: true` in the config,
-  no `lm_head.weight` in the file): the original always wrote a second, untied
-  head, which is a different model from the published one and 0.6 GB more;
+- tied embeddings are written once (`tie_word_embeddings: true` in the config
+  and no second head in the file, see `checkpoints/dense.py`): the original
+  always wrote an untied head, which is a different model from the published
+  one and 0.6 GB more;
 - tensors are made in parallel, one numpy stream per tensor keyed by (seed,
   tensor index), directly in bfloat16 (16-bit draws looked up in a table of
   normal quantiles) and written straight to their offsets in the safetensors
@@ -18,11 +23,12 @@ later PR may change the program's tool and not the yardstick. Corrected:
   server sends no SSE chunk for an empty text, and a client then sees its
   first token late or never. Published tokenizers decode every id.
 
-The checkpoint is a pure function of (geometry, seed).
+The checkpoint is a pure function of (geometry, plan, seed).
 """
 
 from __future__ import annotations
 
+import hashlib
 import json
 import os
 import shutil
@@ -38,40 +44,6 @@ WEIGHT_SCALE = 0.02
 SPECIAL_TOKENS = ["<s>", "</s>", "<unk>"]  # ids 0, 1, 2
 
 _CHUNK = 1 << 18  # elements per draw: the index block stays in the core's cache
-
-
-def tensor_plan(cfg: dict) -> list:
-    """[(name, shape, kind)] in file order; kind is "normal" or "ones"."""
-    D, I, V = cfg["hidden_size"], cfg["intermediate_size"], cfg["vocab_size"]
-    Hq, Hkv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    hd = cfg.get("head_dim") or D // Hq
-    plan = [("model.embed_tokens.weight", (V, D), "normal"),
-            ("model.norm.weight", (D,), "ones")]
-    if not cfg.get("tie_word_embeddings", False):
-        plan.append(("lm_head.weight", (V, D), "normal"))
-    qwen = "qwen" in str(cfg.get("model_type", "")).lower()
-    for l in range(cfg["num_hidden_layers"]):
-        p = f"model.layers.{l}."
-        plan += [
-            (p + "input_layernorm.weight", (D,), "ones"),
-            (p + "post_attention_layernorm.weight", (D,), "ones"),
-            (p + "self_attn.q_proj.weight", (Hq * hd, D), "normal"),
-            (p + "self_attn.k_proj.weight", (Hkv * hd, D), "normal"),
-            (p + "self_attn.v_proj.weight", (Hkv * hd, D), "normal"),
-            (p + "self_attn.o_proj.weight", (D, Hq * hd), "normal"),
-        ]
-        if cfg.get("attention_bias", qwen):
-            plan += [
-                (p + "self_attn.q_proj.bias", (Hq * hd,), "normal"),
-                (p + "self_attn.k_proj.bias", (Hkv * hd,), "normal"),
-                (p + "self_attn.v_proj.bias", (Hkv * hd,), "normal"),
-            ]
-        plan += [
-            (p + "mlp.gate_proj.weight", (I, D), "normal"),
-            (p + "mlp.up_proj.weight", (I, D), "normal"),
-            (p + "mlp.down_proj.weight", (D, I), "normal"),
-        ]
-    return plan
 
 
 def _normal_table() -> np.ndarray:
@@ -101,8 +73,9 @@ def tensor_values(seed: int, index: int, shape: tuple, kind: str, table: np.ndar
     return out
 
 
-def write_safetensors(path: Path, cfg: dict, seed: int, workers: int) -> int:
-    plan = tensor_plan(cfg)
+def write_safetensors(path: Path, plan: list, seed: int, workers: int) -> int:
+    """`plan` is [(name, shape, kind)] in file order, kind "normal" or "ones":
+    what a `checkpoints/<name>.py` `tensor_plan(cfg)` returns."""
     header, offset = {}, 0
     for name, shape, _ in plan:
         nbytes = int(np.prod(shape)) * 2
@@ -168,26 +141,30 @@ def token_id_of(text: str) -> int:
     return int(text[1:])
 
 
-def ensure_checkpoint(cache: Path, name: str, hf_config: dict, seed: int,
+def ensure_checkpoint(cache: Path, name: str, hf_config: dict, seed: int, plan_module,
                       workers: int | None = None) -> tuple:
-    """(directory, made-now, seconds, bytes). One checkpoint per
-    configuration is kept: a set of runs uses a new seed each time, and a
+    """(directory, made-now, seconds, bytes). `plan_module` is the
+    configuration's `checkpoints/<benchmark.checkpoint>.py`: its
+    `tensor_plan(config)` says which tensors the file holds. One checkpoint
+    per configuration is kept: a set of runs uses a new seed each time, and a
     full-size one is 6 GB."""
     t0 = time.monotonic()
     out = cache / f"ckpt-{name}-seed{seed}"
     stamp = out / ".complete"
-    want = json.dumps({"config": hf_config, "seed": seed, "format": 2}, sort_keys=True)
+    config = {"hidden_act": "silu", "bos_token_id": 0, "eos_token_id": 1,
+              "torch_dtype": "bfloat16", **hf_config}
+    plan = [(n, tuple(int(d) for d in shape), kind) for n, shape, kind in plan_module.tensor_plan(config)]
+    want = json.dumps({"config": hf_config, "seed": seed, "format": 3,
+                       "plan": hashlib.sha256(json.dumps(plan).encode()).hexdigest()}, sort_keys=True)
     if stamp.exists() and stamp.read_text() == want:
         size = (out / "model.safetensors").stat().st_size
         return out, False, time.monotonic() - t0, size
     for old in cache.glob(f"ckpt-{name}-seed*"):
         shutil.rmtree(old, ignore_errors=True)
     out.mkdir(parents=True)
-    config = {"hidden_act": "silu", "bos_token_id": 0, "eos_token_id": 1,
-              "torch_dtype": "bfloat16", **hf_config}
     (out / "config.json").write_text(json.dumps(config, indent=1))
     write_tokenizer(out, config["vocab_size"])
-    size = write_safetensors(out / "model.safetensors", config, seed,
+    size = write_safetensors(out / "model.safetensors", plan, seed,
                              workers or min(12, os.cpu_count() or 4))
     stamp.write_text(want)
     return out, True, time.monotonic() - t0, size

@@ -6,7 +6,8 @@ entry point on the chip and driven from outside as a user would.
     python3 benchmark/run.py --sweep <cell> [--seed n]        (builder's use)
 
 Two processes. This one never imports JAX: it makes the checkpoint from the
-seed, starts the server (`benchmark/serve_entry.py`, which runs
+seed (the tensors its configuration's `checkpoints/<plan>.py` lists), starts
+the server (`benchmark/serve_entry.py`, which runs
 `dynamo_tpu.launch.run.main` unchanged), waits for `/ready` and for the
 first of the program's background compiles to end, holds the scheduler busy
 with keeper streams so that no further one starts (`Keepers`), sends warm
@@ -449,11 +450,13 @@ def serve_and_measure(files: Files, args, cell: dict, trace: bool, steps: list |
     why_not = files.module("launchers", b["launcher"]).preflight()
     if why_not:
         raise BenchError(why_not)
+    # which tensors the checkpoint holds: `checkpoints/<name>.py`, found by name
+    plan = files.module("checkpoints", b.get("checkpoint", "dense"))
     cache = HERE / ".cache"
     work = cache / "work" / cell["name"]
     work.mkdir(parents=True, exist_ok=True)
     hf = {k: v for k, v in conf.items() if k not in OWN_KEYS}
-    ckpt, made, secs, size = checkpoint.ensure_checkpoint(cache, cell["config"], hf, args.seed)
+    ckpt, made, secs, size = checkpoint.ensure_checkpoint(cache, cell["config"], hf, args.seed, plan)
     note(phase="checkpoint", path=os.path.relpath(ckpt, HERE.parent), made=made,
          seconds=round(secs, 2), bytes=size)
     mix = files.data("traffic", cell["traffic"])
@@ -519,8 +522,9 @@ def run_cell(files: Files, args) -> int:
     atol = float(b["logprob_atol"])
     correct = worst <= atol and compiles == 0 and counts_ok and attempted > 0
     lags = [o.lag_s * 1e3 for o in ctx["outcomes"] if ctx["t_open"] <= o.due < ctx["t_close"]]
+    compared = sum(len(r) for r in ref)
     note(phase="correctness", logprob_worst_abs_diff=worst, tolerance=atol,
-         logprobs_compared=sum(len(r) for r in ref), reference_kept=kept,
+         logprobs_compared=compared, reference_kept=kept,
          reference_seconds=round(ref_s, 2), compiles_in_window=compiles,
          token_counts_exact=counts_ok)
     note(phase="window", attempted=attempted, failed=failed,
@@ -560,6 +564,11 @@ def run_cell(files: Files, args) -> int:
         if reduced["busy_s"] <= 0 and dev["platform"] == "tpu":
             raise BenchError("no operation ran on the device in the traced window")
     result["device"] = device
+    # each number compared beside its limit, as the last lines of stderr too
+    print(f"compared: logprob_worst_abs_diff {worst:.6g} (limit {atol:g}) over {compared} logprobs\n"
+          f"compared: compiles_in_window {compiles:g} (limit 0)\n"
+          f"compared: token_counts_exact {counts_ok} (must be True), attempted {attempted} (must be over 0)\n"
+          f"correct: {bool(correct)}", file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0
 

@@ -26,6 +26,7 @@ def test_every_name_resolves():
         b = conf["benchmark"]
         assert files.find("launchers", f"{b['launcher']}.py")
         assert files.find("reference", f"{b['reference']}.py")
+        assert hasattr(files.module("checkpoints", b.get("checkpoint", "dense")), "tensor_plan")
     for w in spec["workloads"]:
         assert NAME.match(w["name"]) and w["config"] in configs and w["chips"] in (1, 4)
         assert len(w["why"]) <= 200

@@ -27,11 +27,13 @@ MIX = {"generator": "open_loop", "arrival": "poisson",
        "warm": {"depths": [0], "tails": [20, 100, 200], "bursts": [1, 2, 4], "repeats": 3, "tokens": 9}}
 
 
-def _tree(tmp: Path) -> None:
+def throwaway_spec(tmp: Path, config: str, traffic: str) -> None:
+    """`BENCHMARK.json` of a temporary root: the repo's metrics, one
+    configuration and one cell of it, everything else under `extra/`."""
     spec = json.loads((ROOT / "BENCHMARK.json").read_text())
-    spec["configs"] = [{"name": "tiny", "source": "none", "file": "extra/configs/tiny.json",
+    spec["configs"] = [{"name": config, "source": "none", "file": f"extra/configs/{config}.json",
                         "reduced": [], "why": "rehearsal"}]
-    spec["workloads"] = [{"name": "tiny.chat-tiny", "config": "tiny", "traffic": "chat-tiny",
+    spec["workloads"] = [{"name": f"{config}.{traffic}", "config": config, "traffic": traffic,
                           "chips": 1, "why": "rehearsal"}]
     spec["paths"] = ["extra"]
     # every metric in the one throwaway cell, each quantity once
@@ -39,6 +41,10 @@ def _tree(tmp: Path) -> None:
     for m in spec["end_to_end"] + spec["per_layer"]:
         m.pop("workloads", None)
     (tmp / "BENCHMARK.json").write_text(json.dumps(spec))
+
+
+def _tree(tmp: Path) -> None:
+    throwaway_spec(tmp, "tiny", "chat-tiny")
     for sub in ("configs", "traffic", "cells"):
         (tmp / "extra" / sub).mkdir(parents=True)
     (tmp / "extra/configs/tiny.json").write_text(json.dumps(TINY))
@@ -46,20 +52,24 @@ def _tree(tmp: Path) -> None:
     (tmp / "extra/cells/tiny.chat-tiny.json").write_text(json.dumps({"rate_rps": 4.0}))
 
 
-def _run(tmp: Path, trace: int) -> tuple:
+def _run(tmp: Path, trace: int, workload: str = "tiny.chat-tiny") -> tuple:
     proc = subprocess.run(
         [sys.executable, str(ROOT / "benchmark" / "run.py"), "--root", str(tmp),
-         "--workload", "tiny.chat-tiny", "--seed", str(2**31 + 11), "--seconds", "6",
+         "--workload", workload, "--seed", str(2**31 + 11), "--seconds", "6",
          "--trace", str(trace)],
         env={**os.environ, "JAX_PLATFORMS": "cpu"}, capture_output=True, text=True, timeout=1500)
     assert proc.returncode == 0, proc.stderr[-3000:] + proc.stdout[-3000:]
     return json.loads(proc.stdout.strip().splitlines()[-1]), proc.stdout
 
 
+def benchmark_files() -> dict:
+    return {p: p.stat().st_mtime_ns for p in (ROOT / "benchmark").rglob("*")
+            if p.is_file() and ".cache" not in p.parts and "__pycache__" not in p.parts}
+
+
 def test_throwaway_cell_runs_end_to_end(tmp_path):
     _tree(tmp_path)
-    before = {p: p.stat().st_mtime_ns for p in (ROOT / "benchmark").rglob("*")
-              if p.is_file() and ".cache" not in p.parts and "__pycache__" not in p.parts}
+    before = benchmark_files()
     res, log = _run(tmp_path, 0)
     assert set(res) == {"correct", "attempted", "failed", "metrics", "device"}
     assert res["correct"] is True and res["failed"] == 0 and res["attempted"] >= 15, log[-3000:]
@@ -76,8 +86,7 @@ def test_throwaway_cell_runs_end_to_end(tmp_path):
     assert {"busy_s", "window_s"} <= set(traced["device"])
     # the second run found the first's reference result and checkpoint
     assert '"reference_kept": true' in log and '"made": false' in log
-    after = {p: p.stat().st_mtime_ns for p in before}
-    assert after == before, "a run edited a file of the benchmark"
+    assert benchmark_files() == before, "a run edited a file of the benchmark"
 
 
 def test_no_result_without_the_platform_the_configuration_asks_for(tmp_path):
