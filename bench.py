@@ -122,7 +122,7 @@ def moe_model_id() -> str:
     cfg = {
         "vocab_size": 32000, "hidden_size": 1024, "intermediate_size": 3584,
         "num_layers": 12, "num_heads": 8, "num_kv_heads": 4, "head_dim": 128,
-        "num_experts": 8, "num_experts_per_tok": 2, "moe_capacity_factor": 2.0,
+        "num_experts": 8, "num_experts_per_tok": 2,
         "dtype": "bf16",
     }
     return "tiny-moe:" + json.dumps(cfg)

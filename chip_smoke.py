@@ -883,6 +883,28 @@ def child_parity(sizes: Sizes, args) -> int:
         ctx = pages[table].reshape(maxp * ps, lat)
         return got, reference(model._absorbed_attention, lp, qn, qr, ctx, pos)
 
+    def ssm_update(slots, H, P, G, N):
+        """The one-token Mamba-2 state update, in place over the state, at a
+        batch where a third of the slots are not live (they read and write
+        the trash row): output y and the whole state against `jax.numpy`."""
+        from dynamo_tpu.ops.pallas.ssm_update import ssm_state_update_pallas
+        from dynamo_tpu.ops.ssm import ssm_state_update_reference
+
+        def f32(*shape, scale=1.0):
+            return jnp.asarray(rng.standard_normal(shape, dtype=np.float32) * scale)
+
+        state = f32(slots + 1, H, P, N).at[slots].set(0.0)
+        live = jnp.asarray(np.arange(slots) % 3 != 1)
+        rows = jnp.where(live, jnp.arange(slots), slots).astype(jnp.int32)
+        decay = jnp.where(live[:, None], jnp.exp(-jnp.abs(f32(slots, H))), 1.0)
+        dtx = jnp.where(live[:, None, None], f32(slots, H, P), 0.0)
+        b, c = f32(slots, G, N), f32(slots, G, N, scale=0.1)
+        want_y, want_s = reference(ssm_state_update_reference, state, decay, dtx, b, c, rows)
+        got_y, got_s = ssm_state_update_pallas(state, decay, dtx, b, c, rows, interpret=interpret)
+        pick = np.asarray(live)
+        return (np.concatenate([np.asarray(got_y)[pick].ravel(), np.asarray(got_s).ravel()]),
+                np.concatenate([np.asarray(want_y)[pick].ravel(), np.asarray(want_s).ravel()]))
+
     if full:
         tiny, qwen, mixtral, bench, shard = (32, 4, 64), (28, 4, 128), (32, 8, 128), (16, 8, 128), (7, 1, 128)
         qwen3b = (16, 2, 128)  # the benchmark's configuration
@@ -911,6 +933,8 @@ def child_parity(sizes: Sizes, args) -> int:
         ("mla decode classic ps16", lambda: mla(16)),
         ("mla decode lookahead ps16", lambda: mla(16, lookahead=True)),
         ("mla prefill ps16", lambda: mla(16, T=T, prefix=prefix)),
+        # Mamba-2 at the published widths (128 heads x 64 x 128), 24 slots
+        ("ssm state update nemotron-h f32", lambda: ssm_update(*((24, 128, 64, 8, 128) if full else (6, 8, 8, 2, 128)))),
     ]
     ok = True
     for name, thunk in cases:

@@ -46,6 +46,9 @@ class DisaggDecodeEngine:
         disagg_router: Optional[DisaggregatedRouter] = None,
         remote_prefill_timeout: float = 120.0,
     ):
+        from dynamo_tpu.disagg import refuse_recurrent
+
+        refuse_recurrent(engine, "a disaggregated decode worker")
         self.engine = engine
         self.drt = drt
         self.namespace = namespace

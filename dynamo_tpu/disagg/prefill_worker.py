@@ -34,6 +34,9 @@ class PrefillWorker:
         kv_stream: Optional[bool] = None,
         kv_stream_lanes: Optional[int] = None,
     ):
+        from dynamo_tpu.disagg import refuse_recurrent
+
+        refuse_recurrent(engine, "a disaggregated prefill worker")
         self.engine = engine
         self.drt = drt
         self.namespace = namespace

@@ -177,6 +177,15 @@ class AsyncJaxEngine:
             kv_cache_dtype=self.config.kv_cache_dtype,
         )
         self.runner = ModelRunner(self.config, self.model, params)
+        if self.runner.recurrent and self.config.migration:
+            # refused, not an error: migration is on by default, and a peer
+            # could adopt this model's pages but not its per-slot state
+            log.warning(
+                "live migration is refused for %s: a sequence's recurrent "
+                "state has no wire form yet; drain degrades to attrition",
+                type(self.model).__name__,
+            )
+            self.config.migration = False
         offload = None
         if self.config.host_cache_blocks > 0 or self.config.host_cache_bytes > 0:
             from dynamo_tpu.engine.offload import (
@@ -233,6 +242,7 @@ class AsyncJaxEngine:
             self.config.page_size,
             event_sink=self._on_kv_event,
             offload=offload,
+            match_prefix=not self.runner.recurrent,
         )
         self.scheduler = Scheduler(self.config, self.runner, self.allocator)
         self.scheduler.slo = self.slo
@@ -1106,6 +1116,7 @@ class AsyncJaxEngine:
         page_bytes = 0
         if runner is not None and hasattr(runner.model, "kv_page_bytes"):
             page_bytes = runner.model.kv_page_bytes(self.config.page_size)
+        recurrent = getattr(runner, "recurrent", False)
         snap = {
             "kv_cache_dtype": self.config.kv_cache_dtype or "bf16",
             "kv_page_bytes": page_bytes,
@@ -1121,6 +1132,15 @@ class AsyncJaxEngine:
                 0, alloc.cache_query_blocks - alloc.cache_hit_blocks
             ),
             "prefix_cache_query_blocks": alloc.cache_query_blocks,
+            "prefix_cache_refused": alloc.prefix_refused,
+            # the second kind of cache: per-slot recurrent state (zeros for
+            # a model with no recurrent layers)
+            "state_slots_total": self.config.max_seqs if recurrent else 0,
+            "state_slots_active": sched.state_slots_active,
+            "hbm_state_bytes": runner.model.state_bytes(self.config.max_seqs) if recurrent else 0,
+            "moe_assignments": sched.moe_assignments,
+            "moe_routed": sched.moe_routed,
+            "moe_busiest_over_mean": round(sched.moe_busiest_over_mean, 4),
             # fleet prefix cache: remote pulls this engine issued (requester
             # side; the pull SERVER's counters ride the worker's kv_pull stats)
             "prefix_fetch_hits": sched.prefix_fetch_hits,
@@ -1538,7 +1558,38 @@ class AsyncJaxEngine:
                 "device memory summed over local devices (zeros on CPU)",
                 [({"kind": "live"}, r["hbm_bytes_in_use"]),
                  ({"kind": "peak"}, r["hbm_peak_bytes_in_use"]),
-                 ({"kind": "limit"}, r["hbm_bytes_limit"])],
+                 ({"kind": "limit"}, r["hbm_bytes_limit"]),
+                 ({"kind": "state"}, r["hbm_state_bytes"])],
+            ),
+            render_family(
+                "dynamo_engine_state_slots", "gauge",
+                "decode slots of the per-slot recurrent state cache (total 0: "
+                "the model has no recurrent layers)",
+                [({"state": "active"}, r["state_slots_active"]),
+                 ({"state": "total"}, r["state_slots_total"])],
+            ),
+            render_family(
+                "dynamo_engine_prefix_cache_refused_total", "counter",
+                "sequences whose cached prefix was withheld because the model "
+                "has recurrent layers (pages without state are another model)",
+                [({}, r["prefix_cache_refused"])],
+            ),
+            render_family(
+                "dynamo_engine_moe_assignments_total", "counter",
+                "decode-step expert assignments that landed on experts held here",
+                [({}, r["moe_assignments"])],
+            ),
+            render_family(
+                "dynamo_engine_moe_routed_total", "counter",
+                "decode-step expert assignments routed: tokens x experts a "
+                "token x expert blocks, held here or not",
+                [({}, r["moe_routed"])],
+            ),
+            render_family(
+                "dynamo_engine_moe_busiest_over_mean", "gauge",
+                "the last decode window's busiest held expert over the mean "
+                "of the held experts' assignment counts",
+                [({}, r["moe_busiest_over_mean"])],
             ),
             # KV cache bytes at the ACTUAL storage dtype (int8 pages cost
             # half + scale planes; pre-r6 consumers assumed bf16)

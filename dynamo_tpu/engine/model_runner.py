@@ -33,6 +33,29 @@ from dynamo_tpu.utils import get_logger
 log = get_logger("engine.runner")
 
 
+def recurrent_refusal(config: EngineConfig) -> Optional[str]:
+    """Why this engine configuration cannot serve a model with recurrent
+    layers, or None. Each of these would have to copy, ship or roll back the
+    per-slot state together with the KV pages it belongs to, and nothing here
+    does that yet (state snapshots at block boundaries are a later PR); to
+    run them on the pages alone would serve another model, silently."""
+    if config.speculative:
+        return ("speculative decoding is refused: a rejected draft would have "
+                "to roll the recurrent state back, and no snapshot is kept")
+    if config.host_cache_blocks > 0 or config.host_cache_bytes > 0 or config.disk_cache_bytes > 0:
+        return ("the host and disk KV tiers are refused: a restored prefix has "
+                "pages and no recurrent state to go with them")
+    if config.tp > 1 or config.pp > 1 or config.sp > 1:
+        return ("tp/pp/sp > 1 are refused: the state cache and the expert "
+                "dispatch run on one chip (expert parallelism across chips, "
+                "with its exchange, is not built)")
+    if config.lora_adapters:
+        return "LoRA adapters are refused: the blocks carry no adapter pass"
+    if config.kv_cache_dtype == "int8":
+        return "the int8 KV cache is refused: the state cache has no 8-bit form"
+    return None
+
+
 class ModelRunner:
     def __init__(
         self,
@@ -133,6 +156,14 @@ class ModelRunner:
                     f"sp={config.sp} divides none of prefill_buckets="
                     f"{config.prefill_buckets}; SP prefill would never engage"
                 )
+        #: a model with recurrent layers (models/nemotron_h.py) keeps a
+        #: fixed-size state per DECODE SLOT beside the paged KV: its size
+        #: follows max_seqs, and it exists because the model has such layers
+        self.recurrent = bool(getattr(model, "recurrent", False))
+        if self.recurrent:
+            why = recurrent_refusal(config)
+            if why:
+                raise ValueError(f"model {type(model).__name__}: {why}")
         if mesh is None:
             if config.pp > 1 and config.sp > 1:
                 # composed stage x sequence (x head) mesh: sp between pp and
@@ -200,9 +231,16 @@ class ModelRunner:
             shardings = model.param_shardings(mesh)
             kv_sharding = model.kv_cache_sharding(mesh)
         self.params = jax.device_put(params, shardings)
-        self.kv_cache = jax.device_put(
-            model.init_kv_cache(config.num_pages, config.page_size), kv_sharding
-        )
+        cache = model.init_kv_cache(config.num_pages, config.page_size)
+        if self.recurrent:
+            # the second kind of cache rides the same donated bundle as the
+            # page pools, so every step function carries it unchanged
+            cache.update(model.init_state_cache(config.max_seqs))
+            kv_sharding = dict(kv_sharding, **model.state_cache_sharding(mesh))
+        self.kv_cache = jax.device_put(cache, kv_sharding)
+        #: the last decode window's extra device output (the held experts'
+        #: assignment counts of a model that routes), or None
+        self.window_aux = None
         self._replicated = NamedSharding(mesh, P())
         self._key = jax.random.key(0)
         # device-resident per-slot state, donated through every step:
@@ -361,7 +399,7 @@ class ModelRunner:
 
     # ---------------- jitted bodies ----------------
 
-    def _model_prefill(self, params, kv, tokens, positions, page_table, valid, last, embeds=None, emask=None, rope_pos=None, lora=None, lora_id=None):
+    def _model_prefill(self, params, kv, tokens, positions, page_table, valid, last, embeds=None, emask=None, rope_pos=None, lora=None, lora_id=None, state_slot=None):
         """model.prefill, or its GPipe-pipelined form when pp > 1 (which has
         no LoRA threading — the lora+pp combination is gated at init)."""
         if self.config.pp > 1:
@@ -373,6 +411,8 @@ class ModelRunner:
                 rope_positions=rope_pos,
             )
         lkw = {} if lora is None else dict(lora=lora, lora_id=lora_id)
+        if state_slot is not None:
+            lkw["state_slot"] = state_slot
         return self.model.prefill(
             params, kv, tokens, positions, page_table, valid, last,
             input_embeds=embeds, embeds_mask=emask, rope_positions=rope_pos, **lkw,
@@ -411,7 +451,7 @@ class ModelRunner:
         trace."""
         if mp is None:
             mp = self.config.max_pages_per_seq
-        bucket = ints.shape[0] - mp - 6 - MAX_EOS_IDS
+        bucket = ints.shape[0] - mp - 6 - MAX_EOS_IDS - int(self.recurrent)
         tokens = ints[:bucket]
         page_table = ints[bucket : bucket + mp]
         start_pos = ints[bucket + mp]
@@ -420,13 +460,16 @@ class ModelRunner:
         slot = ints[bucket + mp + 3]
         seed = ints[bucket + mp + 4]
         lora_id = ints[bucket + mp + 5]
-        eos_ids = ints[bucket + mp + 6 :]
+        eos_ids = ints[bucket + mp + 6 : bucket + mp + 6 + MAX_EOS_IDS]
         positions = start_pos + jnp.arange(bucket, dtype=jnp.int32)
         valid = jnp.arange(bucket) < n
         logits, kv = self._model_prefill(
             params, kv, tokens, positions, page_table, valid, n - 1,
             embeds=embeds, emask=emask, rope_pos=rope_pos,
             lora=lora, lora_id=lora_id,
+            # the row's last int: the decode slot whose state this chunk
+            # continues (every chunk's, not only the sampling one's)
+            state_slot=ints[-1] if self.recurrent else None,
         )
         tok, lp, slot_state = self._sample_one(
             logits, key, flts, top_k, slot, seed, start_pos + n - 1, slot_state,
@@ -486,7 +529,7 @@ class ModelRunner:
         if mp is None:
             mp = self.config.max_pages_per_seq
         N = ints.shape[0]
-        bucket = ints.shape[1] - mp - 6 - MAX_EOS_IDS
+        bucket = ints.shape[1] - mp - 6 - MAX_EOS_IDS - int(self.recurrent)
         tokens = ints[:, :bucket]
         page_tables = ints[:, bucket : bucket + mp]
         start_pos = ints[:, bucket + mp]
@@ -495,10 +538,12 @@ class ModelRunner:
         slots = ints[:, bucket + mp + 3]
         seeds = ints[:, bucket + mp + 4]
         lora_ids = ints[:, bucket + mp + 5]
-        eos_ids = ints[:, bucket + mp + 6 :]  # [N, MAX_EOS_IDS] V-padded
+        eos_ids = ints[:, bucket + mp + 6 : bucket + mp + 6 + MAX_EOS_IDS]  # V-padded
         positions = start_pos[:, None] + jnp.arange(bucket, dtype=jnp.int32)[None, :]
         valid = jnp.arange(bucket)[None, :] < n[:, None]
         lkw = {} if lora is None else dict(lora=lora, lora_ids=lora_ids)
+        if self.recurrent:
+            lkw = dict(state_slots=ints[:, -1])  # each lane's decode slot
         logits, kv = self.model.prefill_packed(
             params, kv, tokens, positions, page_tables, valid, n - 1, **lkw
         )
@@ -551,7 +596,7 @@ class ModelRunner:
         # lanes zero-pad into the trash page) — short packs keep their
         # narrow executable; only packs containing a deep sequence go wide
         mp = self.config.table_bucket_for(max(len(l[2]) for l in lanes))
-        ints = np.full((N, bucket + mp + 6 + MAX_EOS_IDS), V, np.int32)
+        ints = np.full((N, bucket + mp + 6 + MAX_EOS_IDS + int(self.recurrent)), V, np.int32)
         ints[:, :bucket] = 0
         ints[:, bucket : bucket + mp] = 0
         flts = np.zeros((6, N), np.float32)
@@ -570,6 +615,8 @@ class ModelRunner:
             ints[j, bucket + mp + 3] = slot if (is_final and slot >= 0) else self.config.max_seqs
             ints[j, bucket + mp + 4] = fold_seed(sampling.seed)
             ints[j, bucket + mp + 5] = lora_slot
+            if self.recurrent:
+                ints[j, -1] = slot  # state slot of EVERY chunk; -1 = the trash row
             want_eos = bool(
                 is_final and eos_ids and sampling.min_tokens >= 1
                 and not sampling.ignore_eos
@@ -599,6 +646,8 @@ class ModelRunner:
         for j in range(len(lanes), N):
             ints[j, bucket : bucket + mp + 6] = 0
             ints[j, bucket + mp + 3] = self.config.max_seqs
+            if self.recurrent:
+                ints[j, -1] = -1
         return ints, flts, want_extras, mp
 
     def prefill_chunk_batch(
@@ -702,6 +751,9 @@ class ModelRunner:
         temps, top_ps, min_ps = flts[0], flts[1], flts[2]
         pres, freq, reps = flts[3], flts[4], flts[5]
         keys = jax.random.split(key, num_steps)
+        if "moe_counts" in kv:
+            # the window's own count: the steps below add to it
+            kv = dict(kv, moe_counts=jnp.zeros_like(kv["moe_counts"]))
 
         def body(carry, k):
             kv, st, positions, act = carry
@@ -754,8 +806,9 @@ class ModelRunner:
         )
         all_toks = ys[0]
         lp = (ys[1], ys[2], ys[3]) if want_lp else None
-        # [num_steps, B] tokens (+ ([num_steps, B], [num_steps, B, K] x2) lp)
-        return all_toks, lp, kv, slot_state
+        # [num_steps, B] tokens (+ ([num_steps, B], [num_steps, B, K] x2) lp);
+        # last, what the model counted over the window (None: an empty output)
+        return all_toks, lp, kv, slot_state, kv.get("moe_counts")
 
     def _verify_impl(self, params, kv, ints, flts, key, draft_probs=None, lora=None):
         """Speculative verify step: every slot feeds its anchor token plus up
@@ -833,6 +886,7 @@ class ModelRunner:
         sampling=None,  # SamplingParams: penalties / min_p / seed (optional)
         eos_ids=None,  # request EOS ids (min_tokens device-side suppression)
         lora_slot: int = 0,  # adapter slot for this chunk (0 = base/zero)
+        state_slot: int = -1,  # recurrent models: the sequence's decode slot, on EVERY chunk
     ):
         """Run one prefill chunk.
 
@@ -847,7 +901,9 @@ class ModelRunner:
         # engine build them via table_bucket_for); its width picks the trace
         mp = len(page_table)
         V = self.model.config.vocab_size
-        ints = np.full(bucket + mp + 6 + MAX_EOS_IDS, V, np.int32)  # tail = eos pad
+        ints = np.full(bucket + mp + 6 + MAX_EOS_IDS + int(self.recurrent), V, np.int32)  # tail = eos pad
+        if self.recurrent:
+            ints[-1] = state_slot
         ints[:bucket] = 0
         ints[:n] = tokens
         ints[bucket : bucket + mp] = page_table[:mp]
@@ -1138,7 +1194,7 @@ class ModelRunner:
         )
         if want_extras:
             self._ensure_penalty_state()
-        toks, lp, self.kv_cache, self.slot_state = self._decode_window(
+        toks, lp, self.kv_cache, self.slot_state, self.window_aux = self._decode_window(
             self.params,
             self.kv_cache,
             self.slot_state,
@@ -1157,6 +1213,8 @@ class ModelRunner:
             if want_logprobs:
                 for a in lp:
                     a.copy_to_host_async()
+            if self.window_aux is not None:
+                self.window_aux.copy_to_host_async()
         except Exception:
             pass
         return (toks, lp) if want_logprobs else toks

@@ -56,11 +56,18 @@ class PageAllocator:
         page_size: int,
         event_sink: Optional[Callable[[KvCacheEvent], None]] = None,
         offload=None,  # Optional[HostKvPool]: host-DRAM tier (engine/offload.py)
+        match_prefix: bool = True,
     ):
         if num_pages < 2:
             raise ValueError("need at least 2 pages (page 0 is reserved)")
         self.num_pages = num_pages
         self.page_size = page_size
+        #: False for a model with recurrent layers: pages hold the attention
+        #: layers' KV only, and a hit on them without the recurrent state at
+        #: that position would be another model, silently. Every match is
+        #: withheld (and counted) until state snapshots exist.
+        self.match_prefix = match_prefix
+        self.prefix_refused = 0  # sequences whose cached prefix was withheld
         self.event_sink = event_sink
         self.offload = offload
         # off-device blocks (host DRAM *or* disk tier): meta survives until
@@ -200,6 +207,8 @@ class PageAllocator:
         granularity), without allocating. Disagg routing's prefix-hit estimate.
         ``salt`` = the request's LoRA adapter uid (0 = base): adapter-specific
         prefixes live under salted chained hashes and never cross-hit."""
+        if not self.match_prefix:
+            return 0
         ts = TokenSequence(prompt_tokens, self.page_size, salt=salt)
         hits = 0
         for block in ts.blocks:
@@ -248,6 +257,9 @@ class PageAllocator:
             if page is None:
                 break
             device_hits.append(page)
+        if device_hits and not self.match_prefix:
+            self.prefix_refused += 1
+            device_hits = []
 
         # 2. host-tier hits continuing the chain: each costs a fresh device
         # page + a host->device block copy, but no recompute

@@ -228,6 +228,9 @@ class _InFlight:
     # step-anatomy record of the dispatch that produced this entry: the
     # reconcile's device-wait/emission time attributes back to it
     rec: object = None
+    # window: what the model counted on the device over the window (the held
+    # experts' assignment counts), async copy started; None for most models
+    aux: object = None
 
 
 def _mm_chunk_overrides(req: EngineRequest, start: int, end: int):
@@ -498,6 +501,13 @@ class Scheduler:
         # lower-class lane); migrate_shed is the hosting worker's hook —
         # (request_id) -> bool — that hands the victim to a peer via live
         # migration instead of preempt+recompute when a servable peer exists
+        # expert routing, from the counts a routing model returns per decode
+        # window (runner.window_aux): assignments that landed on experts held
+        # here, all assignments routed (tokens x experts a token x expert
+        # blocks), and the last window's busiest held expert over their mean
+        self.moe_assignments = 0
+        self.moe_routed = 0
+        self.moe_busiest_over_mean = 0.0
         self.qos_preempted: dict[str, int] = {}
         self.qos_sheds = 0
         self.qos_shed_migrations = 0
@@ -1067,6 +1077,7 @@ class Scheduler:
         handoff = bool(req.kv_handoff_seq)
         if (
             self.prefix_fetcher is None
+            or not self.allocator.match_prefix  # pages without state: refused
             or not req.kv_holder_addr
             or req.kv_holder_blocks <= 0
         ):
@@ -1624,6 +1635,7 @@ class Scheduler:
                     top_k=s.top_k,
                     top_p=s.top_p,
                     slot=slot if is_last else -1,
+                    state_slot=slot,
                     sync=sync,
                     embeds=embeds,
                     embeds_mask=embeds_mask,
@@ -2252,6 +2264,7 @@ class Scheduler:
         toks_dev, lp = result if want_lp else (result, None)
         self.in_flight.append(_InFlight(
             kind="window", dev=toks_dev, seqs=snapshot, lp=lp, rec=rec,
+            aux=getattr(self.runner, "window_aux", None),
         ))
         return True
 
@@ -2305,6 +2318,8 @@ class Scheduler:
                     self._emit_token(seq, int(data[lane]), cached=cached, lp=step_lp)
                 )
         else:
+            if entry.aux is not None:
+                self._count_routing(entry)
             for seq, slot_idx, steps in entry.seqs:
                 if seq.finished:
                     continue  # EOS/cancel discovered earlier; zombie tokens
@@ -2318,6 +2333,24 @@ class Scheduler:
                     if seq.finished:
                         break
         return outputs
+
+    def _count_routing(self, entry: "_InFlight") -> None:
+        """One decode window's expert routing, from the device's counts."""
+        counts = np.asarray(entry.aux)
+        tokens = sum(steps for _, _, steps in entry.seqs)
+        self.moe_assignments += int(counts.sum())
+        self.moe_routed += tokens * self.runner.model.config.routed_per_token
+        mean = float(counts.mean())
+        self.moe_busiest_over_mean = float(counts.max()) / mean if mean else 0.0
+
+    @property
+    def state_slots_active(self) -> int:
+        """Decode slots whose recurrent state is live: a sequence holds its
+        slot's state from its first prefill chunk to its finish or preemption
+        (0 for a model with no recurrent layers)."""
+        if not getattr(self.runner, "recurrent", False):
+            return 0
+        return sum(s is not None for s in self.slots)
 
     # ---------------- helpers ----------------
 

@@ -13,6 +13,7 @@ Implementations:
 
 from __future__ import annotations
 
+import os
 import threading
 from pathlib import Path
 from typing import Optional, Protocol, Sequence
@@ -121,6 +122,11 @@ class HfTokenizer:
     def _tok(self):
         tok = getattr(self._local, "tok", None)
         if tok is None:
+            # a tokenizer needs no framework. Left to look for one,
+            # transformers imports torch, sklearn and scipy: 20-27 s between
+            # "engine ready" and the first request served (PERF.md, PR 29)
+            for framework in ("USE_TORCH", "USE_TF", "USE_FLAX"):
+                os.environ.setdefault(framework, "0")
             from transformers import AutoTokenizer
 
             tok = AutoTokenizer.from_pretrained(self._path)

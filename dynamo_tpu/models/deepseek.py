@@ -80,7 +80,6 @@ class DeepseekConfig:
     n_shared_experts: int = 2
     moe_intermediate_size: int = 1536
     first_k_dense_replace: int = 1
-    moe_capacity_factor: float = 2.0
     # routed-expert output scale (DeepSeek-V2 uses 16.0; V2-Lite 1.0)
     routed_scaling_factor: float = 1.0
     # False (DeepSeek default): top-k probs taken from the full softmax,
@@ -168,7 +167,6 @@ class DeepseekConfig:
             n_shared_experts=1,
             moe_intermediate_size=32,
             first_k_dense_replace=1,
-            moe_capacity_factor=8.0,  # exact (no drops) at test scale
             dtype=jnp.float32,
         )
         return replace(base, **overrides)
@@ -626,7 +624,6 @@ class DeepseekModel:
                 lp["w_up"],
                 lp["w_down"],
                 num_experts_per_tok=c.num_experts_per_tok,
-                capacity_factor=c.moe_capacity_factor,
                 renormalize=c.norm_topk_prob,
             )
             hidden = hidden + shared + c.routed_scaling_factor * routed

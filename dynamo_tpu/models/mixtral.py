@@ -1,7 +1,7 @@
 """Mixtral-family MoE model: Llama attention + sparse top-k expert MLP.
 
 Reuses the paged-attention layer machinery from LlamaModel; replaces the dense
-MLP with the GShard-style MoE block (dynamo_tpu/ops/moe.py). Expert weights
+MLP with the dropless MoE dispatch (dynamo_tpu/ops/moe.py). Expert weights
 carry a leading [E] axis sharded over the mesh's "ep" axis; everything else
 follows the Llama TP rules. Covers the reference's DeepSeek-V3/Mixtral MoE
 target (BASELINE.md config 4; the reference itself delegates MoE to engines,
@@ -26,7 +26,6 @@ from dynamo_tpu.quant import qlinear, quantize_shardings_int8
 class MixtralConfig(LlamaConfig):
     num_experts: int = 8
     num_experts_per_tok: int = 2
-    moe_capacity_factor: float = 2.0
 
     @classmethod
     def from_hf_config(cls, d: dict) -> "MixtralConfig":
@@ -48,7 +47,6 @@ class MixtralConfig(LlamaConfig):
             **{f: getattr(tiny, f) for f in tiny.__dataclass_fields__},
             num_experts=4,
             num_experts_per_tok=2,
-            moe_capacity_factor=8.0,  # exact (no drops) at test scale
         )
         return replace(base, **overrides)
 
@@ -136,7 +134,6 @@ class MixtralModel(LlamaModel):
             lp["w_up"],
             lp["w_down"],
             num_experts_per_tok=c.num_experts_per_tok,
-            capacity_factor=c.moe_capacity_factor,
         )
         hidden = hidden + moe_out
         return hidden, k_pool, v_pool

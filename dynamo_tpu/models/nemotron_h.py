@@ -1,0 +1,513 @@
+"""NemotronH (`NemotronHForCausalLM`): a hybrid decoder whose blocks are NOT one
+scanned stack. `hybrid_override_pattern` gives each block ONE mixer:
+
+  `M`  Mamba-2: a state-space layer with a fixed-size state per sequence
+       (ops/ssm.py; the one-token update is a kernel, ops/pallas/ssm_update.py)
+  `*`  attention: grouped-query, causal, NO rotary embedding, on the paged KV
+       pool and the same kernels as the Llama family (ops/attention.py)
+  `E`  latent experts: sigmoid router over ALL experts routed over, experts in
+       a latent width, `relu(x)^2`, one shared expert (ops/moe.py)
+
+and every block is `h = h + mixer(RMSNorm(h))` on a residual in the model's
+dtype; then `norm_f` and an untied head.
+
+Two caches. The paged KV pool holds the attention blocks only (flat over them,
+as models/llama.py lays it out). Beside it every Mamba block keeps, per DECODE
+SLOT and not per page, a float32 state [H, P, N] and the last `conv_kernel - 1`
+inputs of its convolution: rows of two flat arrays, `slot` of block m at row
+`m * (max_seqs + 1) + slot`, the last row of each block being a trash row for
+padding lanes and slots that are not live. A chunk that starts at position 0
+starts from zeros, so a slot needs no clearing between sequences; a later chunk
+and every decode step continue from what the row holds. The engine gives the
+slot (`state_slot(s)`); nothing here knows about requests.
+
+An expert layer may hold a share of the experts (`n_routed_experts` held, from
+`moe_expert_offset`, of `moe_routed_over`): see ops/moe.py.
+"""
+
+from __future__ import annotations
+
+from dataclasses import dataclass, replace
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from dynamo_tpu.models.llama import parse_dtype
+from dynamo_tpu.ops.attention import (
+    dispatch_paged_decode_attention,
+    dispatch_paged_prefill_attention,
+    scatter_kv,
+)
+from dynamo_tpu.ops.moe import grouped_matmul, moe_dispatch, relu2, sigmoid_topk_routing
+from dynamo_tpu.ops.norms import rms_norm
+from dynamo_tpu.ops.ssm import causal_conv, ssd_chunked, ssm_state_update
+
+
+@dataclass(frozen=True)
+class NemotronHConfig:
+    vocab_size: int = 131072
+    hidden_size: int = 4096
+    pattern: str = "MEMEMEM*EME"  # hybrid_override_pattern
+    # attention blocks
+    num_heads: int = 32
+    num_kv_heads: int = 2
+    head_dim: int = 128
+    # Mamba-2 blocks
+    mamba_num_heads: int = 128
+    mamba_head_dim: int = 64
+    ssm_state_size: int = 128
+    n_groups: int = 8
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    # expert blocks: experts HELD here, of how many routed over, from which id
+    n_routed_experts: int = 512
+    moe_routed_over: int = 512
+    moe_expert_offset: int = 0
+    num_experts_per_tok: int = 22
+    moe_latent_size: int = 1024
+    moe_intermediate_size: int = 2688
+    moe_shared_expert_intermediate_size: int = 5376
+    routed_scaling_factor: float = 5.0
+    rms_norm_eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+
+    @property
+    def num_layers(self) -> int:
+        return len(self.pattern)
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_num_heads * self.mamba_head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        return self.mamba_inner + 2 * self.n_groups * self.ssm_state_size
+
+    def count(self, kind: str) -> int:
+        return self.pattern.count(kind)
+
+    @property
+    def routed_per_token(self) -> int:
+        """Expert assignments one token makes through the whole model."""
+        return self.num_experts_per_tok * self.count("E")
+
+    @classmethod
+    def from_hf_config(cls, d: dict) -> "NemotronHConfig":
+        pattern = d["hybrid_override_pattern"]
+        if len(pattern) != d["num_hidden_layers"] or set(pattern) - set("ME*"):
+            raise ValueError(
+                f"hybrid_override_pattern {pattern!r} must name num_hidden_layers="
+                f"{d['num_hidden_layers']} blocks, each M, E or *"
+            )
+        unsupported = {
+            "n_group": 1, "topk_group": 1, "norm_topk_prob": True,
+            "mlp_hidden_act": "relu2", "n_shared_experts": 1,
+            "use_conv_bias": True, "mamba_proj_bias": False,
+            "attention_bias": False, "mlp_bias": False,
+        }
+        for key, want in unsupported.items():
+            if d.get(key, want) != want:
+                raise ValueError(f"nemotron_h: {key}={d[key]!r} is not supported (only {want!r})")
+        return cls(
+            vocab_size=d["vocab_size"],
+            hidden_size=d["hidden_size"],
+            pattern=pattern,
+            num_heads=d["num_attention_heads"],
+            num_kv_heads=d["num_key_value_heads"],
+            head_dim=d.get("head_dim") or d["hidden_size"] // d["num_attention_heads"],
+            mamba_num_heads=d["mamba_num_heads"],
+            mamba_head_dim=d["mamba_head_dim"],
+            ssm_state_size=d["ssm_state_size"],
+            n_groups=d["n_groups"],
+            conv_kernel=d["conv_kernel"],
+            chunk_size=d.get("chunk_size", 128),
+            n_routed_experts=d["n_routed_experts"],
+            moe_routed_over=d.get("moe_routed_over", d["n_routed_experts"]),
+            moe_expert_offset=d.get("moe_expert_offset", 0),
+            num_experts_per_tok=d["num_experts_per_tok"],
+            moe_latent_size=d["moe_latent_size"],
+            moe_intermediate_size=d["moe_intermediate_size"],
+            moe_shared_expert_intermediate_size=d["moe_shared_expert_intermediate_size"],
+            routed_scaling_factor=float(d.get("routed_scaling_factor", 1.0)),
+            rms_norm_eps=d.get("layer_norm_epsilon", d.get("norm_eps", 1e-5)),
+            dtype=parse_dtype(d.get("torch_dtype") or "bfloat16"),
+        )
+
+    @classmethod
+    def tiny(cls, **overrides) -> "NemotronHConfig":
+        """Small config for tests: every kind of block, a share of the experts."""
+        if "dtype" in overrides:
+            overrides["dtype"] = parse_dtype(overrides["dtype"])
+        base = cls(
+            vocab_size=256, hidden_size=64, pattern="ME*E",
+            num_heads=4, num_kv_heads=2, head_dim=16,
+            mamba_num_heads=8, mamba_head_dim=8, ssm_state_size=16, n_groups=2,
+            conv_kernel=4, chunk_size=16,
+            n_routed_experts=4, moe_routed_over=8, moe_expert_offset=0,
+            num_experts_per_tok=3, moe_latent_size=32, moe_intermediate_size=48,
+            moe_shared_expert_intermediate_size=96, routed_scaling_factor=2.5,
+            dtype=jnp.float32,
+        )
+        return replace(base, **overrides)
+
+
+class NemotronHModel:
+    """Stateless forward functions over a params pytree (models/llama.py's
+    contract, plus the per-slot state: `state_slot(s)` on the prefills)."""
+
+    #: the engine keeps a per-slot state cache beside the paged KV, matches no
+    #: prefix for this model, and refuses what would need the state copied
+    recurrent = True
+    SUPPORTS_LORA = False
+    SUPPORTS_KV_INT8 = False
+
+    def __init__(self, config: NemotronHConfig):
+        self.config = config
+        self.attn_mesh = None  # one chip: expert parallelism across chips is not built
+
+    # ---------------- params ----------------
+
+    def init_params(self, rng: jax.Array) -> dict:
+        c = self.config
+        keys = iter(jax.random.split(rng, 16 * c.num_layers + 4))
+
+        def dense(shape, scale_axis=0, dtype=None):
+            scale = 1.0 / jnp.sqrt(jnp.float32(shape[scale_axis]))
+            w = jax.random.normal(next(keys), shape, jnp.float32) * scale
+            return w.astype(dtype or c.dtype)
+
+        def small(shape):
+            return jax.random.normal(next(keys), shape, jnp.float32) * 0.5
+
+        D, H = c.hidden_size, c.mamba_num_heads
+        inner, cd = c.mamba_inner, c.conv_dim
+        Z, F, Fs = c.moe_latent_size, c.moe_intermediate_size, c.moe_shared_expert_intermediate_size
+        blocks = []
+        for kind in c.pattern:
+            bp = {"norm": jnp.ones((D,), c.dtype)}
+            if kind == "M":
+                bp.update(
+                    in_proj=dense((D, inner + cd + H)),
+                    conv_w=small((c.conv_kernel, cd)),
+                    conv_b=small((cd,)),
+                    dt_bias=small((H,)),
+                    A_log=small((H,)),
+                    D=small((H,)) + 1.0,
+                    mixer_norm=jnp.ones((inner,), c.dtype),
+                    out_proj=dense((inner, D)),
+                )
+            elif kind == "*":
+                bp.update(
+                    wq=dense((D, c.num_heads * c.head_dim)),
+                    wk=dense((D, c.num_kv_heads * c.head_dim)),
+                    wv=dense((D, c.num_kv_heads * c.head_dim)),
+                    wo=dense((c.num_heads * c.head_dim, D)),
+                )
+            else:
+                bp.update(
+                    router=dense((D, c.moe_routed_over), dtype=jnp.float32),
+                    router_bias=small((c.moe_routed_over,)) * 0.1,
+                    lat_down=dense((D, Z)),
+                    lat_up=dense((Z, D)),
+                    w1=dense((c.n_routed_experts, Z, F), 1),
+                    w2=dense((c.n_routed_experts, F, Z), 1),
+                    shared_up=dense((D, Fs)),
+                    shared_down=dense((Fs, D)),
+                )
+            blocks.append(bp)
+        return {
+            "embed": dense((c.vocab_size, D), 1),
+            "blocks": blocks,
+            "final_norm": jnp.ones((D,), c.dtype),
+            "lm_head": dense((c.vocab_size, D), 1),
+        }
+
+    def param_shardings(self, mesh: Mesh, tp_axis: str = "tp") -> dict:
+        shapes = jax.eval_shape(self.init_params, jax.random.key(0))
+        return jax.tree.map(lambda _: NamedSharding(mesh, P()), shapes)
+
+    # ---------------- the paged KV pool (attention blocks only) ----------------
+
+    kv_folded = False
+
+    def kv_cache_shape(self, num_pages: int, page_size: int) -> tuple[int, ...]:
+        c = self.config
+        return (c.count("*") * num_pages, page_size, c.num_kv_heads, c.head_dim)
+
+    def init_kv_cache(self, num_pages: int, page_size: int) -> dict:
+        shape = self.kv_cache_shape(num_pages, page_size)
+        return {"k": jnp.zeros(shape, self.config.dtype), "v": jnp.zeros(shape, self.config.dtype)}
+
+    def kv_page_bytes(self, page_size: int) -> int:
+        c = self.config
+        return (2 * c.count("*") * page_size * c.num_kv_heads * c.head_dim
+                * jnp.dtype(c.dtype).itemsize)
+
+    def kv_cache_sharding(self, mesh: Mesh, tp_axis: str = "tp") -> dict:
+        ns = NamedSharding(mesh, P())
+        return {"k": ns, "v": ns}
+
+    # ---------------- the per-slot state cache (Mamba blocks) ----------------
+
+    def init_state_cache(self, max_seqs: int) -> dict:
+        """The leaves the engine keeps beside the KV pools, in the same
+        donated bundle: `ssm` and `conv`, a row per (Mamba block, slot) plus
+        each block's trash row, and `moe_counts`, where decode steps add the
+        assignments each held expert received (the engine zeroes it at the
+        start of a decode window and reads it at the end)."""
+        c = self.config
+        rows = c.count("M") * (max_seqs + 1)
+        return {
+            "ssm": jnp.zeros(
+                (rows, c.mamba_num_heads, c.mamba_head_dim, c.ssm_state_size), jnp.float32
+            ),
+            "conv": jnp.zeros((rows, c.conv_kernel - 1, c.conv_dim), c.dtype),
+            "moe_counts": jnp.zeros((c.n_routed_experts,), jnp.int32),
+        }
+
+    def state_cache_sharding(self, mesh: Mesh) -> dict:
+        ns = NamedSharding(mesh, P())
+        return {"ssm": ns, "conv": ns, "moe_counts": ns}
+
+    def state_bytes(self, max_seqs: int) -> int:
+        """Device bytes of the state cache at this many slots."""
+        c = self.config
+        per_row = (c.mamba_num_heads * c.mamba_head_dim * c.ssm_state_size * 4
+                   + (c.conv_kernel - 1) * c.conv_dim * jnp.dtype(c.dtype).itemsize)
+        return c.count("M") * (max_seqs + 1) * per_row
+
+    # ---------------- blocks ----------------
+
+    def _split_proj(self, proj):
+        c = self.config
+        z = proj[..., : c.mamba_inner]
+        xbc = proj[..., c.mamba_inner : c.mamba_inner + c.conv_dim]
+        dt = proj[..., c.mamba_inner + c.conv_dim :]
+        return z, xbc, dt
+
+    def _split_xbc(self, xbc):
+        """silu(conv) [..., conv_dim] float32 -> x [..., H, P], B, C [..., G, N]."""
+        c = self.config
+        gn = c.n_groups * c.ssm_state_size
+        lead = xbc.shape[:-1]
+        x = xbc[..., : c.mamba_inner].reshape(*lead, c.mamba_num_heads, c.mamba_head_dim)
+        B = xbc[..., c.mamba_inner : c.mamba_inner + gn].reshape(*lead, c.n_groups, c.ssm_state_size)
+        C = xbc[..., c.mamba_inner + gn :].reshape(*lead, c.n_groups, c.ssm_state_size)
+        return x, B, C
+
+    def _mamba_out(self, bp, y, z):
+        """`GroupRMSNorm(y * silu(z)) * w` (the gate BEFORE the norm, one norm
+        per group of inner / n_groups), then the output projection."""
+        c = self.config
+        g = y * jax.nn.silu(z.astype(jnp.float32))
+        gg = g.reshape(*g.shape[:-1], c.n_groups, c.mamba_inner // c.n_groups)
+        gg = gg * jax.lax.rsqrt(jnp.mean(gg * gg, axis=-1, keepdims=True) + c.rms_norm_eps)
+        g = gg.reshape(g.shape) * bp["mixer_norm"].astype(jnp.float32)
+        return g.astype(c.dtype) @ bp["out_proj"]
+
+    def _mamba_prefill(self, bp, h, cache, rows, fresh, valid):
+        """h [L, T, D]; rows [L] this block's state row per lane; fresh [L]:
+        the lane starts its sequence; valid [L, T]."""
+        c = self.config
+        with jax.named_scope("ssm"):
+            z, xbc, dt = self._split_proj(h @ bp["in_proj"])
+            window = jnp.where(fresh[:, None, None], 0, cache["conv"][rows])
+            n_valid = jnp.sum(valid, axis=1).astype(jnp.int32)
+            xbc, window = causal_conv(xbc, window, bp["conv_w"], bp["conv_b"], n_valid)
+            x, B, C = self._split_xbc(jax.nn.silu(xbc))
+            # padding: dt = 0 is the identity on the state
+            dt = jax.nn.softplus(dt.astype(jnp.float32) + bp["dt_bias"]) * valid[..., None]
+            state = jnp.where(fresh[:, None, None, None], 0.0, cache["ssm"][rows])
+            y, state = ssd_chunked(
+                x, dt, -jnp.exp(bp["A_log"]), B, C, bp["D"], state, c.chunk_size
+            )
+            cache = dict(
+                cache,
+                ssm=cache["ssm"].at[rows].set(state),
+                conv=cache["conv"].at[rows].set(window),
+            )
+            return self._mamba_out(bp, y.reshape(*y.shape[:2], c.mamba_inner), z), cache
+
+    def _mamba_decode(self, bp, h, cache, base, active):
+        """h [B, D]; batch row b's state is row base + b; rows that are not
+        active leave state and window as they were."""
+        c = self.config
+        nb = h.shape[0]
+        with jax.named_scope("ssm"):
+            z, xbc, dt = self._split_proj(h @ bp["in_proj"])
+            mine = base + jnp.arange(nb)
+            xbc, window = causal_conv(
+                xbc[:, None, :], cache["conv"][mine], bp["conv_w"], bp["conv_b"],
+                active.astype(jnp.int32),
+            )
+            x, B, C = self._split_xbc(jax.nn.silu(xbc[:, 0]))
+            dt = jax.nn.softplus(dt.astype(jnp.float32) + bp["dt_bias"])
+            trash = base + cache["ssm"].shape[0] // c.count("M") - 1
+            y, ssm = ssm_state_update(
+                cache["ssm"], jnp.where(active, mine, trash), x, dt,
+                -jnp.exp(bp["A_log"]), B, C, bp["D"], active,
+            )
+            cache = dict(cache, ssm=ssm, conv=cache["conv"].at[mine].set(window))
+            return self._mamba_out(bp, y.reshape(nb, c.mamba_inner), z), cache
+
+    def _attention(self, bp, h, kv, flat_phys, offsets, attn_fn):
+        """h [T, D]. No rotary embedding: the published model applies none."""
+        c = self.config
+        T = h.shape[0]
+        with jax.named_scope("attn"):
+            q = (h @ bp["wq"]).reshape(T, c.num_heads, c.head_dim)
+            k = (h @ bp["wk"]).reshape(T, c.num_kv_heads, c.head_dim)
+            v = (h @ bp["wv"]).reshape(T, c.num_kv_heads, c.head_dim)
+            k_pool, v_pool = scatter_kv(kv["k"], kv["v"], k, v, flat_phys, offsets)
+            attn = attn_fn(q, k_pool, v_pool)
+            return attn.reshape(T, -1) @ bp["wo"], dict(kv, k=k_pool, v=v_pool)
+
+    def _experts(self, bp, h, count_rows=None):
+        """h [T, D] -> (out [T, D], the held experts' assignment counts over
+        the rows of `count_rows` (all rows when None))."""
+        c = self.config
+        with jax.named_scope("moe"):
+            # the router: float32 on the full hidden state, at full precision
+            # (a bf16 pass would move the choice of expert, not just a weight)
+            logits = jnp.dot(
+                h.astype(jnp.float32), bp["router"], precision=jax.lax.Precision.HIGHEST
+            )
+            weights, idx = sigmoid_topk_routing(
+                logits, bp["router_bias"], c.num_experts_per_tok, c.routed_scaling_factor
+            )
+            if count_rows is not None:
+                idx = jnp.where(count_rows[:, None], idx, -1)  # held nowhere
+
+            def ffn(rows, group_sizes):
+                mid = relu2(grouped_matmul(rows, bp["w1"], group_sizes))
+                return grouped_matmul(mid, bp["w2"], group_sizes)
+
+            routed, counts = moe_dispatch(
+                h @ bp["lat_down"], weights, idx, ffn,
+                num_held=c.n_routed_experts, offset=c.moe_expert_offset,
+            )
+            out = routed.astype(c.dtype) @ bp["lat_up"]
+            return out + relu2(h @ bp["shared_up"]) @ bp["shared_down"], counts
+
+    def _unembed(self, params: dict, hidden: jnp.ndarray) -> jnp.ndarray:
+        with jax.named_scope("lm_head"):
+            h = rms_norm(hidden, params["final_norm"], self.config.rms_norm_eps)
+            return jax.lax.dot_general(
+                h, params["lm_head"], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+
+    # ---------------- forward ----------------
+
+    def _packed_forward(self, params, cache, tokens, positions, page_tables, valid, state_slots):
+        """N lanes (chunks of N different sequences) through every block.
+        Returns (hidden [N*T, D], cache)."""
+        c = self.config
+        N, T = tokens.shape
+        page_size = cache["k"].shape[1]
+        num_pages = cache["k"].shape[0] // max(1, c.count("*"))
+        slot_rows = cache["ssm"].shape[0] // max(1, c.count("M"))
+        lane = jnp.arange(N)
+        phys = jnp.where(valid, page_tables[lane[:, None], positions // page_size], 0)
+        offsets = jnp.where(valid, positions % page_size, 0).reshape(N * T)
+        fresh = positions[:, 0] == 0
+        # a slot the engine does not name (padding lanes, warm-up) is the trash row
+        slots = jnp.where((state_slots >= 0) & (state_slots < slot_rows - 1),
+                          state_slots, slot_rows - 1)
+
+        hidden = params["embed"][tokens.reshape(N * T)].astype(c.dtype)
+        m = a = 0
+        for kind, bp in zip(c.pattern, params["blocks"]):
+            h = rms_norm(hidden, bp["norm"], c.rms_norm_eps)
+            if kind == "M":
+                out, cache = self._mamba_prefill(
+                    bp, h.reshape(N, T, -1), cache, m * slot_rows + slots, fresh, valid
+                )
+                out = out.reshape(N * T, -1)
+                m += 1
+            elif kind == "*":
+                off = a * num_pages
+
+                def attn_fn(q, k_pool, v_pool, off=off):
+                    qs = q.reshape(N, T, *q.shape[1:])
+                    return jnp.concatenate([
+                        dispatch_paged_prefill_attention(
+                            qs[j], k_pool, v_pool, off + page_tables[j], positions[j],
+                            mesh=self.attn_mesh,
+                        )
+                        for j in range(N)
+                    ], axis=0)
+
+                out, cache = self._attention(
+                    bp, h, cache, off + phys.reshape(N * T), offsets, attn_fn
+                )
+                a += 1
+            else:
+                out, _ = self._experts(bp, h)
+            hidden = hidden + out
+        return hidden, cache
+
+    def prefill_packed(self, params, kv_cache, tokens, positions, page_tables, valid,
+                       last_idx, state_slots=None):
+        """models/llama.py's `prefill_packed`, plus `state_slots` [N]: the
+        decode slot whose state each lane continues (or, from position 0,
+        starts). Returns (logits [N, V], cache)."""
+        N, T = tokens.shape
+        if state_slots is None:
+            state_slots = jnp.full((N,), -1, jnp.int32)
+        hidden, kv_cache = self._packed_forward(
+            params, kv_cache, tokens, positions, page_tables, valid, state_slots
+        )
+        rows = hidden[jnp.arange(N) * T + last_idx]
+        return self._unembed(params, rows), kv_cache
+
+    def prefill(self, params, kv_cache, tokens, positions, page_table, valid, last_idx,
+                input_embeds=None, embeds_mask=None, rope_positions=None, state_slot=None):
+        """One chunk of one sequence: a pack of one lane."""
+        if input_embeds is not None or rope_positions is not None:
+            raise ValueError("nemotron_h is text-only")
+        slots = None if state_slot is None else jnp.reshape(state_slot, (1,))
+        logits, kv_cache = self.prefill_packed(
+            params, kv_cache, tokens[None], positions[None], page_table[None],
+            valid[None], jnp.reshape(last_idx, (1,)), state_slots=slots,
+        )
+        return logits[0], kv_cache
+
+    def decode(self, params, kv_cache, tokens, positions, page_tables, active,
+               rope_deltas=None):
+        """One decode step for the whole batch; batch row b is decode slot b.
+        Returns (logits [B, V], cache)."""
+        c = self.config
+        cache = kv_cache
+        page_size = cache["k"].shape[1]
+        num_pages = cache["k"].shape[0] // max(1, c.count("*"))
+        slot_rows = cache["ssm"].shape[0] // max(1, c.count("M"))
+        B = tokens.shape[0]
+        phys = jnp.where(active, page_tables[jnp.arange(B), positions // page_size], 0)
+        offsets = jnp.where(active, positions % page_size, 0)
+
+        hidden = params["embed"][tokens].astype(c.dtype)
+        counts = cache["moe_counts"]
+        m = a = 0
+        for kind, bp in zip(c.pattern, params["blocks"]):
+            h = rms_norm(hidden, bp["norm"], c.rms_norm_eps)
+            if kind == "M":
+                out, cache = self._mamba_decode(bp, h, cache, m * slot_rows, active)
+                m += 1
+            elif kind == "*":
+                off = a * num_pages
+
+                def attn_fn(q, k_pool, v_pool, off=off):
+                    return dispatch_paged_decode_attention(
+                        q, k_pool, v_pool, off + page_tables, positions, mesh=self.attn_mesh
+                    )
+
+                out, cache = self._attention(bp, h, cache, off + phys, offsets, attn_fn)
+                a += 1
+            else:
+                out, n = self._experts(bp, h, count_rows=active)
+                counts = counts + n
+            hidden = hidden + out
+        return self._unembed(params, hidden), dict(cache, moe_counts=counts)

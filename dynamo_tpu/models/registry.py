@@ -1,7 +1,8 @@
 """Model registry: model_id -> (model, params).
 
 Supported ids:
-  - ``tiny`` / ``tiny:<json-overrides>``: random-weight test model
+  - ``tiny`` / ``tiny:<json-overrides>``: random-weight test model (and
+    ``tiny-moe``, ``tiny-mla``, ``tiny-vl``, ``tiny-hybrid`` likewise)
   - a local HuggingFace checkpoint directory (config.json [+ safetensors])
 
 The reference resolves models from HF repos via its model-deployment-card
@@ -34,6 +35,35 @@ log = get_logger("models.registry")
 _cache: tuple | None = None  # ((model_id, seed), (model_cls, config, params))
 
 
+#: checkpoint architectures (config.json `architectures[0]`, by exact name) ->
+#: (module, config class, model class, loader in models/loader.py). A
+#: checkpoint that names none is read as a Llama.
+ARCHITECTURES = {
+    "LlamaForCausalLM": ("llama", "LlamaConfig", "LlamaModel", "load_llama_weights"),
+    "MistralForCausalLM": ("llama", "LlamaConfig", "LlamaModel", "load_llama_weights"),
+    "Qwen2ForCausalLM": ("llama", "LlamaConfig", "LlamaModel", "load_llama_weights"),
+    "MixtralForCausalLM": ("mixtral", "MixtralConfig", "MixtralModel", "load_mixtral_weights"),
+    "DeepseekV2ForCausalLM": ("deepseek", "DeepseekConfig", "DeepseekModel", "load_deepseek_weights"),
+    "DeepseekV3ForCausalLM": ("deepseek", "DeepseekConfig", "DeepseekModel", "load_deepseek_weights"),
+    "Qwen2VLForConditionalGeneration": (
+        "qwen2_vl", "Qwen2VLConfig", "Qwen2VLModel", "load_qwen2_vl_weights"),
+    "NemotronHForCausalLM": (
+        "nemotron_h", "NemotronHConfig", "NemotronHModel", "load_nemotron_h_weights"),
+}
+
+
+def _resolve(entry: tuple):
+    """(config class, model class, loader) of an ARCHITECTURES entry; the
+    model's module is imported only when a checkpoint asks for it."""
+    import importlib
+
+    module, config_cls, model_cls, loader = entry
+    mod = importlib.import_module(f"dynamo_tpu.models.{module}")
+    loaders = importlib.import_module("dynamo_tpu.models.loader")
+    return getattr(mod, config_cls), getattr(mod, model_cls), getattr(loaders, loader)
+
+
+
 def is_tiny_family(model_id) -> bool:
     """Exactly the synthetic tiny-family forms this registry special-cases —
     NOT any path that merely starts with "tiny": a checkpoint directory named
@@ -42,7 +72,7 @@ def is_tiny_family(model_id) -> bool:
     if model_id is None:
         return True
     s = str(model_id)
-    for fam in ("tiny", "tiny-moe", "tiny-mla", "tiny-vl"):
+    for fam in ("tiny", "tiny-moe", "tiny-mla", "tiny-vl", "tiny-hybrid"):
         if s == fam or s.startswith(fam + ":"):
             return True
     return False
@@ -90,6 +120,11 @@ def _load_model_uncached(model_id: str, seed: int = 0, quantize: str | None = No
 
     def with_quant(cfg):
         replace = {}
+        fields = getattr(cfg, "__dataclass_fields__", {})
+        if quantize and "quantize" not in fields:
+            raise ValueError(
+                f"quantize={quantize!r} is not supported by {type(cfg).__name__}"
+            )
         if quantize:
             replace["quantize"] = quantize
         if kv_cache_dtype and "kv_cache_dtype" in getattr(
@@ -123,6 +158,15 @@ def _load_model_uncached(model_id: str, seed: int = 0, quantize: str | None = No
         jax.block_until_ready(params)
         return model, params
 
+    if model_id is not None and (model_id == "tiny-hybrid" or model_id.startswith("tiny-hybrid:")):
+        from dynamo_tpu.models.nemotron_h import NemotronHConfig, NemotronHModel
+
+        overrides = json.loads(model_id.split(":", 1)[1]) if ":" in model_id else {}
+        model = NemotronHModel(with_quant(NemotronHConfig.tiny(**overrides)))
+        params = jax.jit(model.init_params)(jax.random.key(seed))
+        jax.block_until_ready(params)
+        return model, params
+
     if model_id is not None and (model_id == "tiny-vl" or model_id.startswith("tiny-vl:")):
         from dynamo_tpu.models.qwen2_vl import Qwen2VLConfig, Qwen2VLModel
 
@@ -148,34 +192,14 @@ def _load_model_uncached(model_id: str, seed: int = 0, quantize: str | None = No
     if path.is_dir() and (path / "config.json").exists():
         hf_cfg = json.loads((path / "config.json").read_text())
         arch = (hf_cfg.get("architectures") or ["LlamaForCausalLM"])[0]
-        if "Mixtral" in arch:
-            from dynamo_tpu.models.loader import load_mixtral_weights
-            from dynamo_tpu.models.mixtral import MixtralConfig, MixtralModel
-
-            cfg = with_quant(MixtralConfig.from_hf_config(hf_cfg))
-            model = MixtralModel(cfg)
-            return model, load_mixtral_weights(model, path)
-        if "Deepseek" in arch:
-            from dynamo_tpu.models.deepseek import DeepseekConfig, DeepseekModel
-            from dynamo_tpu.models.loader import load_deepseek_weights
-
-            cfg = with_quant(DeepseekConfig.from_hf_config(hf_cfg))
-            model = DeepseekModel(cfg)
-            return model, load_deepseek_weights(model, path)
-        if "Qwen2VL" in arch or hf_cfg.get("model_type") == "qwen2_vl":
-            from dynamo_tpu.models.loader import load_qwen2_vl_weights
-            from dynamo_tpu.models.qwen2_vl import Qwen2VLConfig, Qwen2VLModel
-
-            cfg = with_quant(Qwen2VLConfig.from_hf_config(hf_cfg))
-            model = Qwen2VLModel(cfg)
-            return model, load_qwen2_vl_weights(model, path)
-        if "Llama" not in arch and "Qwen" not in arch:
-            raise ValueError(f"unsupported architecture {arch}")
-        cfg = with_quant(LlamaConfig.from_hf_config(hf_cfg))
-        model = LlamaModel(cfg)
-        from dynamo_tpu.models.loader import load_llama_weights
-
-        params = load_llama_weights(model, path)
-        return model, params
+        entry = ARCHITECTURES.get(arch)
+        if entry is None:
+            raise ValueError(
+                f"unsupported architecture {arch!r}; supported: {sorted(ARCHITECTURES)}"
+            )
+        config_cls, model_cls, loader = _resolve(entry)
+        cfg = with_quant(config_cls.from_hf_config(hf_cfg))
+        model = model_cls(cfg)
+        return model, loader(model, path)
 
     raise ValueError(f"unknown model id {model_id!r} (not 'tiny' and not a local checkpoint dir)")

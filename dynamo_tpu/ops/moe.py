@@ -1,22 +1,36 @@
-"""Sparse Mixture-of-Experts block (Mixtral-style top-k routing).
+"""Sparse Mixture-of-Experts: routing, and ONE dispatch that drops nothing.
 
-TPU-first formulation: GShard-style capacity-based dispatch — one-hot dispatch/
-combine einsums turn token->expert routing into dense batched matmuls (MXU
-friendly, static shapes), and the expert axis shards over the mesh's "ep"
-axis so each chip holds E/ep experts (reference has no MoE of its own,
-SURVEY.md §2.8 — only engine-delegated; this is the native design).
+The dispatch sorts the (token, choice) assignments by expert and runs the
+expert FFN as a grouped matrix product over the experts HELD here
+(``jax.lax.ragged_dot``: rows of one group meet one expert's matrix; on the
+TPU it lowers to one kernel, asked of the chip's compiler for the shapes of
+``tools/tpu_compile.py``). Shapes are static at ``T * K`` rows, the worst
+case, so no assignment is ever dropped whatever the routing: there is no
+capacity and no factor to tune.
 
-capacity = ceil(T * K / E * capacity_factor); tokens beyond an expert's
-capacity are dropped (their weight is renormalized away). For exactness in
-tests use capacity_factor large enough that nothing drops.
+An expert layer may hold a SHARE of the experts it routes over (one chip of an
+expert-parallel deployment): the router scores all of them, the weights are
+normalised over everything a token chose, and this chip computes the part of
+the sum that its own experts give. Assignments to experts held elsewhere sort
+past the last group and contribute nothing here; nothing stands in for them.
+The expert axis of the banks still shards over a mesh's "ep" axis under GSPMD
+(Mixtral's test), where the compiler gathers what the product needs.
 """
 
 from __future__ import annotations
 
+from typing import Callable
+
 import jax
 import jax.numpy as jnp
 
-from dynamo_tpu.quant import qlinear_expert
+from dynamo_tpu.quant import QuantizedLinear
+
+
+def relu2(x: jnp.ndarray) -> jnp.ndarray:
+    """``relu(x) ** 2`` (``mlp_hidden_act: relu2``)."""
+    r = jnp.maximum(x, 0)
+    return r * r
 
 
 def topk_routing(
@@ -24,7 +38,7 @@ def topk_routing(
     k: int,
     renormalize: bool = True,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Returns (weights [T, K], indices [T, K]).
+    """Softmax routing. Returns (weights [T, K], indices [T, K]).
 
     renormalize=True (Mixtral, norm_topk_prob): softmax over the selected k.
     renormalize=False (DeepSeek default): softmax over ALL experts, top-k
@@ -38,6 +52,66 @@ def topk_routing(
     return weights, top_idx
 
 
+def sigmoid_topk_routing(
+    router_logits: jnp.ndarray,  # [T, E] float32, over ALL experts routed over
+    bias: jnp.ndarray,  # [E] e_score_correction_bias: moves the choice only
+    k: int,
+    scale: float = 1.0,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Sigmoid routing with a selection bias (no group limit):
+    ``s = sigmoid(logits)``; chosen = top-k of ``s + bias``;
+    ``w = scale * s_chosen / (sum of s over the chosen + 1e-20)``."""
+    s = jax.nn.sigmoid(router_logits)
+    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), k)
+    chosen = jnp.take_along_axis(s, idx, axis=-1)
+    weights = scale * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+    return weights, idx
+
+
+def grouped_matmul(rows: jnp.ndarray, bank, group_sizes: jnp.ndarray) -> jnp.ndarray:
+    """``rows [M, in]`` (sorted by group) times ``bank [G, in, out]``: row r
+    meets the matrix of its group. Rows past the last group are undefined.
+    A weight-only int8 bank dequantizes into the product, scaled per row by
+    its group's output-channel scales."""
+    if not isinstance(bank, QuantizedLinear):
+        return jax.lax.ragged_dot(rows, bank, group_sizes)
+    y = jax.lax.ragged_dot(
+        rows, bank.q.astype(rows.dtype), group_sizes,
+        preferred_element_type=jnp.float32,
+    )
+    ends = jnp.cumsum(group_sizes)
+    group_of_row = jnp.minimum(
+        jnp.searchsorted(ends, jnp.arange(rows.shape[0]), side="right"),
+        bank.s.shape[0] - 1,
+    )
+    return (y * bank.s[group_of_row]).astype(rows.dtype)
+
+
+def moe_dispatch(
+    hidden: jnp.ndarray,  # [T, D]
+    weights: jnp.ndarray,  # [T, K] float32, normalised over all K chosen
+    idx: jnp.ndarray,  # [T, K] expert ids among the experts ROUTED over
+    expert_ffn: Callable,  # (rows [T*K, D], group_sizes [held]) -> [T*K, Dout]
+    num_held: int,
+    offset: int = 0,  # first expert id held here
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Each token's weighted sum over the chosen experts that are held here.
+    Returns (out [T, Dout] float32, counts [num_held] int32: the assignments
+    each held expert received)."""
+    T, K = idx.shape
+    local = idx.reshape(T * K).astype(jnp.int32) - offset
+    held = (local >= 0) & (local < num_held)
+    key = jnp.where(held, local, num_held)  # absent experts sort last
+    order = jnp.argsort(key, stable=True)
+    counts = jnp.zeros(num_held + 1, jnp.int32).at[key].add(1)[:num_held]
+    y = expert_ffn(hidden[order // K], counts)  # [T*K, Dout], sorted order
+    y = jnp.where(held[order][:, None], y.astype(jnp.float32), 0.0)
+    # back to (token, choice) order: a gather, so the sum over a token's
+    # choices has one order whatever the routing
+    y = y[jnp.argsort(order)].reshape(T, K, -1)
+    return jnp.einsum("tk,tkd->td", weights.astype(jnp.float32), y), counts
+
+
 def moe_block(
     hidden: jnp.ndarray,  # [T, D]
     router_w: jnp.ndarray,  # [D, E]
@@ -45,39 +119,16 @@ def moe_block(
     w_up: jnp.ndarray,  # [E, D, F]
     w_down: jnp.ndarray,  # [E, F, D]
     num_experts_per_tok: int,
-    capacity_factor: float = 2.0,
     renormalize: bool = True,
 ) -> jnp.ndarray:
-    T, D = hidden.shape
-    E = router_w.shape[1]
-    K = num_experts_per_tok
-    capacity = max(1, int(-(-T * K * capacity_factor // E)))
+    """Softmax-routed SwiGLU experts, all held here (Mixtral, DeepSeek-V2)."""
+    logits = hidden.astype(jnp.float32) @ router_w.astype(jnp.float32)  # [T, E]
+    weights, idx = topk_routing(logits, num_experts_per_tok, renormalize=renormalize)
 
-    logits = (hidden.astype(jnp.float32) @ router_w.astype(jnp.float32))  # [T, E]
-    weights, idx = topk_routing(logits, K, renormalize=renormalize)  # [T, K]
+    def ffn(rows, group_sizes):
+        gated = jax.nn.silu(grouped_matmul(rows, w_gate, group_sizes))
+        up = grouped_matmul(rows, w_up, group_sizes)
+        return grouped_matmul(gated * up, w_down, group_sizes)
 
-    # one-hot over experts per routing slot: [T, K, E]
-    onehot = jax.nn.one_hot(idx, E, dtype=jnp.float32)
-    # position of each (token, slot) within its expert queue: [T, K, E]
-    # flatten routing slots in (slot-major, token-minor) order for the cumsum
-    flat = onehot.transpose(1, 0, 2).reshape(K * T, E)
-    pos_flat = jnp.cumsum(flat, axis=0) - flat  # position within expert
-    pos = pos_flat.reshape(K, T, E).transpose(1, 0, 2)  # [T, K, E]
-    keep = (pos < capacity) * onehot  # drop overflow
-    pos_clamped = jnp.minimum(pos, capacity - 1).astype(jnp.int32)
-
-    # dispatch[t, e, c]: token t occupies slot c of expert e
-    cap_onehot = jax.nn.one_hot(pos_clamped, capacity, dtype=jnp.float32)  # [T,K,E,C]
-    dispatch = jnp.einsum("tke,tkec->tec", keep, cap_onehot)
-    combine = jnp.einsum("tk,tke,tkec->tec", weights, keep, cap_onehot)
-
-    expert_in = jnp.einsum("tec,td->ecd", dispatch, hidden.astype(jnp.float32))
-    expert_in = expert_in.astype(hidden.dtype)
-    # batched expert FFN: [E, C, D] x [E, D, F] (banks may be weight-only
-    # int8 — qlinear_expert dequantizes into the einsum)
-    gated = jax.nn.silu(qlinear_expert(expert_in, w_gate))
-    up = qlinear_expert(expert_in, w_up)
-    expert_out = qlinear_expert(gated * up, w_down)  # [E, C, D]
-
-    out = jnp.einsum("tec,ecd->td", combine, expert_out.astype(jnp.float32))
+    out, _ = moe_dispatch(hidden, weights, idx, ffn, num_held=router_w.shape[1])
     return out.astype(hidden.dtype)

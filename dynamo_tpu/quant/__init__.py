@@ -11,7 +11,6 @@ from dynamo_tpu.quant.int8 import (
     QuantizedLinear,
     dequantize_int8,
     qlinear,
-    qlinear_expert,
     quantize_int8,
     quantize_shardings_int8,
     quantize_tree_int8,
@@ -37,7 +36,6 @@ __all__ = [
     "kv_page_bytes",
     "pages_for_hbm_budget",
     "qlinear",
-    "qlinear_expert",
     "quantize_int8",
     "quantize_kv_rows",
     "quantize_shardings_int8",

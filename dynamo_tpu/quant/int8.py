@@ -125,20 +125,6 @@ def qlinear(h, w):
     return (y * w.s).astype(h.dtype)
 
 
-def qlinear_expert(x, w):
-    """Batched expert matmul ``[E, C, in] x [E, in, out] -> [E, C, out]`` for
-    plain or quantized expert banks (the MoE block's per-expert FFN)."""
-    if not isinstance(w, QuantizedLinear):
-        return jnp.einsum("eci,eio->eco", x, w)
-    y = jnp.einsum(
-        "eci,eio->eco",
-        x,
-        w.q.astype(x.dtype),
-        preferred_element_type=jnp.float32,
-    )
-    return (y * w.s[:, None, :]).astype(x.dtype)
-
-
 def quantize_tree_int8(group: dict, names) -> dict:
     """Replace the named leaves of one layer-group dict with QuantizedLinear
     containers (idempotent: already-quantized leaves and absent names skip)."""

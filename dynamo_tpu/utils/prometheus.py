@@ -55,6 +55,9 @@ DECLARED_METRIC_FAMILIES: tuple = (
     "dynamo_engine_kv_cache_bytes",
     "dynamo_engine_kv_cache_page_bytes",
     "dynamo_engine_kv_pages",
+    "dynamo_engine_moe_assignments_total",
+    "dynamo_engine_moe_busiest_over_mean",
+    "dynamo_engine_moe_routed_total",
     "dynamo_engine_offload_blocks_total",
     "dynamo_engine_offload_bytes_resident",
     "dynamo_engine_offload_pressure_blocks_total",
@@ -63,6 +66,7 @@ DECLARED_METRIC_FAMILIES: tuple = (
     "dynamo_engine_prefill_roofline_fraction",
     "dynamo_engine_prefill_seconds",
     "dynamo_engine_prefix_cache_blocks_total",
+    "dynamo_engine_prefix_cache_refused_total",
     "dynamo_engine_pressure_drains_total",
     "dynamo_engine_queue_wait_seconds",
     "dynamo_engine_reconcile_wait_seconds",
@@ -70,6 +74,7 @@ DECLARED_METRIC_FAMILIES: tuple = (
     "dynamo_engine_slo_latency_seconds",
     "dynamo_engine_slo_violations_total",
     "dynamo_engine_stage_seconds_total",
+    "dynamo_engine_state_slots",
     "dynamo_engine_ttft_seconds",
     "dynamo_engine_xla_compile_seconds_total",
     "dynamo_engine_xla_compiles_total",

@@ -88,7 +88,6 @@ def naive_forward(cfg, params, tokens):
                 lp["w_up"],
                 lp["w_down"],
                 num_experts_per_tok=cfg.num_experts_per_tok,
-                capacity_factor=cfg.moe_capacity_factor,
                 renormalize=cfg.norm_topk_prob,
             )
             h = h + shared + cfg.routed_scaling_factor * routed

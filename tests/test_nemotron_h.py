@@ -1,0 +1,471 @@
+"""NemotronH (models/nemotron_h.py): Mamba-2 blocks with a per-slot state
+beside the paged KV, latent experts of which a share is held, NoPE attention.
+
+Everything is compared with the plain reference the benchmark uses
+(`benchmark/reference/nemotron_h.py`: float32, a sequential scan, no cache),
+which reads the same checkpoint files the program loads.
+"""
+
+import asyncio
+import importlib.util
+import json
+import sys
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dynamo_tpu.engine.config import EngineConfig
+from dynamo_tpu.engine.engine import AsyncJaxEngine
+from dynamo_tpu.engine.sampling import SamplingParams
+from dynamo_tpu.engine.scheduler import EngineRequest
+from dynamo_tpu.models.registry import load_model
+
+ROOT = Path(__file__).resolve().parents[1]
+
+
+def _bench_module(kind: str, name: str):
+    path = ROOT / "benchmark" / kind / f"{name}.py"
+    spec = importlib.util.spec_from_file_location(f"bench_{kind}_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+reference = _bench_module("reference", "nemotron_h")
+plan = _bench_module("checkpoints", "nemotron_h")
+
+#: config.json keys of a small NemotronH: every kind of block, Mamba before
+#: and after attention, half of 8 experts held, float32 so that the
+#: comparison's tolerance is float32 rounding and nothing hides under it
+HF_TINY = {
+    "architectures": ["NemotronHForCausalLM"], "model_type": "nemotron_h",
+    "torch_dtype": "float32", "hidden_size": 64, "vocab_size": 256,
+    "num_hidden_layers": 5, "hybrid_override_pattern": "ME*EM",
+    "layer_norm_epsilon": 1e-5,
+    "mamba_num_heads": 8, "mamba_head_dim": 8, "ssm_state_size": 16, "n_groups": 2,
+    "conv_kernel": 4, "chunk_size": 16, "use_conv_bias": True,
+    "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
+    "n_routed_experts": 4, "moe_routed_over": 8, "moe_expert_offset": 0,
+    "num_experts_per_tok": 3, "moe_latent_size": 32, "moe_intermediate_size": 48,
+    "moe_shared_expert_intermediate_size": 96, "n_shared_experts": 1,
+    "routed_scaling_factor": 2.5, "norm_topk_prob": True, "n_group": 1, "topk_group": 1,
+    "mlp_hidden_act": "relu2",
+}
+
+
+def write_checkpoint(out: Path, hf: dict, seed: int) -> Path:
+    """The plan's tensors in float32 at a scale where every block matters
+    (matrices at 1/sqrt(fan_in), vectors at 0.5, norms near 1, A_log and D
+    drawn): a checkpoint only these tests write."""
+    from safetensors.numpy import save_file
+
+    rng = np.random.default_rng(seed)
+    tensors = {}
+    for name, shape, kind in plan.tensor_plan(hf):
+        if name.endswith("A_log"):
+            t = rng.normal(0.0, 0.5, shape)
+        elif name.endswith(".D") or kind == "ones":
+            t = 1.0 + rng.normal(0.0, 0.1, shape)
+        elif name.endswith("e_score_correction_bias"):
+            t = rng.normal(0.0, 0.05, shape)
+        elif len(shape) == 2:
+            t = rng.normal(0.0, 1.0 / np.sqrt(shape[1]), shape)
+        else:
+            t = rng.normal(0.0, 0.5, shape)
+        tensors[name] = t.astype(np.float32)
+    out.mkdir(parents=True, exist_ok=True)
+    (out / "config.json").write_text(json.dumps(hf))
+    save_file(tensors, str(out / "model.safetensors"))
+    return out
+
+
+@pytest.fixture(scope="module")
+def ckpt(tmp_path_factory):
+    return write_checkpoint(tmp_path_factory.mktemp("nemotron_h") / "ckpt", HF_TINY, 29)
+
+
+@pytest.fixture(scope="module")
+def loaded(ckpt):
+    return load_model(str(ckpt))
+
+
+def _tokens(seed: int, n: int) -> list:
+    return [int(t) for t in np.random.default_rng(seed).integers(3, HF_TINY["vocab_size"], n)]
+
+
+# ---------------------------------------------------------------- the model, on logits
+
+#: float32 on both sides: the program's chunked scan, grouped products and
+#: paged attention against the reference's sequential scan and dense products
+#: differ by summation order only. Measured 3e-5 at most on logits of size ~3
+#: (this file, PR 29); 5e-4 leaves an order of magnitude, and a wrong window,
+#: state, expert weight or mask moves logits by 1e-2 to 1 (the controls below).
+LOGIT_ATOL = 5e-4
+
+
+class _Driver:
+    """The model's own prefill and decode functions over hand-made caches:
+    what the runner's jitted steps call, without the scheduler."""
+
+    def __init__(self, model, params, max_seqs=3, num_pages=32, page_size=16):
+        self.model, self.params = model, params
+        self.ps, self.max_seqs = page_size, max_seqs
+        self.cache = {**model.init_kv_cache(num_pages, page_size),
+                      **model.init_state_cache(max_seqs)}
+        self.tables = np.zeros((max_seqs, 8), np.int32)
+        for s in range(max_seqs):  # pages 1.. (0 is the null page), 8 a slot
+            self.tables[s] = 1 + s * 8 + np.arange(8)
+
+    def prefill(self, lanes, T):
+        """lanes: [(slot, tokens, start)]; one packed call at bucket T.
+        Returns logits [len(lanes), V] at each lane's last real token."""
+        N = len(lanes)
+        toks = np.zeros((N, T), np.int32)
+        pos = np.zeros((N, T), np.int32)
+        valid = np.zeros((N, T), bool)
+        last = np.zeros(N, np.int32)
+        slots = np.zeros(N, np.int32)
+        pts = np.zeros((N, 8), np.int32)
+        for j, (slot, tokens, start) in enumerate(lanes):
+            n = len(tokens)
+            toks[j, :n] = tokens
+            pos[j] = start + np.arange(T)
+            valid[j, :n] = True
+            last[j] = n - 1
+            slots[j] = slot
+            if slot >= 0:
+                pts[j] = self.tables[slot]
+        logits, self.cache = jax.jit(self.model.prefill_packed)(
+            self.params, self.cache, jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(pts),
+            jnp.asarray(valid), jnp.asarray(last), state_slots=jnp.asarray(slots),
+        )
+        return np.asarray(logits)
+
+    def decode(self, fed: dict):
+        """fed: {slot: (token, position)}; the other slots are not active."""
+        B = self.max_seqs
+        toks, pos, act = np.zeros(B, np.int32), np.zeros(B, np.int32), np.zeros(B, bool)
+        for slot, (t, p) in fed.items():
+            toks[slot], pos[slot], act[slot] = t, p, True
+        logits, self.cache = jax.jit(self.model.decode)(
+            self.params, self.cache, jnp.asarray(toks), jnp.asarray(pos),
+            jnp.asarray(self.tables), jnp.asarray(act),
+        )
+        return np.asarray(logits)
+
+
+def test_prefill_chunks_packs_and_decode_match_the_reference_logits(ckpt, loaded):
+    """(a) on logits: one-chunk, chunked and packed prefill with padding
+    lanes, then decode steps through both caches with a slot left inactive,
+    against the reference's one forward pass."""
+    model, params = loaded
+    a, b = _tokens(1, 44), _tokens(2, 21)
+    ref = np.asarray(reference.forward_logits(ckpt, np.array([a, b + [0] * 23])))
+    d = _Driver(model, params)
+    # sequence a in slot 2: chunk [0:16) alone, then [16:37) packed with b's
+    # whole prompt (slot 0) and a padding lane, at bucket 32
+    got = d.prefill([(2, a[:16], 0)], 16)
+    np.testing.assert_allclose(got[0], ref[0, 15], atol=LOGIT_ATOL)
+    got = d.prefill([(2, a[16:37], 16), (0, b[:18], 0), (-1, [], 0)], 32)
+    np.testing.assert_allclose(got[0], ref[0, 36], atol=LOGIT_ATOL)
+    np.testing.assert_allclose(got[1], ref[1, 17], atol=LOGIT_ATOL)
+    # decode both, slot 1 never active; a runs four steps further than b
+    for step in range(7):
+        fed = {2: (a[37 + step], 37 + step)}
+        if step < 3:
+            fed[0] = (b[18 + step], 18 + step)
+        got = d.decode(fed)
+        np.testing.assert_allclose(got[2], ref[0, 37 + step], atol=LOGIT_ATOL)
+        if step < 3:
+            np.testing.assert_allclose(got[0], ref[1, 18 + step], atol=LOGIT_ATOL)
+    assert not np.asarray(d.cache["ssm"][1]).any(), "an inactive slot's state was touched"
+    assert not np.asarray(d.cache["ssm"][d.max_seqs]).any(), "the trash row moved"
+
+
+def _window_off_by_one(conv):
+    """The window one position late: every entry moved back by one, the newest
+    input lost (what a hand-off that stops one token early leaves)."""
+    return jnp.roll(conv, 1, axis=1).at[:, 0].set(0)
+
+
+@pytest.mark.parametrize("fault", ["zeroed_state", "shifted_window"])
+def test_a_broken_hand_off_fails_the_logit_tolerance(ckpt, loaded, fault):
+    """The control of (a): a state not handed from prefill to decode, and a
+    convolution window off by one position, each move the first decoded
+    logits far past LOGIT_ATOL."""
+    model, params = loaded
+    a = _tokens(3, 30)
+    ref = np.asarray(reference.forward_logits(ckpt, np.array([a])))
+    d = _Driver(model, params)
+    d.prefill([(0, a[:24], 0)], 32)
+    if fault == "zeroed_state":
+        d.cache["ssm"] = jnp.zeros_like(d.cache["ssm"])
+    else:
+        d.cache["conv"] = _window_off_by_one(d.cache["conv"])
+    got = d.decode({0: (a[24], 24)})
+    assert np.abs(got[0] - ref[0, 24]).max() > 100 * LOGIT_ATOL
+
+
+# ---------------------------------------------------------------- through the engine
+
+#: logprobs of the tokens the engine chose, float32 on both sides (see
+#: LOGIT_ATOL: a logprob is a logit minus a log-sum-exp of logits)
+LOGPROB_ATOL = 5e-4
+
+
+def _engine(ckpt, **kw):
+    base = dict(model_id=str(ckpt), num_pages=64, max_seqs=2, max_model_len=128,
+                prefill_buckets=(16, 32), decode_steps=4)
+    return AsyncJaxEngine(EngineConfig(**{**base, **kw}))
+
+
+async def _generate(eng, rid, prompt, max_tokens):
+    toks, lps = [], []
+    req = EngineRequest(request_id=rid, token_ids=list(prompt), logprobs=1,
+                        sampling=SamplingParams(temperature=0.0, max_tokens=max_tokens))
+    async for out in eng.generate(req):
+        if out.token is not None:
+            toks.append(out.token)
+            lps.append(out.logprob)
+    return toks, lps
+
+
+def _check_against_reference(ckpt, prompts, results):
+    probes = [{"tokens": list(p) + toks, "prompt_len": len(p)}
+              for p, (toks, _) in zip(prompts, results)]
+    ref = reference.teacher_forced_logprobs(ckpt, probes)
+    for (toks, lps), want in zip(results, ref):
+        assert len(toks) == len(want)
+        np.testing.assert_allclose(lps, want, atol=LOGPROB_ATOL)
+
+
+ENGINE_CASES = {
+    # one request, one chunk, two decode windows
+    "one_chunk": dict(prompts=[_tokens(10, 20)], max_tokens=8, concurrent=True, engine={}),
+    # a prompt of three chunks (the largest bucket is 32)
+    "chunked": dict(prompts=[_tokens(11, 75)], max_tokens=6, concurrent=True, engine={}),
+    # two at once: their chunks share packed calls, their decode shares windows
+    "packed": dict(prompts=[_tokens(12, 40), _tokens(13, 9)], max_tokens=9,
+                   concurrent=True, engine={}),
+    # four sequences through two slots: each slot's state is used again by a
+    # sequence that must not see what the one before left
+    "slot_reused": dict(prompts=[_tokens(14 + i, 12 + 9 * i) for i in range(4)],
+                        max_tokens=7, concurrent=True, engine={}),
+    # 7 usable pages for two sequences that need 4 each: the younger is
+    # preempted, its state dropped, and it resumes by recomputing
+    "preempted": dict(prompts=[_tokens(20, 30), _tokens(21, 30)], max_tokens=30,
+                      concurrent=True,
+                      engine=dict(num_pages=8, max_model_len=64, watermark=0.0)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ENGINE_CASES))
+def test_engine_matches_the_reference(ckpt, case):
+    """(a) through the scheduler, runner, page table and sampler."""
+    spec = ENGINE_CASES[case]
+
+    async def body():
+        eng = _engine(ckpt, **spec["engine"])
+        await eng.start()
+        try:
+            results = await asyncio.gather(*[
+                _generate(eng, f"{case}-{i}", p, spec["max_tokens"])
+                for i, p in enumerate(spec["prompts"])
+            ])
+            return results, eng.scheduler.preempt_count, eng.resource_snapshot()
+        finally:
+            await eng.shutdown()
+
+    results, preempted, snap = asyncio.run(body())
+    _check_against_reference(ckpt, spec["prompts"], results)
+    if case == "preempted":
+        assert preempted >= 1
+    assert snap["state_slots_total"] == 2 and snap["state_slots_active"] == 0
+    assert snap["moe_routed"] > snap["moe_assignments"] > 0  # half the experts are held
+
+
+def test_no_prefix_hit_for_a_recurrent_model(ckpt):
+    """A repeated prompt recomputes (the pages hold no recurrent state), the
+    withheld match is counted, and the answer is the same."""
+
+    async def body():
+        eng = _engine(ckpt)
+        await eng.start()
+        try:
+            prompt = _tokens(30, 40)
+            first = await _generate(eng, "p0", prompt, 5)
+            second = await _generate(eng, "p1", prompt, 5)
+            return first, second, eng.resource_snapshot(), eng.render_stage_metrics()
+        finally:
+            await eng.shutdown()
+
+    first, second, snap, text = asyncio.run(body())
+    assert first[0] == second[0]
+    np.testing.assert_allclose(first[1], second[1], atol=1e-6)
+    assert snap["prefix_cache_hit_blocks"] == 0 and snap["prefix_cache_refused"] == 1
+    for family in ("dynamo_engine_prefix_cache_refused_total 1",
+                   'dynamo_engine_state_slots{state="total"} 2',
+                   'dynamo_engine_hbm_bytes{kind="state"}',
+                   "dynamo_engine_moe_assignments_total", "dynamo_engine_moe_routed_total"):
+        assert family in text, family
+
+
+REFUSED = {
+    "speculation": (dict(speculative="ngram:2"), "speculative decoding is refused"),
+    "offload": (dict(host_cache_blocks=4), "host and disk KV tiers are refused"),
+    "tensor_parallel": (dict(tp=2), "tp/pp/sp > 1 are refused"),
+    "int8_kv": (dict(kv_cache_dtype="int8"), "int8"),
+}
+
+
+@pytest.mark.parametrize("what", sorted(REFUSED))
+def test_refused_at_start_up_with_the_reason(what):
+    kw, reason = REFUSED[what]
+
+    async def body():
+        eng = AsyncJaxEngine(EngineConfig(model_id="tiny-hybrid", num_pages=16, max_seqs=2, **kw))
+        await eng.start()
+
+    with pytest.raises(ValueError, match=reason):
+        asyncio.run(body())
+
+
+def test_migration_and_disaggregation_are_refused():
+    """Migration is on by default, so the engine turns it off with a logged
+    reason and refuses an adoption; the disaggregated roles refuse to wrap
+    the engine at all."""
+    from dynamo_tpu.disagg.decode_worker import DisaggDecodeEngine
+    from dynamo_tpu.disagg.prefill_worker import PrefillWorker
+
+    async def body():
+        eng = AsyncJaxEngine(EngineConfig(model_id="tiny-hybrid", num_pages=16, max_seqs=2))
+        await eng.start()
+        try:
+            assert eng.config.migration is False
+            with pytest.raises(RuntimeError, match="migration is disabled"):
+                async for _ in eng.adopt_migrated(None):
+                    pass
+            assert (await eng.migrate_out("nobody", None))["status"] == "skipped"
+            for role in (PrefillWorker, DisaggDecodeEngine):
+                with pytest.raises(ValueError, match="recurrent"):
+                    role(eng, None, "ns", "comp", "model") if role is DisaggDecodeEngine \
+                        else role(eng, None, "ns", "model")
+        finally:
+            await eng.shutdown()
+
+    asyncio.run(body())
+
+
+# ---------------------------------------------------------------- the share of the experts
+
+def test_the_shares_add_up_to_the_uncut_expert_block(tmp_path):
+    """(b) `model-configs` section 4: the routed parts that the four shares
+    give, plus the shared expert counted once, are the uncut reference's `E`
+    block; and no token is dropped at a batch where the old capacity rule
+    (ceil(T * K / E * 2) rows an expert) would have dropped."""
+    from dynamo_tpu.models.nemotron_h import NemotronHConfig, NemotronHModel
+
+    rng = np.random.default_rng(5)
+    T, D, Z, F, Fs, E, K = 48, 64, 32, 48, 96, 8, 3
+    hf = dict(HF_TINY, n_routed_experts=E, moe_routed_over=E)
+    w = {name.split("mixer.", 1)[1]: rng.normal(0, 1 / np.sqrt(shape[-1]), shape).astype(np.float32)
+         for name, shape, _ in plan.tensor_plan(dict(hf, num_hidden_layers=1, hybrid_override_pattern="E"))
+         if "mixer." in name}
+    # a router that crowds two experts: every token chooses 0 and 1
+    w["gate.e_score_correction_bias"] = np.array([9, 9, 0, 0, 0, 0, 0, 0], np.float32)
+    x = rng.normal(0, 1, (1, T, D)).astype(np.float32)
+    with jax.default_matmul_precision("highest"):
+        whole = np.asarray(reference.expert_mixer(jnp.asarray(x), {k: jnp.asarray(v) for k, v in w.items()}, hf))
+        shared = np.asarray(reference._relu2(x @ w["shared_experts.up_proj.weight"].T)
+                            @ w["shared_experts.down_proj.weight"].T)
+    assert T * K // E * 2 < T, "the old capacity would not have dropped here"
+
+    total, counts = np.zeros_like(whole), []
+    for share in range(4):
+        cfg = NemotronHConfig.from_hf_config(
+            dict(hf, n_routed_experts=2, moe_routed_over=E, moe_expert_offset=2 * share))
+        held = [2 * share, 2 * share + 1]
+        bp = {
+            "router": jnp.asarray(w["gate.weight"].T), "router_bias": jnp.asarray(w["gate.e_score_correction_bias"]),
+            "lat_down": jnp.asarray(w["fc1_latent_proj.weight"].T), "lat_up": jnp.asarray(w["fc2_latent_proj.weight"].T),
+            "w1": jnp.stack([w[f"experts.{e}.up_proj.weight"].T for e in held]),
+            "w2": jnp.stack([w[f"experts.{e}.down_proj.weight"].T for e in held]),
+            "shared_up": jnp.asarray(w["shared_experts.up_proj.weight"].T),
+            "shared_down": jnp.asarray(w["shared_experts.down_proj.weight"].T),
+        }
+        with jax.default_matmul_precision("highest"):
+            out, n = NemotronHModel(cfg)._experts(bp, jnp.asarray(x[0]))
+        total += np.asarray(out)[None] - shared  # the routed part of this share
+        counts.append(np.asarray(n))
+    np.testing.assert_allclose(total + shared, whole, atol=2e-4)
+    counts = np.concatenate(counts)
+    assert counts.sum() == T * K, "an assignment was dropped"
+    assert counts[0] == counts[1] == T, "the crowded experts did not take every token"
+
+
+# ---------------------------------------------------------------- the plan's kinds
+
+def test_with_the_plans_kinds_the_hand_off_decides_the_first_tokens(tmp_path):
+    """(c) The benchmark writes `normal` (0.02) and `ones` only. With the
+    plan's kinds (A = -e, dt = softplus of about N(0, 1), the convolution a
+    box filter of ones) the state must matter for the first decoded tokens,
+    or the benchmark's comparison would be blind to a lost hand-off. At a
+    width of 1024 (0.02 * sqrt(1024) = 0.64 a matmul, where the published
+    4096 gives 1.28), in bfloat16 as served: the healthy path is within 0.02
+    of the float32 reference on the logprobs a sampler would see, a zeroed
+    hand-off state moves them by more than ten times that, and a window one
+    position late by more still. (With `conv1d.weight` at 0.02, as ISSUE 29
+    first gave the kinds, the zeroed state read 0.008 against a healthy
+    0.005: the state did not matter, and the plan was changed. On the chip at
+    full width the same faults read 1.16 and 3.04 against a healthy 0.06:
+    PERF.md section 6.)"""
+    sys.path.insert(0, str(ROOT / "benchmark"))
+    import checkpoint as writer
+
+    hf = dict(HF_TINY, torch_dtype="bfloat16", hidden_size=1024, vocab_size=512,
+              num_hidden_layers=3, hybrid_override_pattern="MEM",
+              mamba_num_heads=16, mamba_head_dim=64, ssm_state_size=128, n_groups=2,
+              moe_latent_size=256, moe_intermediate_size=512,
+              moe_shared_expert_intermediate_size=1024)
+    ck = tmp_path / "plan_kinds"
+    ck.mkdir()
+    (ck / "config.json").write_text(json.dumps(hf))
+    writer.write_safetensors(ck / "model.safetensors", plan.tensor_plan(hf), 2147483999, workers=2)
+    model, params = load_model(str(ck))
+
+    prompt = [int(t) for t in np.random.default_rng(7).integers(3, 512, 40)]
+    ref = np.asarray(jax.nn.log_softmax(reference.forward_logits(ck, np.array([prompt + [5]])), -1))
+
+    def first_decoded(fault):
+        d = _Driver(model, params)
+        d.prefill([(0, prompt[:-1], 0)], 64)
+        if fault == "zeroed_state":
+            d.cache["ssm"] = jnp.zeros_like(d.cache["ssm"])
+        elif fault == "shifted_window":
+            d.cache["conv"] = _window_off_by_one(d.cache["conv"])
+        got = d.decode({0: (prompt[-1], len(prompt) - 1)})[0]
+        return np.asarray(jax.nn.log_softmax(got.astype(jnp.float32)))
+
+    top = np.argsort(ref[0, len(prompt) - 1])[-8:]  # the tokens a sampler would see
+    err = {f: np.abs(first_decoded(f)[top] - ref[0, len(prompt) - 1][top]).max()
+           for f in (None, "zeroed_state", "shifted_window")}
+    assert err[None] < 0.02, err
+    assert err["zeroed_state"] > 10 * err[None] and err["zeroed_state"] > 0.05, err
+    assert err["shifted_window"] > err["zeroed_state"], err
+
+
+# ---------------------------------------------------------------- the registry
+
+def test_architectures_are_one_table_by_exact_name(tmp_path):
+    from dynamo_tpu.models.registry import ARCHITECTURES
+
+    assert {"LlamaForCausalLM", "Qwen2ForCausalLM", "MixtralForCausalLM", "DeepseekV2ForCausalLM",
+            "NemotronHForCausalLM"} <= set(ARCHITECTURES)
+    (tmp_path / "config.json").write_text(json.dumps({"architectures": ["QwenishForCausalLM"]}))
+    with pytest.raises(ValueError) as e:
+        load_model(str(tmp_path))
+    assert "QwenishForCausalLM" in str(e.value)
+    assert all(name in str(e.value) for name in ARCHITECTURES), "the error does not list what exists"

@@ -66,3 +66,22 @@ def test_thread_local_tokenizer_loads_bounded_by_pool(monkeypatch):
         assert f.result() == [1, 2, 3]
     # construction thread + at most one load per pool worker
     assert len(loads) <= 1 + pool._max_workers
+
+
+
+def test_the_tokenizer_import_looks_for_no_framework(monkeypatch):
+    """`HfTokenizer` tells transformers not to look for torch, TensorFlow or
+    Flax before it imports it (the look imports them: 20 s of a server's
+    start), and leaves a value the user set alone."""
+    import os
+
+    fake = types.ModuleType("transformers")
+    fake.AutoTokenizer = types.SimpleNamespace(from_pretrained=lambda path: [0, 1])
+    monkeypatch.setitem(sys.modules, "transformers", fake)
+    monkeypatch.delenv("USE_TORCH", raising=False)
+    monkeypatch.delenv("USE_FLAX", raising=False)
+    monkeypatch.setenv("USE_TF", "1")
+    tok = HfTokenizer.__new__(HfTokenizer)
+    tok._path, tok._local = "/does/not/matter", threading.local()
+    assert tok._tok == [0, 1]
+    assert (os.environ["USE_TORCH"], os.environ["USE_FLAX"], os.environ["USE_TF"]) == ("0", "0", "1")

@@ -115,10 +115,6 @@ def test_mixtral_checkpoint_roundtrip(tmp_path):
     from dynamo_tpu.models.mixtral import MixtralConfig, MixtralModel
 
     cfg = MixtralConfig.from_hf_config(hf_cfg)
-    # huge capacity => exact routing for the comparison
-    from dataclasses import replace
-
-    cfg = replace(cfg, moe_capacity_factor=8.0)
     model = MixtralModel(cfg)
     params = model.init_params(jax.random.key(8))
 
@@ -145,7 +141,6 @@ def test_mixtral_checkpoint_roundtrip(tmp_path):
     save_file(tensors, str(tmp_path / "model.safetensors"))
 
     loaded_model, loaded_params = load_model(str(tmp_path))
-    object.__setattr__(loaded_model.config, "moe_capacity_factor", 8.0)
     np.testing.assert_allclose(
         _prefill_logits(loaded_model, loaded_params),
         _prefill_logits(model, params),
@@ -175,11 +170,9 @@ def test_deepseek_checkpoint_roundtrip(tmp_path):
     }
     (tmp_path / "config.json").write_text(json.dumps(hf_cfg))
 
-    from dataclasses import replace
-
     from dynamo_tpu.models.deepseek import DeepseekConfig, DeepseekModel
 
-    cfg = replace(DeepseekConfig.from_hf_config(hf_cfg), moe_capacity_factor=8.0)
+    cfg = DeepseekConfig.from_hf_config(hf_cfg)
     model = DeepseekModel(cfg)
     params = model.init_params(jax.random.key(9))
 
@@ -224,7 +217,6 @@ def test_deepseek_checkpoint_roundtrip(tmp_path):
     save_file(tensors, str(tmp_path / "model.safetensors"))
 
     loaded_model, loaded_params = load_model(str(tmp_path))
-    object.__setattr__(loaded_model.config, "moe_capacity_factor", 8.0)
     np.testing.assert_allclose(
         _prefill_logits(loaded_model, loaded_params),
         _prefill_logits(model, params),

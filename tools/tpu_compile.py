@@ -171,6 +171,23 @@ def _mla_prefill_case(ps, T):
     return Case(f"mla-prefill-ps{ps}-T{T}", build)
 
 
+def _ssm_update_case(slots: int = 128):
+    """The one-token state update at the published Mamba-2 widths (128 heads
+    x 64 x 128 float32 a slot), one block's rows plus its trash row."""
+    H, P, G, N = 128, 64, 8, 128
+
+    def build(S):
+        from dynamo_tpu.ops.pallas.ssm_update import ssm_state_update_pallas
+
+        return ssm_state_update_pallas, (
+            S((slots + 1, H, P, N), jnp.float32), S((slots, H), jnp.float32),
+            S((slots, H, P), jnp.float32), S((slots, G, N), jnp.float32),
+            S((slots, G, N), jnp.float32), S((slots,), jnp.int32),
+        )
+
+    return Case(f"ssm-state-update-{slots}slots", build)
+
+
 def kernel_cases(full: bool) -> list[Case]:
     """Every Pallas kernel the default dispatch can reach, at published head
     geometries. ``full``: each page size (16 engine default, 64, 128) x each
@@ -195,6 +212,7 @@ def kernel_cases(full: bool) -> list[Case]:
         for ps in (16, 64, 128):
             cases += [_mla_decode_case(ps, False), _mla_decode_case(ps, True)]
             cases += [_mla_prefill_case(ps, T) for T in (128, 256, 512, 1024)]
+        cases.append(_ssm_update_case())
         return cases
     return [
         # decode: folded, lookahead, and the per-sequence kernel lookahead
@@ -217,6 +235,8 @@ def kernel_cases(full: bool) -> list[Case]:
         # model passes (17.3 MiB)
         _mla_decode_case(16, False), _mla_decode_case(128, True),
         _mla_prefill_case(16, 512),
+        # the Mamba-2 state update, in place over the donated state
+        _ssm_update_case(),
     ]
 
 
@@ -291,6 +311,44 @@ def compile_steps(geometry: dict, tp: int, num_pages: int, page_size: int = 16,
     return out
 
 
+def compile_hybrid_steps(hf_config: dict, num_pages: int, max_seqs: int, page_size: int = 16,
+                         lanes: int = 2, bucket: int = 512, max_model_len: int = 4096,
+                         topo=None) -> dict:
+    """Compile one decode step and one packed prefill step of a NemotronH
+    model (models/nemotron_h.py: state cache beside the page pools, the
+    dropless expert dispatch, the state-update kernel) on one described chip;
+    ``hf_config`` is a config.json dict. Returns {step: compiled}."""
+    from jax.sharding import SingleDeviceSharding
+
+    from dynamo_tpu.models.nemotron_h import NemotronHConfig, NemotronHModel
+
+    one = SingleDeviceSharding((topo or topology()).devices[0])
+    mp = max_model_len // page_size
+
+    def R(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def place(tree):
+        return jax.tree.map(lambda x: R(x.shape, x.dtype), tree)
+
+    out = {}
+    with on_chip_dispatch():
+        model = NemotronHModel(NemotronHConfig.from_hf_config(hf_config))
+        params = place(jax.eval_shape(model.init_params, jax.random.key(0)))
+        kv = place(jax.eval_shape(lambda: {**model.init_kv_cache(num_pages, page_size),
+                                           **model.init_state_cache(max_seqs)}))
+        out["decode"] = jax.jit(model.decode, donate_argnums=(1,)).lower(
+            params, kv, R((max_seqs,), jnp.int32), R((max_seqs,), jnp.int32),
+            R((max_seqs, mp), jnp.int32), R((max_seqs,), jnp.bool_),
+        ).compile()
+        out["prefill_packed"] = jax.jit(model.prefill_packed, donate_argnums=(1,)).lower(
+            params, kv, R((lanes, bucket), jnp.int32), R((lanes, bucket), jnp.int32),
+            R((lanes, mp), jnp.int32), R((lanes, bucket), jnp.bool_), R((lanes,), jnp.int32),
+            R((lanes,), jnp.int32),
+        ).compile()
+    return out
+
+
 def _report_steps(title: str, steps: dict) -> None:
     for name, compiled in steps.items():
         m = compiled.memory_analysis()
@@ -332,6 +390,12 @@ def main(argv=None) -> int:
         for tp in (1, 4):
             _report_steps(f"qwen2.5-7b-width L={args.tp4_layers} tp={tp}",
                           compile_steps(qwen, tp, num_pages=512, topo=topo))
+        import json
+
+        nemotron = json.loads(
+            (Path(__file__).resolve().parents[1] / "benchmark/configs/nemotron3-super-ep4.json").read_text())
+        _report_steps("nemotron3-super-ep4 (11 blocks, 128 of 512 experts) 128 slots",
+                      compile_hybrid_steps(nemotron, num_pages=49152, max_seqs=128, topo=topo))
     print(f"{'FAILED' if failed else 'ok'}: {failed} refused", flush=True)
     return 1 if failed else 0
 
