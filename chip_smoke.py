@@ -905,6 +905,35 @@ def child_parity(sizes: Sizes, args) -> int:
         return (np.concatenate([np.asarray(got_y)[pick].ravel(), np.asarray(got_s).ravel()]),
                 np.concatenate([np.asarray(want_y)[pick].ravel(), np.asarray(want_s).ravel()]))
 
+    def grouped_matmul(M, K, N, G, tokens, topk, routed):
+        """The expert layer's grouped product at a decode step's shape: each
+        of `tokens` tokens chooses `topk` of `routed` experts, the first G are
+        held, the rest of the M static rows belong to nobody and hold NaN on
+        the way in. Rows inside groups against one dense float32 product per
+        expert (XLA's `ragged_dot` is itself a Mosaic kernel on the chip, and
+        refuses bf16 operands at the reference's precision)."""
+        from dynamo_tpu.ops.pallas.grouped_matmul import grouped_matmul_pallas
+
+        chosen = np.concatenate([rng.choice(routed, topk, replace=False) for _ in range(tokens)])
+        sizes = np.bincount(chosen, minlength=routed)[:G]
+        real, ends = int(sizes.sum()), np.cumsum(sizes)
+        rows, bank = normal(M, K), normal(G, K, N, scale=K ** -0.5)
+        row = jnp.arange(real)[:, None]
+
+        def dense(rows, bank):
+            def one(out, group):
+                matrix, start, end = group
+                y = rows[:real].astype(jnp.float32) @ matrix.astype(jnp.float32)
+                return jnp.where((row >= start) & (row < end), y, out), None
+
+            groups = (bank, jnp.asarray(ends - sizes), jnp.asarray(ends))
+            return jax.lax.scan(one, jnp.zeros((real, N), jnp.float32), groups)[0]
+
+        got = grouped_matmul_pallas(
+            rows.at[real:].set(jnp.nan), bank, jnp.asarray(sizes, jnp.int32), interpret=interpret
+        )
+        return got[:real], reference(jax.jit(dense), rows, bank)
+
     if full:
         tiny, qwen, mixtral, bench, shard = (32, 4, 64), (28, 4, 128), (32, 8, 128), (16, 8, 128), (7, 1, 128)
         qwen3b = (16, 2, 128)  # the benchmark's configuration
@@ -935,6 +964,9 @@ def child_parity(sizes: Sizes, args) -> int:
         ("mla prefill ps16", lambda: mla(16, T=T, prefix=prefix)),
         # Mamba-2 at the published widths (128 heads x 64 x 128), 24 slots
         ("ssm state update nemotron-h f32", lambda: ssm_update(*((24, 128, 64, 8, 128) if full else (6, 8, 8, 2, 128)))),
+        # the cell's decode step: 2816 static rows, 108 tokens x 22 of 512, 128 held
+        ("moe grouped matmul nemotron-h decode bf16", lambda: grouped_matmul(
+            *((2816, 1024, 2688, 128, 108, 22, 512) if full else (64, 128, 256, 4, 6, 3, 8)))),
     ]
     ok = True
     for name, thunk in cases:

@@ -203,6 +203,9 @@ class ModelRunner:
                 devices = jax.devices()[: config.tp]
                 mesh = Mesh(np.array(devices).reshape(len(devices)), ("tp",))
         self.mesh = mesh
+        if mesh.size > 1 and hasattr(model, "expert_mesh"):
+            # expert banks may be sharded over it: ops/moe.grouped_matmul
+            model.expert_mesh = mesh
         if config.tp > 1 and config.pp == 1:
             # the Pallas decode kernel runs under shard_map on this mesh
             # (attention is head-parallel; no collectives inside). With pp > 1

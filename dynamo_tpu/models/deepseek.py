@@ -191,6 +191,9 @@ class DeepseekModel:
         # set by ModelRunner for tp>1: the Pallas MLA kernel runs under
         # shard_map on this mesh (heads sharded; latent pool replicated)
         self.attn_mesh = None
+        # set by ModelRunner where the engine's mesh has several devices: the
+        # grouped product is then XLA's (ops/moe.grouped_matmul)
+        self.expert_mesh = None
 
     # ---------------- params ----------------
 
@@ -625,6 +628,7 @@ class DeepseekModel:
                 lp["w_down"],
                 num_experts_per_tok=c.num_experts_per_tok,
                 renormalize=c.norm_topk_prob,
+                mesh=self.expert_mesh,
             )
             hidden = hidden + shared + c.routed_scaling_factor * routed
         else:

@@ -64,6 +64,9 @@ class MixtralModel(LlamaModel):
 
     def __init__(self, config: MixtralConfig):
         super().__init__(config)
+        # set by ModelRunner where the engine's mesh has several devices: the
+        # grouped product is then XLA's (ops/moe.grouped_matmul)
+        self.expert_mesh = None
 
     def _init_raw_params(self, rng: jax.Array) -> dict:
         c = self.config
@@ -134,6 +137,7 @@ class MixtralModel(LlamaModel):
             lp["w_up"],
             lp["w_down"],
             num_experts_per_tok=c.num_experts_per_tok,
+            mesh=self.expert_mesh,
         )
         hidden = hidden + moe_out
         return hidden, k_pool, v_pool

@@ -1,20 +1,31 @@
 """Sparse Mixture-of-Experts: routing, and ONE dispatch that drops nothing.
 
 The dispatch sorts the (token, choice) assignments by expert and runs the
-expert FFN as a grouped matrix product over the experts HELD here
-(``jax.lax.ragged_dot``: rows of one group meet one expert's matrix; on the
-TPU it lowers to one kernel, asked of the chip's compiler for the shapes of
-``tools/tpu_compile.py``). Shapes are static at ``T * K`` rows, the worst
+expert FFN as a grouped matrix product over the experts HELD here: rows of one
+group meet one expert's matrix. Shapes are static at ``T * K`` rows, the worst
 case, so no assignment is ever dropped whatever the routing: there is no
 capacity and no factor to tune.
+
+The product (`grouped_matmul`) is the kernel `moe_grouped_matmul`
+(`ops/pallas/grouped_matmul.py`): it streams the matrix of each expert that
+received a row once, visits row tiles by the groups' real boundaries and stops
+at the last real row, so the static worst case costs nothing where it is not
+met. Rows past the last group are UNDEFINED in its result (stale memory); a
+row-by-row function such as `relu2` may stand between two products, and
+`moe_dispatch` selects those rows away. `jax.lax.ragged_dot` is the reference
+and the path that is taken off the chip, for a weight-only int8 bank, for a
+bank too wide for the kernel's blocks, and whenever the caller's model runs
+under a mesh of several devices (the expert axis of the banks shards over a
+mesh's "ep" axis under GSPMD, which gathers what the product needs and cannot
+partition a kernel: Mixtral's test). The caller says so with ``mesh``: inside
+a jit a bank's sharding is not there to ask. `tools/tpu_compile.py` asks the
+chip's compiler for the kernel at the shapes served.
 
 An expert layer may hold a SHARE of the experts it routes over (one chip of an
 expert-parallel deployment): the router scores all of them, the weights are
 normalised over everything a token chose, and this chip computes the part of
 the sum that its own experts give. Assignments to experts held elsewhere sort
 past the last group and contribute nothing here; nothing stands in for them.
-The expert axis of the banks still shards over a mesh's "ep" axis under GSPMD
-(Mixtral's test), where the compiler gathers what the product needs.
 """
 
 from __future__ import annotations
@@ -68,23 +79,32 @@ def sigmoid_topk_routing(
     return weights, idx
 
 
-def grouped_matmul(rows: jnp.ndarray, bank, group_sizes: jnp.ndarray) -> jnp.ndarray:
+def grouped_matmul(rows: jnp.ndarray, bank, group_sizes: jnp.ndarray, mesh=None) -> jnp.ndarray:
     """``rows [M, in]`` (sorted by group) times ``bank [G, in, out]``: row r
-    meets the matrix of its group. Rows past the last group are undefined.
+    meets the matrix of its group. Rows past the last group are UNDEFINED
+    (whatever memory held; never read them but through a select).
+    ``mesh``: the mesh of several devices the caller's model runs under, if
+    any; the bank may be sharded over it, and the product is then left to XLA.
     A weight-only int8 bank dequantizes into the product, scaled per row by
     its group's output-channel scales."""
-    if not isinstance(bank, QuantizedLinear):
-        return jax.lax.ragged_dot(rows, bank, group_sizes)
-    y = jax.lax.ragged_dot(
-        rows, bank.q.astype(rows.dtype), group_sizes,
-        preferred_element_type=jnp.float32,
-    )
-    ends = jnp.cumsum(group_sizes)
-    group_of_row = jnp.minimum(
-        jnp.searchsorted(ends, jnp.arange(rows.shape[0]), side="right"),
-        bank.s.shape[0] - 1,
-    )
-    return (y * bank.s[group_of_row]).astype(rows.dtype)
+    if isinstance(bank, QuantizedLinear):
+        y = jax.lax.ragged_dot(
+            rows, bank.q.astype(rows.dtype), group_sizes,
+            preferred_element_type=jnp.float32,
+        )
+        ends = jnp.cumsum(group_sizes)
+        group_of_row = jnp.minimum(
+            jnp.searchsorted(ends, jnp.arange(rows.shape[0]), side="right"),
+            bank.s.shape[0] - 1,
+        )
+        return (y * bank.s[group_of_row]).astype(rows.dtype)
+    from dynamo_tpu.ops.attention import _on_tpu, _pallas_enabled
+    from dynamo_tpu.ops.pallas.grouped_matmul import column_block, grouped_matmul_pallas
+
+    fits = column_block(*bank.shape[1:], bank.dtype.itemsize) is not None
+    if mesh is None and fits and rows.dtype == bank.dtype and _pallas_enabled(True):
+        return grouped_matmul_pallas(rows, bank, group_sizes, interpret=not _on_tpu())
+    return jax.lax.ragged_dot(rows, bank, group_sizes)
 
 
 def moe_dispatch(
@@ -120,15 +140,16 @@ def moe_block(
     w_down: jnp.ndarray,  # [E, F, D]
     num_experts_per_tok: int,
     renormalize: bool = True,
+    mesh=None,  # the caller's mesh of several devices, if any: `grouped_matmul`
 ) -> jnp.ndarray:
     """Softmax-routed SwiGLU experts, all held here (Mixtral, DeepSeek-V2)."""
     logits = hidden.astype(jnp.float32) @ router_w.astype(jnp.float32)  # [T, E]
     weights, idx = topk_routing(logits, num_experts_per_tok, renormalize=renormalize)
 
     def ffn(rows, group_sizes):
-        gated = jax.nn.silu(grouped_matmul(rows, w_gate, group_sizes))
-        up = grouped_matmul(rows, w_up, group_sizes)
-        return grouped_matmul(gated * up, w_down, group_sizes)
+        gated = jax.nn.silu(grouped_matmul(rows, w_gate, group_sizes, mesh))
+        up = grouped_matmul(rows, w_up, group_sizes, mesh)
+        return grouped_matmul(gated * up, w_down, group_sizes, mesh)
 
     out, _ = moe_dispatch(hidden, weights, idx, ffn, num_held=router_w.shape[1])
     return out.astype(hidden.dtype)

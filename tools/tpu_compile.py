@@ -188,6 +188,28 @@ def _ssm_update_case(slots: int = 128):
     return Case(f"ssm-state-update-{slots}slots", build)
 
 
+def _grouped_matmul_case(name: str, M: int, K: int, N: int):
+    """The expert layer's grouped product at `nemotron3-super-ep4`'s widths
+    (128 held experts, latent 1024, intermediate 2688, bf16): M static rows,
+    22 a token."""
+
+    def build(S):
+        from dynamo_tpu.ops.pallas.grouped_matmul import grouped_matmul_pallas
+
+        return grouped_matmul_pallas, (
+            S((M, K), jnp.bfloat16), S((128, K, N), jnp.bfloat16), S((128,), jnp.int32),
+        )
+
+    return Case(f"moe-grouped-matmul-{name}", build)
+
+
+#: a decode step of 128 slots (both banks) and a prefill pack of 2 x 512 rows
+GROUPED_MATMUL_CASES = (
+    ("decode-w1", 128 * 22, 1024, 2688), ("decode-w2", 128 * 22, 2688, 1024),
+    ("prefill-w1", 1024 * 22, 1024, 2688),
+)
+
+
 def kernel_cases(full: bool) -> list[Case]:
     """Every Pallas kernel the default dispatch can reach, at published head
     geometries. ``full``: each page size (16 engine default, 64, 128) x each
@@ -213,6 +235,8 @@ def kernel_cases(full: bool) -> list[Case]:
             cases += [_mla_decode_case(ps, False), _mla_decode_case(ps, True)]
             cases += [_mla_prefill_case(ps, T) for T in (128, 256, 512, 1024)]
         cases.append(_ssm_update_case())
+        cases += [_grouped_matmul_case(*c) for c in GROUPED_MATMUL_CASES]
+        cases.append(_grouped_matmul_case("prefill-w2", 1024 * 22, 2688, 1024))
         return cases
     return [
         # decode: folded, lookahead, and the per-sequence kernel lookahead
@@ -237,6 +261,9 @@ def kernel_cases(full: bool) -> list[Case]:
         _mla_prefill_case(16, 512),
         # the Mamba-2 state update, in place over the donated state
         _ssm_update_case(),
+        # the expert layer's grouped product: whole-matrix blocks of 5.25 MiB,
+        # double-buffered, need more scoped VMEM than the default 16 MiB
+        *(_grouped_matmul_case(*c) for c in GROUPED_MATMUL_CASES),
     ]
 
 
