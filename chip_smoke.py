@@ -843,7 +843,7 @@ def child_parity(sizes: Sizes, args) -> int:
             got = paged_prefill_attention_pallas(q, k, v, table, pos, interpret=interpret, lookahead=lookahead)
         return got, reference(A.paged_prefill_attention, q, k, v, table, pos)
 
-    def mla(ps, lookahead=None, T=None, prefix=0):
+    def mla(ps, T=None, prefix=0):
         """The model's own Pallas call against its own _absorbed_attention."""
         h, dc, dn, dr, dv = (16, 512, 128, 64, 128) if full else (4, 128, 16, 64, 16)
         cfg = DeepseekConfig.tiny_mla(num_heads=h, kv_lora_rank=dc, qk_nope_head_dim=dn,
@@ -861,13 +861,7 @@ def child_parity(sizes: Sizes, args) -> int:
             B, maxp, tables, pos = ragged_batch(ps)
             pages = pool(B * maxp + 1)
             qn, qr = normal(B, h, dn), normal(B, h, dr)
-            os.environ.pop("DYNTPU_DECODE_KERNEL", None)
-            if lookahead:
-                os.environ["DYNTPU_DECODE_KERNEL"] = "lookahead"
-            try:
-                got = jax.jit(model._mla_decode_pallas)(lp, qn, qr, pages, tables, pos)
-            finally:
-                os.environ.pop("DYNTPU_DECODE_KERNEL", None)
+            got = jax.jit(model._mla_decode_pallas)(lp, qn, qr, pages, tables, pos)
 
             def one(qn_b, qr_b, pt_b, pos_b):
                 ctx = pages[pt_b].reshape(pt_b.shape[0] * ps, lat)
@@ -960,7 +954,6 @@ def child_parity(sizes: Sizes, args) -> int:
         ("prefill basic mixtral ps16 bf16", lambda: prefill(*mixtral, 16, T, prefix, False, lookahead=False)),
         ("prefill folded qwen2.5-7b tp4-shard ps16 bf16", lambda: prefill(*shard, 16, T, prefix, False, folded=True)),
         ("mla decode classic ps16", lambda: mla(16)),
-        ("mla decode lookahead ps16", lambda: mla(16, lookahead=True)),
         ("mla prefill ps16", lambda: mla(16, T=T, prefix=prefix)),
         # Mamba-2 at the published widths (128 heads x 64 x 128), 24 slots
         ("ssm state update nemotron-h f32", lambda: ssm_update(*((24, 128, 64, 8, 128) if full else (6, 8, 8, 2, 128)))),

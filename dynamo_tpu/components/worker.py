@@ -612,7 +612,7 @@ def main(argv=None) -> None:
                    help="packed prefill calls dispatched ahead of result "
                         "materialization (1 = strict reconcile per call; "
                         "default 2 overlaps call N+1's host prep with call "
-                        "N's device time — see tools/profile_prefill.py)")
+                        "N's device time)")
     p.add_argument("--host-cache-blocks", type=int, default=0,
                    help="host-DRAM KV offload tier capacity in blocks "
                         "(0 disables; long-context cold KV drains here "

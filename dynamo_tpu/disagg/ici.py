@@ -107,20 +107,6 @@ def transfer_count() -> int:
         return len(_transfers)
 
 
-def drain_all() -> int:
-    """Drop every parked transfer. Teardown belt for harnesses that cycle
-    disagg worker fleets in one process: an abandoned transfer holds a
-    DEVICE array (hundreds of MB at serving geometry), and anything a
-    cancelled consumer raced past must not pin HBM into the next fleet.
-    Tombstones are kept — a park still in flight on a producer thread must
-    find its tombstone and drop, or it would re-pin HBM right after the
-    drain. Returns the number of parked arrays dropped."""
-    with _lock:
-        n = len(_transfers)
-        _transfers.clear()
-        return n
-
-
 def total_transfers() -> int:
     """Device transfers ever started."""
     with _lock:

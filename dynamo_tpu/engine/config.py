@@ -138,8 +138,7 @@ class EngineConfig:
     # cap, preemption victims lowest-class-first, and a waiting critical
     # request may evict a lower-class lane (preferring live migration over
     # preempt+recompute when a peer can adopt). False = classes ignored:
-    # pure FIFO admission and recency-only victims (the pre-QoS behavior,
-    # and the bench's isolation-off arm).
+    # pure FIFO admission and recency-only victims (the pre-QoS behavior).
     qos: bool = True
     # how long a critical request must sit queued with no free slot before
     # the scheduler evicts a lower-class lane for it (the anti-thrash gate)
@@ -199,9 +198,9 @@ class EngineConfig:
     # packed prefill calls dispatched ahead of result materialization (the
     # prefill analogue of pipeline_depth): call N+1's host prep + dispatch
     # overlap call N's device time, so the per-call fixed cost
-    # (tools/profile_prefill.py) stops serializing with the kernel. 1 =
+    # (StepAnatomy.prefill_fixed_ms) stops serializing with the kernel. 1 =
     # strict reconcile-before-next-dispatch — the old behavior in the mixed
-    # decode+prefill regime, and the bench prefill_anatomy baseline arm.
+    # decode+prefill regime (tests/test_prefill_pipeline.py compares both).
     prefill_pipeline_depth: int = 2
     # admission fairness: at most this many (packed) prefill calls dispatch
     # per scheduler step before decode windows get the chip again. A request

@@ -1334,7 +1334,7 @@ class AsyncJaxEngine:
 
     def stage_snapshot(self) -> dict:
         """Per-stage latency attribution totals (scheduler StageStats plus the
-        host-KV-offload transfer leg) — the bench artifact's breakdown source."""
+        host-KV-offload transfer leg)."""
         if self.scheduler is None:
             return {}
         snap = self.scheduler.stage.snapshot()

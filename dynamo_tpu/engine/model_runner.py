@@ -589,9 +589,7 @@ class ModelRunner:
         N: int,  # lane count the executable is compiled for (>= len(lanes))
     ):
         """Host-prep half of :meth:`prefill_chunk_batch`: build the packed
-        int/float control arrays on the host (no device work). Split out so
-        ``tools/profile_prefill.py`` can time host prep, H2D staging, and
-        dispatch against the SAME arrays production dispatches — returns
+        int/float control arrays on the host (no device work). Returns
         (ints, flts, want_extras, mp)."""
         V = self.model.config.vocab_size
         bucket = self.config.bucket_for(max(len(l[0]) for l in lanes))

@@ -267,7 +267,7 @@ class StageStats:
     """Cumulative per-stage engine-time attribution (seconds + counts).
 
     Always on — the cost is a handful of monotonic() reads per window against
-    ms-scale stages — so bench artifacts and worker stats can break a round's
+    ms-scale stages — so worker stats and /metrics can break a round's
     wall time into queue wait / prefill / decode dispatch / device sync
     without enabling tracing. Spans (DYNTPU_TRACE) add the per-request
     timeline on top of these aggregates.

@@ -2,8 +2,7 @@
 
 Pure renderer (testable without an engine): one row per scenario with the
 goodput verdict, latency percentiles against their budgets, throughput, and
-the replay harness's own health (schedule lag, errors). The same dict shape
-the bench artifact's ``replay.{scenario}.*`` keys compress from.
+the replay harness's own health (schedule lag, errors).
 """
 
 from __future__ import annotations

@@ -12,7 +12,7 @@ and the ``--dry-run`` CLI stay sub-second and importable anywhere (the
 determinism contract rides ``random.Random(seed)``, whose generators are
 stable across platforms).
 
-Builtin scenarios (``BUILTIN_SCENARIOS``) are the bench spine's workload
+Builtin scenarios (``BUILTIN_SCENARIOS``) are the replay CLI's workload
 shapes; YAML/dict overrides layer on top via ``load_scenario``.
 """
 
@@ -121,8 +121,8 @@ def _spec(**kw) -> ScenarioSpec:
     return ScenarioSpec(**kw)
 
 
-#: The bench spine's scenario set. Names are stable artifact keys
-#: (``replay.{name}.*``); geometry scales via replace() at the call site.
+#: The replay CLI's scenario set. Names are stable report keys; geometry
+#: scales via replace() at the call site.
 BUILTIN_SCENARIOS: dict = {
     # bursty chat: on/off Poisson bursts, heavy-tailed short prompts — the
     # shape that blows ITL p99 when admission serializes prefill ahead of
@@ -176,7 +176,7 @@ BUILTIN_SCENARIOS: dict = {
     # each arrival is a conversation whose turn k prompt is turn k-1's
     # prompt plus a fresh tail, with park_s of silence in between. While
     # parked, the session's KV blocks demote HBM -> host -> disk; the
-    # follow-up turn's TTFT is the cold-resume headline (bench kv_tiers)
+    # follow-up turn's TTFT is the cold-resume headline
     "parked_sessions": _spec(
         name="parked_sessions", arrival="poisson", rate_rps=2.0,
         num_requests=8, session_turns=3, park_s=20.0,

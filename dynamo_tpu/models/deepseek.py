@@ -479,20 +479,12 @@ class DeepseekModel:
         dc = c.kv_lora_rank
         q_cat = self._fold_q(lp, q_nope, q_rope)
         import functools
-        import os
 
-        # kernel choice resolved HERE (dispatch level, like ops/attention.py's
-        # GQA dispatcher) and passed as a static argument — not read inside
-        # the jitted kernel where it would freeze at first trace per shape
-        lookahead = os.environ.get("DYNTPU_DECODE_KERNEL") == "lookahead"
-        interpret = not _on_tpu()
         kernel = functools.partial(
-            paged_mla_decode_attention_pallas, d_c=dc,
-            lookahead=lookahead, interpret=interpret,
+            paged_mla_decode_attention_pallas, d_c=dc, interpret=not _on_tpu()
         )
         a_lat = self._latent_kernel(
-            "mla decode", "lookahead" if lookahead else "classic", kernel,
-            q_cat, pool, page_tables, positions,
+            "mla decode", "classic", kernel, q_cat, pool, page_tables, positions
         )
         out = jnp.einsum(
             "bhc,chv->bhv", a_lat.astype(jnp.float32), lp["w_vb"].astype(jnp.float32)

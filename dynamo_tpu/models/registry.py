@@ -24,7 +24,7 @@ log = get_logger("models.registry")
 
 # single-entry params cache for the synthetic "tiny*" families: colocated
 # engines serving the SAME model (disagg prefill+decode pairs, router
-# replicas, the bench's engine fleets) share one set of immutable weight
+# replicas, a test's engine fleets) share one set of immutable weight
 # buffers instead of materializing a copy each — params are never donated
 # (only kv/slot_state are), and ModelRunner's device_put is a no-op when the
 # sharding already matches, so sharing is safe. One entry only (loading a

@@ -302,7 +302,6 @@ def dispatch_paged_decode_attention(q, k_pages, v_pages, page_tables, positions,
     With a tensor-parallel mesh the kernel runs under shard_map: attention is
     head-parallel, so each device handles its Hq/Hkv shard with no
     communication (GSPMD cannot partition a pallas_call by itself)."""
-    import os
 
     Hq, D = q.shape[1], q.shape[-1]
     folded = k_pages.ndim == 3
@@ -315,42 +314,31 @@ def dispatch_paged_decode_attention(q, k_pages, v_pages, page_tables, positions,
     from dynamo_tpu.ops.pallas.paged_attention import (
         decode_tile_pages,
         lookahead_window,
-        paged_decode_attention_pallas,
-        paged_decode_attention_pallas_chunked,
         paged_decode_attention_pallas_folded,
-        paged_decode_attention_pallas_grouped,
         paged_decode_attention_pallas_lookahead,
     )
 
-    # lookahead (default): one sequence per grid program, a TILE of pages
-    # (128 context tokens) per loop iteration, cross-program prefetch of the
-    # next sequence's first tiles; tile width and window follow from the
-    # shapes (decode_tile_pages, lookahead_window), and it falls back to
-    # perseq internally when not even one window tile fits the VMEM budget.
-    # perseq: a page per iteration, in-program double buffer only.
-    # chunked/grouped: selectable, bf16 only; on this chip chunked ran 2.3x
-    # faster than the page-at-a-time lookahead and 1.3x slower than the tiled
-    # one (design record in ops/pallas/paged_attention.py).
-    # folded: head_dim < 128 shapes (Mosaic can't DMA-slice sub-128-lane
-    # pools; heads live folded into the lane dim); still a page at a time.
-    quantized = isinstance(k_pages, QuantizedPages)
-    kernel_choice = os.environ.get("DYNTPU_DECODE_KERNEL", "lookahead")
-    if quantized and kernel_choice in ("chunked", "grouped"):
-        # chunked/grouped never grew int8 support — an int8 cache rides the
-        # lookahead/perseq family
-        kernel_choice = "lookahead"
-    if folded or D % 128 != 0:
-        kernel_choice = "folded"
-    kernel = {
-        "folded": paged_decode_attention_pallas_folded,
-        "lookahead": paged_decode_attention_pallas_lookahead,
-        "chunked": paged_decode_attention_pallas_chunked,
-        "grouped": paged_decode_attention_pallas_grouped,
-    }.get(kernel_choice, paged_decode_attention_pallas)
+    # One kernel per shape class, chosen from the shapes alone.
+    # folded: the pool is folded or head_dim is under a lane row (Mosaic
+    # can't DMA-slice sub-128-lane pools; heads live folded into the lane
+    # dim); still a page at a time.
+    # lookahead: one sequence per grid program, a TILE of pages (128 context
+    # tokens) per loop iteration, cross-program prefetch of the next
+    # sequence's first tiles; tile width and window follow from the shapes
+    # (decode_tile_pages, lookahead_window), and it falls back to perseq (a
+    # page per iteration, in-program double buffer only) internally when not
+    # even one window tile fits the VMEM budget (design record in
+    # ops/pallas/paged_attention.py).
+    use_folded = folded or D % 128 != 0
+    kernel = (
+        paged_decode_attention_pallas_folded
+        if use_folded
+        else paged_decode_attention_pallas_lookahead
+    )
     interpret = not _on_tpu()
     tp = 1 if mesh is None else mesh.shape.get("tp", 1)
     path = f"pallas:{kernel.__name__}"
-    if kernel_choice == "lookahead" and num_kv_heads % tp == 0:
+    if not use_folded and num_kv_heads % tp == 0:
         # the geometry one head shard's kernel derives, so a server log says
         # which tile width ran
         geometry = (k_pages.shape[1], num_kv_heads // tp, D, k_pages.dtype.itemsize)
@@ -378,15 +366,6 @@ def use_pallas_prefill(head_dim: int, chunk_len: int, block_q: int = 128) -> boo
     head_dim and block-divisible chunks (buckets are multiples of 128 in
     practice)."""
     return chunk_len % block_q == 0 and _pallas_enabled(head_dim % 128 == 0)
-
-
-def prefill_kernel_lookahead() -> bool:
-    """DYNTPU_PREFILL_KERNEL: "lookahead" (default — cross-program context-
-    tile prefetch, the decode lookahead insight ported to the flash prefill
-    grid) or "basic" (the in-program-only double buffer; escape hatch)."""
-    import os
-
-    return os.environ.get("DYNTPU_PREFILL_KERNEL", "lookahead") != "basic"
 
 
 def dispatch_paged_prefill_attention(
@@ -440,6 +419,8 @@ def dispatch_paged_prefill_attention(
     from dynamo_tpu.ops.pallas.prefill_attention import (
         paged_prefill_attention_pallas,
         paged_prefill_attention_pallas_folded,
+        prefill_lookahead_window,
+        prefill_tile_pages,
     )
 
     interpret = not _on_tpu()
@@ -449,11 +430,14 @@ def dispatch_paged_prefill_attention(
         )
         path = "pallas:folded"
     else:
-        lookahead = prefill_kernel_lookahead()
-        fn = functools.partial(
-            paged_prefill_attention_pallas, interpret=interpret, lookahead=lookahead
+        fn = functools.partial(paged_prefill_attention_pallas, interpret=interpret)
+        # the window one head shard's kernel derives: it takes the basic
+        # in-program double buffer where not one lookahead tile fits
+        ps = k_pages.shape[1]
+        window = prefill_lookahead_window(
+            ps, prefill_tile_pages(ps), num_kv_heads // tp, D, k_pages.dtype.itemsize
         )
-        path = "pallas:" + ("lookahead" if lookahead else "basic")
+        path = "pallas:" + ("lookahead" if window else "basic")
     if interpret:
         path += " interpret"
     if tp == 1:

@@ -100,178 +100,22 @@ def _kernel(
     out_ref[0] = (acc / jnp.maximum(l, 1e-20)[:, None]).astype(out_ref.dtype)
 
 
-def _kernel_lookahead(
-    # scalar prefetch
-    page_tables_ref,  # [B, max_pages] SMEM
-    lengths_ref,  # [B] SMEM
-    # inputs
-    q_ref,  # [1, H, latent] VMEM
-    pages_hbm,  # [P, ps, latent] HBM
-    # output
-    out_ref,  # [1, H, d_c] VMEM
-    # scratch
-    pre,  # [2, W, ps, latent] VMEM — per-parity prefetch window
-    tail,  # [2, ps, latent] VMEM — double buffer for pages >= W
-    sems_pre,  # [2, W]
-    sems_tail,  # [2]
-    *,
-    page_size: int,
-    d_c: int,
-    lookahead: int,
-):
-    """Cross-program DMA pipelining (see paged_attention._kernel_lookahead):
-    program b issues program b+1's first W latent-page DMAs into the opposite
-    parity's slots while computing on its own (prefetched by b-1). Latent
-    pages are small (~147 KB at ps=128/latent=576), so the per-program DMA
-    LATENCY — not bandwidth — dominates the stream; hiding it across
-    programs matters even more here than for the GQA kernel."""
-    b = pl.program_id(0)
-    nb = pl.num_programs(0)
-    par = jax.lax.rem(b, 2)
-    W = lookahead
-    length = lengths_ref[b]
-    n_pages = jnp.maximum(1, pl.cdiv(length, page_size))
-
-    q = q_ref[0].astype(jnp.float32)  # [H, latent]
-
-    def pre_dma(parity, j, seq_idx):
-        return pltpu.make_async_copy(
-            pages_hbm.at[page_tables_ref[seq_idx, j]],
-            pre.at[parity, j],
-            sems_pre.at[parity, j],
-        )
-
-    def tail_dma(slot, i):
-        return pltpu.make_async_copy(
-            pages_hbm.at[page_tables_ref[b, i]], tail.at[slot], sems_tail.at[slot]
-        )
-
-    def issue_pre(seq_idx, parity):
-        npg = jnp.maximum(1, pl.cdiv(lengths_ref[seq_idx], page_size))
-        for j in range(W):
-
-            @pl.when(j < npg)
-            def _(j=j):
-                pre_dma(parity, j, seq_idx).start()
-
-    @pl.when(b == 0)
-    def _():
-        issue_pre(0, 0)
-
-    @pl.when(b + 1 < nb)
-    def _():
-        issue_pre(b + 1, 1 - par)
-
-    @pl.when(W < n_pages)
-    def _():
-        tail_dma(W % 2, W).start()
-
-    def merge(carry, rows, i):
-        m, l, acc = carry
-        scores = jax.lax.dot_general(
-            q, rows, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        idx = i * page_size + jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
-        scores = jnp.where(idx < length, scores, _NEG_INF)
-        chunk_max = jnp.max(scores, axis=-1)
-        new_m = jnp.maximum(m, chunk_max)
-        corr = jnp.exp(m - new_m)
-        probs = jnp.exp(scores - new_m[:, None])
-        new_l = l * corr + jnp.sum(probs, axis=-1)
-        chunk_out = jax.lax.dot_general(
-            probs, rows[:, :d_c], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return new_m, new_l, acc * corr[:, None] + chunk_out
-
-    def pre_body(j, carry):
-        pre_dma(par, j, b).wait()
-        return merge(carry, pre[par, j].astype(jnp.float32), j)
-
-    def tail_body(j, carry):
-        slot = jax.lax.rem(j, 2)
-        next_slot = jax.lax.rem(j + 1, 2)
-
-        @pl.when(j + 1 < n_pages)
-        def _():
-            tail_dma(next_slot, j + 1).start()
-
-        tail_dma(slot, j).wait()
-        return merge(carry, tail[slot].astype(jnp.float32), j)
-
-    H = q_ref.shape[1]
-    m0 = jnp.full((H,), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((H,), jnp.float32)
-    acc0 = jnp.zeros((H, d_c), jnp.float32)
-    carry = jax.lax.fori_loop(0, jnp.minimum(W, n_pages), pre_body, (m0, l0, acc0))
-    m, l, acc = jax.lax.fori_loop(W, n_pages, tail_body, carry)
-
-    out_ref[0] = (acc / jnp.maximum(l, 1e-20)[:, None]).astype(out_ref.dtype)
-
-
-#: scratch budget mirrors paged_attention's (latent pages are much smaller)
-_LOOKAHEAD_SCRATCH_BYTES = 6 * 1024 * 1024
-
-
-def _mla_lookahead_window(page_size: int, latent: int, itemsize: int) -> int:
-    page_bytes = page_size * latent * itemsize
-    budget = _LOOKAHEAD_SCRATCH_BYTES - 2 * page_bytes
-    return max(0, min(4, budget // (2 * page_bytes)))
-
-
-@functools.partial(jax.jit, static_argnames=("d_c", "lookahead", "interpret"))
+@functools.partial(jax.jit, static_argnames=("d_c", "interpret"))
 def paged_mla_decode_attention_pallas(
     q_cat: jnp.ndarray,  # [B, H, latent] pre-scaled
     pages: jnp.ndarray,  # [P, ps, latent]
     page_tables: jnp.ndarray,  # [B, max_pages] int32
     positions: jnp.ndarray,  # [B] int32 query positions
     d_c: int,
-    lookahead: bool = False,
     interpret: bool = False,
 ) -> jnp.ndarray:
     B, H, latent = q_cat.shape
     P, ps, _ = pages.shape
     lengths = positions.astype(jnp.int32) + 1
-    # The MLA stream defaults to the simpler classic double buffer (its one
-    # small latent DMA per page pipelines well already); an A/B on an earlier
-    # machine found the cross-program prefetch variant within noise of it.
-    # Neither has been measured on the current chip (ROADMAP S1).
-    # DYNTPU_DECODE_KERNEL=lookahead opts in —
-    # resolved by the DISPATCHER (deepseek._mla_decode_pallas) and passed as
-    # a static jit argument: an os.environ read here would freeze into the
-    # first-traced executable per shape (ADVICE r5).
-    W = _mla_lookahead_window(ps, latent, pages.dtype.itemsize) if lookahead else 0
-
-    if W >= 1:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B,),
-            in_specs=[
-                pl.BlockSpec((1, H, latent), lambda b, *_: (b, 0, 0)),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
-            out_specs=pl.BlockSpec((1, H, d_c), lambda b, *_: (b, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((2, W, ps, latent), pages.dtype),
-                pltpu.VMEM((2, ps, latent), pages.dtype),
-                pltpu.SemaphoreType.DMA((2, W)),
-                pltpu.SemaphoreType.DMA((2,)),
-            ],
-        )
-        kernel = pl.pallas_call(
-            functools.partial(_kernel_lookahead, page_size=ps, d_c=d_c, lookahead=W),
-            out_shape=jax.ShapeDtypeStruct((B, H, d_c), q_cat.dtype),
-            grid_spec=grid_spec,
-            # cross-program scratch persistence (program b prefetches b+1's
-            # pages into the opposite parity's slots) requires the grid to run
-            # SERIALLY — pin it rather than relying on the implicit default
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("arbitrary",)
-            ),
-            interpret=interpret,
-        )
-        return kernel(page_tables.astype(jnp.int32), lengths, q_cat, pages)
-
+    # One latent page per loop iteration through the classic in-program
+    # double buffer. It has not been measured on the current chip; the GQA
+    # kernel's record (paged_attention.py) says a tile of pages per iteration
+    # is what pays there, and ROADMAP M2 brings that with a cell to price it.
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B,),

@@ -67,6 +67,10 @@ qwen2.5-3b.chat; the HBM floor of the two is 33 and 11 us).
     are Mosaic-illegal outright (tpu.matmul requires leading batch dims).
   - perseq stays as the fallback for a geometry whose window does not fit;
     folded (head_dim < 128) is still a page at a time and has no cell.
+  - chunked, and grouped (several sequences a grid program, a page at a
+    time), were deleted in PR 31: bf16 only, slower than the tiled default at
+    every shape measured, and reachable only through an environment variable.
+    The file holds three kernels: lookahead, perseq, folded.
 
 Int8 KV (quant/kv.py QuantizedPages): perseq, lookahead, and folded accept
 int8 pools plus their per-row f32 scales, which arrive as lane-aligned rows
@@ -77,7 +81,6 @@ DMAs (the HBM context stream halves — that is the
 win) and dequantization is applied to the score/prob tiles in VMEM:
 ``scores *= k_s`` / ``probs *= v_s`` is the exact per-column algebra, and
 both are lane-axis broadcasts (Mosaic-legal; no sub-128 minor-dim reshapes).
-chunked/grouped stay bf16-only — the dispatcher never routes int8 to them.
 """
 
 from __future__ import annotations
@@ -228,163 +231,6 @@ def _kernel(
 
     out = acc / jnp.maximum(l, 1e-20)[..., None]
     out_ref[0] = out.reshape(Hq, D).astype(out_ref.dtype)
-
-
-def _kernel_grouped(
-    # scalar prefetch
-    page_tables_ref,  # [B, max_pages] SMEM
-    lengths_ref,  # [B] SMEM
-    # inputs
-    q_ref,  # [Gq, Hq, D] VMEM (this group's queries)
-    k_hbm,  # [P, ps, Hkv, D] HBM
-    v_hbm,  # [P, ps, Hkv, D] HBM
-    # output
-    out_ref,  # [Gq, Hq, D] VMEM
-    # scratch
-    k_scratch,  # [2, Gq, ps, Hkv, D] VMEM
-    v_scratch,  # [2, Gq, ps, Hkv, D] VMEM
-    sems,  # DMA sems [2, Gq, 2]
-    *,
-    page_size: int,
-    group: int,
-):
-    """Gq sequences per grid program: page index walks the whole group at
-    once (2*Gq outstanding DMAs per iteration) and the per-program fixed cost
-    amortizes across the group — the winning regime once pages are large
-    (few pages/seq, per-PROGRAM overhead dominates the per-seq kernel)."""
-    g0 = pl.program_id(0) * group
-    Hq, D = q_ref.shape[1], q_ref.shape[2]
-    Hkv = k_hbm.shape[2]
-    G = Hq // Hkv
-
-    lengths = [lengths_ref[g0 + j] for j in range(group)]
-    n_pages = [jnp.maximum(1, pl.cdiv(lengths[j], page_size)) for j in range(group)]
-    max_n = n_pages[0]
-    for j in range(1, group):
-        max_n = jnp.maximum(max_n, n_pages[j])
-
-    qs = [q_ref[j].reshape(Hkv, G, D) for j in range(group)]
-    scale = 1.0 / jnp.sqrt(jnp.float32(D))
-
-    def dma(slot, j, i, which):
-        hbm, scratch = (k_hbm, k_scratch) if which == 0 else (v_hbm, v_scratch)
-        return pltpu.make_async_copy(
-            hbm.at[page_tables_ref[g0 + j, i]],
-            scratch.at[slot, j],
-            sems.at[slot, j, which],
-        )
-
-    def start_all(slot, i):
-        for j in range(group):  # static unroll
-            @pl.when(i < n_pages[j])
-            def _(j=j):
-                dma(slot, j, i, 0).start()
-                dma(slot, j, i, 1).start()
-
-    def wait_all(slot, i):
-        for j in range(group):
-            @pl.when(i < n_pages[j])
-            def _(j=j):
-                dma(slot, j, i, 0).wait()
-                dma(slot, j, i, 1).wait()
-
-    start_all(0, 0)
-
-    def body(i, carry):
-        m, l, acc = carry  # [group, Hkv, G], [group, Hkv, G], [group, Hkv, G, D]
-        slot = jax.lax.rem(i, 2)
-        next_slot = jax.lax.rem(i + 1, 2)
-
-        @pl.when(i + 1 < max_n)
-        def _():
-            start_all(next_slot, i + 1)
-
-        wait_all(slot, i)
-
-        idx = i * page_size + jax.lax.broadcasted_iota(jnp.int32, (1, 1, page_size), 2)
-        vidx = i * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size, 1), 1
-        )
-        ms, ls, accs = [], [], []
-        for j in range(group):
-            kt = jnp.transpose(k_scratch[slot, j], (1, 0, 2))  # [Hkv, ps, D] bf16
-            vt = jnp.transpose(v_scratch[slot, j], (1, 0, 2))
-            scores = jax.lax.dot_general(
-                qs[j], kt, (((2,), (2,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32,
-            ) * scale
-            # beyond-length/stale rows: mask K scores outright and zero V so
-            # 0-weight garbage (or uninitialized first-call VMEM) can't
-            # poison acc via 0 * NaN
-            scores = jnp.where(idx < lengths[j], scores, _NEG_INF)
-            vt = jnp.where(vidx < lengths[j], vt, 0)
-
-            chunk_max = jnp.max(scores, axis=-1)
-            new_m = jnp.maximum(m[j], chunk_max)
-            corr = jnp.exp(m[j] - new_m)
-            probs = jnp.exp(scores - new_m[..., None])
-            new_l = l[j] * corr + jnp.sum(probs, axis=-1)
-            chunk_out = jax.lax.dot_general(
-                probs.astype(kt.dtype), vt, (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32,
-            )
-            ms.append(new_m)
-            ls.append(new_l)
-            accs.append(acc[j] * corr[..., None] + chunk_out)
-        return jnp.stack(ms), jnp.stack(ls), jnp.stack(accs)
-
-    m0 = jnp.full((group, Hkv, G), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((group, Hkv, G), jnp.float32)
-    acc0 = jnp.zeros((group, Hkv, G, D), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, max_n, body, (m0, l0, acc0))
-
-    out = acc / jnp.maximum(l, 1e-20)[..., None]
-    out_ref[...] = out.reshape(group, Hq, D).astype(out_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def paged_decode_attention_pallas_grouped(
-    q: jnp.ndarray,  # [B, Hq, D]
-    k_pages: jnp.ndarray,  # [P, ps, Hkv, D]
-    v_pages: jnp.ndarray,
-    page_tables: jnp.ndarray,  # [B, max_pages] int32
-    positions: jnp.ndarray,  # [B] int32 query positions
-    interpret: bool = False,
-) -> jnp.ndarray:
-    B, Hq, D = q.shape
-    P, ps, Hkv, _ = k_pages.shape
-    lengths = positions.astype(jnp.int32) + 1
-    # largest group that divides B AND keeps the double-buffered K+V scratch
-    # within a conservative VMEM budget (v5e scoped limit is ~16MB)
-    bytes_per_seq = 2 * 2 * ps * Hkv * D * k_pages.dtype.itemsize  # 2 slots x k+v
-    group = 1
-    for cand in (8, 4, 2):
-        if B % cand == 0 and cand * bytes_per_seq <= 8 * 1024 * 1024:
-            group = cand
-            break
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B // group,),
-        in_specs=[
-            pl.BlockSpec((group, Hq, D), lambda b, *_: (b, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((group, Hq, D), lambda b, *_: (b, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((2, group, ps, Hkv, D), k_pages.dtype),
-            pltpu.VMEM((2, group, ps, Hkv, D), v_pages.dtype),
-            pltpu.SemaphoreType.DMA((2, group, 2)),
-        ],
-    )
-    kernel = pl.pallas_call(
-        functools.partial(_kernel_grouped, page_size=ps, group=group),
-        out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
-        grid_spec=grid_spec,
-        interpret=interpret,
-    )
-    return kernel(page_tables.astype(jnp.int32), lengths, q, k_pages, v_pages)
 
 
 def _heads_major(tile_ref):
@@ -903,157 +749,6 @@ def paged_decode_attention_pallas_folded(
     )
     args = (kf, vf, ks, vs) if quantized else (kf, vf)
     return kernel(page_tables.astype(jnp.int32), lengths, q, *args)
-
-
-def _kernel_chunked(
-    # scalar prefetch
-    page_tables_ref,  # [B, max_pages] SMEM
-    lengths_ref,  # [B] SMEM
-    # inputs
-    q_ref,  # [1, Hq, D] VMEM (this sequence's query)
-    k_hbm,  # [P, ps, Hkv, D] HBM
-    v_hbm,  # [P, ps, Hkv, D] HBM
-    # output
-    out_ref,  # [1, Hq, D] VMEM
-    # scratch
-    k_scratch,  # [2, C, ps, Hkv, D] VMEM
-    v_scratch,  # [2, C, ps, Hkv, D] VMEM
-    sems,  # DMA sems [2, C, 2]
-    *,
-    page_size: int,
-    chunk: int,
-):
-    """Per-sequence grid, C pages per loop iteration: the C k/v DMAs of a
-    chunk are all in flight together (hides HBM latency) and the softmax
-    update contracts [Hkv, G, D] x [Hkv, C*ps, D] — C*ps context positions
-    per MXU call instead of ps (the one-page version's 2x16 dots use a
-    vanishing fraction of the 128x128 MXU tile and run overhead-bound)."""
-    b = pl.program_id(0)
-    length = lengths_ref[b]
-    n_pages = jnp.maximum(1, pl.cdiv(length, page_size))
-    C = chunk
-    n_chunks = pl.cdiv(n_pages, C)
-
-    Hq, D = q_ref.shape[1], q_ref.shape[2]
-    Hkv = k_hbm.shape[2]
-    G = Hq // Hkv
-    q = q_ref[0].reshape(Hkv, G, D)  # native dtype: MXU takes bf16 directly
-    scale = 1.0 / jnp.sqrt(jnp.float32(D))
-
-    def dma(slot, j, page_idx, which):
-        hbm, scratch = (k_hbm, k_scratch) if which == 0 else (v_hbm, v_scratch)
-        return pltpu.make_async_copy(
-            hbm.at[page_tables_ref[b, page_idx]],
-            scratch.at[slot, j],
-            sems.at[slot, j, which],
-        )
-
-    def start_chunk(slot, c):
-        for j in range(C):  # static unroll
-            @pl.when(c * C + j < n_pages)
-            def _(j=j):
-                dma(slot, j, c * C + j, 0).start()
-                dma(slot, j, c * C + j, 1).start()
-
-    def wait_chunk(slot, c):
-        for j in range(C):
-            @pl.when(c * C + j < n_pages)
-            def _(j=j):
-                dma(slot, j, c * C + j, 0).wait()
-                dma(slot, j, c * C + j, 1).wait()
-
-    start_chunk(0, 0)
-
-    def body(c, carry):
-        m, l, acc = carry
-        slot = jax.lax.rem(c, 2)
-        next_slot = jax.lax.rem(c + 1, 2)
-
-        @pl.when(c + 1 < n_chunks)
-        def _():
-            start_chunk(next_slot, c + 1)
-
-        wait_chunk(slot, c)
-
-        N = C * page_size
-        # [C, ps, Hkv, D] -> [N, Hkv, D] (leading-dim merge: layout-preserving)
-        # -> [Hkv, N, D] (one bf16 relayout per chunk)
-        kt = jnp.transpose(k_scratch[slot].reshape(N, Hkv, D), (1, 0, 2))
-        vt = jnp.transpose(v_scratch[slot].reshape(N, Hkv, D), (1, 0, 2))
-        idx = c * N + jax.lax.broadcasted_iota(jnp.int32, (1, 1, N), 2)
-        vidx = c * N + jax.lax.broadcasted_iota(jnp.int32, (1, N, 1), 1)
-
-        # [Hkv, G, N] = [Hkv, G, D] x [Hkv, N, D]
-        scores = jax.lax.dot_general(
-            q, kt, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
-        ) * scale
-        # beyond-length/unfetched tail: mask K scores outright and zero V so
-        # 0-weight garbage (or uninitialized first-call VMEM) can't poison
-        # acc via 0 * NaN
-        scores = jnp.where(idx < length, scores, _NEG_INF)
-        vt = jnp.where(vidx < length, vt, 0)
-
-        chunk_max = jnp.max(scores, axis=-1)  # [Hkv, G]
-        new_m = jnp.maximum(m, chunk_max)
-        corr = jnp.exp(m - new_m)
-        probs = jnp.exp(scores - new_m[..., None])  # [Hkv, G, N]
-        new_l = l * corr + jnp.sum(probs, axis=-1)
-        # [Hkv, G, D] = [Hkv, G, N] x [Hkv, N, D]; probs in the pages' dtype
-        chunk_out = jax.lax.dot_general(
-            probs.astype(kt.dtype), vt, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )
-        new_acc = acc * corr[..., None] + chunk_out
-        return new_m, new_l, new_acc
-
-    m0 = jnp.full((Hkv, G), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((Hkv, G), jnp.float32)
-    acc0 = jnp.zeros((Hkv, G, D), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, n_chunks, body, (m0, l0, acc0))
-
-    out = acc / jnp.maximum(l, 1e-20)[..., None]
-    out_ref[0] = out.reshape(Hq, D).astype(out_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def paged_decode_attention_pallas_chunked(
-    q: jnp.ndarray,  # [B, Hq, D]
-    k_pages: jnp.ndarray,  # [P, ps, Hkv, D]
-    v_pages: jnp.ndarray,  # [P, ps, Hkv, D]
-    page_tables: jnp.ndarray,  # [B, max_pages] int32
-    positions: jnp.ndarray,  # [B] int32 query positions
-    interpret: bool = False,
-) -> jnp.ndarray:
-    B, Hq, D = q.shape
-    P, ps, Hkv, _ = k_pages.shape
-    max_pages = page_tables.shape[1]
-    lengths = positions.astype(jnp.int32) + 1
-    # ~256 context positions per chunk: MXU-worthy contraction length while
-    # 2 x 2 x C pages of scratch stay tiny vs VMEM
-    chunk = max(1, min(max_pages, -(-256 // ps)))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, Hq, D), lambda b, *_: (b, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((1, Hq, D), lambda b, *_: (b, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((2, chunk, ps, Hkv, D), k_pages.dtype),
-            pltpu.VMEM((2, chunk, ps, Hkv, D), v_pages.dtype),
-            pltpu.SemaphoreType.DMA((2, chunk, 2)),
-        ],
-    )
-    kernel = pl.pallas_call(
-        functools.partial(_kernel_chunked, page_size=ps, chunk=chunk),
-        out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
-        grid_spec=grid_spec,
-        interpret=interpret,
-    )
-    return kernel(page_tables.astype(jnp.int32), lengths, q, k_pages, v_pages)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -26,7 +26,10 @@ Two scheduling variants share the math:
     repeats T/block_q times per chunk per layer. Tiles >= lookahead stream
     through the classic in-program double buffer. What the window costs and
     buys at head_dim 128 has not been measured on the current chip (ROADMAP).
-    DYNTPU_PREFILL_KERNEL=basic selects the basic variant.
+
+``paged_prefill_attention_pallas`` takes the lookahead variant wherever one
+window tile fits its scratch budget (``prefill_lookahead_window``) and the
+basic one elsewhere: a choice by shape, with no setting.
 
 Int8 KV (quant/kv.py QuantizedPages): the pools arrive as int8 plus a
 per-row f32 scale plane. The scale rows a chunk needs are gathered by XLA
@@ -74,16 +77,21 @@ _NEG_INF = -1e30
 PREFILL_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
 
+def prefill_tile_pages(page_size: int) -> int:
+    """Pages per context tile: 128 rows for small pages, else one page."""
+    return max(1, 128 // page_size)
+
+
 def _unpack_pools(k_pages, v_pages, page_table):
     """(k, v, k_scale tiles | None, v_scale tiles | None, tile_pages) from
     plain or QuantizedPages pools; a context tile is ``tile_pages`` pages
-    (128 rows for small pages). Scale tiles are ``gather_scale_rows`` over
+    (``prefill_tile_pages``). Scale tiles are ``gather_scale_rows`` over
     the page table (edge-padded to whole tiles: the kernels clamp their page
     indices the same way and mask what lies beyond the table): row t is
     context tile t's [1, S] scale row."""
+    tile_pages = prefill_tile_pages(k_pages.shape[1])
     if not isinstance(k_pages, QuantizedPages):
-        return k_pages, v_pages, None, None, max(1, 128 // k_pages.shape[1])
-    tile_pages = max(1, 128 // k_pages.q.shape[1])
+        return k_pages, v_pages, None, None, tile_pages
     table = jnp.pad(page_table, (0, -page_table.shape[0] % tile_pages), mode="edge")
     return (
         k_pages.q, v_pages.q,
@@ -258,71 +266,6 @@ def _kernel(
     out = acc / jnp.maximum(l, 1e-20)[..., None]  # [Hkv, G*Bq, D]
     out_ref[...] = (
         out.reshape(Hkv, G, Bq, D).transpose(2, 0, 1, 3).reshape(Bq, Hq, D)
-    ).astype(out_ref.dtype)
-
-
-def _kernel_dmaonly(
-    *refs,
-    page_size: int,
-    max_pages: int,
-    tile_pages: int,
-    block_q: int,
-    quantized: bool,
-):
-    """Null-hypothesis prefill kernel: ``_kernel``'s exact grid, causal tile
-    bound, and double-buffered context-tile DMA stream with NO attention
-    math — the decode ``dmaonly`` methodology (tools/profile_attn.py)
-    ported to the prefill grid. Its wall time is the irreducible per-chunk
-    HBM context traffic; the gap to the real kernel is compute not hidden
-    under DMA. Computes garbage by design — timing only."""
-    if quantized:
-        (page_table_ref, positions_ref, q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm,
-         out_ref, k_scratch, v_scratch, ks_scratch, vs_scratch, sems) = refs
-        scale_pairs = [(ks_hbm, ks_scratch), (vs_hbm, vs_scratch)]
-    else:
-        (page_table_ref, positions_ref, q_ref, k_hbm, v_hbm,
-         out_ref, k_scratch, v_scratch, sems) = refs
-        scale_pairs = []
-    pairs = [(k_hbm, k_scratch), (v_hbm, v_scratch)]
-
-    qb = pl.program_id(0)
-    Bq = q_ref.shape[0]
-    TP = tile_pages
-    S = TP * page_size
-
-    q_start = qb * block_q
-    last_pos = positions_ref[q_start + Bq - 1]
-    n_tiles = jnp.minimum(
-        pl.cdiv(last_pos + 1, S), pl.cdiv(jnp.int32(max_pages * page_size), S)
-    )
-
-    start, wait = _tile_dma_helpers(
-        page_table_ref, pairs, scale_pairs, sems, TP, max_pages
-    )
-    start(0, 0)
-
-    def body(t, acc):
-        buf = jax.lax.rem(t, 2)
-
-        @pl.when(t + 1 < n_tiles)
-        def _():
-            start(jax.lax.rem(t + 1, 2), t + 1)
-
-        wait(buf, t)
-        # consume one row per tile so the waits can't be elided; no matmuls,
-        # no softmax, no casts, no relayouts
-        return (
-            acc
-            + k_scratch[buf, 0, 0].astype(jnp.float32)
-            + v_scratch[buf, 0, 0].astype(jnp.float32)
-        )
-
-    Hkv, D = k_scratch.shape[3], k_scratch.shape[4]
-    acc = jax.lax.fori_loop(
-        0, n_tiles, body, jnp.zeros((Hkv, D), jnp.float32)
-    )
-    out_ref[...] = jnp.broadcast_to(
-        acc[:1] * 1e-6, out_ref.shape
     ).astype(out_ref.dtype)
 
 
@@ -768,36 +711,4 @@ def paged_prefill_attention_pallas(
     return _prefill_call(
         body, scratch, q, page_table, positions, pools, block_q, interpret,
         serial_grid=W >= 1,
-    )
-
-
-@functools.partial(jax.jit, static_argnames=("interpret", "block_q"))
-def paged_prefill_dmaonly(
-    q: jnp.ndarray,
-    k_pages,
-    v_pages,
-    page_table: jnp.ndarray,
-    positions: jnp.ndarray,
-    block_q: int = 128,
-    interpret: bool = False,
-) -> jnp.ndarray:
-    """Null-hypothesis A/B partner of ``paged_prefill_attention_pallas``
-    (basic variant): same grid geometry and DMA stream, no attention math.
-    ``tools/profile_prefill.py`` differences this against the real kernel to
-    split a prefill call's cost into DMA floor vs exposed compute. Output is
-    garbage by design — never dispatch it for serving."""
-    kq, vq, ks, vs, tile_pages = _unpack_pools(k_pages, v_pages, page_table)
-    _, ps, Hkv, D = kq.shape
-    shapes, sems = _tile_scratch((2,), (tile_pages, ps, Hkv, D), kq, vq, ks, vs)
-    body = functools.partial(
-        _kernel_dmaonly,
-        page_size=ps,
-        max_pages=page_table.shape[0],
-        tile_pages=tile_pages,
-        block_q=block_q,
-        quantized=ks is not None,
-    )
-    pools = (kq, vq) if ks is None else (kq, vq, ks, vs)
-    return _prefill_call(
-        body, [*shapes, sems], q, page_table, positions, pools, block_q, interpret
     )

@@ -19,7 +19,7 @@ defaults; an unset budget never fails a request.
 Exposed as the ``dynamo_goodput_*`` Prometheus families on the engine and
 HTTP-frontend /metrics surfaces (conformance-checked), in worker stats
 broadcasts (dynotop's GOODPUT column), and — via ``summarize_outcomes`` — as
-the ``replay.{scenario}.*`` sections of the bench artifact.
+the per-scenario report of ``dynamo_tpu.loadgen``'s replay.
 
 Thread-safe: the engine loop and the HTTP asyncio thread both observe.
 """
@@ -331,10 +331,9 @@ def summarize_outcomes(
     ttft_budget_s: Optional[float] = None,
     itl_budget_s: Optional[float] = None,
 ) -> dict:
-    """Bench/replay report over a finished outcome set: goodput against the
+    """Replay report over a finished outcome set: goodput against the
     budgets, pooled TTFT/ITL percentiles (ms), and output tok/s over
-    ``wall_s`` (the replay's wall clock). The ``replay.{scenario}.*`` keys in
-    the bench artifact come from exactly this dict."""
+    ``wall_s`` (the replay's wall clock)."""
     outcomes = list(outcomes)
     n = len(outcomes)
     met = sum(

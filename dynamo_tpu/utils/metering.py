@@ -39,8 +39,8 @@ is maintained on the *same* edges with the *same* timestamps, so
 
     sum over tenants of kv_byte_seconds[tier] == occupancy integral[tier]
 
-exactly (shared piecewise-constant integration grid; tests and the bench
-``metering`` section assert both identities).
+exactly (shared piecewise-constant integration grid; tests/test_metering.py
+asserts both identities).
 
 **Queue/token plane.** Queued-seconds per tenant at admission, plus
 admitted-vs-consumed token counters against the QoS bucket charge
@@ -89,8 +89,7 @@ class MeterLedger:
 
     Thread-safe: the engine thread writes on every dispatch phase and KV
     edge; snapshot/render/request_cost run on the asyncio and scrape
-    threads. The write path is a handful of dict float-adds under one lock —
-    the bench ``metering`` section prices it at <1% of a decode step wall.
+    threads. The write path is a handful of dict float-adds under one lock.
     The clock is injectable so conservation tests can drive a fake timeline.
     """
 
@@ -143,8 +142,7 @@ class MeterLedger:
                 device[key] = device.get(key, 0.0) + share
                 if rid:
                     # hot path: no LRU bump per phase — footer recency rides
-                    # creation and the (rarer) KV edges; the bench prices
-                    # this loop against the decode step wall (<1% contract)
+                    # creation and the (rarer) KV edges
                     ent = footers.get(rid)
                     if ent is None:
                         ent = self._footer(rid, tenant, adapter, priority)
@@ -191,7 +189,7 @@ class MeterLedger:
             t[0] += nbytes
             if rid:
                 # hot path: no LRU bump per page — footer recency rides
-                # creation; this edge is priced by the bench <1% contract
+                # creation
                 ent = self._footers.get(rid)
                 if ent is None:
                     ent = self._footer(rid, tenant, None, None)
@@ -322,9 +320,9 @@ class MeterLedger:
             }
 
     def conservation(self, anatomy=None, now: Optional[float] = None) -> dict:
-        """Both identities in one report (the bench ``metering`` section's
-        payload): attributed device-seconds vs the step-anatomy wall totals,
-        and per-tier summed byte-seconds vs the occupancy integrals."""
+        """Both identities in one report: attributed device-seconds vs the
+        step-anatomy wall totals, and per-tier summed byte-seconds vs the
+        occupancy integrals."""
         out: dict = {}
         if anatomy is not None:
             with anatomy._lock:

@@ -23,10 +23,10 @@ three points, all built from this module:
     new 429 path and the existing draining-503 path (which used to send a
     constant), clamped to [1, 30] s.
 
-Everything here is pure stdlib + thread-safe (the engine loop, the HTTP
-asyncio thread, and the bench all touch it). Exposed as the ``dynamo_qos_*``
-Prometheus families (conformance-checked), ``resource_snapshot.qos``,
-dynotop's QOS column, and the bench ``qos`` isolation section.
+Everything here is pure stdlib + thread-safe (the engine loop and the HTTP
+asyncio thread both touch it). Exposed as the ``dynamo_qos_*`` Prometheus
+families (conformance-checked), ``resource_snapshot.qos`` and dynotop's QOS
+column.
 """
 
 from __future__ import annotations

@@ -1,10 +1,9 @@
 """Step-anatomy profiler: per-dispatch host/device time attribution with a
 live roofline accounting plane.
 
-The r5 judge decomposition put decode at 7.24 ms/step — 69.8% of the 5.05 ms
-weight+KV HBM floor — with ~30% of every step lost to host dispatch/reconcile
-overhead, but that number came from a one-off ``tools/profile_decode.py`` run.
-This module makes the split a *standing* measurement: every engine dispatch
+How much of a step is the device's and how much the host's dispatch and
+reconcile overhead used to come from one-off profiling scripts. This module
+makes the split a *standing* measurement: every engine dispatch
 (decode window, packed prefill, per-request chunk, spec draft, spec verify,
 LoRA slot load, prefix-fetch scatter, offload drain) records one
 :class:`StepRecord` into a bounded ring, decomposed into four phases:
@@ -57,18 +56,16 @@ one weight read plus the KV the chunk writes against HBM bandwidth. Each
 ``prefill_packed``/``prefill_chunk`` record prices its floor at dispatch
 (``note_prefill_floor``); ``prefill_roofline_fraction`` = summed floors /
 measured prefill engine seconds (``dynamo_engine_prefill_roofline_fraction``)
-and ``prefill_fixed_ms`` is the live per-dispatch host cost — the quantity
-``tools/profile_prefill.py`` decomposes into host-prep / H2D / dispatch /
-kernel on hardware.
+and ``prefill_fixed_ms`` is the live per-dispatch host cost (host prep and
+dispatch; the kernel's own time is the profiler trace's).
 
 Exposed everywhere the repo already has rails: ``render_metrics`` emits
 ``dynamo_step_seconds_total{phase,kind}`` / ``dynamo_step_dispatch_total
 {kind}`` / ``dynamo_engine_roofline_fraction`` on the engine's conformance
 surface, ``snapshot()`` rides ``resource_snapshot`` -> worker stats ->
-dynotop STEP/ROOF/PREFILL columns, ``records()`` backs the ``/debug/steps``
-JSON endpoint, and the bench ``step_anatomy``/``prefill_anatomy`` sections
-price ``host_frac``/``roofline_frac``/``dispatch_gap_ms_p50`` and the
-prefill dispatch economics per arm.
+dynotop STEP/ROOF/PREFILL columns, and ``records()`` backs the
+``/debug/steps`` JSON endpoint, which ``benchmark/run.py`` reads for
+``decode_step_ms``, ``decode_batch_mean`` and ``host_share``.
 """
 
 from __future__ import annotations
@@ -531,9 +528,8 @@ class StepAnatomy:
 
     def prefill_fixed_ms(self) -> Optional[float]:
         """Mean host-side (host_prep + dispatch) milliseconds per prefill
-        dispatch — the live proxy for the per-call fixed cost
-        ``tools/profile_prefill.py`` decomposes offline. None before any
-        prefill dispatch."""
+        dispatch: the per-call fixed cost. None before any prefill
+        dispatch."""
         with self._lock:
             host = sum(
                 s for (phase, kind), s in self.phase_seconds.items()

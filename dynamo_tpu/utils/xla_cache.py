@@ -2,8 +2,8 @@
 
 An engine restart otherwise re-pays every executable's compile; with the
 cache, executables deserialize from disk. Every entry point that starts an
-engine (run CLI, worker, prefill worker, SDK service worker, bench,
-chip_smoke's children) goes through this one helper, and no other site sets
+engine (run CLI, worker, prefill worker, SDK service worker, chip_smoke's
+children) goes through this one helper, and no other site sets
 a cache directory.
 """
 

@@ -267,20 +267,29 @@ def test_unrenormalized_topk_routing():
     np.testing.assert_allclose(np.asarray(w_renorm[0]).sum(), 1.0, rtol=1e-6)
 
 
-def test_pallas_mla_kernel_matches_reference():
+@pytest.mark.parametrize(
+    "P, mp, positions",
+    [
+        (16, 6, [3, 9, 14]),
+        # one token, a page boundary from both sides, a 14-page context, one
+        # page; an odd batch
+        (96, 14, [0, 15, 16, 54, 3]),
+    ],
+)
+def test_pallas_mla_kernel_matches_reference(P, mp, positions):
     """The Pallas latent-page kernel (interpret mode) vs the pure-JAX absorbed
     attention, across lengths straddling page boundaries."""
     from dynamo_tpu.ops.pallas.mla_attention import paged_mla_decode_attention_pallas
 
     rng = np.random.default_rng(5)
-    B, H, dc, dr, ps, P, mp = 3, 4, 32, 8, 4, 16, 6
+    B, H, dc, dr, ps = len(positions), 4, 32, 8, 4
     latent = dc + dr
     q_cat = jnp.asarray(rng.standard_normal((B, H, latent)), jnp.float32)
     pages = jnp.asarray(rng.standard_normal((P, ps, latent)), jnp.float32)
     pt = np.zeros((B, mp), np.int32)
     for b in range(B):
         pt[b] = rng.choice(np.arange(1, P), size=mp, replace=False)
-    positions = jnp.asarray([3, 9, 14], jnp.int32)
+    positions = jnp.asarray(positions, jnp.int32)
 
     got = paged_mla_decode_attention_pallas(
         q_cat, pages, jnp.asarray(pt), positions, d_c=dc, interpret=True
@@ -473,48 +482,3 @@ def test_engine_mla_prefill_pallas_token_parity(monkeypatch):
     monkeypatch.setenv("DYNTPU_PALLAS", "1")
     got = run()
     assert got == ref
-
-
-def test_pallas_mla_lookahead_tail_path(monkeypatch):
-    """Lengths deep past the prefetch window W (the tail double-buffer path
-    long-context decodes hit in production) + ragged short sequences and odd
-    B for parity alternation — vs the same numpy reference (review r5).
-    Lookahead is opt-in for MLA (classic won the on-chip A/B), so force it
-    here to keep the kernel covered."""
-    from dynamo_tpu.ops.pallas.mla_attention import (
-        _mla_lookahead_window,
-        paged_mla_decode_attention_pallas,
-    )
-
-    monkeypatch.setenv("DYNTPU_DECODE_KERNEL", "lookahead")
-
-    rng = np.random.default_rng(9)
-    B, H, dc, dr, ps, P, mp = 5, 4, 32, 8, 4, 96, 14
-    latent = dc + dr
-    W = _mla_lookahead_window(ps, latent, 4)
-    assert 1 <= W <= 4
-    assert mp > W  # the tail path really engages
-    q_cat = jnp.asarray(rng.standard_normal((B, H, latent)), jnp.float32)
-    pages = jnp.asarray(rng.standard_normal((P, ps, latent)), jnp.float32)
-    pt = np.zeros((B, mp), np.int32)
-    pool = list(range(1, P))
-    rng.shuffle(pool)
-    for b in range(B):
-        pt[b] = pool[b * mp:(b + 1) * mp]
-    # 1 token; W pages exactly; W pages + 1 token; 14-page tail; 1 page
-    positions = jnp.asarray(
-        [0, W * ps - 1, W * ps, mp * ps - 2, ps - 1], jnp.int32
-    )
-
-    got = paged_mla_decode_attention_pallas(
-        q_cat, pages, jnp.asarray(pt), positions, d_c=dc, interpret=True
-    )
-    for b in range(B):
-        ctx = np.asarray(pages)[pt[b]].reshape(mp * ps, latent)
-        scores = np.asarray(q_cat)[b] @ ctx.T
-        mask = np.arange(mp * ps) <= int(positions[b])
-        scores = np.where(mask[None], scores, -1e30)
-        probs = np.exp(scores - scores.max(-1, keepdims=True))
-        probs /= probs.sum(-1, keepdims=True)
-        want = probs @ ctx[:, :dc]
-        np.testing.assert_allclose(np.asarray(got[b]), want, atol=2e-5)

@@ -5,6 +5,7 @@ and what cannot be used is an error."""
 
 import json
 import os
+import re
 import subprocess
 import sys
 from pathlib import Path
@@ -131,16 +132,23 @@ def _gitignore() -> list:
     return (ROOT / ".gitignore").read_text().split()
 
 
+_NOT_COMMITTED = {".git", ".chip_smoke", ".xla_cache", ".archive_check", ".bench_check",
+                  ".cache", "chiprun_out", "__pycache__", "_build"}
+
+
+def _files(skip=frozenset()):
+    """Paths of the files git would commit, as `Path`s under ROOT."""
+    for dirpath, dirnames, filenames in os.walk(ROOT):
+        dirnames[:] = [d for d in dirnames if d not in _NOT_COMMITTED and d not in skip]
+        for f in filenames:
+            yield Path(dirpath) / f
+
+
 def _product_sources():
     """Python files git would commit, tests aside."""
-    skip = {".git", ".chip_smoke", ".xla_cache", ".archive_check", "chiprun_out",
-            "__pycache__", "tests", "_build"}
-    for dirpath, dirnames, filenames in os.walk(ROOT):
-        dirnames[:] = [d for d in dirnames if d not in skip]
-        for f in filenames:
-            if f.endswith(".py"):
-                path = Path(dirpath) / f
-                yield str(path.relative_to(ROOT)), path.read_text()
+    for path in _files(skip={"tests"}):
+        if path.suffix == ".py":
+            yield str(path.relative_to(ROOT)), path.read_text()
 
 
 def test_no_other_site_sets_a_cache_directory():
@@ -153,7 +161,7 @@ def test_no_other_site_sets_a_cache_directory():
     assert "config.update" not in sources["chip_smoke.py"]
     callers = {p for p, text in sources.items() if "enable_compilation_cache()" in text}
     assert callers >= {
-        "bench.py", "chip_smoke.py", "dynamo_tpu/launch/_run_impl.py",
+        "chip_smoke.py", "dynamo_tpu/launch/_run_impl.py",
         "dynamo_tpu/components/worker.py", "dynamo_tpu/components/prefill_worker.py",
         "dynamo_tpu/sdk/serve_worker.py",
     }, callers
@@ -177,3 +185,43 @@ def test_native_libraries_are_built_not_tracked():
     # keyed by the sources' content: a second call builds nothing new
     assert native_build.build() == first and first.parent == ROOT / "native" / "_build"
     assert len(first.stem.rsplit("-", 1)[1]) == 16
+
+
+# ---------------- the kernel choice is not an operator's setting ----------------
+
+
+def test_ops_and_models_read_no_environment_but_the_platform_override():
+    """Which kernel runs follows from platform, dtype, shape and mesh. The one
+    variable the ops and models may read is DYNTPU_PALLAS (`pallas_flag`: the
+    platform override tier-1 uses to reach interpret mode)."""
+    readers = {}
+    for sub in ("ops", "models"):
+        for path in sorted((ROOT / "dynamo_tpu" / sub).rglob("*.py")):
+            lines = [ln.strip() for ln in path.read_text().splitlines()
+                     if "os.environ" in ln or "getenv" in ln]
+            if lines:
+                readers[str(path.relative_to(ROOT))] = lines
+    assert readers == {
+        "dynamo_tpu/ops/attention.py": ['flag = os.environ.get("DYNTPU_PALLAS")'],
+    }, readers
+
+
+# ---------------- documents name files that exist ----------------
+
+_PATH_IN_DOC = re.compile(r"(?<![\w./*<{-])([\w*][\w./*-]*\.(?:py|sh))\b")
+
+
+@pytest.mark.parametrize("doc", ["README.md", "ARCHITECTURE.md", "COMPONENTS.md"])
+def test_every_script_a_document_names_exists(doc):
+    """A `*.py` / `*.sh` path in a document is a promise that the file is
+    there: whole from the root, relative to the package, by its own name, or
+    as a glob that matches something."""
+    import fnmatch
+
+    files = [str(p.relative_to(ROOT)) for p in _files()]
+    missing = []
+    for name in sorted(set(_PATH_IN_DOC.findall((ROOT / doc).read_text()))):
+        pattern = name.lstrip("./")
+        if not any(fnmatch.fnmatch(f, pattern) or fnmatch.fnmatch(f, "*/" + pattern) for f in files):
+            missing.append(name)
+    assert missing == [], f"{doc} names files that are not in the tree: {missing}"

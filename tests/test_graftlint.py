@@ -3,7 +3,7 @@ each of the six detectors catches its seeded positive fixture and stays
 silent on its negative fixture (which includes reasoned suppressions, so the
 allowlist machinery is exercised), the whole-repo scan comes back with zero
 unsuppressed findings, the suppression/baseline plumbing behaves, exit codes
-follow the bench_compare convention, and the metric-conformance detector's
+are 0 clean / 1 findings / 2 usage error, and the metric-conformance detector's
 static view of DECLARED_METRIC_FAMILIES matches the runtime declaration the
 prometheus --check gate validates against the rendered surfaces.
 
@@ -135,7 +135,7 @@ def test_baseline_acknowledges_debt(tmp_path):
     assert [f for f in findings2 if not f.suppressed and f.fingerprint not in fps] == []
 
 
-# ---------------- CLI exit codes (the bench_compare convention) ----------
+# ---------------- CLI exit codes: 0 clean, 1 findings, 2 usage -----------
 
 
 def test_cli_exit_codes(tmp_path, capsys):

@@ -284,6 +284,42 @@ def test_radix_byte_cap_bounds_resident_bytes():
     assert s["evictions_total"] > 0
 
 
+def test_sharded_bounded_index_under_distinct_prefix_churn():
+    """The sharded, bounded index against the unbounded one under churn of
+    distinct single-block prefixes with a hot working set of depth-4 chains
+    that traffic keeps walking: resident nodes never pass the cap, evictions
+    happened, the unbounded arm only grows, and the hot set's hit ratio stays
+    within 0.05 of the unbounded arm's. Counts only, no latency."""
+    CAP, HOT, DEPTH, CHURN, EVERY = 2_000, 50, 4, 40_000, 2_000
+
+    def hot_seq(j):
+        return [(1 << 40) + j * DEPTH + d for d in range(DEPTH)]
+
+    def arm(**kw):
+        idx = py_indexer(**kw)
+        for j in range(HOT):
+            stored(1, idx, None, [((1 << 50) + h, h) for h in hot_seq(j)])
+        checkpoints = []
+        for i in range(CHURN):
+            stored(1, idx, None, [((1 << 51) + i, i)])
+            if i % 8 == 0:  # LRU only protects what gets walked
+                idx.find_matches(hot_seq((i // 8) % HOT))
+            if i % EVERY == 0:
+                checkpoints.append(idx.radix_stats()["nodes"])
+        matched = sum(idx.find_matches(hot_seq(j)).scores.get(1, 0) for j in range(HOT))
+        return idx.radix_stats(), checkpoints, matched / (HOT * DEPTH)
+
+    _, grown, hot_unbounded = arm()
+    stats, held, hot_bounded = arm(max_nodes=CAP, num_shards=4)
+    assert all(b > a for a, b in zip(grown, grown[1:])), grown
+    assert grown[-1] >= HOT * DEPTH + CHURN - EVERY
+    assert max(held) <= CAP and stats["nodes"] <= CAP, (held, stats)
+    assert stats["shards"] == 4 and stats["max_nodes"] == CAP
+    assert stats["evictions_total"] >= CHURN - CAP, stats
+    assert hot_unbounded == 1.0
+    assert hot_bounded >= hot_unbounded - 0.05, hot_bounded
+
+
 def test_stats_incremental_counters_match_recount():
     """stats() is O(1) off incremental counters; they must agree with a full
     recount of the lookup tables after a mixed store/remove/evict workload."""

@@ -183,21 +183,6 @@ def test_prefill_dispatch_tp2_shard_map(monkeypatch):
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-5)
 
 
-def test_pallas_chunked_matches_reference():
-    from dynamo_tpu.ops.pallas.paged_attention import (
-        paged_decode_attention_pallas_chunked,
-    )
-
-    for B, Hq, Hkv, seed in [(3, 4, 2, 0), (8, 16, 8, 1), (2, 8, 8, 5)]:
-        q, k, v, pt, pos = make_case(B=B, Hq=Hq, Hkv=Hkv, seed=seed)
-        pos = jnp.asarray(np.random.default_rng(seed).integers(0, 15, B), jnp.int32)
-        ref = paged_decode_attention(q, k, v, pt, pos)
-        got = paged_decode_attention_pallas_chunked(q, k, v, pt, pos, interpret=True)
-        np.testing.assert_allclose(
-            np.asarray(got), np.asarray(ref), atol=2e-5, err_msg=f"B={B} Hq={Hq}"
-        )
-
-
 def test_pallas_folded_matches_reference():
     """head_dim < 128 variant: heads folded into lanes, zero-placed Q."""
     from dynamo_tpu.ops.pallas.paged_attention import (
@@ -211,21 +196,6 @@ def test_pallas_folded_matches_reference():
         got = paged_decode_attention_pallas_folded(q, k, v, pt, pos, interpret=True)
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(ref), atol=2e-5, err_msg=f"B={B} Hq={Hq} D={D}"
-        )
-
-
-def test_pallas_grouped_matches_reference():
-    from dynamo_tpu.ops.pallas.paged_attention import (
-        paged_decode_attention_pallas_grouped,
-    )
-
-    for B, Hq, Hkv, seed in [(8, 16, 8, 1), (4, 8, 8, 2), (3, 4, 2, 0), (6, 4, 2, 5)]:
-        q, k, v, pt, pos = make_case(B=B, Hq=Hq, Hkv=Hkv, seed=seed)
-        pos = jnp.asarray(np.random.default_rng(seed).integers(0, 15, B), jnp.int32)
-        ref = paged_decode_attention(q, k, v, pt, pos)
-        got = paged_decode_attention_pallas_grouped(q, k, v, pt, pos, interpret=True)
-        np.testing.assert_allclose(
-            np.asarray(got), np.asarray(ref), atol=2e-5, err_msg=f"B={B} Hq={Hq}"
         )
 
 

@@ -2,7 +2,7 @@
 engines, routing by radix-tree overlap must recover ~all prefix tokens from
 cache while random routing forfeits roughly half — the mechanism behind the
 reference's 3x TTFT / 2x latency claim for KV-aware routing (reference:
-docs/architecture.md:76-87, BASELINE.md parity checkpoint #2).
+docs/architecture.md:76-87, the "KV-aware routing" rows of BASELINE.md).
 """
 
 import asyncio

@@ -244,9 +244,8 @@ def render_status(doc: dict, events_rows: int = 8, events_offset: int = 0) -> st
         )
         # PREFILL: host-side fraction of prefill dispatch time + the
         # rows-amortized per-call fixed cost + the prefill roofline fraction
-        # (max(MXU-FLOP, bytes) floor over measured — see
-        # tools/profile_prefill.py for the offline decomposition). Workers
-        # predating the prefill plane (r19) show "-"
+        # (max(MXU-FLOP, bytes) floor over measured). Workers predating the
+        # prefill plane (r19) show "-"
         prefill = "-"
         if anat.get("prefill_host_frac") is not None:
             prefill = f"h{100.0 * anat['prefill_host_frac']:.0f}%"

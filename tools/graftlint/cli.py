@@ -5,8 +5,8 @@
     python -m tools.graftlint path/to.py     # scoped scan
     python -m tools.graftlint --write-baseline   # acknowledge current debt
 
-Exit codes mirror tools/bench_compare.py: 0 = clean, 1 = unsuppressed
-findings (or a failed self-check), 2 = usage/internal error. tools/lint.sh
+Exit codes: 0 = clean, 1 = unsuppressed findings (or a failed self-check),
+2 = usage/internal error. tools/lint.sh
 runs ``--self-check`` then the full scan between the prometheus conformance
 check and ruff, so a broken detector fails the gate as loudly as a broken
 hot path.
@@ -32,7 +32,7 @@ from tools.graftlint.detectors import ALL_DETECTORS
 #: what the repo gate scans: the package plus the tooling the tier-1 suite
 #: shells out to. Tests are deliberately out of scope — they block, sync and
 #: fake metrics on purpose.
-DEFAULT_SCAN_ROOTS = ("dynamo_tpu", "tools", "bench.py")
+DEFAULT_SCAN_ROOTS = ("dynamo_tpu", "tools")
 
 DEFAULT_BASELINE = "tools/graftlint/baseline.json"
 

@@ -1,7 +1,7 @@
 """graftlint --self-check: detectors vs their seeded fixtures.
 
-Mirrors ``tools/bench_compare.py --self-check``: before the repo scan runs,
-every detector must (a) catch exactly the seeded violations in its POSITIVE
+A gate that checks itself first: before the repo scan runs, every detector
+must (a) catch exactly the seeded violations in its POSITIVE
 fixture, (b) stay silent on its NEGATIVE fixture — which includes annotated
 violations, so the suppression machinery is exercised too — and (c) never
 bleed findings into another detector's fixture. A detector that rots fails

@@ -24,16 +24,12 @@ JAX_PLATFORMS=cpu python -m dynamo_tpu.utils.prometheus --check
 python -m tools.graftlint --self-check
 python -m tools.graftlint
 
-# bench regression gate self-check: the compare tool must flag a synthetic
-# regression and pass an identical pair (pure stdlib, no cluster)
-python tools/bench_compare.py --self-check
-
 if command -v ruff >/dev/null 2>&1; then
-    exec ruff check dynamo_tpu tests tools bench.py
+    exec ruff check dynamo_tpu tests tools
 fi
 if python -c "import ruff" >/dev/null 2>&1; then
-    exec python -m ruff check dynamo_tpu tests tools bench.py
+    exec python -m ruff check dynamo_tpu tests tools
 fi
 echo "lint: ruff unavailable (no-egress image); falling back to the" \
      "compileall syntax gate" >&2
-exec python -m compileall -q dynamo_tpu tests tools bench.py
+exec python -m compileall -q dynamo_tpu tests tools

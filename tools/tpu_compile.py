@@ -140,12 +140,12 @@ def _prefill_case(geo, ps, T, int8, lookahead=None):
     return Case(f"prefill{tag}-{name}-ps{ps}-T{T}-{'int8' if int8 else 'bf16'}", build)
 
 
-def _mla_decode_case(ps, lookahead):
+def _mla_decode_case(ps):
     def build(S):
         from dynamo_tpu.ops.pallas.mla_attention import paged_mla_decode_attention_pallas
 
         def fn(*a):
-            return paged_mla_decode_attention_pallas(*a, d_c=MLA_DC, lookahead=lookahead)
+            return paged_mla_decode_attention_pallas(*a, d_c=MLA_DC)
         # the model hands the kernels an f32 folded query (deepseek._fold_q)
         return fn, (
             S((16, MLA_HEADS, MLA_LATENT), jnp.float32),
@@ -153,7 +153,7 @@ def _mla_decode_case(ps, lookahead):
             S((16, 2048 // ps), jnp.int32), S((16,), jnp.int32),
         )
 
-    return Case(f"mla-decode-{'lookahead' if lookahead else 'classic'}-ps{ps}", build)
+    return Case(f"mla-decode-classic-ps{ps}", build)
 
 
 def _mla_prefill_case(ps, T):
@@ -232,7 +232,7 @@ def kernel_cases(full: bool) -> list[Case]:
                   _decode_case(qwen, 16, False, kernel=paged_decode_attention_pallas),
                   _decode_case(qwen, 16, True, kernel=paged_decode_attention_pallas)]
         for ps in (16, 64, 128):
-            cases += [_mla_decode_case(ps, False), _mla_decode_case(ps, True)]
+            cases.append(_mla_decode_case(ps))
             cases += [_mla_prefill_case(ps, T) for T in (128, 256, 512, 1024)]
         cases.append(_ssm_update_case())
         cases += [_grouped_matmul_case(*c) for c in GROUPED_MATMUL_CASES]
@@ -255,9 +255,9 @@ def kernel_cases(full: bool) -> list[Case]:
         _prefill_case(mixtral, 16, 512, True),
         # the basic variant was refused at 32q/8kv too
         _prefill_case(mixtral, 16, 512, False, lookahead=False),
-        # MLA: decode both variants; prefill refused with the f32 query the
-        # model passes (17.3 MiB)
-        _mla_decode_case(16, False), _mla_decode_case(128, True),
+        # MLA: decode at the smallest and largest page; prefill refused with
+        # the f32 query the model passes (17.3 MiB)
+        _mla_decode_case(16), _mla_decode_case(128),
         _mla_prefill_case(16, 512),
         # the Mamba-2 state update, in place over the donated state
         _ssm_update_case(),
