@@ -183,13 +183,19 @@ class EngineConfig:
     offload_drain_batch: int = 32
     # decode steps fused into one device call (lax.scan over steps with the
     # sampled-token feedback kept on device); amortizes dispatch + host<->device
-    # transfer overhead. 1 = classic one-step decode. Streaming granularity and
-    # worst-case wasted decode past EOS both scale with this.
+    # transfer overhead. 1 = classic one-step decode. Streaming granularity,
+    # the unit of admission (a new prompt's prefill waits behind the window
+    # that is running) and worst-case wasted decode past EOS scale with this.
     decode_steps: int = 8
-    # decode windows dispatched ahead of result materialization (dispatch-ahead
-    # pipelining; the token feedback lives on device so window N+1 never waits
-    # for window N's tokens to reach the host). 1 = fully synchronous.
-    pipeline_depth: int = 3
+    # decode windows in flight ahead of result materialization (the token
+    # feedback lives on device, so window N+1 never waits for window N's
+    # tokens to reach the host). 2 = double buffering: the window that runs
+    # and one that waits, which is all it takes to hide the host's refill (a
+    # few percent of a window). Every further window stands ahead of each
+    # new prompt's prefill on the device's FIFO queue and holds a finished
+    # sequence's slot one window longer; a third bought no throughput
+    # (PERF.md, PR 32). 1 = fully synchronous.
+    pipeline_depth: int = 2
     # cross-request prefill packing: chunks of up to this many DISTINCT
     # sequences ride one prefill call (one weight pass). The effective lane
     # count per bucket is row-budgeted by lanes_for() (see its r5-measured

@@ -1370,6 +1370,19 @@ class AsyncJaxEngine:
             "cumulative engine-thread seconds attributed to each stage",
             [({"stage": k}, v) for k, v in sorted(stage_seconds.items())],
         ))
+        # the order of the device's queue, as a quotient: windows ahead /
+        # dispatches = the decode windows a new prompt's prefill waits behind
+        parts.append(render_family(
+            "dynamo_engine_prefill_dispatches_total", "counter",
+            "prefill calls dispatched to the device (packed and per-request)",
+            [({}, st.prefill_calls)],
+        ))
+        parts.append(render_family(
+            "dynamo_engine_prefill_windows_ahead_total", "counter",
+            "decode windows in flight at the moment of each prefill "
+            "dispatch, summed over the dispatches",
+            [({}, st.prefill_windows_ahead)],
+        ))
         if self.config.speculative is not None:
             parts.append(render_family(
                 "dynamo_spec_proposed_total", "counter",

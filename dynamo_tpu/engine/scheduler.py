@@ -8,12 +8,23 @@ Policy (deliberately simple admission; aggressive latency hiding):
     prefix (from the page allocator) is skipped, mirroring the reference's
     prefix-hit accounting used for routing/disagg decisions
   - decode runs as fused K-step windows dispatched **ahead** of result
-    materialization (config.pipeline_depth windows in flight): the sampled
-    token feedback lives on device (ModelRunner.tokens_dev), so the host never
-    syncs between windows. Results are reconciled in dispatch order; EOS is
-    therefore discovered up to (pipeline_depth * K) steps late, and the device
-    wastes at most that much work per finished sequence — the price of hiding
-    per-call dispatch/transfer latency.
+    materialization: the sampled token feedback lives on device
+    (ModelRunner.tokens_dev), so the host never syncs between windows. The
+    device's queue is FIFO, so every window committed to it stands ahead of
+    a prompt nobody has sent yet. The invariant (_window_room): besides the
+    entry the device is running, at most config.pipeline_depth - 1 windows
+    that have not started stand on its queue — ONE at the default of 2,
+    double buffering. The host refills the queue in a few percent of a
+    window, so one waiting window hides it; a second one bought no
+    throughput and put 90 ms in front of every first token (PERF.md, PR 32).
+    Results are reconciled in dispatch order; EOS is therefore discovered up
+    to two windows (2 * K steps) late, the device wastes at most that much
+    work per finished sequence, and its slot is held through one window in
+    which it plans no step.
+  - step() never blocks on the device while it holds tokens: whatever a
+    non-blocking reconcile (or the prefill gate) materialized is returned
+    once the queue is refilled, the engine loop posts it and calls again,
+    and the blocking wait happens on that next call
   - on page exhaustion mid-decode the pipeline is drained, then the
     most-recently-admitted sequence is preempted back to the waiting queue
     (prompt = original + generated so far)
@@ -278,6 +289,9 @@ class StageStats:
     prefill_s: float = 0.0  # dispatch time of prefill calls (packed + chained)
     prefill_calls: int = 0
     prefill_rows: int = 0
+    # decode windows in flight at each prefill dispatch, summed: over
+    # prefill_calls, the windows a new prompt's prefill stands behind
+    prefill_windows_ahead: int = 0
     decode_dispatch_s: float = 0.0
     decode_windows: int = 0
     decode_steps: int = 0
@@ -313,6 +327,7 @@ class StageStats:
             "prefill_s": round(self.prefill_s, 4),
             "prefill_calls": self.prefill_calls,
             "prefill_rows": self.prefill_rows,
+            "prefill_windows_ahead": self.prefill_windows_ahead,
             "decode_dispatch_s": round(self.decode_dispatch_s, 4),
             "decode_windows": self.decode_windows,
             "decode_steps": self.decode_steps,
@@ -602,10 +617,15 @@ class Scheduler:
         if self.spec is not None:
             dispatched += self._dispatch_spec_round(outputs)
         dispatched += self._dispatch_windows(outputs)
-        pipeline_full = self._windows_in_flight() >= max(1, self.config.pipeline_depth)
-        if pipeline_full or (self.in_flight and not dispatched and not outputs):
+        if outputs:
+            # tokens in hand (the opening reconcile's, or the prefill gate's):
+            # the queue is refilled, so hand them over before any device
+            # wait; the engine loop posts them and comes straight back
+            return outputs
+        if self.in_flight and not (dispatched and self._window_room()):
+            # the queue holds all it may, or this call added nothing to it
             outputs.extend(self._reconcile(block=True))
-        elif not outputs and not dispatched and not self.in_flight and (
+        elif not dispatched and not self.in_flight and (
             self._fetching() or self._migrating()
         ):
             # FETCHING_KV / MIGRATING_OUT is the only live work: both resolve
@@ -616,6 +636,27 @@ class Scheduler:
 
     def _windows_in_flight(self) -> int:
         return sum(1 for e in self.in_flight if e.kind == "window")
+
+    def _window_room(self) -> bool:
+        """Whether the device's queue may take one more decode window: at
+        most ``pipeline_depth`` in flight, and of those at most
+        ``pipeline_depth - 1`` that have not started. The oldest in-flight
+        entry is what the device is running; where that is a prefill, every
+        window in flight is still waiting behind it."""
+        depth = max(1, self.config.pipeline_depth)
+        windows = self._windows_in_flight()
+        if self.in_flight and self.in_flight[0].kind != "window":
+            return windows < max(1, depth - 1)
+        return windows < depth
+
+    def _note_windows_ahead(self) -> int:
+        """The decode windows a prefill dispatched now stands behind on the
+        device's queue (in flight, not reconciled), added to the counter
+        whose quotient with ``stage.prefill_calls`` says how many windows
+        the scheduler commits ahead of a new prompt."""
+        ahead = self._windows_in_flight()
+        self.stage.prefill_windows_ahead += ahead
+        return ahead
 
     def _prefills_in_flight(self) -> int:
         return sum(
@@ -1486,6 +1527,7 @@ class Scheduler:
                     rec, "dispatch", request_id=chunks[0][0].req.request_id,
                     trace_id=chunks[0][0].req.trace_id,
                     rows=rows, lanes=N, packed=True, finals=len(finals),
+                    windows_ahead=self._note_windows_ahead(),
                 ) as ph:
                     result = self.runner.prefill_chunk_batch(
                         lanes, N=N, want_logprobs=want_lp
@@ -1616,6 +1658,7 @@ class Scheduler:
         with self.anatomy.phase(
             rec, "dispatch", request_id=req.request_id, trace_id=req.trace_id,
             rows=rows, cached=cached_len, sync=sync,
+            windows_ahead=self._note_windows_ahead(),
         ):
             while start < prompt_len:
                 # depth-aware chunk sizing: shrink the chunk as the context
@@ -2103,7 +2146,7 @@ class Scheduler:
 
     def _dispatch_windows(self, outputs: list[StepOutput]) -> int:
         count = 0
-        while self._windows_in_flight() < max(1, self.config.pipeline_depth):
+        while self._window_room():
             if not self._dispatch_one_window(outputs):
                 break
             count += 1

@@ -62,9 +62,11 @@ DECLARED_METRIC_FAMILIES: tuple = (
     "dynamo_engine_offload_bytes_resident",
     "dynamo_engine_offload_pressure_blocks_total",
     "dynamo_engine_preemptions_total",
+    "dynamo_engine_prefill_dispatches_total",
     "dynamo_engine_prefill_hold_seconds",
     "dynamo_engine_prefill_roofline_fraction",
     "dynamo_engine_prefill_seconds",
+    "dynamo_engine_prefill_windows_ahead_total",
     "dynamo_engine_prefix_cache_blocks_total",
     "dynamo_engine_prefix_cache_refused_total",
     "dynamo_engine_pressure_drains_total",

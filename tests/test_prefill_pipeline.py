@@ -5,6 +5,8 @@ pipelined scheduler vs the strict reconcile-per-call baseline (greedy,
 seeded, and int8-KV arms) plus cancel-mid-pipeline safety."""
 
 import asyncio
+import functools
+import zlib
 
 import numpy as np
 import pytest
@@ -229,3 +231,229 @@ def test_cancel_mid_pipeline():
             await eng.shutdown()
 
     asyncio.run(run())
+
+
+# ---------------- the order of the device's queue (PR 32) ----------------
+#
+# The device's queue is FIFO: what the scheduler commits to it stands ahead of
+# every prompt that has not arrived yet. These tests drive Scheduler.step() by
+# hand, with no engine thread, and make the device "slow" or "fast" by fixing
+# what the readiness poll answers, so the order is the same on every machine.
+
+
+def _hand_driven(monkeypatch, ready, **over):
+    """A tiny engine whose scheduler the test steps itself; ``ready`` is a
+    one-element list the test flips: what every non-blocking poll of an
+    in-flight result answers (False: a device still busy with it)."""
+    from dynamo_tpu.engine import scheduler as sched_mod
+    from dynamo_tpu.engine.engine import AsyncJaxEngine
+
+    over.setdefault("max_seqs", 4)
+    eng = AsyncJaxEngine(_cfg(2, **over))
+    eng._initialize()
+    monkeypatch.setattr(sched_mod, "_is_ready", lambda arr: ready[0])
+    return eng
+
+
+def _hand_request(rid, n_prompt=12, max_tokens=64):
+    from dynamo_tpu.engine.sampling import SamplingParams
+    from dynamo_tpu.engine.scheduler import EngineRequest
+
+    rng = np.random.default_rng(zlib.crc32(rid.encode()))
+    return EngineRequest(
+        request_id=rid, token_ids=rng.integers(1, 200, n_prompt).tolist(),
+        sampling=SamplingParams(temperature=0.0, max_tokens=max_tokens,
+                                ignore_eos=True),
+    )
+
+
+def _spy_prefill_dispatches(sched):
+    """[(request ids of the pack, kinds in flight ahead of it)] of every
+    packed prefill dispatched from now on, taken at the dispatch."""
+    seen = []
+    real = sched.runner.prefill_chunk_batch
+
+    def spy(lanes, **kw):
+        slots = {lane[3] for lane in lanes}
+        rids = [s.req.request_id for s in sched.slots
+                if s is not None and s.slot in slots]
+        seen.append((rids, _kinds(sched)))
+        return real(lanes, **kw)
+
+    sched.runner.prefill_chunk_batch = spy
+    return seen
+
+
+def _kinds(sched):
+    return ["window" if e.kind == "window" else "prefill" for e in sched.in_flight]
+
+
+def test_default_depth_is_double_buffering():
+    assert EngineConfig(model_id="tiny").pipeline_depth == 2
+
+
+@pytest.mark.parametrize("depth", [1, 2, 3])
+def test_new_prompt_stands_behind_depth_minus_one_windows(depth, monkeypatch):
+    """With decode windows in flight and a device that finishes nothing
+    until the host blocks on it, a newly added request's prefill is
+    dispatched behind ``pipeline_depth - 1`` unreconciled windows: one at the
+    default of 2 (the window that is running), two at the old default of 3."""
+    ready = [False]
+    eng = _hand_driven(monkeypatch, ready, pipeline_depth=depth)
+    sched = eng.scheduler
+    for rid in ("a", "b"):
+        sched.add_request(_hand_request(rid))
+    for _ in range(4):
+        sched.step()
+    assert _kinds(sched) == ["window"] * (depth - 1)
+    calls0, ahead0 = sched.stage.prefill_calls, sched.stage.prefill_windows_ahead
+    seen = _spy_prefill_dispatches(sched)
+    sched.add_request(_hand_request("late"))
+    sched.step()
+    assert sched.stage.prefill_calls == calls0 + 1
+    assert sched.stage.prefill_windows_ahead - ahead0 == depth - 1
+    # the same, read off the queue at the moment of the dispatch: the
+    # windows dispatched before the prefill that nobody has reconciled
+    assert seen == [(["late"], ["window"] * (depth - 1))]
+
+
+@pytest.mark.parametrize("depth", [2, 3])
+def test_a_running_prefill_counts_as_the_running_entry(depth, monkeypatch):
+    """Where the oldest in-flight entry is a prefill, it is what the device
+    runs, and every window in flight waits behind it: at most depth - 1 of
+    them are committed, so the next prompt finds no second waiting window."""
+    ready = [False]
+    eng = _hand_driven(monkeypatch, ready, pipeline_depth=depth)
+    sched = eng.scheduler
+    sched.add_request(_hand_request("a"))
+    # one step: admit, prefill, windows behind it; the device "finishes
+    # nothing", so the closing block materializes the prefill alone
+    outs = sched.step()
+    assert [o.request_id for o in outs if o.token is not None] == ["a"]
+    assert _kinds(sched) == ["window"] * (depth - 1)
+    # the next prompts, one a step: none finds more than depth - 1 windows
+    # waiting, whether a window or a prefill heads the queue
+    seen = _spy_prefill_dispatches(sched)
+    for rid in ("b", "c", "d"):
+        sched.add_request(_hand_request(rid))
+        sched.step()
+        kinds = _kinds(sched)
+        assert kinds.count("window") <= depth
+        assert kinds[1:].count("window") <= depth - 1
+    assert [rids for rids, _ in seen] == [["b"], ["c"], ["d"]]
+    # ahead[0] is what the device runs; behind it at most depth - 1 windows
+    assert all(ahead[1:].count("window") <= depth - 1 for _, ahead in seen), seen
+
+
+def test_step_returns_tokens_without_a_device_wait(monkeypatch):
+    """A step whose opening non-blocking reconcile materialized tokens
+    refills the device's queue and returns them; it enters no blocking
+    device wait (no ``device_wait`` phase is recorded for it), and the wait
+    happens on the next call."""
+    ready = [False]
+    eng = _hand_driven(monkeypatch, ready)
+    sched = eng.scheduler
+    sched.add_request(_hand_request("a"))
+    sched.add_request(_hand_request("b"))
+    for _ in range(3):
+        sched.step()
+    assert _kinds(sched) == ["window"]
+    def waited_ms():
+        return sum(r["device_wait_ms"] for r in sched.anatomy.records(512))
+
+    waits0, waited0 = sched.stage.reconcile_waits, waited_ms()
+    ready[0] = True  # the window in flight has landed by the next call
+    outs = sched.step()
+    ready[0] = False
+    assert {o.request_id for o in outs if o.token is not None} == {"a", "b"}
+    assert sched.stage.reconcile_waits == waits0
+    assert waited_ms() == waited0
+    # ... and the queue was refilled before it returned
+    assert _kinds(sched) == ["window", "window"]
+    outs = sched.step()  # nothing in hand now: this call blocks
+    assert sched.stage.reconcile_waits == waits0 + 1
+    assert waited_ms() > waited0 and outs
+
+
+@functools.lru_cache(maxsize=None)
+def _staggered_tokens(depth):
+    """Greedy streams of a mixed run: prompts of several lengths arriving
+    while earlier ones decode, so prefills interleave with windows."""
+    from dynamo_tpu.engine.engine import AsyncJaxEngine
+    from dynamo_tpu.engine.sampling import SamplingParams
+    from dynamo_tpu.engine.scheduler import EngineRequest
+
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(1, 200, n).tolist() for n in (24, 9, 40, 17, 30, 12)]
+
+    async def run():
+        eng = AsyncJaxEngine(_cfg(2, pipeline_depth=depth))
+        await eng.start()
+        try:
+            toks = {i: [] for i in range(len(prompts))}
+
+            async def one(i):
+                await asyncio.sleep(0.03 * i)
+                req = EngineRequest(
+                    request_id=f"s-{i}", token_ids=list(prompts[i]),
+                    sampling=SamplingParams(temperature=0.0, max_tokens=20,
+                                            ignore_eos=True),
+                )
+                async for out in eng.generate(req):
+                    if out.token is not None:
+                        toks[i].append(out.token)
+
+            await asyncio.gather(*[one(i) for i in range(len(prompts))])
+            return toks
+        finally:
+            await eng.shutdown()
+
+    return asyncio.run(run())
+
+
+@pytest.mark.parametrize("depth", [2, 3])
+def test_greedy_streams_do_not_depend_on_depth(depth):
+    """The order on the device's queue is all that depth changes: greedy
+    streams of a mixed prefill and decode run are token for token those of
+    the synchronous engine (depth 1)."""
+    base = _staggered_tokens(1)
+    got = _staggered_tokens(depth)
+    for i, want in base.items():
+        assert len(want) == 20
+        assert got[i] == want, f"request {i} at depth {depth}: {got[i]} != {want}"
+
+
+def test_windows_ahead_on_the_span_and_in_metrics(monkeypatch):
+    """The prefill dispatch span says how many windows stood ahead of it, and
+    /metrics carries the two counters whose quotient is that number's mean:
+    at most 1 at the default depth."""
+    from dynamo_tpu.utils import tracing
+    from dynamo_tpu.utils.prometheus import check_exposition
+
+    ready = [False]
+    eng = _hand_driven(monkeypatch, ready)
+    sched = eng.scheduler
+    tracing.clear()
+    tracing.enable()
+    try:
+        sched.add_request(_hand_request("a"))
+        for i in range(6):
+            sched.step()
+            sched.add_request(_hand_request(f"late-{i}", max_tokens=8))
+        for _ in range(4):
+            sched.step()
+        spans = [e for e in tracing.events() if e["name"] == "engine.prefill"]
+    finally:
+        tracing.disable()
+        tracing.clear()
+    assert len(spans) >= 6
+    assert all("windows_ahead" in e["args"] and "rows" in e["args"] for e in spans)
+    assert [e["args"]["windows_ahead"] for e in spans][0] == 0  # an empty queue
+    st = sched.stage
+    assert st.prefill_calls == len(spans)
+    assert st.prefill_windows_ahead == sum(e["args"]["windows_ahead"] for e in spans)
+    assert 0 < st.prefill_windows_ahead <= st.prefill_calls
+    text = eng.render_stage_metrics()
+    assert check_exposition(text) == []
+    assert f"dynamo_engine_prefill_dispatches_total {st.prefill_calls}" in text
+    assert f"dynamo_engine_prefill_windows_ahead_total {st.prefill_windows_ahead}" in text
