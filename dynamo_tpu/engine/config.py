@@ -183,15 +183,25 @@ class EngineConfig:
     offload_drain_batch: int = 32
     # decode steps fused into one device call (lax.scan over steps with the
     # sampled-token feedback kept on device); amortizes dispatch + host<->device
-    # transfer overhead. 1 = classic one-step decode. Streaming granularity,
-    # the unit of admission (a new prompt's prefill waits behind the window
-    # that is running) and worst-case wasted decode past EOS scale with this.
-    decode_steps: int = 8
+    # transfer overhead. 1 = classic one-step decode. The window is the unit
+    # of admission: a request lies in the inbox for half a window, its prefill
+    # waits behind the whole window that is running, and a finished sequence's
+    # slot is dead until its window ends; streaming granularity and wasted
+    # decode past EOS scale with it too. 4, not 8, by a rule fixed before the
+    # runs (ISSUE 36: the smallest K of {2, 4} that costs no throughput,
+    # TPOT or set-up, leaves the device under 0.1% idle and the engine thread
+    # at most 20% host work in every benchmark cell). On the v5e K = 8 -> 4 ->
+    # 2 read a median first token of 195 -> 106 -> 62 ms and a TPOT p95 of 15.2
+    # -> 14.9 -> 14.7 ms in qwen2.5-3b.chat, 2242 -> 2339 -> 2243 tokens/s in
+    # chat-over; 4 held all six lines, 2 fell on the idle line (the host comes
+    # up to 12 ms late for nemotron3-super-ep4's 47 ms window: 0.49% idle).
+    # PERF.md sections 5 and 6, PR 36; tests/test_prefill_pipeline.py pins it.
+    decode_steps: int = 4
     # decode windows in flight ahead of result materialization (the token
     # feedback lives on device, so window N+1 never waits for window N's
     # tokens to reach the host). 2 = double buffering: the window that runs
     # and one that waits, which is all it takes to hide the host's refill (a
-    # few percent of a window). Every further window stands ahead of each
+    # tenth of a 4-step window). Every further window stands ahead of each
     # new prompt's prefill on the device's FIFO queue and holds a finished
     # sequence's slot one window longer; a third bought no throughput
     # (PERF.md, PR 32). 1 = fully synchronous.

@@ -267,9 +267,11 @@ def _hand_request(rid, n_prompt=12, max_tokens=64):
     )
 
 
-def _spy_prefill_dispatches(sched):
+def _spy_prefill_dispatches(sched, look=None):
     """[(request ids of the pack, kinds in flight ahead of it)] of every
-    packed prefill dispatched from now on, taken at the dispatch."""
+    packed prefill dispatched from now on, taken at the dispatch; ``look``
+    reads something else than the kinds off the scheduler."""
+    look = look or _kinds
     seen = []
     real = sched.runner.prefill_chunk_batch
 
@@ -277,7 +279,7 @@ def _spy_prefill_dispatches(sched):
         slots = {lane[3] for lane in lanes}
         rids = [s.req.request_id for s in sched.slots
                 if s is not None and s.slot in slots]
-        seen.append((rids, _kinds(sched)))
+        seen.append((rids, look(sched)))
         return real(lanes, **kw)
 
     sched.runner.prefill_chunk_batch = spy
@@ -457,3 +459,93 @@ def test_windows_ahead_on_the_span_and_in_metrics(monkeypatch):
     assert check_exposition(text) == []
     assert f"dynamo_engine_prefill_dispatches_total {st.prefill_calls}" in text
     assert f"dynamo_engine_prefill_windows_ahead_total {st.prefill_windows_ahead}" in text
+
+
+# ---------------- the decode window as the unit of admission (ISSUE 36) -------
+#
+# A window is ``decode_steps`` fused steps, and a prompt that arrives while
+# one runs waits for it: the default went from 8 to 4 by a rule fixed before
+# the chip runs (PERF.md, PR 36). The order on the device's queue must not
+# depend on K, and neither may a token.
+
+
+def test_default_window_is_the_one_issue_36_chose():
+    """ISSUE 36: ``decode_steps`` and ``pipeline_depth`` together set how long a
+    new prompt waits (one window of K steps ahead of its prefill, half of
+    one in the inbox). Whoever edits either default re-runs that issue's
+    rule on the chip (PERF.md section 6, PR 36) and changes this test."""
+    cfg = EngineConfig(model_id="tiny")
+    assert cfg.decode_steps == 4
+    assert cfg.pipeline_depth == 2
+
+
+@functools.lru_cache(maxsize=None)
+def _late_prompt_run(k):
+    """A hand-stepped engine with windows of ``k`` steps: two requests decode,
+    a third arrives while a window runs on a device that finishes nothing
+    until the host blocks on it, and all three are stepped to their end.
+    Returns what the late prompt's prefill found ahead of it and every
+    request's streamed tokens."""
+    from dynamo_tpu.utils import tracing
+
+    with pytest.MonkeyPatch.context() as mp:
+        ready = [False]
+        eng = _hand_driven(mp, ready, decode_steps=k)
+        sched = eng.scheduler
+        toks = {}
+
+        def step():
+            for o in sched.step():
+                if o.token is not None:
+                    toks.setdefault(o.request_id, []).append(o.token)
+
+        tracing.clear()
+        tracing.enable()
+        try:
+            for rid in ("a", "b"):
+                sched.add_request(_hand_request(rid, max_tokens=40))
+            for _ in range(3):
+                step()
+            before = _kinds(sched)
+            calls0, ahead0 = sched.stage.prefill_calls, sched.stage.prefill_windows_ahead
+            seen = _spy_prefill_dispatches(
+                sched, look=lambda s: [(e.kind, e.rec.steps) for e in s.in_flight])
+            sched.add_request(_hand_request("late", max_tokens=40))
+            step()
+            found = dict(
+                before=before, seen=seen,
+                calls=sched.stage.prefill_calls - calls0,
+                windows_ahead=sched.stage.prefill_windows_ahead - ahead0,
+            )
+            for _ in range(200):
+                if not sched.has_work():
+                    break
+                step()
+            spans = tracing.events()
+        finally:
+            tracing.disable()
+            tracing.clear()
+        found["span_ahead"] = [e["args"]["windows_ahead"] for e in spans
+                               if e["name"] == "engine.prefill"][-1]
+        found["span_k"] = {e["args"]["k"] for e in spans if e["name"] == "engine.decode.window"}
+        found["record_steps"] = {r["steps"] for r in sched.anatomy.records(512, kind="decode_window")}
+        found["tokens"] = toks
+        return found
+
+
+@pytest.mark.parametrize("k", [2, 4, 8])
+def test_late_prompt_waits_behind_one_window_of_k_steps(k):
+    """Whatever the window's length, a prompt that arrives while a window runs
+    has its prefill dispatched behind exactly that one window (``windows_ahead``
+    1 on the span and in the counter), the window is K steps long by its own
+    dispatch record and span, and the three requests' greedy streams are token
+    for token those of the 8-step window this default replaced."""
+    got = _late_prompt_run(k)
+    assert got["before"] == ["window"]
+    assert got["calls"] == 1 and got["windows_ahead"] == 1 and got["span_ahead"] == 1
+    assert got["seen"] == [(["late"], [("window", k)])]
+    assert got["span_k"] == {k} and got["record_steps"] == {k}
+    want = _late_prompt_run(8)["tokens"]
+    assert sorted(got["tokens"]) == ["a", "b", "late"]
+    assert all(len(t) == 40 for t in got["tokens"].values())
+    assert got["tokens"] == want
