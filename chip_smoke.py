@@ -754,6 +754,8 @@ def _parity_case(name: str, thunk) -> bool:
 def child_parity(sizes: Sizes, args) -> int:
     """Every Pallas kernel the dispatch can reach, compiled for the device
     this child runs on, against the gather reference."""
+    import functools
+
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -842,6 +844,37 @@ def child_parity(sizes: Sizes, args) -> int:
         else:
             got = paged_prefill_attention_pallas(q, k, v, table, pos, interpret=interpret, lookahead=lookahead)
         return got, reference(A.paged_prefill_attention, q, k, v, table, pos)
+
+    def window_decode(hq, hkv, d, ps, window, B):
+        """The sliding-window decode kernel at contexts around 3 windows,
+        the table's entries behind each window pointing at the null page (the
+        engine gives those pages back), against the XLA path with the mask."""
+        lengths = [3 * window + 7, 3 * window, 2 * window + ps + 1, window - 3][:B]
+        maxp = -(-max(lengths) // ps) + 3
+        order = iter(1 + rng.permutation(sum(-(-n // ps) for n in lengths)))
+        tables = np.zeros((B, maxp), np.int32)
+        for b, n in enumerate(lengths):
+            tables[b, : -(-n // ps)] = [next(order) for _ in range(-(-n // ps))]
+        k, v = pools(int(tables.max()) + 1, ps, hkv, d, False, False)
+        q, pos = normal(B, hq, d), jnp.asarray([n - 1 for n in lengths], jnp.int32)
+        want = reference(A.paged_decode_attention, q, k, v, jnp.asarray(tables), pos, window)
+        for b, n in enumerate(lengths):
+            tables[b, : max(0, n - window) // ps] = 0
+        got = jax.jit(functools.partial(A.dispatch_paged_decode_attention, window=window))(
+            q, k, v, jnp.asarray(tables), pos)
+        return got, want
+
+    def window_prefill(hq, hkv, d, ps, T, prefix, window):
+        maxp = -(-(prefix + T) // ps) + 3
+        k, v = pools(maxp + 2, ps, hkv, d, False, False)
+        q = normal(T, hq, d)
+        table = 1 + rng.permutation(maxp) % (maxp + 1)
+        pos = jnp.asarray(prefix + np.arange(T), jnp.int32)
+        want = reference(A.paged_prefill_attention, q, k, v, jnp.asarray(table, jnp.int32), pos, window)
+        table[: max(0, prefix - window + 1) // ps] = 0
+        got = jax.jit(functools.partial(A.dispatch_paged_prefill_attention, window=window))(
+            q, k, v, jnp.asarray(table, jnp.int32), pos)
+        return got, want
 
     def mla(ps, T=None, prefix=0):
         """The model's own Pallas call against its own _absorbed_attention."""
@@ -932,10 +965,12 @@ def child_parity(sizes: Sizes, args) -> int:
         tiny, qwen, mixtral, bench, shard = (32, 4, 64), (28, 4, 128), (32, 8, 128), (16, 8, 128), (7, 1, 128)
         qwen3b = (16, 2, 128)  # the benchmark's configuration
         T, prefix = 512, 1000
+        command_a, window = (128, 8, 128), 4096  # command-a-plus-ep8
     else:  # the CPU rehearsal: same code paths, interpret-mode sizes
         tiny, qwen, mixtral, bench, shard = (8, 2, 64), (4, 2, 128), (4, 2, 128), (4, 2, 128), (2, 1, 128)
         qwen3b = (4, 2, 128)
         T, prefix = 128, 200
+        command_a, window = (4, 2, 128), 128
     cases = [
         ("decode folded tinyllama ps16 bf16", lambda: decode(*tiny, 16, False)),
         ("decode folded tinyllama ps16 int8", lambda: decode(*tiny, 16, True)),
@@ -953,6 +988,10 @@ def child_parity(sizes: Sizes, args) -> int:
         ("prefill lookahead bench-16q8kv ps128 bf16", lambda: prefill(*bench, 128, T, prefix, False)),
         ("prefill basic mixtral ps16 bf16", lambda: prefill(*mixtral, 16, T, prefix, False, lookahead=False)),
         ("prefill folded qwen2.5-7b tp4-shard ps16 bf16", lambda: prefill(*shard, 16, T, prefix, False, folded=True)),
+        ("decode sliding window command-a-plus 3W ps16 bf16",
+         lambda: window_decode(*command_a, 16, window, 4)),
+        ("prefill sliding window command-a-plus 2.5W ps16 bf16",
+         lambda: window_prefill(*command_a, 16, T, 2 * window + window // 2, window)),
         ("mla decode classic ps16", lambda: mla(16)),
         ("mla prefill ps16", lambda: mla(16, T=T, prefix=prefix)),
         # Mamba-2 at the published widths (128 heads x 64 x 128), 24 slots

@@ -70,6 +70,10 @@ class DisaggDecodeEngine:
 
     async def start(self) -> "DisaggDecodeEngine":
         """Serve the prefill_result endpoint prefill workers call home to."""
+        refusal = getattr(self.engine, "transfer_refusal", None)
+        why = refusal() if refusal else None
+        if why:
+            raise ValueError(why)
         from dynamo_tpu.disagg import ici
         from dynamo_tpu.disagg.dataplane import KvDataPlaneServer
 

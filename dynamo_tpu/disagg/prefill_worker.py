@@ -65,6 +65,10 @@ class PrefillWorker:
         self.stream_overlap_s = 0.0
 
     async def start(self) -> "PrefillWorker":
+        refusal = getattr(self.engine, "transfer_refusal", None)
+        why = refusal() if refusal else None
+        if why:
+            raise ValueError(why)
         self._task = asyncio.create_task(self._loop())
         return self
 

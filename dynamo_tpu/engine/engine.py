@@ -19,7 +19,7 @@ from dataclasses import dataclass
 from typing import AsyncIterator, Callable, Optional
 
 from dynamo_tpu.engine.config import EngineConfig
-from dynamo_tpu.engine.page_table import PageAllocator
+from dynamo_tpu.engine.page_table import GroupedPageAllocator, PageAllocator
 from dynamo_tpu.engine.scheduler import EngineRequest, Scheduler, StepOutput
 from dynamo_tpu.llm.kv_events import KvCacheEvent
 from dynamo_tpu.runtime.context import current_context
@@ -186,6 +186,17 @@ class AsyncJaxEngine:
                 type(self.model).__name__,
             )
             self.config.migration = False
+        groups = getattr(self.model, "layer_groups", None)
+        if groups and (self.config.migration or self.config.prefix_fetch):
+            # refused, not an error: both are on by default, and both move a
+            # block between engines as ONE page id for every layer
+            log.warning(
+                "live migration and the fleet prefix fetch are refused for %s: "
+                "its layers come in groups with a page table each, and the "
+                "transfer paths carry one table", type(self.model).__name__,
+            )
+            self.config.migration = False
+            self.config.prefix_fetch = False
         offload = None
         if self.config.host_cache_blocks > 0 or self.config.host_cache_bytes > 0:
             from dynamo_tpu.engine.offload import (
@@ -237,13 +248,19 @@ class AsyncJaxEngine:
                 block_bytes=block_bytes,
             )
         self.offload = offload
-        self.allocator = PageAllocator(
-            self.config.num_pages,
-            self.config.page_size,
-            event_sink=self._on_kv_event,
-            offload=offload,
-            match_prefix=not self.runner.recurrent,
-        )
+        if groups:
+            self.allocator = GroupedPageAllocator(
+                self.config.num_pages, self.config.page_size, groups,
+                event_sink=self._on_kv_event,
+            )
+        else:
+            self.allocator = PageAllocator(
+                self.config.num_pages,
+                self.config.page_size,
+                event_sink=self._on_kv_event,
+                offload=offload,
+                match_prefix=not self.runner.recurrent,
+            )
         self.scheduler = Scheduler(self.config, self.runner, self.allocator)
         self.scheduler.slo = self.slo
         self.scheduler.outcome_sink = self._observe_outcome
@@ -419,6 +436,21 @@ class AsyncJaxEngine:
     # The decode side allocates pages and adopts; the prefill side computes KV
     # in its own cache and extracts blocks. See dynamo_tpu/disagg/.
 
+    def transfer_refusal(self) -> Optional[str]:
+        """Why this engine's pages cannot be moved to or from another engine
+        (disaggregated prefill, prefix pulls, migration), or None. The disagg
+        workers ask at their start-up; the entry points below ask again."""
+        if getattr(self.model, "layer_groups", None):
+            return (f"{type(self.model).__name__}: its attention layers come in groups "
+                    "with a page table each, and the transfer paths carry one table "
+                    "(disaggregated prefill, prefix pulls and migration are refused)")
+        return None
+
+    def _refuse_transfer(self) -> None:
+        why = self.transfer_refusal()
+        if why:
+            raise ValueError(why)
+
     def sync_lookup_prefix(self, token_ids: list[int], salt: int = 0) -> int:
         return self.allocator.lookup_prefix(token_ids, salt=salt)
 
@@ -438,6 +470,7 @@ class AsyncJaxEngine:
         the buffers), then host-pool blocks. Returns ``(n_dev_blocks,
         dev_host_future_or_None, host_blocks, cat_axis)``; None = leading
         block in no tier (the server answers with a clean "gone")."""
+        self._refuse_transfer()
         alloc, runner = self.allocator, self.runner
         if alloc is None or runner is None:
             return None
@@ -480,6 +513,7 @@ class AsyncJaxEngine:
         sequence's page still export OUR copy; a sequence already released
         (source raced ahead) falls back to the shared prefix cache, which
         usually still holds the committed blocks."""
+        self._refuse_transfer()
         alloc, runner = self.allocator, self.runner
         if alloc is None or runner is None or not hashes:
             return None
@@ -803,6 +837,7 @@ class AsyncJaxEngine:
         Returns (cached_len, shared_prefix_pages, page_ids) — the page ids in
         logical order, so the caller can scatter streamed KV parts into them
         as the parts land, before adoption."""
+        self._refuse_transfer()
         cached_len, state = self.allocator.allocate_sequence(request_id, token_ids)
         return cached_len, state.shared_prefix_pages, list(state.pages)
 
@@ -839,6 +874,7 @@ class AsyncJaxEngine:
         ``on_part(part_seq, part_total, page_from, page_to, host_future)``
         while the next chunk computes. The result then carries
         ``kv_parts == part_total`` and no host_data."""
+        self._refuse_transfer()
         from dynamo_tpu.disagg import ici
         from dynamo_tpu.disagg.dataplane import stream_part_plan
         from dynamo_tpu.engine.sampling import SamplingParams
@@ -1030,6 +1066,7 @@ class AsyncJaxEngine:
         (``kv_data``), or — the streamed path — scattered incrementally as
         parts landed, in which case ``injected_pages`` says how many pages
         the caller already wrote and this adopt only validates the count."""
+        self._refuse_transfer()
         from dynamo_tpu.disagg import ici
 
         state = self.allocator._seqs[req.request_id]
@@ -1133,6 +1170,13 @@ class AsyncJaxEngine:
             ),
             "prefix_cache_query_blocks": alloc.cache_query_blocks,
             "prefix_cache_refused": alloc.prefix_refused,
+            # layer groups (a window beside full attention): pages by group,
+            # and what running sequences gave back behind a window
+            "kv_group_pages": {
+                g: dict(states, decoding=sched.decode_group_pages.get(g, 0))
+                for g, states in alloc.group_pages().items()
+            } if hasattr(alloc, "group_pages") else {},
+            "kv_window_pages_released": getattr(alloc, "window_pages_released", 0),
             # the second kind of cache: per-slot recurrent state (zeros for
             # a model with no recurrent layers)
             "state_slots_total": self.config.max_seqs if recurrent else 0,
@@ -1583,9 +1627,27 @@ class AsyncJaxEngine:
             ),
             render_family(
                 "dynamo_engine_prefix_cache_refused_total", "counter",
-                "sequences whose cached prefix was withheld because the model "
-                "has recurrent layers (pages without state are another model)",
+                "sequences whose cached prefix was withheld: the model has "
+                "recurrent layers (pages without state), or a window layer no "
+                "longer holds the blocks behind the match (another model, silently)",
                 [({}, r["prefix_cache_refused"])],
+            ),
+            render_family(
+                "dynamo_engine_kv_group_pages", "gauge",
+                "single-layer pages by layer group (a model whose attention "
+                "layers keep different tokens): active = held by running "
+                "sequences, cached = evictable, free_equivalent = what the "
+                "free pool gives the group in whole blocks, whole = what the "
+                "running sequences would hold of it with no window, decoding = "
+                "held by the sequences of the last decode window",
+                [({"group": g, "state": st}, n)
+                 for g, states in r.get("kv_group_pages", {}).items() for st, n in states.items()],
+            ),
+            render_family(
+                "dynamo_engine_kv_window_pages_released_total", "counter",
+                "pages running sequences gave back because every token of them "
+                "lay behind a sliding window",
+                [({}, r.get("kv_window_pages_released", 0))],
             ),
             render_family(
                 "dynamo_engine_moe_assignments_total", "counter",

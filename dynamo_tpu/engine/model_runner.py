@@ -56,6 +56,32 @@ def recurrent_refusal(config: EngineConfig) -> Optional[str]:
     return None
 
 
+def layer_group_refusal(config: EngineConfig) -> Optional[str]:
+    """Why this engine configuration cannot serve a model whose attention
+    layers come in groups with a page table each (a sliding window beside full
+    attention: models/cohere2_moe.py), or None. Each of these moves, copies or
+    splits pages by ONE id a block for every layer, and nothing here has been
+    made to carry a table per layer yet; to run them would read another
+    layer's pages, silently."""
+    if config.speculative:
+        return ("speculative decoding is refused: the verify pass and the draft "
+                "cache have not been made to work with a page table per layer")
+    if config.host_cache_blocks > 0 or config.host_cache_bytes > 0 or config.disk_cache_bytes > 0:
+        return ("the host and disk KV tiers are refused: a block is several "
+                "pages, one per layer of its group, and the tiers move one")
+    if config.tp > 1 or config.pp > 1 or config.sp > 1:
+        return ("tp/pp/sp > 1 are refused: layer groups run on one chip (a "
+                "table per layer under a mesh is not built)")
+    if config.lora_adapters:
+        return "LoRA adapters are refused: the blocks carry no adapter pass"
+    if config.kv_cache_dtype == "int8":
+        return "the int8 KV cache is refused: the window kernels take no 8-bit pages"
+    if config.prefill_lanes <= 1:
+        return ("prefill_lanes <= 1 is refused: only the packed prefill path "
+                "takes and gives back a window layer's pages chunk by chunk")
+    return None
+
+
 class ModelRunner:
     def __init__(
         self,
@@ -164,6 +190,14 @@ class ModelRunner:
             why = recurrent_refusal(config)
             if why:
                 raise ValueError(f"model {type(model).__name__}: {why}")
+        #: page tables a sequence has: one, or one per attention layer of a
+        #: model with layer groups (its tables ride side by side, table-major,
+        #: wherever this file carries one: `_flat_table`)
+        self.kv_tables = int(getattr(model, "kv_tables", 1))
+        if self.kv_tables > 1:
+            why = layer_group_refusal(config)
+            if why:
+                raise ValueError(f"model {type(model).__name__}: {why}")
         if mesh is None:
             if config.pp > 1 and config.sp > 1:
                 # composed stage x sequence (x head) mesh: sp between pp and
@@ -235,9 +269,10 @@ class ModelRunner:
             kv_sharding = model.kv_cache_sharding(mesh)
         self.params = jax.device_put(params, shardings)
         cache = model.init_kv_cache(config.num_pages, config.page_size)
-        if self.recurrent:
-            # the second kind of cache rides the same donated bundle as the
-            # page pools, so every step function carries it unchanged
+        if hasattr(model, "init_state_cache"):
+            # what a model keeps beside the page pools (a recurrent state per
+            # slot, the expert counters) rides the same donated bundle, so
+            # every step function carries it unchanged
             cache.update(model.init_state_cache(config.max_seqs))
             kv_sharding = dict(kv_sharding, **model.state_cache_sharding(mesh))
         self.kv_cache = jax.device_put(cache, kv_sharding)
@@ -596,7 +631,8 @@ class ModelRunner:
         # table width for THIS call: the widest lane's ladder bucket (narrow
         # lanes zero-pad into the trash page) — short packs keep their
         # narrow executable; only packs containing a deep sequence go wide
-        mp = self.config.table_bucket_for(max(len(l[2]) for l in lanes))
+        width = self.config.table_bucket_for(max(l[2].shape[-1] for l in lanes))
+        mp = self.kv_tables * width
         ints = np.full((N, bucket + mp + 6 + MAX_EOS_IDS + int(self.recurrent)), V, np.int32)
         ints[:, :bucket] = 0
         ints[:, bucket : bucket + mp] = 0
@@ -609,7 +645,8 @@ class ModelRunner:
             lora_slot = lane[7] if len(lane) > 7 else 0
             n = len(tokens)
             ints[j, :n] = tokens
-            ints[j, bucket : bucket + len(page_table[:mp])] = page_table[:mp]
+            ints[j, bucket : bucket + mp].reshape(self.kv_tables, width)[
+                :, : page_table.shape[-1]] = page_table
             ints[j, bucket + mp] = start_pos
             ints[j, bucket + mp + 1] = n
             ints[j, bucket + mp + 2] = sampling.top_k
@@ -900,6 +937,7 @@ class ModelRunner:
         bucket = self.config.bucket_for(n)
         # the caller's table is already sized to a ladder bucket (scheduler/
         # engine build them via table_bucket_for); its width picks the trace
+        page_table = page_table.reshape(-1)  # a table per layer: side by side
         mp = len(page_table)
         V = self.model.config.vocab_size
         ints = np.full(bucket + mp + 6 + MAX_EOS_IDS + int(self.recurrent), V, np.int32)  # tail = eos pad
@@ -1168,6 +1206,7 @@ class ModelRunner:
         (np.asarray) while further windows run on device."""
         B = positions.shape[0]
         V = self.model.config.vocab_size
+        page_tables = page_tables.reshape(B, -1)  # a table per layer: side by side
         ints = np.empty((7 + MAX_EOS_IDS + page_tables.shape[1], B), np.int32)
         ints[0] = positions
         ints[1] = limits
@@ -1303,6 +1342,10 @@ class ModelRunner:
             and hasattr(self.model, "prefill_packed")
         )
 
+    def _table_shape(self, width: int) -> tuple:
+        """One sequence's page table at ladder width `width`."""
+        return (self.kv_tables, width) if self.kv_tables > 1 else (width,)
+
     def _warmup_shapes(self, table_width: Optional[int] = None):
         B = self.config.max_seqs
         # narrow (first-rung) tables are the hot path for a fresh engine —
@@ -1311,7 +1354,7 @@ class ModelRunner:
         mp = table_width or self.config.table_buckets[0]
         return {
             "zeros_i": np.zeros(B, np.int32),
-            "pt": np.zeros((B, mp), np.int32),
+            "pt": np.zeros((B, *self._table_shape(mp)), np.int32),
             "inactive": np.zeros(B, bool),
             "temps": np.zeros(B, np.float32),
             "ones_f": np.ones(B, np.float32),
@@ -1492,7 +1535,7 @@ class ModelRunner:
 
         def wide_chunk(width, b):
             def run():
-                pt = np.zeros(width, np.int32)
+                pt = np.zeros(self._table_shape(width), np.int32)
                 if self.packed_prefill_mode:
                     lane = (
                         np.zeros(b, np.int32), 0, pt, -1,

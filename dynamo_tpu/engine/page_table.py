@@ -111,6 +111,10 @@ class PageAllocator:
         """Pages referenced by live sequences."""
         return (self.num_pages - 1) - len(self._free) - len(self._reusable)
 
+    def pages_for_prompt(self, n_tokens: int) -> int:
+        """Pages a prompt of `n_tokens` needs (admission's reckoning)."""
+        return -(-n_tokens // self.page_size)
+
     def _pop_free_page(self) -> int:
         return self._pop_free_pages(1)[0]
 
@@ -514,3 +518,348 @@ class PageAllocator:
         self._cache_meta[block.sequence_hash] = meta
         state.registered_hashes.append(block.sequence_hash)
         self._emit(KvCacheEvent.stored(parent_hash=block.parent_sequence_hash, blocks=[meta]))
+
+
+# ---------------------------------------------------------------- layer groups
+
+
+@dataclass
+class GroupedSequencePages:
+    """Page state for one live sequence of a model with layer groups: a page
+    table per attention layer, all of one logical length; an entry is 0 where
+    the sequence holds no page for that block (given back behind a window, or
+    never taken because a prefix hit began past it)."""
+
+    seq_id: str
+    tables: list  # [table][logical block] -> physical page or 0
+    token_seq: Optional[TokenSequence] = None
+    shared_prefix_pages: int = 0  # leading blocks taken from the prefix cache
+    released: list = field(default_factory=list)  # per group: blocks below are gone
+    held: list = field(default_factory=list)  # per group: blocks from here on are not taken yet
+
+    @property
+    def num_pages(self) -> int:
+        """Logical blocks the sequence has room for (every table's length)."""
+        return len(self.tables[0])
+
+
+class GroupedPageAllocator(PageAllocator):
+    """ONE pool of single-layer pages and one byte budget under attention
+    layers that come in GROUPS (`model.layer_groups`: name, page tables,
+    window). A logical block of a group is an ENTRY: one page per layer of the
+    group, taken and given back together. A group with a window W keeps, per
+    running sequence, the blocks that hold a token within W of its newest
+    position and gives the rest back (`release_behind`), during a long chunked
+    prefill as during decode; a group without one keeps every block.
+
+    Prefix cache, per group: a registered entry that loses its last user stays
+    as evictable, in ONE LRU, whether its sequence finished or dropped it
+    behind a window. What decode drops lies within a window of the prompt's
+    end, which is where a conversation's next turn matches (the answer is not
+    part of the next prompt), so it must not be reclaimed ahead of older
+    entries. A match of P tokens is
+    given only if every group can serve the first new token: the groups
+    without a window hold every block below P, and each group with one holds
+    every block with a token in (P - W, P]. Else the match is refused WHOLE
+    and counted (`prefix_refused`): a hit without those rows would be another
+    model, silently.
+
+    Not made for this allocator, and refused at start-up for such a model
+    (model_runner.layer_group_refusal): the host and disk tiers, transfer of
+    pages between engines, int8 pages, tp/pp/sp.
+    """
+
+    def __init__(self, num_pages: int, page_size: int, groups,
+                 event_sink: Optional[Callable[[KvCacheEvent], None]] = None):
+        super().__init__(num_pages, page_size, event_sink=event_sink)
+        self.groups = list(groups)
+        self.num_tables = sum(len(g.tables) for g in self.groups)
+        #: the groups that keep every block: their chain decides a match's length
+        self._whole = [i for i, g in enumerate(self.groups) if not g.window]
+        # without one no chain of blocks is ever whole: every match is refused
+        self.match_prefix = bool(self._whole)
+        # (group, seq_hash) -> entry (tuple of pages, one per table of the group)
+        self._entries: dict[tuple, tuple] = {}
+        # an entry's users, by its first page (pages belong to one entry)
+        self._users: dict[int, int] = {}
+        # refcount-0 registered entries, oldest first: (group, seq_hash) -> entry
+        self._reusable: OrderedDict[tuple, tuple] = OrderedDict()
+        # counters, kept as the pools change: `/metrics` reads them from
+        # another thread, where the pools themselves cannot be walked
+        self._evictable_pages = 0
+        self._cached = [0] * len(self.groups)  # evictable pages, by group
+        self._active = [0] * len(self.groups)  # pages held by running sequences
+        self._live_blocks = 0  # logical blocks of all running sequences
+        self.window_pages_released = 0
+
+    # ------------- capacity -------------
+
+    @property
+    def free_pages(self) -> int:
+        return len(self._free) + self._evictable_pages
+
+    @property
+    def active_pages(self) -> int:
+        return (self.num_pages - 1) - len(self._free) - self._evictable_pages
+
+    def pages_for_prompt(self, n_tokens: int) -> int:
+        """Pages a prompt of `n_tokens` needs at most at one time."""
+        blocks = -(-n_tokens // self.page_size)
+        need = 0
+        for g in self.groups:
+            kept = min(blocks, -(-g.window // self.page_size) + 1) if g.window else blocks
+            need += kept * len(g.tables)
+        return need
+
+    def group_pages(self) -> dict:
+        """{group: {state: pages}}: `active` held by running sequences,
+        `cached` evictable, `free_equivalent` what the free and evictable
+        pages give this group in whole entries, `whole` what the running
+        sequences would hold of this group with no window. Counters only:
+        safe to read from a thread that is not the engine's."""
+        out = {}
+        for gi, g in enumerate(self.groups):
+            n = len(g.tables)
+            out[g.name] = {
+                "active": self._active[gi], "cached": self._cached[gi],
+                "free_equivalent": self.free_pages // n * n, "whole": self._live_blocks * n,
+            }
+        return out
+
+    def _pop_free_pages(self, n: int) -> list[int]:
+        if n > self.free_pages:
+            raise MemoryError("out of KV pages")
+        removed = []
+        while len(self._free) < n:
+            (gi, seq_hash), entry = self._reusable.popitem(last=False)
+            del self._entries[(gi, seq_hash)]
+            self._evictable_pages -= len(entry)
+            self._cached[gi] -= len(entry)
+            for page in entry:
+                self._meter_release(page)
+            self._free.extend(entry)
+            if self._whole and gi == self._whole[0]:
+                removed.append(seq_hash)
+        if removed:
+            self._emit(KvCacheEvent.removed(removed))
+        out = [self._free.pop() for _ in range(n)]
+        if self.used_pages > self.peak_used_pages:
+            self.peak_used_pages = self.used_pages
+        return out
+
+    # ------------- entries -------------
+
+    def _take(self, gi: int, entry: tuple) -> None:
+        """One more running sequence uses `entry`."""
+        users = self._users.get(entry[0], 0)
+        if users == 0:
+            self._active[gi] += len(entry)
+        self._users[entry[0]] = users + 1
+
+    def _take_cached(self, gi: int, seq_hash: int) -> tuple:
+        key = (gi, seq_hash)
+        entry = self._entries[key]
+        if self._reusable.pop(key, None) is not None:
+            self._evictable_pages -= len(entry)
+            self._cached[gi] -= len(entry)
+        self._take(gi, entry)
+        return entry
+
+    def _drop(self, gi: int, entry: tuple, seq_hash: Optional[int]) -> None:
+        """A running sequence lets go of `entry`; with its last user gone it
+        stays as evictable where it is the registered entry of `seq_hash`,
+        else its pages are free."""
+        users = self._users[entry[0]] - 1
+        if users > 0:
+            self._users[entry[0]] = users
+            return
+        del self._users[entry[0]]
+        self._active[gi] -= len(entry)
+        key = (gi, seq_hash)
+        if seq_hash is not None and self._entries.get(key) == entry:
+            self._reusable[key] = entry
+            self._evictable_pages += len(entry)
+            self._cached[gi] += len(entry)
+        else:
+            for page in entry:
+                self._meter_release(page)
+            self._free.extend(entry)
+
+    def _fresh(self, gi: int, owner) -> tuple:
+        entry = tuple(self._pop_free_pages(len(self.groups[gi].tables)))
+        self._meter_acquire(list(entry), owner)
+        self._take(gi, entry)
+        return entry
+
+    def _entry_of(self, state: GroupedSequencePages, gi: int, block: int):
+        entry = tuple(state.tables[t][block] for t in self.groups[gi].tables)
+        return entry if entry[0] else None
+
+    def _set(self, state: GroupedSequencePages, gi: int, block: int, entry) -> None:
+        for t, page in zip(self.groups[gi].tables, entry or [0] * len(self.groups[gi].tables)):
+            state.tables[t][block] = page
+
+    def _hash_of(self, state: GroupedSequencePages, block: int) -> Optional[int]:
+        blocks = state.token_seq.blocks
+        return blocks[block].sequence_hash if block < len(blocks) else None
+
+    def _first_needed(self, gi: int, position: int) -> int:
+        """First block that holds a key a query at `position` may see."""
+        window = self.groups[gi].window
+        return max(0, position - window + 1) // self.page_size if window else 0
+
+    # ------------- the prefix rule -------------
+
+    def _match(self, ts: TokenSequence, prompt_len: int) -> tuple:
+        """(blocks matched, refused): the longest chain every whole group
+        holds, never the entire prompt, and only if every window group still
+        holds the blocks behind it that the first new token will read."""
+        if not self._whole:
+            return 0, False
+        n = 0
+        for block in ts.blocks:
+            if any((gi, block.sequence_hash) not in self._entries for gi in self._whole):
+                break
+            n += 1
+        if n and n * self.page_size >= prompt_len:
+            n -= 1
+        if not n:
+            return 0, False
+        for gi, g in enumerate(self.groups):
+            if not g.window:
+                continue
+            for b in range(self._first_needed(gi, n * self.page_size), n):
+                if (gi, ts.blocks[b].sequence_hash) not in self._entries:
+                    return 0, True
+        return n, False
+
+    def lookup_prefix(self, prompt_tokens: list[int], salt: int = 0) -> int:
+        ts = TokenSequence(prompt_tokens, self.page_size, salt=salt)
+        return self._match(ts, len(prompt_tokens))[0] * self.page_size
+
+    def cached_page(self, seq_hash: int) -> Optional[int]:
+        return None  # no single page holds a block: pulls are refused at start-up
+
+    # ------------- sequence lifecycle -------------
+
+    def allocate_sequence(self, seq_id: str, prompt_tokens: list[int], salt: int = 0,
+                          owner: Optional[tuple] = None) -> tuple[int, GroupedSequencePages]:
+        """Pages for a prompt: the matched prefix from the cache, then every
+        block of the groups without a window. A window group's blocks past the
+        match are taken chunk by chunk (`ensure_capacity`) and given back as
+        the prefill moves on (`release_behind`), so a long prompt never holds
+        more of a window layer than the window and a chunk."""
+        if seq_id in self._seqs:
+            raise ValueError(f"sequence {seq_id} already allocated")
+        ts = TokenSequence(prompt_tokens, self.page_size, salt=salt)
+        n, refused = self._match(ts, len(prompt_tokens))
+        self.prefix_refused += int(refused)
+        self.cache_query_blocks += len(ts.blocks)
+        self.cache_hit_blocks += n
+        total = -(-len(prompt_tokens) // self.page_size)
+        state = GroupedSequencePages(
+            seq_id=seq_id, tables=[[0] * total for _ in range(self.num_tables)], token_seq=ts,
+            shared_prefix_pages=n,
+            released=[self._first_needed(gi, n * self.page_size) for gi in range(len(self.groups))],
+            held=[n if g.window else total for g in self.groups],
+        )
+        self._seq_owner[seq_id] = owner
+        try:
+            for gi, g in enumerate(self.groups):
+                for b in range(state.released[gi], n):
+                    self._set(state, gi, b, self._take_cached(gi, ts.blocks[b].sequence_hash))
+                if not g.window:
+                    for b in range(n, total):
+                        self._set(state, gi, b, self._fresh(gi, owner))
+        except MemoryError:
+            self._release_all(state)
+            self._seq_owner.pop(seq_id, None)
+            raise
+        self._seqs[seq_id] = state
+        self._live_blocks += total
+        return n * self.page_size, state
+
+    def ensure_capacity(self, seq_id: str, length: int) -> bool:
+        """Make sure pages exist to hold tokens up to `length` in every group
+        (a window group: from the first block it has not given back). False,
+        with nothing taken, if the pool cannot give them."""
+        state = self._seqs[seq_id]
+        needed = -(-length // self.page_size)
+        missing = [
+            (gi, b) for gi in range(len(self.groups))
+            for b in range(max(state.held[gi], state.released[gi]), needed)
+        ]
+        if sum(len(self.groups[gi].tables) for gi, _ in missing) > self.free_pages:
+            return False
+        self._live_blocks += max(0, needed - state.num_pages)
+        for table in state.tables:
+            table.extend([0] * (needed - len(table)))
+        for gi, b in missing:
+            self._set(state, gi, b, self._fresh(gi, self._seq_owner.get(seq_id)))
+        state.held = [max(h, needed) for h in state.held]
+        return True
+
+    def release_behind(self, seq_id: str, position: int) -> int:
+        """Every query still to be dispatched for this sequence sits at
+        `position` or later: give back each window group's blocks that lie
+        wholly behind its window there. Steps already dispatched read their own
+        copy of the tables and run before any later write to the pages. A
+        registered entry that is dropped joins the LRU (see the class). Returns
+        the pages given back."""
+        state = self._seqs[seq_id]
+        given = 0
+        for gi, g in enumerate(self.groups):
+            upto = min(self._first_needed(gi, position), state.num_pages)
+            for b in range(state.released[gi], upto):
+                entry = self._entry_of(state, gi, b)
+                if entry is not None:
+                    self._drop(gi, entry, self._hash_of(state, b))
+                    self._set(state, gi, b, None)
+                    given += len(entry)
+            state.released[gi] = max(state.released[gi], upto)
+        self.window_pages_released += given
+        return given
+
+    def commit_prefilled(self, seq_id: str, prompt_len: int) -> None:
+        state = self._seqs[seq_id]
+        for b in range(state.shared_prefix_pages, prompt_len // self.page_size):
+            self._register_block(state, b)
+
+    def append_token(self, seq_id: str, token: int) -> None:
+        """As `PageAllocator.append_token`: a decode-written block is
+        registered one token after it fills."""
+        state = self._seqs[seq_id]
+        state.token_seq.push_token(token)
+        n = len(state.token_seq)
+        if (n - 1) % self.page_size == 0 and n > self.page_size:
+            b = (n - 1) // self.page_size - 1
+            if b < state.num_pages:
+                self._register_block(state, b)
+
+    def _register_block(self, state: GroupedSequencePages, b: int) -> None:
+        block = state.token_seq.blocks[b]
+        for gi in range(len(self.groups)):
+            entry = self._entry_of(state, gi, b)
+            key = (gi, block.sequence_hash)
+            if entry is None or key in self._entries:
+                continue  # given back already, or first writer wins
+            self._entries[key] = entry
+            if self._whole and gi == self._whole[0]:
+                meta = StoredBlock(block_hash=block.sequence_hash, tokens_hash=block.block_hash,
+                                   parent_hash=block.parent_sequence_hash)
+                self._emit(KvCacheEvent.stored(parent_hash=block.parent_sequence_hash, blocks=[meta]))
+
+    def free_sequence(self, seq_id: str) -> None:
+        state = self._seqs.pop(seq_id)
+        self._live_blocks -= state.num_pages
+        self._release_all(state)
+        self._seq_owner.pop(seq_id, None)
+
+    def _release_all(self, state: GroupedSequencePages) -> None:
+        for gi in range(len(self.groups)):
+            for b in range(state.num_pages):
+                entry = self._entry_of(state, gi, b)
+                if entry is not None:
+                    self._drop(gi, entry, self._hash_of(state, b))
+        for table in state.tables:
+            table.clear()

@@ -508,6 +508,12 @@ class Scheduler:
         # page-table width ladder, depth-aware chunk planner, and the
         # watermark-driven cold-block drain to the host tier
         self.table_promotions = 0  # sequences promoted to a wider table rung
+        #: the model's attention layers come in groups with a page table each
+        #: (engine/page_table.py GroupedPageAllocator)
+        self.grouped = hasattr(allocator, "release_behind")
+        #: layer groups: pages the last decode window's sequences held, by
+        #: group (what its attention calls had to read, all layers together)
+        self.decode_group_pages: dict = {}
         self.table_dispatches: dict[int, int] = {}  # table width -> dispatches
         self.chunk_dispatches: dict[int, int] = {}  # chunk bucket -> chunks
         self.offload_pressure_blocks = 0  # cold blocks drained to host by watermark
@@ -699,23 +705,55 @@ class Scheduler:
 
     # ---------------- page-table ladder ----------------
 
-    def _new_table(self, pages: list[int]) -> np.ndarray:
+    def _new_table(self, state) -> np.ndarray:
         """Page table at the sequence's CURRENT ladder width (pow2 bucket of
         its page count) — not the dense max_pages_per_seq width, so a short
-        request in a 128K-capable engine dispatches a narrow table."""
-        table = np.zeros(self.config.table_bucket_for(max(1, len(pages))), np.int32)
-        table[: len(pages)] = pages
+        request in a 128K-capable engine dispatches a narrow table. A model
+        with layer groups has one row per attention layer (`[tables, width]`,
+        from `GroupedSequencePages.tables`); every other model one row."""
+        width = self.config.table_bucket_for(max(1, state.num_pages))
+        if self.grouped:
+            table = np.zeros((self.allocator.num_tables, width), np.int32)
+            table[:, : state.num_pages] = state.tables
+            return table
+        table = np.zeros(width, np.int32)
+        table[: state.num_pages] = state.pages
         return table
 
     def _refresh_table(self, seq: RunningSeq) -> None:
         """Re-sync a sequence's table from the allocator, promoting it to
         the next ladder rung when its pages outgrew the current width."""
         state = self.allocator._seqs[seq.req.request_id]
-        n = len(state.pages)
-        if n > len(seq.page_table):
-            seq.page_table = np.zeros(self.config.table_bucket_for(n), np.int32)
+        n = state.num_pages
+        if n > seq.page_table.shape[-1]:
             self.table_promotions += 1
-        seq.page_table[:n] = state.pages
+            seq.page_table = self._new_table(state)
+        elif self.grouped:
+            seq.page_table[:, :n] = state.tables
+        else:
+            seq.page_table[:n] = state.pages
+
+    def _batch_tables(self, rows: int, seqs: list) -> tuple:
+        """(zeroed page tables for a batch of `rows`, their ladder width): the
+        widest of `seqs` sets the width; a model with layer groups gets
+        `[rows, tables, width]` (`_flat_tables` before the runner sees it)."""
+        W = self.config.table_bucket_for(max(s.page_table.shape[-1] for s in seqs))
+        self._count_table_dispatch(W)
+        shape = (rows, self.allocator.num_tables, W) if self.grouped else (rows, W)
+        return np.zeros(shape, np.int32), W
+
+    @staticmethod
+    def _flat_tables(page_tables: np.ndarray) -> np.ndarray:
+        """[rows, tables, width] -> [rows, tables * width], table-major (the
+        layout models/cohere2_moe.py splits again); [rows, width] as it is."""
+        return page_tables.reshape(page_tables.shape[0], -1)
+
+    def _release_behind(self, seq: RunningSeq, position: int) -> None:
+        """Layer groups: every query still to be dispatched for `seq` sits at
+        `position` or later, so what lies behind a window there goes back to
+        the pool (engine/page_table.py `release_behind`)."""
+        if self.grouped and self.allocator.release_behind(seq.req.request_id, position):
+            self._refresh_table(seq)
 
     def _count_table_dispatch(self, width: int) -> None:
         self.table_dispatches[width] = self.table_dispatches.get(width, 0) + 1
@@ -800,7 +838,7 @@ class Scheduler:
                     and not (packed_mode and not req.images)
                 ):
                     break
-                pages_needed = -(-len(req.token_ids) // self.config.page_size)
+                pages_needed = self.allocator.pages_for_prompt(len(req.token_ids))
                 if self.allocator.free_pages < pages_needed + watermark_pages:
                     break
                 lora_slot = 0
@@ -1043,7 +1081,7 @@ class Scheduler:
             owner=(req.tenant, req.request_id),
         )
         prompt_len = len(req.token_ids)
-        page_table = self._new_table(state.pages)
+        page_table = self._new_table(state)
 
         seq = RunningSeq(
             req=req,
@@ -1481,8 +1519,14 @@ class Scheduler:
                 cand = self.config.bucket_for(max(bucket, end - s.prefill_pos))
                 if chunks and len(chunks) + 1 > self.config.lanes_for(cand):
                     break
+                if self.grouped and not self._chunk_pages(s, end, outputs):
+                    continue
                 chunks.append((s, s.prefill_pos, end))
                 bucket = cand
+            # a later lane's page pressure may have preempted an earlier one
+            chunks = [c for c in chunks if self.slots[c[0].slot] is c[0] and not c[0].finished]
+            if not chunks:
+                return count
             lanes_max = self.config.lanes_for(bucket)
             # lone chunks ride the packed trace at N=1 too: measured 33%
             # faster than the per-request trace for identical work (r5
@@ -1513,7 +1557,7 @@ class Scheduler:
                 cb = self.config.bucket_for(end - start)
                 self.chunk_dispatches[cb] = self.chunk_dispatches.get(cb, 0) + 1
             self._count_table_dispatch(self.config.table_bucket_for(
-                max(len(s.page_table) for s, _, _ in chunks)
+                max(s.page_table.shape[-1] for s, _, _ in chunks)
             ))
             N = min(lanes_max, 1 << (len(chunks) - 1).bit_length())
             rec = self.anatomy.begin(
@@ -1550,6 +1594,8 @@ class Scheduler:
                     seq.prefill_dispatched_ts = ph.t1
                 else:
                     seq.prefill_pos = end
+                # the next chunk, or the first decode step, starts at `end`
+                self._release_behind(seq, end)
             toks_dev, lp = result if want_lp else (result, None)
             # EVERY pack (not just final-bearing ones) rides the in-flight
             # queue: the pipeline gate above counts it, and its reconcile
@@ -1561,6 +1607,27 @@ class Scheduler:
                 rec=rec,
             ))
             count += 1
+
+    def _chunk_pages(self, seq: RunningSeq, end: int, outputs: list[StepOutput]) -> bool:
+        """Layer groups: a window group's pages are taken chunk by chunk, so
+        make sure `seq` has them up to `end` before its chunk is dispatched.
+        Page pressure takes the decode windows' ladder: drain the pipeline,
+        then preempt the youngest other sequence. False: no chunk this time."""
+        rid = seq.req.request_id
+        while self.slots[seq.slot] is seq and not self.allocator.ensure_capacity(rid, end):
+            if self.in_flight:
+                self.pressure_drain_count += 1
+                outputs.extend(self._reconcile(block=True, drain=True))
+                continue
+            victim = self._pick_victim(exclude=seq)
+            if victim is None:
+                outputs.extend(self._finish(seq, "error"))
+                return False
+            self._preempt(victim)
+        if self.slots[seq.slot] is not seq or seq.finished:
+            return False
+        self._refresh_table(seq)
+        return True
 
     def _prep_prefill(
         self, req: EngineRequest, slot: int, prompt_len: int, cached_len: int = 0
@@ -1641,7 +1708,7 @@ class Scheduler:
         self.local_prefill_rows += rows
         if rows:
             self._count_table_dispatch(
-                self.config.table_bucket_for(len(page_table))
+                self.config.table_bucket_for(page_table.shape[-1])
             )
         s = req.sampling
         first_token = None
@@ -1734,7 +1801,7 @@ class Scheduler:
         )
         self._charge_admission(req, wait)
         state = self.allocator._seqs[req.request_id]
-        page_table = self._new_table(state.pages)
+        page_table = self._new_table(state)
         lora_slot = 0
         if req.lora_name:
             # adopted sequences arrive with their KV already computed; the
@@ -2039,12 +2106,8 @@ class Scheduler:
         B = self.config.max_seqs
         # per-round table width: the widest participant's ladder rung (narrow
         # sequences zero-pad into the trash page)
-        W = self.config.table_bucket_for(
-            max(len(s.page_table) for s, _, _, _ in candidates)
-        )
-        self._count_table_dispatch(W)
+        page_tables, W = self._batch_tables(B, [s for s, _, _, _ in candidates])
         positions = np.zeros(B, np.int32)
-        page_tables = np.zeros((B, W), np.int32)
         active = np.zeros(B, bool)
         fed = np.zeros((B, K + 1), np.int32)
         n_drafts = np.zeros(B, np.int32)
@@ -2058,7 +2121,7 @@ class Scheduler:
         for seq, p, drafts, _ in candidates:
             i = seq.slot
             positions[i] = p
-            page_tables[i, : len(seq.page_table)] = seq.page_table
+            page_tables[i, ..., : seq.page_table.shape[-1]] = seq.page_table
             active[i] = True
             fed[i, 0] = seq.generated[-1]
             if drafts:
@@ -2075,7 +2138,7 @@ class Scheduler:
 
         t0 = time.monotonic()
         out_dev, n_emit_dev = self.runner.dispatch_verify(
-            positions, page_tables, active, fed, n_drafts, temps, top_ks,
+            positions, self._flat_tables(page_tables), active, fed, n_drafts, temps, top_ks,
             top_ps, min_ps=min_ps, seeds=seeds if np.any(seeds) else None,
             draft_probs=draft_probs,
             lora_slots=lora_slots if np.any(lora_slots) else None,
@@ -2196,6 +2259,8 @@ class Scheduler:
                     break
                 self._preempt(victim)
             if self.slots[seq.slot] is seq:
+                if self.grouped:
+                    self.allocator.release_behind(seq.req.request_id, seq.next_fed_pos)
                 self._refresh_table(seq)
 
         # host-prep timing starts AFTER the capacity pass: a pressure drain
@@ -2221,12 +2286,13 @@ class Scheduler:
         # per-window table width: the widest participant's ladder rung —
         # short-sequence batches keep their narrow H2D + gather, and only
         # windows containing a deep sequence dispatch the wide executable
-        W = self.config.table_bucket_for(
-            max(len(seq.page_table) for seq, _ in participants)
-        )
-        self._count_table_dispatch(W)
+        page_tables, W = self._batch_tables(B, [seq for seq, _ in participants])
+        if self.grouped:
+            held = sum(np.count_nonzero(seq.page_table, axis=1) for seq, _ in participants)
+            self.decode_group_pages = {
+                g.name: int(held[list(g.tables)].sum()) for g in self.allocator.groups
+            }
         positions = np.zeros(B, np.int32)
-        page_tables = np.zeros((B, W), np.int32)
         active = np.zeros(B, bool)
         limits = np.zeros(B, np.int32)
         temps = np.zeros(B, np.float32)
@@ -2244,7 +2310,7 @@ class Scheduler:
         for seq, steps in participants:
             i = seq.slot
             positions[i] = seq.next_fed_pos
-            page_tables[i, : len(seq.page_table)] = seq.page_table
+            page_tables[i, ..., : seq.page_table.shape[-1]] = seq.page_table
             active[i] = True
             limits[i] = seq.next_fed_pos + steps - 1  # max fed position
             temps[i] = seq.req.sampling.temperature
@@ -2292,7 +2358,7 @@ class Scheduler:
             participants=len(snapshot), k=K, steps_total=steps_total,
         ):
             result = self.runner.dispatch_decode_window(
-                positions, page_tables, active, limits, temps, top_ks, top_ps, K,
+                positions, self._flat_tables(page_tables), active, limits, temps, top_ks, top_ps, K,
                 want_logprobs=want_lp, rope_deltas=rope_deltas, min_ps=min_ps,
                 penalties=penalties if want_pen else None,
                 seeds=seeds if np.any(seeds) else None,

@@ -436,3 +436,72 @@ def load_nemotron_h_weights(model, path: Path) -> dict:
     if missing:
         raise ValueError(f"checkpoint {path} lacks {sorted(missing)}")
     return arrays
+
+
+def load_cohere2_moe_weights(model, path: Path) -> dict:
+    """Cohere2-MoE (`model.layers.N.{input_layernorm, self_attn.*, mlp.*}`):
+    layers are a list, the leaves are filled in their own dtype on a pool of
+    threads (as `load_nemotron_h_weights`: the expert banks of one chip's share
+    are gigabytes). Routed experts are read from `mlp.experts.0 ..` up to the
+    count the config holds; the shared experts `mlp.shared_experts.0 ..` go
+    side by side into one matrix each (their outputs are averaged, so the
+    concatenated product divided by their number is the same sum)."""
+    import os
+    from collections import deque
+    from concurrent.futures import ThreadPoolExecutor
+
+    shapes = jax.eval_shape(model.init_params, jax.random.key(0))
+    arrays = jax.tree.map(lambda s: np.zeros(s.shape, s.dtype), shapes)
+    F = model.config.intermediate_size
+    per_layer = {
+        "input_layernorm.weight": ("norm", False),
+        "self_attn.q_proj.weight": ("wq", True), "self_attn.k_proj.weight": ("wk", True),
+        "self_attn.v_proj.weight": ("wv", True), "self_attn.o_proj.weight": ("wo", True),
+        "mlp.gate.weight": ("router", True),
+    }
+    routed = {"gate_proj.weight": "w_gate", "up_proj.weight": "w_up", "down_proj.weight": "w_down"}
+    top = {"model.embed_tokens.weight": "embed", "model.norm.weight": "final_norm"}
+    layers = arrays["layers"]
+    filled = set()
+    pending: deque = deque()
+    with ThreadPoolExecutor(min(16, os.cpu_count() or 4)) as pool:
+
+        def put(dest: np.ndarray, src: np.ndarray) -> None:
+            pending.append(pool.submit(dest.__setitem__, ..., src))
+            if len(pending) > 256:  # bounds the tensors read and not yet copied
+                pending.popleft().result()
+
+        for name, tensor in _iter_checkpoint_tensors(path):
+            if name in top:
+                put(arrays[top[name]], tensor)
+                filled.add(top[name])
+                continue
+            if not name.startswith("model.layers."):
+                log.debug("skipping unmapped weight %s", name)
+                continue
+            layer_str, sub = name[len("model.layers."):].split(".", 1)
+            if int(layer_str) >= len(layers):
+                continue
+            lp = layers[int(layer_str)]
+            if sub.startswith("mlp.experts."):
+                e_str, which = sub[len("mlp.experts."):].split(".", 1)
+                if which in routed and int(e_str) < lp["w_gate"].shape[0]:
+                    put(lp[routed[which]][int(e_str)], tensor.T)
+            elif sub.startswith("mlp.shared_experts."):
+                j_str, which = sub[len("mlp.shared_experts."):].split(".", 1)
+                at = slice(int(j_str) * F, (int(j_str) + 1) * F)
+                if which == "down_proj.weight":
+                    put(lp["shared_down"][at], tensor.T)
+                elif which in routed:
+                    put(lp["shared_" + which.split("_")[0]][:, at], tensor.T)
+            elif sub in per_layer:
+                key, transpose = per_layer[sub]
+                put(lp[key], tensor.T if transpose else tensor)
+            else:
+                log.debug("skipping unmapped weight %s", name)
+        for done in pending:
+            done.result()
+    missing = {"embed", "final_norm"} - filled
+    if missing:
+        raise ValueError(f"checkpoint {path} lacks {sorted(missing)}")
+    return arrays

@@ -2,7 +2,8 @@
 
 Supported ids:
   - ``tiny`` / ``tiny:<json-overrides>``: random-weight test model (and
-    ``tiny-moe``, ``tiny-mla``, ``tiny-vl``, ``tiny-hybrid`` likewise)
+    ``tiny-moe``, ``tiny-mla``, ``tiny-vl``, ``tiny-hybrid``, ``tiny-window``
+    likewise)
   - a local HuggingFace checkpoint directory (config.json [+ safetensors])
 
 The reference resolves models from HF repos via its model-deployment-card
@@ -49,6 +50,8 @@ ARCHITECTURES = {
         "qwen2_vl", "Qwen2VLConfig", "Qwen2VLModel", "load_qwen2_vl_weights"),
     "NemotronHForCausalLM": (
         "nemotron_h", "NemotronHConfig", "NemotronHModel", "load_nemotron_h_weights"),
+    "Cohere2MoeForCausalLM": (
+        "cohere2_moe", "Cohere2MoeConfig", "Cohere2MoeModel", "load_cohere2_moe_weights"),
 }
 
 
@@ -72,7 +75,7 @@ def is_tiny_family(model_id) -> bool:
     if model_id is None:
         return True
     s = str(model_id)
-    for fam in ("tiny", "tiny-moe", "tiny-mla", "tiny-vl", "tiny-hybrid"):
+    for fam in ("tiny", "tiny-moe", "tiny-mla", "tiny-vl", "tiny-hybrid", "tiny-window"):
         if s == fam or s.startswith(fam + ":"):
             return True
     return False
@@ -163,6 +166,15 @@ def _load_model_uncached(model_id: str, seed: int = 0, quantize: str | None = No
 
         overrides = json.loads(model_id.split(":", 1)[1]) if ":" in model_id else {}
         model = NemotronHModel(with_quant(NemotronHConfig.tiny(**overrides)))
+        params = jax.jit(model.init_params)(jax.random.key(seed))
+        jax.block_until_ready(params)
+        return model, params
+
+    if model_id is not None and (model_id == "tiny-window" or model_id.startswith("tiny-window:")):
+        from dynamo_tpu.models.cohere2_moe import Cohere2MoeConfig, Cohere2MoeModel
+
+        overrides = json.loads(model_id.split(":", 1)[1]) if ":" in model_id else {}
+        model = Cohere2MoeModel(with_quant(Cohere2MoeConfig.tiny(**overrides)))
         params = jax.jit(model.init_params)(jax.random.key(seed))
         jax.block_until_ready(params)
         return model, params

@@ -144,8 +144,11 @@ def attention_with_positions(
     k_ctx: jnp.ndarray,  # [S, Hkv, D] in logical order (index == position)
     v_ctx: jnp.ndarray,  # [S, Hkv, D]
     q_positions: jnp.ndarray,  # [T] int32
+    window: int = 0,
 ) -> jnp.ndarray:
-    """Causal attention where context index j attends iff j <= q_position[t].
+    """Causal attention where context index j attends iff j <= q_position[t]
+    and, with a sliding ``window`` W > 0, j > q_position[t] - W (the query
+    itself included, W keys in all).
 
     Softmax in float32; output cast back to q.dtype.
     """
@@ -156,6 +159,8 @@ def attention_with_positions(
     scores = jnp.einsum("thd,shd->hts", q.astype(jnp.float32), k.astype(jnp.float32)) * scale
     ctx_idx = jnp.arange(k.shape[0], dtype=jnp.int32)
     mask = ctx_idx[None, :] <= q_positions[:, None]  # [T, S]
+    if window:
+        mask &= ctx_idx[None, :] > q_positions[:, None] - window
     scores = jnp.where(mask[None, :, :], scores, _NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("hts,shd->thd", probs, v.astype(jnp.float32))
@@ -168,12 +173,13 @@ def paged_prefill_attention(
     v_pages: jnp.ndarray,
     page_table: jnp.ndarray,  # [max_pages]
     q_positions: jnp.ndarray,  # [T] absolute positions (pad rows: anything)
+    window: int = 0,
 ) -> jnp.ndarray:
     """Chunk attention over all cached context + self (already written to pages)."""
     D = q.shape[-1]
     k_ctx = gather_pages(k_pages, page_table, head_dim=D)
     v_ctx = gather_pages(v_pages, page_table, head_dim=D)
-    return attention_with_positions(q, k_ctx, v_ctx, q_positions)
+    return attention_with_positions(q, k_ctx, v_ctx, q_positions, window)
 
 
 def paged_decode_attention(
@@ -182,6 +188,7 @@ def paged_decode_attention(
     v_pages: jnp.ndarray,
     page_tables: jnp.ndarray,  # [B, max_pages]
     positions: jnp.ndarray,  # [B] the query token's absolute position
+    window: int = 0,
 ) -> jnp.ndarray:
     """Single-token-per-sequence attention for the decode batch."""
     D = q.shape[-1]
@@ -192,6 +199,7 @@ def paged_decode_attention(
             gather_pages(k_pages, pt_b, head_dim=D),
             gather_pages(v_pages, pt_b, head_dim=D),
             pos_b[None],
+            window,
         )
         return out[0]
 
@@ -296,8 +304,15 @@ def _over_head_shards(fn, mesh, q, k_pages, v_pages, tables, positions):
     )(q, k_pages, v_pages, tables, positions)
 
 
-def dispatch_paged_decode_attention(q, k_pages, v_pages, page_tables, positions, mesh=None):
+def dispatch_paged_decode_attention(q, k_pages, v_pages, page_tables, positions, mesh=None,
+                                    window: int = 0):
     """Pallas kernel on TPU, pure-JAX reference elsewhere (same contract).
+
+    ``window`` W > 0 is a sliding-window layer: a query at position p sees the
+    keys in (p - W, p]. The tiled kernel then walks only the tiles that hold
+    such keys (a table entry behind them may be the null page: the engine has
+    given the page back) and carries a name of its own on the device's
+    operation line. The folded and page-at-a-time kernels take no window.
 
     With a tensor-parallel mesh the kernel runs under shard_map: attention is
     head-parallel, so each device handles its Hq/Hkv shard with no
@@ -307,9 +322,11 @@ def dispatch_paged_decode_attention(q, k_pages, v_pages, page_tables, positions,
     folded = k_pages.ndim == 3
     num_kv_heads = k_pages.shape[2] // D if folded else k_pages.shape[2]
     shape = f"Hq={Hq} Hkv={num_kv_heads} D={D} ps={k_pages.shape[1]}"
+    if window:
+        shape += f" window={window}"
     if not use_pallas_decode(D, num_kv_heads):
         _log_path("decode", "reference", f"{shape}: no Pallas kernel for this backend/shape")
-        return paged_decode_attention(q, k_pages, v_pages, page_tables, positions)
+        return paged_decode_attention(q, k_pages, v_pages, page_tables, positions, window)
 
     from dynamo_tpu.ops.pallas.paged_attention import (
         decode_tile_pages,
@@ -338,13 +355,20 @@ def dispatch_paged_decode_attention(q, k_pages, v_pages, page_tables, positions,
     interpret = not _on_tpu()
     tp = 1 if mesh is None else mesh.shape.get("tp", 1)
     path = f"pallas:{kernel.__name__}"
+    if window:
+        tiled = not use_folded and num_kv_heads % tp == 0 and lookahead_window(
+            k_pages.shape[1], num_kv_heads // tp, D, k_pages.dtype.itemsize)
+        if not tiled:
+            _log_path("decode", "reference", f"{shape}: only the tiled kernel takes a window")
+            return paged_decode_attention(q, k_pages, v_pages, page_tables, positions, window)
+        kernel = functools.partial(kernel, window=window)
     if not use_folded and num_kv_heads % tp == 0:
         # the geometry one head shard's kernel derives, so a server log says
         # which tile width ran
         geometry = (k_pages.shape[1], num_kv_heads // tp, D, k_pages.dtype.itemsize)
-        window = lookahead_window(*geometry)
-        path += (f" tile={decode_tile_pages(*geometry)}x{geometry[0]} window={window}"
-                 if window else " window=0:perseq")
+        ahead = lookahead_window(*geometry)
+        path += (f" tile={decode_tile_pages(*geometry)}x{geometry[0]} window={ahead}"
+                 if ahead else " window=0:perseq")
     path += " interpret" if interpret else ""
     if tp == 1:
         _log_path("decode", path, shape)
@@ -353,7 +377,7 @@ def dispatch_paged_decode_attention(q, k_pages, v_pages, page_tables, positions,
     why = _head_shard_refusal(shape, Hq, num_kv_heads, D, tp, folded)
     if why is not None:
         _log_path("decode", "reference", why)
-        return paged_decode_attention(q, k_pages, v_pages, page_tables, positions)
+        return paged_decode_attention(q, k_pages, v_pages, page_tables, positions, window)
     _log_path("decode", f"{path} shard_map tp={tp}", shape)
     return _over_head_shards(
         functools.partial(kernel, interpret=interpret),
@@ -369,7 +393,7 @@ def use_pallas_prefill(head_dim: int, chunk_len: int, block_q: int = 128) -> boo
 
 
 def dispatch_paged_prefill_attention(
-    q, k_pages, v_pages, page_table, positions, mesh=None
+    q, k_pages, v_pages, page_table, positions, mesh=None, window: int = 0
 ):
     """Chunked-prefill attention: Pallas flash kernel on TPU (context pages
     streamed HBM->VMEM in double-buffered tiles — with the next query
@@ -384,14 +408,20 @@ def dispatch_paged_prefill_attention(
     Kernel precondition (stricter than the reference): ``positions`` must be
     UNIT-STRIDE within the chunk (positions[i] = positions[0] + i), which is
     exactly what the engine's bucket-padded chunks provide. The reference
-    path only needs monotone positions."""
-    from jax.sharding import PartitionSpec as P
+    path only needs monotone positions.
+
+    ``window`` W > 0 is a sliding-window layer (see the decode dispatcher):
+    the unfolded kernel masks by (p - W, p] per query row, skips the key tiles
+    wholly behind every row's window and carries a name of its own."""
+    from dynamo_tpu.ops.pallas.prefill_attention import prefill_block_q
 
     T, Hq, D = q.shape
     folded = k_pages.ndim == 3
     num_kv_heads = k_pages.shape[2] // D if folded else k_pages.shape[2]
     tp = 1 if mesh is None else mesh.shape.get("tp", 1)
     shape = f"T={T} Hq={Hq} Hkv={num_kv_heads} D={D} ps={k_pages.shape[1]}"
+    if window:
+        shape += f" window={window}"
     if folded:
         block_q = 64
         # the folded kernel's working set is several [R, F] f32 buffers per
@@ -401,11 +431,13 @@ def dispatch_paged_prefill_attention(
         fits = F % 128 == 0 and R * F * 4 * 5 <= 12 * 1024 * 1024
         enabled = _pallas_enabled(True)
     else:
-        block_q = 128
+        block_q = prefill_block_q(Hq // tp if Hq % tp == 0 else Hq)
         fits = True
         enabled = _pallas_enabled(D % 128 == 0)
     if not enabled:
         why = f"{shape}: no Pallas kernel for this backend/shape"
+    elif window and folded:
+        why = f"{shape}: the folded kernel takes no window"
     elif T % block_q:
         why = f"{shape}: chunk is not a multiple of block_q={block_q}"
     elif not fits:
@@ -414,7 +446,7 @@ def dispatch_paged_prefill_attention(
         why = _head_shard_refusal(shape, Hq, num_kv_heads, D, tp, folded)
     if why is not None:
         _log_path("prefill", "reference", why)
-        return paged_prefill_attention(q, k_pages, v_pages, page_table, positions)
+        return paged_prefill_attention(q, k_pages, v_pages, page_table, positions, window)
 
     from dynamo_tpu.ops.pallas.prefill_attention import (
         paged_prefill_attention_pallas,
@@ -430,14 +462,18 @@ def dispatch_paged_prefill_attention(
         )
         path = "pallas:folded"
     else:
-        fn = functools.partial(paged_prefill_attention_pallas, interpret=interpret)
+        fn = functools.partial(
+            paged_prefill_attention_pallas, block_q=block_q, interpret=interpret, window=window
+        )
         # the window one head shard's kernel derives: it takes the basic
         # in-program double buffer where not one lookahead tile fits
         ps = k_pages.shape[1]
-        window = prefill_lookahead_window(
+        ahead = prefill_lookahead_window(
             ps, prefill_tile_pages(ps), num_kv_heads // tp, D, k_pages.dtype.itemsize
         )
-        path = "pallas:" + ("lookahead" if window else "basic")
+        path = "pallas:" + ("lookahead" if ahead else "basic")
+        if block_q != 128:
+            path += f" block_q={block_q}"
     if interpret:
         path += " interpret"
     if tp == 1:

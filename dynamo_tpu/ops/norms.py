@@ -11,3 +11,13 @@ def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float) -> jnp.ndarray:
     variance = jnp.mean(xf * xf, axis=-1, keepdims=True)
     normed = xf * jnp.reciprocal(jnp.sqrt(variance + eps))
     return (normed * weight.astype(jnp.float32)).astype(x.dtype)
+
+
+def layer_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float) -> jnp.ndarray:
+    """Cohere's LayerNorm over the last axis: mean-centred, divided by
+    ``sqrt(var + eps)``, times a weight, no bias; in f32, cast back."""
+    xf = x.astype(jnp.float32)
+    centred = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    variance = jnp.mean(centred * centred, axis=-1, keepdims=True)
+    normed = centred * jnp.reciprocal(jnp.sqrt(variance + eps))
+    return (normed * weight.astype(jnp.float32)).astype(x.dtype)

@@ -274,9 +274,17 @@ def _kernel_lookahead(
     tiles_per_seq: int,
     lookahead: int,
     quantized: bool = False,
+    window: int = 0,
 ):
     """Decode attention over TILES of pages, with CROSS-PROGRAM DMA
     pipelining.
+
+    ``window`` > 0 (a sliding-window layer): the query sees the last
+    ``window`` positions only, so the walk starts at the tile that holds
+    position ``length - window`` (``first_tile``) and masks what lies before
+    it in that tile; the page table's entries behind it are never read (the
+    engine may have given those pages back). With no window every index below
+    is what it was.
 
     A loop iteration handles one tile: ``tile_pages`` consecutive logical
     pages (128 context tokens at small page sizes). All of the tile's page
@@ -323,8 +331,14 @@ def _kernel_lookahead(
     def pages_of(seq_idx):
         return jnp.maximum(1, pl.cdiv(lengths_ref[seq_idx], page_size))
 
+    def first_tile(seq_idx):
+        if not window:
+            return 0
+        return jnp.maximum(0, lengths_ref[seq_idx] - window) // S
+
     n_pages = pages_of(b)
     n_tiles = pl.cdiv(n_pages, TP)
+    t0 = first_tile(b)
 
     Hq, D = q_ref.shape[1], q_ref.shape[2]
     Hkv = k_hbm.shape[2]
@@ -353,8 +367,8 @@ def _kernel_lookahead(
             )
             getattr(copy, op)()
 
-    def pre_dmas(op, parity, j, seq_idx, npg):
-        tile_dmas(op, seq_idx, j, npg, pre_pools, pre_scales,
+    def pre_dmas(op, parity, j, seq_idx, npg, base):
+        tile_dmas(op, seq_idx, base + j, npg, pre_pools, pre_scales,
                   lambda scratch: scratch.at[parity, j], sems_pre.at[parity, j])
 
     def tail_dmas(op, slot, t):
@@ -363,12 +377,13 @@ def _kernel_lookahead(
 
     def issue_pre(seq_idx, parity):
         npg = pages_of(seq_idx)
+        base = first_tile(seq_idx)
 
         def issue(j, _):
-            pre_dmas("start", parity, j, seq_idx, npg)
+            pre_dmas("start", parity, j, seq_idx, npg, base)
             return 0
 
-        jax.lax.fori_loop(0, jnp.minimum(W, pl.cdiv(npg, TP)), issue, 0)
+        jax.lax.fori_loop(0, jnp.minimum(W, pl.cdiv(npg, TP) - base), issue, 0)
 
     # program 0 has no predecessor: prefetch its own window
     @pl.when(b == 0)
@@ -381,9 +396,9 @@ def _kernel_lookahead(
         issue_pre(b + 1, 1 - par)
 
     # long-context tail: warm the in-program double buffer for tile W
-    @pl.when(W < n_tiles)
+    @pl.when(t0 + W < n_tiles)
     def _():
-        tail_dmas("start", W % 2, W)
+        tail_dmas("start", jax.lax.rem(t0 + W, 2) if window else W % 2, t0 + W)
 
     col = jax.lax.broadcasted_iota(jnp.int32, (1, 1, S), 2)
 
@@ -407,6 +422,8 @@ def _kernel_lookahead(
         if quantized:
             scores = scores * k_s[:, :S][None]  # [1, 1, S] per-row K scales
         valid = t * S + col < length
+        if window:
+            valid &= t * S + col >= length - window
         scores = jnp.where(valid, scores, _NEG_INF)
         chunk_max = jnp.max(scores, axis=-1)
         new_m = jnp.maximum(m, chunk_max)
@@ -424,9 +441,9 @@ def _kernel_lookahead(
         return new_m, new_l, acc * corr[..., None] + chunk_out
 
     def pre_body(j, carry):
-        pre_dmas("wait", par, j, b, n_pages)
+        pre_dmas("wait", par, j, b, n_pages, t0)
         return merge(
-            carry, j, k_pre.at[par, j], v_pre.at[par, j],
+            carry, t0 + j, k_pre.at[par, j], v_pre.at[par, j],
             ks_pre[par, j] if quantized else None,
             vs_pre[par, j] if quantized else None,
         )
@@ -448,8 +465,8 @@ def _kernel_lookahead(
     m0 = jnp.full((Hkv, G), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((Hkv, G), jnp.float32)
     acc0 = jnp.zeros((Hkv, G, D), jnp.float32)
-    carry = jax.lax.fori_loop(0, jnp.minimum(W, n_tiles), pre_body, (m0, l0, acc0))
-    m, l, acc = jax.lax.fori_loop(W, n_tiles, tail_body, carry)
+    carry = jax.lax.fori_loop(0, jnp.minimum(W, n_tiles - t0), pre_body, (m0, l0, acc0))
+    m, l, acc = jax.lax.fori_loop(t0 + W, n_tiles, tail_body, carry)
 
     out = acc / jnp.maximum(l, 1e-20)[..., None]
     out_ref[0] = out.reshape(Hq, D).astype(out_ref.dtype)
@@ -489,7 +506,12 @@ def lookahead_window(page_size: int, num_kv_heads: int, head_dim: int,
     return max(0, min(_LOOKAHEAD_MAX_TILES[tp == 1], budget // (2 * tile_bytes)))
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+#: the window-layer calls' name on the device's operation line (a reader
+#: tells them from the full layers' by it)
+SLIDING_DECODE_NAME = "paged_decode_attention_sliding_window"
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "window"))
 def paged_decode_attention_pallas_lookahead(
     q: jnp.ndarray,  # [B, Hq, D]
     k_pages,  # [P, ps, Hkv, D] plain or QuantizedPages
@@ -497,12 +519,15 @@ def paged_decode_attention_pallas_lookahead(
     page_tables: jnp.ndarray,  # [B, max_pages] int32
     positions: jnp.ndarray,  # [B] int32 query positions
     interpret: bool = False,
+    window: int = 0,  # sliding window in tokens (0: the whole context)
 ) -> jnp.ndarray:
     B, Hq, D = q.shape
     P, ps, Hkv, _ = k_pages.shape
     itemsize = k_pages.dtype.itemsize
     W = lookahead_window(ps, Hkv, D, itemsize)
     if W < 1:
+        if window:
+            raise ValueError("the page-at-a-time decode kernel takes no window")
         return paged_decode_attention_pallas(
             q, k_pages, v_pages, page_tables, positions, interpret=interpret
         )
@@ -538,7 +563,7 @@ def paged_decode_attention_pallas_lookahead(
         functools.partial(
             _kernel_lookahead, page_size=ps, tile_pages=TP,
             tiles_per_seq=pl.cdiv(page_tables.shape[1], TP), lookahead=W,
-            quantized=quantized,
+            quantized=quantized, window=window,
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
         grid_spec=grid_spec,
@@ -547,6 +572,7 @@ def paged_decode_attention_pallas_lookahead(
         # — pin it rather than relying on the implicit default
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name=SLIDING_DECODE_NAME if window else None,
     )
     args = (kq, vq, ks, vs) if quantized else (kq, vq)
     return kernel(page_tables.astype(jnp.int32), lengths, q, *args)

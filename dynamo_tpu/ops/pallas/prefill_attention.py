@@ -77,6 +77,26 @@ _NEG_INF = -1e30
 PREFILL_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
 
+#: the window-layer calls' name on the device's operation line
+SLIDING_PREFILL_NAME = "paged_prefill_attention_sliding_window"
+
+
+def prefill_block_q(num_q_heads: int) -> int:
+    """Query rows per grid program: 128 up to 32 query heads a device, halved
+    while rows x heads pass 32 x 128. The kernel holds the whole [block_q, Hq,
+    D] query block, its accumulator and a score tile in f32, and both the
+    stack the compiler needs and the time Mosaic takes to compile the body
+    grow faster than rows x heads: 64 heads at 128 rows took 50 of the 64 MiB
+    of PREFILL_VMEM_LIMIT_BYTES, and at 128 heads a described v5e compiled 64
+    rows in 32 s, 32 rows in 7 s, 16 rows in 3 s (PR 37; a server compiles
+    the kernel in every prefill program it warms). 128 heads get 32 rows: 512
+    rows a kv head for the MXU at 16 query heads a group."""
+    block_q = 128
+    while block_q > 8 and num_q_heads * block_q > 32 * 128:
+        block_q //= 2
+    return block_q
+
+
 def prefill_tile_pages(page_size: int) -> int:
     """Pages per context tile: 128 rows for small pages, else one page."""
     return max(1, 128 // page_size)
@@ -173,8 +193,11 @@ def _kernel(
     tile_pages: int,
     block_q: int,
     quantized: bool,
+    window: int = 0,
 ):
     """Basic (in-program double buffer) flash prefill; see module docstring.
+    ``window`` > 0: a row at position p sees keys in (p - window, p], and the
+    walk starts at the tile that holds the first row's first key.
 
     refs layout: page_table, positions (scalar prefetch) | q, k_hbm, v_hbm
     [, ks_tiles, vs_tiles] | out | k_scratch, v_scratch [, ks_scratch,
@@ -218,12 +241,12 @@ def _kernel(
     start, wait = _tile_dma_helpers(
         page_table_ref, pairs, scale_pairs, sems, TP, max_pages
     )
-    start(0, 0)
-
     # causal mask geometry, built directly in 2D [G*Bq, S] (Mosaic rejects 1D
     # vector reshapes): row i is block-row i % Bq; its query position is
     # positions[q_start] + (i % Bq)
     pos0 = positions_ref[q_start]
+    base = jnp.maximum(0, pos0 - window + 1) // S if window else 0
+    start(jax.lax.rem(base, 2) if window else 0, base)
     iota_row = jax.lax.broadcasted_iota(jnp.int32, (G * Bq, S), 0)
     iota_col = jax.lax.broadcasted_iota(jnp.int32, (G * Bq, S), 1)
     q_pos_2d = pos0 + jax.lax.rem(iota_row, Bq)  # [G*Bq, S]
@@ -256,12 +279,14 @@ def _kernel(
         # causal, and never beyond the page table (the final tile clamps its
         # page indices to max_pages - 1, which would alias earlier content)
         mask = (ctx_idx <= q_pos_2d) & (ctx_idx < max_pages * page_size)
+        if window:
+            mask &= ctx_idx > q_pos_2d - window
         return _flash_merge(carry, q, kt, vt, scale, mask, ks_row, vs_row)
 
     m0 = jnp.full((Hkv, G * Bq), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((Hkv, G * Bq), jnp.float32)
     acc0 = jnp.zeros((Hkv, G * Bq, D), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, n_tiles, body, (m0, l0, acc0))
+    m, l, acc = jax.lax.fori_loop(base, n_tiles, body, (m0, l0, acc0))
 
     out = acc / jnp.maximum(l, 1e-20)[..., None]  # [Hkv, G*Bq, D]
     out_ref[...] = (
@@ -277,10 +302,13 @@ def _kernel_lookahead(
     block_q: int,
     lookahead: int,
     quantized: bool,
+    window: int = 0,
 ):
     """Flash prefill with CROSS-PROGRAM context-tile prefetch (the decode
     lookahead kernel's scheduling applied to the query-block grid; see the
     module docstring for why the boundary exposure matters more here).
+    ``window`` > 0: as in `_kernel`; the prefetch window then holds a query
+    block's first tiles from ``block_base`` on.
 
     refs layout: page_table, positions | q, k_hbm, v_hbm [, ks_tiles,
     vs_tiles] | out | k_pre, v_pre [, ks_pre, vs_pre], k_tail, v_tail
@@ -316,7 +344,14 @@ def _kernel_lookahead(
         last_pos = positions_ref[block_idx * block_q + Bq - 1]
         return jnp.minimum(pl.cdiv(last_pos + 1, S), pl.cdiv(ctx_cap, S))
 
+    def block_base(block_idx):
+        """First tile that holds a key any row of the block may see."""
+        if not window:
+            return 0
+        return jnp.maximum(0, positions_ref[block_idx * block_q] - window + 1) // S
+
     n_tiles = block_tiles(qb)
+    base = block_base(qb)
 
     q = (
         q_ref[...]
@@ -327,11 +362,12 @@ def _kernel_lookahead(
     )
     scale = 1.0 / jnp.sqrt(jnp.float32(D))
 
-    def pre_dmas(parity, j):
-        """Every copy of window tile j: TP pages of k and v [+ scale rows]."""
+    def pre_dmas(parity, j, first):
+        """Every copy of window tile j (context tile ``first + j``): TP pages
+        of k and v [+ scale rows]."""
         copies = []
         for p in range(TP):
-            idx = jnp.minimum(j * TP + p, max_pages - 1)
+            idx = jnp.minimum((first + j) * TP + p, max_pages - 1)
             for c, (hbm, scratch) in enumerate(pre_pools):
                 copies.append(pltpu.make_async_copy(
                     hbm.at[page_table_ref[idx]],
@@ -340,7 +376,7 @@ def _kernel_lookahead(
                 ))
         for c, (hbm, scratch) in enumerate(pre_scales):
             copies.append(pltpu.make_async_copy(
-                hbm.at[j], scratch.at[parity, j], sems_pre.at[parity, j, 2 + c, 0]
+                hbm.at[first + j], scratch.at[parity, j], sems_pre.at[parity, j, 2 + c, 0]
             ))
         return copies
 
@@ -365,12 +401,13 @@ def _kernel_lookahead(
         # context pages are shared by every query block of the chunk, so the
         # NEXT block's first W tiles are known from the page table alone;
         # only how many it needs (its causal bound) depends on the block
-        npg = block_tiles(block_idx)
+        first = block_base(block_idx)
+        npg = block_tiles(block_idx) - first
         for j in range(W):  # static unroll: DMA issues only
 
             @pl.when(j < npg)
             def _(j=j):
-                for cp in pre_dmas(parity, j):
+                for cp in pre_dmas(parity, j, first):
                     cp.start()
 
     # program 0 has no predecessor: prefetch its own window
@@ -384,9 +421,9 @@ def _kernel_lookahead(
         issue_pre(qb + 1, 1 - par)
 
     # long-context tail: warm the in-program double buffer for tile W
-    @pl.when(W < n_tiles)
+    @pl.when(base + W < n_tiles)
     def _():
-        for cp in tail_dmas(W % 2, W):
+        for cp in tail_dmas(jax.lax.rem(base + W, 2) if window else W % 2, base + W):
             cp.start()
 
     pos0 = positions_ref[qb * block_q]
@@ -401,13 +438,15 @@ def _kernel_lookahead(
         vs_row = vs_tile[:, :S] if quantized else None
         ctx_idx = t * S + iota_col
         mask = (ctx_idx <= q_pos_2d) & (ctx_idx < ctx_cap)
+        if window:
+            mask &= ctx_idx > q_pos_2d - window
         return _flash_merge(carry, q, kt, vt, scale, mask, ks_row, vs_row)
 
     def pre_body(j, carry):
-        for cp in pre_dmas(par, j):
+        for cp in pre_dmas(par, j, base):
             cp.wait()
         return merge_tile(
-            carry, j, k_pre[par, j], v_pre[par, j],
+            carry, base + j, k_pre[par, j], v_pre[par, j],
             ks_pre[par, j] if quantized else None,
             vs_pre[par, j] if quantized else None,
         )
@@ -432,8 +471,8 @@ def _kernel_lookahead(
     m0 = jnp.full((Hkv, G * Bq), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((Hkv, G * Bq), jnp.float32)
     acc0 = jnp.zeros((Hkv, G * Bq, D), jnp.float32)
-    carry = jax.lax.fori_loop(0, jnp.minimum(W, n_tiles), pre_body, (m0, l0, acc0))
-    m, l, acc = jax.lax.fori_loop(W, n_tiles, tail_body, carry)
+    carry = jax.lax.fori_loop(0, jnp.minimum(W, n_tiles - base), pre_body, (m0, l0, acc0))
+    m, l, acc = jax.lax.fori_loop(base + W, n_tiles, tail_body, carry)
 
     out = acc / jnp.maximum(l, 1e-20)[..., None]
     out_ref[...] = (
@@ -581,7 +620,8 @@ def _tile_scratch(lead: tuple, tile_shape: tuple, kq, vq, ks, vs):
 
 
 def _prefill_call(body, scratch_shapes, q, page_table, positions, pools,
-                  block_q: int, interpret: bool, serial_grid: bool = False):
+                  block_q: int, interpret: bool, serial_grid: bool = False,
+                  name: str | None = None):
     """One pallas_call over the query-block grid shared by every prefill
     kernel: page table + positions scalar-prefetched, q/out blocked by
     query block, pools (and int8 scale tiles) left in HBM for manual DMA."""
@@ -608,6 +648,7 @@ def _prefill_call(body, scratch_shapes, q, page_table, positions, pools,
             vmem_limit_bytes=PREFILL_VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
+        name=name,
     )
     return kernel(
         page_table.astype(jnp.int32), positions.astype(jnp.int32), q, *pools
@@ -669,7 +710,7 @@ def paged_prefill_attention_pallas_folded(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("interpret", "block_q", "lookahead")
+    jax.jit, static_argnames=("interpret", "block_q", "lookahead", "window")
 )
 def paged_prefill_attention_pallas(
     q: jnp.ndarray,  # [T, Hq, D] bucket-padded chunk
@@ -680,6 +721,7 @@ def paged_prefill_attention_pallas(
     block_q: int = 128,
     interpret: bool = False,
     lookahead: bool = True,
+    window: int = 0,  # sliding window in tokens (0: the whole context)
 ) -> jnp.ndarray:
     """Flash prefill dispatcher: lookahead (cross-program tile prefetch)
     when the window fits its scratch budget — a trace-time choice by shape —
@@ -698,6 +740,7 @@ def paged_prefill_attention_pallas(
         tile_pages=tile_pages,
         block_q=block_q,
         quantized=ks is not None,
+        window=window,
     )
     tail_shapes, tail_sems = _tile_scratch((2,), tile, kq, vq, ks, vs)
     if W >= 1:
@@ -710,5 +753,5 @@ def paged_prefill_attention_pallas(
     pools = (kq, vq) if ks is None else (kq, vq, ks, vs)
     return _prefill_call(
         body, scratch, q, page_table, positions, pools, block_q, interpret,
-        serial_grid=W >= 1,
+        serial_grid=W >= 1, name=SLIDING_PREFILL_NAME if window else None,
     )

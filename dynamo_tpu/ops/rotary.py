@@ -1,4 +1,5 @@
-"""Rotary position embeddings (RoPE), NeoX/Llama convention."""
+"""Rotary position embeddings (RoPE): by halves (NeoX/Llama, `apply_rope`) and
+by interleaved pairs (GPT-J, `apply_rope_pairs`)."""
 
 from __future__ import annotations
 
@@ -25,6 +26,21 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndar
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     rotated = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return rotated.astype(x.dtype)
+
+
+def apply_rope_pairs(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
+    """Rotate q or k by position, by INTERLEAVED PAIRS (`rope_gptj`): lanes
+    (2i, 2i + 1) are one pair turned by frequency i, where `apply_rope` pairs
+    lane i with lane i + head_dim // 2. Same shapes as `apply_rope`."""
+    head_dim = x.shape[-1]
+    inv_freq = rope_frequencies(head_dim, theta)  # [hd/2]
+    angles = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]  # [T, hd/2]
+    cos = jnp.cos(angles)[:, None, :]  # [T, 1, hd/2]
+    sin = jnp.sin(angles)[:, None, :]
+    pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], head_dim // 2, 2)
+    x1, x2 = pairs[..., 0], pairs[..., 1]
+    rotated = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return rotated.reshape(x.shape).astype(x.dtype)
 
 
 def apply_mrope(

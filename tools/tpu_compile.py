@@ -22,6 +22,7 @@ option for this.
 from __future__ import annotations
 
 import contextlib
+import functools
 import os
 import sys
 import time
@@ -45,6 +46,9 @@ GQA_GEOMETRIES = (
     ("qwen2.5-7b", 28, 4, 128),
     ("mixtral-8x7b", 32, 8, 128),
 )
+#: command-a-plus-05-2026: 128 query heads over 8 kv heads, a window of 4096
+COMMAND_A = ("command-a-plus", 128, 8, 128)
+COMMAND_A_WINDOW = 4096
 #: DeepSeek-V2-Lite: 16 heads, kv_lora_rank 512 + rope 64, latent padded to 640
 MLA_HEADS, MLA_DC, MLA_LATENT = 16, 512, 640
 
@@ -95,14 +99,16 @@ def _pools(S, num_pages, ps, hkv, d, int8: bool, folded: bool):
     return S(shape, jnp.bfloat16)
 
 
-def _decode_case(geo, ps, int8, kernel=None):
+def _decode_case(geo, ps, int8, kernel=None, window=0, max_len=2048):
     name, hq, hkv, d = geo
-    B, num_pages, max_pages = 16, 256, 2048 // ps
+    B, num_pages, max_pages = 16, 256, max_len // ps
 
     def build(S):
         from dynamo_tpu.ops import attention
 
         fn = kernel or attention.dispatch_paged_decode_attention
+        if window:
+            fn = functools.partial(fn, window=window)
         folded = d < 128
         return fn, (
             S((B, hq, d), jnp.bfloat16),
@@ -111,13 +117,13 @@ def _decode_case(geo, ps, int8, kernel=None):
             S((B, max_pages), jnp.int32), S((B,), jnp.int32),
         )
 
-    tag = f"-{kernel.__name__}" if kernel else ""
+    tag = (f"-{kernel.__name__}" if kernel else "") + (f"-window{window}" if window else "")
     return Case(f"decode{tag}-{name}-ps{ps}-{'int8' if int8 else 'bf16'}", build)
 
 
-def _prefill_case(geo, ps, T, int8, lookahead=None):
+def _prefill_case(geo, ps, T, int8, lookahead=None, window=0, max_len=2048):
     name, hq, hkv, d = geo
-    num_pages, max_pages = 256, 2048 // ps
+    num_pages, max_pages = 256, max_len // ps
 
     def build(S):
         from dynamo_tpu.ops import attention
@@ -125,7 +131,7 @@ def _prefill_case(geo, ps, T, int8, lookahead=None):
 
         folded = d < 128
         if lookahead is None:
-            fn = attention.dispatch_paged_prefill_attention
+            fn = functools.partial(attention.dispatch_paged_prefill_attention, window=window)
         else:
             def fn(*a):
                 return paged_prefill_attention_pallas(*a, lookahead=lookahead)
@@ -137,6 +143,7 @@ def _prefill_case(geo, ps, T, int8, lookahead=None):
         )
 
     tag = "" if lookahead is None else ("-lookahead" if lookahead else "-basic")
+    tag += f"-window{window}" if window else ""
     return Case(f"prefill{tag}-{name}-ps{ps}-T{T}-{'int8' if int8 else 'bf16'}", build)
 
 
@@ -188,16 +195,17 @@ def _ssm_update_case(slots: int = 128):
     return Case(f"ssm-state-update-{slots}slots", build)
 
 
-def _grouped_matmul_case(name: str, M: int, K: int, N: int):
-    """The expert layer's grouped product at `nemotron3-super-ep4`'s widths
-    (128 held experts, latent 1024, intermediate 2688, bf16): M static rows,
-    22 a token."""
+def _grouped_matmul_case(name: str, M: int, K: int, N: int, held: int = 128):
+    """The expert layer's grouped product over `held` experts' [K, N] matrices
+    (bf16) and M static rows: `nemotron3-super-ep4` holds 128 (latent 1024,
+    intermediate 2688, 22 rows a token), `command-a-plus-ep8` 16 of
+    [4096, 4096] (32 MiB a matrix, walked in column blocks; 8 rows a token)."""
 
     def build(S):
         from dynamo_tpu.ops.pallas.grouped_matmul import grouped_matmul_pallas
 
         return grouped_matmul_pallas, (
-            S((M, K), jnp.bfloat16), S((128, K, N), jnp.bfloat16), S((128,), jnp.int32),
+            S((M, K), jnp.bfloat16), S((held, K, N), jnp.bfloat16), S((held,), jnp.int32),
         )
 
     return Case(f"moe-grouped-matmul-{name}", build)
@@ -208,6 +216,23 @@ GROUPED_MATMUL_CASES = (
     ("decode-w1", 128 * 22, 1024, 2688), ("decode-w2", 128 * 22, 2688, 1024),
     ("prefill-w1", 1024 * 22, 1024, 2688),
 )
+
+
+def window_cases() -> list[Case]:
+    """`command-a-plus-ep8`: the window and the full decode and prefill kernels
+    at 128 query / 8 kv heads of 128, page 16, tables of 16384 tokens, and the
+    grouped product at a bank of [16, 4096, 4096] (a decode step of 48 slots,
+    a prefill pack of 1024 rows)."""
+    W = COMMAND_A_WINDOW
+    return [
+        _decode_case(COMMAND_A, 16, False, window=W, max_len=16384),
+        _decode_case(COMMAND_A, 16, False, max_len=16384),
+        _prefill_case(COMMAND_A, 16, 512, False, window=W, max_len=16384),
+        _prefill_case(COMMAND_A, 16, 512, False, max_len=16384),
+        _prefill_case(COMMAND_A, 16, 64, False, window=W, max_len=16384),
+        _grouped_matmul_case("command-a-decode", 48 * 8, 4096, 4096, held=16),
+        _grouped_matmul_case("command-a-prefill", 1024 * 8, 4096, 4096, held=16),
+    ]
 
 
 def kernel_cases(full: bool) -> list[Case]:
@@ -237,7 +262,7 @@ def kernel_cases(full: bool) -> list[Case]:
         cases.append(_ssm_update_case())
         cases += [_grouped_matmul_case(*c) for c in GROUPED_MATMUL_CASES]
         cases.append(_grouped_matmul_case("prefill-w2", 1024 * 22, 2688, 1024))
-        return cases
+        return cases + window_cases()
     return [
         # decode: folded, lookahead, and the per-sequence kernel lookahead
         # falls back to; int8 at page size < 128 was refused (scale-plane
@@ -264,6 +289,9 @@ def kernel_cases(full: bool) -> list[Case]:
         # the expert layer's grouped product: whole-matrix blocks of 5.25 MiB,
         # double-buffered, need more scoped VMEM than the default 16 MiB
         *(_grouped_matmul_case(*c) for c in GROUPED_MATMUL_CASES),
+        # command-a-plus-ep8: a window in both attention kernels at 128 query
+        # heads (neither had run above 32), and a bank of [16, 4096, 4096]
+        *window_cases(),
     ]
 
 
@@ -376,6 +404,43 @@ def compile_hybrid_steps(hf_config: dict, num_pages: int, max_seqs: int, page_si
     return out
 
 
+def compile_window_steps(hf_config: dict, num_pages: int, max_seqs: int, page_size: int = 16,
+                         lanes: int = 2, bucket: int = 512, max_model_len: int = 16384,
+                         topo=None) -> dict:
+    """Compile one decode step and one packed prefill step of a Cohere2-MoE
+    model (models/cohere2_moe.py: a page table per attention layer, the window
+    kernels, the dropless dispatch over a held share) on one described chip;
+    ``hf_config`` is a config.json dict. Returns {step: compiled}."""
+    from jax.sharding import SingleDeviceSharding
+
+    from dynamo_tpu.models.cohere2_moe import Cohere2MoeConfig, Cohere2MoeModel
+
+    one = SingleDeviceSharding((topo or topology()).devices[0])
+
+    def R(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def place(tree):
+        return jax.tree.map(lambda x: R(x.shape, x.dtype), tree)
+
+    out = {}
+    with on_chip_dispatch():
+        model = Cohere2MoeModel(Cohere2MoeConfig.from_hf_config(hf_config))
+        mp = model.kv_tables * (max_model_len // page_size)
+        params = place(jax.eval_shape(model.init_params, jax.random.key(0)))
+        kv = place(jax.eval_shape(lambda: {**model.init_kv_cache(num_pages, page_size),
+                                           **model.init_state_cache(max_seqs)}))
+        out["decode"] = jax.jit(model.decode, donate_argnums=(1,)).lower(
+            params, kv, R((max_seqs,), jnp.int32), R((max_seqs,), jnp.int32),
+            R((max_seqs, mp), jnp.int32), R((max_seqs,), jnp.bool_),
+        ).compile()
+        out["prefill_packed"] = jax.jit(model.prefill_packed, donate_argnums=(1,)).lower(
+            params, kv, R((lanes, bucket), jnp.int32), R((lanes, bucket), jnp.int32),
+            R((lanes, mp), jnp.int32), R((lanes, bucket), jnp.bool_), R((lanes,), jnp.int32),
+        ).compile()
+    return out
+
+
 def _report_steps(title: str, steps: dict) -> None:
     for name, compiled in steps.items():
         m = compiled.memory_analysis()
@@ -394,6 +459,8 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--kernels", action="store_true", help="only the kernel sweep")
     ap.add_argument("--steps", action="store_true", help="only the whole-step compiles")
+    ap.add_argument("--window", action="store_true",
+                    help="only command-a-plus-ep8: its kernels and whole steps")
     ap.add_argument("--tp4-layers", type=int, default=4,
                     help="depth of the Qwen2.5-7B-width model in the tp=4 step")
     args = ap.parse_args(argv)
@@ -401,8 +468,9 @@ def main(argv=None) -> int:
     print(f"compiling for {topo.devices[0].device_kind} x{len(topo.devices)} "
           f"({TOPOLOGY}, described, not attached)", flush=True)
     failed = 0
+    bench = Path(__file__).resolve().parents[1] / "benchmark/configs"
     if not args.steps:
-        for case in kernel_cases(full=True):
+        for case in window_cases() if args.window else kernel_cases(full=True):
             t0 = time.monotonic()
             try:
                 compile_case(case, topo)
@@ -412,15 +480,19 @@ def main(argv=None) -> int:
                 verdict = "REFUSED " + " ".join(str(e).split())[:240]
             print(f"{case.name}: {verdict} ({time.monotonic() - t0:.1f}s)", flush=True)
     if not args.kernels:
+        import json
+
+        command_a = json.loads((bench / "command-a-plus-ep8.json").read_text())
+        pages = command_a["benchmark"]["server_args"][3]
+        _report_steps(f"command-a-plus-ep8 (4 layers, 16 of 128 experts) 48 slots, {pages} pages",
+                      compile_window_steps(command_a, num_pages=pages, max_seqs=48, topo=topo))
+    if not args.kernels and not args.window:
         _report_steps("tinyllama-1.1b tp=1", compile_steps(TINYLLAMA_GEOMETRY, 1, num_pages=2048, topo=topo))
         qwen = dict(QWEN25_7B_GEOMETRY, num_hidden_layers=args.tp4_layers)
         for tp in (1, 4):
             _report_steps(f"qwen2.5-7b-width L={args.tp4_layers} tp={tp}",
                           compile_steps(qwen, tp, num_pages=512, topo=topo))
-        import json
-
-        nemotron = json.loads(
-            (Path(__file__).resolve().parents[1] / "benchmark/configs/nemotron3-super-ep4.json").read_text())
+        nemotron = json.loads((bench / "nemotron3-super-ep4.json").read_text())
         _report_steps("nemotron3-super-ep4 (11 blocks, 128 of 512 experts) 128 slots",
                       compile_hybrid_steps(nemotron, num_pages=49152, max_seqs=128, topo=topo))
     print(f"{'FAILED' if failed else 'ok'}: {failed} refused", flush=True)
