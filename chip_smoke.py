@@ -966,11 +966,13 @@ def child_parity(sizes: Sizes, args) -> int:
         qwen3b = (16, 2, 128)  # the benchmark's configuration
         T, prefix = 512, 1000
         command_a, window = (128, 8, 128), 4096  # command-a-plus-ep8
+        deep_window, deep_full = 2 * window, 3 * window - T  # chunk starts at depth
     else:  # the CPU rehearsal: same code paths, interpret-mode sizes
         tiny, qwen, mixtral, bench, shard = (8, 2, 64), (4, 2, 128), (4, 2, 128), (4, 2, 128), (2, 1, 128)
         qwen3b = (4, 2, 128)
         T, prefix = 128, 200
         command_a, window = (4, 2, 128), 128
+        deep_window = deep_full = 2176  # a table past 2048 tokens: the long tile
     cases = [
         ("decode folded tinyllama ps16 bf16", lambda: decode(*tiny, 16, False)),
         ("decode folded tinyllama ps16 int8", lambda: decode(*tiny, 16, True)),
@@ -992,6 +994,11 @@ def child_parity(sizes: Sizes, args) -> int:
          lambda: window_decode(*command_a, 16, window, 4)),
         ("prefill sliding window command-a-plus 2.5W ps16 bf16",
          lambda: window_prefill(*command_a, 16, T, 2 * window + window // 2, window)),
+        # at depth, where the page table's width gives the long context tile
+        ("prefill sliding window command-a-plus start 8k ps16 bf16",
+         lambda: window_prefill(*command_a, 16, T, deep_window, window)),
+        ("prefill full command-a-plus 12k ps16 bf16",
+         lambda: prefill(*command_a, 16, T, deep_full, False)),
         ("mla decode classic ps16", lambda: mla(16)),
         ("mla prefill ps16", lambda: mla(16, T=T, prefix=prefix)),
         # Mamba-2 at the published widths (128 heads x 64 x 128), 24 slots

@@ -50,6 +50,7 @@ import numpy as np
 from dynamo_tpu.engine.config import EngineConfig
 from dynamo_tpu.engine.page_table import PageAllocator
 from dynamo_tpu.engine.sampling import MAX_EOS_IDS, SamplingParams, fold_seed
+from dynamo_tpu.ops.attention import prefill_tiles
 from dynamo_tpu.spec import make_proposer
 from dynamo_tpu.utils import events, get_logger, tracing
 from dynamo_tpu.utils.goodput import MAX_ITL_SAMPLES, RequestOutcome
@@ -1556,9 +1557,10 @@ class Scheduler:
             for _, start, end in chunks:
                 cb = self.config.bucket_for(end - start)
                 self.chunk_dispatches[cb] = self.chunk_dispatches.get(cb, 0) + 1
-            self._count_table_dispatch(self.config.table_bucket_for(
+            width = self.config.table_bucket_for(
                 max(s.page_table.shape[-1] for s, _, _ in chunks)
-            ))
+            )
+            self._count_table_dispatch(width)
             N = min(lanes_max, 1 << (len(chunks) - 1).bit_length())
             rec = self.anatomy.begin(
                 "prefill_packed", ts=t_prep,
@@ -1570,7 +1572,8 @@ class Scheduler:
                 with self.anatomy.phase(
                     rec, "dispatch", request_id=chunks[0][0].req.request_id,
                     trace_id=chunks[0][0].req.trace_id,
-                    rows=rows, lanes=N, packed=True, finals=len(finals),
+                    rows=rows, lanes=N, tile=prefill_tiles.get(width, 0),
+                    packed=True, finals=len(finals),
                     windows_ahead=self._note_windows_ahead(),
                 ) as ph:
                     result = self.runner.prefill_chunk_batch(
@@ -1706,10 +1709,9 @@ class Scheduler:
         put on the wire) while the next chunk computes."""
         rows = max(0, prompt_len - cached_len)
         self.local_prefill_rows += rows
+        width = self.config.table_bucket_for(page_table.shape[-1])
         if rows:
-            self._count_table_dispatch(
-                self.config.table_bucket_for(page_table.shape[-1])
-            )
+            self._count_table_dispatch(width)
         s = req.sampling
         first_token = None
         start = cached_len
@@ -1724,7 +1726,7 @@ class Scheduler:
         # per chunk, so device wait folds into the same phase here)
         with self.anatomy.phase(
             rec, "dispatch", request_id=req.request_id, trace_id=req.trace_id,
-            rows=rows, cached=cached_len, sync=sync,
+            rows=rows, tile=prefill_tiles.get(width, 0), cached=cached_len, sync=sync,
             windows_ahead=self._note_windows_ahead(),
         ):
             while start < prompt_len:

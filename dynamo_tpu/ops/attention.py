@@ -385,6 +385,12 @@ def dispatch_paged_decode_attention(q, k_pages, v_pages, page_tables, positions,
     )
 
 
+#: context tokens a tile of the flash prefill kernel holds, by the width of the
+#: page table it was traced for (no entry where the kernel never was): a label
+#: for the scheduler's prefill dispatch span, the choice being static in the shape
+prefill_tiles: dict[int, int] = {}
+
+
 def use_pallas_prefill(head_dim: int, chunk_len: int, block_q: int = 128) -> bool:
     """Trace-time choice of the (unfolded) Pallas prefill kernel: lane-aligned
     head_dim and block-divisible chunks (buckets are multiples of 128 in
@@ -396,10 +402,11 @@ def dispatch_paged_prefill_attention(
     q, k_pages, v_pages, page_table, positions, mesh=None, window: int = 0
 ):
     """Chunked-prefill attention: Pallas flash kernel on TPU (context pages
-    streamed HBM->VMEM in double-buffered tiles — with the next query
-    block's tiles prefetched ACROSS grid programs by default, see
-    prefill_attention.py _kernel_lookahead — online softmax, causal work
-    bound per query block), gather-based pure-JAX reference elsewhere. Int8
+    streamed HBM->VMEM in double-buffered tiles of 128 tokens, 512 under a
+    page table of more than 2048 — with the next query block's short tiles
+    prefetched ACROSS grid programs, see prefill_attention.py — online
+    softmax, causal work bound per query block), gather-based pure-JAX
+    reference elsewhere. Int8
     pools (QuantizedPages) ride the same kernels with scale rows DMA'd next
     to the pages. Under tensor parallelism the kernel runs per-head-shard
     via shard_map like the decode kernel. Folded pools (sub-128 head_dim)
@@ -468,12 +475,15 @@ def dispatch_paged_prefill_attention(
         # the window one head shard's kernel derives: it takes the basic
         # in-program double buffer where not one lookahead tile fits
         ps = k_pages.shape[1]
+        tile_pages = prefill_tile_pages(ps, page_table.shape[0])
         ahead = prefill_lookahead_window(
-            ps, prefill_tile_pages(ps), num_kv_heads // tp, D, k_pages.dtype.itemsize
+            ps, tile_pages, num_kv_heads // tp, D, k_pages.dtype.itemsize
         )
         path = "pallas:" + ("lookahead" if ahead else "basic")
         if block_q != 128:
             path += f" block_q={block_q}"
+        path += f" tile={tile_pages * ps}"
+        prefill_tiles[page_table.shape[0]] = tile_pages * ps
     if interpret:
         path += " interpret"
     if tp == 1:

@@ -13,23 +13,118 @@ query block: block b only loops over tiles up to its last query position.
 Two scheduling variants share the math:
 
   - ``_kernel`` (basic): per-program double buffer only. Every grid program
-    (query block) pays the full first-tile DMA latency at its boundary
-    before any compute can start.
-  - ``_kernel_lookahead`` (default on TPU): the decode ``_kernel_lookahead``
-    insight ported to prefill. Grid programs run serially on the core and
-    scratch PERSISTS across them; the page table and positions are
-    scalar-prefetched, so query block b issues block b+1's first
-    ``lookahead`` context-tile DMAs into the opposite parity's window while
-    it runs its own online softmax — the decode lookahead kernel's
-    cross-program pipelining (paged_attention.py). Prefill re-reads the
-    context from tile 0 for every query block, so the boundary exposure
-    repeats T/block_q times per chunk per layer. Tiles >= lookahead stream
-    through the classic in-program double buffer. What the window costs and
-    buys at head_dim 128 has not been measured on the current chip (ROADMAP).
+    (query block) pays the first-tile DMA latency at its boundary before any
+    compute can start.
+  - ``_kernel_lookahead``: the decode ``_kernel_lookahead`` insight ported to
+    prefill. Grid programs run serially on the core and scratch PERSISTS
+    across them; the page table and positions are scalar-prefetched, so query
+    block b issues block b+1's first ``lookahead`` context-tile DMAs into the
+    opposite parity's window while it runs its own online softmax. Tiles >=
+    lookahead stream through the classic in-program double buffer.
 
-``paged_prefill_attention_pallas`` takes the lookahead variant wherever one
-window tile fits its scratch budget (``prefill_lookahead_window``) and the
-basic one elsewhere: a choice by shape, with no setting.
+``paged_prefill_attention_pallas`` chooses by shape, with no setting: the
+context tile's length from the page table's width (``prefill_tile_pages``),
+then the lookahead variant wherever a window of two tiles or more fits its
+budget (``prefill_lookahead_window``) and the basic one elsewhere.
+
+Design record (PR 38; every time below is my chip run on a TPU v5e,
+tools/profile_prefill_attention.py: ONE chunk's call, 24 chained calls in one
+jit, best of 5, bf16 pools of page 16 under a shuffled page table; the static
+counts are operations a vreg in one iteration of the tile loop, from Mosaic's
+own listing for a described v5e, by the recipe in that tool's docstring).
+
+  command-a-plus-ep8: Hq 128, Hkv 8, T 512, block_q 32 (512 rows a kv head;
+  a tile of 128 tokens is two products of 268 MFLOP, 1.36 us of the MXU)
+
+  us a call                          window 4096,   full layer,     full layer,
+                                     start 8192     context 16384   context 512
+  null (the DMA stream alone), 128       434           1472              91
+  tile 128, window 4 (until PR 38)      3182          11586             400
+  tile 128, no window                   3104          11521             357
+  tile 256, window 2                    2188           7582             354
+  tile 256, no window                   2112           7515             306
+  tile 512, window 1                    1890           6158             364
+  tile 512, no window (the rule)        1809           6041             322
+  tile 1024, no window                  1870           5648             502
+
+  qwen2.5-3b: Hq 16, Hkv 2, T 512 (256 at context 256), block_q 128 (1024
+  rows a kv head), full layer
+
+  us a call, by context       256    512    1024   2048   4096   8192
+  null, 128                  26.9   32.9   37.5   48.0   69.7    112
+  tile 128, window 4         45.8   75.1    118    201    373    709
+  tile 128, no window        43.1   63.6    107    192    361    701
+  tile 256, window 2         48.3   73.8    113    186    333    629
+  tile 512, window 1         58.7   79.8    114    181    315    577
+  tile 512, no window        51.9   71.5    104    172    301    566
+  tile 1024, no window       64.7   99.5   98.5    157    281    519
+
+  - The cost is per TILE, and it is vector work, not the MXU and not the DMA
+    stream: at 128 / 8 heads a tile of 128 tokens took 6.0 us (3182 us over
+    528 tiles) where the MXU needs 1.36 and the null kernel 0.8. One
+    iteration is 9263 operations for 16 ``tpu.matmul``: the scores are 512
+    vregs, and the running max, sum and correction, kept one row a sublane,
+    are 512 vregs too, so ``maximumf``, ``subf``, ``exp``, ``mulf``, ``addf``
+    on them are 2560 operations for 4096 numbers, and each score vreg pays
+    two cross-lane reductions (``all_reduce`` 1024). A longer tile's vregs
+    are combined elementwise before the one cross-lane reduce (1024 an
+    iteration at every length): 9263 / 14865 / 26069 operations at 128 / 256
+    / 512 tokens are 9263 / 7433 / 6517 per 128 tokens (qwen2.5-3b's
+    geometry 5827 / 4923 / 4470). The chip gains more than the count: 0.57 of
+    the time on the window layer, 0.52 on the full one at 16k.
+  - The rule (``prefill_tile_pages``): 512 tokens under a page table of more
+    than 2048 tokens, 128 under a narrower one. The width is the sequence's
+    depth bucket (EngineConfig.table_buckets; the engine allocates a
+    prompt's pages at admission, so a long prompt's FIRST chunks carry the
+    wide table too: 512 wins there as well, 322 against 400 us at context
+    512). A table of 2048 tokens keeps 128: at 1024 rows a kv head and a
+    context of 256 the long tile is 13% slower (28% with a window of one),
+    and 128 pages is every prompt of three of the four benchmark cells, whose
+    programs stay what they were. From a context of 1024 on the long
+    tile wins on that geometry too (-12% at 1024, -20% at 8192).
+  - The window re-budget (``prefill_lookahead_window``): the window is
+    counted in pages, four tiles of 128 tokens, because ``issue_pre``
+    unrolls a DMA issue a page and pool statically; that leaves a 512-token
+    tile a window of one, and a window of one LOST to none at every shape
+    (1890 against 1809, 364 against 322, 577 against 566 us): the lookahead
+    kernel carries the loop body twice (window and tail), Mosaic compiled it
+    in 15.4 s against 6.7 s for the basic kernel (9.1-11.6 s for the kernel
+    of 128 with its window of four), and behind a tile of 512 tokens the first
+    fetch a program waits for is small beside its work. So the long tile
+    runs ``_kernel``: faster, and a shorter compile than before PR 38.
+  - Not 1024: 7% faster on the full layer at 16k (5648 against 6041), 3%
+    slower on the window layers, 56% slower at a context of 512, and 16 MiB
+    each of scores and probabilities at 128 heads.
+  - The window loses at the SHORT tile too (the rows "no window" at 128 and
+    256: 2% at depth, 10-15% at contexts of 512-1024, and Mosaic compiles
+    the basic kernel in 1.6-4.3 s against 4.4-11.6). PR 38 left the tile of
+    128 its window of four so that the programs of a 2048-token table stay
+    what they were; taking it away is a change to three benchmark cells'
+    prefill programs and wants its own measurement end to end.
+  - Operands stay f32, as in decode (paged_attention.py). That is not what
+    the kernel pays for: jax 0.9.0's Pallas lowering hands Mosaic no
+    precision for a product at default precision, so the MXU takes ONE bf16
+    pass over the f32 vregs (PR 26 read rms 1.5e-4 against float64 off the
+    chip). Operands cast to bf16 after the 32-bit relayout ADD some 4600
+    pack / unpack / bitcast operations a tile (13724 against 9116 in ISSUE
+    38's listing), which PR 26 measured on the chip for decode (288-305 us
+    against 248).
+  - No fast path for interior tiles (wholly inside the causal bound and the
+    window, where the mask selects everything: 7 of 9 tiles of a window
+    layer): as a ``lax.cond`` between a masked and an unmasked merge it ran
+    1.6 times SLOWER (3050 against 1890 us on the window layer, 898 against
+    374 at context 512, beside the window of one: the carry of 1536 vregs crosses the branch) and
+    compiled in 30 s against 15: ISSUE 38 asked for 8% faster and under 20%
+    more compile, so it is out.
+  - A core has 2 KiB of DMA semaphores (512). Scratch takes one a page and
+    pool (``_tile_scratch``); the scale rows of an int8 pool ride one more
+    slot a channel, not two more channels (which the described chip refused
+    at 32 pages a tile). A tile of 1024 tokens with a window does not fit.
+  - Next, by the listing at 512: the K/V relayout [tokens, Hkv, D] -> [Hkv,
+    tokens, D] is 1280 ``sublane_shuffle`` of the 6517 operations per 128
+    tokens, repeated by every query block of 32 rows over the same context
+    (more rows a program would amortize it, and ``prefill_block_q`` says what
+    that costs to compile); the mask is 900.
 
 Int8 KV (quant/kv.py QuantizedPages): the pools arrive as int8 plus a
 per-row f32 scale plane. The scale rows a chunk needs are gathered by XLA
@@ -90,34 +185,50 @@ def prefill_block_q(num_q_heads: int) -> int:
     of PREFILL_VMEM_LIMIT_BYTES, and at 128 heads a described v5e compiled 64
     rows in 32 s, 32 rows in 7 s, 16 rows in 3 s (PR 37; a server compiles
     the kernel in every prefill program it warms). 128 heads get 32 rows: 512
-    rows a kv head for the MXU at 16 query heads a group."""
+    rows a kv head for the MXU at 16 query heads a group. With the context
+    tile of 512 tokens a wide page table gets (PR 38, on the chip) 32 rows
+    compiled in 7.0 s as the basic kernel and 15.4 s with a cross-program
+    window, against 9.2 s for the tile of 128 with its window; 16 query heads
+    at 128 rows 3.3, 6.0 and 3.4 s."""
     block_q = 128
     while block_q > 8 and num_q_heads * block_q > 32 * 128:
         block_q //= 2
     return block_q
 
 
-def prefill_tile_pages(page_size: int) -> int:
-    """Pages per context tile: 128 rows for small pages, else one page."""
-    return max(1, 128 // page_size)
+#: a table of more context tokens than this is walked in long tiles
+_SHORT_TABLE_TOKENS = 2048
+_LONG_TILE_TOKENS = 512
 
 
-def _unpack_pools(k_pages, v_pages, page_table):
-    """(k, v, k_scale tiles | None, v_scale tiles | None, tile_pages) from
-    plain or QuantizedPages pools; a context tile is ``tile_pages`` pages
-    (``prefill_tile_pages``). Scale tiles are ``gather_scale_rows`` over
-    the page table (edge-padded to whole tiles: the kernels clamp their page
-    indices the same way and mask what lies beyond the table): row t is
-    context tile t's [1, S] scale row."""
-    tile_pages = prefill_tile_pages(k_pages.shape[1])
+def prefill_tile_pages(page_size: int, max_pages: int = 0) -> int:
+    """Pages per context tile of the head_dim-128 flash kernels, from what a
+    call can see: 128 tokens (one page where a page holds more) under a page
+    table of up to 2048 tokens, 512 tokens under a wider one (the design
+    record in the module docstring has the times). The table's width is the
+    depth bucket of the sequence (EngineConfig.table_buckets), so a deep
+    context pays the per-tile vector work a quarter as often and a short one
+    keeps the tile that wastes least beyond its causal bound. With no width
+    given (the folded kernel): 128 tokens."""
+    short = max(1, 128 // page_size)
+    if max_pages * page_size > _SHORT_TABLE_TOKENS:
+        return max(short, _LONG_TILE_TOKENS // page_size)
+    return short
+
+
+def _unpack_pools(k_pages, v_pages, page_table, tile_pages: int):
+    """(k, v, k_scale tiles | None, v_scale tiles | None) from plain or
+    QuantizedPages pools; a context tile is ``tile_pages`` pages. Scale tiles
+    are ``gather_scale_rows`` over the page table (edge-padded to whole
+    tiles: the kernels clamp their page indices the same way and mask what
+    lies beyond the table): row t is context tile t's [1, S] scale row."""
     if not isinstance(k_pages, QuantizedPages):
-        return k_pages, v_pages, None, None, tile_pages
+        return k_pages, v_pages, None, None
     table = jnp.pad(page_table, (0, -page_table.shape[0] % tile_pages), mode="edge")
     return (
         k_pages.q, v_pages.q,
         gather_scale_rows(k_pages.s, table, tile_pages),
         gather_scale_rows(v_pages.s, table, tile_pages),
-        tile_pages,
     )
 
 
@@ -127,8 +238,8 @@ def _tile_dma_helpers(page_table_ref, page_pairs, scale_pairs, sems,
     kernels: ``page_pairs`` is [(hbm_pool, scratch)] for k/v, each scratch
     indexed ``[buf, p]``; ``scale_pairs`` (int8 pools only) is [(scale tiles,
     scratch)], one [1, S] row per tile, scratch indexed ``[buf]``. ``sems``
-    is ``[2, C, TP]``: channel c < 2 page p for the pages, channel 2 + c
-    slot 0 for the scale rows. Returns (start, wait), each taking (buf,
+    is ``[2, 2, TP (+ 1)]``: channel c (k, v) slot p for page p, slot TP for
+    the channel's scale row. Returns (start, wait), each taking (buf,
     tile). The final tile clamps page indices to max_pages - 1 (aliased
     content is masked by the callers' ctx-bound check)."""
 
@@ -146,7 +257,7 @@ def _tile_dma_helpers(page_table_ref, page_pairs, scale_pairs, sems,
         for c, (hbm, scratch) in enumerate(scale_pairs):
             copies.append(
                 pltpu.make_async_copy(
-                    hbm.at[tile], scratch.at[buf], sems.at[buf, 2 + c, 0]
+                    hbm.at[tile], scratch.at[buf], sems.at[buf, c, tile_pages]
                 )
             )
         return copies
@@ -312,8 +423,8 @@ def _kernel_lookahead(
 
     refs layout: page_table, positions | q, k_hbm, v_hbm [, ks_tiles,
     vs_tiles] | out | k_pre, v_pre [, ks_pre, vs_pre], k_tail, v_tail
-    [, ks_tail, vs_tail], sems_pre, sems_tail. Semaphore channel c < 2 page p
-    carries the pages, channel 2 + c slot 0 the tile's scale row."""
+    [, ks_tail, vs_tail], sems_pre, sems_tail. Semaphore channel c (k, v)
+    slot p carries page p, slot TP the tile's scale row."""
     if quantized:
         (page_table_ref, positions_ref, q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm,
          out_ref, k_pre, v_pre, ks_pre, vs_pre, k_tail, v_tail, ks_tail,
@@ -376,7 +487,7 @@ def _kernel_lookahead(
                 ))
         for c, (hbm, scratch) in enumerate(pre_scales):
             copies.append(pltpu.make_async_copy(
-                hbm.at[first + j], scratch.at[parity, j], sems_pre.at[parity, j, 2 + c, 0]
+                hbm.at[first + j], scratch.at[parity, j], sems_pre.at[parity, j, c, TP]
             ))
         return copies
 
@@ -393,7 +504,7 @@ def _kernel_lookahead(
                 ))
         for c, (hbm, scratch) in enumerate(tail_scales):
             copies.append(pltpu.make_async_copy(
-                hbm.at[tile], scratch.at[slot], sems_tail.at[slot, 2 + c, 0]
+                hbm.at[tile], scratch.at[slot], sems_tail.at[slot, c, TP]
             ))
         return copies
 
@@ -608,14 +719,16 @@ def _kernel_folded(
 def _tile_scratch(lead: tuple, tile_shape: tuple, kq, vq, ks, vs):
     """VMEM scratch + DMA semaphores for context tiles buffered ``lead``
     deep: k/v tiles ``[*lead, *tile_shape]`` [, int8 scale rows ``[*lead, 1,
-    S]``], then sems ``[*lead, C, TP]`` (the layout _tile_dma_helpers and
-    _kernel_lookahead index)."""
+    S]``], then sems ``[*lead, 2, TP (+ 1 for the scale row)]`` (the layout
+    _tile_dma_helpers and _kernel_lookahead index; a core has 2 KiB of DMA
+    semaphores, 512 of them, and a long tile's 32 pages a channel, window and
+    tail, take 256)."""
     shapes = [pltpu.VMEM((*lead, *tile_shape), kq.dtype),
               pltpu.VMEM((*lead, *tile_shape), vq.dtype)]
     if ks is not None:
         shapes += [pltpu.VMEM((*lead, 1, ks.shape[-1]), jnp.float32),
                    pltpu.VMEM((*lead, 1, vs.shape[-1]), jnp.float32)]
-    sems = pltpu.SemaphoreType.DMA((*lead, 2 if ks is None else 4, tile_shape[0]))
+    sems = pltpu.SemaphoreType.DMA((*lead, 2, tile_shape[0] + (ks is not None)))
     return shapes, sems
 
 
@@ -665,10 +778,18 @@ def prefill_lookahead_window(page_size: int, tile_pages: int,
                              itemsize: int = 2) -> int:
     """Prefetch window W in context TILES that fits the scratch budget
     (0 = lookahead not applicable at this geometry). Scratch = 2 parities x
-    W tiles x (k+v) + the 2-slot tail; int8 scale tiles are noise."""
+    W tiles x (k+v) + the 2-slot tail; int8 scale tiles are noise. The window
+    is budgeted in PAGES, four tiles of 128 tokens (`issue_pre` unrolls one
+    DMA issue a page and pool statically, twice a program), and a tile that
+    takes the whole budget gets none: a window of one tile of 512 tokens
+    timed 3-15% slower than the basic kernel and compiled twice as long
+    (module docstring)."""
     tile_bytes = 2 * tile_pages * page_size * num_kv_heads * head_dim * itemsize
     budget = _PREFILL_LOOKAHEAD_SCRATCH_BYTES - 2 * tile_bytes  # tail buffers
-    return max(0, min(4, budget // (2 * tile_bytes)))
+    tiles = 4 * prefill_tile_pages(page_size) // tile_pages
+    if tiles < 2:
+        return 0
+    return max(0, min(tiles, budget // (2 * tile_bytes)))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_q"))
@@ -690,7 +811,8 @@ def paged_prefill_attention_pallas_folded(
         else:
             k_pages = k_pages.reshape(P, ps, Hkv * D)
             v_pages = v_pages.reshape(P, ps, Hkv * D)
-    kq, vq, ks, vs, tile_pages = _unpack_pools(k_pages, v_pages, page_table)
+    tile_pages = prefill_tile_pages(k_pages.shape[1])
+    kq, vq, ks, vs = _unpack_pools(k_pages, v_pages, page_table, tile_pages)
     _, ps, F = kq.shape
     shapes, sems = _tile_scratch((2,), (tile_pages, ps, F), kq, vq, ks, vs)
     body = functools.partial(
@@ -710,7 +832,8 @@ def paged_prefill_attention_pallas_folded(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("interpret", "block_q", "lookahead", "window")
+    jax.jit,
+    static_argnames=("interpret", "block_q", "lookahead", "window", "tile_pages"),
 )
 def paged_prefill_attention_pallas(
     q: jnp.ndarray,  # [T, Hq, D] bucket-padded chunk
@@ -722,11 +845,15 @@ def paged_prefill_attention_pallas(
     interpret: bool = False,
     lookahead: bool = True,
     window: int = 0,  # sliding window in tokens (0: the whole context)
+    tile_pages: int | None = None,  # None: prefill_tile_pages (tools time others)
 ) -> jnp.ndarray:
     """Flash prefill dispatcher: lookahead (cross-program tile prefetch)
     when the window fits its scratch budget — a trace-time choice by shape —
-    else the basic in-program double buffer."""
-    kq, vq, ks, vs, tile_pages = _unpack_pools(k_pages, v_pages, page_table)
+    else the basic in-program double buffer. The context tile's length
+    follows the page table's width (``prefill_tile_pages``)."""
+    if tile_pages is None:
+        tile_pages = prefill_tile_pages(k_pages.shape[1], page_table.shape[0])
+    kq, vq, ks, vs = _unpack_pools(k_pages, v_pages, page_table, tile_pages)
     _, ps, Hkv, D = kq.shape
     tile = (tile_pages, ps, Hkv, D)
     W = (

@@ -33,9 +33,9 @@ def _decode(hq, hkv, d, ps, **pool):
     return (S((4, hq, d), jnp.bfloat16), k, k, S((4, 8), jnp.int32), S((4,), jnp.int32))
 
 
-def _prefill(T, hq, hkv, d, ps, **pool):
+def _prefill(T, hq, hkv, d, ps, width=8, **pool):
     k = _pool(64, ps, hkv, d, **pool)
-    return (S((T, hq, d), jnp.bfloat16), k, k, S((8,), jnp.int32), S((T,), jnp.int32))
+    return (S((T, hq, d), jnp.bfloat16), k, k, S((width,), jnp.int32), S((T,), jnp.int32))
 
 
 LOOKAHEAD = "pallas:paged_decode_attention_pallas_lookahead"
@@ -60,7 +60,17 @@ TABLE = [
     pytest.param("decode", _decode(16, 8, 128, 16), 4,
         [LOOKAHEAD, "tile=8x16", "shard_map tp=4"], "", id="decode-tp4"),
     pytest.param("prefill", _prefill(256, 16, 2, 128, 16), 1,
-        ["pallas:lookahead"], "T=256", id="prefill-t256"),
+        ["pallas:lookahead", "tile=128"], "T=256", id="prefill-t256"),
+    # the ladder of table widths: 128 pages of 16 keep the tile of 128 tokens
+    # and its cross-program window, a wider table takes 512 and the basic kernel
+    pytest.param("prefill", _prefill(512, 16, 2, 128, 16, width=128), 1,
+        ["pallas:lookahead", "tile=128"], "", id="prefill-table-128-pages"),
+    pytest.param("prefill", _prefill(512, 16, 2, 128, 16, width=256), 1,
+        ["pallas:basic", "tile=512"], "", id="prefill-table-256-pages"),
+    pytest.param("prefill", _prefill(512, 128, 8, 128, 16, width=1024), 1,
+        ["pallas:basic", "block_q=32", "tile=512"], "", id="prefill-table-1024-pages-128-heads"),
+    pytest.param("prefill", _prefill(512, 28, 4, 128, 16, width=512, int8=True), 1,
+        ["pallas:basic", "tile=512"], "", id="prefill-table-512-pages-int8"),
     pytest.param("prefill", _prefill(128, 28, 4, 128, 16, int8=True), 1,
         ["pallas:lookahead"], "", id="prefill-int8"),
     pytest.param("prefill", _prefill(64, 16, 2, 128, 16), 1,
@@ -72,6 +82,8 @@ TABLE = [
         ["pallas:basic"], "", id="prefill-window-0"),
     pytest.param("prefill", _prefill(128, 16, 8, 128, 16), 4,
         ["pallas:lookahead", "shard_map tp=4"], "", id="prefill-tp4"),
+    pytest.param("prefill", _prefill(128, 16, 8, 128, 16, width=512), 4,
+        ["pallas:basic", "tile=512", "shard_map tp=4"], "", id="prefill-tp4-table-512-pages"),
 ]
 
 
@@ -100,3 +112,15 @@ def test_dispatch_chooses_from_shapes_alone(monkeypatch, op, args, tp, path_has,
     for piece in path_has:
         assert piece in path, (path, why)
     assert why_has in why, (path, why)
+
+
+def test_prefill_dispatch_labels_the_tile_by_table_width(monkeypatch):
+    """The scheduler's prefill dispatch span reads the tile from
+    `attention.prefill_tiles`, keyed by the width it dispatches."""
+    monkeypatch.delenv("DYNTPU_PALLAS", raising=False)
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(attention, "prefill_tiles", {})
+    for width in (128, 1024):
+        jax.eval_shape(attention.dispatch_paged_prefill_attention,
+                       *_prefill(512, 16, 2, 128, 16, width=width))
+    assert attention.prefill_tiles == {128: 128, 1024: 512}

@@ -450,6 +450,8 @@ def test_windows_ahead_on_the_span_and_in_metrics(monkeypatch):
         tracing.clear()
     assert len(spans) >= 6
     assert all("windows_ahead" in e["args"] and "rows" in e["args"] for e in spans)
+    # the flash kernel's context tile, a label: 0 where the gather reference runs
+    assert all(e["args"]["tile"] == 0 for e in spans)
     assert [e["args"]["windows_ahead"] for e in spans][0] == 0  # an empty queue
     st = sched.stage
     assert st.prefill_calls == len(spans)

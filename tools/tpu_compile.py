@@ -46,6 +46,8 @@ GQA_GEOMETRIES = (
     ("qwen2.5-7b", 28, 4, 128),
     ("mixtral-8x7b", 32, 8, 128),
 )
+#: qwen2.5-3b, the benchmark's dense configuration
+QWEN25_3B = ("qwen2.5-3b", 16, 2, 128)
 #: command-a-plus-05-2026: 128 query heads over 8 kv heads, a window of 4096
 COMMAND_A = ("command-a-plus", 128, 8, 128)
 COMMAND_A_WINDOW = 4096
@@ -144,6 +146,7 @@ def _prefill_case(geo, ps, T, int8, lookahead=None, window=0, max_len=2048):
 
     tag = "" if lookahead is None else ("-lookahead" if lookahead else "-basic")
     tag += f"-window{window}" if window else ""
+    tag += f"-table{max_len}" if max_len != 2048 else ""
     return Case(f"prefill{tag}-{name}-ps{ps}-T{T}-{'int8' if int8 else 'bf16'}", build)
 
 
@@ -220,9 +223,11 @@ GROUPED_MATMUL_CASES = (
 
 def window_cases() -> list[Case]:
     """`command-a-plus-ep8`: the window and the full decode and prefill kernels
-    at 128 query / 8 kv heads of 128, page 16, tables of 16384 tokens, and the
-    grouped product at a bank of [16, 4096, 4096] (a decode step of 48 slots,
-    a prefill pack of 1024 rows)."""
+    at 128 query / 8 kv heads of 128, page 16, tables of 16384 tokens (1024
+    pages: the prefill kernels walk them in tiles of 512 tokens, 8 MiB of
+    scores and as much of probabilities a program), and the grouped product
+    at a bank of [16, 4096, 4096] (a decode step of 48 slots, a prefill pack
+    of 1024 rows)."""
     W = COMMAND_A_WINDOW
     return [
         _decode_case(COMMAND_A, 16, False, window=W, max_len=16384),
@@ -253,6 +258,8 @@ def kernel_cases(full: bool) -> list[Case]:
                     cases.append(_decode_case(geo, ps, int8))
                     buckets = (64, 128, 256, 512, 1024) if geo[3] < 128 else (128, 256, 512, 1024)
                     cases += [_prefill_case(geo, ps, T, int8) for T in buckets]
+        cases += [_prefill_case(QWEN25_3B, 16, 512, False, max_len=8192),
+                  _prefill_case(mixtral, 16, 512, True, max_len=8192)]
         cases += [_prefill_case(mixtral, 16, 512, False, lookahead=False),
                   _decode_case(qwen, 16, False, kernel=paged_decode_attention_pallas),
                   _decode_case(qwen, 16, True, kernel=paged_decode_attention_pallas)]
@@ -277,6 +284,11 @@ def kernel_cases(full: bool) -> list[Case]:
         # (scoped VMEM 18-23 MiB against the 16 MiB default)
         _prefill_case(bench, 128, 512, False),
         _prefill_case(qwen, 16, 1024, False),
+        # a table past 2048 tokens: the long context tile at 1024 rows a kv
+        # head (qwen2.5-3b's 128 query rows a program, 8 heads a group), and
+        # on an int8 pool (a [1, 512] scale row a tile)
+        _prefill_case(QWEN25_3B, 16, 512, False, max_len=8192),
+        _prefill_case(mixtral, 16, 512, True, max_len=8192),
         _prefill_case(mixtral, 16, 512, True),
         # the basic variant was refused at 32q/8kv too
         _prefill_case(mixtral, 16, 512, False, lookahead=False),
