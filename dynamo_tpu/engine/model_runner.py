@@ -347,7 +347,10 @@ class ModelRunner:
             method's is its function's), so that is what is set; the name is
             fixed text, since the persistent compile cache keys on it. (A
             wrapper function with the name cost 1.7 s more at the first call
-            of every prefill variant on the chip: PERF.md, PR 25.)"""
+            of every prefill variant on the chip: PERF.md, PR 25.) The three
+            step programs are decorated `jax.named_scope("step")`: whatever
+            they do outside the model's and the sampler's own scopes is the
+            part `step` of a trace (benchmark/trace_parts.py PARTS)."""
             fn = getattr(impl, "__func__", impl)
             fn.__name__ = fn.__qualname__ = f"dynamo_{label}"
             return monitored_jit(
@@ -470,6 +473,7 @@ class ModelRunner:
             rope_deltas=rope_deltas, **lkw,
         )
 
+    @jax.named_scope("step")
     def _prefill_impl(self, params, kv, slot_state, ints, flts, key, embeds=None, emask=None, rope_pos=None, lora=None, want_lp=False, want_pen=False, want_seed=False, want_eos_mask=False, mp=None):
         """ints [bucket + mp + 6 + MAX_EOS_IDS] = token buf, page
         table, (start_pos, n_real, top_k, slot, seed, lora_slot), then the
@@ -555,6 +559,7 @@ class ModelRunner:
             slot_state = dict(slot_state, counts=counts, seen=seen)
         return tok, lp, slot_state
 
+    @jax.named_scope("step")
     def _prefill_packed_impl(self, params, kv, slot_state, ints, flts, key, lora=None, want_lp=False, want_pen=False, want_seed=False, want_eos_mask=False, mp=None):
         """Cross-request packed prefill: ints [N, bucket + mp + 6 +
         MAX_EOS_IDS] — N lanes of the SAME per-lane row layout as
@@ -763,6 +768,7 @@ class ModelRunner:
         )
         return tok, lp, kv, slot_state
 
+    @jax.named_scope("step")
     def _decode_window_impl(self, params, kv, slot_state, ints, flts, key, lora=None, num_steps=1, want_lp=False, want_pen=False, want_seed=False, want_eos_mask=False):
         """num_steps fused decode steps; the sampled-token feedback loop starts
         from the device-resident ``slot_state["tokens"]`` buffer, so the host can

@@ -1492,87 +1492,93 @@ class Scheduler:
                         break
                 self.stage.prefill_stalls += 1
                 outputs.extend(self._reconcile(block=True))
-            t_prep = time.monotonic()
-            pending = sorted(
-                (s for s in self.slots
-                 if s is not None and not s.finished and s.prefill_pos is not None
-                 and s.fetch is None),  # FETCHING_KV: hold until the pull resolves
-                key=lambda s: s.admitted_order,
-            )
-            if not pending:
-                return count
-            # greedy bucket-aware packing in admission order: grow the lane
-            # set while every taken lane still fits the (possibly enlarged)
-            # bucket's row budget — one long head chunk goes alone, short
-            # chunks pack together. Each lane's chunk length is depth-aware:
-            # chunk_len_for shrinks it as that sequence's prefill advances
-            # into a long prompt, keeping per-chunk latency roughly flat —
-            # and backlog-aware: a deep pending queue promotes the bucket so
-            # the burst takes fewer, larger dispatches.
-            backlog_rows = sum(s.prompt_len - s.prefill_pos for s in pending)
-            chunks = []
-            bucket = 0
-            for s in pending:
-                limit = self.config.chunk_len_for(
-                    s.prefill_pos, backlog_rows=backlog_rows
+            # host_prep is a span of its own (one clock pair, no record yet):
+            # a device gap under it has its name in a trace
+            with tracing.span("engine.prefill_packed.host_prep") as prep:
+                pending = sorted(
+                    (s for s in self.slots
+                     if s is not None and not s.finished and s.prefill_pos is not None
+                     and s.fetch is None),  # FETCHING_KV: hold until the pull resolves
+                    key=lambda s: s.admitted_order,
                 )
-                end = min(s.prefill_pos + limit, s.prompt_len)
-                cand = self.config.bucket_for(max(bucket, end - s.prefill_pos))
-                if chunks and len(chunks) + 1 > self.config.lanes_for(cand):
-                    break
-                if self.grouped and not self._chunk_pages(s, end, outputs):
-                    continue
-                chunks.append((s, s.prefill_pos, end))
-                bucket = cand
-            # a later lane's page pressure may have preempted an earlier one
-            chunks = [c for c in chunks if self.slots[c[0].slot] is c[0] and not c[0].finished]
-            if not chunks:
-                return count
-            lanes_max = self.config.lanes_for(bucket)
-            # lone chunks ride the packed trace at N=1 too: measured 33%
-            # faster than the per-request trace for identical work (r5
-            # on-chip, 512-row call: 11.3 vs 16.8 ms). N rounds up to a
-            # power of two so partial packs compile at most log2(lanes_max)
-            # executables per bucket, padding <= 2x on the rare odd sizes.
-            lanes = []
-            finals = []  # (seq, lane_idx)
-            want_lp = False
-            for j, (seq, start, end) in enumerate(chunks):
-                is_final = end == seq.prompt_len
-                lanes.append((
-                    np.asarray(seq.req.token_ids[start:end], np.int32),
-                    start,
-                    seq.page_table,
-                    seq.slot,
-                    seq.req.sampling,
-                    () if seq.req.sampling.ignore_eos else seq.req.eos_token_ids,
-                    is_final,
-                    seq.lora_slot,
-                ))
-                if is_final:
-                    finals.append((seq, j))
-                    want_lp = want_lp or seq.req.logprobs is not None
-            rows = sum(end - start for _, start, end in chunks)
-            self.local_prefill_rows += rows
-            for _, start, end in chunks:
-                cb = self.config.bucket_for(end - start)
-                self.chunk_dispatches[cb] = self.chunk_dispatches.get(cb, 0) + 1
-            width = self.config.table_bucket_for(
-                max(s.page_table.shape[-1] for s, _, _ in chunks)
-            )
-            self._count_table_dispatch(width)
-            N = min(lanes_max, 1 << (len(chunks) - 1).bit_length())
-            rec = self.anatomy.begin(
-                "prefill_packed", ts=t_prep,
-                # cost split: each sequence pays for its own rows in the pack
-                bill=[self._bill(s.req, end - start) for s, start, end in chunks],
-            )
-            self.anatomy.add_phase(rec, "host_prep", time.monotonic() - t_prep)
+                if not pending:
+                    return count
+                # greedy bucket-aware packing in admission order: grow the lane
+                # set while every taken lane still fits the (possibly enlarged)
+                # bucket's row budget — one long head chunk goes alone, short
+                # chunks pack together. Each lane's chunk length is depth-aware:
+                # chunk_len_for shrinks it as that sequence's prefill advances
+                # into a long prompt, keeping per-chunk latency roughly flat —
+                # and backlog-aware: a deep pending queue promotes the bucket so
+                # the burst takes fewer, larger dispatches.
+                backlog_rows = sum(s.prompt_len - s.prefill_pos for s in pending)
+                chunks = []
+                bucket = 0
+                for s in pending:
+                    limit = self.config.chunk_len_for(
+                        s.prefill_pos, backlog_rows=backlog_rows
+                    )
+                    end = min(s.prefill_pos + limit, s.prompt_len)
+                    cand = self.config.bucket_for(max(bucket, end - s.prefill_pos))
+                    if chunks and len(chunks) + 1 > self.config.lanes_for(cand):
+                        break
+                    if self.grouped and not self._chunk_pages(s, end, outputs):
+                        continue
+                    chunks.append((s, s.prefill_pos, end))
+                    bucket = cand
+                # a later lane's page pressure may have preempted an earlier one
+                chunks = [c for c in chunks if self.slots[c[0].slot] is c[0] and not c[0].finished]
+                if not chunks:
+                    return count
+                lanes_max = self.config.lanes_for(bucket)
+                # lone chunks ride the packed trace at N=1 too: measured 33%
+                # faster than the per-request trace for identical work (r5
+                # on-chip, 512-row call: 11.3 vs 16.8 ms). N rounds up to a
+                # power of two so partial packs compile at most log2(lanes_max)
+                # executables per bucket, padding <= 2x on the rare odd sizes.
+                lanes = []
+                finals = []  # (seq, lane_idx)
+                want_lp = False
+                for j, (seq, start, end) in enumerate(chunks):
+                    is_final = end == seq.prompt_len
+                    lanes.append((
+                        np.asarray(seq.req.token_ids[start:end], np.int32),
+                        start,
+                        seq.page_table,
+                        seq.slot,
+                        seq.req.sampling,
+                        () if seq.req.sampling.ignore_eos else seq.req.eos_token_ids,
+                        is_final,
+                        seq.lora_slot,
+                    ))
+                    if is_final:
+                        finals.append((seq, j))
+                        want_lp = want_lp or seq.req.logprobs is not None
+                rows = sum(end - start for _, start, end in chunks)
+                self.local_prefill_rows += rows
+                for _, start, end in chunks:
+                    cb = self.config.bucket_for(end - start)
+                    self.chunk_dispatches[cb] = self.chunk_dispatches.get(cb, 0) + 1
+                width = self.config.table_bucket_for(
+                    max(s.page_table.shape[-1] for s, _, _ in chunks)
+                )
+                self._count_table_dispatch(width)
+                N = min(lanes_max, 1 << (len(chunks) - 1).bit_length())
+                rec = self.anatomy.begin(
+                    "prefill_packed", ts=prep.t0,
+                    # cost split: each sequence pays for its own rows in the pack
+                    bill=[self._bill(s.req, end - start) for s, start, end in chunks],
+                )
+            self.anatomy.add_phase(rec, "host_prep", prep.dt)
             try:
                 with self.anatomy.phase(
                     rec, "dispatch", request_id=chunks[0][0].req.request_id,
                     trace_id=chunks[0][0].req.trace_id,
                     rows=rows, lanes=N, tile=prefill_tiles.get(width, 0),
+                    # what the pack holds: the rows the program computes, and
+                    # the context already in the cache under its chunks
+                    padded=N * self.config.bucket_for(max(end - start for _, start, end in chunks)),
+                    ctx=sum(start for _, start, _ in chunks),
                     packed=True, finals=len(finals),
                     windows_ahead=self._note_windows_ahead(),
                 ) as ph:
@@ -1714,25 +1720,33 @@ class Scheduler:
             self._count_table_dispatch(width)
         s = req.sampling
         first_token = None
-        start = cached_len
-        t0 = time.monotonic()
-        rec = self._last_prefill_rec = self.anatomy.begin(
-            "prefill_chunk", ts=t0, bill=[self._bill(req, max(1, rows))],
-        )
-        if prep:
-            self._prep_prefill(req, slot, prompt_len, cached_len=cached_len)
-        self.anatomy.add_phase(rec, "host_prep", time.monotonic() - t0)
+        with tracing.span("engine.prefill_chunk.host_prep") as host_prep:
+            rec = self._last_prefill_rec = self.anatomy.begin(
+                "prefill_chunk", ts=host_prep.t0, bill=[self._bill(req, max(1, rows))],
+            )
+            if prep:
+                self._prep_prefill(req, slot, prompt_len, cached_len=cached_len)
+            # depth-aware chunk sizing: shrink the chunk as the context
+            # deepens so per-chunk latency stays roughly flat at depth
+            chunks = []
+            start = cached_len
+            while start < prompt_len:
+                end = min(start + self.config.chunk_len_for(start), prompt_len)
+                chunks.append((start, end))
+                start = end
+        self.anatomy.add_phase(rec, "host_prep", host_prep.dt)
         # everything past host_prep is dispatch time (sync=True chains block
         # per chunk, so device wait folds into the same phase here)
         with self.anatomy.phase(
             rec, "dispatch", request_id=req.request_id, trace_id=req.trace_id,
             rows=rows, tile=prefill_tiles.get(width, 0), cached=cached_len, sync=sync,
+            # what the chunks hold: the rows their programs compute, and the
+            # context already in the cache when each starts
+            padded=sum(self.config.bucket_for(end - start) for start, end in chunks),
+            ctx=sum(start for start, _ in chunks),
             windows_ahead=self._note_windows_ahead(),
         ):
-            while start < prompt_len:
-                # depth-aware chunk sizing: shrink the chunk as the context
-                # deepens so per-chunk latency stays roughly flat at depth
-                end = min(start + self.config.chunk_len_for(start), prompt_len)
+            for start, end in chunks:
                 is_last = end == prompt_len
                 cb = self.config.bucket_for(end - start)
                 self.chunk_dispatches[cb] = self.chunk_dispatches.get(cb, 0) + 1
@@ -1761,7 +1775,6 @@ class Scheduler:
                     first_token = tok
                 if on_chunk is not None:
                     on_chunk(start, end)
-                start = end
         self.stage.prefill_rows += rows
         self.anatomy.note_steps(rec, tokens=rows, participants=1)
         self.anatomy.note_prefill_floor(rec, rows)
@@ -2268,92 +2281,92 @@ class Scheduler:
         # host-prep timing starts AFTER the capacity pass: a pressure drain
         # up there blocks in _reconcile, and that wait is already attributed
         # as device_wait on the drained entries' own records
-        t_prep = time.monotonic()
-        participants = []
-        for seq in self.slots:
-            if seq is None or seq.finished:
-                continue
-            steps = self._plan_steps(seq, K)
-            if steps <= 0:
-                continue
-            cap = self.allocator._seqs[seq.req.request_id].num_pages * self.config.page_size
-            steps = min(steps, cap - seq.next_fed_pos)
-            if steps <= 0:
-                continue
-            participants.append((seq, steps))
-        if not participants:
-            return False
+        with tracing.span("engine.decode_window.host_prep") as prep:
+            participants = []
+            for seq in self.slots:
+                if seq is None or seq.finished:
+                    continue
+                steps = self._plan_steps(seq, K)
+                if steps <= 0:
+                    continue
+                cap = self.allocator._seqs[seq.req.request_id].num_pages * self.config.page_size
+                steps = min(steps, cap - seq.next_fed_pos)
+                if steps <= 0:
+                    continue
+                participants.append((seq, steps))
+            if not participants:
+                return False
 
-        B = self.config.max_seqs
-        # per-window table width: the widest participant's ladder rung —
-        # short-sequence batches keep their narrow H2D + gather, and only
-        # windows containing a deep sequence dispatch the wide executable
-        page_tables, W = self._batch_tables(B, [seq for seq, _ in participants])
-        if self.grouped:
-            held = sum(np.count_nonzero(seq.page_table, axis=1) for seq, _ in participants)
-            self.decode_group_pages = {
-                g.name: int(held[list(g.tables)].sum()) for g in self.allocator.groups
-            }
-        positions = np.zeros(B, np.int32)
-        active = np.zeros(B, bool)
-        limits = np.zeros(B, np.int32)
-        temps = np.zeros(B, np.float32)
-        top_ks = np.zeros(B, np.int32)
-        top_ps = np.ones(B, np.float32)
-        rope_deltas = np.zeros(B, np.int32)
-        min_ps = np.zeros(B, np.float32)
-        penalties = np.tile(np.array([[0.0], [0.0], [1.0]], np.float32), (1, B))
-        seeds = np.zeros(B, np.int32)
-        eos_allowed_from = np.zeros(B, np.int32)
-        eos_rows = np.full((B, MAX_EOS_IDS), self.runner.model.config.vocab_size, np.int32)
-        any_eos_mask = False
+            B = self.config.max_seqs
+            # per-window table width: the widest participant's ladder rung —
+            # short-sequence batches keep their narrow H2D + gather, and only
+            # windows containing a deep sequence dispatch the wide executable
+            page_tables, W = self._batch_tables(B, [seq for seq, _ in participants])
+            if self.grouped:
+                held = sum(np.count_nonzero(seq.page_table, axis=1) for seq, _ in participants)
+                self.decode_group_pages = {
+                    g.name: int(held[list(g.tables)].sum()) for g in self.allocator.groups
+                }
+            positions = np.zeros(B, np.int32)
+            active = np.zeros(B, bool)
+            limits = np.zeros(B, np.int32)
+            temps = np.zeros(B, np.float32)
+            top_ks = np.zeros(B, np.int32)
+            top_ps = np.ones(B, np.float32)
+            rope_deltas = np.zeros(B, np.int32)
+            min_ps = np.zeros(B, np.float32)
+            penalties = np.tile(np.array([[0.0], [0.0], [1.0]], np.float32), (1, B))
+            seeds = np.zeros(B, np.int32)
+            eos_allowed_from = np.zeros(B, np.int32)
+            eos_rows = np.full((B, MAX_EOS_IDS), self.runner.model.config.vocab_size, np.int32)
+            any_eos_mask = False
 
-        snapshot = []
-        for seq, steps in participants:
-            i = seq.slot
-            positions[i] = seq.next_fed_pos
-            page_tables[i, ..., : seq.page_table.shape[-1]] = seq.page_table
-            active[i] = True
-            limits[i] = seq.next_fed_pos + steps - 1  # max fed position
-            temps[i] = seq.req.sampling.temperature
-            top_ks[i] = seq.req.sampling.top_k
-            top_ps[i] = seq.req.sampling.top_p
-            rope_deltas[i] = seq.req.mrope_delta
-            min_ps[i] = seq.req.sampling.min_p
-            penalties[0, i] = seq.req.sampling.presence_penalty
-            penalties[1, i] = seq.req.sampling.frequency_penalty
-            penalties[2, i] = seq.req.sampling.repetition_penalty
-            seeds[i] = fold_seed(seq.req.sampling.seed)
-            sam = seq.req.sampling
-            if sam.min_tokens > 1 and seq.req.eos_token_ids and not sam.ignore_eos:
-                # the decode step sampling generation #k feeds position
-                # prompt_len + k - 2 (prefill sampled #1); EOS is suppressed
-                # while sampling generation #k for k <= min_tokens (vLLM
-                # semantics: min_tokens non-EOS tokens are guaranteed), so it
-                # unblocks at fed position prompt_len + min_tokens - 1
-                eos_allowed_from[i] = seq.prompt_len + sam.min_tokens - 1
-                ids = np.asarray(seq.req.eos_token_ids[:MAX_EOS_IDS], np.int32)
-                eos_rows[i, : len(ids)] = ids
-                any_eos_mask = True
-            snapshot.append((seq, i, steps))
-            seq.sched_len += steps
+            snapshot = []
+            for seq, steps in participants:
+                i = seq.slot
+                positions[i] = seq.next_fed_pos
+                page_tables[i, ..., : seq.page_table.shape[-1]] = seq.page_table
+                active[i] = True
+                limits[i] = seq.next_fed_pos + steps - 1  # max fed position
+                temps[i] = seq.req.sampling.temperature
+                top_ks[i] = seq.req.sampling.top_k
+                top_ps[i] = seq.req.sampling.top_p
+                rope_deltas[i] = seq.req.mrope_delta
+                min_ps[i] = seq.req.sampling.min_p
+                penalties[0, i] = seq.req.sampling.presence_penalty
+                penalties[1, i] = seq.req.sampling.frequency_penalty
+                penalties[2, i] = seq.req.sampling.repetition_penalty
+                seeds[i] = fold_seed(seq.req.sampling.seed)
+                sam = seq.req.sampling
+                if sam.min_tokens > 1 and seq.req.eos_token_ids and not sam.ignore_eos:
+                    # the decode step sampling generation #k feeds position
+                    # prompt_len + k - 2 (prefill sampled #1); EOS is suppressed
+                    # while sampling generation #k for k <= min_tokens (vLLM
+                    # semantics: min_tokens non-EOS tokens are guaranteed), so it
+                    # unblocks at fed position prompt_len + min_tokens - 1
+                    eos_allowed_from[i] = seq.prompt_len + sam.min_tokens - 1
+                    ids = np.asarray(seq.req.eos_token_ids[:MAX_EOS_IDS], np.int32)
+                    eos_rows[i, : len(ids)] = ids
+                    any_eos_mask = True
+                snapshot.append((seq, i, steps))
+                seq.sched_len += steps
 
-        want_lp = any(seq.req.logprobs is not None for seq, _ in participants)
-        want_pen = any(seq.req.sampling.needs_penalties for seq, _ in participants)
-        # step anatomy: every scanned step reads the weights + each live
-        # participant's KV pages — the bytes-moved floor at this occupancy
-        live_pages = sum(
-            self.allocator._seqs[seq.req.request_id].num_pages
-            for seq, _ in participants
-            if seq.req.request_id in self.allocator._seqs
-        )
-        rec = self.anatomy.begin(
-            "decode_window", ts=t_prep,
-            # cost split: each participant pays for its scheduled steps
-            bill=[self._bill(s.req, max(1, n)) for s, _, n in snapshot],
-        )
-        steps_total = sum(steps for _, _, steps in snapshot)
-        self.anatomy.add_phase(rec, "host_prep", time.monotonic() - t_prep)
+            want_lp = any(seq.req.logprobs is not None for seq, _ in participants)
+            want_pen = any(seq.req.sampling.needs_penalties for seq, _ in participants)
+            # step anatomy: every scanned step reads the weights + each live
+            # participant's KV pages — the bytes-moved floor at this occupancy
+            live_pages = sum(
+                self.allocator._seqs[seq.req.request_id].num_pages
+                for seq, _ in participants
+                if seq.req.request_id in self.allocator._seqs
+            )
+            rec = self.anatomy.begin(
+                "decode_window", ts=prep.t0,
+                # cost split: each participant pays for its scheduled steps
+                bill=[self._bill(s.req, max(1, n)) for s, _, n in snapshot],
+            )
+            steps_total = sum(steps for _, _, steps in snapshot)
+        self.anatomy.add_phase(rec, "host_prep", prep.dt)
         with self.anatomy.phase(
             rec, "dispatch", request_id=snapshot[0][0].req.request_id,
             trace_id=snapshot[0][0].req.trace_id,

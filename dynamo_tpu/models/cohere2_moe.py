@@ -251,22 +251,28 @@ class Cohere2MoeModel:
         """n [T, D] (normed), positions [T]. Returns (out [T, D], kv)."""
         c = self.config
         T = n.shape[0]
+        # the outer scope says which kind of layer; inside it the innermost name
+        # of the closed vocabulary is the part (benchmark/trace_parts.py PARTS):
+        # rope and the cache write name themselves `attn_kv`
         with jax.named_scope("attn_window" if kind == SLIDING else "attn_full"):
-            q = (n @ lp["wq"]).reshape(T, c.num_heads, c.head_dim)
-            k = (n @ lp["wk"]).reshape(T, c.num_kv_heads, c.head_dim)
-            v = (n @ lp["wv"]).reshape(T, c.num_kv_heads, c.head_dim)
+            with jax.named_scope("attn_proj"):
+                q = (n @ lp["wq"]).reshape(T, c.num_heads, c.head_dim)
+                k = (n @ lp["wk"]).reshape(T, c.num_kv_heads, c.head_dim)
+                v = (n @ lp["wv"]).reshape(T, c.num_kv_heads, c.head_dim)
             if kind == SLIDING:
                 q = apply_rope_pairs(q, positions, c.rope_theta)
                 k = apply_rope_pairs(k, positions, c.rope_theta)
             k_pool, v_pool = scatter_kv(kv["k"], kv["v"], k, v, phys, offsets)
-            attn = attn_fn(q, k_pool, v_pool, c.sliding_window if kind == SLIDING else 0)
-            return attn.reshape(T, -1) @ lp["wo"], dict(kv, k=k_pool, v=v_pool)
+            with jax.named_scope("attn"):
+                attn = attn_fn(q, k_pool, v_pool, c.sliding_window if kind == SLIDING else 0)
+            with jax.named_scope("attn_proj"):
+                return attn.reshape(T, -1) @ lp["wo"], dict(kv, k=k_pool, v=v_pool)
 
     def _experts(self, lp, n, count_rows=None):
         """n [T, D] -> (routed + shared [T, D], the held experts' assignment
         counts over the rows of `count_rows` (all rows when None))."""
         c = self.config
-        with jax.named_scope("moe"):
+        with jax.named_scope("moe_router"):
             # the router: float32 on the normed hidden state, at full precision
             # (a bf16 pass would move the choice of expert, not just a weight)
             logits = jnp.dot(
@@ -278,18 +284,18 @@ class Cohere2MoeModel:
             if count_rows is not None:
                 idx = jnp.where(count_rows[:, None], idx, -1)  # held nowhere
 
-            def ffn(rows, group_sizes):
-                gated = jax.nn.silu(grouped_matmul(rows, lp["w_gate"], group_sizes))
-                up = grouped_matmul(rows, lp["w_up"], group_sizes)
-                return grouped_matmul(gated * up, lp["w_down"], group_sizes)
+        def ffn(rows, group_sizes):  # `moe_dispatch` calls it under `moe_experts`
+            gated = jax.nn.silu(grouped_matmul(rows, lp["w_gate"], group_sizes))
+            up = grouped_matmul(rows, lp["w_up"], group_sizes)
+            return grouped_matmul(gated * up, lp["w_down"], group_sizes)
 
-            routed, counts = moe_dispatch(
-                n, weights, idx, ffn, num_held=c.num_experts, offset=c.moe_expert_offset
-            )
+        routed, counts = moe_dispatch(
+            n, weights, idx, ffn, num_held=c.num_experts, offset=c.moe_expert_offset
+        )
         with jax.named_scope("shared_experts"):
             mid = jax.nn.silu(n @ lp["shared_gate"]) * (n @ lp["shared_up"])
             shared = (mid @ lp["shared_down"]).astype(jnp.float32) / c.num_shared_experts
-        return (routed + shared).astype(c.dtype), counts
+            return (routed + shared).astype(c.dtype), counts
 
     def _unembed(self, params: dict, hidden: jnp.ndarray) -> jnp.ndarray:
         c = self.config
@@ -316,14 +322,17 @@ class Cohere2MoeModel:
         page_size = kv_cache["k"].shape[1]
         tables = self._tables(page_tables)  # [L, N, W]
         lane = jnp.arange(N)
-        offsets = jnp.where(valid, positions % page_size, 0).reshape(N * T)
+        with jax.named_scope("attn_kv"):  # where each row's K and V go
+            offsets = jnp.where(valid, positions % page_size, 0).reshape(N * T)
         flat_pos = positions.reshape(N * T)
 
-        hidden = params["embed"][tokens.reshape(N * T)].astype(c.dtype)
+        with jax.named_scope("embed"):
+            hidden = params["embed"][tokens.reshape(N * T)].astype(c.dtype)
         cache = kv_cache
         for l, (kind, lp) in enumerate(zip(c.layer_types, params["layers"])):
             table = tables[l]
-            phys = jnp.where(valid, table[lane[:, None], positions // page_size], 0)
+            with jax.named_scope("attn_kv"):
+                phys = jnp.where(valid, table[lane[:, None], positions // page_size], 0)
 
             def attn_fn(q, k_pool, v_pool, window, table=table):
                 # one lane at a time through ONE instance of the kernel (a
@@ -344,7 +353,8 @@ class Cohere2MoeModel:
                 lp, kind, n, cache, flat_pos, phys.reshape(N * T), offsets, attn_fn
             )
             ffn, _ = self._experts(lp, n)
-            hidden = hidden + attn + ffn
+            with jax.named_scope("attn_proj"):  # the residual add of a parallel block
+                hidden = hidden + attn + ffn
         rows = hidden[jnp.arange(N) * T + last_idx]
         return self._unembed(params, rows), cache
 
@@ -367,13 +377,16 @@ class Cohere2MoeModel:
         page_size = cache["k"].shape[1]
         B = tokens.shape[0]
         tables = self._tables(page_tables)  # [L, B, W]
-        offsets = jnp.where(active, positions % page_size, 0)
+        with jax.named_scope("attn_kv"):
+            offsets = jnp.where(active, positions % page_size, 0)
 
-        hidden = params["embed"][tokens].astype(c.dtype)
+        with jax.named_scope("embed"):
+            hidden = params["embed"][tokens].astype(c.dtype)
         counts = cache.get("moe_counts")  # absent where no engine keeps it
         for l, (kind, lp) in enumerate(zip(c.layer_types, params["layers"])):
             table = tables[l]
-            phys = jnp.where(active, table[jnp.arange(B), positions // page_size], 0)
+            with jax.named_scope("attn_kv"):
+                phys = jnp.where(active, table[jnp.arange(B), positions // page_size], 0)
 
             def attn_fn(q, k_pool, v_pool, window, table=table):
                 return dispatch_paged_decode_attention(
@@ -384,7 +397,8 @@ class Cohere2MoeModel:
             attn, cache = self._attention(lp, kind, n, cache, positions, phys, offsets, attn_fn)
             ffn, got = self._experts(lp, n, count_rows=active)
             counts = None if counts is None else counts + got
-            hidden = hidden + attn + ffn
+            with jax.named_scope("attn_proj"):  # the residual add of a parallel block
+                hidden = hidden + attn + ffn
         if counts is not None:
             cache = dict(cache, moe_counts=counts)
         return self._unembed(params, hidden), cache

@@ -280,6 +280,9 @@ class NemotronHModel:
 
     # ---------------- blocks ----------------
 
+    #: the residual add after a block counts with the projection that feeds it
+    RESIDUAL_PART = {"M": "ssm_proj", "*": "attn_proj", "E": "shared_experts"}
+
     def _split_proj(self, proj):
         c = self.config
         z = proj[..., : c.mamba_inner]
@@ -301,18 +304,23 @@ class NemotronHModel:
         """`GroupRMSNorm(y * silu(z)) * w` (the gate BEFORE the norm, one norm
         per group of inner / n_groups), then the output projection."""
         c = self.config
-        g = y * jax.nn.silu(z.astype(jnp.float32))
-        gg = g.reshape(*g.shape[:-1], c.n_groups, c.mamba_inner // c.n_groups)
-        gg = gg * jax.lax.rsqrt(jnp.mean(gg * gg, axis=-1, keepdims=True) + c.rms_norm_eps)
-        g = gg.reshape(g.shape) * bp["mixer_norm"].astype(jnp.float32)
-        return g.astype(c.dtype) @ bp["out_proj"]
+        with jax.named_scope("ssm"):
+            g = y * jax.nn.silu(z.astype(jnp.float32))
+            gg = g.reshape(*g.shape[:-1], c.n_groups, c.mamba_inner // c.n_groups)
+            gg = gg * jax.lax.rsqrt(jnp.mean(gg * gg, axis=-1, keepdims=True) + c.rms_norm_eps)
+            g = gg.reshape(g.shape) * bp["mixer_norm"].astype(jnp.float32)
+        with jax.named_scope("ssm_proj"):
+            return g.astype(c.dtype) @ bp["out_proj"]
 
     def _mamba_prefill(self, bp, h, cache, rows, fresh, valid):
         """h [L, T, D]; rows [L] this block's state row per lane; fresh [L]:
         the lane starts its sequence; valid [L, T]."""
         c = self.config
-        with jax.named_scope("ssm"):
+        # parts by scope (benchmark/trace_parts.py PARTS): the projections are
+        # `ssm_proj`; convolution, scan and state rows are `ssm`
+        with jax.named_scope("ssm_proj"):
             z, xbc, dt = self._split_proj(h @ bp["in_proj"])
+        with jax.named_scope("ssm"):
             window = jnp.where(fresh[:, None, None], 0, cache["conv"][rows])
             n_valid = jnp.sum(valid, axis=1).astype(jnp.int32)
             xbc, window = causal_conv(xbc, window, bp["conv_w"], bp["conv_b"], n_valid)
@@ -328,15 +336,16 @@ class NemotronHModel:
                 ssm=cache["ssm"].at[rows].set(state),
                 conv=cache["conv"].at[rows].set(window),
             )
-            return self._mamba_out(bp, y.reshape(*y.shape[:2], c.mamba_inner), z), cache
+        return self._mamba_out(bp, y.reshape(*y.shape[:2], c.mamba_inner), z), cache
 
     def _mamba_decode(self, bp, h, cache, base, active):
         """h [B, D]; batch row b's state is row base + b; rows that are not
         active leave state and window as they were."""
         c = self.config
         nb = h.shape[0]
-        with jax.named_scope("ssm"):
+        with jax.named_scope("ssm_proj"):
             z, xbc, dt = self._split_proj(h @ bp["in_proj"])
+        with jax.named_scope("ssm"):
             mine = base + jnp.arange(nb)
             xbc, window = causal_conv(
                 xbc[:, None, :], cache["conv"][mine], bp["conv_w"], bp["conv_b"],
@@ -350,25 +359,27 @@ class NemotronHModel:
                 -jnp.exp(bp["A_log"]), B, C, bp["D"], active,
             )
             cache = dict(cache, ssm=ssm, conv=cache["conv"].at[mine].set(window))
-            return self._mamba_out(bp, y.reshape(nb, c.mamba_inner), z), cache
+        return self._mamba_out(bp, y.reshape(nb, c.mamba_inner), z), cache
 
     def _attention(self, bp, h, kv, flat_phys, offsets, attn_fn):
         """h [T, D]. No rotary embedding: the published model applies none."""
         c = self.config
         T = h.shape[0]
-        with jax.named_scope("attn"):
+        with jax.named_scope("attn_proj"):
             q = (h @ bp["wq"]).reshape(T, c.num_heads, c.head_dim)
             k = (h @ bp["wk"]).reshape(T, c.num_kv_heads, c.head_dim)
             v = (h @ bp["wv"]).reshape(T, c.num_kv_heads, c.head_dim)
-            k_pool, v_pool = scatter_kv(kv["k"], kv["v"], k, v, flat_phys, offsets)
+        k_pool, v_pool = scatter_kv(kv["k"], kv["v"], k, v, flat_phys, offsets)  # `attn_kv`
+        with jax.named_scope("attn"):
             attn = attn_fn(q, k_pool, v_pool)
+        with jax.named_scope("attn_proj"):
             return attn.reshape(T, -1) @ bp["wo"], dict(kv, k=k_pool, v=v_pool)
 
     def _experts(self, bp, h, count_rows=None):
         """h [T, D] -> (out [T, D], the held experts' assignment counts over
         the rows of `count_rows` (all rows when None))."""
         c = self.config
-        with jax.named_scope("moe"):
+        with jax.named_scope("moe_router"):
             # the router: float32 on the full hidden state, at full precision
             # (a bf16 pass would move the choice of expert, not just a weight)
             logits = jnp.dot(
@@ -380,14 +391,19 @@ class NemotronHModel:
             if count_rows is not None:
                 idx = jnp.where(count_rows[:, None], idx, -1)  # held nowhere
 
-            def ffn(rows, group_sizes):
-                mid = relu2(grouped_matmul(rows, bp["w1"], group_sizes))
-                return grouped_matmul(mid, bp["w2"], group_sizes)
+        def ffn(rows, group_sizes):  # `moe_dispatch` calls it under `moe_experts`
+            mid = relu2(grouped_matmul(rows, bp["w1"], group_sizes))
+            return grouped_matmul(mid, bp["w2"], group_sizes)
 
-            routed, counts = moe_dispatch(
-                h @ bp["lat_down"], weights, idx, ffn,
-                num_held=c.n_routed_experts, offset=c.moe_expert_offset,
-            )
+        # the latent projections around the experts are dense matrices every
+        # row meets: they count with the shared expert
+        with jax.named_scope("shared_experts"):
+            latent = h @ bp["lat_down"]
+        routed, counts = moe_dispatch(
+            latent, weights, idx, ffn,
+            num_held=c.n_routed_experts, offset=c.moe_expert_offset,
+        )
+        with jax.named_scope("shared_experts"):
             out = routed.astype(c.dtype) @ bp["lat_up"]
             return out + relu2(h @ bp["shared_up"]) @ bp["shared_down"], counts
 
@@ -410,14 +426,17 @@ class NemotronHModel:
         num_pages = cache["k"].shape[0] // max(1, c.count("*"))
         slot_rows = cache["ssm"].shape[0] // max(1, c.count("M"))
         lane = jnp.arange(N)
-        phys = jnp.where(valid, page_tables[lane[:, None], positions // page_size], 0)
-        offsets = jnp.where(valid, positions % page_size, 0).reshape(N * T)
-        fresh = positions[:, 0] == 0
-        # a slot the engine does not name (padding lanes, warm-up) is the trash row
-        slots = jnp.where((state_slots >= 0) & (state_slots < slot_rows - 1),
-                          state_slots, slot_rows - 1)
+        with jax.named_scope("attn_kv"):  # where each row's K and V go
+            phys = jnp.where(valid, page_tables[lane[:, None], positions // page_size], 0)
+            offsets = jnp.where(valid, positions % page_size, 0).reshape(N * T)
+        with jax.named_scope("ssm"):  # which state row each lane continues
+            fresh = positions[:, 0] == 0
+            # a slot the engine does not name (padding lanes, warm-up) is the trash row
+            slots = jnp.where((state_slots >= 0) & (state_slots < slot_rows - 1),
+                              state_slots, slot_rows - 1)
 
-        hidden = params["embed"][tokens.reshape(N * T)].astype(c.dtype)
+        with jax.named_scope("embed"):
+            hidden = params["embed"][tokens.reshape(N * T)].astype(c.dtype)
         m = a = 0
         for kind, bp in zip(c.pattern, params["blocks"]):
             h = rms_norm(hidden, bp["norm"], c.rms_norm_eps)
@@ -446,7 +465,8 @@ class NemotronHModel:
                 a += 1
             else:
                 out, _ = self._experts(bp, h)
-            hidden = hidden + out
+            with jax.named_scope(self.RESIDUAL_PART[kind]):
+                hidden = hidden + out
         return hidden, cache
 
     def prefill_packed(self, params, kv_cache, tokens, positions, page_tables, valid,
@@ -485,10 +505,12 @@ class NemotronHModel:
         num_pages = cache["k"].shape[0] // max(1, c.count("*"))
         slot_rows = cache["ssm"].shape[0] // max(1, c.count("M"))
         B = tokens.shape[0]
-        phys = jnp.where(active, page_tables[jnp.arange(B), positions // page_size], 0)
-        offsets = jnp.where(active, positions % page_size, 0)
+        with jax.named_scope("attn_kv"):
+            phys = jnp.where(active, page_tables[jnp.arange(B), positions // page_size], 0)
+            offsets = jnp.where(active, positions % page_size, 0)
 
-        hidden = params["embed"][tokens].astype(c.dtype)
+        with jax.named_scope("embed"):
+            hidden = params["embed"][tokens].astype(c.dtype)
         counts = cache["moe_counts"]
         m = a = 0
         for kind, bp in zip(c.pattern, params["blocks"]):
@@ -509,5 +531,6 @@ class NemotronHModel:
             else:
                 out, n = self._experts(bp, h, count_rows=active)
                 counts = counts + n
-            hidden = hidden + out
+            with jax.named_scope(self.RESIDUAL_PART[kind]):
+                hidden = hidden + out
         return self._unembed(params, hidden), dict(cache, moe_counts=counts)

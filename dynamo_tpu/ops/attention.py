@@ -50,6 +50,7 @@ log = get_logger("ops.attention")
 _NEG_INF = -1e30
 
 
+@jax.named_scope("attn_kv")
 def scatter_kv(
     k_pages,  # [LP, ps, Hkv, D] flat pool (plain or QuantizedPages)
     v_pages,  # [LP, ps, Hkv, D]
@@ -304,6 +305,7 @@ def _over_head_shards(fn, mesh, q, k_pages, v_pages, tables, positions):
     )(q, k_pages, v_pages, tables, positions)
 
 
+@jax.named_scope("attn")
 def dispatch_paged_decode_attention(q, k_pages, v_pages, page_tables, positions, mesh=None,
                                     window: int = 0):
     """Pallas kernel on TPU, pure-JAX reference elsewhere (same contract).
@@ -398,6 +400,7 @@ def use_pallas_prefill(head_dim: int, chunk_len: int, block_q: int = 128) -> boo
     return chunk_len % block_q == 0 and _pallas_enabled(head_dim % 128 == 0)
 
 
+@jax.named_scope("attn")
 def dispatch_paged_prefill_attention(
     q, k_pages, v_pages, page_table, positions, mesh=None, window: int = 0
 ):

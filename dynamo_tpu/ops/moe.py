@@ -44,6 +44,7 @@ def relu2(x: jnp.ndarray) -> jnp.ndarray:
     return r * r
 
 
+@jax.named_scope("moe_router")
 def topk_routing(
     router_logits: jnp.ndarray,  # [T, E] float32
     k: int,
@@ -63,6 +64,7 @@ def topk_routing(
     return weights, top_idx
 
 
+@jax.named_scope("moe_router")
 def sigmoid_topk_routing(
     router_logits: jnp.ndarray,  # [T, E] float32, over ALL experts routed over
     bias: jnp.ndarray,  # [E] e_score_correction_bias: moves the choice only
@@ -79,6 +81,7 @@ def sigmoid_topk_routing(
     return weights, idx
 
 
+@jax.named_scope("moe_experts")
 def grouped_matmul(rows: jnp.ndarray, bank, group_sizes: jnp.ndarray, mesh=None) -> jnp.ndarray:
     """``rows [M, in]`` (sorted by group) times ``bank [G, in, out]``: row r
     meets the matrix of its group. Rows past the last group are UNDEFINED
@@ -107,6 +110,7 @@ def grouped_matmul(rows: jnp.ndarray, bank, group_sizes: jnp.ndarray, mesh=None)
     return jax.lax.ragged_dot(rows, bank, group_sizes)
 
 
+@jax.named_scope("moe_dispatch")
 def moe_dispatch(
     hidden: jnp.ndarray,  # [T, D]
     weights: jnp.ndarray,  # [T, K] float32, normalised over all K chosen
@@ -124,7 +128,9 @@ def moe_dispatch(
     key = jnp.where(held, local, num_held)  # absent experts sort last
     order = jnp.argsort(key, stable=True)
     counts = jnp.zeros(num_held + 1, jnp.int32).at[key].add(1)[:num_held]
-    y = expert_ffn(hidden[order // K], counts)  # [T*K, Dout], sorted order
+    rows = hidden[order // K]
+    with jax.named_scope("moe_experts"):  # the products and what stands between them
+        y = expert_ffn(rows, counts)  # [T*K, Dout], sorted order
     y = jnp.where(held[order][:, None], y.astype(jnp.float32), 0.0)
     # back to (token, choice) order: a gather, so the sum over a token's
     # choices has one order whatever the routing
@@ -143,8 +149,9 @@ def moe_block(
     mesh=None,  # the caller's mesh of several devices, if any: `grouped_matmul`
 ) -> jnp.ndarray:
     """Softmax-routed SwiGLU experts, all held here (Mixtral, DeepSeek-V2)."""
-    logits = hidden.astype(jnp.float32) @ router_w.astype(jnp.float32)  # [T, E]
-    weights, idx = topk_routing(logits, num_experts_per_tok, renormalize=renormalize)
+    with jax.named_scope("moe_router"):
+        logits = hidden.astype(jnp.float32) @ router_w.astype(jnp.float32)  # [T, E]
+        weights, idx = topk_routing(logits, num_experts_per_tok, renormalize=renormalize)
 
     def ffn(rows, group_sizes):
         gated = jax.nn.silu(grouped_matmul(rows, w_gate, group_sizes, mesh))

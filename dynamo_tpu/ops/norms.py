@@ -2,9 +2,11 @@
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
+@jax.named_scope("norm")
 def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float) -> jnp.ndarray:
     """RMSNorm over the last axis; accumulates in f32 like the TPU-friendly norm."""
     xf = x.astype(jnp.float32)
@@ -13,6 +15,7 @@ def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float) -> jnp.ndarray:
     return (normed * weight.astype(jnp.float32)).astype(x.dtype)
 
 
+@jax.named_scope("norm")
 def layer_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float) -> jnp.ndarray:
     """Cohere's LayerNorm over the last axis: mean-centred, divided by
     ``sqrt(var + eps)``, times a weight, no bias; in f32, cast back."""

@@ -3,6 +3,7 @@ by interleaved pairs (GPT-J, `apply_rope_pairs`)."""
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -12,6 +13,7 @@ def rope_frequencies(head_dim: int, theta: float) -> jnp.ndarray:
     return 1.0 / (theta**exponent)
 
 
+@jax.named_scope("attn_kv")
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
     """Rotate q or k by position.
 
@@ -28,6 +30,7 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndar
     return rotated.astype(x.dtype)
 
 
+@jax.named_scope("attn_kv")
 def apply_rope_pairs(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
     """Rotate q or k by position, by INTERLEAVED PAIRS (`rope_gptj`): lanes
     (2i, 2i + 1) are one pair turned by frequency i, where `apply_rope` pairs
@@ -43,6 +46,7 @@ def apply_rope_pairs(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jn
     return rotated.reshape(x.shape).astype(x.dtype)
 
 
+@jax.named_scope("attn_kv")
 def apply_mrope(
     x: jnp.ndarray,  # [T, num_heads, head_dim]
     positions3: jnp.ndarray,  # [T, 3] (temporal, row, col) position per token

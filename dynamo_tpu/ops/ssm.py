@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 
+@jax.named_scope("ssm")
 def causal_conv(
     x: jnp.ndarray,  # [L, T, C] the lane's new inputs
     window: jnp.ndarray,  # [L, K-1, C] the K-1 inputs before them (zeros at a start)
@@ -66,6 +67,7 @@ def ssd_sequential(x, dt, A, B, C, D, state):
     return y.swapaxes(0, 1), state
 
 
+@jax.named_scope("ssm")
 def ssd_chunked(x, dt, A, B, C, D, state, chunk_size: int = 128):
     """`ssd_sequential`'s result, a chunk at a time (same arguments)."""
     L, T, H, P = x.shape
@@ -119,6 +121,7 @@ def ssm_state_update_reference(state, decay, dtx, b_vec, c_vec, rows):
     return y, state.at[rows].set(S)
 
 
+@jax.named_scope("ssm")
 def ssm_state_update(state, rows, x, dt, A, B, C, D, active):
     """One decode token for every batch row b, whose state is row ``rows[b]``
     of ``state`` [R, H, P, N]; rows that are not ``active`` name a trash row
