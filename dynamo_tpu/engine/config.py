@@ -206,10 +206,12 @@ class EngineConfig:
     # sequence's slot one window longer; a third bought no throughput
     # (PERF.md, PR 32). 1 = fully synchronous.
     pipeline_depth: int = 2
-    # cross-request prefill packing: chunks of up to this many DISTINCT
-    # sequences ride one prefill call (one weight pass). The effective lane
-    # count per bucket is row-budgeted by lanes_for() (see its r5-measured
-    # ~1024-row rationale). 1 = disabled (per-request prefill).
+    # cross-request prefill packing: chunks of several sequences ride one
+    # prefill call (one weight pass). 1 = disabled (per-request prefill). For
+    # a model with recurrent layers it is also the most lanes of a pack (each
+    # a whole chunk, row-budgeted per bucket by lanes_for()); every other
+    # model's pack is made of blocks (prefill_block, pack_blocks) and takes
+    # no count from here.
     prefill_lanes: int = 4
     # packed prefill calls dispatched ahead of result materialization (the
     # prefill analogue of pipeline_depth): call N+1's host prep + dispatch
@@ -416,13 +418,34 @@ class EngineConfig:
         return best
 
     def lanes_for(self, bucket: int) -> int:
-        """Packed-prefill lane count for a bucket: bounded by prefill_lanes
-        and a ~1024-row budget. r5 on-chip: per-CALL cost is dominated by a
-        ~10 ms fixed component (flat from 128 to 512 rows), so packing keeps
-        paying well past the old 512-row cap — 2x512 rows measured 20.2 ms
-        vs 2 separate calls at 33.7 ms (-40%); beyond ~1024 rows compute
-        finally dominates and padding risk outweighs the amortization."""
+        """Packed-prefill lane count for a bucket, on the path that keeps the
+        rectangle (a model with recurrent layers) and in warm-up: bounded by
+        prefill_lanes and a 1024-row budget. On a v5e one `qwen2.5-3b` pack
+        (tools/profile_prefill_pack.py, PR 40, best of 5 in one jit chain)
+        costs 10.4 / 11.9 ms at 1 / 2 blocks of 128 rows whatever it holds
+        (the weights' stream), then about 5 ms a block: 16.9, 21.9, 26.9,
+        31.8, 36.7, 40.2 ms at 3 to 8 blocks; a rectangle costs what its rows
+        cost as blocks ([1,512] 23.0, [2,256] 21.9, [2,512] 40.9, [4,256]
+        41.2). A row is 42.7 us in a pack of 512 and 39.2 in one of 1024, so
+        packing pays up to the budget; more rows a call lengthen the stall a
+        decode stream sees."""
         return max(1, min(self.prefill_lanes, 1024 // bucket))
+
+    @property
+    def prefill_block(self) -> int:
+        """Rows of one block of a packed prefill (a model with no recurrent
+        layers: `Scheduler._dispatch_prefill_batches`): the smallest bucket
+        that is a multiple of 128, the flash kernels' query block, else (the
+        tiny test models' `(16, 32)`) the smallest bucket. Derived, not
+        configured."""
+        whole = [b for b in self.prefill_buckets if b % 128 == 0]
+        return min(whole or self.prefill_buckets)
+
+    @property
+    def pack_blocks(self) -> int:
+        """Blocks a packed prefill holds at most: 8, inside the 1024 rows
+        `lanes_for` allows a rectangle."""
+        return max(1, min(8, 1024 // self.prefill_block))
 
     def bucket_for(self, n: int) -> int:
         """Smallest bucket >= n (n must be <= max bucket)."""

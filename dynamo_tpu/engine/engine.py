@@ -1421,6 +1421,18 @@ class AsyncJaxEngine:
             "prefill calls dispatched to the device (packed and per-request)",
             [({}, st.prefill_calls)],
         ))
+        # a pack's fill, as a quotient: rows / padded rows
+        parts.append(render_family(
+            "dynamo_engine_prefill_rows_total", "counter",
+            "prompt rows prefilled on this engine's device",
+            [({}, st.prefill_rows)],
+        ))
+        parts.append(render_family(
+            "dynamo_engine_prefill_padded_rows_total", "counter",
+            "rows the prefill programs computed for them, padding included "
+            "(a pack's blocks or lanes x bucket, a chain's chunk buckets)",
+            [({}, st.prefill_padded_rows)],
+        ))
         parts.append(render_family(
             "dynamo_engine_prefill_windows_ahead_total", "counter",
             "decode windows in flight at the moment of each prefill "

@@ -627,12 +627,12 @@ class ModelRunner:
         self,
         lanes: list,  # [(tokens np[int32], start_pos, page_table, slot_or_-1, sampling, eos_ids, is_final[, lora_slot])]
         N: int,  # lane count the executable is compiled for (>= len(lanes))
+        bucket: int,  # rows a lane is padded to (>= the longest lane)
     ):
         """Host-prep half of :meth:`prefill_chunk_batch`: build the packed
         int/float control arrays on the host (no device work). Returns
         (ints, flts, want_extras, mp)."""
         V = self.model.config.vocab_size
-        bucket = self.config.bucket_for(max(len(l[0]) for l in lanes))
         # table width for THIS call: the widest lane's ladder bucket (narrow
         # lanes zero-pad into the trash page) — short packs keep their
         # narrow executable; only packs containing a deep sequence go wide
@@ -697,14 +697,17 @@ class ModelRunner:
         self,
         lanes: list,
         N: int,
+        bucket: int,
         want_logprobs: bool = False,
     ):
-        """Dispatch ONE packed prefill covering chunks of up to N distinct
-        sequences (pad lanes are all-invalid; see :meth:`pack_prefill_lanes`
-        for the lane tuple contract). Returns the [N] device token array
-        (async copy started) — callers read only final-chunk lanes — plus
-        the logprob arrays when requested."""
-        ints, flts, want_extras, mp = self.pack_prefill_lanes(lanes, N)
+        """Dispatch ONE packed prefill of up to N lanes of ``bucket`` rows:
+        whole chunks of distinct sequences (a model with recurrent layers),
+        or blocks of rows of which several may be one sequence's (pad lanes
+        are all-invalid; see :meth:`pack_prefill_lanes` for the lane tuple
+        contract). Returns the [N] device token array (async copy started) —
+        callers read only final lanes — plus the logprob arrays when
+        requested."""
+        ints, flts, want_extras, mp = self.pack_prefill_lanes(lanes, N, bucket)
         if want_extras:
             self._ensure_penalty_state()
         toks, lp, self.kv_cache, self.slot_state = self._prefill_packed(
@@ -1412,26 +1415,74 @@ class ModelRunner:
                 sh["temps"], sh["zeros_i"], sh["ones_f"], draft_probs=dp,
             )
             jax.block_until_ready(out)  # graftlint: sync-ok warmup: compile gate, not serving traffic
-        for b in self.config.prefill_buckets:
-            if not self.packed_prefill_mode:
+        if self.packed_prefill_mode:
+            # the packed programs a first request can reach; the rest, the
+            # feature variants and the per-request trace (still reached by
+            # disagg remote prefill and image requests) compile via the
+            # extras thunks
+            for N, T, width in self.packed_warmup_shapes()[0]:
+                self._warm_packed(N, T, width)
+        else:
+            for b in self.config.prefill_buckets:
                 self.prefill_chunk(
                     np.zeros(b, np.int32), 0, sh["pt"][0], sample=True,
                     temperature=0.0, top_k=0, top_p=1.0, slot=-1, sync=True,
                 )
-                continue
-            # the scheduler dispatches packed calls at power-of-two N up to
-            # lanes_for(b); N=1 (lone chunks) and N=lanes_max are the hot
-            # ones — intermediates, feature variants, and the per-request
-            # trace (still reached by disagg remote prefill and image
-            # requests) compile via the extras thunks
-            lane = (
-                np.zeros(b, np.int32), 0, sh["pt"][0], -1,
-                SamplingParams(temperature=0.0), (), False,
-            )
-            for N in {1, self.config.lanes_for(b)}:
-                out = self.prefill_chunk_batch([lane], N=N)
-                jax.block_until_ready(out)  # graftlint: sync-ok warmup: compile gate, not serving traffic
         log.info("warmup(core): compiled in %.1fs", _time.monotonic() - t0)
+
+    def packed_prefill_shapes(self) -> list:
+        """(N, T) of every packed prefill program the scheduler's packer can
+        emit: blocks of `prefill_block` rows, 1 to `pack_blocks` of them; for
+        a model with recurrent layers a rectangle per bucket, its lane count
+        a power of two up to `lanes_for`."""
+        c = self.config
+        if not self.recurrent:
+            return [(n, c.prefill_block) for n in range(1, c.pack_blocks + 1)]
+        return [
+            (n, b) for b in c.prefill_buckets
+            for n in sorted({min(c.lanes_for(b), 1 << k) for k in range(c.lanes_for(b).bit_length() + 1)})
+        ]
+
+    def packed_warmup_shapes(self) -> tuple:
+        """(N, T, table width) of the packed prefill programs warm-up
+        compiles: (before readiness, behind it), the default variant of each.
+
+        Blocks: every N on the first rung of the page-table ladder (where a
+        fresh engine's prompts run) and on the last (where a deployment whose
+        max_model_len was set for long contexts runs them) before readiness,
+        because traffic reaches any N from its first second and a program met
+        first in traffic stalls every stream for its compile; the rungs
+        between compile behind readiness.
+
+        Rectangles: N = 1 and N = lanes_for(b) of every bucket on the first
+        rung before readiness; the powers of two between, and on each wider
+        rung the one chunk the depth-aware planner runs at that depth
+        (chunk_len_for shrinks chunks as context grows) at N = 1, behind."""
+        c = self.config
+        rungs = c.table_buckets
+        shapes = self.packed_prefill_shapes()
+        if not self.recurrent:
+            first = sorted({rungs[0], rungs[-1]})
+            return (
+                [(n, t, w) for w in first for n, t in shapes],
+                [(n, t, w) for w in rungs[1:-1] for n, t in shapes],
+            )
+        core = [(n, t, rungs[0]) for n, t in shapes if n in (1, c.lanes_for(t))]
+        later = [(n, t, rungs[0]) for n, t in shapes if n not in (1, c.lanes_for(t))]
+        later += [(1, c.chunk_len_for((w // 2) * c.page_size), w) for w in rungs[1:]]
+        return core, later
+
+    def _warm_packed(self, N: int, T: int, width: int, sampling=None, want_lp: bool = False) -> None:
+        """Compile (or read back) one packed prefill program by running it on
+        one all-zero lane whose writes land on the reserved null page."""
+        lane = (
+            np.zeros(T, np.int32), 0, np.zeros(self._table_shape(width), np.int32), -1,
+            sampling or SamplingParams(temperature=0.0),
+            (0,) if sampling is not None else (),
+            sampling is not None,
+        )
+        out = self.prefill_chunk_batch([lane], N=N, want_logprobs=want_lp, bucket=T)
+        jax.block_until_ready(out)  # graftlint: sync-ok warmup: compile gate, not serving traffic
 
     def warmup_extra_thunks(self) -> list:
         """Thunks compiling the feature-bearing trace variants — decode
@@ -1471,17 +1522,8 @@ class ModelRunner:
                     jax.block_until_ready(out)  # graftlint: sync-ok warmup: compile gate, not serving traffic
             return run
 
-        def packed(bucket, N, sampling, want_lp):
-            def run():
-                lane = (
-                    np.zeros(bucket, np.int32), 0, sh["pt"][0], -1,
-                    sampling or SamplingParams(temperature=0.0),
-                    (0,) if sampling is not None else (),
-                    sampling is not None,
-                )
-                out = self.prefill_chunk_batch([lane], N=N, want_logprobs=want_lp)
-                jax.block_until_ready(out)  # graftlint: sync-ok warmup: compile gate, not serving traffic
-            return run
+        def packed(N, T, width, sampling=None, want_lp=False):
+            return lambda: self._warm_packed(N, T, width, sampling, want_lp)
 
         bucket = self.config.prefill_buckets[0]
         for sampling, want_lp in (
@@ -1504,25 +1546,22 @@ class ModelRunner:
 
             for b in self.config.prefill_buckets:
                 thunks.append(per_request(b))
-        # packed-prefill executables: each power-of-two N <= lanes_for(b) per
-        # bucket (the scheduler rounds partial packs up to pow2), for the
-        # neutral AND feature-bearing variants (want_* are static jit args —
-        # every combo is a distinct executable). Without these the first
-        # packed shape cold-compiles mid-traffic, a stall that can exceed
-        # HTTP client timeouts.
-        for b in self.config.prefill_buckets:
-            lanes_max = self.config.lanes_for(b)
-            n = 1
-            while n <= lanes_max:
-                if n > 1 and n < lanes_max:
-                    thunks.append(packed(b, n, None, False))
+        # packed-prefill executables: every shape the packer can emit, the
+        # neutral AND the feature-bearing variants (want_* are static jit
+        # args — every combo is a distinct executable). Without these the
+        # first packed shape cold-compiles mid-traffic, a stall that can
+        # exceed HTTP client timeouts.
+        if self.packed_prefill_mode:
+            w0 = self.config.table_buckets[0]
+            later = self.packed_warmup_shapes()[1]
+            thunks += [packed(*shape) for shape in later if shape[2] == w0]
+            for N, T in self.packed_prefill_shapes():
                 for sampling, want_lp in (
                     (None, True),
                     (SamplingParams(presence_penalty=0.1, min_tokens=1), False),
                     (SamplingParams(presence_penalty=0.1, min_tokens=1), True),
                 ):
-                    thunks.append(packed(b, n, sampling, want_lp))
-                n *= 2
+                    thunks.append(packed(N, T, w0, sampling, want_lp))
         # page-table ladder: wider-table variants for the traces a DEEP
         # sequence promotes into mid-serving — the default decode window and
         # the prefill bucket the depth-aware planner runs at that depth
@@ -1541,25 +1580,19 @@ class ModelRunner:
 
         def wide_chunk(width, b):
             def run():
-                pt = np.zeros(self._table_shape(width), np.int32)
-                if self.packed_prefill_mode:
-                    lane = (
-                        np.zeros(b, np.int32), 0, pt, -1,
-                        SamplingParams(temperature=0.0), (), False,
-                    )
-                    out = self.prefill_chunk_batch([lane], N=1)
-                    jax.block_until_ready(out)  # graftlint: sync-ok warmup: compile gate, not serving traffic
-                else:
-                    self.prefill_chunk(
-                        np.zeros(b, np.int32), 0, pt, sample=True,
-                        temperature=0.0, top_k=0, top_p=1.0, slot=-1, sync=True,
-                    )
+                self.prefill_chunk(
+                    np.zeros(b, np.int32), 0, np.zeros(self._table_shape(width), np.int32),
+                    sample=True, temperature=0.0, top_k=0, top_p=1.0, slot=-1, sync=True,
+                )
             return run
 
         for w in self.config.table_buckets[1:]:
             thunks.append(wide_window(w))
-            depth = (w // 2) * self.config.page_size  # where this rung starts
-            thunks.append(wide_chunk(w, self.config.chunk_len_for(depth)))
+            if self.packed_prefill_mode:
+                thunks += [packed(*shape) for shape in later if shape[2] == w]
+            else:
+                depth = (w // 2) * self.config.page_size  # where this rung starts
+                thunks.append(wide_chunk(w, self.config.chunk_len_for(depth)))
         return thunks
 
     def extract_pages_device(self, page_ids: np.ndarray) -> jax.Array:

@@ -267,6 +267,25 @@ def _mm_chunk_overrides(req: EngineRequest, start: int, end: int):
     return embeds, mask
 
 
+def plan_block_pack(chunks: list, block: int, budget: int) -> list:
+    """The blocks of one packed prefill, as a function of the pending set.
+    ``chunks`` are the next chunks of the pending sequences in admission
+    order, ``(start, end)`` in each sequence's own positions; a block is up
+    to ``block`` rows of one chunk and a pack holds ``budget`` of them. Every
+    chunk is cut into whole blocks (only its last may be short), a chunk
+    that does not fit what is left of the budget is cut at a block boundary
+    (its rest rides the next call), and what comes after a full budget
+    waits. Returns ``(index into chunks, start, end)`` for each block."""
+    blocks = []
+    for i, (start, end) in enumerate(chunks):
+        left = budget - len(blocks)
+        if left <= 0:
+            break
+        end = min(end, start + left * block)
+        blocks.extend((i, b, min(b + block, end)) for b in range(start, end, block))
+    return blocks
+
+
 def _is_ready(arr) -> bool:
     try:
         return bool(arr.is_ready())
@@ -290,6 +309,9 @@ class StageStats:
     prefill_s: float = 0.0  # dispatch time of prefill calls (packed + chained)
     prefill_calls: int = 0
     prefill_rows: int = 0
+    # the rows the prefill programs computed for them: blocks or lanes x
+    # bucket of a pack, the chunk buckets of a per-request chain
+    prefill_padded_rows: int = 0
     # decode windows in flight at each prefill dispatch, summed: over
     # prefill_calls, the windows a new prompt's prefill stands behind
     prefill_windows_ahead: int = 0
@@ -328,6 +350,7 @@ class StageStats:
             "prefill_s": round(self.prefill_s, 4),
             "prefill_calls": self.prefill_calls,
             "prefill_rows": self.prefill_rows,
+            "prefill_padded_rows": self.prefill_padded_rows,
             "prefill_windows_ahead": self.prefill_windows_ahead,
             "decode_dispatch_s": round(self.decode_dispatch_s, 4),
             "decode_windows": self.decode_windows,
@@ -1450,13 +1473,15 @@ class Scheduler:
         ))
 
     def _dispatch_prefill_batches(self, outputs: list[StepOutput]) -> int:
-        """Pack pending prefill chunks of distinct sequences into shared
+        """Pack pending prefill chunks of several sequences into shared
         prefill calls (one weight pass per call — the reference's engines
         batch prefills the same way; SURVEY.md §2.4 vLLM scheduler). Each
-        sequence contributes at most one chunk per call (chunk i+1 reads the
-        pages chunk i wrote, so same-sequence chunks ride consecutive calls).
-        Single pending chunks take the per-request path — a packed call pads
-        compute to its full lane count, which a lone request shouldn't pay.
+        sequence contributes at most one chunk per call (`chunk_len_for`'s
+        length: the longest stall a decode stream sees is one call's). The
+        pack is made of blocks (`_pack_blocks`: each chunk padded to its own
+        next block, N the number of blocks), or for a model with recurrent
+        layers of whole chunks a lane (`_pack_rectangle`). A single pending
+        chunk rides the packed program too, at N = its blocks (or 1).
 
         Fairness: dispatches at most ``config.prefill_batches_per_step``
         calls per invocation when decode work is running, so a burst of new
@@ -1503,43 +1528,26 @@ class Scheduler:
                 )
                 if not pending:
                     return count
-                # greedy bucket-aware packing in admission order: grow the lane
-                # set while every taken lane still fits the (possibly enlarged)
-                # bucket's row budget — one long head chunk goes alone, short
-                # chunks pack together. Each lane's chunk length is depth-aware:
-                # chunk_len_for shrinks it as that sequence's prefill advances
-                # into a long prompt, keeping per-chunk latency roughly flat —
-                # and backlog-aware: a deep pending queue promotes the bucket so
-                # the burst takes fewer, larger dispatches.
                 backlog_rows = sum(s.prompt_len - s.prefill_pos for s in pending)
-                chunks = []
-                bucket = 0
-                for s in pending:
-                    limit = self.config.chunk_len_for(
-                        s.prefill_pos, backlog_rows=backlog_rows
-                    )
-                    end = min(s.prefill_pos + limit, s.prompt_len)
-                    cand = self.config.bucket_for(max(bucket, end - s.prefill_pos))
-                    if chunks and len(chunks) + 1 > self.config.lanes_for(cand):
-                        break
-                    if self.grouped and not self._chunk_pages(s, end, outputs):
-                        continue
-                    chunks.append((s, s.prefill_pos, end))
-                    bucket = cand
-                # a later lane's page pressure may have preempted an earlier one
-                chunks = [c for c in chunks if self.slots[c[0].slot] is c[0] and not c[0].finished]
+                # two packers, chosen by what the model carries from row to
+                # row, because the needs conflict: a recurrent state flows
+                # ALONG a lane, so each lane is one whole chunk of another
+                # sequence (the rectangle); where the page cache is all a
+                # prefill carries, a lane is a mere block of rows, several of
+                # them may belong to one sequence, and the pack computes the
+                # rows it holds
+                if self.runner.recurrent:
+                    chunks, bucket, N = self._pack_rectangle(pending, backlog_rows, outputs)
+                    pieces = chunks
+                else:
+                    chunks, pieces = self._pack_blocks(pending, backlog_rows, outputs)
+                    bucket, N = self.config.prefill_block, len(pieces)
                 if not chunks:
                     return count
-                lanes_max = self.config.lanes_for(bucket)
-                # lone chunks ride the packed trace at N=1 too: measured 33%
-                # faster than the per-request trace for identical work (r5
-                # on-chip, 512-row call: 11.3 vs 16.8 ms). N rounds up to a
-                # power of two so partial packs compile at most log2(lanes_max)
-                # executables per bucket, padding <= 2x on the rare odd sizes.
                 lanes = []
                 finals = []  # (seq, lane_idx)
                 want_lp = False
-                for j, (seq, start, end) in enumerate(chunks):
+                for j, (seq, start, end) in enumerate(pieces):
                     is_final = end == seq.prompt_len
                     lanes.append((
                         np.asarray(seq.req.token_ids[start:end], np.int32),
@@ -1563,7 +1571,6 @@ class Scheduler:
                     max(s.page_table.shape[-1] for s, _, _ in chunks)
                 )
                 self._count_table_dispatch(width)
-                N = min(lanes_max, 1 << (len(chunks) - 1).bit_length())
                 rec = self.anatomy.begin(
                     "prefill_packed", ts=prep.t0,
                     # cost split: each sequence pays for its own rows in the pack
@@ -1576,14 +1583,15 @@ class Scheduler:
                     trace_id=chunks[0][0].req.trace_id,
                     rows=rows, lanes=N, tile=prefill_tiles.get(width, 0),
                     # what the pack holds: the rows the program computes, and
-                    # the context already in the cache under its chunks
-                    padded=N * self.config.bucket_for(max(end - start for _, start, end in chunks)),
+                    # the context already in the cache under its chunks (one
+                    # per sequence: a chunk's later blocks add nothing)
+                    padded=N * bucket,
                     ctx=sum(start for _, start, _ in chunks),
                     packed=True, finals=len(finals),
                     windows_ahead=self._note_windows_ahead(),
                 ) as ph:
                     result = self.runner.prefill_chunk_batch(
-                        lanes, N=N, want_logprobs=want_lp
+                        lanes, N=N, want_logprobs=want_lp, bucket=bucket
                     )
             except Exception:
                 log.exception(
@@ -1594,6 +1602,7 @@ class Scheduler:
                     outputs.extend(self._finish(seq, "error"))
                 continue
             self.stage.prefill_rows += rows
+            self.stage.prefill_padded_rows += N * bucket
             self.anatomy.note_steps(rec, tokens=rows, participants=len(chunks))
             self.anatomy.note_prefill_floor(rec, rows)
             for j, (seq, start, end) in enumerate(chunks):
@@ -1616,6 +1625,68 @@ class Scheduler:
                 rec=rec,
             ))
             count += 1
+
+    def _pack_rectangle(self, pending: list, backlog_rows: int, outputs: list[StepOutput]):
+        """The pack of a model with recurrent layers: one whole chunk a lane,
+        every lane another sequence, all padded to the bucket of the longest.
+        Returns (chunks [(seq, start, end)], bucket, N)."""
+        # greedy bucket-aware packing in admission order: grow the lane
+        # set while every taken lane still fits the (possibly enlarged)
+        # bucket's row budget — one long head chunk goes alone, short
+        # chunks pack together. Each lane's chunk length is depth-aware:
+        # chunk_len_for shrinks it as that sequence's prefill advances
+        # into a long prompt, keeping per-chunk latency roughly flat —
+        # and backlog-aware: a deep pending queue promotes the bucket so
+        # the burst takes fewer, larger dispatches.
+        chunks = []
+        bucket = 0
+        for s in pending:
+            limit = self.config.chunk_len_for(s.prefill_pos, backlog_rows=backlog_rows)
+            end = min(s.prefill_pos + limit, s.prompt_len)
+            cand = self.config.bucket_for(max(bucket, end - s.prefill_pos))
+            if chunks and len(chunks) + 1 > self.config.lanes_for(cand):
+                break
+            if self.grouped and not self._chunk_pages(s, end, outputs):
+                continue
+            chunks.append((s, s.prefill_pos, end))
+            bucket = cand
+        # a later lane's page pressure may have preempted an earlier one
+        chunks = [c for c in chunks if self.slots[c[0].slot] is c[0] and not c[0].finished]
+        if not chunks:
+            return [], 0, 0
+        # N rounds up to a power of two so partial packs compile at most
+        # log2(lanes_max) executables per bucket, padding <= 2x on the rare
+        # odd sizes
+        N = min(self.config.lanes_for(bucket), 1 << (len(chunks) - 1).bit_length())
+        return chunks, bucket, N
+
+    def _pack_blocks(self, pending: list, backlog_rows: int, outputs: list[StepOutput]):
+        """The pack of every other model (`plan_block_pack`): blocks of
+        `config.prefill_block` rows, `config.pack_blocks` of them at most,
+        filled in admission order. A sequence gives its next chunk
+        (`chunk_len_for`'s length, in whole blocks), which rides as so many
+        lanes with the sequence's page table and start positions one block
+        apart: the layer scatters every lane's new rows before any lane's
+        attention reads the pages. A lone chunk takes this path too (the
+        packed program at N = its blocks). Returns (chunks [(seq, start, end)],
+        one per sequence, and blocks [(seq, start, end)], one per lane)."""
+        block = self.config.prefill_block
+        asked = []
+        for s in pending[: self.config.pack_blocks]:
+            limit = self.config.chunk_len_for(s.prefill_pos, backlog_rows=backlog_rows)
+            limit = -(-limit // block) * block  # a limit under one block is one block
+            asked.append((s.prefill_pos, min(s.prefill_pos + limit, s.prompt_len)))
+        plan = plan_block_pack(asked, block, self.config.pack_blocks)
+        ends = {i: end for i, _, end in plan}  # each chunk's (cut) end: its last block's
+        if self.grouped:
+            for i, end in ends.items():
+                self._chunk_pages(pending[i], end, outputs)
+        # page pressure may have preempted or finished any sequence of the plan
+        live = {i for i in ends if self.slots[pending[i].slot] is pending[i]
+                and not pending[i].finished}
+        chunks = [(pending[i], pending[i].prefill_pos, ends[i]) for i in sorted(live)]
+        blocks = [(pending[i], start, end) for i, start, end in plan if i in live]
+        return chunks, blocks
 
     def _chunk_pages(self, seq: RunningSeq, end: int, outputs: list[StepOutput]) -> bool:
         """Layer groups: a window group's pages are taken chunk by chunk, so
@@ -1735,6 +1806,7 @@ class Scheduler:
                 chunks.append((start, end))
                 start = end
         self.anatomy.add_phase(rec, "host_prep", host_prep.dt)
+        padded = sum(self.config.bucket_for(end - start) for start, end in chunks)
         # everything past host_prep is dispatch time (sync=True chains block
         # per chunk, so device wait folds into the same phase here)
         with self.anatomy.phase(
@@ -1742,7 +1814,7 @@ class Scheduler:
             rows=rows, tile=prefill_tiles.get(width, 0), cached=cached_len, sync=sync,
             # what the chunks hold: the rows their programs compute, and the
             # context already in the cache when each starts
-            padded=sum(self.config.bucket_for(end - start) for start, end in chunks),
+            padded=padded,
             ctx=sum(start for start, _ in chunks),
             windows_ahead=self._note_windows_ahead(),
         ):
@@ -1776,6 +1848,7 @@ class Scheduler:
                 if on_chunk is not None:
                     on_chunk(start, end)
         self.stage.prefill_rows += rows
+        self.stage.prefill_padded_rows += padded
         self.anatomy.note_steps(rec, tokens=rows, participants=1)
         self.anatomy.note_prefill_floor(rec, rows)
         return first_token

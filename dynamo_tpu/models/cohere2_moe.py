@@ -315,7 +315,10 @@ class Cohere2MoeModel:
     # ---------------- forward ----------------
 
     def prefill_packed(self, params, kv_cache, tokens, positions, page_tables, valid, last_idx):
-        """N lanes (chunks of N different sequences) through every layer.
+        """N lanes through every layer: each T consecutive rows of one
+        sequence under that sequence's page tables, several of them one
+        sequence's where its chunk rides as blocks (every layer scatters all
+        lanes' rows before any lane's attention reads the pages).
         Returns (logits [N, V], cache)."""
         c = self.config
         N, T = tokens.shape

@@ -664,14 +664,17 @@ class LlamaModel:
         lora: dict | None = None,  # slot-stacked adapter pool
         lora_ids: jnp.ndarray | None = None,  # [N] per-lane adapter slots
     ) -> tuple[jnp.ndarray, dict]:
-        """Cross-request packed prefill: N lanes (chunks of N DIFFERENT
-        sequences) flattened into one [N*T] token stream so the layer matmuls
-        read the weights ONCE per call instead of once per request — the
-        per-call overhead and weight traffic of N short prefills for the
-        price of one (the reference's engines batch prefills the same way;
-        vLLM scheduler: SURVEY.md §2.4). Lanes must belong to distinct
-        sequences (chunk i+1 of one sequence reads pages chunk i wrote, so
-        same-sequence chunks go in consecutive calls, never one call).
+        """Cross-request packed prefill: N lanes flattened into one [N*T]
+        token stream so the layer matmuls read the weights ONCE per call
+        instead of once per request — the per-call overhead and weight
+        traffic of N short prefills for the price of one (the reference's
+        engines batch prefills the same way; vLLM scheduler: SURVEY.md §2.4).
+        A lane is T consecutive rows of one sequence with that sequence's
+        page table. Lanes need NOT be distinct sequences: every layer
+        scatters all lanes' new rows into the pages before any lane's
+        attention reads them, so a sequence's chunk rides as consecutive
+        lanes one block apart (the scheduler's block packer), each reading
+        the blocks before it back from the pages like any older context.
 
         Returns (logits [N, V] at each lane's last_idx, updated kv_cache)."""
         N, T = tokens.shape
@@ -697,7 +700,11 @@ class LlamaModel:
         """Shared N-lane layer stack for prefill_packed and verify: one weight
         pass over the flattened [N*T] token stream, per-lane paged attention.
         A mixed-adapter pack broadcasts each lane's slot id over its tokens —
-        one gathered dispatch, not N per-adapter calls.
+        one gathered dispatch, not N per-adapter calls. The attention is N
+        copies of the kernel call, not one instance under `jax.lax.map` (as
+        models/cohere2_moe.py has it for 128 query heads): at 16 heads 8
+        copies compile in 0.4 s more a program and run 1.6-2.5% faster a pack
+        (tools/profile_prefill_pack.py, PERF.md section 5, PR 40).
         Returns (hidden [N*T, D], updated kv_cache)."""
         c = self.config
         k_pool, v_pool = kv_cache["k"], kv_cache["v"]

@@ -66,7 +66,9 @@ DECLARED_METRIC_FAMILIES: tuple = (
     "dynamo_engine_preemptions_total",
     "dynamo_engine_prefill_dispatches_total",
     "dynamo_engine_prefill_hold_seconds",
+    "dynamo_engine_prefill_padded_rows_total",
     "dynamo_engine_prefill_roofline_fraction",
+    "dynamo_engine_prefill_rows_total",
     "dynamo_engine_prefill_seconds",
     "dynamo_engine_prefill_windows_ahead_total",
     "dynamo_engine_prefix_cache_blocks_total",

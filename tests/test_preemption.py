@@ -129,9 +129,11 @@ def test_prefill_burst_interleaves_with_running_decode():
             while not tags or tags[-1] != "window":
                 await asyncio.sleep(0.01)
             burst_from = len(tags)
-            # ...then a 6-request burst (3 packed prefill calls at 2 lanes)
+            # ...then a 6-request burst: 28 tokens are 4 blocks of 8 rows, a
+            # pack holds 8 blocks, so 3 packed prefill calls of 2 prompts
             rng_prompts = [[i + 1, 50 + i, 60 + i, 70 + i, 80 + i, 90 + i,
-                            30 + i, 40 + i, 20 + i, 10 + i, 3, 4] for i in range(6)]
+                            30 + i, 40 + i, 20 + i, 10 + i, 3, 4]
+                           + [100 + i + j for j in range(16)] for i in range(6)]
             burst = await asyncio.gather(*[
                 run_req(f"b{i}", rng_prompts[i], 4) for i in range(6)
             ])

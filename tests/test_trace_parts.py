@@ -373,20 +373,22 @@ def _prefill_spans(eng, requests, steps=10):
 
 @pytest.mark.parametrize("path", ["packed", "chunked"])
 def test_prefill_dispatch_spans_say_what_they_hold(path):
-    """`padded`: the rows the program computes (lanes x chunk bucket, or the
-    chunk buckets summed); `ctx`: the tokens already in the cache when each
-    chunk starts, summed. 70 tokens take chunks of 32, 32 and 6 (buckets 32,
-    32, 8); 12 tokens take one chunk of 12 (bucket 16)."""
+    """`padded`: the rows the program computes (a pack's blocks of 8 rows, or
+    the chunk buckets summed); `ctx`: the tokens already in the cache when
+    each chunk starts, summed over the chunks (one per sequence, however many
+    blocks it rides as). 70 tokens take chunks of 32, 32 and 6 (buckets 32,
+    32, 8; 4, 4 and 1 blocks); 12 tokens take one chunk of 12 (bucket 16; 2
+    blocks)."""
     eng = _hand_engine(prefill_lanes=2 if path == "packed" else 1)
     spans, preps = _prefill_spans(eng, [_request("long", 70), _request("short", 12)])
     args = [{k: e["args"].get(k) for k in ("rows", "lanes", "padded", "ctx")} for e in spans]
     assert all(a["padded"] >= a["rows"] > 0 and a["ctx"] >= 0 for a in args)
     assert sum(a["rows"] for a in args) == 82
     if path == "packed":
-        # the first pack holds both prompts' first chunks in two lanes of 32
-        assert args[0] == {"rows": 44, "lanes": 2, "padded": 64, "ctx": 0}
+        # the first pack holds both prompts' first chunks in 4 + 2 blocks of 8
+        assert args[0] == {"rows": 44, "lanes": 6, "padded": 48, "ctx": 0}
         # then the long prompt alone: 32 rows on 32 cached, 6 rows on 64
-        assert args[1:] == [{"rows": 32, "lanes": 1, "padded": 32, "ctx": 32},
+        assert args[1:] == [{"rows": 32, "lanes": 4, "padded": 32, "ctx": 32},
                             {"rows": 6, "lanes": 1, "padded": 8, "ctx": 64}]
         assert {e["name"] for e in preps} >= {"engine.prefill_packed.host_prep", "engine.decode_window.host_prep"}
     else:
