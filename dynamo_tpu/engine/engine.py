@@ -1184,6 +1184,7 @@ class AsyncJaxEngine:
             "hbm_state_bytes": runner.model.state_bytes(self.config.max_seqs) if recurrent else 0,
             "moe_assignments": sched.moe_assignments,
             "moe_routed": sched.moe_routed,
+            "moe_experts_touched": sched.moe_experts_touched,
             "moe_busiest_over_mean": round(sched.moe_busiest_over_mean, 4),
             # fleet prefix cache: remote pulls this engine issued (requester
             # side; the pull SERVER's counters ride the worker's kv_pull stats)
@@ -1671,6 +1672,12 @@ class AsyncJaxEngine:
                 "decode-step expert assignments routed: tokens x experts a "
                 "token x expert blocks, held here or not",
                 [({}, r["moe_routed"])],
+            ),
+            render_family(
+                "dynamo_engine_moe_experts_touched_total", "counter",
+                "held experts that received a row, summed over decode steps "
+                "and expert layers (0 for a model without expert layers)",
+                [({}, r["moe_experts_touched"])],
             ),
             render_family(
                 "dynamo_engine_moe_busiest_over_mean", "gauge",

@@ -276,8 +276,9 @@ class ModelRunner:
             cache.update(model.init_state_cache(config.max_seqs))
             kv_sharding = dict(kv_sharding, **model.state_cache_sharding(mesh))
         self.kv_cache = jax.device_put(cache, kv_sharding)
-        #: the last decode window's extra device output (the held experts'
-        #: assignment counts of a model that routes), or None
+        #: the last decode window's extra device output (the leaves a model
+        #: names in `window_counters`: a routing model's `moe_counts` and
+        #: `moe_touched`), or None
         self.window_aux = None
         self._replicated = NamedSharding(mesh, P())
         self._key = jax.random.key(0)
@@ -798,9 +799,11 @@ class ModelRunner:
         temps, top_ps, min_ps = flts[0], flts[1], flts[2]
         pres, freq, reps = flts[3], flts[4], flts[5]
         keys = jax.random.split(key, num_steps)
-        if "moe_counts" in kv:
-            # the window's own count: the steps below add to it
-            kv = dict(kv, moe_counts=jnp.zeros_like(kv["moe_counts"]))
+        # what a model that routes counts over the window, by its own
+        # declaration (`window_counters`: leaves of its state cache): zeroed
+        # here, the steps below add
+        counters = getattr(self.model, "window_counters", ())
+        kv = dict(kv, **{k: jnp.zeros_like(kv[k]) for k in counters})
 
         def body(carry, k):
             kv, st, positions, act = carry
@@ -855,7 +858,7 @@ class ModelRunner:
         lp = (ys[1], ys[2], ys[3]) if want_lp else None
         # [num_steps, B] tokens (+ ([num_steps, B], [num_steps, B, K] x2) lp);
         # last, what the model counted over the window (None: an empty output)
-        return all_toks, lp, kv, slot_state, kv.get("moe_counts")
+        return all_toks, lp, kv, slot_state, {k: kv[k] for k in counters} or None
 
     def _verify_impl(self, params, kv, ints, flts, key, draft_probs=None, lora=None):
         """Speculative verify step: every slot feeds its anchor token plus up
@@ -1262,8 +1265,8 @@ class ModelRunner:
             if want_logprobs:
                 for a in lp:
                     a.copy_to_host_async()
-            if self.window_aux is not None:
-                self.window_aux.copy_to_host_async()
+            for a in jax.tree.leaves(self.window_aux):
+                a.copy_to_host_async()
         except Exception:
             pass
         return (toks, lp) if want_logprobs else toks

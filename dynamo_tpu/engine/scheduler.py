@@ -549,8 +549,11 @@ class Scheduler:
         # expert routing, from the counts a routing model returns per decode
         # window (runner.window_aux): assignments that landed on experts held
         # here, all assignments routed (tokens x experts a token x expert
-        # blocks), and the last window's busiest held expert over their mean
+        # blocks), the last window's busiest held expert over their mean, and
+        # the held experts that received a row, summed over steps and expert
+        # layers: the matrices a step had to stream
         self.moe_assignments = 0
+        self.moe_experts_touched = 0
         self.moe_routed = 0
         self.moe_busiest_over_mean = 0.0
         self.qos_preempted: dict[str, int] = {}
@@ -2533,9 +2536,10 @@ class Scheduler:
 
     def _count_routing(self, entry: "_InFlight") -> None:
         """One decode window's expert routing, from the device's counts."""
-        counts = np.asarray(entry.aux)
+        counts = np.asarray(entry.aux["moe_counts"])
         tokens = sum(steps for _, _, steps in entry.seqs)
         self.moe_assignments += int(counts.sum())
+        self.moe_experts_touched += int(np.asarray(entry.aux["moe_touched"]).sum())
         self.moe_routed += tokens * self.runner.model.config.routed_per_token
         mean = float(counts.mean())
         self.moe_busiest_over_mean = float(counts.max()) / mean if mean else 0.0

@@ -236,14 +236,21 @@ class Cohere2MoeModel:
 
     # ---------------- what rides beside the pool ----------------
 
+    #: the state-cache leaves a decode window zeroes, adds to and hands back
+    window_counters = ("moe_counts", "moe_touched")
+
     def init_state_cache(self, max_seqs: int) -> dict:
-        """`moe_counts`: where decode steps add the assignments each held
-        expert received (the engine zeroes it at the start of a decode window
-        and reads it at the end)."""
-        return {"moe_counts": jnp.zeros((self.config.num_experts,), jnp.int32)}
+        """The `window_counters`: `moe_counts`, where decode steps add the
+        assignments each held expert received, and `moe_touched`, where they
+        add the number of (layer, held expert) pairs that received a row (the
+        engine zeroes both at the start of a decode window and reads them at
+        the end)."""
+        return {"moe_counts": jnp.zeros((self.config.num_experts,), jnp.int32),
+                "moe_touched": jnp.zeros((1,), jnp.int32)}
 
     def state_cache_sharding(self, mesh: Mesh) -> dict:
-        return {"moe_counts": NamedSharding(mesh, P())}
+        ns = NamedSharding(mesh, P())
+        return {"moe_counts": ns, "moe_touched": ns}
 
     # ---------------- blocks ----------------
 
@@ -385,7 +392,8 @@ class Cohere2MoeModel:
 
         with jax.named_scope("embed"):
             hidden = params["embed"][tokens].astype(c.dtype)
-        counts = cache.get("moe_counts")  # absent where no engine keeps it
+        # absent where no engine keeps them
+        counts, touched = cache.get("moe_counts"), cache.get("moe_touched")
         for l, (kind, lp) in enumerate(zip(c.layer_types, params["layers"])):
             table = tables[l]
             with jax.named_scope("attn_kv"):
@@ -399,9 +407,11 @@ class Cohere2MoeModel:
             n = layer_norm(hidden, lp["norm"], c.layer_norm_eps)
             attn, cache = self._attention(lp, kind, n, cache, positions, phys, offsets, attn_fn)
             ffn, got = self._experts(lp, n, count_rows=active)
-            counts = None if counts is None else counts + got
+            if counts is not None:
+                counts = counts + got
+                touched = touched + jnp.sum(got > 0, dtype=jnp.int32)
             with jax.named_scope("attn_proj"):  # the residual add of a parallel block
                 hidden = hidden + attn + ffn
         if counts is not None:
-            cache = dict(cache, moe_counts=counts)
+            cache = dict(cache, moe_counts=counts, moe_touched=touched)
         return self._unembed(params, hidden), cache

@@ -505,3 +505,73 @@ def load_cohere2_moe_weights(model, path: Path) -> dict:
     if missing:
         raise ValueError(f"checkpoint {path} lacks {sorted(missing)}")
     return arrays
+
+
+def load_lfm2_moe_weights(model, path: Path) -> dict:
+    """LFM2-MoE (`model.layers.N.{operator_norm, ffn_norm, conv.*, self_attn.*,
+    feed_forward.*}`, `model.embedding_norm`; the head is the embedding):
+    blocks are a list, the leaves are filled in their own dtype on a pool of
+    threads (as `load_nemotron_h_weights`: the expert banks are gigabytes).
+    `conv.in_proj`'s output thirds are B, C, x in that order; `conv.conv.weight`
+    [C, 1, K] goes taps first. Experts are read from `feed_forward.experts.0 ..`
+    up to the count the config holds."""
+    import os
+    from collections import deque
+    from concurrent.futures import ThreadPoolExecutor
+
+    shapes = jax.eval_shape(model.init_params, jax.random.key(0))
+    arrays = jax.tree.map(lambda s: np.zeros(s.shape, s.dtype), shapes)
+    per_block = {
+        "operator_norm.weight": ("op_norm", False), "ffn_norm.weight": ("ffn_norm", False),
+        "conv.in_proj.weight": ("in_proj", True), "conv.out_proj.weight": ("out_proj", True),
+        "self_attn.q_proj.weight": ("wq", True), "self_attn.k_proj.weight": ("wk", True),
+        "self_attn.v_proj.weight": ("wv", True), "self_attn.out_proj.weight": ("wo", True),
+        "self_attn.q_layernorm.weight": ("q_norm", False),
+        "self_attn.k_layernorm.weight": ("k_norm", False),
+        "feed_forward.w1.weight": ("w1", True), "feed_forward.w2.weight": ("w2", True),
+        "feed_forward.w3.weight": ("w3", True),
+        "feed_forward.gate.weight": ("router", True),
+        "feed_forward.expert_bias": ("router_bias", False),
+    }
+    top = {"model.embed_tokens.weight": "embed", "model.embedding_norm.weight": "final_norm"}
+    blocks = arrays["blocks"]
+    filled = set()
+    pending: deque = deque()
+    with ThreadPoolExecutor(min(16, os.cpu_count() or 4)) as pool:
+
+        def put(dest: np.ndarray, src: np.ndarray) -> None:
+            pending.append(pool.submit(dest.__setitem__, ..., src))
+            if len(pending) > 256:  # bounds the tensors read and not yet copied
+                pending.popleft().result()
+
+        for name, tensor in _iter_checkpoint_tensors(path):
+            if name in top:
+                put(arrays[top[name]], tensor)
+                filled.add(top[name])
+                continue
+            if not name.startswith("model.layers."):
+                log.debug("skipping unmapped weight %s", name)
+                continue
+            layer_str, sub = name[len("model.layers."):].split(".", 1)
+            if int(layer_str) >= len(blocks):
+                continue
+            bp = blocks[int(layer_str)]
+            if sub == "conv.conv.weight":  # [C, 1, K] -> taps first
+                put(bp["conv_w"], tensor[:, 0, :].T)
+            elif sub.startswith("feed_forward.experts."):
+                e_str, which = sub[len("feed_forward.experts."):].split(".", 1)
+                key = which[: -len(".weight")]
+                if key in ("w1", "w2", "w3") and int(e_str) < bp[key].shape[0]:
+                    put(bp[key][int(e_str)], tensor.T)
+            else:
+                key, transpose = per_block.get(sub, (None, False))
+                if key is None or key not in bp:
+                    log.debug("skipping unmapped weight %s", name)
+                    continue
+                put(bp[key], tensor.T if transpose else tensor)
+        for done in pending:
+            done.result()
+    missing = {"embed", "final_norm"} - filled
+    if missing:
+        raise ValueError(f"checkpoint {path} lacks {sorted(missing)}")
+    return arrays

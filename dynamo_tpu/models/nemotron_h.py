@@ -251,12 +251,17 @@ class NemotronHModel:
 
     # ---------------- the per-slot state cache (Mamba blocks) ----------------
 
+    #: the state-cache leaves a decode window zeroes, adds to and hands back
+    window_counters = ("moe_counts", "moe_touched")
+
     def init_state_cache(self, max_seqs: int) -> dict:
         """The leaves the engine keeps beside the KV pools, in the same
         donated bundle: `ssm` and `conv`, a row per (Mamba block, slot) plus
-        each block's trash row, and `moe_counts`, where decode steps add the
-        assignments each held expert received (the engine zeroes it at the
-        start of a decode window and reads it at the end)."""
+        each block's trash row, and the `window_counters`: `moe_counts`, where
+        decode steps add the assignments each held expert received, and
+        `moe_touched`, where they add the number of (expert block, held
+        expert) pairs that received a row (the engine zeroes both at the start
+        of a decode window and reads them at the end)."""
         c = self.config
         rows = c.count("M") * (max_seqs + 1)
         return {
@@ -265,11 +270,12 @@ class NemotronHModel:
             ),
             "conv": jnp.zeros((rows, c.conv_kernel - 1, c.conv_dim), c.dtype),
             "moe_counts": jnp.zeros((c.n_routed_experts,), jnp.int32),
+            "moe_touched": jnp.zeros((1,), jnp.int32),
         }
 
     def state_cache_sharding(self, mesh: Mesh) -> dict:
         ns = NamedSharding(mesh, P())
-        return {"ssm": ns, "conv": ns, "moe_counts": ns}
+        return {"ssm": ns, "conv": ns, "moe_counts": ns, "moe_touched": ns}
 
     def state_bytes(self, max_seqs: int) -> int:
         """Device bytes of the state cache at this many slots."""
@@ -511,7 +517,7 @@ class NemotronHModel:
 
         with jax.named_scope("embed"):
             hidden = params["embed"][tokens].astype(c.dtype)
-        counts = cache["moe_counts"]
+        counts, touched = cache["moe_counts"], cache["moe_touched"]
         m = a = 0
         for kind, bp in zip(c.pattern, params["blocks"]):
             h = rms_norm(hidden, bp["norm"], c.rms_norm_eps)
@@ -531,6 +537,7 @@ class NemotronHModel:
             else:
                 out, n = self._experts(bp, h, count_rows=active)
                 counts = counts + n
+                touched = touched + jnp.sum(n > 0, dtype=jnp.int32)
             with jax.named_scope(self.RESIDUAL_PART[kind]):
                 hidden = hidden + out
-        return self._unembed(params, hidden), dict(cache, moe_counts=counts)
+        return self._unembed(params, hidden), dict(cache, moe_counts=counts, moe_touched=touched)

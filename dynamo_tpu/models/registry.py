@@ -2,8 +2,8 @@
 
 Supported ids:
   - ``tiny`` / ``tiny:<json-overrides>``: random-weight test model (and
-    ``tiny-moe``, ``tiny-mla``, ``tiny-vl``, ``tiny-hybrid``, ``tiny-window``
-    likewise)
+    ``tiny-moe``, ``tiny-mla``, ``tiny-vl``, ``tiny-hybrid``, ``tiny-window``,
+    ``tiny-conv`` likewise)
   - a local HuggingFace checkpoint directory (config.json [+ safetensors])
 
 The reference resolves models from HF repos via its model-deployment-card
@@ -52,6 +52,16 @@ ARCHITECTURES = {
         "nemotron_h", "NemotronHConfig", "NemotronHModel", "load_nemotron_h_weights"),
     "Cohere2MoeForCausalLM": (
         "cohere2_moe", "Cohere2MoeConfig", "Cohere2MoeModel", "load_cohere2_moe_weights"),
+    "Lfm2MoeForCausalLM": (
+        "lfm2_moe", "Lfm2MoeConfig", "Lfm2MoeModel", "load_lfm2_moe_weights"),
+}
+
+
+#: the tiny families whose config class has a `tiny(**overrides)`
+_TINY_FAMILIES = {
+    "tiny-hybrid": ("nemotron_h", "NemotronHConfig", "NemotronHModel"),
+    "tiny-window": ("cohere2_moe", "Cohere2MoeConfig", "Cohere2MoeModel"),
+    "tiny-conv": ("lfm2_moe", "Lfm2MoeConfig", "Lfm2MoeModel"),
 }
 
 
@@ -75,7 +85,7 @@ def is_tiny_family(model_id) -> bool:
     if model_id is None:
         return True
     s = str(model_id)
-    for fam in ("tiny", "tiny-moe", "tiny-mla", "tiny-vl", "tiny-hybrid", "tiny-window"):
+    for fam in ("tiny", "tiny-moe", "tiny-mla", "tiny-vl", *_TINY_FAMILIES):
         if s == fam or s.startswith(fam + ":"):
             return True
     return False
@@ -161,23 +171,16 @@ def _load_model_uncached(model_id: str, seed: int = 0, quantize: str | None = No
         jax.block_until_ready(params)
         return model, params
 
-    if model_id is not None and (model_id == "tiny-hybrid" or model_id.startswith("tiny-hybrid:")):
-        from dynamo_tpu.models.nemotron_h import NemotronHConfig, NemotronHModel
+    for family, (module, config_cls, model_cls) in _TINY_FAMILIES.items():
+        if model_id is not None and (model_id == family or model_id.startswith(family + ":")):
+            import importlib
 
-        overrides = json.loads(model_id.split(":", 1)[1]) if ":" in model_id else {}
-        model = NemotronHModel(with_quant(NemotronHConfig.tiny(**overrides)))
-        params = jax.jit(model.init_params)(jax.random.key(seed))
-        jax.block_until_ready(params)
-        return model, params
-
-    if model_id is not None and (model_id == "tiny-window" or model_id.startswith("tiny-window:")):
-        from dynamo_tpu.models.cohere2_moe import Cohere2MoeConfig, Cohere2MoeModel
-
-        overrides = json.loads(model_id.split(":", 1)[1]) if ":" in model_id else {}
-        model = Cohere2MoeModel(with_quant(Cohere2MoeConfig.tiny(**overrides)))
-        params = jax.jit(model.init_params)(jax.random.key(seed))
-        jax.block_until_ready(params)
-        return model, params
+            mod = importlib.import_module(f"dynamo_tpu.models.{module}")
+            overrides = json.loads(model_id.split(":", 1)[1]) if ":" in model_id else {}
+            model = getattr(mod, model_cls)(with_quant(getattr(mod, config_cls).tiny(**overrides)))
+            params = jax.jit(model.init_params)(jax.random.key(seed))
+            jax.block_until_ready(params)
+            return model, params
 
     if model_id is not None and (model_id == "tiny-vl" or model_id.startswith("tiny-vl:")):
         from dynamo_tpu.models.qwen2_vl import Qwen2VLConfig, Qwen2VLModel

@@ -340,7 +340,10 @@ def dispatch_paged_decode_attention(q, k_pages, v_pages, page_tables, positions,
     # One kernel per shape class, chosen from the shapes alone.
     # folded: the pool is folded or head_dim is under a lane row (Mosaic
     # can't DMA-slice sub-128-lane pools; heads live folded into the lane
-    # dim); still a page at a time.
+    # dim); still a page at a time: 0.38-0.41 us for every page of 16 x 512
+    # bf16 (K and V, 16 KiB each), a tenth of the HBM roofline at batches of
+    # 64 to 256 and contexts of 512 to 4096 (tools/profile_folded_attention.py
+    # on a v5e, PR 42).
     # lookahead: one sequence per grid program, a TILE of pages (128 context
     # tokens) per loop iteration, cross-program prefetch of the next
     # sequence's first tiles; tile width and window follow from the shapes
@@ -423,7 +426,11 @@ def dispatch_paged_prefill_attention(
     ``window`` W > 0 is a sliding-window layer (see the decode dispatcher):
     the unfolded kernel masks by (p - W, p] per query row, skips the key tiles
     wholly behind every row's window and carries a name of its own."""
-    from dynamo_tpu.ops.pallas.prefill_attention import prefill_block_q
+    from dynamo_tpu.ops.pallas.prefill_attention import (
+        folded_prefill_block_q,
+        folded_prefill_fits,
+        prefill_block_q,
+    )
 
     T, Hq, D = q.shape
     folded = k_pages.ndim == 3
@@ -433,12 +440,12 @@ def dispatch_paged_prefill_attention(
     if window:
         shape += f" window={window}"
     if folded:
-        block_q = 64
         # the folded kernel's working set is several [R, F] f32 buffers per
-        # head shard (R = block_q * Hq rows, F folded lanes); keep their sum
-        # inside scoped VMEM (R*F*4B*~5 buffers)
-        R, F = block_q * Hq // tp, k_pages.shape[2] // tp
-        fits = F % 128 == 0 and R * F * 4 * 5 <= 12 * 1024 * 1024
+        # head shard (R = block_q * Hq rows, F folded lanes); block_q follows
+        # from the head count so that their sum stays inside scoped VMEM
+        heads, F = Hq // tp, k_pages.shape[2] // tp
+        block_q = folded_prefill_block_q(heads, F)
+        fits = F % 128 == 0 and folded_prefill_fits(block_q, heads, F)
         enabled = _pallas_enabled(True)
     else:
         block_q = prefill_block_q(Hq // tp if Hq % tp == 0 else Hq)
@@ -470,7 +477,7 @@ def dispatch_paged_prefill_attention(
         fn = functools.partial(
             paged_prefill_attention_pallas_folded, block_q=block_q, interpret=interpret
         )
-        path = "pallas:folded"
+        path = f"pallas:folded block_q={block_q}"
     else:
         fn = functools.partial(
             paged_prefill_attention_pallas, block_q=block_q, interpret=interpret, window=window
@@ -483,9 +490,7 @@ def dispatch_paged_prefill_attention(
             ps, tile_pages, num_kv_heads // tp, D, k_pages.dtype.itemsize
         )
         path = "pallas:" + ("lookahead" if ahead else "basic")
-        if block_q != 128:
-            path += f" block_q={block_q}"
-        path += f" tile={tile_pages * ps}"
+        path += f" block_q={block_q} tile={tile_pages * ps}"
         prefill_tiles[page_table.shape[0]] = tile_pages * ps
     if interpret:
         path += " interpret"

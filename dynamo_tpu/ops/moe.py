@@ -70,14 +70,19 @@ def sigmoid_topk_routing(
     bias: jnp.ndarray,  # [E] e_score_correction_bias: moves the choice only
     k: int,
     scale: float = 1.0,
+    eps: float = 1e-20,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Sigmoid routing with a selection bias (no group limit):
     ``s = sigmoid(logits)``; chosen = top-k of ``s + bias``;
-    ``w = scale * s_chosen / (sum of s over the chosen + 1e-20)``."""
+    ``w = scale * s_chosen / (sum of s over the chosen + eps)``.
+    ``eps`` is the published model's: 1e-20 for NemotronH and Cohere2-MoE
+    (models/nemotron_h.py, models/cohere2_moe.py: the default, a guard against
+    0/0 and nothing else), 1e-6 for LFM2-MoE (models/lfm2_moe.py), whose
+    weights then sum to a little under ``scale``."""
     s = jax.nn.sigmoid(router_logits)
     _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), k)
     chosen = jnp.take_along_axis(s, idx, axis=-1)
-    weights = scale * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+    weights = scale * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + eps)
     return weights, idx
 
 

@@ -196,6 +196,28 @@ def prefill_block_q(num_q_heads: int) -> int:
     return block_q
 
 
+#: what the folded kernel's [R, F] float32 buffers may take of scoped VMEM
+_FOLDED_WORKING_SET_BYTES = 12 * 1024 * 1024
+
+
+def folded_prefill_block_q(num_q_heads: int, folded_lanes: int) -> int:
+    """Query rows per grid program of the folded kernel: 64, halved while its
+    working set does not fit. The kernel holds about five float32 buffers of
+    [R, F] (R = rows x query heads, F = kv heads x head_dim folded lanes: the
+    zero-placed queries, their mask, the accumulator and a tile's product).
+    TinyLlama's 32 heads over 256 lanes keep 64 rows (10.5 MB); LFM2's 32 heads
+    over 512 lanes (8 kv heads of 64) get 32. A shape that does not fit at 8
+    rows is refused by the dispatcher (`folded_prefill_fits`)."""
+    block_q = 64
+    while block_q > 8 and not folded_prefill_fits(block_q, num_q_heads, folded_lanes):
+        block_q //= 2
+    return block_q
+
+
+def folded_prefill_fits(block_q: int, num_q_heads: int, folded_lanes: int) -> bool:
+    return block_q * num_q_heads * folded_lanes * 4 * 5 <= _FOLDED_WORKING_SET_BYTES
+
+
 #: a table of more context tokens than this is walked in long tiles
 _SHORT_TABLE_TOKENS = 2048
 _LONG_TILE_TOKENS = 512

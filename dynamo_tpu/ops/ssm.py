@@ -30,7 +30,7 @@ def causal_conv(
     x: jnp.ndarray,  # [L, T, C] the lane's new inputs
     window: jnp.ndarray,  # [L, K-1, C] the K-1 inputs before them (zeros at a start)
     w: jnp.ndarray,  # [K, C] tap k multiplies the input K-1-k positions back
-    bias: jnp.ndarray,  # [C]
+    bias: jnp.ndarray | None,  # [C], or None: the convolution has none
     n_valid: jnp.ndarray,  # [L] real tokens in each lane (the rest is padding)
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Depthwise causal convolution that continues from a carried window.
@@ -39,7 +39,9 @@ def causal_conv(
     K, T = w.shape[0], x.shape[1]
     xp = jnp.concatenate([window.astype(jnp.float32), x.astype(jnp.float32)], axis=1)
     wf = w.astype(jnp.float32)
-    y = sum(xp[:, k:k + T] * wf[k] for k in range(K)) + bias.astype(jnp.float32)
+    y = sum(xp[:, k:k + T] * wf[k] for k in range(K))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     # inputs n-K+1 .. n-1 sit at xp[n : n+K-1]; n = 0 gives the old window back
     idx = n_valid[:, None] + jnp.arange(K - 1)[None, :]
     new_window = jnp.take_along_axis(xp, idx[:, :, None], axis=1)

@@ -59,6 +59,7 @@ DECLARED_METRIC_FAMILIES: tuple = (
     "dynamo_engine_kv_window_pages_released_total",
     "dynamo_engine_moe_assignments_total",
     "dynamo_engine_moe_busiest_over_mean",
+    "dynamo_engine_moe_experts_touched_total",
     "dynamo_engine_moe_routed_total",
     "dynamo_engine_offload_blocks_total",
     "dynamo_engine_offload_bytes_resident",

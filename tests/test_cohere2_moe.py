@@ -189,6 +189,7 @@ def test_chunked_packed_prefill_and_decode_match_the_reference_past_the_window(c
             np.testing.assert_allclose(got[0], rb[18 + step], atol=LOGIT_ATOL)
     counts = np.asarray(d.cache["moe_counts"])
     assert 0 < counts.sum() < 13 * 3 * 4  # 13 rows x top-3 x 4 layers, half held
+    assert 0 < int(d.cache["moe_touched"][0]) <= counts.sum()
 
 
 def test_pages_behind_the_window_are_never_read(ckpt, loaded):

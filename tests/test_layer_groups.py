@@ -105,6 +105,8 @@ def test_engine_matches_the_reference(ckpt, case):
     assert snap["kv_pages_active"] == 0  # every page came back
     assert snap["kv_group_pages"]["window"]["active"] == 0
     assert snap["moe_routed"] > snap["moe_assignments"] > 0  # half the experts are held
+    # a (layer, held expert) pair is touched by at least one assignment
+    assert 0 < snap["moe_experts_touched"] <= snap["moe_assignments"]
 
 
 def test_pages_behind_the_window_come_back_while_the_sequence_runs(ckpt):

@@ -285,6 +285,8 @@ def test_engine_matches_the_reference(ckpt, case):
         assert preempted >= 1
     assert snap["state_slots_total"] == 2 and snap["state_slots_active"] == 0
     assert snap["moe_routed"] > snap["moe_assignments"] > 0  # half the experts are held
+    # a (block, held expert) pair is touched by at least one assignment
+    assert 0 < snap["moe_experts_touched"] <= snap["moe_assignments"]
 
 
 def test_no_prefix_hit_for_a_recurrent_model(ckpt):

@@ -248,13 +248,13 @@ def test_a_chunk_as_blocks_of_one_call(model_id):
 # ---------------- (d) warm-up compiles what the packer can emit ----------------
 
 
-@pytest.mark.parametrize("model_id", ["tiny", "tiny-window", "tiny-hybrid"])
+@pytest.mark.parametrize("model_id", ["tiny", "tiny-window", "tiny-hybrid", "tiny-conv"])
 def test_every_program_the_packer_can_emit_is_in_warm_ups_list(model_id):
     """The sets compared, nothing compiled: every (N, T) of the packer on
     every rung of the page-table ladder; for the blocks, all of it on the first
     and the last rung before readiness."""
     over = dict(prefill_buckets=(64, 128, 256, 512), max_model_len=8192, page_size=16, num_pages=64)
-    if model_id != "tiny-hybrid":
+    if model_id not in ("tiny-hybrid", "tiny-conv"):  # the recurrent contract's two signers
         over["prefill_lanes"] = 2  # the blocks take no count from it
     eng = _hand_engine(model_id, **over)
     runner, c = eng.runner, eng.config

@@ -40,6 +40,7 @@ MODELS = {
     "tiny": SHARED | {"mlp"},
     "tiny-hybrid": SHARED | {"ssm_proj", "ssm", "moe_router", "moe_dispatch", "moe_experts", "shared_experts"},
     "tiny-window": SHARED | {"moe_router", "moe_dispatch", "moe_experts", "shared_experts"},
+    "tiny-conv": SHARED | {"mlp", "ssm_proj", "ssm", "moe_router", "moe_dispatch", "moe_experts"},
 }
 STEPS = {"decode_window": "_decode_window", "prefill_packed": "_prefill_packed"}
 

@@ -51,6 +51,8 @@ QWEN25_3B = ("qwen2.5-3b", 16, 2, 128)
 #: command-a-plus-05-2026: 128 query heads over 8 kv heads, a window of 4096
 COMMAND_A = ("command-a-plus", 128, 8, 128)
 COMMAND_A_WINDOW = 4096
+#: LFM2-8B-A1B: 32 query heads over 8 kv heads of 64, folded pools of 512 lanes
+LFM2 = ("lfm2-8b-a1b", 32, 8, 64)
 #: DeepSeek-V2-Lite: 16 heads, kv_lora_rank 512 + rope 64, latent padded to 640
 MLA_HEADS, MLA_DC, MLA_LATENT = 16, 512, 640
 
@@ -221,6 +223,22 @@ GROUPED_MATMUL_CASES = (
 )
 
 
+def folded_cases() -> list[Case]:
+    """`lfm2-8b-a1b-d16`: the folded decode and prefill kernels at 32 query / 8
+    kv heads of 64 (512 folded lanes: the prefill kernel takes 32 query rows a
+    program, `folded_prefill_block_q`), page 16, tables of 5120 tokens, and
+    the grouped product at a bank of [32, 2048, 1792] and back (a decode step
+    of 256 slots, a prefill pack of 1024 rows; 4 rows a token)."""
+    return [
+        _decode_case(LFM2, 16, False, max_len=5120),
+        _prefill_case(LFM2, 16, 128, False, max_len=5120),
+        _prefill_case(LFM2, 16, 1024, False, max_len=5120),
+        _grouped_matmul_case("lfm2-decode-w1", 256 * 4, 2048, 1792, held=32),
+        _grouped_matmul_case("lfm2-decode-w2", 256 * 4, 1792, 2048, held=32),
+        _grouped_matmul_case("lfm2-prefill-w1", 1024 * 4, 2048, 1792, held=32),
+    ]
+
+
 def window_cases() -> list[Case]:
     """`command-a-plus-ep8`: the window and the full decode and prefill kernels
     at 128 query / 8 kv heads of 128, page 16, tables of 16384 tokens (1024
@@ -269,7 +287,7 @@ def kernel_cases(full: bool) -> list[Case]:
         cases.append(_ssm_update_case())
         cases += [_grouped_matmul_case(*c) for c in GROUPED_MATMUL_CASES]
         cases.append(_grouped_matmul_case("prefill-w2", 1024 * 22, 2688, 1024))
-        return cases + window_cases()
+        return cases + window_cases() + folded_cases()
     return [
         # decode: folded, lookahead, and the per-sequence kernel lookahead
         # falls back to; int8 at page size < 128 was refused (scale-plane
@@ -304,6 +322,10 @@ def kernel_cases(full: bool) -> list[Case]:
         # command-a-plus-ep8: a window in both attention kernels at 128 query
         # heads (neither had run above 32), and a bank of [16, 4096, 4096]
         *window_cases(),
+        # lfm2-8b-a1b-d16: the folded kernels at 512 lanes (the prefill one was
+        # refused a place by the dispatcher at 64 rows x 32 heads) and the
+        # grouped product at the two expert shapes
+        *folded_cases(),
     ]
 
 
@@ -381,13 +403,16 @@ def compile_steps(geometry: dict, tp: int, num_pages: int, page_size: int = 16,
 def compile_hybrid_steps(hf_config: dict, num_pages: int, max_seqs: int, page_size: int = 16,
                          lanes: int = 2, bucket: int = 512, max_model_len: int = 4096,
                          topo=None) -> dict:
-    """Compile one decode step and one packed prefill step of a NemotronH
-    model (models/nemotron_h.py: state cache beside the page pools, the
-    dropless expert dispatch, the state-update kernel) on one described chip;
+    """Compile one decode step and one packed prefill step of a model with a
+    per-slot state beside the page pools (models/nemotron_h.py: Mamba-2 state,
+    the state-update kernel; models/lfm2_moe.py: a convolution window, folded
+    pools; both the dropless expert dispatch) on one described chip;
     ``hf_config`` is a config.json dict. Returns {step: compiled}."""
     from jax.sharding import SingleDeviceSharding
 
-    from dynamo_tpu.models.nemotron_h import NemotronHConfig, NemotronHModel
+    from dynamo_tpu.models.registry import ARCHITECTURES, _resolve
+
+    config_cls, model_cls, _ = _resolve(ARCHITECTURES[hf_config["architectures"][0]])
 
     one = SingleDeviceSharding((topo or topology()).devices[0])
     mp = max_model_len // page_size
@@ -400,7 +425,7 @@ def compile_hybrid_steps(hf_config: dict, num_pages: int, max_seqs: int, page_si
 
     out = {}
     with on_chip_dispatch():
-        model = NemotronHModel(NemotronHConfig.from_hf_config(hf_config))
+        model = model_cls(config_cls.from_hf_config(hf_config))
         params = place(jax.eval_shape(model.init_params, jax.random.key(0)))
         kv = place(jax.eval_shape(lambda: {**model.init_kv_cache(num_pages, page_size),
                                            **model.init_state_cache(max_seqs)}))
@@ -467,6 +492,7 @@ def _report_steps(title: str, steps: dict) -> None:
 
 def main(argv=None) -> int:
     import argparse
+    import json
 
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--kernels", action="store_true", help="only the kernel sweep")
@@ -482,7 +508,8 @@ def main(argv=None) -> int:
     failed = 0
     bench = Path(__file__).resolve().parents[1] / "benchmark/configs"
     if not args.steps:
-        for case in window_cases() if args.window else kernel_cases(full=True):
+        cases = window_cases() if args.window else kernel_cases(full=True)
+        for case in cases:
             t0 = time.monotonic()
             try:
                 compile_case(case, topo)
@@ -491,9 +518,13 @@ def main(argv=None) -> int:
                 failed += 1
                 verdict = "REFUSED " + " ".join(str(e).split())[:240]
             print(f"{case.name}: {verdict} ({time.monotonic() - t0:.1f}s)", flush=True)
+    if not args.kernels and not args.window:
+        lfm2 = json.loads((bench / "lfm2-8b-a1b-d16.json").read_text())
+        slots, pages, max_len = lfm2["benchmark"]["server_args"][1::2]
+        _report_steps(f"lfm2-8b-a1b-d16 (16 layers, all 32 experts) {slots} slots, {pages} pages",
+                      compile_hybrid_steps(lfm2, num_pages=pages, max_seqs=slots,
+                                           max_model_len=max_len, topo=topo))
     if not args.kernels:
-        import json
-
         command_a = json.loads((bench / "command-a-plus-ep8.json").read_text())
         pages = command_a["benchmark"]["server_args"][3]
         _report_steps(f"command-a-plus-ep8 (4 layers, 16 of 128 experts) 48 slots, {pages} pages",
