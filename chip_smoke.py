@@ -809,14 +809,31 @@ def child_parity(sizes: Sizes, args) -> int:
         the cross-program window two tiles, then the double-buffered tail."""
         B = 64 if full else 6
         edges = [127, 128, 129, 255, 256, 257, 383, 384 + ps + 1, 641, 897, 130, 2 * ps - 1]
-        lengths = [1 if b % 3 == 0 else edges[b % len(edges)] for b in range(B)]
+        return batch_of([1 if b % 3 == 0 else edges[b % len(edges)] for b in range(B)], ps)
+
+    def rag_batch(ps, deepest=4096):
+        """`lfm2-8b-a1b-d16.rag-over`'s decode batch: 256 slots (8 in the
+        rehearsal), 77 of them live (3), every third slot from the first and
+        empty ones between, ragged across the tile, the window and the tail,
+        one context of `deepest` tokens (of 400)."""
+        B, live, deep = (256, 77, deepest) if full else (8, 3, 400)
+        edges = [127, 128, 129, 255, 257, 383, 1531, 2 * ps - 1, 1024, 641, 2049, 1]
+        lengths = [1] * B
+        for i in range(live):
+            lengths[3 * i] = deep if i == 2 else min(edges[i % len(edges)], deep)
+        return batch_of(lengths, ps)
+
+    def batch_of(lengths, ps):
+        """(B, max_pages, tables, positions) for these context lengths: pages
+        of their own in a shuffled order, a table wider than any context and
+        padded with page 0."""
         needed = [-(-n // ps) for n in lengths]
-        maxp = max(needed) + 3  # a table wider than any context, padded with page 0
+        maxp = max(needed) + 3
         order = iter(1 + rng.permutation(sum(needed)))
-        tables = np.zeros((B, maxp), np.int32)
+        tables = np.zeros((len(lengths), maxp), np.int32)
         for b, n in enumerate(needed):
             tables[b, :n] = [next(order) for _ in range(n)]
-        return B, maxp, jnp.asarray(tables), jnp.asarray([n - 1 for n in lengths], jnp.int32)
+        return len(lengths), maxp, jnp.asarray(tables), jnp.asarray([n - 1 for n in lengths], jnp.int32)
 
     def decode(hq, hkv, d, ps, int8, kernel=None, batch=ragged_batch):
         folded = d < 128 or kernel == "folded"
@@ -827,7 +844,48 @@ def child_parity(sizes: Sizes, args) -> int:
             got = paged_decode_attention_pallas(q, k, v, tables, pos, interpret=interpret)
         else:
             got = jax.jit(A.dispatch_paged_decode_attention)(q, k, v, tables, pos)
-        return got, reference(A.paged_decode_attention, q, k, v, tables, pos)
+        # the gather reference holds every row's whole table in f32: 32 rows at
+        # a time (256 rows of 4096 tokens by 512 lanes would be 8 GB)
+        want = [reference(A.paged_decode_attention, q[i:i + 32], k, v, tables[i:i + 32], pos[i:i + 32])
+                for i in range(0, B, 32)]
+        return got, jnp.concatenate(want)
+
+    def decode_repeats(hq, hkv, d, ps, repeats):
+        """The folded decode kernel `repeats` times on the SAME inputs at
+        `lfm2-8b-a1b-d16.rag-over`'s geometry, every output compared bit for
+        bit with the first: a tile merged before its DMAs had landed would
+        show as a run that differs (ISSUE 44, H2). The pools are as a busy
+        engine leaves them: every row past a length and every page no
+        sequence owns holds what another sequence wrote, a finite value in
+        one half and NaN in the other. The second half of the runs alternates
+        with a program that streams 1 GiB (256 KiB in the rehearsal) through
+        HBM, all queued without a wait between. Returns (the number of runs
+        that differ from the first, 0)."""
+        B, maxp, tables, pos = rag_batch(ps, deepest=4864)  # 4096 + 768: the mix's longest
+        lengths = np.asarray(pos) + 1
+        P = int(tables.max()) + 1 + 64  # and 64 pages that no table names
+        k, v = pools(P, ps, hkv, d, False, True)
+        stale = np.ones((P, ps), bool)
+        stale[0, 0] = False  # an empty slot's one token, on the trash page
+        for b, n in enumerate(lengths):
+            for i in range(-(-n // ps)):
+                stale[int(tables[b, i]), : min(ps, n - i * ps)] = False
+        nan = jnp.asarray(stale & (np.arange(P)[:, None] % 2 == 1))[..., None]
+        k, v = (jnp.where(nan, jnp.asarray(jnp.nan, bf16), x) for x in (k, v))
+        q = normal(B, hq, d)
+        kernel = jax.jit(A.dispatch_paged_decode_attention)
+        first = jax.block_until_ready(kernel(q, k, v, tables, pos))
+        check(bool(jnp.isfinite(first.astype(jnp.float32)).all()), "a stale row reached the output")
+        stream = jax.jit(lambda x: x + 1)
+        big = jnp.zeros(((1 << 30) if full else (1 << 18)) // 4, jnp.float32)
+        differs = jax.jit(lambda a, b: jnp.any(jax.lax.bitcast_convert_type(a, jnp.uint16)
+                                               != jax.lax.bitcast_convert_type(b, jnp.uint16)))
+        bad = []
+        for i in range(repeats):
+            if i >= repeats // 2:
+                big = stream(big)
+            bad.append(differs(kernel(q, k, v, tables, pos), first))
+        return jnp.sum(jnp.stack(bad)).astype(jnp.float32)[None], jnp.zeros(1, jnp.float32)
 
     def prefill(hq, hkv, d, ps, T, prefix, int8, lookahead=None, folded=None):
         # a chunk of T rows behind `prefix` cached tokens (not page-aligned
@@ -964,12 +1022,14 @@ def child_parity(sizes: Sizes, args) -> int:
     if full:
         tiny, qwen, mixtral, bench, shard = (32, 4, 64), (28, 4, 128), (32, 8, 128), (16, 8, 128), (7, 1, 128)
         qwen3b = (16, 2, 128)  # the benchmark's configuration
+        lfm2 = (32, 8, 64)  # lfm2-8b-a1b-d16: folded pools of 512 lanes
         T, prefix = 512, 1000
         command_a, window = (128, 8, 128), 4096  # command-a-plus-ep8
         deep_window, deep_full = 2 * window, 3 * window - T  # chunk starts at depth
     else:  # the CPU rehearsal: same code paths, interpret-mode sizes
         tiny, qwen, mixtral, bench, shard = (8, 2, 64), (4, 2, 128), (4, 2, 128), (4, 2, 128), (2, 1, 128)
         qwen3b = (4, 2, 128)
+        lfm2 = (8, 4, 64)
         T, prefix = 128, 200
         command_a, window = (4, 2, 128), 128
         deep_window = deep_full = 2176  # a table past 2048 tokens: the long tile
@@ -983,6 +1043,11 @@ def child_parity(sizes: Sizes, args) -> int:
         ("decode lookahead qwen2.5-7b cell batch ps16 int8", lambda: decode(*qwen, 16, True, batch=cell_batch)),
         ("decode perseq qwen2.5-7b ps16 int8", lambda: decode(*qwen, 16, True, kernel="perseq")),
         ("decode folded qwen2.5-7b tp4-shard ps16 bf16", lambda: decode(*shard, 16, False, kernel="folded")),
+        ("decode folded lfm2 cell batch ps16 bf16", lambda: decode(*lfm2, 16, False, batch=rag_batch)),
+        ("decode folded lfm2 cell batch ps16 int8", lambda: decode(*lfm2, 16, True, batch=rag_batch)),
+        # max_abs_err here is a COUNT: runs whose output differs from the first in any bit
+        ("decode folded lfm2 cell batch ps16 bf16 runs of 200 that differ from the first",
+         lambda: decode_repeats(*lfm2, 16, 200 if full else 4)),
         ("prefill folded tinyllama ps16 bf16", lambda: prefill(*tiny, 16, T, prefix, False)),
         ("prefill folded tinyllama ps16 int8", lambda: prefill(*tiny, 16, T, prefix, True)),
         ("prefill lookahead qwen2.5-7b ps16 bf16", lambda: prefill(*qwen, 16, T, prefix, False)),

@@ -417,7 +417,7 @@ class EngineConfig:
                 best = max(best, b)
         return best
 
-    def lanes_for(self, bucket: int) -> int:
+    def lanes_for(self, bucket: int, wide: bool = False) -> int:
         """Packed-prefill lane count for a bucket, on the path that keeps the
         rectangle (a model with recurrent layers) and in warm-up: bounded by
         prefill_lanes and a 1024-row budget. On a v5e one `qwen2.5-3b` pack
@@ -428,8 +428,21 @@ class EngineConfig:
         cost as blocks ([1,512] 23.0, [2,256] 21.9, [2,512] 40.9, [4,256]
         41.2). A row is 42.7 us in a pack of 512 and 39.2 in one of 1024, so
         packing pays up to the budget; more rows a call lengthen the stall a
-        decode stream sees."""
-        return max(1, min(self.prefill_lanes, 1024 // bucket))
+        decode stream sees.
+
+        ``wide``: the pack holds a lane whose page table is beyond the first
+        rung of the ladder. Such a pack takes two lanes at most (PR 44). Every
+        (lanes, bucket, rung) is a program of its own, a sequence that deep
+        runs chunks of the largest bucket, two a pack, until its tail, and
+        four short chunks of which one is a deep sequence's tail come
+        together about once in ten windows of `lfm2-8b-a1b-d16.rag-over`
+        (`PERF.md` section 6): rare enough that no warm-up traffic meets
+        them, often enough that one run in some dozens compiled such a
+        program in mid-traffic. Two packs of two cost 2.6 ms more than one of
+        four by the figures above; three programs fewer a wider rung are
+        10 s of every start and 60 s of a first one."""
+        lanes = max(1, min(self.prefill_lanes, 1024 // bucket))
+        return min(lanes, 2) if wide else lanes
 
     @property
     def prefill_block(self) -> int:

@@ -1395,11 +1395,14 @@ class ModelRunner:
         self._ensure_penalty_state()
         sh = self._warmup_shapes()
         K = self.config.decode_steps
-        out = self.dispatch_decode_window(
-            sh["zeros_i"], sh["pt"], sh["inactive"], sh["zeros_i"],
-            sh["temps"], sh["zeros_i"], sh["ones_f"], K,
-        )
-        jax.block_until_ready(out)  # graftlint: sync-ok warmup: compile gate, not serving traffic
+        rungs = self.config.table_buckets
+        for width in rungs if self.warms_every_rung else rungs[:1]:
+            shw = self._warmup_shapes(table_width=width)
+            out = self.dispatch_decode_window(
+                shw["zeros_i"], shw["pt"], shw["inactive"], shw["zeros_i"],
+                shw["temps"], shw["zeros_i"], shw["ones_f"], K,
+            )
+            jax.block_until_ready(out)  # graftlint: sync-ok warmup: compile gate, not serving traffic
         spec = self.config.spec
         if spec is not None:
             # one verify executable per configured k (all slots inactive, KV
@@ -1433,18 +1436,41 @@ class ModelRunner:
                 )
         log.info("warmup(core): compiled in %.1fs", _time.monotonic() - t0)
 
-    def packed_prefill_shapes(self) -> list:
+    @property
+    def warms_every_rung(self) -> bool:
+        """True where warm-up compiles the default step programs of EVERY
+        rung of the page-table ladder before readiness: a model with
+        recurrent layers on a ladder of up to three rungs. Such a model takes
+        no prefix from the cache, so a long prompt arrives whole and its
+        chunks and decode windows run on the wider rungs from the first
+        second of traffic; which (lanes, bucket) they meet there turns on how
+        arrivals fall together. (`lfm2-8b-a1b-d16.rag-over`, PR 44: with the
+        first rung's eight alone before readiness, the benchmark's warm
+        bursts met the same 20 further rectangles in each of four runs and
+        never five of those the packer could then emit, four lanes beside a
+        prompt of over 2048 tokens, which a walk of the packer under that mix
+        meets in one window of ten. Those five no longer exist:
+        `EngineConfig.lanes_for`.) A short ladder makes the whole set cheap
+        to have, 27 + 3 programs at (128, 256, 320), all of which a first
+        hour of traffic loads anyway. On a longer one (contexts past 8192
+        tokens at a page of 16) rectangles x rungs would hold readiness for
+        every one of them, and the wider rungs compile behind it as they did."""
+        return self.recurrent and len(self.config.table_buckets) <= 3
+
+    def packed_prefill_shapes(self, wide: bool = False) -> list:
         """(N, T) of every packed prefill program the scheduler's packer can
         emit: blocks of `prefill_block` rows, 1 to `pack_blocks` of them; for
         a model with recurrent layers a rectangle per bucket, its lane count
-        a power of two up to `lanes_for`."""
+        a power of two up to `lanes_for` (on a rung beyond the first, `wide`,
+        up to two)."""
         c = self.config
         if not self.recurrent:
             return [(n, c.prefill_block) for n in range(1, c.pack_blocks + 1)]
-        return [
-            (n, b) for b in c.prefill_buckets
-            for n in sorted({min(c.lanes_for(b), 1 << k) for k in range(c.lanes_for(b).bit_length() + 1)})
-        ]
+        shapes = []
+        for b in c.prefill_buckets:
+            lanes = c.lanes_for(b, wide)
+            shapes += [(n, b) for n in sorted({min(lanes, 1 << k) for k in range(lanes.bit_length() + 1)})]
+        return shapes
 
     def packed_warmup_shapes(self) -> tuple:
         """(N, T, table width) of the packed prefill programs warm-up
@@ -1457,10 +1483,12 @@ class ModelRunner:
         first in traffic stalls every stream for its compile; the rungs
         between compile behind readiness.
 
-        Rectangles: N = 1 and N = lanes_for(b) of every bucket on the first
-        rung before readiness; the powers of two between, and on each wider
-        rung the one chunk the depth-aware planner runs at that depth
-        (chunk_len_for shrinks chunks as context grows) at N = 1, behind."""
+        Rectangles: on a ladder of up to three rungs (`warms_every_rung`)
+        every rectangle on every rung before readiness. On a longer ladder
+        N = 1 and N = lanes_for(b) of every bucket on the first rung before
+        readiness; the powers of two between, and on each wider rung the one
+        chunk the depth-aware planner runs at that depth (chunk_len_for
+        shrinks chunks as context grows) at N = 1, behind."""
         c = self.config
         rungs = c.table_buckets
         shapes = self.packed_prefill_shapes()
@@ -1470,6 +1498,9 @@ class ModelRunner:
                 [(n, t, w) for w in first for n, t in shapes],
                 [(n, t, w) for w in rungs[1:-1] for n, t in shapes],
             )
+        if self.warms_every_rung:
+            return [(n, t, w) for w in rungs
+                    for n, t in self.packed_prefill_shapes(wide=w != rungs[0])], []
         core = [(n, t, rungs[0]) for n, t in shapes if n in (1, c.lanes_for(t))]
         later = [(n, t, rungs[0]) for n, t in shapes if n not in (1, c.lanes_for(t))]
         later += [(1, c.chunk_len_for((w // 2) * c.page_size), w) for w in rungs[1:]]
@@ -1590,7 +1621,8 @@ class ModelRunner:
             return run
 
         for w in self.config.table_buckets[1:]:
-            thunks.append(wide_window(w))
+            if not self.warms_every_rung:  # else warmup_core has it
+                thunks.append(wide_window(w))
             if self.packed_prefill_mode:
                 thunks += [packed(*shape) for shape in later if shape[2] == w]
             else:

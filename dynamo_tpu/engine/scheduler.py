@@ -1641,18 +1641,23 @@ class Scheduler:
         # into a long prompt, keeping per-chunk latency roughly flat —
         # and backlog-aware: a deep pending queue promotes the bucket so
         # the burst takes fewer, larger dispatches.
+        # A pack with a lane beyond the first rung of the page-table ladder
+        # holds two lanes at most (`lanes_for`).
         chunks = []
         bucket = 0
+        wide = False
+        first_rung = self.config.table_buckets[0]
         for s in pending:
             limit = self.config.chunk_len_for(s.prefill_pos, backlog_rows=backlog_rows)
             end = min(s.prefill_pos + limit, s.prompt_len)
             cand = self.config.bucket_for(max(bucket, end - s.prefill_pos))
-            if chunks and len(chunks) + 1 > self.config.lanes_for(cand):
+            cand_wide = wide or s.page_table.shape[-1] > first_rung
+            if chunks and len(chunks) + 1 > self.config.lanes_for(cand, cand_wide):
                 break
             if self.grouped and not self._chunk_pages(s, end, outputs):
                 continue
             chunks.append((s, s.prefill_pos, end))
-            bucket = cand
+            bucket, wide = cand, cand_wide
         # a later lane's page pressure may have preempted an earlier one
         chunks = [c for c in chunks if self.slots[c[0].slot] is c[0] and not c[0].finished]
         if not chunks:
@@ -1660,7 +1665,7 @@ class Scheduler:
         # N rounds up to a power of two so partial packs compile at most
         # log2(lanes_max) executables per bucket, padding <= 2x on the rare
         # odd sizes
-        N = min(self.config.lanes_for(bucket), 1 << (len(chunks) - 1).bit_length())
+        N = min(self.config.lanes_for(bucket, wide), 1 << (len(chunks) - 1).bit_length())
         return chunks, bucket, N
 
     def _pack_blocks(self, pending: list, backlog_rows: int, outputs: list[StepOutput]):

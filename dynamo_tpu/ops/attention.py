@@ -314,7 +314,7 @@ def dispatch_paged_decode_attention(q, k_pages, v_pages, page_tables, positions,
     keys in (p - W, p]. The tiled kernel then walks only the tiles that hold
     such keys (a table entry behind them may be the null page: the engine has
     given the page back) and carries a name of its own on the device's
-    operation line. The folded and page-at-a-time kernels take no window.
+    operation line. A folded pool and the page-at-a-time kernel take no window.
 
     With a tensor-parallel mesh the kernel runs under shard_map: attention is
     head-parallel, so each device handles its Hq/Hkv shard with no
@@ -337,20 +337,18 @@ def dispatch_paged_decode_attention(q, k_pages, v_pages, page_tables, positions,
         paged_decode_attention_pallas_lookahead,
     )
 
-    # One kernel per shape class, chosen from the shapes alone.
+    # One walk for every shape class, its tile and window chosen from the
+    # shapes alone: one sequence per grid program, a TILE of pages (128
+    # context tokens) per loop iteration, cross-program prefetch of the next
+    # sequence's first tiles (design record in ops/pallas/paged_attention.py).
+    # lookahead: pools [P, ps, Hkv, D], tile and window from decode_tile_pages
+    # / lookahead_window; it falls back to perseq (a page per iteration,
+    # in-program double buffer only) internally when not even one window tile
+    # fits the VMEM budget.
     # folded: the pool is folded or head_dim is under a lane row (Mosaic
     # can't DMA-slice sub-128-lane pools; heads live folded into the lane
-    # dim); still a page at a time: 0.38-0.41 us for every page of 16 x 512
-    # bf16 (K and V, 16 KiB each), a tenth of the HBM roofline at batches of
-    # 64 to 256 and contexts of 512 to 4096 (tools/profile_folded_attention.py
-    # on a v5e, PR 42).
-    # lookahead: one sequence per grid program, a TILE of pages (128 context
-    # tokens) per loop iteration, cross-program prefetch of the next
-    # sequence's first tiles; tile width and window follow from the shapes
-    # (decode_tile_pages, lookahead_window), and it falls back to perseq (a
-    # page per iteration, in-program double buffer only) internally when not
-    # even one window tile fits the VMEM budget (design record in
-    # ops/pallas/paged_attention.py).
+    # dim). The same walk with the folded row of Hkv * D lanes taken as one
+    # head, and the merge that never unfolds.
     use_folded = folded or D % 128 != 0
     kernel = (
         paged_decode_attention_pallas_folded
@@ -367,12 +365,17 @@ def dispatch_paged_decode_attention(q, k_pages, v_pages, page_tables, positions,
             _log_path("decode", "reference", f"{shape}: only the tiled kernel takes a window")
             return paged_decode_attention(q, k_pages, v_pages, page_tables, positions, window)
         kernel = functools.partial(kernel, window=window)
-    if not use_folded and num_kv_heads % tp == 0:
+    if num_kv_heads % tp == 0:
         # the geometry one head shard's kernel derives, so a server log says
         # which tile width ran
-        geometry = (k_pages.shape[1], num_kv_heads // tp, D, k_pages.dtype.itemsize)
+        ps, heads, itemsize = k_pages.shape[1], num_kv_heads // tp, k_pages.dtype.itemsize
+        # a folded pool's row of Hkv * D lanes is one head to the walk
+        geometry = (ps, 1, heads * D, itemsize) if use_folded else (ps, heads, D, itemsize)
         ahead = lookahead_window(*geometry)
-        path += (f" tile={decode_tile_pages(*geometry)}x{geometry[0]} window={ahead}"
+        if use_folded and not ahead:
+            _log_path("decode", "reference", f"{shape}: no tile of the folded pool fits VMEM")
+            return paged_decode_attention(q, k_pages, v_pages, page_tables, positions)
+        path += (f" tile={decode_tile_pages(*geometry)}x{ps} window={ahead}"
                  if ahead else " window=0:perseq")
     path += " interpret" if interpret else ""
     if tp == 1:

@@ -5,12 +5,15 @@ table rides in as scalar-prefetch (available before the kernel body, so page
 DMAs can be issued from dynamic indices), K/V page pools stay in HBM, and
 pages stream through a double-buffered VMEM scratch overlapping DMA with
 compute (pallas_guide.md: PrefetchScalarGridSpec + double buffering): a tile
-of pages at a time in the default kernel, a page at a time in the others.
+of pages at a time in the tiled kernel, which walks pools of either rank
+(since PR 44), a page at a time in its fallback.
 
 Contract matches the pure-JAX reference (dynamo_tpu/ops/attention.py
-paged_decode_attention): q [B, Hq, D], pages [P, ps, Hkv, D],
+paged_decode_attention): q [B, Hq, D], pages [P, ps, Hkv, D] or FOLDED
+[P, ps, Hkv*D] (head_dim under 128, or one kv head a tensor-parallel shard),
 page_tables [B, max_pages], positions [B] (query position; context length =
-position + 1). GQA folded as [Hkv, G, D] per-kv-head batched matmuls.
+position + 1). GQA folded as [Hkv, G, D] per-kv-head batched matmuls; over a
+folded pool as one [Hq, Hkv*D] product on a zero-placed query.
 
 Design record (PR 26; every time below is my chip run on a TPU v5e, 36
 chained calls in one jit, best of 5, at the benchmark cells' shapes: B 64,
@@ -65,20 +68,55 @@ qwen2.5-3b.chat; the HBM floor of the two is 33 and 11 us).
     were taken out again for a tile of one page, which is fetched whole.)
   - The no-transpose dot_general variants (batch dim in K's middle position)
     are Mosaic-illegal outright (tpu.matmul requires leading batch dims).
-  - perseq stays as the fallback for a geometry whose window does not fit;
-    folded (head_dim < 128) is still a page at a time and has no cell.
+  - perseq stays as the fallback for a geometry whose window does not fit.
   - chunked, and grouped (several sequences a grid program, a page at a
     time), were deleted in PR 31: bf16 only, slower than the tiled default at
     every shape measured, and reachable only through an environment variable.
-    The file holds three kernels: lookahead, perseq, folded.
 
-Int8 KV (quant/kv.py QuantizedPages): perseq, lookahead, and folded accept
-int8 pools plus their per-row f32 scales, which arrive as lane-aligned rows
-gathered by XLA in page-table order (gather_scale_rows — Mosaic refuses to
-DMA-slice the raw [P, ps] plane when ps < 128), one row per page, or per tile
-of pages in lookahead. Scale rows ride their own tiny DMAs beside the page
-DMAs (the HBM context stream halves — that is the
-win) and dequantization is applied to the score/prob tiles in VMEM:
+Folded pools (PR 44, first built by PR 43; chip runs on a TPU v5e,
+tools/profile_folded_attention.py: 24 chained calls, best of 5, at
+lfm2-8b-a1b's shape, Hq 32, Hkv 8, D 64, page 16, bf16: a page's K and V are
+one DMA of 16 KiB each; every sequence at the context named, HBM floor 82 /
+246 / 656 us at a batch of 64). Until PR 44 a kernel of their own walked them
+a page at a time (two DMAs in flight behind the page in use, two products of
+16 context rows, a mask and a select a page):
+
+  us per call (ns per page), batch 64     ctx 512      ctx 1536      ctx 4096
+  folded, a page per iteration (PR 43)    829 (405)    2368 (385)    6198 (378)
+  the tiled walk, folded merge (PR 44)    246 (120)     621 (101)    1566  (96)
+
+  and 455 / 1207 / 3086 us at a batch of 128, 878 / 2384 / 6143 at 256
+  (against 1631 / 4686 / 12330 and 3227 / 9346 / 24622): 3.4-4.0 times, and
+  34-43% of the HBM roofline where a page at a time stood at 10-10.7%
+  whatever the batch and the depth.
+
+  - It is the SAME walk (_kernel_lookahead's tile / window / tail DMAs never
+    look inside a page) with the folded row of Hkv * D lanes taken as one head
+    (decode_tile_pages / lookahead_window at num_kv_heads 1, head_dim Hkv * D):
+    a tile of 8 pages and a window of 2 at page 16 for 128, 256 and 512 lanes,
+    bf16 or int8; six tiles of 256 KiB of scratch at 512 lanes. Only the merge
+    follows the pool's rank. The programs lowered for rank-4 pools are what
+    they were, instruction for instruction (the Mosaic module of the
+    qwen2.5-3b, qwen2.5-7b int8, command-a-plus full and window, and mixtral
+    page-128 / page-64 int8 decode kernels printed without debug locations,
+    before and after: identical).
+  - The folded merge keeps its operands as the pool holds them (bf16 K, V,
+    zero-placed query and probabilities to the MXU, f32 accumulation; int8
+    pages as f32): a tile [8, 16, F] is [128, F] with no relayout, bf16 pages
+    being whole (16, 128) tiles, so there is no 32-bit transpose to pay and
+    none of the f32-operand findings above apply to it.
+  - Not tried: a tile of 256 tokens, a wider window (W 1-16 timed within 7%
+    of each other for rank 4). An empty slot costs what it does there.
+  - The file holds two kernel bodies: the tiled walk (lookahead, with a merge
+    per pool rank) and perseq.
+
+Int8 KV (quant/kv.py QuantizedPages): perseq and the tiled walk (both pool
+ranks) accept int8 pools plus their per-row f32 scales, which arrive as
+lane-aligned rows gathered by XLA in page-table order (gather_scale_rows —
+Mosaic refuses to DMA-slice the raw [P, ps] plane when ps < 128), one row per
+page in perseq, per tile of pages in the tiled walk. Scale rows ride their
+own tiny DMAs beside the page DMAs (the HBM context stream halves — that is
+the win) and dequantization is applied to the score/prob tiles in VMEM:
 ``scores *= k_s`` / ``probs *= v_s`` is the exact per-column algebra, and
 both are lane-axis broadcasts (Mosaic-legal; no sub-128 minor-dim reshapes).
 """
@@ -106,8 +144,9 @@ def gather_scale_rows(scales, tables, pages_per_row: int = 1):
     by physical page. Instead the rows a call will need are gathered here
     (a few bytes per context token — noise next to the int8 page stream),
     ``pages_per_row`` consecutive logical pages are laid side by side on the
-    lane axis (1 for the page-at-a-time decode kernels, the tile width for
-    prefill and the tiled decode kernel), and the row is zero-padded to a multiple of 128 lanes. Row r of
+    lane axis (1 for the page-at-a-time decode kernel, the tile width for
+    prefill and the tiled decode kernel), and the row is zero-padded to a
+    multiple of 128 lanes. Row r of
     the result covers logical pages [r * pages_per_row, (r+1) * pages_per_row)
     of the flattened ``tables``; the kernel DMAs ``rows.at[r]`` -> [1, W] and
     reads its first pages_per_row * ps lanes."""
@@ -257,14 +296,42 @@ def _heads_major(tile_ref):
 
 def _zero_tokens_from(tile_ref, first):
     """Zero tokens ``first`` and beyond of a tile ``[TP, ps, Hkv, D]`` in
-    place, as whole 32-bit words where the heads fill them."""
+    place, as whole 32-bit words where the heads fill them. A folded tile
+    ``[TP, ps, Hkv*D]`` packs TOKENS into its words: it is zeroed as it is."""
     ps, Hkv = tile_ref.shape[1:3]
     per_word = 4 // tile_ref.dtype.itemsize
-    view = tile_ref.bitcast(jnp.uint32) if Hkv % per_word == 0 else tile_ref
+    words = len(tile_ref.shape) == 4 and Hkv % per_word == 0
+    view = tile_ref.bitcast(jnp.uint32) if words else tile_ref
     token = (jax.lax.broadcasted_iota(jnp.int32, view.shape, 0) * ps
              + jax.lax.broadcasted_iota(jnp.int32, view.shape, 1))
     kept = jnp.where(token < first, view[...], jnp.zeros((), view.dtype))
     tile_ref[...] = kept if view is tile_ref else pltpu.bitcast(kept, tile_ref.dtype)
+
+
+def _fold_query(q_ref, F: int, dtype):
+    """(ownership mask [Hq, F] f32, zero-placed folded query [Hq, F] in
+    ``dtype``) for a pool whose kv heads are folded into F lanes: head h owns
+    the D lanes of its kv head, ``mask[h, f] = (f // D == h // G)``, and the
+    query is Hkv copies of itself side by side with every lane a head does not
+    own zeroed. Everything stays 2D: Mosaic rejects minor-dim reshapes."""
+    Hq, D = q_ref.shape[1], q_ref.shape[2]
+    Hkv = F // D
+    lane = jax.lax.broadcasted_iota(jnp.int32, (Hq, F), 1)
+    head = jax.lax.broadcasted_iota(jnp.int32, (Hq, F), 0)
+    own = (lane // D == head // (Hq // Hkv)).astype(jnp.float32)
+    q = jnp.concatenate([q_ref[0].astype(jnp.float32)] * Hkv, axis=1)
+    return own, (q * own).astype(dtype)
+
+
+def _owned_lanes(acc, own, D: int):
+    """Folded accumulator [Hq, F] -> [Hq, D]: zero the lanes a head does not
+    own, then add the D-wide lane slices together (only the owned one is
+    nonzero)."""
+    acc = acc * own
+    out = acc[:, 0:D]
+    for j in range(1, acc.shape[1] // D):
+        out = out + acc[:, j * D : (j + 1) * D]
+    return out
 
 
 def _kernel_lookahead(
@@ -302,12 +369,28 @@ def _kernel_lookahead(
     contexts) stream through the in-program double buffer: tile t+1 in flight
     while tile t is merged.
 
+    The walk never looks inside a page, so it serves pools of either rank;
+    the merge follows the rank. Pools ``[P, ps, Hkv, D]`` (head_dim a multiple
+    of 128): per-kv-head batched products on ``_heads_major`` f32 operands.
+    FOLDED pools ``[P, ps, Hkv*D]`` (head_dim under 128, or one kv head a
+    shard: Mosaic cannot DMA-slice a pool whose minor dim is under the
+    128-lane tile, so the kv heads are folded into the lanes): the per-head
+    math never unfolds. The query is placed into a zero-padded folded layout
+    (``_fold_query``: each q head occupies its kv head's D lanes, zeros
+    elsewhere), so one ``[Hq, F] x [S, F]`` product a tile yields exact
+    per-head scores (the zero slices kill every cross-head term), and
+    ``probs x V`` gives ``[Hq, F]`` with each head's true output in its kv
+    head's lanes, picked out once after the walk (``_owned_lanes``). Folded
+    operands go to the MXU as the pool holds them (bf16; int8 as f32, the
+    per-row scale being head-independent), with f32 accumulation.
+
     refs: page_tables + lengths (scalar prefetch) | q, k/v pools [, k/v
     scale rows [B*tiles_per_seq, 1, Ws], one per tile, see
     gather_scale_rows] | out | k_pre, v_pre [2, W, TP, ps, Hkv, D] [, scale
     windows [2, W, 1, Ws]], k_tail, v_tail [2, TP, ps, Hkv, D] [, scale tails
-    [2, 1, Ws]], sems_pre [2, W, 2|4], sems_tail [2, 2|4]. The copies of one
-    tile and pool share a semaphore: each wait takes one page's bytes off it."""
+    [2, 1, Ws]], sems_pre [2, W, 2|4], sems_tail [2, 2|4]; a folded pool's
+    scratch is [.., TP, ps, Hkv*D]. The copies of one tile and pool share a
+    semaphore: each wait takes one page's bytes off it."""
     if quantized:
         (page_tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm,
          out_ref, k_pre, v_pre, ks_pre, vs_pre, k_tail, v_tail, ks_tail,
@@ -341,10 +424,24 @@ def _kernel_lookahead(
     t0 = first_tile(b)
 
     Hq, D = q_ref.shape[1], q_ref.shape[2]
-    Hkv = k_hbm.shape[2]
-    G = Hq // Hkv
-    q = q_ref[0].astype(jnp.float32).reshape(Hkv, G, D)
+    folded = len(k_hbm.shape) == 3
+    if folded:  # no caller gives a folded pool a window
+        # int8 pages go to the MXU as f32 (operand dtypes must match)
+        operand = jnp.float32 if quantized else k_hbm.dtype
+        own, q = _fold_query(q_ref, k_hbm.shape[2], operand)
+        heads, width = (Hq,), k_hbm.shape[2]
+        qk_dims, pv_dims = (((1,), (1,)), ((), ())), (((1,), (0,)), ((), ()))
+
+        def rows(tile_ref):  # [TP, ps, F] -> [S, F]: whole pages, lanes untouched
+            return tile_ref[...].astype(operand).reshape(S, width)
+    else:
+        Hkv = k_hbm.shape[2]
+        G = Hq // Hkv
+        q = q_ref[0].astype(jnp.float32).reshape(Hkv, G, D)
+        heads, width, rows = (Hkv, G), D, _heads_major
+        qk_dims, pv_dims = (((2,), (2,)), ((0,), (0,))), (((2,), (1,)), ((0,), (0,)))
     scale = 1.0 / jnp.sqrt(jnp.float32(D))
+    lead = (None,) * (len(heads) - 1)  # a scale row [1, S] against the scores
 
     def tile_dmas(op, seq_idx, t, npg, pools, scales, at, sems):
         """Start or wait (``op``) every copy of tile t of ``seq_idx``: its
@@ -400,7 +497,7 @@ def _kernel_lookahead(
     def _():
         tail_dmas("start", jax.lax.rem(t0 + W, 2) if window else W % 2, t0 + W)
 
-    col = jax.lax.broadcasted_iota(jnp.int32, (1, 1, S), 2)
+    col = jax.lax.broadcasted_iota(jnp.int32, (1,) * len(heads) + (S,), len(heads))
 
     def merge(carry, t, k_tile, v_tile, k_s, v_s):
         m, l, acc = carry
@@ -413,14 +510,14 @@ def _kernel_lookahead(
             def _():
                 _zero_tokens_from(v_tile, length - t * S)
 
-        kt = _heads_major(k_tile)  # [Hkv, S, D] f32
-        vt = _heads_major(v_tile)
-        # [Hkv, G, S] = [Hkv, G, D] x [Hkv, S, D]
+        kt = rows(k_tile)  # [Hkv, S, D] f32; folded [S, F]
+        vt = rows(v_tile)
+        # [Hkv, G, S] = [Hkv, G, D] x [Hkv, S, D]; folded [Hq, S] = [Hq, F] x [S, F]
         scores = jax.lax.dot_general(
-            q, kt, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
+            q, kt, qk_dims, preferred_element_type=jnp.float32
         ) * scale
         if quantized:
-            scores = scores * k_s[:, :S][None]  # [1, 1, S] per-row K scales
+            scores = scores * k_s[:, :S][lead]  # [1, 1, S] per-row K scales
         valid = t * S + col < length
         if window:
             valid &= t * S + col >= length - window
@@ -433,10 +530,10 @@ def _kernel_lookahead(
         if quantized:
             # V scales fold into probs (masked: a scale row's unused lanes
             # belong to whatever page the table's padding names)
-            probs = jnp.where(valid, probs * v_s[:, :S][None], 0.0)
-        # [Hkv, G, D] = [Hkv, G, S] x [Hkv, S, D]
+            probs = jnp.where(valid, probs * v_s[:, :S][lead], 0.0)
+        # [Hkv, G, D] = [Hkv, G, S] x [Hkv, S, D]; folded [Hq, F] = [Hq, S] x [S, F]
         chunk_out = jax.lax.dot_general(
-            probs, vt, (((2,), (1,)), ((0,), (0,))), preferred_element_type=jnp.float32
+            probs.astype(vt.dtype), vt, pv_dims, preferred_element_type=jnp.float32
         )
         return new_m, new_l, acc * corr[..., None] + chunk_out
 
@@ -462,12 +559,14 @@ def _kernel_lookahead(
             vs_tail[slot] if quantized else None,
         )
 
-    m0 = jnp.full((Hkv, G), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((Hkv, G), jnp.float32)
-    acc0 = jnp.zeros((Hkv, G, D), jnp.float32)
+    m0 = jnp.full(heads, _NEG_INF, jnp.float32)
+    l0 = jnp.zeros(heads, jnp.float32)
+    acc0 = jnp.zeros((*heads, width), jnp.float32)
     carry = jax.lax.fori_loop(0, jnp.minimum(W, n_tiles - t0), pre_body, (m0, l0, acc0))
     m, l, acc = jax.lax.fori_loop(t0 + W, n_tiles, tail_body, carry)
 
+    if folded:
+        acc = _owned_lanes(acc, own, D)
     out = acc / jnp.maximum(l, 1e-20)[..., None]
     out_ref[0] = out.reshape(Hq, D).astype(out_ref.dtype)
 
@@ -511,33 +610,18 @@ def lookahead_window(page_size: int, num_kv_heads: int, head_dim: int,
 SLIDING_DECODE_NAME = "paged_decode_attention_sliding_window"
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "window"))
-def paged_decode_attention_pallas_lookahead(
-    q: jnp.ndarray,  # [B, Hq, D]
-    k_pages,  # [P, ps, Hkv, D] plain or QuantizedPages
-    v_pages,
-    page_tables: jnp.ndarray,  # [B, max_pages] int32
-    positions: jnp.ndarray,  # [B] int32 query positions
-    interpret: bool = False,
-    window: int = 0,  # sliding window in tokens (0: the whole context)
-) -> jnp.ndarray:
+def _tiled_decode(q, k_pages, v_pages, page_tables, positions, TP: int, W: int, *,
+                  interpret: bool, window: int = 0, name=None):
+    """``_kernel_lookahead`` over pools of either rank, at tiles of ``TP``
+    pages and a window of ``W`` tiles."""
     B, Hq, D = q.shape
-    P, ps, Hkv, _ = k_pages.shape
-    itemsize = k_pages.dtype.itemsize
-    W = lookahead_window(ps, Hkv, D, itemsize)
-    if W < 1:
-        if window:
-            raise ValueError("the page-at-a-time decode kernel takes no window")
-        return paged_decode_attention_pallas(
-            q, k_pages, v_pages, page_tables, positions, interpret=interpret
-        )
-    TP = decode_tile_pages(ps, Hkv, D, itemsize)
     kq, vq, ks, vs, quantized = _decode_unpack_pools(k_pages, v_pages, page_tables, TP)
+    page = kq.shape[1:]  # [ps, Hkv, D], or folded [ps, Hkv*D]
     lengths = positions.astype(jnp.int32) + 1
 
     def tile_scratch(*lead):
-        shapes = [pltpu.VMEM((*lead, TP, ps, Hkv, D), kq.dtype),
-                  pltpu.VMEM((*lead, TP, ps, Hkv, D), vq.dtype)]
+        shapes = [pltpu.VMEM((*lead, TP, *page), kq.dtype),
+                  pltpu.VMEM((*lead, TP, *page), vq.dtype)]
         if quantized:
             shapes += [pltpu.VMEM((*lead, 1, ks.shape[-1]), jnp.float32),
                        pltpu.VMEM((*lead, 1, vs.shape[-1]), jnp.float32)]
@@ -561,7 +645,7 @@ def paged_decode_attention_pallas_lookahead(
     )
     kernel = pl.pallas_call(
         functools.partial(
-            _kernel_lookahead, page_size=ps, tile_pages=TP,
+            _kernel_lookahead, page_size=page[0], tile_pages=TP,
             tiles_per_seq=pl.cdiv(page_tables.shape[1], TP), lookahead=W,
             quantized=quantized, window=window,
         ),
@@ -572,149 +656,42 @@ def paged_decode_attention_pallas_lookahead(
         # — pin it rather than relying on the implicit default
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        name=SLIDING_DECODE_NAME if window else None,
+        name=name,
     )
     args = (kq, vq, ks, vs) if quantized else (kq, vq)
     return kernel(page_tables.astype(jnp.int32), lengths, q, *args)
 
 
-def _kernel_folded(
-    *refs,
-    page_size: int,
-    max_pages: int,
-    num_kv_heads: int,
-    head_dim: int,
-    quantized: bool = False,
-):
-    """Decode attention for head_dim < 128 (e.g. TinyLlama/Qwen2-small: 64).
-
-    Mosaic can't DMA-slice an HBM pool whose minor dim is under the 128-lane
-    tile, so the pools arrive with kv heads FOLDED into the lane dim
-    ([ps, Hkv*D] rows, >= 128 lanes). The per-head math never unfolds in bf16:
-
-      - scores: Q is placed into a zero-padded folded layout (each q head
-        occupies its kv head's D-slice, zeros elsewhere), so one
-        [Hq, Hkv*D] x [ps, Hkv*D] matmul yields exact per-head scores —
-        the zero slices kill every cross-head term.
-      - output: probs @ V_folded gives [Hq, Hkv*D]; each head's true output
-        sits in its kv head's slice, selected with a one-hot contraction in
-        f32 (32-bit ops may reshape the minor dim; bf16 may not).
-
-    refs: page_tables + lengths (scalar prefetch) | q [1, Hq, D], k/v pools
-    [P, ps, Hkv*D] [, k/v scale rows [B*max_pages, 1, W], see
-    gather_scale_rows] | out | k/v scratch [2, ps, Hkv*D] [, scale scratch
-    [2, 1, W]], sems [2, 2|4]. The per-row int8 scale is head-independent,
-    so the folded scores/probs scale with the same [1, ps] rows as the
-    unfolded kernels.
-    """
-    if quantized:
-        (page_tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm,
-         out_ref, k_scratch, v_scratch, ks_scratch, vs_scratch, sems) = refs
-        pools = [(k_hbm, k_scratch), (v_hbm, v_scratch),
-                 (ks_hbm, ks_scratch), (vs_hbm, vs_scratch)]
-    else:
-        (page_tables_ref, lengths_ref, q_ref, k_hbm, v_hbm,
-         out_ref, k_scratch, v_scratch, sems) = refs
-        pools = [(k_hbm, k_scratch), (v_hbm, v_scratch)]
-
-    b = pl.program_id(0)
-    length = lengths_ref[b]
-    n_pages = jnp.maximum(1, pl.cdiv(length, page_size))
-
-    Hq, D = q_ref.shape[1], head_dim
-    Hkv = num_kv_heads
-    G = Hq // Hkv
-    F = Hkv * D  # folded lane width
-
-    q32 = q_ref[0].astype(jnp.float32)  # [Hq, D]
-    # Everything stays 2D — Mosaic (this version) rejects minor-dim reshapes
-    # outright. The folded-lane ownership mask [Hq, F]:
-    #   mask[h, f] = (f // D == h // G)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (Hq, F), 1)
-    head = jax.lax.broadcasted_iota(jnp.int32, (Hq, F), 0)
-    mask = (lane // D == head // G).astype(jnp.float32)
-    # folded q via lane-tiling: concat Hkv copies of q along lanes, zero all
-    # slices a head doesn't own
-    qtile = jnp.concatenate([q32] * Hkv, axis=1)  # [Hq, F]
-    qf = (qtile * mask).astype(q_ref.dtype)
-    scale = 1.0 / jnp.sqrt(jnp.float32(D))
-
-    def dma(slot, i, c):
-        hbm, scratch = pools[c]
-        # pages by physical id; scale rows by (sequence, logical page)
-        src = page_tables_ref[b, i] if c < 2 else b * max_pages + i
-        return pltpu.make_async_copy(hbm.at[src], scratch.at[slot], sems.at[slot, c])
-
-    for c in range(len(pools)):
-        dma(0, 0, c).start()
-
-    def body(i, carry):
-        m, l, acc = carry  # [Hq], [Hq], [Hq, F] f32
-        slot = jax.lax.rem(i, 2)
-        next_slot = jax.lax.rem(i + 1, 2)
-
-        @pl.when(i + 1 < n_pages)
-        def _():
-            for c in range(len(pools)):
-                dma(next_slot, i + 1, c).start()
-
-        for c in range(len(pools)):
-            dma(slot, i, c).wait()
-
-        k_page = k_scratch[slot]  # [ps, F] bf16 (or int8)
-        v_page = v_scratch[slot]
-        idx = i * page_size + jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
-        vidx = i * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (page_size, 1), 0
+@functools.partial(jax.jit, static_argnames=("interpret", "window"))
+def paged_decode_attention_pallas_lookahead(
+    q: jnp.ndarray,  # [B, Hq, D]
+    k_pages,  # [P, ps, Hkv, D] plain or QuantizedPages
+    v_pages,
+    page_tables: jnp.ndarray,  # [B, max_pages] int32
+    positions: jnp.ndarray,  # [B] int32 query positions
+    interpret: bool = False,
+    window: int = 0,  # sliding window in tokens (0: the whole context)
+) -> jnp.ndarray:
+    B, Hq, D = q.shape
+    P, ps, Hkv, _ = k_pages.shape
+    itemsize = k_pages.dtype.itemsize
+    W = lookahead_window(ps, Hkv, D, itemsize)
+    if W < 1:
+        if window:
+            raise ValueError("the page-at-a-time decode kernel takes no window")
+        return paged_decode_attention_pallas(
+            q, k_pages, v_pages, page_tables, positions, interpret=interpret
         )
+    return _tiled_decode(
+        q, k_pages, v_pages, page_tables, positions,
+        decode_tile_pages(ps, Hkv, D, itemsize), W, interpret=interpret,
+        window=window, name=SLIDING_DECODE_NAME if window else None,
+    )
 
-        # [Hq, ps] exact per-head scores via the folded contraction
-        # (int8 pages upcast to f32 for the dot — operand dtypes must match)
-        scores = jax.lax.dot_general(
-            qf.astype(jnp.float32) if quantized else qf,
-            k_page.astype(jnp.float32) if quantized else k_page,
-            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        if quantized:
-            scores = scores * ks_scratch[slot][:, :page_size]  # [1, ps] per-row K scales
-        scores = jnp.where(idx < length, scores, _NEG_INF)
-        v_page = jnp.where(vidx < length, v_page, 0)
 
-        chunk_max = jnp.max(scores, axis=-1)  # [Hq]
-        new_m = jnp.maximum(m, chunk_max)
-        corr = jnp.exp(m - new_m)
-        probs = jnp.exp(scores - new_m[:, None])  # [Hq, ps]
-        new_l = l * corr + jnp.sum(probs, axis=-1)
-        # [Hq, F] = [Hq, ps] x [ps, F]
-        if quantized:
-            probs = probs * vs_scratch[slot][:, :page_size]  # V scales fold into probs
-            chunk_out = jax.lax.dot_general(
-                probs, v_page.astype(jnp.float32),
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-        else:
-            chunk_out = jax.lax.dot_general(
-                probs.astype(v_page.dtype), v_page,
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-        new_acc = acc * corr[:, None] + chunk_out
-        return new_m, new_l, new_acc
-
-    m0 = jnp.full((Hq,), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((Hq,), jnp.float32)
-    acc0 = jnp.zeros((Hq, F), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, n_pages, body, (m0, l0, acc0))
-
-    # select each head's slice: zero un-owned lanes, then fold the Hkv
-    # D-wide lane slices together (only the owned one is nonzero)
-    acc_m = acc * mask
-    out = acc_m[:, 0:D]
-    for j in range(1, Hkv):
-        out = out + acc_m[:, j * D : (j + 1) * D]
-    out = out / jnp.maximum(l, 1e-20)[:, None]
-    out_ref[0] = out.astype(out_ref.dtype)
+#: the folded calls' name on the device's operation line (two readers of the
+#: benchmark find the kernel by it)
+FOLDED_DECODE_NAME = "paged_decode_attention_pallas_folded"
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -726,8 +703,11 @@ def paged_decode_attention_pallas_folded(
     positions: jnp.ndarray,  # [B] int32 query positions
     interpret: bool = False,
 ) -> jnp.ndarray:
-    B, Hq, D = q.shape
-    lengths = positions.astype(jnp.int32) + 1
+    """Decode attention for head_dim < 128 (TinyLlama, Qwen2-small, LFM2: 64)
+    and for one kv head a tensor-parallel shard: the tiled walk of
+    ``_kernel_lookahead`` over pools whose kv heads are folded into the lane
+    dim, with its folded merge."""
+    D = q.shape[-1]
     if k_pages.ndim == 4:
         # direct-call convenience (tests): fold here. Serving passes pools
         # ALREADY folded (LlamaConfig.kv_folded) — reshaping a donated,
@@ -739,42 +719,14 @@ def paged_decode_attention_pallas_folded(
         else:
             k_pages = k_pages.reshape(P, ps, Hkv * D)
             v_pages = v_pages.reshape(P, ps, Hkv * D)
-    kf, vf, ks, vs, quantized = _decode_unpack_pools(k_pages, v_pages, page_tables)
-    P, ps, F = kf.shape
-    Hkv = F // D
-
-    scratch_shapes = [
-        pltpu.VMEM((2, ps, Hkv * D), kf.dtype),
-        pltpu.VMEM((2, ps, Hkv * D), vf.dtype),
-    ]
-    if quantized:
-        scratch_shapes += [
-            pltpu.VMEM((2, 1, ks.shape[-1]), jnp.float32),
-            pltpu.VMEM((2, 1, vs.shape[-1]), jnp.float32),
-        ]
-    C = 4 if quantized else 2
-    scratch_shapes.append(pltpu.SemaphoreType.DMA((2, C)))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, Hq, D), lambda b, *_: (b, 0, 0)),
-            *[pl.BlockSpec(memory_space=pl.ANY) for _ in range(C)],
-        ],
-        out_specs=pl.BlockSpec((1, Hq, D), lambda b, *_: (b, 0, 0)),
-        scratch_shapes=scratch_shapes,
-    )
-    kernel = pl.pallas_call(
-        functools.partial(
-            _kernel_folded, page_size=ps, max_pages=page_tables.shape[1],
-            num_kv_heads=Hkv, head_dim=D, quantized=quantized,
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
-        grid_spec=grid_spec,
-        interpret=interpret,
-    )
-    args = (kf, vf, ks, vs) if quantized else (kf, vf)
-    return kernel(page_tables.astype(jnp.int32), lengths, q, *args)
+    # the folded row of Hkv * D lanes is one head to the walk
+    geometry = (k_pages.shape[1], 1, k_pages.shape[2], k_pages.dtype.itemsize)
+    W = lookahead_window(*geometry)
+    if W < 1:  # a page of 128 tokens by 4096 lanes: no kernel here walks such a pool
+        raise ValueError(f"no tile of a folded pool {k_pages.shape} fits the decode kernel's VMEM")
+    return _tiled_decode(q, k_pages, v_pages, page_tables, positions,
+                         decode_tile_pages(*geometry), W,
+                         interpret=interpret, name=FOLDED_DECODE_NAME)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -624,7 +624,7 @@ def _kernel_folded(
     quantized: bool,
 ):
     """Folded-lane flash prefill for head_dim < 128 (see the decode
-    _kernel_folded in paged_attention.py for the trick): every (query row,
+    _kernel_lookahead in paged_attention.py for the trick): every (query row,
     head) pair becomes one row of a zero-placed folded Q [Bq*Hq, Hkv*D], so a
     single [R, F] x [S, F] matmul yields exact per-head scores — the zero
     slices kill cross-head terms and cost only Hkv x extra MACs on an op

@@ -40,7 +40,7 @@ class CompileMonitor:
         self.last_ts: Optional[float] = None
         self.per_label: dict[str, int] = {}
 
-    def record(self, label: str, seconds: float, count: int = 1) -> None:
+    def record(self, label: str, seconds: float, count: int = 1, what: str = "") -> None:
         with self._lock:
             self.compiles += count
             self.compile_s += seconds
@@ -50,7 +50,10 @@ class CompileMonitor:
         if seconds > 1.0:
             # a slow compile mid-serving is worth a log line even without
             # Prometheus scraping: it is the stall the caller just felt
-            log.info("xla compile: %s took %.2fs (%d total)", label, seconds, self.compiles)
+            # `what` names the program among the label's variants: without it
+            # a log cannot say WHICH shape traffic met first
+            log.info("xla compile: %s took %.2fs (%d total)%s", label, seconds,
+                     self.compiles, f" [{what}]" if what else "")
 
     def snapshot(self) -> dict:
         with self._lock:
@@ -66,6 +69,20 @@ class CompileMonitor:
                 "last_compile_age_s": age,
                 "per_label": dict(self.per_label),
             }
+
+
+def _describe(args, kwargs) -> str:
+    """What tells one executable of a jitted function from another, as far as
+    a call shows it: the shapes of its small integer arrays (the packed
+    control arrays of the engine's step programs carry lanes, rows and table
+    width in theirs) and its static keywords that are set. Built only when a
+    call compiled."""
+    shapes = ["x".join(map(str, a.shape)) for a in args
+              if getattr(a, "ndim", 0) == 2 and getattr(a, "dtype", None) is not None
+              and a.dtype.kind == "i"]
+    flags = [k if v is True else f"{k}={v}" for k, v in kwargs.items()
+             if isinstance(v, (bool, int)) and v]
+    return " ".join(shapes + flags)
 
 
 class _MonitoredJit:
@@ -95,7 +112,8 @@ class _MonitoredJit:
         if before is not None:
             after = self._cache_size()
             if after is not None and after > before:
-                self._monitor.record(self._label, self._clock() - t0, after - before)
+                self._monitor.record(self._label, self._clock() - t0, after - before,
+                                     _describe(args, kwargs))
         return result
 
     def __getattr__(self, name):

@@ -39,6 +39,7 @@ def _prefill(T, hq, hkv, d, ps, width=8, **pool):
 
 
 LOOKAHEAD = "pallas:paged_decode_attention_pallas_lookahead"
+FOLDED = "pallas:paged_decode_attention_pallas_folded"
 
 #: id: op, arguments, tp, what the logged path must contain, and the reason
 TABLE = [
@@ -51,10 +52,23 @@ TABLE = [
     # 32 kv heads at page 128: four tiles of 2 MiB overrun the 6 MiB budget
     pytest.param("decode", _decode(32, 32, 128, 128), 1,
         [LOOKAHEAD, "window=0:perseq"], "", id="decode-window-0"),
+    # folded pools take the same walk, the folded row of Hkv * D lanes one head
     pytest.param("decode", _decode(32, 4, 64, 16, folded=True), 1,
-        ["pallas:paged_decode_attention_pallas_folded"], "D=64", id="decode-folded-d64"),
+        [FOLDED, "tile=8x16 window=2"], "D=64", id="decode-folded-d64"),
     pytest.param("decode", _decode(32, 4, 64, 16), 1,
-        ["pallas:paged_decode_attention_pallas_folded"], "", id="decode-d64-unfolded-pool"),
+        [FOLDED, "tile=8x16 window=2"], "", id="decode-d64-unfolded-pool"),
+    pytest.param("decode", _decode(32, 8, 64, 16, folded=True), 1,
+        [FOLDED, "tile=8x16 window=2"], "Hkv=8 D=64", id="decode-folded-lfm2"),
+    pytest.param("decode", _decode(32, 8, 64, 16, int8=True, folded=True), 1,
+        [FOLDED, "tile=8x16 window=2"], "", id="decode-folded-int8"),
+    pytest.param("decode", _decode(32, 8, 64, 128, folded=True), 1,
+        [FOLDED, "tile=1x128 window=4"], "", id="decode-folded-ps128"),
+    # one kv head of 128 a shard: 128 folded lanes each
+    pytest.param("decode", _decode(28, 4, 128, 16, folded=True), 4,
+        [FOLDED, "tile=8x16 window=2", "shard_map tp=4"], "", id="decode-folded-tp4-one-kv-head"),
+    # a page of 128 tokens by 4096 lanes: four of them overrun the budget
+    pytest.param("decode", _decode(64, 64, 64, 128, folded=True), 1,
+        ["reference"], "no tile of the folded pool fits VMEM", id="decode-folded-window-0"),
     pytest.param("decode", _decode(4, 2, 80, 16), 1,
         ["reference"], "no Pallas kernel for this backend/shape", id="decode-d80-not-lane-aligned"),
     pytest.param("decode", _decode(16, 8, 128, 16), 4,

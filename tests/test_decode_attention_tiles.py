@@ -1,6 +1,7 @@
 """The tiled lookahead decode kernel (interpret mode) against the gather
 reference, at the boundaries its loop structure has: a tile of pages per
-iteration, a cross-program window of W tiles, a double-buffered tail."""
+iteration, a cross-program window of W tiles, a double-buffered tail; over
+pools of head_dim 128 and, through the same walk, over folded pools."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -10,6 +11,7 @@ from dynamo_tpu.ops.attention import paged_decode_attention
 from dynamo_tpu.ops.pallas.paged_attention import (
     decode_tile_pages,
     lookahead_window,
+    paged_decode_attention_pallas_folded,
     paged_decode_attention_pallas_lookahead,
 )
 from dynamo_tpu.quant.kv import QuantizedPages, quantize_kv_rows
@@ -42,27 +44,31 @@ CASES = {
 }
 
 
-def _pools(rng, P, ps, hkv, dtype, int8):
+def _pools(rng, P, ps, hkv, dtype, int8, d=D, folded=False):
+    shape = (P, ps, hkv * d) if folded else (P, ps, hkv, d)
     out = []
     for _ in range(2):
-        x = jnp.asarray(rng.standard_normal((P, ps, hkv, D), dtype=np.float32), dtype)
+        x = jnp.asarray(rng.standard_normal((P, ps, hkv, d), dtype=np.float32), dtype)
         if int8:
-            q, s = quantize_kv_rows(x.reshape(P * ps, hkv, D))
-            x = QuantizedPages(q.reshape(P, ps, hkv, D), s.reshape(P, ps))
-        out.append(x)
+            q, s = quantize_kv_rows(x.reshape(P * ps, hkv, d))
+            x = QuantizedPages(q.reshape(shape), s.reshape(P, ps))
+        out.append(x if int8 else x.reshape(shape))
     return out
 
 
 B = 5  # odd: the last program's parity has no successor to prefetch for
 
 
-def _batch(rng, ps, lengths):
+def _batch(rng, ps, lengths, deep=False):
     """Disjoint page tables (page 0 is the padding every table ends in) and
     positions for B rows; a length of 0, and every row past ``lengths``, is a
     padded row, which the engine sends as position 0 over the trash page. One
-    shape per page size, so cases of a geometry share a compiled kernel."""
+    shape per page size (and one for a ``deep`` context of thousands of tokens), so
+    cases of a geometry share a compiled kernel."""
     lengths = list(lengths) + [0] * (B - len(lengths))
     P, max_pages = (128, 48) if ps < 128 else (24, 8)
+    if deep:  # lfm2-8b-a1b-d16's last rung: 320 pages, contexts to 4864 tokens
+        P, max_pages = 384, 320
     order = 1 + rng.permutation(P - 1)
     tables = np.zeros((B, max_pages), np.int32)
     at = 0
@@ -103,18 +109,21 @@ def test_tiled_decode_matches_reference(name):
     assert np.isfinite(np.asarray(got, np.float32)).all()
 
 
+@pytest.mark.parametrize("folded", [False, True], ids=["heads", "folded"])
 @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
-def test_stale_values_in_unused_pages_do_not_reach_the_output(int8):
+def test_stale_values_in_unused_pages_do_not_reach_the_output(int8, folded):
     """Non-finite values in every page and scale row no sequence owns, and in
     the unused tail of each sequence's last page: the output is the one the
-    clean pool gives."""
-    hq, hkv, ps = 8, 4, PS
+    clean pool gives. Over pools [P, ps, 4, 128] and folded [P, ps, 8 * 64]."""
+    hq, hkv, d, ps = (16, 8, 64, PS) if folded else (8, 4, D, PS)
+    kernel = (paged_decode_attention_pallas_folded if folded
+              else paged_decode_attention_pallas_lookahead)
     rng = np.random.default_rng(11)
     P, tables, positions, lengths, owned = _batch(
         rng, ps, [1, TILE + 1, 0, (W + 1) * TILE + PS + 2, PS - 1])
-    k, v = _pools(rng, P, ps, hkv, "bfloat16", int8)
-    q = jnp.asarray(rng.standard_normal((B, hq, D), dtype=np.float32), jnp.bfloat16)
-    want = paged_decode_attention_pallas_lookahead(q, k, v, tables, positions, interpret=True)
+    k, v = _pools(rng, P, ps, hkv, "bfloat16", int8, d, folded)
+    q = jnp.asarray(rng.standard_normal((B, hq, d), dtype=np.float32), jnp.bfloat16)
+    want = kernel(q, k, v, tables, positions, interpret=True)
 
     stale = np.ones((P, ps), bool)
     stale[0, 0] = False  # the padded row's one token, on the trash page
@@ -122,17 +131,80 @@ def test_stale_values_in_unused_pages_do_not_reach_the_output(int8):
         for i in range(-(-n // ps)):
             stale[int(tables[b, i]), : min(ps, n - i * ps)] = False
     assert stale[sorted(set(range(1, P)) - owned)].all() and not stale.all()
+    rows = stale.reshape(P, ps, *[1] * (k.ndim - 2))  # against a page's head and lane dims
 
     def plant(pool, bad):
         if int8:
-            return QuantizedPages(
-                jnp.where(stale[..., None, None], jnp.int8(127), pool.q),
-                jnp.where(stale, bad, pool.s),
-            )
-        return jnp.where(stale[..., None, None], jnp.asarray(bad, pool.dtype), pool)
+            return QuantizedPages(jnp.where(rows, jnp.int8(127), pool.q),
+                                  jnp.where(stale, bad, pool.s))
+        return jnp.where(rows, jnp.asarray(bad, pool.dtype), pool)
 
-    got = paged_decode_attention_pallas_lookahead(
-        q, plant(k, jnp.nan), plant(v, jnp.inf), tables, positions, interpret=True
-    )
+    got = kernel(q, plant(k, jnp.nan), plant(v, jnp.inf), tables, positions, interpret=True)
     np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+    assert np.isfinite(np.asarray(got, np.float32)).all()
+
+
+# ---------------------------------------------------------------- folded pools
+# (head_dim under 128, or one kv head a tensor-parallel shard): the same walk
+# with the folded row of Hkv * D lanes taken as one head, so the same tile of
+# 128 tokens and window of two tiles at page 16, whatever the lanes
+
+#: name -> (Hq, Hkv, D, pool dtype, int8, pool rank, lengths of the first rows)
+FOLDED_CASES = {
+    # F = 512: lfm2-8b-a1b's 8 kv heads of 64
+    "f512_tile_edges": (16, 8, 64, "bfloat16", False, 3, [1, TILE - 1, TILE, TILE + 1, 2 * TILE - 1]),
+    "f512_window_edges": (16, 8, 64, "bfloat16", False, 3, [W * TILE + 1, 0, W * TILE, 0, (W + 1) * TILE + PS + 1]),
+    "f512_empty_between_live": (16, 8, 64, "bfloat16", False, 3, [TILE + 3, 0, 1, 0, 3 * TILE]),
+    "f512_two_tile_edges": (16, 8, 64, "bfloat16", False, 3, [2 * TILE - 1, 2 * TILE + 1, 1, TILE - 1, 2 * TILE]),
+    "f512_context_4096": (16, 8, 64, "bfloat16", False, 3, [4096, 1, TILE + 2]),
+    # the longest context `lfm2-8b-a1b-d16.rag-over` decodes: 4096 + 768, on the last rung
+    "f512_context_4864": (16, 8, 64, "bfloat16", False, 3, [TILE + 1, 0, 4864]),
+    "f512_float32": (16, 8, 64, "float32", False, 3, [5, W * TILE + 9, 1, 2 * TILE - 1, (W + 2) * TILE]),
+    "f512_int8": (16, 8, 64, "bfloat16", True, 3, [1, TILE, (W + 1) * TILE + 6]),
+    "f512_int8_context_4096": (16, 8, 64, "bfloat16", True, 3, [4096, 0, TILE - 1]),
+    # F = 256: TinyLlama's 4 kv heads of 64
+    "f256_bf16": (32, 4, 64, "bfloat16", False, 3, [TILE + 1, 0, 1, 0, (W + 3) * TILE + 3]),
+    "f256_float32": (8, 4, 64, "float32", False, 3, [TILE - 1, W * TILE + 1, 0, PS, (W + 1) * TILE]),
+    "f256_int8": (8, 4, 64, "bfloat16", True, 3, [TILE + 1, (W + 1) * TILE + 2, 3]),
+    # F = 128: one kv head of 128 a tensor-parallel shard
+    "f128_one_kv_head": (7, 1, 128, "bfloat16", False, 3, [TILE - 1, (W + 1) * TILE + 1, 0, W * TILE, PS]),
+    "f128_one_kv_head_float32": (7, 1, 128, "float32", False, 3, [1, W * TILE + 1, 2 * TILE]),
+    "f128_one_kv_head_int8": (7, 1, 128, "bfloat16", True, 3, [TILE, 0, (W + 2) * TILE - 1]),
+    # the direct call's convenience: a pool that is not folded yet
+    "rank4_direct": (8, 4, 64, "bfloat16", False, 4, [TILE + 1, 0, (W + 1) * TILE + 5]),
+    "rank4_direct_int8": (8, 4, 64, "bfloat16", True, 4, [1, W * TILE + 1, TILE]),
+}
+
+
+def test_geometry_of_the_folded_cases():
+    # the folded row is one head to the walk: lfm2's 512 lanes, TinyLlama's 256
+    # and a shard's 128 all get the tile of 8 pages and the window of two
+    for lanes in (128, 256, 512):
+        for itemsize in (1, 2, 4):
+            assert decode_tile_pages(PS, 1, lanes, itemsize) == TP
+            assert lookahead_window(PS, 1, lanes, itemsize) == W
+    # a K+V tile of 512 lanes is 256 KiB: six of them are well inside the budget
+    assert 6 * 2 * TP * PS * 512 * 2 == 1536 * 1024
+    # where four tiles of 128 tokens overrun it the tile narrows, as for rank 4
+    assert decode_tile_pages(PS, 1, 4096, 4) == 2
+    # and a page that four of do not fit has no kernel: the dispatcher refuses it
+    assert lookahead_window(128, 1, 4096, 2) == 0
+
+
+@pytest.mark.parametrize("name", FOLDED_CASES)
+def test_folded_tiled_decode_matches_reference(name):
+    hq, hkv, d, dtype, int8, rank, lengths = FOLDED_CASES[name]
+    rng = np.random.default_rng(100 + sorted(FOLDED_CASES).index(name))
+    P, tables, positions, lengths, _ = _batch(rng, PS, lengths, deep=max(lengths) > 768)
+    k, v = _pools(rng, P, PS, hkv, dtype, int8, d, folded=rank == 3)
+    q = jnp.asarray(rng.standard_normal((B, hq, d), dtype=np.float32), dtype)
+    got = paged_decode_attention_pallas_folded(q, k, v, tables, positions, interpret=True)
+    want = paged_decode_attention(q, k, v, tables, positions)
+    live = lengths > 0
+    # bf16 pools: the probabilities go to the MXU in bf16 too, as they did a
+    # page at a time; one bf16 ulp of an output near 2 still holds them
+    atol = 2e-5 if dtype == "float32" else 2e-2
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32)[live], np.asarray(want, np.float32)[live], atol=atol
+    )
     assert np.isfinite(np.asarray(got, np.float32)).all()

@@ -178,6 +178,25 @@ def test_monitored_jit_counts_compiles():
     assert snap["last_label"] == "add"
 
 
+def test_a_compile_log_line_says_which_program(monkeypatch):
+    """A label has many executables (one per lanes x rows x table width): the
+    line of a slow compile carries the shapes of the call's integer arrays and
+    its static keywords that are set."""
+    import numpy as np
+
+    from dynamo_tpu.utils import compile_monitor
+    from dynamo_tpu.utils.compile_monitor import _describe
+
+    ints, flts = np.zeros((4, 215), np.int32), np.zeros((6, 4), np.float32)
+    assert _describe((None, ints, flts), {"mp": 128, "want_lp": True, "want_pen": False}) == "4x215 mp=128 want_lp"
+    assert _describe((), {}) == ""
+    lines = []
+    monkeypatch.setattr(compile_monitor.log, "info", lambda fmt, *a: lines.append(fmt % a))
+    CompileMonitor().record("prefill_packed", 2.5, what="4x215 mp=128")
+    CompileMonitor().record("prefill_packed", 0.5, what="4x215 mp=128")  # fast: no line
+    assert lines == ["xla compile: prefill_packed took 2.50s (1 total) [4x215 mp=128]"]
+
+
 def test_monitored_jit_passthrough_without_monitor():
     def fn(x):
         return x

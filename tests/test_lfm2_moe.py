@@ -417,7 +417,8 @@ def test_the_folded_dispatch_takes_a_kernel_and_agrees_with_the_gather(name, Hq,
     want = attn_ops.paged_decode_attention(qd, k_pool, v_pool, tables, at)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
     assert dict(seen)["prefill"].startswith("pallas:folded"), seen
-    assert dict(seen)["decode"].startswith("pallas:paged_decode_attention_pallas_folded"), seen
+    assert dict(seen)["decode"].startswith(
+        "pallas:paged_decode_attention_pallas_folded tile=8x16 window=2"), seen
     assert ("block_q=32" in dict(seen)["prefill"]) == (block_q == 32)
 
 

@@ -252,21 +252,30 @@ def test_a_chunk_as_blocks_of_one_call(model_id):
 def test_every_program_the_packer_can_emit_is_in_warm_ups_list(model_id):
     """The sets compared, nothing compiled: every (N, T) of the packer on
     every rung of the page-table ladder; for the blocks, all of it on the first
-    and the last rung before readiness."""
-    over = dict(prefill_buckets=(64, 128, 256, 512), max_model_len=8192, page_size=16, num_pages=64)
-    if model_id not in ("tiny-hybrid", "tiny-conv"):  # the recurrent contract's two signers
+    and the last rung before readiness; for the rectangles on a ladder of four
+    rungs, the parent's lists."""
+    recurrent = model_id in ("tiny-hybrid", "tiny-conv")  # the recurrent contract's two signers
+    over = dict(prefill_buckets=(64, 128, 256, 512), page_size=16, num_pages=64,
+                max_model_len=16384 if recurrent else 8192)
+    if not recurrent:
         over["prefill_lanes"] = 2  # the blocks take no count from it
     eng = _hand_engine(model_id, **over)
     runner, c = eng.runner, eng.config
-    assert c.table_buckets == (128, 256, 512)
+    assert c.table_buckets == ((128, 256, 512, 1024) if recurrent else (128, 256, 512))
     core, later = runner.packed_warmup_shapes()
     assert len(set(core + later)) == len(core + later)
     if runner.recurrent:
-        # the parent's lists: 11 (bucket, power-of-two N) shapes on the first
-        # rung, 8 of them before readiness, and one chunk at N = 1 a wider rung
+        # a ladder past three rungs: 11 (bucket, power-of-two N) shapes on the
+        # first rung, 8 of them before readiness, and one chunk at N = 1 a
+        # wider rung. What a long prompt's packs meet beyond that (N over 1 on
+        # the wider rungs, the shorter buckets there) compiles in traffic: 33
+        # more programs before readiness would hold a start-up for every one
+        assert not runner.warms_every_rung
+        assert [c.lanes_for(b) for b in c.prefill_buckets] == [4, 4, 4, 2]
+        assert [c.lanes_for(b, wide=True) for b in c.prefill_buckets] == [2, 2, 2, 2]
         assert sorted(core) == sorted((n, b, 128) for b in c.prefill_buckets for n in {1, c.lanes_for(b)})
         assert len([s for s in core + later if s[2] == 128]) == 11
-        assert [s for s in later if s[2] != 128] == [(1, 512, 256), (1, 512, 512)]
+        assert [s for s in later if s[2] != 128] == [(1, 512, 256), (1, 512, 512), (1, 256, 1024)]
         return
     # what the packer emits: driven over many pending sets, not read off the runner
     rng = np.random.default_rng(7)
@@ -283,3 +292,111 @@ def test_every_program_the_packer_can_emit_is_in_warm_ups_list(model_id):
     # decode: 3 variants + 2 wider rungs; per-request: 3 variants + 4 buckets;
     # packed: 8 on the middle rung + 3 feature variants x 8 on the first
     assert len(thunks) == 3 + 2 + 3 + 4 + 8 + 3 * 8
+
+
+@pytest.mark.parametrize("model_id,max_model_len,ladder", [
+    ("tiny-conv", 5120, (128, 256, 320)),  # lfm2-8b-a1b-d16's ladder
+    ("tiny-hybrid", 4096, (128, 256)),  # nemotron3-super-ep4's
+    ("tiny-hybrid", 8192, (128, 256, 512)),
+])
+def test_a_recurrent_model_on_a_short_ladder_meets_no_new_step_program_in_traffic(
+        monkeypatch, model_id, max_model_len, ladder):
+    """Nothing compiled: the scheduler's own rectangle packer driven over
+    pending sets of every kind (prompts of every length at every chunk the
+    planner cuts, one to eight at once), and warm-up's core with the two
+    runner calls that compile replaced by a record. Every (lanes, bucket,
+    table width) the packer emits and a decode window on every rung are
+    compiled BEFORE readiness, and nothing is left behind it.
+
+    (PR 44: with N = 1 and N = lanes_for on the first rung alone before
+    readiness, `lfm2-8b-a1b-d16.rag-over` opened every window with five of
+    the then 33 rectangles never compiled, all of them four lanes on the 256
+    or the 320 rung; the driver read one run of the same change (PR 43) as not
+    `correct`, and a compile in the window is one of the three things it checks.
+    A pack that holds a lane beyond the first rung now takes two lanes at
+    most, so those five and (4, 128, 256) are no programs any more: 11
+    rectangles on the first rung and 8 on each wider one.)"""
+    from types import SimpleNamespace
+
+    from dynamo_tpu.engine.scheduler import Scheduler
+
+    eng = _hand_engine(model_id, prefill_buckets=(64, 128, 256, 512), page_size=16,
+                       num_pages=64, max_model_len=max_model_len, max_seqs=8)
+    runner, c = eng.runner, eng.config
+    assert c.table_buckets == ladder and runner.warms_every_rung
+
+    rng = np.random.default_rng(44)
+    emitted = set()
+    for _ in range(6000):
+        pending = []
+        for slot in range(int(rng.integers(1, 9))):
+            # some whole chunks and a tail of any bucket, as often at its tail
+            # as anywhere: four tails of 64 beside a long prompt are rare otherwise
+            tail = int(rng.integers(1, rng.choice(c.prefill_buckets) + 1))
+            prompt = min(int(rng.integers(0, 11)) * c.max_prefill_chunk + tail, max_model_len - 1)
+            cuts = [0]
+            while cuts[-1] + c.chunk_len_for(cuts[-1]) < prompt:
+                cuts.append(cuts[-1] + c.chunk_len_for(cuts[-1]))
+            pages = -(-prompt // c.page_size)
+            pending.append(SimpleNamespace(
+                prefill_pos=int(rng.choice([cuts[-1], rng.choice(cuts)])), prompt_len=prompt,
+                slot=slot, finished=False,
+                page_table=np.zeros(c.table_bucket_for(pages), np.int32)))
+        me = SimpleNamespace(config=c, grouped=False, slots=pending)
+        backlog = sum(s.prompt_len - s.prefill_pos for s in pending)
+        chunks, bucket, N = Scheduler._pack_rectangle(me, pending, backlog, [])
+        # the width as `_dispatch_prefill_batches` and `pack_prefill_lanes` take it
+        width = c.table_bucket_for(max(s.page_table.shape[-1] for s, _, _ in chunks))
+        emitted.add((N, bucket, width))
+    core, later = runner.packed_warmup_shapes()
+    assert later == [] and len(core) == len(set(core)) == 11 + 8 * (len(ladder) - 1)
+    assert emitted == set(core)
+    assert {n for n, _, w in emitted if w > ladder[0]} == {1, 2}
+
+    compiled = {"packed": [], "windows": []}
+    monkeypatch.setattr(runner, "_warm_packed", lambda n, t, w: compiled["packed"].append((n, t, w)))
+    monkeypatch.setattr(runner, "dispatch_decode_window",
+                        lambda pos, tables, *a, **kw: compiled["windows"].append((tables.shape, a[-1], kw)))
+    runner.warmup_core()
+    assert compiled["packed"] == core
+    # `_batch_tables`: a window is as wide as its widest sequence's rung
+    assert compiled["windows"] == [((8, w), c.decode_steps, {}) for w in ladder]
+    # behind readiness: the feature variants and the per-request trace alone
+    # (decode 3, per-request 3 + 4, packed 3 x 11 on the first rung)
+    assert len(runner.warmup_extra_thunks()) == 3 + 3 + 4 + 3 * 11
+
+
+#: name -> (pending [(prompt_len, prefill_pos)], the pack [(N, bucket, width), lanes taken])
+WIDE_PACKS = {
+    # four short chunks on the first rung: one pack of four, as ever
+    "four_short": ([(200, 0), (100, 0), (60, 0), (50, 0)], ((4, 256, 128), 4)),
+    # the first is the tail of a prompt of 2248 tokens (141 pages: the 256 rung):
+    # the parent packed (4, 256, 256), a program no warm-up met
+    "deep_tail_first": ([(2248, 2048), (100, 0), (60, 0), (50, 0)], ((2, 256, 256), 2)),
+    # the deep one comes third: the pack closes before it would be the third lane
+    "deep_tail_third": ([(100, 0), (60, 0), (2248, 2048), (50, 0)], ((2, 128, 128), 2)),
+    # and second: two lanes, the pack is on the wider rung
+    "deep_tail_second": ([(100, 0), (4200, 4096), (60, 0)], ((2, 128, 320), 2)),
+    # chunks of 512 were two a pack on every rung already
+    "deep_heads": ([(4000, 512), (3000, 1024), (100, 0)], ((2, 512, 256), 2)),
+    "deep_alone": ([(4000, 3584)], ((1, 512, 256), 1)),
+}
+
+
+@pytest.mark.parametrize("name", WIDE_PACKS)
+def test_a_rectangle_beyond_the_first_rung_takes_two_lanes(name):
+    from types import SimpleNamespace
+
+    from dynamo_tpu.engine.scheduler import Scheduler
+
+    c = EngineConfig(model_id="tiny-conv", page_size=16, max_model_len=5120, num_pages=64,
+                     prefill_buckets=(64, 128, 256, 512), prefill_lanes=4)
+    asked, (shape, taken) = WIDE_PACKS[name]
+    pending = [SimpleNamespace(prefill_pos=pos, prompt_len=n, slot=i, finished=False,
+                               page_table=np.zeros(c.table_bucket_for(-(-n // c.page_size)), np.int32))
+               for i, (n, pos) in enumerate(asked)]
+    me = SimpleNamespace(config=c, grouped=False, slots=pending)
+    chunks, bucket, N = Scheduler._pack_rectangle(me, pending, sum(n - p for n, p in asked), [])
+    width = c.table_bucket_for(max(s.page_table.shape[-1] for s, _, _ in chunks))
+    assert ((N, bucket, width), len(chunks)) == (shape, taken)
+    assert [s.slot for s, _, _ in chunks] == list(range(taken))  # admission order, no one skipped

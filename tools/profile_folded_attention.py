@@ -2,13 +2,18 @@
 `lfm2-8b-a1b-d16` serves (Hq 32, Hkv 8, D 64: pools of [pages, 16, 512] in
 bf16, a shuffled page table of 320 pages; PERF.md section 5, PR 42).
 
-  decode   `paged_decode_attention_pallas_folded`, a page at a time: batches
-           of 64, 128 and 256 sequences, each at a context of 512, 1536 and
-           4096 tokens. `roofline` is the K and V of every context token and
-           a query and an output row a sequence (`benchmark/costs.py`
+  decode   `paged_decode_attention_pallas_folded`, since PR 44 the tiled walk
+           (a tile of 8 pages = 128 context tokens an iteration, its 16 page
+           DMAs started and waited together, the next sequence's first two
+           tiles in flight; a page at a time until then): batches of 64, 128
+           and 256 sequences, each at a context of 512, 1536 and 4096 tokens.
+           `roofline` is the K and V of every context token and a query and
+           an output row a sequence (`benchmark/costs.py`
            `decode_attention_bytes`) over 819 GB/s, as a share of the time
            measured; `us_per_page` divides the time by the pages walked (K and
-           V of one page are one DMA each).
+           V of one page are one DMA of 16 KiB each; a tile is 8 of them, so
+           a tile's time is 8 times the figure: it is kept by the page so
+           that the tables before and after PR 44 read side by side).
   prefill  `paged_prefill_attention_pallas_folded` at the block of query rows
            the dispatcher gives this shape (`folded_prefill_block_q`: 32):
            chunks of 128, 256 and 512 rows that start at depths 0, 1024 and
@@ -102,7 +107,7 @@ def main() -> int:
 
     # decode: every sequence's pages are its own (a pool of B x table pages would
     # not fit at 256 x 320, so sequences share a pool of 64 tables' worth and
-    # each table is a shuffle of its own: the walk is a page at a time all the same)
+    # each table is a shuffle of its own: the walk fetches page by page all the same)
     pool_pages = TABLE_PAGES * min(64, max(BATCHES)) + 1
     kk, kv, kq = jax.random.split(jax.random.key(42), 3)
     k = jax.random.normal(kk, (pool_pages, PS, F), jnp.bfloat16)

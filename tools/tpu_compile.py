@@ -15,6 +15,10 @@ option for this.
 
     JAX_PLATFORMS=cpu python tools/tpu_compile.py            # every case
     JAX_PLATFORMS=cpu python tools/tpu_compile.py --steps    # whole steps only
+    JAX_PLATFORMS=cpu python tools/tpu_compile.py --mosaic DIR   # the tier-1 kernel
+        # cases' Mosaic modules as text without debug locations, one file a case:
+        # run it before and after an edit to a kernel and `diff -r` the two
+        # directories to see which programs the edit changed (PR 44)
 
 ``tests/test_tpu_compile.py`` runs ``kernel_cases(full=False)`` in tier-1.
 """
@@ -53,6 +57,9 @@ COMMAND_A = ("command-a-plus", 128, 8, 128)
 COMMAND_A_WINDOW = 4096
 #: LFM2-8B-A1B: 32 query heads over 8 kv heads of 64, folded pools of 512 lanes
 LFM2 = ("lfm2-8b-a1b", 32, 8, 64)
+#: one tensor-parallel shard of qwen2.5-7b at tp=4: one kv head, which is under
+#: Mosaic's sublane pack, so the pool is folded (LlamaModel.kv_folded), 128 lanes
+TP4_SHARD = ("qwen2.5-7b-tp4-shard", 7, 1, 128)
 #: DeepSeek-V2-Lite: 16 heads, kv_lora_rank 512 + rope 64, latent padded to 640
 MLA_HEADS, MLA_DC, MLA_LATENT = 16, 512, 640
 
@@ -113,7 +120,9 @@ def _decode_case(geo, ps, int8, kernel=None, window=0, max_len=2048):
         fn = kernel or attention.dispatch_paged_decode_attention
         if window:
             fn = functools.partial(fn, window=window)
-        folded = d < 128
+        # LlamaModel.kv_folded's rule: head_dim under a lane row, or kv heads
+        # that do not fill Mosaic's sublane pack (2 rows of bf16, 4 of int8)
+        folded = d < 128 or hkv % (4 if int8 else 2) != 0
         return fn, (
             S((B, hq, d), jnp.bfloat16),
             _pools(S, num_pages, ps, hkv, d, int8, folded),
@@ -225,12 +234,15 @@ GROUPED_MATMUL_CASES = (
 
 def folded_cases() -> list[Case]:
     """`lfm2-8b-a1b-d16`: the folded decode and prefill kernels at 32 query / 8
-    kv heads of 64 (512 folded lanes: the prefill kernel takes 32 query rows a
-    program, `folded_prefill_block_q`), page 16, tables of 5120 tokens, and
+    kv heads of 64 (512 folded lanes: the decode kernel walks tiles of 8 pages,
+    16 DMAs of 16 KiB each, six tiles of scratch; the prefill kernel takes 32
+    query rows a program, `folded_prefill_block_q`), page 16, tables of 5120
+    tokens (the decode kernel on an int8 pool too, which no cell serves), and
     the grouped product at a bank of [32, 2048, 1792] and back (a decode step
     of 256 slots, a prefill pack of 1024 rows; 4 rows a token)."""
     return [
         _decode_case(LFM2, 16, False, max_len=5120),
+        _decode_case(LFM2, 16, True, max_len=5120),
         _prefill_case(LFM2, 16, 128, False, max_len=5120),
         _prefill_case(LFM2, 16, 1024, False, max_len=5120),
         _grouped_matmul_case("lfm2-decode-w1", 256 * 4, 2048, 1792, held=32),
@@ -274,6 +286,8 @@ def kernel_cases(full: bool) -> list[Case]:
             for ps in (16, 64, 128):
                 for int8 in (False, True):
                     cases.append(_decode_case(geo, ps, int8))
+                    if geo is qwen:
+                        cases.append(_decode_case(TP4_SHARD, ps, int8))
                     buckets = (64, 128, 256, 512, 1024) if geo[3] < 128 else (128, 256, 512, 1024)
                     cases += [_prefill_case(geo, ps, T, int8) for T in buckets]
         cases += [_prefill_case(QWEN25_3B, 16, 512, False, max_len=8192),
@@ -289,10 +303,12 @@ def kernel_cases(full: bool) -> list[Case]:
         cases.append(_grouped_matmul_case("prefill-w2", 1024 * 22, 2688, 1024))
         return cases + window_cases() + folded_cases()
     return [
-        # decode: folded, lookahead, and the per-sequence kernel lookahead
-        # falls back to; int8 at page size < 128 was refused (scale-plane
-        # slice not aligned to the 128 tiling)
+        # decode: folded (256 lanes, and the 128 of one kv head a tp=4 shard),
+        # lookahead, and the per-sequence kernel lookahead falls back to; int8
+        # at page size < 128 was refused (scale-plane slice not aligned to the
+        # 128 tiling)
         _decode_case(tiny, 16, False), _decode_case(tiny, 16, True),
+        _decode_case(TP4_SHARD, 16, False), _decode_case(TP4_SHARD, 16, True),
         _decode_case(qwen, 16, False), _decode_case(qwen, 16, True),
         _decode_case(mixtral, 128, False), _decode_case(mixtral, 64, True),
         _decode_case(qwen, 16, True, kernel=paged_decode_attention_pallas),
@@ -329,9 +345,9 @@ def kernel_cases(full: bool) -> list[Case]:
     ]
 
 
-def compile_case(case: Case, topo=None):
-    """Lower and compile one case for the first described chip; raises what
-    the chip's compiler raises."""
+def _lower_case(case: Case, topo=None):
+    """One case lowered for the first described chip (inside
+    ``on_chip_dispatch()``)."""
     from jax.sharding import SingleDeviceSharding
 
     topo = topo or topology()
@@ -340,9 +356,39 @@ def compile_case(case: Case, topo=None):
     def S(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
+    fn, args = case.build(S)
+    return jax.jit(fn).lower(*args)
+
+
+def compile_case(case: Case, topo=None):
+    """Lower and compile one case for the first described chip; raises what
+    the chip's compiler raises."""
     with on_chip_dispatch():
-        fn, args = case.build(S)
-        return jax.jit(fn).lower(*args).compile()
+        return _lower_case(case, topo).compile()
+
+
+def mosaic_text(case: Case, topo=None) -> str:
+    """The case's program as lowered for the chip, with every Mosaic kernel's
+    module (the custom call's ``body``: MLIR bytecode, base64) printed as text
+    WITHOUT debug locations after it: a kernel file's line numbers are in the
+    bytecode, so the lowered text itself differs after any edit above a
+    kernel."""
+    import base64
+    import re
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    with on_chip_dispatch():
+        text = _lower_case(case, topo).as_text()
+    body = re.compile(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22')
+    out = [body.sub("BODY", text)]
+    for blob in body.findall(text):
+        ctx = mlir.make_ir_context()
+        ctx.allow_unregistered_dialects = True  # the serialized dialect is versioned
+        with ctx:
+            out.append(ir.Module.parse(base64.b64decode(blob)).operation.get_asm(enable_debug_info=False))
+    return "\n".join(out)
 
 
 # ---------------- whole steps ----------------
@@ -499,12 +545,21 @@ def main(argv=None) -> int:
     ap.add_argument("--steps", action="store_true", help="only the whole-step compiles")
     ap.add_argument("--window", action="store_true",
                     help="only command-a-plus-ep8: its kernels and whole steps")
+    ap.add_argument("--mosaic", metavar="DIR",
+                    help="write each tier-1 kernel case's Mosaic module as text and stop")
     ap.add_argument("--tp4-layers", type=int, default=4,
                     help="depth of the Qwen2.5-7B-width model in the tp=4 step")
     args = ap.parse_args(argv)
     topo = topology()
     print(f"compiling for {topo.devices[0].device_kind} x{len(topo.devices)} "
           f"({TOPOLOGY}, described, not attached)", flush=True)
+    if args.mosaic:
+        Path(args.mosaic).mkdir(parents=True, exist_ok=True)
+        cases = kernel_cases(full=False)
+        for case in cases:
+            (Path(args.mosaic) / f"{case.name}.mlir").write_text(mosaic_text(case, topo))
+        print(f"{len(cases)} modules under {args.mosaic}", flush=True)
+        return 0
     failed = 0
     bench = Path(__file__).resolve().parents[1] / "benchmark/configs"
     if not args.steps:
