@@ -1023,6 +1023,7 @@ def child_parity(sizes: Sizes, args) -> int:
         tiny, qwen, mixtral, bench, shard = (32, 4, 64), (28, 4, 128), (32, 8, 128), (16, 8, 128), (7, 1, 128)
         qwen3b = (16, 2, 128)  # the benchmark's configuration
         lfm2 = (32, 8, 64)  # lfm2-8b-a1b-d16: folded pools of 512 lanes
+        falcon = (20, 4, 128)  # falcon-h1-34b-d6: five query heads a kv head
         T, prefix = 512, 1000
         command_a, window = (128, 8, 128), 4096  # command-a-plus-ep8
         deep_window, deep_full = 2 * window, 3 * window - T  # chunk starts at depth
@@ -1030,6 +1031,7 @@ def child_parity(sizes: Sizes, args) -> int:
         tiny, qwen, mixtral, bench, shard = (8, 2, 64), (4, 2, 128), (4, 2, 128), (4, 2, 128), (2, 1, 128)
         qwen3b = (4, 2, 128)
         lfm2 = (8, 4, 64)
+        falcon = (10, 2, 128)
         T, prefix = 128, 200
         command_a, window = (4, 2, 128), 128
         deep_window = deep_full = 2176  # a table past 2048 tokens: the long tile
@@ -1042,6 +1044,9 @@ def child_parity(sizes: Sizes, args) -> int:
         ("decode lookahead qwen2.5-3b cell batch ps16 bf16", lambda: decode(*qwen3b, 16, False, batch=cell_batch)),
         ("decode lookahead qwen2.5-7b cell batch ps16 int8", lambda: decode(*qwen, 16, True, batch=cell_batch)),
         ("decode perseq qwen2.5-7b ps16 int8", lambda: decode(*qwen, 16, True, kernel="perseq")),
+        # a group of 5 query heads is padded to 8 sublanes: rows 5-7 must reach no result
+        ("decode lookahead falcon-h1 cell batch ps16 bf16", lambda: decode(*falcon, 16, False, batch=cell_batch)),
+        ("prefill falcon-h1 ps16 bf16", lambda: prefill(*falcon, 16, T, prefix, False)),
         ("decode folded qwen2.5-7b tp4-shard ps16 bf16", lambda: decode(*shard, 16, False, kernel="folded")),
         ("decode folded lfm2 cell batch ps16 bf16", lambda: decode(*lfm2, 16, False, batch=rag_batch)),
         ("decode folded lfm2 cell batch ps16 int8", lambda: decode(*lfm2, 16, True, batch=rag_batch)),
@@ -1068,6 +1073,8 @@ def child_parity(sizes: Sizes, args) -> int:
         ("mla prefill ps16", lambda: mla(16, T=T, prefix=prefix)),
         # Mamba-2 at the published widths (128 heads x 64 x 128), 24 slots
         ("ssm state update nemotron-h f32", lambda: ssm_update(*((24, 128, 64, 8, 128) if full else (6, 8, 8, 2, 128)))),
+        # Falcon-H1's (32 heads x 128 x 256 in 2 groups): a block of 16 heads from its bytes
+        ("ssm state update falcon-h1 f32", lambda: ssm_update(*((24, 32, 128, 2, 256) if full else (6, 4, 8, 2, 128)))),
         # the cell's decode step: 2816 static rows, 108 tokens x 22 of 512, 128 held
         ("moe grouped matmul nemotron-h decode bf16", lambda: grouped_matmul(
             *((2816, 1024, 2688, 128, 108, 22, 512) if full else (64, 128, 256, 4, 6, 3, 8)))),

@@ -1639,6 +1639,16 @@ class AsyncJaxEngine:
                  ({"state": "total"}, r["state_slots_total"])],
             ),
             render_family(
+                "dynamo_engine_state_bytes", "gauge",
+                "device bytes of the per-slot recurrent state cache beside the "
+                "page pool's, and what one slot costs before its first page "
+                "(zeros: the model has no recurrent layers)",
+                [({"cache": "state"}, r["hbm_state_bytes"]),
+                 ({"cache": "pages"}, r["kv_pool_bytes_total"]),
+                 ({"cache": "state_per_slot"},
+                  r["hbm_state_bytes"] // (r["state_slots_total"] + 1) if r["state_slots_total"] else 0)],
+            ),
+            render_family(
                 "dynamo_engine_prefix_cache_refused_total", "counter",
                 "sequences whose cached prefix was withheld: the model has "
                 "recurrent layers (pages without state), or a window layer no "

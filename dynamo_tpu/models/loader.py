@@ -575,3 +575,82 @@ def load_lfm2_moe_weights(model, path: Path) -> dict:
     if missing:
         raise ValueError(f"checkpoint {path} lacks {sorted(missing)}")
     return arrays
+
+
+def load_falcon_h1_weights(model, path: Path) -> dict:
+    """Falcon-H1 (`model.layers.N.{input_layernorm, pre_ff_layernorm, mamba.*,
+    self_attn.*, feed_forward.*}`, `model.final_layernorm`, an untied
+    `lm_head`): the layers are one stack, and the leaves are filled in their
+    own dtype on a pool of threads (as `load_nemotron_h_weights`: a float32
+    staging copy of 10.5 GB would double it). `mamba.in_proj`'s output rows are
+    z | x B C | dt in that order and become three matrices;
+    `mamba.conv1d.weight` [C, 1, K] goes taps first. No multiplier is folded
+    into a matrix."""
+    import os
+    from collections import deque
+    from concurrent.futures import ThreadPoolExecutor
+
+    shapes = jax.eval_shape(model.init_params, jax.random.key(0))
+    arrays = jax.tree.map(lambda s: np.zeros(s.shape, s.dtype), shapes)
+    per_layer = {
+        "input_layernorm.weight": ("input_norm", False),
+        "pre_ff_layernorm.weight": ("pre_ff_norm", False),
+        "mamba.conv1d.bias": ("conv_b", False),
+        "mamba.dt_bias": ("dt_bias", False), "mamba.A_log": ("A_log", False),
+        "mamba.D": ("D", False), "mamba.norm.weight": ("mixer_norm", False),
+        "mamba.out_proj.weight": ("out_proj", True),
+        # q, k and v stay [out, in] as the checkpoint has them (models/falcon_h1.py says why)
+        "self_attn.q_proj.weight": ("wq", False), "self_attn.k_proj.weight": ("wk", False),
+        "self_attn.v_proj.weight": ("wv", False), "self_attn.o_proj.weight": ("wo", True),
+        "feed_forward.gate_proj.weight": ("gate", True),
+        "feed_forward.up_proj.weight": ("up", True),
+        "feed_forward.down_proj.weight": ("down", True),
+    }
+    top = {"model.embed_tokens.weight": "embed", "model.final_layernorm.weight": "final_norm",
+           "lm_head.weight": "lm_head"}
+    layers = arrays["layers"]
+    filled = set()
+    pending: deque = deque()
+    with ThreadPoolExecutor(min(16, os.cpu_count() or 4)) as pool:
+
+        def put(dest: np.ndarray, src: np.ndarray) -> None:
+            pending.append(pool.submit(dest.__setitem__, ..., src))
+            if len(pending) > 64:  # bounds the tensors read and not yet copied
+                pending.popleft().result()
+
+        for name, tensor in _iter_checkpoint_tensors(path):
+            if name in top:
+                put(arrays[top[name]], tensor)
+                filled.add(top[name])
+                continue
+            if not name.startswith("model.layers."):
+                log.debug("skipping unmapped weight %s", name)
+                continue
+            layer_str, sub = name[len("model.layers."):].split(".", 1)
+            l = int(layer_str)
+            if l >= model.config.num_layers:
+                continue
+            if sub == "mamba.conv1d.weight":  # [C, 1, K] -> taps first
+                put(layers["conv_w"][l], tensor[:, 0, :].T)
+                filled.add((l, "conv_w"))
+                continue
+            if sub == "mamba.in_proj.weight":  # rows z | x B C | dt, a matrix each
+                at = 0
+                for key in ("in_z", "in_xbc", "in_dt"):
+                    width = layers[key].shape[-1]
+                    put(layers[key][l], tensor[at:at + width].T)
+                    filled.add((l, key))
+                    at += width
+                continue
+            key, transpose = per_layer.get(sub, (None, False))
+            if key is None:
+                log.debug("skipping unmapped weight %s", name)
+                continue
+            put(layers[key][l], tensor.T if transpose else tensor)
+            filled.add((l, key))
+        for done in pending:
+            done.result()
+    want = set(top.values()) | {(l, k) for l in range(model.config.num_layers) for k in layers}
+    if want - filled:
+        raise ValueError(f"checkpoint {path} lacks {sorted(map(str, want - filled))[:8]}")
+    return arrays

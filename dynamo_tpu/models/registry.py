@@ -3,7 +3,7 @@
 Supported ids:
   - ``tiny`` / ``tiny:<json-overrides>``: random-weight test model (and
     ``tiny-moe``, ``tiny-mla``, ``tiny-vl``, ``tiny-hybrid``, ``tiny-window``,
-    ``tiny-conv`` likewise)
+    ``tiny-conv``, ``tiny-parallel`` likewise)
   - a local HuggingFace checkpoint directory (config.json [+ safetensors])
 
 The reference resolves models from HF repos via its model-deployment-card
@@ -54,6 +54,8 @@ ARCHITECTURES = {
         "cohere2_moe", "Cohere2MoeConfig", "Cohere2MoeModel", "load_cohere2_moe_weights"),
     "Lfm2MoeForCausalLM": (
         "lfm2_moe", "Lfm2MoeConfig", "Lfm2MoeModel", "load_lfm2_moe_weights"),
+    "FalconH1ForCausalLM": (
+        "falcon_h1", "FalconH1Config", "FalconH1Model", "load_falcon_h1_weights"),
 }
 
 
@@ -62,6 +64,7 @@ _TINY_FAMILIES = {
     "tiny-hybrid": ("nemotron_h", "NemotronHConfig", "NemotronHModel"),
     "tiny-window": ("cohere2_moe", "Cohere2MoeConfig", "Cohere2MoeModel"),
     "tiny-conv": ("lfm2_moe", "Lfm2MoeConfig", "Lfm2MoeModel"),
+    "tiny-parallel": ("falcon_h1", "FalconH1Config", "FalconH1Model"),
 }
 
 

@@ -7,19 +7,23 @@ A decode step's recurrence, per live sequence and head,
     y  = S C
 
 reads and writes every live sequence's state once: 2 x 4.19 MB a sequence and
-layer at the published widths (128 heads x 64 x 128 float32), a third of a
-full-batch decode step's bytes, and nothing else of size. As an XLA fusion it
+layer at either published shape (NemotronH: 128 heads x 64 x 128 float32;
+Falcon-H1: 32 x 128 x 256), a third of a full-batch decode step's bytes, and
+nothing else of size. As an XLA fusion it
 has no name a trace reader can find; as a kernel it is `ssm_state_update` on
 the device line, and the state array is aliased to the output so no copy of
 it is ever made.
 
-Layout. The grid is (batch row, block of heads). The state block is
+Layout. The grid is (batch row, block of heads); the heads a block follow
+from the block's bytes (`head_block_for`). The state block is
 [1, Hb, P, N] with N on the lanes. The per-row vectors come transposed, P on
 the sublanes and the block's heads on the lanes ([B, H/Hb, P, Hb]), so that
 head j's `dt * x` is a [P, 1] column that broadcasts along the lanes against
 B's [1, N] row: the outer product needs no relayout. `y`'s column is the lane
-reduction of `S * C` and is stored into the same transposed layout. The decay
-`a` is a scalar per (row, head), read from SMEM.
+reduction of `S * C` and is stored into the same transposed layout (at
+Falcon-H1's 16 heads a block the vectors fill an eighth of a lane row; they
+are a thousandth of the block's bytes). The decay `a` is a scalar per
+(row, head), read from SMEM.
 
 Rows that are not live map, through the scalar-prefetched `rows`, to a
 trash row of the state: their blocks are the same block step after
@@ -37,11 +41,22 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-#: heads per grid step: four head groups of the published model (16 heads a
-#: group), a 2 MiB state block each way. On the v5e a call over 128 live slots
-#: (1.083 GB in and out) takes 1.77 ms inside a decode step, 75% of the HBM
-#: roofline (PERF.md section 6, PR 29)
-HEAD_BLOCK = 64
+#: bytes of one state block, each way: input and output double-buffered are
+#: four of them, half the 16 MiB of scoped VMEM a v5e kernel has by default.
+#: NemotronH's 128 heads x 64 x 128 give 64 heads a block (four groups of 16):
+#: on the v5e a call over 128 live slots (1.083 GB in and out) takes 1.77 ms
+#: inside a decode step, 75% of the HBM roofline (PERF.md section 6, PR 29).
+#: Falcon-H1's 32 heads x 128 x 256 give 16 (one group)
+STATE_BLOCK_BYTES = 2 << 20
+
+
+def head_block_for(H: int, P: int, N: int, G: int) -> int:
+    """Heads per grid step: the most whole groups whose float32 state fills
+    no more than `STATE_BLOCK_BYTES`, a divisor of the G groups, one at least."""
+    hpg = H // G
+    fit = max(1, STATE_BLOCK_BYTES // (hpg * P * N * 4))
+    return hpg * max(g for g in range(1, G + 1) if G % g == 0 and g <= fit)
+
 
 
 def _kernel(rows_ref, a_ref, s_ref, dtx_ref, b_ref, c_ref, y_ref, so_ref, *,
@@ -69,14 +84,14 @@ def ssm_state_update_pallas(
     c_vec: jnp.ndarray,  # [B, G, N] float32
     rows: jnp.ndarray,  # [B] int32: each batch row's state row (a trash row if not live)
     *,
-    head_block: int = HEAD_BLOCK,
+    head_block: int | None = None,  # None: from the block's bytes
     interpret: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Returns (y [B, H, P] float32 = S_new C, the state updated in place)."""
     B, H, P = dtx.shape
     G, N = b_vec.shape[1:]
     hpg = H // G
-    Hb = min(head_block, H)
+    Hb = head_block_for(H, P, N, G) if head_block is None else min(head_block, H)
     if H % Hb or Hb % hpg:
         raise ValueError(f"head block {Hb} must divide {H} heads in whole groups of {hpg}")
     nb = H // Hb

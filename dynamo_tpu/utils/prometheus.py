@@ -81,6 +81,7 @@ DECLARED_METRIC_FAMILIES: tuple = (
     "dynamo_engine_slo_latency_seconds",
     "dynamo_engine_slo_violations_total",
     "dynamo_engine_stage_seconds_total",
+    "dynamo_engine_state_bytes",
     "dynamo_engine_state_slots",
     "dynamo_engine_ttft_seconds",
     "dynamo_engine_xla_compile_seconds_total",

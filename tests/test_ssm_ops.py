@@ -112,3 +112,40 @@ def test_state_update_kernel_matches_jax_numpy(head_block):
     np.testing.assert_allclose(got_s, want_s, atol=1e-5)
     np.testing.assert_array_equal(got_s[1], state[1])
     np.testing.assert_array_equal(got_s[nb], state[nb])
+
+
+def test_the_head_block_follows_from_the_blocks_bytes():
+    """2 MiB of float32 state a block each way, in whole groups: NemotronH's
+    published shape keeps the 64 heads (four groups) it has run with since
+    PR 29, Falcon-H1's takes one group of 16."""
+    from dynamo_tpu.ops.pallas.ssm_update import STATE_BLOCK_BYTES, head_block_for
+
+    assert head_block_for(128, 64, 128, 8) == 64
+    assert 64 * 64 * 128 * 4 == STATE_BLOCK_BYTES
+    assert head_block_for(32, 128, 256, 2) == 16
+    assert 16 * 128 * 256 * 4 == STATE_BLOCK_BYTES
+    # a group larger than the budget is still one whole group; small shapes take all
+    assert head_block_for(8, 256, 512, 2) == 4
+    assert head_block_for(H, P, N, G) == H
+
+
+def test_state_update_kernel_at_falcon_h1s_published_shape():
+    """Interpret mode at H = 32, P = 128, N = 256, G = 2, the block chosen
+    from its bytes (16 heads, two blocks a row): a live row, a row that is
+    not live, the trash row."""
+    rng = np.random.default_rng(5)
+    f = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
+    Hh, Ph, Nh, Gh, nb = 32, 128, 256, 2, 2
+    state = f(nb + 1, Hh, Ph, Nh).at[nb].set(0.0)
+    live = jnp.array([True, False])
+    rows = jnp.where(live, jnp.arange(nb), nb).astype(jnp.int32)
+    decay = jnp.where(live[:, None], jnp.exp(-jnp.abs(f(nb, Hh))), 1.0)
+    dtx = jnp.where(live[:, None, None], f(nb, Hh, Ph), 0.0)
+    b, c = f(nb, Gh, Nh), f(nb, Gh, Nh)
+    with jax.default_matmul_precision("highest"):
+        want_y, want_s = ssm_state_update_reference(state, decay, dtx, b, c, rows)
+    got_y, got_s = ssm_state_update_pallas(state, decay, dtx, b, c, rows, interpret=True)
+    np.testing.assert_allclose(got_y[0], want_y[0], atol=2e-4)
+    np.testing.assert_allclose(got_s, want_s, atol=1e-5)
+    np.testing.assert_array_equal(got_s[1], state[1])
+    np.testing.assert_array_equal(got_s[nb], state[nb])

@@ -41,6 +41,7 @@ MODELS = {
     "tiny-hybrid": SHARED | {"ssm_proj", "ssm", "moe_router", "moe_dispatch", "moe_experts", "shared_experts"},
     "tiny-window": SHARED | {"moe_router", "moe_dispatch", "moe_experts", "shared_experts"},
     "tiny-conv": SHARED | {"mlp", "ssm_proj", "ssm", "moe_router", "moe_dispatch", "moe_experts"},
+    "tiny-parallel": SHARED | {"mlp", "ssm_proj", "ssm"},
 }
 STEPS = {"decode_window": "_decode_window", "prefill_packed": "_prefill_packed"}
 

@@ -57,6 +57,9 @@ COMMAND_A = ("command-a-plus", 128, 8, 128)
 COMMAND_A_WINDOW = 4096
 #: LFM2-8B-A1B: 32 query heads over 8 kv heads of 64, folded pools of 512 lanes
 LFM2 = ("lfm2-8b-a1b", 32, 8, 64)
+#: Falcon-H1-34B: 20 query heads over 4 kv heads of 128, five a group (the
+#: first group that is no power of two)
+FALCON_H1 = ("falcon-h1-34b", 20, 4, 128)
 #: one tensor-parallel shard of qwen2.5-7b at tp=4: one kv head, which is under
 #: Mosaic's sublane pack, so the pool is folded (LlamaModel.kv_folded), 128 lanes
 TP4_SHARD = ("qwen2.5-7b-tp4-shard", 7, 1, 128)
@@ -192,10 +195,10 @@ def _mla_prefill_case(ps, T):
     return Case(f"mla-prefill-ps{ps}-T{T}", build)
 
 
-def _ssm_update_case(slots: int = 128):
-    """The one-token state update at the published Mamba-2 widths (128 heads
-    x 64 x 128 float32 a slot), one block's rows plus its trash row."""
-    H, P, G, N = 128, 64, 8, 128
+def _ssm_update_case(slots: int = 128, H: int = 128, P: int = 64, G: int = 8, N: int = 128):
+    """The one-token state update at a published Mamba-2 shape (NemotronH's
+    128 heads x 64 x 128 float32 a slot by default), one block's rows plus
+    its trash row."""
 
     def build(S):
         from dynamo_tpu.ops.pallas.ssm_update import ssm_state_update_pallas
@@ -206,7 +209,21 @@ def _ssm_update_case(slots: int = 128):
             S((slots, G, N), jnp.float32), S((slots,), jnp.int32),
         )
 
-    return Case(f"ssm-state-update-{slots}slots", build)
+    tag = "" if (H, P, G, N) == (128, 64, 8, 128) else f"-{H}x{P}x{N}g{G}"
+    return Case(f"ssm-state-update-{slots}slots{tag}", build)
+
+
+def parallel_cases() -> list[Case]:
+    """`falcon-h1-34b-d6`: the unfolded decode and prefill kernels at 20 query /
+    4 kv heads of 128 (five query heads a kv head), page 16, tables of 5120
+    tokens, and the state update at 32 heads x 128 x 256 in 2 groups, 96 slots
+    (a block of 16 heads: `head_block_for`)."""
+    return [
+        _decode_case(FALCON_H1, 16, False, max_len=5120),
+        _prefill_case(FALCON_H1, 16, 128, False, max_len=5120),
+        _prefill_case(FALCON_H1, 16, 1024, False, max_len=5120),
+        _ssm_update_case(96, H=32, P=128, G=2, N=256),
+    ]
 
 
 def _grouped_matmul_case(name: str, M: int, K: int, N: int, held: int = 128):
@@ -301,7 +318,7 @@ def kernel_cases(full: bool) -> list[Case]:
         cases.append(_ssm_update_case())
         cases += [_grouped_matmul_case(*c) for c in GROUPED_MATMUL_CASES]
         cases.append(_grouped_matmul_case("prefill-w2", 1024 * 22, 2688, 1024))
-        return cases + window_cases() + folded_cases()
+        return cases + window_cases() + folded_cases() + parallel_cases()
     return [
         # decode: folded (256 lanes, and the 128 of one kv head a tp=4 shard),
         # lookahead, and the per-sequence kernel lookahead falls back to; int8
@@ -342,6 +359,9 @@ def kernel_cases(full: bool) -> list[Case]:
         # refused a place by the dispatcher at 64 rows x 32 heads) and the
         # grouped product at the two expert shapes
         *folded_cases(),
+        # falcon-h1-34b-d6: five query heads a kv head in the unfolded kernels,
+        # and the state update at four times NemotronH's state a head
+        *parallel_cases(),
     ]
 
 
@@ -578,6 +598,11 @@ def main(argv=None) -> int:
         slots, pages, max_len = lfm2["benchmark"]["server_args"][1::2]
         _report_steps(f"lfm2-8b-a1b-d16 (16 layers, all 32 experts) {slots} slots, {pages} pages",
                       compile_hybrid_steps(lfm2, num_pages=pages, max_seqs=slots,
+                                           max_model_len=max_len, topo=topo))
+        falcon = json.loads((bench / "falcon-h1-34b-d6.json").read_text())
+        slots, pages, max_len = falcon["benchmark"]["server_args"][1::2]
+        _report_steps(f"falcon-h1-34b-d6 (6 layers, whole vocabulary) {slots} slots, {pages} pages",
+                      compile_hybrid_steps(falcon, num_pages=pages, max_seqs=slots,
                                            max_model_len=max_len, topo=topo))
     if not args.kernels:
         command_a = json.loads((bench / "command-a-plus-ep8.json").read_text())
