@@ -968,27 +968,48 @@ def child_parity(sizes: Sizes, args) -> int:
         ctx = pages[table].reshape(maxp * ps, lat)
         return got, reference(model._absorbed_attention, lp, qn, qr, ctx, pos)
 
-    def ssm_update(slots, H, P, G, N):
+    def scattered(slots, n_live):
+        """`n_live` of `slots` rows live, where a busy scheduler leaves them."""
+        alive = np.zeros(slots, bool)
+        alive[rng.permutation(slots)[:n_live]] = True
+        return alive
+
+    def live_decode(hq, hkv, d, ps, n_live):
+        """The cell batch with `n_live` of its rows live (`qwen2.5-3b.chat`
+        decodes 11 of 64): the live rows against the reference, every other
+        row against zero."""
+        from dynamo_tpu.ops.live_rows import live_rows
+
+        B, maxp, tables, pos = cell_batch(ps)
+        alive = scattered(B, n_live)
+        k, v = pools(int(tables.max()) + 1, ps, hkv, d, False, False)
+        q = normal(B, hq, d)
+        got = jax.jit(A.dispatch_paged_decode_attention)(q, k, v, tables, pos, live=live_rows(jnp.asarray(alive)))
+        want = reference(A.paged_decode_attention, q, k, v, tables, pos)
+        return got, jnp.where(jnp.asarray(alive)[:, None, None], want, 0)
+
+    def ssm_update(slots, H, P, G, N, n_live=None):
         """The one-token Mamba-2 state update, in place over the state, at a
-        batch where a third of the slots are not live (they read and write
-        the trash row): output y and the whole state against `jax.numpy`."""
+        batch where a third of the slots are not live (or all but `n_live`):
+        output y and the whole state against `jax.numpy`, which leaves a dead
+        row's state as it was and reads it zero."""
+        from dynamo_tpu.ops.live_rows import live_rows
         from dynamo_tpu.ops.pallas.ssm_update import ssm_state_update_pallas
         from dynamo_tpu.ops.ssm import ssm_state_update_reference
 
         def f32(*shape, scale=1.0):
             return jnp.asarray(rng.standard_normal(shape, dtype=np.float32) * scale)
 
-        state = f32(slots + 1, H, P, N).at[slots].set(0.0)
-        live = jnp.asarray(np.arange(slots) % 3 != 1)
-        rows = jnp.where(live, jnp.arange(slots), slots).astype(jnp.int32)
-        decay = jnp.where(live[:, None], jnp.exp(-jnp.abs(f32(slots, H))), 1.0)
-        dtx = jnp.where(live[:, None, None], f32(slots, H, P), 0.0)
+        state = f32(slots + 1, H, P, N)
+        alive = np.arange(slots) % 3 != 1 if n_live is None else scattered(slots, n_live)
+        live = live_rows(jnp.asarray(alive))
+        rows = jnp.arange(slots, dtype=jnp.int32)
+        decay, dtx = jnp.exp(-jnp.abs(f32(slots, H))), f32(slots, H, P)
         b, c = f32(slots, G, N), f32(slots, G, N, scale=0.1)
-        want_y, want_s = reference(ssm_state_update_reference, state, decay, dtx, b, c, rows)
-        got_y, got_s = ssm_state_update_pallas(state, decay, dtx, b, c, rows, interpret=interpret)
-        pick = np.asarray(live)
-        return (np.concatenate([np.asarray(got_y)[pick].ravel(), np.asarray(got_s).ravel()]),
-                np.concatenate([np.asarray(want_y)[pick].ravel(), np.asarray(want_s).ravel()]))
+        want_y, want_s = reference(ssm_state_update_reference, state, decay, dtx, b, c, rows, live)
+        got_y, got_s = ssm_state_update_pallas(state, decay, dtx, b, c, rows, live, interpret=interpret)
+        return (np.concatenate([np.asarray(got_y).ravel(), np.asarray(got_s).ravel()]),
+                np.concatenate([np.asarray(want_y).ravel(), np.asarray(want_s).ravel()]))
 
     def grouped_matmul(M, K, N, G, tokens, topk, routed):
         """The expert layer's grouped product at a decode step's shape: each
@@ -1044,6 +1065,9 @@ def child_parity(sizes: Sizes, args) -> int:
         ("decode lookahead qwen2.5-3b cell batch ps16 bf16", lambda: decode(*qwen3b, 16, False, batch=cell_batch)),
         ("decode lookahead qwen2.5-7b cell batch ps16 int8", lambda: decode(*qwen, 16, True, batch=cell_batch)),
         ("decode perseq qwen2.5-7b ps16 int8", lambda: decode(*qwen, 16, True, kernel="perseq")),
+        # the grid over live rows (PR 46): `chat`'s 11 live of 64, the rest zero
+        ("decode lookahead qwen2.5-3b 11 live of 64 ps16 bf16",
+         lambda: live_decode(*qwen3b, 16, 11 if full else 2)),
         # a group of 5 query heads is padded to 8 sublanes: rows 5-7 must reach no result
         ("decode lookahead falcon-h1 cell batch ps16 bf16", lambda: decode(*falcon, 16, False, batch=cell_batch)),
         ("prefill falcon-h1 ps16 bf16", lambda: prefill(*falcon, 16, T, prefix, False)),
@@ -1073,6 +1097,8 @@ def child_parity(sizes: Sizes, args) -> int:
         ("mla prefill ps16", lambda: mla(16, T=T, prefix=prefix)),
         # Mamba-2 at the published widths (128 heads x 64 x 128), 24 slots
         ("ssm state update nemotron-h f32", lambda: ssm_update(*((24, 128, 64, 8, 128) if full else (6, 8, 8, 2, 128)))),
+        ("ssm state update nemotron-h 11 live of 64 f32",
+         lambda: ssm_update(*((64, 128, 64, 8, 128, 11) if full else (6, 8, 8, 2, 128, 2)))),
         # Falcon-H1's (32 heads x 128 x 256 in 2 groups): a block of 16 heads from its bytes
         ("ssm state update falcon-h1 f32", lambda: ssm_update(*((24, 32, 128, 2, 256) if full else (6, 4, 8, 2, 128)))),
         # the cell's decode step: 2816 static rows, 108 tokens x 22 of 512, 128 held

@@ -41,6 +41,7 @@ from dynamo_tpu.ops.attention import (
     dispatch_paged_prefill_attention,
     scatter_kv,
 )
+from dynamo_tpu.ops.live_rows import live_rows
 from dynamo_tpu.ops.moe import grouped_matmul, moe_dispatch, sigmoid_topk_routing
 from dynamo_tpu.ops.norms import layer_norm
 from dynamo_tpu.ops.rotary import apply_rope_pairs
@@ -389,6 +390,7 @@ class Cohere2MoeModel:
         tables = self._tables(page_tables)  # [L, B, W]
         with jax.named_scope("attn_kv"):
             offsets = jnp.where(active, positions % page_size, 0)
+        live = live_rows(active)  # once a step, for every layer's kernel
 
         with jax.named_scope("embed"):
             hidden = params["embed"][tokens].astype(c.dtype)
@@ -401,7 +403,8 @@ class Cohere2MoeModel:
 
             def attn_fn(q, k_pool, v_pool, window, table=table):
                 return dispatch_paged_decode_attention(
-                    q, k_pool, v_pool, table, positions, mesh=self.attn_mesh, window=window
+                    q, k_pool, v_pool, table, positions, mesh=self.attn_mesh, window=window,
+                    live=live,
                 )
 
             n = layer_norm(hidden, lp["norm"], c.layer_norm_eps)

@@ -28,7 +28,8 @@ models/llama.py lays it out, and beside it, per DECODE SLOT and not per page, a
 float32 state [H, P, N] and the last `mamba_d_conv - 1` inputs of the
 convolution, rows of two flat arrays laid out as models/nemotron_h.py lays its
 state (`slot` of layer l at row `l * (max_seqs + 1) + slot`, the last row of
-each layer a trash row for padding lanes and slots that are not live; a chunk
+each layer a trash row for a pack's padding lanes, while a decode step serves
+its live rows only (`ops/live_rows.py`); a chunk
 that starts at position 0 starts from zeros, so a slot needs no clearing
 between sequences). Every decode step of every layer reads a sequence's pages
 AND reads and writes its state row. The engine gives the slot
@@ -53,6 +54,7 @@ from dynamo_tpu.ops.attention import (
 )
 from dynamo_tpu.ops.norms import rms_norm
 from dynamo_tpu.ops.rotary import apply_rope
+from dynamo_tpu.ops.live_rows import live_rows
 from dynamo_tpu.ops.ssm import causal_conv, ssd_chunked, ssm_state_update
 
 #: the published config's keys that hold a forward multiplier (two of them a
@@ -377,9 +379,9 @@ class FalconH1Model:
             ssm, conv = _with_rows(ssm, rows, state), _with_rows(conv, rows, window)
         return self._mamba_out(lp, y.reshape(*y.shape[:2], c.mamba_inner), z), ssm, conv
 
-    def _mamba_decode(self, lp, u, ssm, conv, base, slot_rows, active):
+    def _mamba_decode(self, lp, u, ssm, conv, base, live):
         """u [B, D]; batch row b's state is row base + b; rows that are not
-        active leave state and window as they were."""
+        live (the step's `LiveRows`) leave state and window as they were."""
         c = self.config
         nb = u.shape[0]
         z, xbc, dt = self._mamba_in(lp, u)
@@ -387,13 +389,12 @@ class FalconH1Model:
             mine = base + jnp.arange(nb)
             xbc, window = causal_conv(
                 xbc[:, None, :], jax.lax.dynamic_slice_in_dim(conv, base, nb), lp["conv_w"],
-                lp["conv_b"], active.astype(jnp.int32),
+                lp["conv_b"], live.mask.astype(jnp.int32),
             )
             x, B, C = self._split_xbc(jax.nn.silu(xbc[:, 0]))
             dt = jax.nn.softplus(dt + lp["dt_bias"])
             y, ssm = ssm_state_update(
-                ssm, jnp.where(active, mine, base + slot_rows - 1), x, dt,
-                -jnp.exp(lp["A_log"]), B, C, lp["D"], active,
+                ssm, mine, x, dt, -jnp.exp(lp["A_log"]), B, C, lp["D"], live,
             )
             conv = jax.lax.dynamic_update_slice_in_dim(conv, window, base, 0)
         return self._mamba_out(lp, y.reshape(nb, c.mamba_inner), z), ssm, conv
@@ -540,6 +541,7 @@ class FalconH1Model:
         with jax.named_scope("attn_kv"):
             phys = jnp.where(active, page_tables[jnp.arange(B), positions // page_size], 0)
             offsets = jnp.where(active, positions % page_size, 0)
+        live = live_rows(active)  # once a step, for every layer's two kernels
 
         def body(carry, xs):
             hidden, k_pool, v_pool, ssm, conv = carry
@@ -548,11 +550,11 @@ class FalconH1Model:
 
             def attn_fn(q, kp, vp):
                 return dispatch_paged_decode_attention(
-                    q, kp, vp, off + page_tables, positions, mesh=self.attn_mesh
+                    q, kp, vp, off + page_tables, positions, mesh=self.attn_mesh, live=live
                 )
 
             u = rms_norm(hidden, lp["input_norm"], c.rms_norm_eps)
-            m_out, ssm, conv = self._mamba_decode(lp, u, ssm, conv, l * slot_rows, slot_rows, active)
+            m_out, ssm, conv = self._mamba_decode(lp, u, ssm, conv, l * slot_rows, live)
             a_out, k_pool, v_pool = self._attention(
                 lp, u, k_pool, v_pool, positions, off + phys, offsets, attn_fn
             )

@@ -47,6 +47,7 @@ from dynamo_tpu.ops.attention import (
     dispatch_paged_prefill_attention,
     scatter_kv,
 )
+from dynamo_tpu.ops.live_rows import live_rows
 from dynamo_tpu.ops.moe import grouped_matmul, moe_dispatch, sigmoid_topk_routing
 from dynamo_tpu.ops.norms import rms_norm
 from dynamo_tpu.ops.rotary import apply_rope
@@ -468,6 +469,7 @@ class Lfm2MoeModel:
         with jax.named_scope("attn_kv"):
             phys = jnp.where(active, page_tables[jnp.arange(B), positions // page_size], 0)
             offsets = jnp.where(active, positions % page_size, 0)
+        live = live_rows(active)  # once a step, for every layer's kernel
 
         with jax.named_scope("embed"):
             hidden = params["embed"][tokens].astype(c.dtype)
@@ -483,7 +485,8 @@ class Lfm2MoeModel:
 
                 def attn_fn(q, k_pool, v_pool, off=off):
                     return dispatch_paged_decode_attention(
-                        q, k_pool, v_pool, off + page_tables, positions, mesh=self.attn_mesh
+                        q, k_pool, v_pool, off + page_tables, positions, mesh=self.attn_mesh,
+                        live=live,
                     )
 
                 out, cache = self._attention(

@@ -34,6 +34,7 @@ from dynamo_tpu.ops.attention import (
     dispatch_paged_prefill_attention,
     scatter_kv,
 )
+from dynamo_tpu.ops.live_rows import live_rows
 from dynamo_tpu.ops.norms import rms_norm
 from dynamo_tpu.ops.rotary import apply_mrope, apply_rope
 from dynamo_tpu.quant import (
@@ -860,6 +861,7 @@ class LlamaModel:
             logical = positions // page_size
             phys = jnp.where(active, page_tables[jnp.arange(B), logical], 0)
             offsets = jnp.where(active, positions % page_size, 0)
+        live = live_rows(active)  # once a step, for every layer's kernel
 
         with jax.named_scope("embed"):
             hidden = params["embed"][tokens].astype(c.dtype)
@@ -874,7 +876,7 @@ class LlamaModel:
 
             def attn_fn(q, k_new, v_new, kp_, vp_):
                 return dispatch_paged_decode_attention(
-                    q, kp_, vp_, off + page_tables, positions, mesh=self.attn_mesh
+                    q, kp_, vp_, off + page_tables, positions, mesh=self.attn_mesh, live=live
                 )
 
             lkw = {}

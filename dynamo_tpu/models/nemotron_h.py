@@ -16,7 +16,8 @@ as models/llama.py lays it out). Beside it every Mamba block keeps, per DECODE
 SLOT and not per page, a float32 state [H, P, N] and the last `conv_kernel - 1`
 inputs of its convolution: rows of two flat arrays, `slot` of block m at row
 `m * (max_seqs + 1) + slot`, the last row of each block being a trash row for
-padding lanes and slots that are not live. A chunk that starts at position 0
+a prefill pack's padding lanes (a decode step serves its live rows only:
+`ops/live_rows.py`). A chunk that starts at position 0
 starts from zeros, so a slot needs no clearing between sequences; a later chunk
 and every decode step continue from what the row holds. The engine gives the
 slot (`state_slot(s)`); nothing here knows about requests.
@@ -42,6 +43,7 @@ from dynamo_tpu.ops.attention import (
 )
 from dynamo_tpu.ops.moe import grouped_matmul, moe_dispatch, relu2, sigmoid_topk_routing
 from dynamo_tpu.ops.norms import rms_norm
+from dynamo_tpu.ops.live_rows import live_rows
 from dynamo_tpu.ops.ssm import causal_conv, ssd_chunked, ssm_state_update
 
 
@@ -344,9 +346,9 @@ class NemotronHModel:
             )
         return self._mamba_out(bp, y.reshape(*y.shape[:2], c.mamba_inner), z), cache
 
-    def _mamba_decode(self, bp, h, cache, base, active):
+    def _mamba_decode(self, bp, h, cache, base, live):
         """h [B, D]; batch row b's state is row base + b; rows that are not
-        active leave state and window as they were."""
+        live (the step's `LiveRows`) leave state and window as they were."""
         c = self.config
         nb = h.shape[0]
         with jax.named_scope("ssm_proj"):
@@ -355,14 +357,12 @@ class NemotronHModel:
             mine = base + jnp.arange(nb)
             xbc, window = causal_conv(
                 xbc[:, None, :], cache["conv"][mine], bp["conv_w"], bp["conv_b"],
-                active.astype(jnp.int32),
+                live.mask.astype(jnp.int32),
             )
             x, B, C = self._split_xbc(jax.nn.silu(xbc[:, 0]))
             dt = jax.nn.softplus(dt.astype(jnp.float32) + bp["dt_bias"])
-            trash = base + cache["ssm"].shape[0] // c.count("M") - 1
             y, ssm = ssm_state_update(
-                cache["ssm"], jnp.where(active, mine, trash), x, dt,
-                -jnp.exp(bp["A_log"]), B, C, bp["D"], active,
+                cache["ssm"], mine, x, dt, -jnp.exp(bp["A_log"]), B, C, bp["D"], live,
             )
             cache = dict(cache, ssm=ssm, conv=cache["conv"].at[mine].set(window))
         return self._mamba_out(bp, y.reshape(nb, c.mamba_inner), z), cache
@@ -514,6 +514,7 @@ class NemotronHModel:
         with jax.named_scope("attn_kv"):
             phys = jnp.where(active, page_tables[jnp.arange(B), positions // page_size], 0)
             offsets = jnp.where(active, positions % page_size, 0)
+        live = live_rows(active)  # once a step, for every layer's kernel
 
         with jax.named_scope("embed"):
             hidden = params["embed"][tokens].astype(c.dtype)
@@ -522,14 +523,15 @@ class NemotronHModel:
         for kind, bp in zip(c.pattern, params["blocks"]):
             h = rms_norm(hidden, bp["norm"], c.rms_norm_eps)
             if kind == "M":
-                out, cache = self._mamba_decode(bp, h, cache, m * slot_rows, active)
+                out, cache = self._mamba_decode(bp, h, cache, m * slot_rows, live)
                 m += 1
             elif kind == "*":
                 off = a * num_pages
 
                 def attn_fn(q, k_pool, v_pool, off=off):
                     return dispatch_paged_decode_attention(
-                        q, k_pool, v_pool, off + page_tables, positions, mesh=self.attn_mesh
+                        q, k_pool, v_pool, off + page_tables, positions, mesh=self.attn_mesh,
+                        live=live,
                     )
 
                 out, cache = self._attention(bp, h, cache, off + phys, offsets, attn_fn)

@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 from jax import shard_map
 
+from dynamo_tpu.ops.live_rows import zero_dead_rows
 from dynamo_tpu.quant.kv import QuantizedPages, quantize_kv_rows
 from dynamo_tpu.utils.logging import get_logger
 
@@ -281,10 +282,11 @@ def _head_shard_refusal(shape: str, Hq: int, Hkv: int, D: int, tp: int, folded: 
     return None
 
 
-def _over_head_shards(fn, mesh, q, k_pages, v_pages, tables, positions):
+def _over_head_shards(fn, mesh, q, k_pages, v_pages, tables, positions, *rest):
     """Run an attention kernel per head shard: q and the pools split over
     "tp" (folded pools on their head-major lane dim), tables and positions
-    replicated. An int8 pool shards like the bf16 pool; its per-row scale
+    replicated, as is what follows them (``rest``: a decode step's live
+    rows). An int8 pool shards like the bf16 pool; its per-row scale
     plane is head-independent, so it replicates."""
     from jax.sharding import PartitionSpec as P
 
@@ -300,15 +302,20 @@ def _over_head_shards(fn, mesh, q, k_pages, v_pages, tables, positions):
             pool_spec,
             P(*[None] * tables.ndim),
             P(None),
+            *jax.tree.map(lambda x: P(*[None] * x.ndim), rest),
         ),
         out_specs=P(None, "tp", None),
-    )(q, k_pages, v_pages, tables, positions)
+    )(q, k_pages, v_pages, tables, positions, *rest)
 
 
 @jax.named_scope("attn")
 def dispatch_paged_decode_attention(q, k_pages, v_pages, page_tables, positions, mesh=None,
-                                    window: int = 0):
+                                    window: int = 0, live=None):
     """Pallas kernel on TPU, pure-JAX reference elsewhere (same contract).
+
+    ``live`` (`ops.live_rows.LiveRows`, made once a decode step; None: every
+    row) names the batch rows that hold a sequence. The tiled kernel's grid
+    is over those alone, and a row that is not live reads zero on every path.
 
     ``window`` W > 0 is a sliding-window layer: a query at position p sees the
     keys in (p - W, p]. The tiled kernel then walks only the tiles that hold
@@ -326,9 +333,14 @@ def dispatch_paged_decode_attention(q, k_pages, v_pages, page_tables, positions,
     shape = f"Hq={Hq} Hkv={num_kv_heads} D={D} ps={k_pages.shape[1]}"
     if window:
         shape += f" window={window}"
+
+    def reference():
+        return zero_dead_rows(
+            paged_decode_attention(q, k_pages, v_pages, page_tables, positions, window), live)
+
     if not use_pallas_decode(D, num_kv_heads):
         _log_path("decode", "reference", f"{shape}: no Pallas kernel for this backend/shape")
-        return paged_decode_attention(q, k_pages, v_pages, page_tables, positions, window)
+        return reference()
 
     from dynamo_tpu.ops.pallas.paged_attention import (
         decode_tile_pages,
@@ -363,7 +375,7 @@ def dispatch_paged_decode_attention(q, k_pages, v_pages, page_tables, positions,
             k_pages.shape[1], num_kv_heads // tp, D, k_pages.dtype.itemsize)
         if not tiled:
             _log_path("decode", "reference", f"{shape}: only the tiled kernel takes a window")
-            return paged_decode_attention(q, k_pages, v_pages, page_tables, positions, window)
+            return reference()
         kernel = functools.partial(kernel, window=window)
     if num_kv_heads % tp == 0:
         # the geometry one head shard's kernel derives, so a server log says
@@ -374,22 +386,22 @@ def dispatch_paged_decode_attention(q, k_pages, v_pages, page_tables, positions,
         ahead = lookahead_window(*geometry)
         if use_folded and not ahead:
             _log_path("decode", "reference", f"{shape}: no tile of the folded pool fits VMEM")
-            return paged_decode_attention(q, k_pages, v_pages, page_tables, positions)
+            return reference()
         path += (f" tile={decode_tile_pages(*geometry)}x{ps} window={ahead}"
                  if ahead else " window=0:perseq")
     path += " interpret" if interpret else ""
     if tp == 1:
         _log_path("decode", path, shape)
-        return kernel(q, k_pages, v_pages, page_tables, positions, interpret=interpret)
+        return kernel(q, k_pages, v_pages, page_tables, positions, live, interpret=interpret)
 
     why = _head_shard_refusal(shape, Hq, num_kv_heads, D, tp, folded)
     if why is not None:
         _log_path("decode", "reference", why)
-        return paged_decode_attention(q, k_pages, v_pages, page_tables, positions, window)
+        return reference()
     _log_path("decode", f"{path} shard_map tp={tp}", shape)
     return _over_head_shards(
         functools.partial(kernel, interpret=interpret),
-        mesh, q, k_pages, v_pages, page_tables, positions,
+        mesh, q, k_pages, v_pages, page_tables, positions, live,
     )
 
 

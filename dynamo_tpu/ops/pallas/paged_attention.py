@@ -57,8 +57,8 @@ qwen2.5-3b.chat; the HBM floor of the two is 33 and 11 us).
     cost 5%, and as a lax.cond around the select 20% more than that.
   - Not gained: a fast path for full tiles (one branch, one wait a tile) and
     q/out resident in VMEM for the whole grid gave 4% together and were left
-    out; an empty slot still costs 1.3 us (0.7 of it in the null kernel),
-    which is half of the 15-row time.
+    out; an empty slot cost 0.7 us until PR 46 (its own trash-page DMA, wait
+    and tile; "Live rows" below), a quarter of the 15-row time.
   - Other geometries sharing this kernel, old -> new at 45 rows (final
     tree): Hkv 4 and 8 at page 16 623 -> 256 and 621 -> 259 us; int8 at page
     16 723 -> 396 and 711 -> 405; Hkv 8 at page 64 262 -> 239; at page 128,
@@ -106,9 +106,52 @@ a page at a time (two DMAs in flight behind the page in use, two products of
     being whole (16, 128) tiles, so there is no 32-bit transpose to pay and
     none of the f32-operand findings above apply to it.
   - Not tried: a tile of 256 tokens, a wider window (W 1-16 timed within 7%
-    of each other for rank 4). An empty slot costs what it does there.
+    of each other for rank 4). An empty slot cost what it did there.
   - The file holds two kernel bodies: the tiled walk (lookahead, with a merge
     per pool rank) and perseq.
+
+Live rows (PR 46; my chip runs on a TPU v5e, tools/profile_live_rows.py: 36
+chained calls, best of 5; the rank-4 rows at the geometry of the design
+record above with the live rows scattered over the 64 slots, the folded rows
+at lfm2-8b-a1b's shape, 256 slots, every live context 1536 tokens). A decode
+batch is the whole slot table, and until PR 46 the grid was too: a slot that
+holds nobody has position 0, so its program found one token on the trash
+page, started and waited a page DMA for K and V and ran a whole tile through
+the merge. Now the grid is over the step's LIVE rows (ops/live_rows.py, made
+once a decode step on the device): `order` rides as scalar prefetch beside the
+page table and the lengths, program i serves row order[i] (q and out blocks
+by the index maps), prefetches row order[i + 1]'s window, and the live count
+is the grid's bound, read on the device (Pallas lowers a traced bound on this
+backend; in interpret mode it is a while_loop). A dead row has no program: no
+DMA, no block, no arithmetic; nothing writes its output row, which the
+wrapper's mask reads as zero. With no live row (a warm-up shape) no program
+runs, so no DMA starts and no semaphore is waited on.
+
+  us per call (share of the HBM roofline)   until PR 46     the live rows only
+  15 live + 49 empty (chat)                 114 (9.8%)      84 (13.3%)
+  45 live + 19 empty (chat-over)            208 (15.4%)     195 (16.3%)
+  64 of 64                                  270 (17.0%)     270 (17.0%)
+  folded, 74 of 256 (rag-over)              820 (34.7%)     699 (40.8%)
+  folded, 256 of 256                        2368 (41.6%)    2346 (42.0%)
+
+  - An empty program cost 0.61-0.67 us at every one of the three partly empty
+    shapes ((114 - 84) / 49, (208 - 195) / 19, (820 - 699) / 182): half of
+    what PR 26's two-point fit gave it (1.19 us a program, live or not). The
+    rest of that fit's constant belongs to the live rows: a call is about
+    27 us and 3.8 us a live row of 36 pages (84, 195 and 270 us at 15, 45 and
+    64 rows), so the 15-row call fell by 26% and not by half.
+  - The first form tried kept the table for a grid ("clamped"): the body
+    under a `pl.when`, and the q / out index maps of a step past the count
+    repeating the last live step's block, so that the pipeline moves nothing:
+    83.9 / 193.6 / 268.4 / 707 / 2350 in an earlier call, where that form
+    with the count for its bound read 80.8 / 193.1 / 269.9 / 700 / 2349. A
+    step that does nothing costs 0.06 us, then. The bound by count compiled
+    on the chip, ran in interpret mode and cost a full table nothing, so it
+    is the one kept, and the clamping went. (Why the kept form reads 84.0
+    and not 80.8 at 15 rows is not known: 0.2 us a program.)
+  - A live row's arithmetic is what it was, to the bit, whatever rows are
+    live beside it (same tiles in the same order; only the scratch parity a
+    row lands on differs, and stale scratch is masked as before).
 
 Int8 KV (quant/kv.py QuantizedPages): perseq and the tiled walk (both pool
 ranks) accept int8 pools plus their per-row f32 scales, which arrive as
@@ -130,6 +173,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from dynamo_tpu.ops.live_rows import LiveRows, every_row, zero_dead_rows
 from dynamo_tpu.quant.kv import QuantizedPages
 
 _NEG_INF = -1e30
@@ -362,12 +406,14 @@ def _kernel_lookahead(
     holds (stale VMEM) is masked out of the scores and zeroed in V, so
     zero-weight garbage cannot reach the accumulator.
 
-    Grid programs execute serially on the core, and scratch PERSISTS across
-    them; the page table is scalar-prefetched, so program b issues program
-    b+1's first ``lookahead`` tiles into the opposite parity's window while it
-    computes on its own (prefetched by b-1). Tiles >= lookahead (long
-    contexts) stream through the in-program double buffer: tile t+1 in flight
-    while tile t is merged.
+    The grid is over the batch's LIVE rows (program i serves row
+    ``order_ref[i]``; the module docstring's "Live rows"). Grid programs
+    execute serially on the core, and scratch PERSISTS across them; the page
+    table is scalar-prefetched, so program i issues program i+1's first
+    ``lookahead`` tiles into the opposite parity's window while it computes on
+    its own (prefetched by i-1). Tiles >= lookahead (long contexts) stream
+    through the in-program double buffer: tile t+1 in flight while tile t is
+    merged.
 
     The walk never looks inside a page, so it serves pools of either rank;
     the merge follows the rank. Pools ``[P, ps, Hkv, D]`` (head_dim a multiple
@@ -384,29 +430,31 @@ def _kernel_lookahead(
     operands go to the MXU as the pool holds them (bf16; int8 as f32, the
     per-row scale being head-independent), with f32 accumulation.
 
-    refs: page_tables + lengths (scalar prefetch) | q, k/v pools [, k/v
+    refs: page_tables + lengths + order (scalar prefetch) | q, k/v pools [, k/v
     scale rows [B*tiles_per_seq, 1, Ws], one per tile, see
     gather_scale_rows] | out | k_pre, v_pre [2, W, TP, ps, Hkv, D] [, scale
     windows [2, W, 1, Ws]], k_tail, v_tail [2, TP, ps, Hkv, D] [, scale tails
     [2, 1, Ws]], sems_pre [2, W, 2|4], sems_tail [2, 2|4]; a folded pool's
     scratch is [.., TP, ps, Hkv*D]. The copies of one tile and pool share a
     semaphore: each wait takes one page's bytes off it."""
+    page_tables_ref, lengths_ref, order_ref, q_ref, *refs = refs
     if quantized:
-        (page_tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm,
+        (k_hbm, v_hbm, ks_hbm, vs_hbm,
          out_ref, k_pre, v_pre, ks_pre, vs_pre, k_tail, v_tail, ks_tail,
          vs_tail, sems_pre, sems_tail) = refs
         pre_scales = [(ks_hbm, ks_pre), (vs_hbm, vs_pre)]
         tail_scales = [(ks_hbm, ks_tail), (vs_hbm, vs_tail)]
     else:
-        (page_tables_ref, lengths_ref, q_ref, k_hbm, v_hbm,
+        (k_hbm, v_hbm,
          out_ref, k_pre, v_pre, k_tail, v_tail, sems_pre, sems_tail) = refs
         pre_scales = tail_scales = []
     pre_pools = [(k_hbm, k_pre), (v_hbm, v_pre)]
     tail_pools = [(k_hbm, k_tail), (v_hbm, v_tail)]
 
-    b = pl.program_id(0)
+    i = pl.program_id(0)  # the grid is over the live rows: every program serves one
     nb = pl.num_programs(0)
-    par = jax.lax.rem(b, 2)
+    b = order_ref[i]  # the batch row this program serves
+    par = jax.lax.rem(i, 2)
     W, TP = lookahead, tile_pages
     S = TP * page_size  # context tokens per tile
     length = lengths_ref[b]
@@ -483,14 +531,14 @@ def _kernel_lookahead(
         jax.lax.fori_loop(0, jnp.minimum(W, pl.cdiv(npg, TP) - base), issue, 0)
 
     # program 0 has no predecessor: prefetch its own window
-    @pl.when(b == 0)
+    @pl.when(i == 0)
     def _():
-        issue_pre(0, 0)
+        issue_pre(b, 0)
 
-    # prefetch the NEXT program's window while this one computes
-    @pl.when(b + 1 < nb)
+    # prefetch the NEXT live row's window while this one computes
+    @pl.when(i + 1 < nb)
     def _():
-        issue_pre(b + 1, 1 - par)
+        issue_pre(order_ref[i + 1], 1 - par)
 
     # long-context tail: warm the in-program double buffer for tile W
     @pl.when(t0 + W < n_tiles)
@@ -610,11 +658,17 @@ def lookahead_window(page_size: int, num_kv_heads: int, head_dim: int,
 SLIDING_DECODE_NAME = "paged_decode_attention_sliding_window"
 
 
-def _tiled_decode(q, k_pages, v_pages, page_tables, positions, TP: int, W: int, *,
+def _tiled_decode(q, k_pages, v_pages, page_tables, positions, live, TP: int, W: int, *,
                   interpret: bool, window: int = 0, name=None):
     """``_kernel_lookahead`` over pools of either rank, at tiles of ``TP``
-    pages and a window of ``W`` tiles."""
+    pages and a window of ``W`` tiles. ``live`` (`ops.live_rows.LiveRows`, or
+    None: every row is live) names the rows the grid serves: it has
+    ``live.count`` programs (a grid bound read on the device), program i
+    walks row ``live.order[i]``, and a row that is not live costs nothing and
+    reads zero."""
     B, Hq, D = q.shape
+    if live is None:
+        live = every_row(B)
     kq, vq, ks, vs, quantized = _decode_unpack_pools(k_pages, v_pages, page_tables, TP)
     page = kq.shape[1:]  # [ps, Hkv, D], or folded [ps, Hkv*D]
     lengths = positions.astype(jnp.int32) + 1
@@ -627,15 +681,18 @@ def _tiled_decode(q, k_pages, v_pages, page_tables, positions, TP: int, W: int, 
                        pltpu.VMEM((*lead, 1, vs.shape[-1]), jnp.float32)]
         return shapes
 
+    def row_block(i, tables, lengths, order):
+        return order[i], 0, 0
+
     C = 4 if quantized else 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B,),
+        num_scalar_prefetch=3,
+        grid=(live.count[0],),
         in_specs=[
-            pl.BlockSpec((1, Hq, D), lambda b, *_: (b, 0, 0)),
+            pl.BlockSpec((1, Hq, D), row_block),
             *[pl.BlockSpec(memory_space=pl.ANY) for _ in range(C)],
         ],
-        out_specs=pl.BlockSpec((1, Hq, D), lambda b, *_: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, Hq, D), row_block),
         scratch_shapes=[
             *tile_scratch(2, W),
             *tile_scratch(2),
@@ -651,7 +708,7 @@ def _tiled_decode(q, k_pages, v_pages, page_tables, positions, TP: int, W: int, 
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
         grid_spec=grid_spec,
-        # cross-program scratch persistence (program b prefetches b+1's tiles
+        # cross-program scratch persistence (program i prefetches i+1's tiles
         # into the opposite parity's slots) requires the grid to run SERIALLY
         # — pin it rather than relying on the implicit default
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
@@ -659,7 +716,9 @@ def _tiled_decode(q, k_pages, v_pages, page_tables, positions, TP: int, W: int, 
         name=name,
     )
     args = (kq, vq, ks, vs) if quantized else (kq, vq)
-    return kernel(page_tables.astype(jnp.int32), lengths, q, *args)
+    out = kernel(page_tables.astype(jnp.int32), lengths, live.order, q, *args)
+    # no program wrote a dead row's block: it is memory as it was found
+    return zero_dead_rows(out, live)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "window"))
@@ -669,6 +728,7 @@ def paged_decode_attention_pallas_lookahead(
     v_pages,
     page_tables: jnp.ndarray,  # [B, max_pages] int32
     positions: jnp.ndarray,  # [B] int32 query positions
+    live: LiveRows | None = None,  # the rows to serve (None: every row)
     interpret: bool = False,
     window: int = 0,  # sliding window in tokens (0: the whole context)
 ) -> jnp.ndarray:
@@ -679,11 +739,12 @@ def paged_decode_attention_pallas_lookahead(
     if W < 1:
         if window:
             raise ValueError("the page-at-a-time decode kernel takes no window")
-        return paged_decode_attention_pallas(
+        # the page-at-a-time kernel walks every row
+        return zero_dead_rows(paged_decode_attention_pallas(
             q, k_pages, v_pages, page_tables, positions, interpret=interpret
-        )
+        ), live)
     return _tiled_decode(
-        q, k_pages, v_pages, page_tables, positions,
+        q, k_pages, v_pages, page_tables, positions, live,
         decode_tile_pages(ps, Hkv, D, itemsize), W, interpret=interpret,
         window=window, name=SLIDING_DECODE_NAME if window else None,
     )
@@ -701,6 +762,7 @@ def paged_decode_attention_pallas_folded(
     v_pages,
     page_tables: jnp.ndarray,  # [B, max_pages] int32
     positions: jnp.ndarray,  # [B] int32 query positions
+    live: LiveRows | None = None,  # the rows to serve (None: every row)
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Decode attention for head_dim < 128 (TinyLlama, Qwen2-small, LFM2: 64)
@@ -724,7 +786,7 @@ def paged_decode_attention_pallas_folded(
     W = lookahead_window(*geometry)
     if W < 1:  # a page of 128 tokens by 4096 lanes: no kernel here walks such a pool
         raise ValueError(f"no tile of a folded pool {k_pages.shape} fits the decode kernel's VMEM")
-    return _tiled_decode(q, k_pages, v_pages, page_tables, positions,
+    return _tiled_decode(q, k_pages, v_pages, page_tables, positions, live,
                          decode_tile_pages(*geometry), W,
                          interpret=interpret, name=FOLDED_DECODE_NAME)
 

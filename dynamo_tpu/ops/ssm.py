@@ -24,6 +24,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from dynamo_tpu.ops.live_rows import zero_dead_rows
+
 
 @jax.named_scope("ssm")
 def causal_conv(
@@ -113,32 +115,36 @@ def ssd_chunked(x, dt, A, B, C, D, state, chunk_size: int = 128):
     return y.swapaxes(0, 1).reshape(L, T, H, P), state
 
 
-def ssm_state_update_reference(state, decay, dtx, b_vec, c_vec, rows):
+def ssm_state_update_reference(state, decay, dtx, b_vec, c_vec, rows, live=None):
     """`ssm_state_update_pallas` in `jax.numpy` (same arguments and result):
     the path off the chip, and the kernel's yardstick."""
     hpg = dtx.shape[1] // b_vec.shape[1]
-    S = state[rows] * decay[..., None, None] \
+    old = state[rows]
+    S = old * decay[..., None, None] \
         + dtx[..., None] * jnp.repeat(b_vec, hpg, axis=1)[:, :, None, :]
     y = jnp.einsum("bhpn,bhn->bhp", S, jnp.repeat(c_vec, hpg, axis=1))
-    return y, state.at[rows].set(S)
+    if live is not None:
+        S = jnp.where(live.mask[:, None, None, None], S, old)
+    return zero_dead_rows(y, live), state.at[rows].set(S)
 
 
 @jax.named_scope("ssm")
-def ssm_state_update(state, rows, x, dt, A, B, C, D, active):
-    """One decode token for every batch row b, whose state is row ``rows[b]``
-    of ``state`` [R, H, P, N]; rows that are not ``active`` name a trash row
-    and get the identity update. x [B, H, P], dt [B, H], B, C [B, G, N],
-    float32. Returns (y [B, H, P], state updated in place where donated)."""
+def ssm_state_update(state, rows, x, dt, A, B, C, D, live):
+    """One decode token for every live batch row b, whose state is row
+    ``rows[b]`` of ``state`` [R, H, P, N]; ``live`` is the step's
+    `ops.live_rows.LiveRows`, and a row that is not live keeps its state and
+    reads y = D x. x [B, H, P], dt [B, H], B, C [B, G, N], float32. Returns
+    (y [B, H, P], state updated in place where donated)."""
     from dynamo_tpu.ops.attention import _on_tpu, _pallas_enabled
     from dynamo_tpu.ops.pallas.ssm_update import ssm_state_update_pallas
 
     rows = rows.astype(jnp.int32)
-    decay = jnp.where(active[:, None], jnp.exp(dt * A), 1.0)
-    dtx = jnp.where(active[:, None, None], dt[..., None] * x, 0.0)
+    decay = jnp.exp(dt * A)
+    dtx = dt[..., None] * x
     if _pallas_enabled(True):
         y, state = ssm_state_update_pallas(
-            state, decay, dtx, B, C, rows, interpret=not _on_tpu()
+            state, decay, dtx, B, C, rows, live, interpret=not _on_tpu()
         )
     else:
-        y, state = ssm_state_update_reference(state, decay, dtx, B, C, rows)
+        y, state = ssm_state_update_reference(state, decay, dtx, B, C, rows, live)
     return y + D[None, :, None] * x, state

@@ -208,3 +208,66 @@ def test_folded_tiled_decode_matches_reference(name):
         np.asarray(got, np.float32)[live], np.asarray(want, np.float32)[live], atol=atol
     )
     assert np.isfinite(np.asarray(got, np.float32)).all()
+
+
+# ---------------------------------------------------------------- live rows
+# (PR 46): the grid serves the rows `ops.live_rows` puts first; a row that is
+# not live is a grid step and nothing else, and reads zero
+
+#: name -> which of the B rows hold a sequence
+LIVE_MASKS = {
+    "all_live": [1, 1, 1, 1, 1],
+    "first_dead": [0, 1, 1, 1, 1],
+    "last_dead": [1, 1, 1, 1, 0],
+    "alternating": [1, 0, 1, 0, 1],
+    "alternating_first_dead": [0, 1, 0, 1, 0],
+    "one_live": [0, 0, 0, 1, 0],
+    "none_live": [0, 0, 0, 0, 0],
+}
+#: name -> (kernel, Hq, Hkv, D, pool dtype, int8, folded); every row holds a
+#: context, so a dead row is one the mask alone takes out
+LIVE_KERNELS = {
+    "heads": (paged_decode_attention_pallas_lookahead, 4, 2, D, "float32", False, False),
+    "heads_int8": (paged_decode_attention_pallas_lookahead, 8, 4, D, "bfloat16", True, False),
+    "folded": (paged_decode_attention_pallas_folded, 16, 8, 64, "bfloat16", False, True),
+    "folded_int8": (paged_decode_attention_pallas_folded, 16, 8, 64, "bfloat16", True, True),
+}
+LIVE_LENGTHS = [TILE + 3, (W + 1) * TILE + PS + 1, 1, W * TILE, 2 * TILE - 1]
+
+
+def test_live_rows_come_first_and_in_order():
+    from dynamo_tpu.ops.live_rows import every_row, live_rows
+
+    for mask in LIVE_MASKS.values():
+        live = live_rows(jnp.asarray(mask, bool))
+        n = int(live.count[0])
+        assert n == sum(mask) and live.count.dtype == live.order.dtype == jnp.int32
+        assert np.asarray(live.order).tolist() == (
+            [b for b in range(B) if mask[b]] + [b for b in range(B) if not mask[b]])
+        np.testing.assert_array_equal(live.mask, np.asarray(mask, bool))
+    whole = every_row(B)
+    assert np.asarray(whole.order).tolist() == list(range(B)) and int(whole.count[0]) == B
+
+
+@pytest.mark.parametrize("mask", LIVE_MASKS)
+@pytest.mark.parametrize("kind", LIVE_KERNELS)
+def test_the_grid_serves_the_live_rows_alone(kind, mask):
+    """A live row's output is the all-live call's to the bit (and the
+    reference's to the tolerance), a dead row's is zero; with no live row the
+    call returns (no DMA is started, so no semaphore is waited on)."""
+    from dynamo_tpu.ops.live_rows import every_row, live_rows
+
+    kernel, hq, hkv, d, dtype, int8, folded = LIVE_KERNELS[kind]
+    rng = np.random.default_rng(46)
+    P, tables, positions, _, _ = _batch(rng, PS, LIVE_LENGTHS)
+    k, v = _pools(rng, P, PS, hkv, dtype, int8, d, folded)
+    q = jnp.asarray(rng.standard_normal((B, hq, d), dtype=np.float32), dtype)
+    alive = np.asarray(LIVE_MASKS[mask], bool)
+    got = np.asarray(
+        kernel(q, k, v, tables, positions, live_rows(jnp.asarray(alive)), interpret=True),
+        np.float32)
+    whole = np.asarray(kernel(q, k, v, tables, positions, every_row(B), interpret=True), np.float32)
+    want = np.asarray(paged_decode_attention(q, k, v, tables, positions), np.float32)
+    np.testing.assert_array_equal(got[alive], whole[alive])
+    np.testing.assert_allclose(got[alive], want[alive], atol=2e-5 if dtype == "float32" else 2e-2)
+    np.testing.assert_array_equal(got[~alive], 0.0)

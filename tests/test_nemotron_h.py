@@ -242,6 +242,48 @@ def _check_against_reference(ckpt, prompts, results):
         np.testing.assert_allclose(lps, want, atol=LOGPROB_ATOL)
 
 
+@pytest.mark.parametrize("kernels", ["reference", "interpret"])
+def test_a_row_that_freezes_inside_a_window_is_skipped_from_the_next_step_on(
+        ckpt, monkeypatch, kernels):
+    """A window is four steps. A request of 6 tokens (one from prefill, five
+    decoded) ends one step into its second window, beside one of 11: the
+    step's live rows, made on the device, lose it from the next step on, and
+    both requests' tokens and logprobs are the reference's. With the state
+    kernel in interpret mode and without."""
+    from dynamo_tpu.models import nemotron_h
+
+    if kernels == "interpret":
+        monkeypatch.setenv("DYNTPU_PALLAS", "1")
+    counts = []
+
+    def spy(active):
+        live = nemotron_h_live_rows(active)
+        jax.debug.callback(lambda n: counts.append(int(n[0])), live.count, ordered=True)
+        return live
+
+    nemotron_h_live_rows = nemotron_h.live_rows
+    monkeypatch.setattr(nemotron_h, "live_rows", spy)
+    prompts, lengths = [_tokens(30, 20), _tokens(31, 9)], [6, 11]
+
+    async def body():
+        eng = _engine(ckpt)
+        await eng.start()
+        try:
+            return await asyncio.gather(*[
+                _generate(eng, f"freeze-{i}", p, n) for i, (p, n) in enumerate(zip(prompts, lengths))
+            ])
+        finally:
+            await eng.shutdown()
+
+    results = asyncio.run(body())
+    assert [len(toks) for toks, _ in results] == lengths
+    _check_against_reference(ckpt, prompts, results)
+    windows = [counts[i:i + 4] for i in range(0, len(counts), 4)]
+    assert len(counts) % 4 == 0 and all(w == sorted(w, reverse=True) for w in windows), windows
+    # the short request's last window: live for one step, skipped for three
+    assert any(w[0] == w[1] + 1 and w[1] == w[3] for w in windows), windows
+
+
 ENGINE_CASES = {
     # one request, one chunk, two decode windows
     "one_chunk": dict(prompts=[_tokens(10, 20)], max_tokens=8, concurrent=True, engine={}),

@@ -51,6 +51,38 @@ def test_decode_window_kernel_matches_reference(window):
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
 
 
+@pytest.mark.parametrize("alive", [
+    [0, 1, 1, 1], [1, 1, 1, 0], [1, 0, 1, 0], [0, 1, 0, 1], [1, 1, 1, 1], [0, 0, 1, 0], [0, 0, 0, 0],
+], ids=["first_dead", "last_dead", "alternating", "alternating_first_dead", "all_live",
+        "one_live", "none_live"])
+def test_decode_window_kernel_serves_the_live_rows_alone(alive):
+    """The window kernel under a step's live rows: a live row reads what the
+    all-live call gives it to the bit, a dead row zero."""
+    from dynamo_tpu.ops.live_rows import every_row, live_rows
+
+    window = 128
+    rng = np.random.default_rng(2)
+    B, Hq, width = 4, 4, 40
+    k, v = _pool(rng, 1 + B * width)
+    positions = np.array([5, window - 1, 3 * window + 7, 600], np.int32)
+    tables = 1 + np.arange(B * width, dtype=np.int32).reshape(B, width)
+    q = jnp.asarray(rng.standard_normal((B, Hq, D)), jnp.float32)
+    want = paged_decode_attention(q, k, v, jnp.asarray(tables), jnp.asarray(positions), window)
+    given_back = np.stack([_released(tables[b], positions[b], window) for b in range(B)])
+    whole = paged_decode_attention_pallas_lookahead(
+        q, k, v, jnp.asarray(given_back), jnp.asarray(positions), every_row(B),
+        interpret=True, window=window,
+    )
+    alive = np.asarray(alive, bool)
+    got = paged_decode_attention_pallas_lookahead(
+        q, k, v, jnp.asarray(given_back), jnp.asarray(positions), live_rows(jnp.asarray(alive)),
+        interpret=True, window=window,
+    )
+    np.testing.assert_array_equal(np.asarray(got)[alive], np.asarray(whole)[alive])
+    np.testing.assert_allclose(np.asarray(got)[alive], np.asarray(want)[alive], atol=2e-5, rtol=2e-5)
+    np.testing.assert_array_equal(np.asarray(got)[~alive], 0.0)
+
+
 @pytest.mark.parametrize("window,start", [(32, 0), (32, 256), (200, 384), (4096, 128)])
 @pytest.mark.parametrize("lookahead", [True, False])
 def test_prefill_window_kernel_matches_reference(window, start, lookahead):

@@ -7,6 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from dynamo_tpu.ops.live_rows import every_row, live_rows
 from dynamo_tpu.ops.pallas.ssm_update import ssm_state_update_pallas
 from dynamo_tpu.ops.ssm import (
     causal_conv,
@@ -90,27 +91,49 @@ def test_convolution_continues_from_its_window(split):
     np.testing.assert_allclose(win, x[:, T - K + 1:], atol=1e-6)
 
 
-@pytest.mark.parametrize("head_block", [8, 4])
-def test_state_update_kernel_matches_jax_numpy(head_block):
-    """Interpret mode, in place over the state: live rows updated, rows that
-    are not live and the trash row untouched."""
+#: name -> which of the five batch rows hold a sequence
+LIVE_MASKS = {
+    "scattered": [1, 0, 1, 1, 0],
+    "first_dead": [0, 1, 1, 1, 1],
+    "last_dead": [1, 1, 1, 1, 0],
+    "alternating": [0, 1, 0, 1, 0],
+    "all_live": [1, 1, 1, 1, 1],
+    "one_live": [0, 0, 0, 1, 0],
+    "none_live": [0, 0, 0, 0, 0],
+}
+
+
+@pytest.mark.parametrize("mask", LIVE_MASKS)
+@pytest.mark.parametrize("head_block", [8, 4], ids=["nb1", "nb2"])
+def test_state_update_kernel_matches_jax_numpy(head_block, mask):
+    """Interpret mode, in place over the state, one block of heads a row and
+    two: live rows updated (to the bit what a call with every row live gives
+    them), rows that are not live and the trash row untouched whatever decay
+    and dt x they carry, a dead row's y zero; with no live row the call
+    returns and the state is what came in."""
     rng = np.random.default_rng(3)
     f = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
     nb = 5
-    state = f(nb + 1, H, P, N).at[nb].set(0.0)
-    live = jnp.array([True, False, True, True, False])
-    rows = jnp.where(live, jnp.arange(nb), nb).astype(jnp.int32)
-    decay = jnp.where(live[:, None], jnp.exp(-jnp.abs(f(nb, H))), 1.0)
-    dtx = jnp.where(live[:, None, None], f(nb, H, P), 0.0)
+    state = f(nb + 1, H, P, N)
+    alive = np.asarray(LIVE_MASKS[mask], bool)
+    live = live_rows(jnp.asarray(alive))
+    rows = jnp.arange(nb, dtype=jnp.int32)
+    decay, dtx = jnp.exp(-jnp.abs(f(nb, H))), f(nb, H, P)
     b, c = f(nb, G, N), f(nb, G, N)
     with jax.default_matmul_precision("highest"):
-        want_y, want_s = ssm_state_update_reference(state, decay, dtx, b, c, rows)
+        want_y, want_s = ssm_state_update_reference(state, decay, dtx, b, c, rows, live)
     got_y, got_s = ssm_state_update_pallas(
-        state, decay, dtx, b, c, rows, head_block=head_block, interpret=True
+        state, decay, dtx, b, c, rows, live, head_block=head_block, interpret=True
     )
-    np.testing.assert_allclose(got_y[live], want_y[live], atol=1e-4)
+    whole_y, whole_s = ssm_state_update_pallas(
+        state, decay, dtx, b, c, rows, every_row(nb), head_block=head_block, interpret=True
+    )
+    np.testing.assert_allclose(got_y, want_y, atol=1e-4)
     np.testing.assert_allclose(got_s, want_s, atol=1e-5)
-    np.testing.assert_array_equal(got_s[1], state[1])
+    np.testing.assert_array_equal(np.asarray(got_y)[alive], np.asarray(whole_y)[alive])
+    np.testing.assert_array_equal(np.asarray(got_s)[:nb][alive], np.asarray(whole_s)[:nb][alive])
+    np.testing.assert_array_equal(np.asarray(got_y)[~alive], 0.0)
+    np.testing.assert_array_equal(np.asarray(got_s)[:nb][~alive], np.asarray(state)[:nb][~alive])
     np.testing.assert_array_equal(got_s[nb], state[nb])
 
 
@@ -129,23 +152,25 @@ def test_the_head_block_follows_from_the_blocks_bytes():
     assert head_block_for(H, P, N, G) == H
 
 
-def test_state_update_kernel_at_falcon_h1s_published_shape():
+@pytest.mark.parametrize("alive", [[True, False], [False, True]], ids=["first", "second"])
+def test_state_update_kernel_at_falcon_h1s_published_shape(alive):
     """Interpret mode at H = 32, P = 128, N = 256, G = 2, the block chosen
     from its bytes (16 heads, two blocks a row): a live row, a row that is
     not live, the trash row."""
     rng = np.random.default_rng(5)
     f = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
     Hh, Ph, Nh, Gh, nb = 32, 128, 256, 2, 2
-    state = f(nb + 1, Hh, Ph, Nh).at[nb].set(0.0)
-    live = jnp.array([True, False])
-    rows = jnp.where(live, jnp.arange(nb), nb).astype(jnp.int32)
-    decay = jnp.where(live[:, None], jnp.exp(-jnp.abs(f(nb, Hh))), 1.0)
-    dtx = jnp.where(live[:, None, None], f(nb, Hh, Ph), 0.0)
+    state = f(nb + 1, Hh, Ph, Nh)
+    live = live_rows(jnp.asarray(alive))
+    rows = jnp.arange(nb, dtype=jnp.int32)
+    decay, dtx = jnp.exp(-jnp.abs(f(nb, Hh))), f(nb, Hh, Ph)
     b, c = f(nb, Gh, Nh), f(nb, Gh, Nh)
     with jax.default_matmul_precision("highest"):
-        want_y, want_s = ssm_state_update_reference(state, decay, dtx, b, c, rows)
-    got_y, got_s = ssm_state_update_pallas(state, decay, dtx, b, c, rows, interpret=True)
-    np.testing.assert_allclose(got_y[0], want_y[0], atol=2e-4)
+        want_y, want_s = ssm_state_update_reference(state, decay, dtx, b, c, rows, live)
+    got_y, got_s = ssm_state_update_pallas(state, decay, dtx, b, c, rows, live, interpret=True)
+    mine, other = alive.index(True), alive.index(False)
+    np.testing.assert_allclose(got_y[mine], want_y[mine], atol=2e-4)
     np.testing.assert_allclose(got_s, want_s, atol=1e-5)
-    np.testing.assert_array_equal(got_s[1], state[1])
+    np.testing.assert_array_equal(got_y[other], 0.0)
+    np.testing.assert_array_equal(got_s[other], state[other])
     np.testing.assert_array_equal(got_s[nb], state[nb])
