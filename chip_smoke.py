@@ -835,6 +835,23 @@ def child_parity(sizes: Sizes, args) -> int:
             tables[b, :n] = [next(order) for _ in range(n)]
         return len(lengths), maxp, jnp.asarray(tables), jnp.asarray([n - 1 for n in lengths], jnp.int32)
 
+    def in_runs(batch):
+        """`batch`'s contexts with every tile of 128 tokens one aligned slab of
+        the pool, the last one whole (PR 47: the allocator reserves the rest of
+        a run for its sequence, and the kernel fetches such a tile in one copy)."""
+        def laid_out(ps):
+            B, maxp, _, pos = batch(ps)
+            tp = max(1, 128 // ps)
+            tiles = -(-(np.asarray(pos) + 1) // (tp * ps))
+            slabs = iter((1 + rng.permutation(int(tiles.sum()))) * tp)
+            tables = np.zeros((B, -(-maxp // tp) * tp), np.int32)
+            for b, n in enumerate(tiles):
+                for t in range(n):
+                    tables[b, t * tp:(t + 1) * tp] = next(slabs) + np.arange(tp)
+            return B, tables.shape[1], jnp.asarray(tables), pos
+
+        return laid_out
+
     def decode(hq, hkv, d, ps, int8, kernel=None, batch=ragged_batch):
         folded = d < 128 or kernel == "folded"
         B, maxp, tables, pos = batch(ps)
@@ -1065,6 +1082,11 @@ def child_parity(sizes: Sizes, args) -> int:
         ("decode lookahead qwen2.5-3b cell batch ps16 bf16", lambda: decode(*qwen3b, 16, False, batch=cell_batch)),
         ("decode lookahead qwen2.5-7b cell batch ps16 int8", lambda: decode(*qwen, 16, True, batch=cell_batch)),
         ("decode perseq qwen2.5-7b ps16 int8", lambda: decode(*qwen, 16, True, kernel="perseq")),
+        # every tile a run of the pool, fetched in one copy a pool (PR 47)
+        ("decode lookahead qwen2.5-3b cell batch in runs ps16 bf16",
+         lambda: decode(*qwen3b, 16, False, batch=in_runs(cell_batch))),
+        ("decode folded lfm2 cell batch in runs ps16 bf16",
+         lambda: decode(*lfm2, 16, False, batch=in_runs(rag_batch))),
         # the grid over live rows (PR 46): `chat`'s 11 live of 64, the rest zero
         ("decode lookahead qwen2.5-3b 11 live of 64 ps16 bf16",
          lambda: live_decode(*qwen3b, 16, 11 if full else 2)),

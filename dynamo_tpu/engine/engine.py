@@ -22,6 +22,7 @@ from dynamo_tpu.engine.config import EngineConfig
 from dynamo_tpu.engine.page_table import GroupedPageAllocator, PageAllocator
 from dynamo_tpu.engine.scheduler import EngineRequest, Scheduler, StepOutput
 from dynamo_tpu.llm.kv_events import KvCacheEvent
+from dynamo_tpu.ops.attention import decode_tile_of
 from dynamo_tpu.runtime.context import current_context
 from dynamo_tpu.utils import events, get_logger, tracing
 from dynamo_tpu.utils.goodput import GoodputTracker
@@ -254,12 +255,18 @@ class AsyncJaxEngine:
                 event_sink=self._on_kv_event,
             )
         else:
+            # a sequence grows by runs of the tile the decode kernel walks
+            # its pages by, derived from the pools' shapes as the kernel does
+            k_pool = self.runner.kv_cache.get("k")
+            head_dim = getattr(getattr(self.model, "config", None), "head_dim", 0)
+            known = k_pool is not None and head_dim  # else: single pages, as ever
             self.allocator = PageAllocator(
                 self.config.num_pages,
                 self.config.page_size,
                 event_sink=self._on_kv_event,
                 offload=offload,
                 match_prefix=not self.runner.recurrent,
+                tile_pages=decode_tile_of(k_pool, head_dim, max(1, self.config.tp)) if known else 1,
             )
         self.scheduler = Scheduler(self.config, self.runner, self.allocator)
         self.scheduler.slo = self.slo
@@ -1164,6 +1171,12 @@ class AsyncJaxEngine:
             "kv_pages_active": alloc.active_pages,
             "kv_pages_free": alloc.free_pages,
             "kv_pages_peak": alloc.peak_used_pages,
+            # the unwritten rest of the runs that running sequences grow into
+            # (taken back before the pool refuses anyone), and their tiles by
+            # whether the decode kernel fetches them in one copy
+            "kv_pages_reserved": alloc.reserved_pages,
+            "kv_tiles_run": alloc.run_tiles,
+            "kv_tiles_scattered": alloc.tiles - alloc.run_tiles,
             "prefix_cache_hit_blocks": alloc.cache_hit_blocks,
             "prefix_cache_miss_blocks": max(
                 0, alloc.cache_query_blocks - alloc.cache_hit_blocks
@@ -1516,7 +1529,13 @@ class AsyncJaxEngine:
                 "dynamo_engine_kv_pages", "gauge",
                 "KV page-pool occupancy by state (total excludes the null page)",
                 [({"state": s}, r[f"kv_pages_{s}"])
-                 for s in ("total", "used", "active", "free", "peak")],
+                 for s in ("total", "used", "active", "free", "peak", "reserved")],
+            ),
+            render_family(
+                "dynamo_engine_kv_tiles", "gauge",
+                "tiles of the running sequences' pages (what the decode attention "
+                "kernel walks at a time) by whether their pages are one run of the pool",
+                [({"state": s}, r[f"kv_tiles_{s}"]) for s in ("run", "scattered")],
             ),
             render_family(
                 "dynamo_engine_prefix_cache_blocks_total", "counter",

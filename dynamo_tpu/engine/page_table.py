@@ -14,6 +14,12 @@ vLLM-style prefix caching, re-designed for the JAX engine:
     (reference: lib/llm/src/kv/reuse.rs:50 AvailableBlocks priority reuse)
   - block store / evict emit KvCacheEvents for the KV router's global index
     (reference: lib/llm/src/kv_router/protocols.rs:35-100, publisher.rs:33-74)
+  - a sequence GROWS by runs of a TILE (PR 47): the decode attention kernel
+    walks a context a tile of pages at a time and fetches a tile whose pages
+    are consecutive in the pool as one copy, so fresh pages are taken a whole
+    aligned tile of the pool at a time wherever one is idle (`_FreeTiles`).
+    The page stays the unit of everything that names it: hashes, sharing,
+    refcounts, events, tiers, transfer
 
 Pure Python bookkeeping — device arrays never flow through here; the scheduler
 translates page ids into jnp page tables.
@@ -23,6 +29,7 @@ from __future__ import annotations
 
 from collections import OrderedDict
 from dataclasses import dataclass, field
+from itertools import islice
 from typing import Callable, Optional
 
 from dynamo_tpu.llm.tokens import TokenBlock, TokenSequence
@@ -42,9 +49,117 @@ class SequencePages:
     token_seq: Optional[TokenSequence] = None  # hashing state (block_size = page_size)
     registered_hashes: list[int] = field(default_factory=list)  # sequence hashes we cached
 
+    #: the rest of the run the newest pages came from, ascending: this
+    #: sequence's to grow into, holding no token yet, and taken back (from the
+    #: end) when the pool has nothing else to give
+    reserved: list[int] = field(default_factory=list)
+    tile_is_run: list[bool] = field(default_factory=list)  # per tile of `pages`
+
     @property
     def num_pages(self) -> int:
         return len(self.pages)
+
+    @property
+    def entries(self) -> list[int]:
+        """What the sequence's page table shows: its pages, then the rest of
+        their run, so that the kernel finds the last tile whole."""
+        return self.pages + self.reserved if self.reserved else self.pages
+
+
+_FREE, _CACHED, _HELD = 0, 1, 2
+
+
+class _FreeTiles:
+    """Which pages no running sequence holds, kept by TILE of the pool
+    (``tile`` consecutive pages, aligned), so that a run of a tile can be
+    found after any churn. A page is FREE, CACHED (a registered block with no
+    user: evictable) or HELD. A tile with no held page is WHOLE and can be
+    given out as one run; the free pages of every other tile are LOOSE and
+    serve the requests for single pages first, so whole tiles stay whole.
+    The allocator owns the blocks; this only tracks states and counts."""
+
+    def __init__(self, num_pages: int, tile: int):
+        self.tile = tile
+        self.state = bytearray(num_pages)  # all free
+        self.state[0] = _HELD  # the null page is nobody's
+        self.idle = [0] * -(-num_pages // tile)  # per tile: pages free or cached
+        for page in range(1, num_pages):
+            self.idle[page // tile] += 1
+        self.free = num_pages - 1  # pages in state FREE
+        #: whole tiles, the next to give first: those with no cached block in
+        #: front (nothing is lost by taking them), then by the time they became whole
+        self.whole: OrderedDict[int, None] = OrderedDict(
+            (k, None) for k, n in enumerate(self.idle) if n == tile)
+        self.loose: dict[int, None] = {
+            page: None for page in range(1, num_pages) if self.idle[page // tile] < tile}
+
+    def _pages(self, k: int) -> range:
+        return range(k * self.tile, min((k + 1) * self.tile, len(self.state)))
+
+    def hold(self, page: int) -> None:
+        """FREE or CACHED -> HELD (a cached block regained a user)."""
+        k = page // self.tile
+        if self.idle[k] == self.tile:  # no longer whole: its free pages are loose
+            del self.whole[k]
+            self.loose.update((p, None) for p in self._pages(k) if self.state[p] == _FREE)
+        if self.state[page] == _FREE:
+            self.free -= 1
+            del self.loose[page]
+        self.state[page] = _HELD
+        self.idle[k] -= 1
+
+    def release(self, page: int, cached: bool) -> None:
+        """HELD -> CACHED (its block stays registered) or FREE."""
+        k = page // self.tile
+        self.state[page] = _CACHED if cached else _FREE
+        self.free += not cached
+        self.idle[k] += 1
+        if self.idle[k] < self.tile:
+            if not cached:
+                self.loose[page] = None
+            return
+        pages = self._pages(k)
+        for p in pages:
+            self.loose.pop(p, None)
+        self.whole[k] = None
+        if all(self.state[p] == _FREE for p in pages):
+            self.whole.move_to_end(k, last=False)
+
+    def drop(self, page: int) -> None:
+        """CACHED -> FREE (its block was evicted where it lay)."""
+        self.state[page] = _FREE
+        self.free += 1
+        if self.idle[page // self.tile] < self.tile:
+            self.loose[page] = None
+
+    def take_run(self) -> Optional[tuple[int, list[int]]]:
+        """Hold the next whole tile: (its first page, its pages that hold a
+        cached block, which the caller evicts), or None where no tile is whole."""
+        if not self.whole:
+            return None
+        k, _ = self.whole.popitem(last=False)
+        pages = self._pages(k)
+        cached = [p for p in pages if self.state[p] == _CACHED]
+        self.free -= len(pages) - len(cached)
+        for p in pages:
+            self.state[p] = _HELD
+        self.idle[k] = 0
+        return pages[0], cached
+
+    def take_single(self) -> Optional[int]:
+        """Hold one free page: a loose one, else one of the next whole tile
+        (which breaks it). None where that tile holds cached blocks only: the
+        caller evicts the oldest block instead."""
+        if self.loose:
+            page = next(reversed(self.loose))
+        else:
+            k = next(iter(self.whole), None)
+            page = None if k is None else next(
+                (p for p in self._pages(k) if self.state[p] == _FREE), None)
+            if page is None:
+                return None
+        self.hold(page)
+        return page
 
 
 class PageAllocator:
@@ -57,11 +172,16 @@ class PageAllocator:
         event_sink: Optional[Callable[[KvCacheEvent], None]] = None,
         offload=None,  # Optional[HostKvPool]: host-DRAM tier (engine/offload.py)
         match_prefix: bool = True,
+        tile_pages: int = 1,
     ):
         if num_pages < 2:
             raise ValueError("need at least 2 pages (page 0 is reserved)")
         self.num_pages = num_pages
         self.page_size = page_size
+        #: pages the decode attention kernel walks at a time
+        #: (`ops.pallas.paged_attention.decode_tile_pages`, handed over by the
+        #: engine): a sequence takes its fresh pages by aligned runs of this many
+        self.tile_pages = tile_pages
         #: False for a model with recurrent layers: pages hold the attention
         #: layers' KV only, and a hit on them without the recurrent state at
         #: that position would be another model, silently. Every match is
@@ -73,14 +193,24 @@ class PageAllocator:
         # off-device blocks (host DRAM *or* disk tier): meta survives until
         # the block leaves its LAST tier, when the one removed event fires
         self._offloaded_meta: dict[int, StoredBlock] = {}
-        self._free: list[int] = list(range(num_pages - 1, 0, -1))  # stack; page 0 reserved
+        self._free = _FreeTiles(num_pages, tile_pages)  # page 0 reserved
         # sequence_hash -> physical page holding that full block
         self._cache: dict[int, int] = {}
         self._cache_meta: dict[int, StoredBlock] = {}  # seq_hash -> event payload
         self._refcount: dict[int, int] = {}  # physical page -> live users
         # refcount-0 cached blocks, LRU order (oldest first): seq_hash -> page
         self._reusable: OrderedDict[int, int] = OrderedDict()
+        self._reusable_hash: dict[int, int] = {}  # the same, page -> seq_hash
         self._seqs: dict[str, SequencePages] = {}
+        # the sequences that hold reserved pages (the unwritten rest of a
+        # run), oldest first, and how many those are in all
+        self._reserving: dict[str, SequencePages] = {}
+        self.reserved_pages = 0
+        # tiles of the running sequences' pages, and those of them that are
+        # one run: counters kept as the sequences change, because `/metrics`
+        # reads them from another thread, where `_seqs` cannot be walked
+        self.tiles = 0
+        self.run_tiles = 0
         # stats
         self.cache_hit_blocks = 0
         self.cache_query_blocks = 0
@@ -99,42 +229,114 @@ class PageAllocator:
 
     @property
     def free_pages(self) -> int:
-        """Immediately + reclaimably free pages."""
-        return len(self._free) + len(self._reusable)
+        """Pages a sequence can still be given: free, reclaimable from the
+        prefix cache, or reserved by a sequence that has written nothing
+        there yet (taken back when the rest runs dry)."""
+        return self._free.free + len(self._reusable) + self.reserved_pages
 
     @property
     def used_pages(self) -> int:
-        return (self.num_pages - 1) - len(self._free)
+        """Pages that hold tokens: of running sequences and of cached blocks."""
+        return (self.num_pages - 1) - self._free.free - self.reserved_pages
 
     @property
     def active_pages(self) -> int:
-        """Pages referenced by live sequences."""
-        return (self.num_pages - 1) - len(self._free) - len(self._reusable)
+        """Pages referenced by live sequences (their reserved ones hold
+        nothing yet and are not counted)."""
+        return self.used_pages - len(self._reusable)
 
     def pages_for_prompt(self, n_tokens: int) -> int:
         """Pages a prompt of `n_tokens` needs (admission's reckoning)."""
         return -(-n_tokens // self.page_size)
 
-    def _pop_free_page(self) -> int:
-        return self._pop_free_pages(1)[0]
-
-    def _pop_free_pages(self, n: int) -> list[int]:
-        """Take ``n`` pages: the free list first, then LRU reclaim from the
-        refcount-0 reusable pool — with the whole reclaim batch offloaded to
-        the host tier in ONE device gather (the per-block save path pays a
-        dispatch + D2H round trip per page, which serializes directly into
-        TTFT when a deep prompt allocates thousands of pages). Raises
-        MemoryError (nothing taken) when both sources run dry."""
-        if n <= len(self._free):
-            out = [self._free.pop() for _ in range(n)]
-        else:
-            if n > len(self._free) + len(self._reusable):
-                raise MemoryError("out of KV pages")
-            out = [self._free.pop() for _ in range(len(self._free))]
-            out.extend(self._reclaim_reusable(n - len(out)))
+    def _grow(self, state: SequencePages, n: int, owner) -> list[int]:
+        """Append ``n`` pages to the sequence and return them. At a tile
+        boundary of its logical pages the sequence takes a whole idle tile of
+        the pool where there is one: the pages it needs now, and the rest
+        RESERVED for it to grow into (the kernel fetches such a tile in one
+        copy). Elsewhere, and where no tile is whole, single pages: a free one,
+        else the LRU's oldest cached block, else a page another sequence has
+        reserved and not written. Every cached block evicted on the way goes
+        to the host tier in ONE batch (the per-block save path pays a dispatch
+        + D2H round trip per page, which serializes into TTFT when a deep
+        prompt allocates thousands of pages). Raises MemoryError, with
+        nothing taken, where the pool cannot give ``n``."""
+        if n > self.free_pages:
+            raise MemoryError("out of KV pages")
+        first_tile = len(state.pages) // self.tile_pages
+        victims: list[tuple[int, int]] = []  # (seq_hash, page) evicted to make room
+        fresh: list[int] = []
+        for _ in range(n):
+            if state.reserved:
+                page = state.reserved.pop(0)
+                self._reserve(state, -1)
+            elif len(state.pages) % self.tile_pages == 0 and (
+                    run := self._free.take_run()) is not None:
+                page, cached = run
+                victims += [(self._unpark(p), p) for p in cached]
+                state.reserved = list(range(page + 1, page + self.tile_pages))
+                self._reserve(state, len(state.reserved))
+            else:
+                page = self._free.take_single()
+                if page is None and self._reusable:
+                    page = next(iter(self._reusable.values()))  # the LRU's oldest
+                    victims.append((self._unpark(page), page))
+                    self._free.hold(page)
+                elif page is None:
+                    page = self._take_back()
+            self._refcount[page] = 1
+            state.pages.append(page)
+            fresh.append(page)
+        self._evict(victims)
+        self._meter_acquire(fresh, owner)
+        self._retile(state, first_tile)
         if self.used_pages > self.peak_used_pages:
             self.peak_used_pages = self.used_pages
-        return out
+        return fresh
+
+    def _reserve(self, state: SequencePages, n: int) -> None:
+        """The sequence's reserved pages changed by ``n``."""
+        self.reserved_pages += n
+        if state.reserved:
+            self._reserving[state.seq_id] = state
+        else:
+            self._reserving.pop(state.seq_id, None)
+
+    def _take_back(self) -> int:
+        """The last reserved page of the sequence that has reserved longest:
+        what it keeps of its run is still the run's start, and its last tile
+        is no run until it has grown through it."""
+        other = next(iter(self._reserving.values()))
+        page = other.reserved.pop()
+        self._reserve(other, -1)
+        self._retile(other, len(other.tile_is_run) - 1)
+        return page
+
+    def _unpark(self, page: int) -> int:
+        """A refcount-0 cached page leaves the reusable pool: its seq_hash."""
+        seq_hash = self._reusable_hash.pop(page)
+        del self._reusable[seq_hash]
+        return seq_hash
+
+    def _is_run(self, state: SequencePages, t: int) -> bool:
+        """Tile t of the sequence's page table is one run of the pool (what
+        the kernel's flag will say of it)."""
+        n = self.tile_pages
+        tile = state.pages[t * n:(t + 1) * n]
+        if len(tile) < n:
+            tile = tile + state.reserved[:n - len(tile)]
+        return n > 1 and len(tile) == n and tile == list(range(tile[0], tile[0] + n))
+
+    def _retile(self, state: SequencePages, first_tile: int) -> None:
+        """Count the sequence's tiles from ``first_tile`` on again (it grew,
+        shared a prefix, or lost a reserved page); with no pages, forget them."""
+        first_tile = max(0, first_tile)
+        was = state.tile_is_run[first_tile:]
+        now = [self._is_run(state, t)
+               for t in range(first_tile, -(-len(state.pages) // self.tile_pages))]
+        state.tile_is_run[first_tile:] = now
+        self.tiles += len(now) - len(was)
+        self.run_tiles += sum(now) - sum(was)
 
     def _meter_acquire(self, pages: list[int], owner) -> None:
         """Metering edge: ``pages`` became HBM-resident under ``owner``."""
@@ -151,40 +353,33 @@ class PageAllocator:
             return self.meter.kv_release("hbm", page)
         return None
 
-    def _reclaim_reusable(self, n: int) -> list[int]:
-        """Evict up to ``n`` LRU refcount-0 cached blocks; with a host tier
-        configured their KV is offloaded (one batched gather) instead of
-        dropped. Returns the freed pages."""
-        victims: list[tuple[int, object, int]] = []  # (seq_hash, meta, page)
-        while self._reusable and len(victims) < n:
-            seq_hash, page = self._reusable.popitem(last=False)
-            del self._cache[seq_hash]
-            victims.append((seq_hash, self._cache_meta.pop(seq_hash), page))
+    def _evict(self, victims: list[tuple[int, int]]) -> None:
+        """Cached blocks ``(seq_hash, page)``, already out of the reusable
+        pool, leave the device: with a host tier configured their KV is
+        offloaded (one batched gather) instead of dropped. Each block that
+        leaves its last tier is named in the one ``removed`` event."""
         if not victims:
-            return []
+            return
+        metas = {h: self._cache_meta.pop(h) for h, _ in victims}
+        for h, _ in victims:
+            del self._cache[h]
         # metering: every victim page leaves HBM here; the owners ride into
         # the host pool so demoted residency keeps charging its creator
-        owners = {h: self._meter_release(p) for h, _, p in victims}
+        owners = {h: self._meter_release(p) for h, p in victims}
         removed = []
         if self.offload is not None:
-            dropped = set(
-                self.offload.save_many(
-                    [(h, p) for h, _, p in victims], owners=owners
-                )
-            )
-            meta_by_hash = {h: m for h, m, _ in victims}
-            for h, m, _ in victims:
+            dropped = set(self.offload.save_many(victims, owners=owners))
+            for h, m in metas.items():
                 if h not in dropped:
                     self._offloaded_meta[h] = m
             for victim in dropped:
-                vm = meta_by_hash.get(victim) or self._offloaded_meta.pop(victim, None)
+                vm = metas.get(victim) or self._offloaded_meta.pop(victim, None)
                 if vm is not None:
                     removed.append(vm.block_hash)
         else:
-            removed = [m.block_hash for _, m, _ in victims]
+            removed = [m.block_hash for m in metas.values()]
         if removed:
             self._emit(KvCacheEvent.removed(removed))
-        return [p for _, _, p in victims]
 
     def drain_to_host(self, n: int) -> int:
         """Pressure-driven offload: move up to ``n`` of the coldest
@@ -194,8 +389,10 @@ class PageAllocator:
         exhaustion. Returns the number of pages freed."""
         if self.offload is None or not self._reusable:
             return 0
-        pages = self._reclaim_reusable(n)
-        self._free.extend(pages)
+        pages = list(islice(self._reusable.values(), n))
+        self._evict([(self._unpark(page), page) for page in pages])
+        for page in pages:
+            self._free.drop(page)
         return len(pages)
 
     # ------------- events -------------
@@ -291,6 +488,10 @@ class PageAllocator:
             self._ref_page(page)
         state.pages.extend(device_hits)
         state.shared_prefix_pages = len(device_hits)
+        # a shared prefix is a run for the sharer wherever it was for its
+        # first writer (the same logical index); the tile where shared and
+        # own pages meet is none
+        self._retile(state, 0)
 
         try:
             # host-tier blocks: fresh pages first, then ONE batched inject for
@@ -299,12 +500,8 @@ class PageAllocator:
             # re-registered on-device so later sequences share them again
             host_pairs: list[tuple[int, int]] = []
             if host_hit_hashes:
-                fresh = self._pop_free_pages(len(host_hit_hashes))
-                self._meter_acquire(fresh, owner)
-                for seq_hash, page in zip(host_hit_hashes, fresh):
-                    self._refcount[page] = 1
-                    state.pages.append(page)
-                    host_pairs.append((seq_hash, page))
+                fresh = self._grow(state, len(host_hit_hashes), owner)
+                host_pairs = list(zip(host_hit_hashes, fresh))
             hit_hashes = self.offload.load_many(host_pairs) if host_pairs else set()
             # only the contiguous restored prefix counts as cached: a block may
             # have been LRU-dropped from the host pool while its destination
@@ -345,11 +542,7 @@ class PageAllocator:
             total_pages_needed = -(-len(prompt_tokens) // self.page_size)
             need = total_pages_needed - len(state.pages)
             if need > 0:
-                fresh = self._pop_free_pages(need)
-                self._meter_acquire(fresh, owner)
-                for page in fresh:
-                    self._refcount[page] = 1
-                    state.pages.append(page)
+                self._grow(state, need, owner)
         except MemoryError:
             self._rollback(state)
             self._seq_owner.pop(seq_id, None)
@@ -413,9 +606,7 @@ class PageAllocator:
         data is still valid; only uncached fresh pages go back to the free list."""
         pages = set(state.pages)
         page_to_hash = {p: h for h, p in self._cache.items() if p in pages}
-        for page in state.pages:
-            self._unref_page(page, evictable_hash=page_to_hash.get(page))
-        state.pages.clear()
+        self._release(state, page_to_hash)
 
     def commit_prefilled(self, seq_id: str, prompt_len: int) -> None:
         """Register all full blocks covered by the (now computed) prompt KV."""
@@ -432,13 +623,9 @@ class PageAllocator:
         if state.num_pages >= needed:
             return True
         try:
-            fresh = self._pop_free_pages(needed - state.num_pages)
+            self._grow(state, needed - state.num_pages, self._seq_owner.get(seq_id))
         except MemoryError:
             return False
-        self._meter_acquire(fresh, self._seq_owner.get(seq_id))
-        for page in fresh:
-            self._refcount[page] = 1
-            state.pages.append(page)
         return True
 
     def append_token(self, seq_id: str, token: int) -> None:
@@ -478,18 +665,30 @@ class PageAllocator:
         for i, block in enumerate(state.token_seq.blocks):
             if i < len(state.pages) and block.sequence_hash in self._cache and self._cache[block.sequence_hash] == state.pages[i]:
                 page_to_hash[state.pages[i]] = block.sequence_hash
-        for page in state.pages:
-            self._unref_page(page, evictable_hash=page_to_hash.get(page))
+        self._release(state, page_to_hash)
 
     # ------------- internals -------------
+
+    def _release(self, state: SequencePages, page_to_hash: dict) -> None:
+        """The sequence lets go of everything: its pages lose a user (a
+        registered block stays, evictable), its reserved pages are free. A
+        tile it alone held is whole again, and comes out as the run it was."""
+        for page in state.pages:
+            self._unref_page(page, evictable_hash=page_to_hash.get(page))
+        state.pages.clear()
+        for page in state.reserved:
+            self._free.release(page, cached=False)
+        n = len(state.reserved)
+        state.reserved.clear()
+        self._reserve(state, -n)
+        self._retile(state, 0)
 
     def _ref_page(self, page: int) -> None:
         self._refcount[page] = self._refcount.get(page, 0) + 1
         # a cached page in the reusable pool that regains a user leaves the pool
-        for seq_hash, p in list(self._reusable.items()):
-            if p == page:
-                del self._reusable[seq_hash]
-                break
+        if page in self._reusable_hash:
+            self._unpark(page)
+            self._free.hold(page)
 
     def _unref_page(self, page: int, evictable_hash: Optional[int]) -> None:
         rc = self._refcount.get(page, 0) - 1
@@ -500,11 +699,13 @@ class PageAllocator:
         if evictable_hash is not None and self._cache.get(evictable_hash) == page:
             self._reusable[evictable_hash] = page  # cached, reclaimable, LRU tail
             self._reusable.move_to_end(evictable_hash)
+            self._reusable_hash[page] = evictable_hash
+            self._free.release(page, cached=True)
             # metering: a reusable-pool page stays resident and keeps
             # charging its owner — no edge until reclaim
         else:
             self._meter_release(page)
-            self._free.append(page)
+            self._free.release(page, cached=False)
 
     def _register_block(self, state: SequencePages, block: TokenBlock, page: int) -> None:
         if block.sequence_hash in self._cache:
@@ -572,6 +773,9 @@ class GroupedPageAllocator(PageAllocator):
     def __init__(self, num_pages: int, page_size: int, groups,
                  event_sink: Optional[Callable[[KvCacheEvent], None]] = None):
         super().__init__(num_pages, page_size, event_sink=event_sink)
+        # a stack of single pages: an entry's pages are one of each layer of a
+        # group, and no kernel walks them as a run
+        self._free: list[int] = list(range(num_pages - 1, 0, -1))
         self.groups = list(groups)
         self.num_tables = sum(len(g.tables) for g in self.groups)
         #: the groups that keep every block: their chain decides a match's length
@@ -599,8 +803,12 @@ class GroupedPageAllocator(PageAllocator):
         return len(self._free) + self._evictable_pages
 
     @property
+    def used_pages(self) -> int:
+        return (self.num_pages - 1) - len(self._free)
+
+    @property
     def active_pages(self) -> int:
-        return (self.num_pages - 1) - len(self._free) - self._evictable_pages
+        return self.used_pages - self._evictable_pages
 
     def pages_for_prompt(self, n_tokens: int) -> int:
         """Pages a prompt of `n_tokens` needs at most at one time."""

@@ -737,14 +737,17 @@ class Scheduler:
         its page count) — not the dense max_pages_per_seq width, so a short
         request in a 128K-capable engine dispatches a narrow table. A model
         with layer groups has one row per attention layer (`[tables, width]`,
-        from `GroupedSequencePages.tables`); every other model one row."""
+        from `GroupedSequencePages.tables`); every other model one row, which
+        shows the rest of the newest pages' run too (`SequencePages.entries`:
+        the decode kernel fetches a tile that is a run whole)."""
         width = self.config.table_bucket_for(max(1, state.num_pages))
         if self.grouped:
             table = np.zeros((self.allocator.num_tables, width), np.int32)
             table[:, : state.num_pages] = state.tables
             return table
         table = np.zeros(width, np.int32)
-        table[: state.num_pages] = state.pages
+        entries = state.entries[:width]
+        table[: len(entries)] = entries
         return table
 
     def _refresh_table(self, seq: RunningSeq) -> None:
@@ -758,7 +761,9 @@ class Scheduler:
         elif self.grouped:
             seq.page_table[:, :n] = state.tables
         else:
-            seq.page_table[:n] = state.pages
+            entries = state.entries[: seq.page_table.shape[-1]]
+            seq.page_table[: len(entries)] = entries
+            seq.page_table[len(entries):] = 0  # a reserved page taken back since
 
     def _batch_tables(self, rows: int, seqs: list) -> tuple:
         """(zeroed page tables for a batch of `rows`, their ladder width): the

@@ -37,6 +37,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dynamo_tpu.models.llama import parse_dtype
 from dynamo_tpu.ops.attention import (
+    decode_tile_runs,
     dispatch_paged_decode_attention,
     dispatch_paged_prefill_attention,
     scatter_kv,
@@ -391,6 +392,10 @@ class Cohere2MoeModel:
         with jax.named_scope("attn_kv"):
             offsets = jnp.where(active, positions % page_size, 0)
         live = live_rows(active)  # once a step, for every layer's kernel
+        # likewise, a row per layer's table (the grouped allocator gives no runs: all zero)
+        runs = decode_tile_runs(tables.reshape(-1, tables.shape[-1]), cache["k"], c.head_dim,
+                                self.attn_mesh)
+        runs = None if runs is None else runs.reshape(tables.shape[0], -1)
 
         with jax.named_scope("embed"):
             hidden = params["embed"][tokens].astype(c.dtype)
@@ -401,10 +406,10 @@ class Cohere2MoeModel:
             with jax.named_scope("attn_kv"):
                 phys = jnp.where(active, table[jnp.arange(B), positions // page_size], 0)
 
-            def attn_fn(q, k_pool, v_pool, window, table=table):
+            def attn_fn(q, k_pool, v_pool, window, table=table, l=l):
                 return dispatch_paged_decode_attention(
                     q, k_pool, v_pool, table, positions, mesh=self.attn_mesh, window=window,
-                    live=live,
+                    live=live, runs=None if runs is None else runs[l],
                 )
 
             n = layer_norm(hidden, lp["norm"], c.layer_norm_eps)

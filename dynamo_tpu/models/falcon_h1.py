@@ -48,6 +48,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dynamo_tpu.models.llama import parse_dtype
 from dynamo_tpu.ops.attention import (
+    decode_tile_runs,
     dispatch_paged_decode_attention,
     dispatch_paged_prefill_attention,
     scatter_kv,
@@ -542,6 +543,7 @@ class FalconH1Model:
             phys = jnp.where(active, page_tables[jnp.arange(B), positions // page_size], 0)
             offsets = jnp.where(active, positions % page_size, 0)
         live = live_rows(active)  # once a step, for every layer's two kernels
+        runs = decode_tile_runs(page_tables, cache["k"], c.head_dim, self.attn_mesh)  # likewise
 
         def body(carry, xs):
             hidden, k_pool, v_pool, ssm, conv = carry
@@ -550,7 +552,8 @@ class FalconH1Model:
 
             def attn_fn(q, kp, vp):
                 return dispatch_paged_decode_attention(
-                    q, kp, vp, off + page_tables, positions, mesh=self.attn_mesh, live=live
+                    q, kp, vp, off + page_tables, positions, mesh=self.attn_mesh, live=live,
+                    runs=runs,
                 )
 
             u = rms_norm(hidden, lp["input_norm"], c.rms_norm_eps)

@@ -43,6 +43,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dynamo_tpu.models.llama import parse_dtype
 from dynamo_tpu.ops.attention import (
+    decode_tile_runs,
     dispatch_paged_decode_attention,
     dispatch_paged_prefill_attention,
     scatter_kv,
@@ -470,6 +471,7 @@ class Lfm2MoeModel:
             phys = jnp.where(active, page_tables[jnp.arange(B), positions // page_size], 0)
             offsets = jnp.where(active, positions % page_size, 0)
         live = live_rows(active)  # once a step, for every layer's kernel
+        runs = decode_tile_runs(page_tables, cache["k"], c.head_dim, self.attn_mesh)  # likewise
 
         with jax.named_scope("embed"):
             hidden = params["embed"][tokens].astype(c.dtype)
@@ -486,7 +488,7 @@ class Lfm2MoeModel:
                 def attn_fn(q, k_pool, v_pool, off=off):
                     return dispatch_paged_decode_attention(
                         q, k_pool, v_pool, off + page_tables, positions, mesh=self.attn_mesh,
-                        live=live,
+                        live=live, runs=runs,
                     )
 
                 out, cache = self._attention(

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dynamo_tpu.ops.attention import (
+    decode_tile_runs,
     dispatch_paged_decode_attention,
     dispatch_paged_prefill_attention,
     scatter_kv,
@@ -862,6 +863,7 @@ class LlamaModel:
             phys = jnp.where(active, page_tables[jnp.arange(B), logical], 0)
             offsets = jnp.where(active, positions % page_size, 0)
         live = live_rows(active)  # once a step, for every layer's kernel
+        runs = decode_tile_runs(page_tables, k_pool, c.head_dim, self.attn_mesh)  # likewise
 
         with jax.named_scope("embed"):
             hidden = params["embed"][tokens].astype(c.dtype)
@@ -876,7 +878,8 @@ class LlamaModel:
 
             def attn_fn(q, k_new, v_new, kp_, vp_):
                 return dispatch_paged_decode_attention(
-                    q, kp_, vp_, off + page_tables, positions, mesh=self.attn_mesh, live=live
+                    q, kp_, vp_, off + page_tables, positions, mesh=self.attn_mesh, live=live,
+                    runs=runs,
                 )
 
             lkw = {}

@@ -308,10 +308,53 @@ def _over_head_shards(fn, mesh, q, k_pages, v_pages, tables, positions, *rest):
     )(q, k_pages, v_pages, tables, positions, *rest)
 
 
+def _decode_geometry(k_pages, head_dim: int, tp: int = 1) -> tuple:
+    """``(page size, kv heads, head_dim, itemsize)`` of the tiled decode walk
+    over such pools on one of ``tp`` head shards: what `decode_tile_pages` and
+    `lookahead_window` derive its tile and window from. A pool the folded
+    kernel serves (folded, or head_dim under a lane row) is one head of
+    Hkv * D lanes to the walk."""
+    folded = k_pages.ndim == 3
+    heads = (k_pages.shape[2] // head_dim if folded else k_pages.shape[2]) // tp
+    ps, itemsize = k_pages.shape[1], k_pages.dtype.itemsize
+    if folded or head_dim % 128 != 0:
+        return ps, 1, heads * head_dim, itemsize
+    return ps, heads, head_dim, itemsize
+
+
+def decode_tile_of(k_pages, head_dim: int, tp: int = 1) -> int:
+    """Pages the tiled decode walk takes at a time over such pools (on one of
+    ``tp`` head shards): the run the engine's allocator grows a sequence by."""
+    from dynamo_tpu.ops.pallas.paged_attention import decode_tile_pages
+
+    return decode_tile_pages(*_decode_geometry(k_pages, head_dim, tp))
+
+
+@jax.named_scope("tile_runs")
+def decode_tile_runs(page_tables, k_pages, head_dim: int, mesh=None):
+    """Which tiles of a decode step's page tables are RUNS of the pool
+    (`ops.pallas.paged_attention.tile_runs`, at the tile the dispatch below
+    will walk these pools by), for `dispatch_paged_decode_attention`'s
+    ``runs``: made once a decode step, outside the layer scan (a layer's
+    offset moves every entry alike). None where no kernel will read it."""
+    folded = k_pages.ndim == 3
+    num_kv_heads = k_pages.shape[2] // head_dim if folded else k_pages.shape[2]
+    tp = 1 if mesh is None else mesh.shape.get("tp", 1)
+    if not use_pallas_decode(head_dim, num_kv_heads) or num_kv_heads % tp:
+        return None
+    from dynamo_tpu.ops.pallas.paged_attention import tile_runs
+
+    return tile_runs(page_tables, decode_tile_of(k_pages, head_dim, tp))
+
+
 @jax.named_scope("attn")
 def dispatch_paged_decode_attention(q, k_pages, v_pages, page_tables, positions, mesh=None,
-                                    window: int = 0, live=None):
+                                    window: int = 0, live=None, runs=None):
     """Pallas kernel on TPU, pure-JAX reference elsewhere (same contract).
+
+    ``runs`` (`decode_tile_runs` of these tables, made once a decode step;
+    None: the kernel makes it from the tables it is given) says which tiles
+    are one slab of the pool, which the tiled walk fetches in one copy.
 
     ``live`` (`ops.live_rows.LiveRows`, made once a decode step; None: every
     row) names the batch rows that hold a sequence. The tiled kernel's grid
@@ -361,6 +404,7 @@ def dispatch_paged_decode_attention(q, k_pages, v_pages, page_tables, positions,
     # can't DMA-slice sub-128-lane pools; heads live folded into the lane
     # dim). The same walk with the folded row of Hkv * D lanes taken as one
     # head, and the merge that never unfolds.
+    tp = 1 if mesh is None else mesh.shape.get("tp", 1)
     use_folded = folded or D % 128 != 0
     kernel = (
         paged_decode_attention_pallas_folded
@@ -368,7 +412,6 @@ def dispatch_paged_decode_attention(q, k_pages, v_pages, page_tables, positions,
         else paged_decode_attention_pallas_lookahead
     )
     interpret = not _on_tpu()
-    tp = 1 if mesh is None else mesh.shape.get("tp", 1)
     path = f"pallas:{kernel.__name__}"
     if window:
         tiled = not use_folded and num_kv_heads % tp == 0 and lookahead_window(
@@ -380,9 +423,8 @@ def dispatch_paged_decode_attention(q, k_pages, v_pages, page_tables, positions,
     if num_kv_heads % tp == 0:
         # the geometry one head shard's kernel derives, so a server log says
         # which tile width ran
-        ps, heads, itemsize = k_pages.shape[1], num_kv_heads // tp, k_pages.dtype.itemsize
-        # a folded pool's row of Hkv * D lanes is one head to the walk
-        geometry = (ps, 1, heads * D, itemsize) if use_folded else (ps, heads, D, itemsize)
+        ps = k_pages.shape[1]
+        geometry = _decode_geometry(k_pages, D, tp)
         ahead = lookahead_window(*geometry)
         if use_folded and not ahead:
             _log_path("decode", "reference", f"{shape}: no tile of the folded pool fits VMEM")
@@ -392,7 +434,7 @@ def dispatch_paged_decode_attention(q, k_pages, v_pages, page_tables, positions,
     path += " interpret" if interpret else ""
     if tp == 1:
         _log_path("decode", path, shape)
-        return kernel(q, k_pages, v_pages, page_tables, positions, live, interpret=interpret)
+        return kernel(q, k_pages, v_pages, page_tables, positions, live, runs, interpret=interpret)
 
     why = _head_shard_refusal(shape, Hq, num_kv_heads, D, tp, folded)
     if why is not None:
@@ -401,7 +443,7 @@ def dispatch_paged_decode_attention(q, k_pages, v_pages, page_tables, positions,
     _log_path("decode", f"{path} shard_map tp={tp}", shape)
     return _over_head_shards(
         functools.partial(kernel, interpret=interpret),
-        mesh, q, k_pages, v_pages, page_tables, positions, live,
+        mesh, q, k_pages, v_pages, page_tables, positions, live, runs,
     )
 
 

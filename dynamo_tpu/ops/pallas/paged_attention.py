@@ -153,6 +153,53 @@ runs, so no DMA starts and no semaphore is waited on.
     live beside it (same tiles in the same order; only the scratch parity a
     row lands on differs, and stale scratch is masked as before).
 
+Runs (PR 47; my chip runs on a TPU v5e, tools/profile_tile_runs.py: 36 chained
+calls, best of 5, parent and change in one call; the rank-4 rows at the design
+record's geometry, 64 / 45 / 15 live contexts of 200-900 tokens holding 2028 /
+1601 / 554 pages in 280 / 215 / 77 tiles; the folded row at lfm2-8b-a1b's
+shape, 256 contexts of 1536 tokens). What a call paid for was the NUMBER of
+copies, not their bytes: 44 ns a page for its two 8 KiB DMAs, started and
+waited by the scalar core in the merge's own instruction stream, where the
+bytes take 20. The pool is one array indexed by physical page, so eight
+consecutive physical pages are one contiguous slab, and the tile's scratch
+slot already has that shape: a tile whose table entries are first, first + 1,
+... moves as ONE copy a pool (`hbm.at[pl.ds(first, TP)]`), started once and
+waited once. Which tiles are such RUNS is read off the input (`tile_runs`, made
+once a decode step on the device, outside the layer scan: a layer's offset
+moves every entry alike; 13-18 us a step), a fourth scalar-prefetch operand of
+one word a tile; the engine's allocator gives a sequence its pages by aligned
+runs of a tile (engine/page_table.py) and shows the rest of the newest run in
+the table, so a run's last tile is fetched WHOLE: what its unwritten pages hold
+is masked out of the scores and zeroed in V like any stale scratch.
+
+  us per call (share of HBM roofline)   until PR 47   every tile a run   half    none
+  64 of 64                              245.7 (16.5)  171.8 (23.7)       206.9   241.1
+  45 live + 19 empty (chat-over)        192.4 (16.7)  135.3 (23.7)       162.3   190.9
+  15 live + 49 empty (chat)              82.3 (13.5)   62.0 (17.9)        70.8    81.8
+  no live row                            18.1          17.7
+  folded, 256 of 256                    2341  (42.1)  1816  (54.3)       2021    2342
+  null walk (no arithmetic), 64 of 64   145.0 *       107.0              107.4   133.4
+  (* a wait a page, my first call; 133.4 is a page a copy with one wait a full tile)
+
+  - A run saves 0.26 us a tile of the rank-4 walk (74 us over 280 tiles) and
+    0.17 us a tile of the folded one (525 us over 3072), and half the tiles as
+    runs save half of it: the cost is linear in the copies.
+  - The null walk (same grid, window, tail and copies, no arithmetic) reads
+    107 us with one copy a tile where the tile's 128 KiB take 160 ns (45 us
+    for 280 tiles): the stream is still latency, not bandwidth. What is left
+    of a real call: 18 us a call before its first row (launch, the page table
+    into SMEM, q / out blocks), the merge's 0.23 us a tile (65 us: real less
+    null), about 1 us a row.
+  - A tile that is no run goes a page a copy as before, but its WAIT is one:
+    the page copies of a FULL tile put a tile's bytes on the semaphore, which
+    one wait takes off as it does a run's (16 waits less a tile). The first
+    form branched twice a side (run / not run) and cost the scattered layout
+    14 us of 244 (+5.7%, 12 ns a branch taken or not); one `pl.when` a side,
+    with the page loop's trip count zeroed by a select, reads 241.1 against
+    the parent's 244.4 with no tile a run.
+  - A live row's arithmetic is what it was, to the bit, however its pages lie:
+    the same rows land in the same scratch.
+
 Int8 KV (quant/kv.py QuantizedPages): perseq and the tiled walk (both pool
 ranks) accept int8 pools plus their per-row f32 scales, which arrive as
 lane-aligned rows gathered by XLA in page-table order (gather_scale_rows —
@@ -430,14 +477,16 @@ def _kernel_lookahead(
     operands go to the MXU as the pool holds them (bf16; int8 as f32, the
     per-row scale being head-independent), with f32 accumulation.
 
-    refs: page_tables + lengths + order (scalar prefetch) | q, k/v pools [, k/v
+    refs: page_tables + lengths + order + runs [B * tiles_per_seq] (scalar
+    prefetch; ``tile_runs``) | q, k/v pools [, k/v
     scale rows [B*tiles_per_seq, 1, Ws], one per tile, see
     gather_scale_rows] | out | k_pre, v_pre [2, W, TP, ps, Hkv, D] [, scale
     windows [2, W, 1, Ws]], k_tail, v_tail [2, TP, ps, Hkv, D] [, scale tails
     [2, 1, Ws]], sems_pre [2, W, 2|4], sems_tail [2, 2|4]; a folded pool's
     scratch is [.., TP, ps, Hkv*D]. The copies of one tile and pool share a
-    semaphore: each wait takes one page's bytes off it."""
-    page_tables_ref, lengths_ref, order_ref, q_ref, *refs = refs
+    semaphore: each wait takes one page's bytes off it, a run's one wait the
+    tile's."""
+    page_tables_ref, lengths_ref, order_ref, runs_ref, q_ref, *refs = refs
     if quantized:
         (k_hbm, v_hbm, ks_hbm, vs_hbm,
          out_ref, k_pre, v_pre, ks_pre, vs_pre, k_tail, v_tail, ks_tail,
@@ -492,9 +541,13 @@ def _kernel_lookahead(
     lead = (None,) * (len(heads) - 1)  # a scale row [1, S] against the scores
 
     def tile_dmas(op, seq_idx, t, npg, pools, scales, at, sems):
-        """Start or wait (``op``) every copy of tile t of ``seq_idx``: its
-        pages below ``npg`` and, for int8 pools, the tile's scale rows.
-        ``at(scratch)`` is the tile's slot in a scratch buffer."""
+        """Start or wait (``op``) every copy of tile t of ``seq_idx``: for
+        int8 pools the tile's scale rows, and its pages. A tile that is a RUN
+        (``runs_ref``: its TP table entries are first, first + 1, ...) is one
+        slab of the pool and moves as ONE copy a pool, whole, whatever the
+        sequence has written of it; any other tile moves a page a copy, the
+        pages below ``npg``. ``at(scratch)`` is the tile's slot in a scratch
+        buffer."""
 
         def page(p, _):
             for c, (hbm, scratch) in enumerate(pools):
@@ -505,7 +558,26 @@ def _kernel_lookahead(
                 getattr(copy, op)()
             return 0
 
-        jax.lax.fori_loop(0, jnp.minimum(TP, npg - t * TP), page, 0)
+        left = npg - t * TP  # the tile's pages that the sequence holds
+        by_page = jnp.minimum(TP, left)
+        if TP > 1:  # a tile of one page has no run to find
+            whole = runs_ref[seq_idx * tiles_per_seq + t] != 0
+            first = page_tables_ref[seq_idx, t * TP]
+            if op == "wait":
+                # a full tile's page copies have put a tile's bytes on the
+                # semaphore too: one wait takes them off as it does a run's
+                whole, first = whole | (left >= TP), 0
+
+            @pl.when(whole)
+            def _():
+                for c, (hbm, scratch) in enumerate(pools):
+                    copy = pltpu.make_async_copy(
+                        hbm.at[pl.ds(first, TP)], at(scratch), sems.at[c]
+                    )
+                    getattr(copy, op)()
+
+            by_page = jnp.where(whole, 0, by_page)
+        jax.lax.fori_loop(0, by_page, page, 0)
         for c, (hbm, scratch) in enumerate(scales):
             copy = pltpu.make_async_copy(
                 hbm.at[seq_idx * tiles_per_seq + t], at(scratch), sems.at[2 + c]
@@ -658,17 +730,41 @@ def lookahead_window(page_size: int, num_kv_heads: int, head_dim: int,
 SLIDING_DECODE_NAME = "paged_decode_attention_sliding_window"
 
 
-def _tiled_decode(q, k_pages, v_pages, page_tables, positions, live, TP: int, W: int, *,
+def tile_runs(page_tables: jnp.ndarray, tile_pages: int) -> jnp.ndarray:
+    """``[B, max_pages]`` -> ``[B * tiles_per_seq]`` int32: 1 where the
+    ``tile_pages`` table entries of a tile are first, first + 1, ... (one slab
+    of the pool, which the tiled walk fetches as one copy), else 0. Adding a
+    layer's offset to every entry changes nothing, so a model makes this once
+    a decode step for all its layers (`ops.attention.decode_tile_runs`). The
+    null page that pads a table never continues a run, and a tile of one page
+    has none to find."""
+    B, width = page_tables.shape
+    T = pl.cdiv(width, tile_pages)
+    if tile_pages == 1:
+        return jnp.zeros((B * T,), jnp.int32)
+    tables = jnp.pad(page_tables.astype(jnp.int32), ((0, 0), (0, T * tile_pages - width)))
+    tiles = tables.reshape(B, T, tile_pages)
+    run = jnp.all(tiles[..., 1:] - tiles[..., :-1] == 1, axis=-1)
+    return run.reshape(B * T).astype(jnp.int32)
+
+
+def _tiled_decode(q, k_pages, v_pages, page_tables, positions, live, runs, TP: int, W: int, *,
                   interpret: bool, window: int = 0, name=None):
     """``_kernel_lookahead`` over pools of either rank, at tiles of ``TP``
     pages and a window of ``W`` tiles. ``live`` (`ops.live_rows.LiveRows`, or
     None: every row is live) names the rows the grid serves: it has
     ``live.count`` programs (a grid bound read on the device), program i
     walks row ``live.order[i]``, and a row that is not live costs nothing and
-    reads zero."""
+    reads zero. ``runs`` (`tile_runs` of these tables at ``TP``, or None: made
+    here) says which tiles are one slab of the pool."""
     B, Hq, D = q.shape
     if live is None:
         live = every_row(B)
+    tiles_per_seq = pl.cdiv(page_tables.shape[1], TP)
+    if runs is None:
+        runs = tile_runs(page_tables, TP)
+    if runs.shape != (B * tiles_per_seq,):
+        raise ValueError(f"tile runs {runs.shape} are not of {B} rows of {tiles_per_seq} tiles of {TP}")
     kq, vq, ks, vs, quantized = _decode_unpack_pools(k_pages, v_pages, page_tables, TP)
     page = kq.shape[1:]  # [ps, Hkv, D], or folded [ps, Hkv*D]
     lengths = positions.astype(jnp.int32) + 1
@@ -681,12 +777,12 @@ def _tiled_decode(q, k_pages, v_pages, page_tables, positions, live, TP: int, W:
                        pltpu.VMEM((*lead, 1, vs.shape[-1]), jnp.float32)]
         return shapes
 
-    def row_block(i, tables, lengths, order):
+    def row_block(i, tables, lengths, order, runs):
         return order[i], 0, 0
 
     C = 4 if quantized else 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(live.count[0],),
         in_specs=[
             pl.BlockSpec((1, Hq, D), row_block),
@@ -703,7 +799,7 @@ def _tiled_decode(q, k_pages, v_pages, page_tables, positions, live, TP: int, W:
     kernel = pl.pallas_call(
         functools.partial(
             _kernel_lookahead, page_size=page[0], tile_pages=TP,
-            tiles_per_seq=pl.cdiv(page_tables.shape[1], TP), lookahead=W,
+            tiles_per_seq=tiles_per_seq, lookahead=W,
             quantized=quantized, window=window,
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
@@ -716,7 +812,7 @@ def _tiled_decode(q, k_pages, v_pages, page_tables, positions, live, TP: int, W:
         name=name,
     )
     args = (kq, vq, ks, vs) if quantized else (kq, vq)
-    out = kernel(page_tables.astype(jnp.int32), lengths, live.order, q, *args)
+    out = kernel(page_tables.astype(jnp.int32), lengths, live.order, runs, q, *args)
     # no program wrote a dead row's block: it is memory as it was found
     return zero_dead_rows(out, live)
 
@@ -729,6 +825,7 @@ def paged_decode_attention_pallas_lookahead(
     page_tables: jnp.ndarray,  # [B, max_pages] int32
     positions: jnp.ndarray,  # [B] int32 query positions
     live: LiveRows | None = None,  # the rows to serve (None: every row)
+    runs: jnp.ndarray | None = None,  # tile_runs of the tables (None: made here)
     interpret: bool = False,
     window: int = 0,  # sliding window in tokens (0: the whole context)
 ) -> jnp.ndarray:
@@ -744,7 +841,7 @@ def paged_decode_attention_pallas_lookahead(
             q, k_pages, v_pages, page_tables, positions, interpret=interpret
         ), live)
     return _tiled_decode(
-        q, k_pages, v_pages, page_tables, positions, live,
+        q, k_pages, v_pages, page_tables, positions, live, runs,
         decode_tile_pages(ps, Hkv, D, itemsize), W, interpret=interpret,
         window=window, name=SLIDING_DECODE_NAME if window else None,
     )
@@ -763,6 +860,7 @@ def paged_decode_attention_pallas_folded(
     page_tables: jnp.ndarray,  # [B, max_pages] int32
     positions: jnp.ndarray,  # [B] int32 query positions
     live: LiveRows | None = None,  # the rows to serve (None: every row)
+    runs: jnp.ndarray | None = None,  # tile_runs of the tables (None: made here)
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Decode attention for head_dim < 128 (TinyLlama, Qwen2-small, LFM2: 64)
@@ -786,7 +884,7 @@ def paged_decode_attention_pallas_folded(
     W = lookahead_window(*geometry)
     if W < 1:  # a page of 128 tokens by 4096 lanes: no kernel here walks such a pool
         raise ValueError(f"no tile of a folded pool {k_pages.shape} fits the decode kernel's VMEM")
-    return _tiled_decode(q, k_pages, v_pages, page_tables, positions, live,
+    return _tiled_decode(q, k_pages, v_pages, page_tables, positions, live, runs,
                          decode_tile_pages(*geometry), W,
                          interpret=interpret, name=FOLDED_DECODE_NAME)
 

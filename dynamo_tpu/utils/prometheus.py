@@ -56,6 +56,7 @@ DECLARED_METRIC_FAMILIES: tuple = (
     "dynamo_engine_kv_cache_page_bytes",
     "dynamo_engine_kv_group_pages",
     "dynamo_engine_kv_pages",
+    "dynamo_engine_kv_tiles",
     "dynamo_engine_kv_window_pages_released_total",
     "dynamo_engine_moe_assignments_total",
     "dynamo_engine_moe_busiest_over_mean",

@@ -271,3 +271,150 @@ def test_the_grid_serves_the_live_rows_alone(kind, mask):
     np.testing.assert_array_equal(got[alive], whole[alive])
     np.testing.assert_allclose(got[alive], want[alive], atol=2e-5 if dtype == "float32" else 2e-2)
     np.testing.assert_array_equal(got[~alive], 0.0)
+
+
+# ---------------------------------------------------------------- runs of a tile
+# (PR 47): a tile whose table entries are first, first + 1, ... is one slab of
+# the pool and moves as ONE copy a pool, whole, whatever the sequence has
+# written of it; any other tile moves a page a copy. Same contexts, three
+# layouts of their pages
+
+RUN_WINDOW = 200  # a sliding window that starts inside a tile
+#: name -> (kernel, keywords, Hq, Hkv, D, pool dtype, int8, folded)
+RUN_KERNELS = {
+    "heads": (paged_decode_attention_pallas_lookahead, {}, 4, 2, D, "bfloat16", False, False),
+    "heads_float32": (paged_decode_attention_pallas_lookahead, {}, 8, 4, D, "float32", False, False),
+    "sliding_window": (paged_decode_attention_pallas_lookahead, {"window": RUN_WINDOW},
+                       4, 2, D, "bfloat16", False, False),
+    "heads_int8": (paged_decode_attention_pallas_lookahead, {}, 8, 4, D, "bfloat16", True, False),
+    "folded": (paged_decode_attention_pallas_folded, {}, 16, 8, 64, "bfloat16", False, True),
+    "folded_int8": (paged_decode_attention_pallas_folded, {}, 16, 8, 64, "bfloat16", True, True),
+}
+RUN_LAYOUTS = ("every_tile_a_run", "no_tile_a_run", "mixed_in_a_sequence")
+RUN_LENGTHS = [TILE + 3, (W + 1) * TILE + PS + 1, 1, W * TILE, 3 * TILE - 5]
+RUN_P, RUN_MAX_PAGES = 30 * TP, 6 * TP
+
+
+def _laid_out(layout, seed=47):
+    """Page tables [B, RUN_MAX_PAGES] for RUN_LENGTHS. A run is an aligned slab
+    of the pool and shows whole in the table, the pages past the sequence's
+    own included (the allocator has reserved them: they hold NaN here). In
+    the mixed layout a sequence's first tile is a shared-prefix boundary (a
+    run's first half, then pages of its own from elsewhere), its last tile a
+    run fetched whole over unwritten pages, the others by turns."""
+    rng = np.random.default_rng(seed)
+    slabs = iter((1 + rng.permutation(RUN_P // TP - 1)) * TP)
+    tables = np.zeros((B, RUN_MAX_PAGES), np.int32)
+    for b, n in enumerate(RUN_LENGTHS):
+        pages = -(-n // PS)
+        tiles = -(-pages // TP)
+        for t in range(tiles):
+            held = min(TP, pages - t * TP)
+            first = int(next(slabs))
+            run = first + np.arange(TP)
+            scattered = first + rng.permutation(TP)
+            while held > 1 and np.all(np.diff(scattered[:held]) == 1):
+                scattered = first + rng.permutation(TP)
+            if layout == "every_tile_a_run":
+                kind = "run"
+            elif layout == "no_tile_a_run":
+                kind = "scattered"
+            elif t == tiles - 1:
+                kind = "run"
+            elif t == 0:
+                kind = "boundary"
+            else:
+                kind = "run" if t % 2 else "scattered"
+            if kind == "run":
+                tables[b, t * TP:(t + 1) * TP] = run
+            elif kind == "scattered":
+                tables[b, t * TP:t * TP + held] = scattered[:held]
+            else:  # half of a writer's run, then own pages that do not continue it
+                tables[b, t * TP:t * TP + TP // 2] = run[:TP // 2]
+                tables[b, t * TP + TP // 2:(t + 1) * TP] = int(next(slabs)) + np.arange(TP // 2)
+    return tables
+
+
+def _pools_of(tables, hkv, d, dtype, int8, folded, seed=48):
+    """(clean pools, planted pools) holding the SAME context rows wherever
+    `tables` puts them: the planted ones have a non-finite value in every row
+    no sequence has written (the reserved pages of a run among them)."""
+    rng = np.random.default_rng(seed)
+    max_tokens = RUN_MAX_PAGES * PS
+    out = []
+    for bad in (jnp.nan, jnp.inf):  # K, V
+        rows = rng.standard_normal((B, max_tokens, hkv, d), dtype=np.float32)
+        pool = np.zeros((RUN_P * PS, hkv, d), np.float32)
+        written = np.zeros(RUN_P * PS, bool)
+        for b, n in enumerate(RUN_LENGTHS):
+            tok = np.arange(n)
+            at = tables[b, tok // PS] * PS + tok % PS
+            pool[at], written[at] = rows[b, :n], True
+        x = jnp.asarray(pool, dtype)
+        shape = (RUN_P, PS, hkv * d) if folded else (RUN_P, PS, hkv, d)
+        mask = jnp.asarray(~written).reshape(RUN_P, PS)
+        if int8:
+            q, s = quantize_kv_rows(x)
+            q, s = q.reshape(shape), s.reshape(RUN_P, PS)
+            lanes = mask.reshape(RUN_P, PS, *[1] * (len(shape) - 2))
+            out.append((QuantizedPages(q, s),
+                        QuantizedPages(jnp.where(lanes, jnp.int8(127), q), jnp.where(mask, bad, s))))
+        else:
+            x = x.reshape(shape)
+            lanes = mask.reshape(RUN_P, PS, *[1] * (len(shape) - 2))
+            out.append((x, jnp.where(lanes, jnp.asarray(bad, x.dtype), x)))
+    (k, k_bad), (v, v_bad) = out
+    return (k, v), (k_bad, v_bad)
+
+
+def _run_case(kind, layout):
+    kernel, kw, hq, hkv, d, dtype, int8, folded = RUN_KERNELS[kind]
+    tables = _laid_out(layout)
+    clean, planted = _pools_of(tables, hkv, d, dtype, int8, folded)
+    q = jnp.asarray(np.random.default_rng(49).standard_normal((B, hq, d), dtype=np.float32), dtype)
+    positions = jnp.asarray([n - 1 for n in RUN_LENGTHS], jnp.int32)
+    got = kernel(q, *planted, jnp.asarray(tables), positions, interpret=True, **kw)
+    want = paged_decode_attention(q, *clean, jnp.asarray(tables), positions, kw.get("window", 0))
+    return tables, np.asarray(got, np.float32), np.asarray(want, np.float32), dtype
+
+
+def test_tile_runs_are_read_off_the_table():
+    from dynamo_tpu.ops.pallas.paged_attention import tile_runs
+
+    tiles = [-(-(-(-n // PS)) // TP) for n in RUN_LENGTHS]
+    for layout in RUN_LAYOUTS:
+        flags = np.asarray(tile_runs(jnp.asarray(_laid_out(layout)), TP)).reshape(B, -1)
+        for b, n in enumerate(tiles):
+            want = {"every_tile_a_run": [1] * n, "no_tile_a_run": [0] * n,
+                    # the boundary, by turns, the last one whole (a context of one tile: that)
+                    "mixed_in_a_sequence": ([0] + [t % 2 for t in range(1, n - 1)] + [1])[-n:]}[layout]
+            assert flags[b].tolist() == want + [0] * (flags.shape[1] - n), (layout, b)
+    # a layer's offset moves every entry alike; the null page never continues a run
+    table = jnp.asarray(_laid_out("mixed_in_a_sequence"))
+    np.testing.assert_array_equal(tile_runs(table + 7 * RUN_P, TP), tile_runs(table, TP))
+    assert not np.asarray(tile_runs(jnp.zeros((2, 2 * TP), jnp.int32), TP)).any()
+    assert not np.asarray(tile_runs(jnp.arange(8, 8 + 16)[None], 1)).any()  # a tile of one page
+    with pytest.raises(ValueError, match="tile runs"):
+        paged_decode_attention_pallas_lookahead(
+            jnp.zeros((B, 4, D)), jnp.zeros((RUN_P, PS, 2, D)), jnp.zeros((RUN_P, PS, 2, D)),
+            table, jnp.zeros((B,), jnp.int32), runs=jnp.zeros((B,), jnp.int32), interpret=True)
+
+
+@pytest.mark.parametrize("layout", RUN_LAYOUTS)
+@pytest.mark.parametrize("kind", RUN_KERNELS)
+def test_runs_of_a_tile_match_the_reference(kind, layout):
+    """Against the gather reference on clean pools, with NaN and inf in every
+    row no sequence has written: a run's last tile is fetched whole, and what
+    its reserved pages hold does not reach the output."""
+    _, got, want, dtype = _run_case(kind, layout)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, atol=2e-5 if dtype == "float32" else 2e-2)
+
+
+@pytest.mark.parametrize("kind", RUN_KERNELS)
+def test_a_row_reads_the_same_to_the_bit_however_its_pages_lie(kind):
+    """One copy a tile or a copy a page put the same rows into the same
+    scratch: the arithmetic of a live row does not move."""
+    outs = [_run_case(kind, layout)[1] for layout in RUN_LAYOUTS]
+    np.testing.assert_array_equal(outs[0], outs[1])
+    np.testing.assert_array_equal(outs[0], outs[2])

@@ -95,3 +95,29 @@ def test_oversized_rejection_does_not_consume_the_cap(monkeypatch):
     assert [o.request_id for o in outputs if o.finish_reason == "error"] == ["too-long"]
     assert started == ["ok-1"]
     assert not sched.waiting
+
+
+def test_reserved_pages_do_not_hold_a_request_back(monkeypatch):
+    """A sequence's unwritten run (PR 47: pages reserved for it to grow into)
+    counts as free at admission: a prompt that fits only with those pages is
+    started, and the allocator takes them back for it."""
+    cfg = EngineConfig(model_id="tiny", page_size=4, num_pages=4 * 8, max_seqs=4,
+                       max_model_len=256, prefill_batches_per_step=4)
+    alloc = PageAllocator(cfg.num_pages, cfg.page_size, tile_pages=8)
+    sched = Scheduler(cfg, _StubRunner(), alloc)
+    for i in range(3):  # each takes a whole tile for its one page: 3 held, 21 reserved
+        alloc.allocate_sequence(f"r{i}", [i + 1] * 4)
+    assert (alloc.active_pages, alloc.reserved_pages, alloc._free.free) == (3, 21, 7)
+    started = []
+
+    def fake_start(req, slot, lora_slot=0):
+        alloc.allocate_sequence(req.request_id, req.token_ids)
+        sched.slots[slot] = RunningSeq(req=req, slot=slot, prompt_len=len(req.token_ids),
+                                       cached_len=0, prefill_pos=None)
+        started.append(req.request_id)
+
+    monkeypatch.setattr(sched, "_start_sequence", fake_start)
+    sched.add_request(EngineRequest("big", list(range(100, 100 + 20 * 4))))  # 20 pages of 7 free
+    sched._admit()
+    assert started == ["big"] and not sched.waiting
+    assert alloc.active_pages == 23 and alloc.reserved_pages == 8
