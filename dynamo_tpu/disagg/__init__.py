@@ -7,7 +7,7 @@ def refuse_recurrent(engine, role: str) -> None:
     """Disaggregation ships a prompt's KV pages from a prefill worker to a
     decode worker. A model with recurrent layers keeps per-slot state beside
     the pages, and that state has no wire form yet: refuse at start-up."""
-    if getattr(getattr(engine, "model", None), "recurrent", False):
+    if engine.model is not None and engine.model.recurrent:  # None: not loaded yet
         raise ValueError(
             f"{role} is refused for {type(engine.model).__name__}: the model has "
             "recurrent layers, whose per-slot state would have to travel with "

@@ -156,7 +156,7 @@ class PrefillWorker:
         delivered = False
         send_tasks: list[asyncio.Task] = []
         loop = asyncio.get_running_loop()
-        cat_axis = getattr(self.engine.runner.model, "wire_n_axis", 2)
+        cat_axis = self.engine.runner.model.wire_n_axis
 
         async def _ship(seq: int, total: int, pf: int, pt: int, d2h_fut):
             from dynamo_tpu.quant.kv import wire_nbytes

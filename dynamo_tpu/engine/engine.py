@@ -187,7 +187,7 @@ class AsyncJaxEngine:
                 type(self.model).__name__,
             )
             self.config.migration = False
-        groups = getattr(self.model, "layer_groups", None)
+        groups = self.model.layer_groups
         if groups and (self.config.migration or self.config.prefix_fetch):
             # refused, not an error: both are on by default, and both move a
             # block between engines as ONE page id for every layer
@@ -209,15 +209,11 @@ class AsyncJaxEngine:
             # (int8 host blocks are ~half the bf16 bytes -> ~2x blocks for
             # the same DRAM budget); the drain watermarks then operate on a
             # truthful block capacity
-            page_bytes = (
-                self.model.kv_page_bytes(self.config.page_size)
-                if hasattr(self.model, "kv_page_bytes")
-                else 0
-            )
+            page_bytes = self.model.kv_page_bytes(self.config.page_size)
             blocks = resolve_host_capacity_blocks(
                 self.config.host_cache_blocks,
-                # a model without page-cost accounting can't honor a byte
-                # budget — fall back to the explicit block knob only
+                # a model that prices its page at 0 (deepseek) can't honor a
+                # byte budget — fall back to the explicit block knob only
                 self.config.host_cache_bytes if page_bytes else 0,
                 page_bytes,
             )
@@ -229,23 +225,19 @@ class AsyncJaxEngine:
             # FETCHING_KV deferred-admission path (engine/kv_store.py)
             from dynamo_tpu.engine.kv_store import DiskKvStore, disk_block_bytes
 
-            mcfg = getattr(self.model, "config", None)
+            mcfg = self.model.config
             block_bytes = (
                 disk_block_bytes(
                     self.config.page_size, mcfg.num_kv_heads, mcfg.head_dim,
                     mcfg.num_layers,
                 )
-                if mcfg is not None
-                and all(
-                    hasattr(mcfg, a)
-                    for a in ("num_kv_heads", "head_dim", "num_layers")
-                )
+                if all(hasattr(mcfg, a) for a in ("num_kv_heads", "head_dim", "num_layers"))
                 else 0
             )
             offload.disk = DiskKvStore(
                 directory=self.config.disk_cache_dir or None,
                 budget_bytes=self.config.disk_cache_bytes,
-                page_axis=getattr(self.model, "wire_n_axis", 2),
+                page_axis=self.model.wire_n_axis,
                 block_bytes=block_bytes,
             )
         self.offload = offload
@@ -258,7 +250,7 @@ class AsyncJaxEngine:
             # a sequence grows by runs of the tile the decode kernel walks
             # its pages by, derived from the pools' shapes as the kernel does
             k_pool = self.runner.kv_cache.get("k")
-            head_dim = getattr(getattr(self.model, "config", None), "head_dim", 0)
+            head_dim = getattr(self.model.config, "head_dim", 0)
             known = k_pool is not None and head_dim  # else: single pages, as ever
             self.allocator = PageAllocator(
                 self.config.num_pages,
@@ -280,11 +272,7 @@ class AsyncJaxEngine:
             self.scheduler.meter = self.meter
             self.scheduler.anatomy.meter = self.meter
             self.allocator.meter = self.meter
-            self.allocator.meter_page_bytes = (
-                self.model.kv_page_bytes(self.config.page_size)
-                if hasattr(self.model, "kv_page_bytes")
-                else 0
-            )
+            self.allocator.meter_page_bytes = self.model.kv_page_bytes(self.config.page_size)
             if offload is not None:
                 offload.meter = self.meter
                 if offload.disk is not None:
@@ -447,7 +435,7 @@ class AsyncJaxEngine:
         """Why this engine's pages cannot be moved to or from another engine
         (disaggregated prefill, prefix pulls, migration), or None. The disagg
         workers ask at their start-up; the entry points below ask again."""
-        if getattr(self.model, "layer_groups", None):
+        if self.model.layer_groups:
             return (f"{type(self.model).__name__}: its attention layers come in groups "
                     "with a page table each, and the transfer paths carry one table "
                     "(disaggregated prefill, prefix pulls and migration are refused)")
@@ -502,7 +490,7 @@ class AsyncJaxEngine:
             if pages
             else None
         )
-        axis = getattr(runner.model, "wire_n_axis", 2)
+        axis = runner.model.wire_n_axis
         return len(pages), fut, host_blocks, axis
 
     # ---------------- live migration (disagg/migrate.py) ----------------
@@ -544,7 +532,7 @@ class AsyncJaxEngine:
         if not pages:
             return None
         fut = runner.extract_pages_async(np.asarray(pages, np.int32))
-        axis = getattr(runner.model, "wire_n_axis", 2)
+        axis = runner.model.wire_n_axis
         return len(pages), fut, [], axis
 
     def sync_snapshot_for_migration(self, request_id: str):
@@ -892,7 +880,7 @@ class AsyncJaxEngine:
         rid = f"rp-{rp.request_id}"
         prompt_len = len(rp.token_ids)
         cached_len, state = self.allocator.allocate_sequence(rid, list(rp.token_ids))
-        # fleet prefix pull BEFORE recomputing (ROADMAP item 3 follow-up):
+        # fleet prefix pull BEFORE recomputing:
         # when the router attached a holder whose cached prefix beats ours,
         # pull the missing leading blocks over the dataplane — the same
         # timeout -> recompute fallback the decode-side FETCHING_KV path
@@ -1157,10 +1145,9 @@ class AsyncJaxEngine:
             return {}
         # actual-dtype KV byte accounting: the page-size arithmetic everyone
         # downstream (dynotop, capacity planning) used to do assuming bf16
-        page_bytes = 0
-        if runner is not None and hasattr(runner.model, "kv_page_bytes"):
-            page_bytes = runner.model.kv_page_bytes(self.config.page_size)
-        recurrent = getattr(runner, "recurrent", False)
+        model = runner.model if runner is not None else None  # None: a metrics-surface test
+        page_bytes = model.kv_page_bytes(self.config.page_size) if model is not None else 0
+        recurrent = runner is not None and runner.recurrent
         snap = {
             "kv_cache_dtype": self.config.kv_cache_dtype or "bf16",
             "kv_page_bytes": page_bytes,
@@ -1194,7 +1181,7 @@ class AsyncJaxEngine:
             # a model with no recurrent layers)
             "state_slots_total": self.config.max_seqs if recurrent else 0,
             "state_slots_active": sched.state_slots_active,
-            "hbm_state_bytes": runner.model.state_bytes(self.config.max_seqs) if recurrent else 0,
+            "hbm_state_bytes": runner.state_bytes if recurrent else 0,
             "moe_assignments": sched.moe_assignments,
             "moe_routed": sched.moe_routed,
             "moe_experts_touched": sched.moe_experts_touched,

@@ -120,7 +120,7 @@ class ModelRunner:
                     f"num_kv_heads={hkv} for the composed sp x tp mesh"
                 )
         if getattr(model.config, "kv_quantized", False):
-            if not getattr(model, "SUPPORTS_KV_INT8", False):
+            if not model.SUPPORTS_KV_INT8:
                 raise ValueError(
                     f"model {type(model).__name__} does not support the int8 KV cache"
                 )
@@ -168,7 +168,7 @@ class ModelRunner:
                         "inside the pipeline shard_map (no _layer tp_axis)"
                     )
         if config.sp > 1:
-            if not hasattr(model, "prefill_sp"):
+            if model.prefill_sp is None:
                 raise ValueError(
                     f"model {type(model).__name__} has no sequence-parallel prefill"
                 )
@@ -185,7 +185,7 @@ class ModelRunner:
         #: a model with recurrent layers (models/nemotron_h.py) keeps a
         #: fixed-size state per DECODE SLOT beside the paged KV: its size
         #: follows max_seqs, and it exists because the model has such layers
-        self.recurrent = bool(getattr(model, "recurrent", False))
+        self.recurrent = bool(model.recurrent)
         if self.recurrent:
             why = recurrent_refusal(config)
             if why:
@@ -193,7 +193,7 @@ class ModelRunner:
         #: page tables a sequence has: one, or one per attention layer of a
         #: model with layer groups (its tables ride side by side, table-major,
         #: wherever this file carries one: `_flat_table`)
-        self.kv_tables = int(getattr(model, "kv_tables", 1))
+        self.kv_tables = int(model.kv_tables)
         if self.kv_tables > 1:
             why = layer_group_refusal(config)
             if why:
@@ -237,7 +237,7 @@ class ModelRunner:
                 devices = jax.devices()[: config.tp]
                 mesh = Mesh(np.array(devices).reshape(len(devices)), ("tp",))
         self.mesh = mesh
-        if mesh.size > 1 and hasattr(model, "expert_mesh"):
+        if mesh.size > 1:
             # expert banks may be sharded over it: ops/moe.grouped_matmul
             model.expert_mesh = mesh
         if config.tp > 1 and config.pp == 1:
@@ -269,12 +269,13 @@ class ModelRunner:
             kv_sharding = model.kv_cache_sharding(mesh)
         self.params = jax.device_put(params, shardings)
         cache = model.init_kv_cache(config.num_pages, config.page_size)
-        if hasattr(model, "init_state_cache"):
-            # what a model keeps beside the page pools (a recurrent state per
-            # slot, the expert counters) rides the same donated bundle, so
-            # every step function carries it unchanged
-            cache.update(model.init_state_cache(config.max_seqs))
-            kv_sharding = dict(kv_sharding, **model.state_cache_sharding(mesh))
+        # what a model keeps beside the page pools (a recurrent state per
+        # slot, the expert counters; most keep nothing) rides the same donated
+        # bundle, so every step function carries it unchanged
+        cache.update(model.init_state_cache(config.max_seqs))
+        #: device bytes of that state, the counters aside (the engine's gauge)
+        self.state_bytes = model.state_bytes(config.max_seqs)
+        kv_sharding = dict(kv_sharding, **model.state_cache_sharding(mesh))
         self.kv_cache = jax.device_put(cache, kv_sharding)
         #: the last decode window's extra device output (the leaves a model
         #: names in `window_counters`: a routing model's `moe_counts` and
@@ -302,7 +303,7 @@ class ModelRunner:
         if config.lora_adapters:
             from dynamo_tpu.lora import LoraStore, init_lora_pool
 
-            if not getattr(model, "SUPPORTS_LORA", False):
+            if not model.SUPPORTS_LORA:
                 raise ValueError(
                     f"model {type(model).__name__} does not support LoRA adapters"
                 )
@@ -802,7 +803,7 @@ class ModelRunner:
         # what a model that routes counts over the window, by its own
         # declaration (`window_counters`: leaves of its state cache): zeroed
         # here, the steps below add
-        counters = getattr(self.model, "window_counters", ())
+        counters = self.model.window_counters
         kv = dict(kv, **{k: jnp.zeros_like(kv[k]) for k in counters})
 
         def body(carry, k):
@@ -1351,7 +1352,7 @@ class ModelRunner:
             self.config.prefill_lanes > 1
             and self.config.pp == 1
             and self.config.sp == 1
-            and hasattr(self.model, "prefill_packed")
+            and self.model.prefill_packed is not None
         )
 
     def _table_shape(self, width: int) -> tuple:
@@ -1675,7 +1676,7 @@ class ModelRunner:
         from dynamo_tpu.quant.kv import wire_pad
 
         if axis is None:
-            axis = getattr(self.model, "wire_n_axis", 2)
+            axis = self.model.wire_n_axis
         ids = np.asarray(page_ids, np.int32)
         n = len(ids)
         if n == 0:

@@ -147,7 +147,7 @@ class HostKvPool:
             return []
         from dynamo_tpu.quant.kv import wire_split
 
-        axis = getattr(getattr(self.runner, "model", None), "wire_n_axis", 2)
+        axis = self.runner.model.wire_n_axis
         t0 = time.monotonic()
         data = self.runner.extract_pages(
             np.asarray([p for _, p in pairs], np.int32)
@@ -210,7 +210,7 @@ class HostKvPool:
             return set()
         from dynamo_tpu.quant.kv import wire_concat
 
-        axis = getattr(self.runner.model, "wire_n_axis", 2)
+        axis = self.runner.model.wire_n_axis
         # the batch is padded to a power of two inside inject_pages_bucketed
         # (shared with the streamed-disagg part scatter) so the donated
         # scatter compiles a handful of shapes, not one per prefix length

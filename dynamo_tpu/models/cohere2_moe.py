@@ -33,16 +33,10 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dynamo_tpu.models.llama import parse_dtype
-from dynamo_tpu.ops.attention import (
-    decode_tile_runs,
-    dispatch_paged_decode_attention,
-    dispatch_paged_prefill_attention,
-    scatter_kv,
-)
-from dynamo_tpu.ops.live_rows import live_rows
+from dynamo_tpu.models.paged import DecodeStep, ExpertCounts, Pack, PackedPrefillModel, route
+from dynamo_tpu.ops.attention import scatter_kv
 from dynamo_tpu.ops.moe import grouped_matmul, moe_dispatch, sigmoid_topk_routing
 from dynamo_tpu.ops.norms import layer_norm
 from dynamo_tpu.ops.rotary import apply_rope_pairs
@@ -145,17 +139,12 @@ class Cohere2MoeConfig:
         return replace(base, **overrides)
 
 
-class Cohere2MoeModel:
-    """Stateless forward functions over a params pytree (models/llama.py's
-    contract; the page tables come one per attention layer, see the module
-    docstring)."""
-
-    SUPPORTS_LORA = False
-    SUPPORTS_KV_INT8 = False
-
-    def __init__(self, config: Cohere2MoeConfig):
-        self.config = config
-        self.attn_mesh = None  # one chip: see model_runner.layer_group_refusal
+class Cohere2MoeModel(PackedPrefillModel):
+    """Stateless forward functions over a params pytree (models/paged.py's
+    contract; `prefill` and `prefill_packed` are `PackedPrefillModel`'s over
+    `_packed_forward`; the page tables come one per attention layer, see the
+    module docstring). One chip (model_runner.layer_group_refusal), so
+    `attn_mesh` stays None."""
 
     # ---------------- layer groups ----------------
 
@@ -211,48 +200,19 @@ class Cohere2MoeModel:
             "final_norm": jnp.ones((D,), c.dtype),
         }
 
-    def param_shardings(self, mesh: Mesh, tp_axis: str = "tp") -> dict:
-        shapes = jax.eval_shape(self.init_params, jax.random.key(0))
-        return jax.tree.map(lambda _: NamedSharding(mesh, P()), shapes)
-
     # ---------------- the paged KV pool: single-layer pages ----------------
 
-    kv_folded = False
-
     def kv_cache_shape(self, num_pages: int, page_size: int) -> tuple[int, ...]:
+        """A page holds K or V of `page_size` tokens in ONE layer."""
         c = self.config
         return (num_pages, page_size, c.num_kv_heads, c.head_dim)
 
-    def init_kv_cache(self, num_pages: int, page_size: int) -> dict:
-        shape = self.kv_cache_shape(num_pages, page_size)
-        return {"k": jnp.zeros(shape, self.config.dtype), "v": jnp.zeros(shape, self.config.dtype)}
-
-    def kv_page_bytes(self, page_size: int) -> int:
-        """One page of the pool: K and V of `page_size` tokens in ONE layer."""
-        c = self.config
-        return 2 * page_size * c.num_kv_heads * c.head_dim * jnp.dtype(c.dtype).itemsize
-
-    def kv_cache_sharding(self, mesh: Mesh, tp_axis: str = "tp") -> dict:
-        ns = NamedSharding(mesh, P())
-        return {"k": ns, "v": ns}
-
     # ---------------- what rides beside the pool ----------------
 
-    #: the state-cache leaves a decode window zeroes, adds to and hands back
-    window_counters = ("moe_counts", "moe_touched")
+    window_counters = ExpertCounts.NAMES
 
     def init_state_cache(self, max_seqs: int) -> dict:
-        """The `window_counters`: `moe_counts`, where decode steps add the
-        assignments each held expert received, and `moe_touched`, where they
-        add the number of (layer, held expert) pairs that received a row (the
-        engine zeroes both at the start of a decode window and reads them at
-        the end)."""
-        return {"moe_counts": jnp.zeros((self.config.num_experts,), jnp.int32),
-                "moe_touched": jnp.zeros((1,), jnp.int32)}
-
-    def state_cache_sharding(self, mesh: Mesh) -> dict:
-        ns = NamedSharding(mesh, P())
-        return {"moe_counts": ns, "moe_touched": ns}
+        return ExpertCounts.leaves(self.config.num_experts)
 
     # ---------------- blocks ----------------
 
@@ -273,7 +233,7 @@ class Cohere2MoeModel:
                 k = apply_rope_pairs(k, positions, c.rope_theta)
             k_pool, v_pool = scatter_kv(kv["k"], kv["v"], k, v, phys, offsets)
             with jax.named_scope("attn"):
-                attn = attn_fn(q, k_pool, v_pool, c.sliding_window if kind == SLIDING else 0)
+                attn = attn_fn(q, k_pool, v_pool)
             with jax.named_scope("attn_proj"):
                 return attn.reshape(T, -1) @ lp["wo"], dict(kv, k=k_pool, v=v_pool)
 
@@ -281,17 +241,12 @@ class Cohere2MoeModel:
         """n [T, D] -> (routed + shared [T, D], the held experts' assignment
         counts over the rows of `count_rows` (all rows when None))."""
         c = self.config
-        with jax.named_scope("moe_router"):
-            # the router: float32 on the normed hidden state, at full precision
-            # (a bf16 pass would move the choice of expert, not just a weight)
-            logits = jnp.dot(
-                n.astype(jnp.float32), lp["router"], precision=jax.lax.Precision.HIGHEST
-            )
-            weights, idx = sigmoid_topk_routing(
-                logits, jnp.zeros((c.moe_routed_over,), jnp.float32), c.num_experts_per_tok
-            )
-            if count_rows is not None:
-                idx = jnp.where(count_rows[:, None], idx, -1)  # held nowhere
+        weights, idx = route(
+            n, lp["router"],
+            lambda logits: sigmoid_topk_routing(
+                logits, jnp.zeros((c.moe_routed_over,), jnp.float32), c.num_experts_per_tok),
+            count_rows,
+        )
 
         def ffn(rows, group_sizes):  # `moe_dispatch` calls it under `moe_experts`
             gated = jax.nn.silu(grouped_matmul(rows, lp["w_gate"], group_sizes))
@@ -316,110 +271,61 @@ class Cohere2MoeModel:
             )
             return logits * c.logit_scale if c.logit_scale != 1.0 else logits
 
-    def _tables(self, page_tables: jnp.ndarray) -> jnp.ndarray:
-        """[rows, kv_tables * width] table-major -> [kv_tables, rows, width]."""
-        rows = page_tables.shape[0]
-        return page_tables.reshape(rows, self.kv_tables, -1).transpose(1, 0, 2)
+    def _window(self, kind: str) -> int:
+        return self.config.sliding_window if kind == SLIDING else 0
 
     # ---------------- forward ----------------
 
-    def prefill_packed(self, params, kv_cache, tokens, positions, page_tables, valid, last_idx):
+    def _packed_forward(self, params, kv_cache, tokens, positions, page_tables, valid):
         """N lanes through every layer: each T consecutive rows of one
         sequence under that sequence's page tables, several of them one
         sequence's where its chunk rides as blocks (every layer scatters all
         lanes' rows before any lane's attention reads the pages).
-        Returns (logits [N, V], cache)."""
+        Returns (hidden [N*T, D], cache)."""
         c = self.config
         N, T = tokens.shape
-        page_size = kv_cache["k"].shape[1]
-        tables = self._tables(page_tables)  # [L, N, W]
-        lane = jnp.arange(N)
-        with jax.named_scope("attn_kv"):  # where each row's K and V go
-            offsets = jnp.where(valid, positions % page_size, 0).reshape(N * T)
-        flat_pos = positions.reshape(N * T)
+        pack = Pack(page_tables, positions, valid, kv_cache["k"].shape[1], self.attn_mesh,
+                    kv_tables=self.kv_tables)
+        flat_pos = pack.flat_positions
 
         with jax.named_scope("embed"):
             hidden = params["embed"][tokens.reshape(N * T)].astype(c.dtype)
         cache = kv_cache
         for l, (kind, lp) in enumerate(zip(c.layer_types, params["layers"])):
-            table = tables[l]
-            with jax.named_scope("attn_kv"):
-                phys = jnp.where(valid, table[lane[:, None], positions // page_size], 0)
-
-            def attn_fn(q, k_pool, v_pool, window, table=table):
-                # one lane at a time through ONE instance of the kernel (a
-                # loop, not N copies: at 128 query heads Mosaic takes seconds
-                # to compile each copy, in every packed program a server warms)
-                def lane(args):
-                    q_j, table_j, positions_j = args
-                    return dispatch_paged_prefill_attention(
-                        q_j, k_pool, v_pool, table_j, positions_j,
-                        mesh=self.attn_mesh, window=window,
-                    )
-
-                out = jax.lax.map(lane, (q.reshape(N, T, *q.shape[1:]), table, positions))
-                return out.reshape(N * T, *q.shape[1:])
-
+            at = pack.layer(l)
             n = layer_norm(hidden, lp["norm"], c.layer_norm_eps)
+            # one lane at a time through ONE instance of the kernel (a loop,
+            # not N copies: at 128 query heads Mosaic takes seconds to compile
+            # each copy, in every packed program a server warms)
             attn, cache = self._attention(
-                lp, kind, n, cache, flat_pos, phys.reshape(N * T), offsets, attn_fn
+                lp, kind, n, cache, flat_pos, at.phys.reshape(N * T), at.offsets,
+                at.attend_mapped(self._window(kind)),
             )
             ffn, _ = self._experts(lp, n)
             with jax.named_scope("attn_proj"):  # the residual add of a parallel block
                 hidden = hidden + attn + ffn
-        rows = hidden[jnp.arange(N) * T + last_idx]
-        return self._unembed(params, rows), cache
-
-    def prefill(self, params, kv_cache, tokens, positions, page_table, valid, last_idx,
-                input_embeds=None, embeds_mask=None, rope_positions=None):
-        """One chunk of one sequence: a pack of one lane."""
-        if input_embeds is not None or rope_positions is not None:
-            raise ValueError("cohere2_moe is served text-only")
-        logits, kv_cache = self.prefill_packed(
-            params, kv_cache, tokens[None], positions[None], page_table[None],
-            valid[None], jnp.reshape(last_idx, (1,)),
-        )
-        return logits[0], kv_cache
+        return hidden, cache
 
     def decode(self, params, kv_cache, tokens, positions, page_tables, active,
                rope_deltas=None):
         """One decode step for the whole batch. Returns (logits [B, V], cache)."""
         c = self.config
         cache = kv_cache
-        page_size = cache["k"].shape[1]
-        B = tokens.shape[0]
-        tables = self._tables(page_tables)  # [L, B, W]
-        with jax.named_scope("attn_kv"):
-            offsets = jnp.where(active, positions % page_size, 0)
-        live = live_rows(active)  # once a step, for every layer's kernel
-        # likewise, a row per layer's table (the grouped allocator gives no runs: all zero)
-        runs = decode_tile_runs(tables.reshape(-1, tables.shape[-1]), cache["k"], c.head_dim,
-                                self.attn_mesh)
-        runs = None if runs is None else runs.reshape(tables.shape[0], -1)
+        step = DecodeStep(page_tables, positions, active, cache["k"], c.head_dim, self.attn_mesh,
+                          kv_tables=self.kv_tables)
 
         with jax.named_scope("embed"):
             hidden = params["embed"][tokens].astype(c.dtype)
-        # absent where no engine keeps them
-        counts, touched = cache.get("moe_counts"), cache.get("moe_touched")
+        routed = ExpertCounts(cache)
         for l, (kind, lp) in enumerate(zip(c.layer_types, params["layers"])):
-            table = tables[l]
-            with jax.named_scope("attn_kv"):
-                phys = jnp.where(active, table[jnp.arange(B), positions // page_size], 0)
-
-            def attn_fn(q, k_pool, v_pool, window, table=table, l=l):
-                return dispatch_paged_decode_attention(
-                    q, k_pool, v_pool, table, positions, mesh=self.attn_mesh, window=window,
-                    live=live, runs=None if runs is None else runs[l],
-                )
-
+            at = step.layer(l)
             n = layer_norm(hidden, lp["norm"], c.layer_norm_eps)
-            attn, cache = self._attention(lp, kind, n, cache, positions, phys, offsets, attn_fn)
+            attn, cache = self._attention(
+                lp, kind, n, cache, positions, at.phys, at.offsets,
+                at.attend(window=self._window(kind)),
+            )
             ffn, got = self._experts(lp, n, count_rows=active)
-            if counts is not None:
-                counts = counts + got
-                touched = touched + jnp.sum(got > 0, dtype=jnp.int32)
+            routed.add(got)
             with jax.named_scope("attn_proj"):  # the residual add of a parallel block
                 hidden = hidden + attn + ffn
-        if counts is not None:
-            cache = dict(cache, moe_counts=counts, moe_touched=touched)
-        return self._unembed(params, hidden), cache
+        return self._unembed(params, hidden), routed.into(cache)

@@ -44,18 +44,12 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dynamo_tpu.models.llama import parse_dtype
-from dynamo_tpu.ops.attention import (
-    decode_tile_runs,
-    dispatch_paged_decode_attention,
-    dispatch_paged_prefill_attention,
-    scatter_kv,
-)
+from dynamo_tpu.models.paged import DecodeStep, Pack, PackedPrefillModel, state_rows
+from dynamo_tpu.ops.attention import scatter_kv
 from dynamo_tpu.ops.norms import rms_norm
 from dynamo_tpu.ops.rotary import apply_rope
-from dynamo_tpu.ops.live_rows import live_rows
 from dynamo_tpu.ops.ssm import causal_conv, ssd_chunked, ssm_state_update
 
 #: the published config's keys that hold a forward multiplier (two of them a
@@ -210,19 +204,16 @@ def _with_rows(table: jnp.ndarray, rows: jnp.ndarray, values: jnp.ndarray) -> jn
     return table
 
 
-class FalconH1Model:
-    """Stateless forward functions over a params pytree (models/llama.py's
-    contract, plus the per-slot state: `state_slot(s)` on the prefills)."""
+class FalconH1Model(PackedPrefillModel):
+    """Stateless forward functions over a params pytree (models/paged.py's
+    contract; `prefill` and `prefill_packed` are `PackedPrefillModel`'s over
+    `_packed_forward`, with the per-slot state: `state_slot(s)`). One chip
+    (model_runner.recurrent_refusal), so `attn_mesh` stays None."""
 
-    #: the engine keeps a per-slot state cache beside the paged KV, matches no
-    #: prefix for this model, and refuses what would need the state copied
     recurrent = True
-    SUPPORTS_LORA = False
-    SUPPORTS_KV_INT8 = False
 
     def __init__(self, config: FalconH1Config):
-        self.config = config
-        self.attn_mesh = None  # one chip: see model_runner.recurrent_refusal
+        super().__init__(config)
         c = config
         gn = c.mamba_n_groups * c.mamba_d_state
         #: `ssm_multipliers`' x, B and C over the columns of x | B | C
@@ -276,30 +267,11 @@ class FalconH1Model:
             "lm_head": dense((c.vocab_size, D), 1),
         }
 
-    def param_shardings(self, mesh: Mesh, tp_axis: str = "tp") -> dict:
-        shapes = jax.eval_shape(self.init_params, jax.random.key(0))
-        return jax.tree.map(lambda _: NamedSharding(mesh, P()), shapes)
-
     # ---------------- the paged KV pool (every layer) ----------------
-
-    kv_folded = False
 
     def kv_cache_shape(self, num_pages: int, page_size: int) -> tuple[int, ...]:
         c = self.config
         return (c.num_layers * num_pages, page_size, c.num_kv_heads, c.head_dim)
-
-    def init_kv_cache(self, num_pages: int, page_size: int) -> dict:
-        shape = self.kv_cache_shape(num_pages, page_size)
-        return {"k": jnp.zeros(shape, self.config.dtype), "v": jnp.zeros(shape, self.config.dtype)}
-
-    def kv_page_bytes(self, page_size: int) -> int:
-        c = self.config
-        return (2 * c.num_layers * page_size * c.num_kv_heads * c.head_dim
-                * jnp.dtype(c.dtype).itemsize)
-
-    def kv_cache_sharding(self, mesh: Mesh, tp_axis: str = "tp") -> dict:
-        ns = NamedSharding(mesh, P())
-        return {"k": ns, "v": ns}
 
     # ---------------- the per-slot state cache (every layer) ----------------
 
@@ -313,17 +285,6 @@ class FalconH1Model:
             "ssm": jnp.zeros((rows, c.mamba_n_heads, c.mamba_d_head, c.mamba_d_state), jnp.float32),
             "conv": jnp.zeros((rows, c.mamba_d_conv - 1, c.conv_dim), c.dtype),
         }
-
-    def state_cache_sharding(self, mesh: Mesh) -> dict:
-        ns = NamedSharding(mesh, P())
-        return {"ssm": ns, "conv": ns}
-
-    def state_bytes(self, max_seqs: int) -> int:
-        """Device bytes of the state cache at this many slots."""
-        c = self.config
-        per_row = (c.mamba_n_heads * c.mamba_d_head * c.mamba_d_state * 4
-                   + (c.mamba_d_conv - 1) * c.conv_dim * jnp.dtype(c.dtype).itemsize)
-        return c.num_layers * (max_seqs + 1) * per_row
 
     # ---------------- the two mixers ----------------
     # parts by scope (benchmark/trace_parts.py PARTS): a multiplier counts with
@@ -457,41 +418,23 @@ class FalconH1Model:
         Returns (hidden [N*T, D], cache)."""
         c = self.config
         N, T = tokens.shape
-        page_size = cache["k"].shape[1]
         num_pages = cache["k"].shape[0] // c.num_layers
         slot_rows = cache["ssm"].shape[0] // c.num_layers
-        lane = jnp.arange(N)
-        with jax.named_scope("attn_kv"):  # where each row's K and V go
-            phys = jnp.where(valid, page_tables[lane[:, None], positions // page_size], 0)
-            phys = phys.reshape(N * T)
-            offsets = jnp.where(valid, positions % page_size, 0).reshape(N * T)
-        with jax.named_scope("ssm"):  # which state row each lane continues
-            fresh = positions[:, 0] == 0
-            # a slot the engine does not name (padding lanes, warm-up) is the trash row
-            slots = jnp.where((state_slots >= 0) & (state_slots < slot_rows - 1),
-                              state_slots, slot_rows - 1)
-        flat_pos = positions.reshape(N * T)
+        pack = Pack(page_tables, positions, valid, cache["k"].shape[1], self.attn_mesh,
+                    flat=("phys", "offsets"))
+        fresh, slots = state_rows(state_slots, slot_rows, positions)
+        flat_pos = pack.flat_positions
 
         def body(carry, xs):
             hidden, k_pool, v_pool, ssm, conv = carry
             lp, l = xs
             off = l * num_pages
-
-            def attn_fn(q, kp, vp):
-                qs = q.reshape(N, T, *q.shape[1:])
-                return jnp.concatenate([
-                    dispatch_paged_prefill_attention(
-                        qs[j], kp, vp, off + page_tables[j], positions[j], mesh=self.attn_mesh
-                    )
-                    for j in range(N)
-                ], axis=0)
-
             u = rms_norm(hidden, lp["input_norm"], c.rms_norm_eps)
             m_out, ssm, conv = self._mamba_prefill(
                 lp, u.reshape(N, T, -1), ssm, conv, l * slot_rows + slots, fresh, valid
             )
             a_out, k_pool, v_pool = self._attention(
-                lp, u, k_pool, v_pool, flat_pos, off + phys, offsets, attn_fn
+                lp, u, k_pool, v_pool, flat_pos, off + pack.phys, pack.offsets, pack.attend(off)
             )
             hidden = self._mlp(lp, self._mix(hidden, m_out.reshape(N * T, -1), a_out))
             return (hidden, k_pool, v_pool, ssm, conv), None
@@ -503,63 +446,25 @@ class FalconH1Model:
         )
         return hidden, dict(cache, k=k_pool, v=v_pool, ssm=ssm, conv=conv)
 
-    def prefill_packed(self, params, kv_cache, tokens, positions, page_tables, valid,
-                       last_idx, state_slots=None):
-        """models/llama.py's `prefill_packed`, plus `state_slots` [N]: the
-        decode slot whose state each lane continues (or, from position 0,
-        starts). Returns (logits [N, V], cache)."""
-        N, T = tokens.shape
-        if state_slots is None:
-            state_slots = jnp.full((N,), -1, jnp.int32)
-        hidden, kv_cache = self._packed_forward(
-            params, kv_cache, tokens, positions, page_tables, valid, state_slots
-        )
-        rows = hidden[jnp.arange(N) * T + last_idx]
-        return self._unembed(params, rows), kv_cache
-
-    def prefill(self, params, kv_cache, tokens, positions, page_table, valid, last_idx,
-                input_embeds=None, embeds_mask=None, rope_positions=None, state_slot=None):
-        """One chunk of one sequence: a pack of one lane."""
-        if input_embeds is not None or rope_positions is not None:
-            raise ValueError("falcon_h1 is text-only")
-        slots = None if state_slot is None else jnp.reshape(state_slot, (1,))
-        logits, kv_cache = self.prefill_packed(
-            params, kv_cache, tokens[None], positions[None], page_table[None],
-            valid[None], jnp.reshape(last_idx, (1,)), state_slots=slots,
-        )
-        return logits[0], kv_cache
-
     def decode(self, params, kv_cache, tokens, positions, page_tables, active,
                rope_deltas=None):
         """One decode step for the whole batch; batch row b is decode slot b.
         Returns (logits [B, V], cache)."""
         c = self.config
         cache = kv_cache
-        page_size = cache["k"].shape[1]
         num_pages = cache["k"].shape[0] // c.num_layers
         slot_rows = cache["ssm"].shape[0] // c.num_layers
-        B = tokens.shape[0]
-        with jax.named_scope("attn_kv"):
-            phys = jnp.where(active, page_tables[jnp.arange(B), positions // page_size], 0)
-            offsets = jnp.where(active, positions % page_size, 0)
-        live = live_rows(active)  # once a step, for every layer's two kernels
-        runs = decode_tile_runs(page_tables, cache["k"], c.head_dim, self.attn_mesh)  # likewise
+        # `step.live` serves every layer's two kernels
+        step = DecodeStep(page_tables, positions, active, cache["k"], c.head_dim, self.attn_mesh)
 
         def body(carry, xs):
             hidden, k_pool, v_pool, ssm, conv = carry
             lp, l = xs
             off = l * num_pages
-
-            def attn_fn(q, kp, vp):
-                return dispatch_paged_decode_attention(
-                    q, kp, vp, off + page_tables, positions, mesh=self.attn_mesh, live=live,
-                    runs=runs,
-                )
-
             u = rms_norm(hidden, lp["input_norm"], c.rms_norm_eps)
-            m_out, ssm, conv = self._mamba_decode(lp, u, ssm, conv, l * slot_rows, live)
+            m_out, ssm, conv = self._mamba_decode(lp, u, ssm, conv, l * slot_rows, step.live)
             a_out, k_pool, v_pool = self._attention(
-                lp, u, k_pool, v_pool, positions, off + phys, offsets, attn_fn
+                lp, u, k_pool, v_pool, positions, off + step.phys, step.offsets, step.attend(off)
             )
             hidden = self._mlp(lp, self._mix(hidden, m_out, a_out))
             return (hidden, k_pool, v_pool, ssm, conv), None

@@ -29,13 +29,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from dynamo_tpu.ops.attention import (
-    decode_tile_runs,
-    dispatch_paged_decode_attention,
-    dispatch_paged_prefill_attention,
-    scatter_kv,
-)
-from dynamo_tpu.ops.live_rows import live_rows
+from dynamo_tpu.models.paged import DecodeStep, Pack, PagedModel, last_rows, prefill_attend
+from dynamo_tpu.ops.attention import scatter_kv
 from dynamo_tpu.ops.norms import rms_norm
 from dynamo_tpu.ops.rotary import apply_mrope, apply_rope
 from dynamo_tpu.quant import (
@@ -173,8 +168,18 @@ class LlamaConfig:
         return replace(base, **overrides)
 
 
-class LlamaModel:
-    """Stateless forward functions over a params pytree."""
+def _on_pools(attend):
+    """A paged attention (`attn_fn(q, k_pool, v_pool)`, models/paged.py) as
+    the `attn_fn` `_layer` calls: the chunk's fresh K and V rows are the ring
+    path's alone."""
+    return lambda q, k_new, v_new, k_pool, v_pool: attend(q, k_pool, v_pool)
+
+
+class LlamaModel(PagedModel):
+    """Stateless forward functions over a params pytree (models/paged.py's
+    contract). `attn_mesh` is set by ModelRunner for tp > 1 so the Pallas
+    decode kernel can run under shard_map (GSPMD cannot partition a
+    pallas_call)."""
 
     #: per-layer weights eligible for weight-only quantization — the decode
     #: hot path's big matmuls; norms/biases (and embed/lm_head outside the
@@ -186,12 +191,6 @@ class LlamaModel:
     #: forward. Subclasses with their own _layer (mixtral's MoE block,
     #: deepseek's absorbed attention) opt out until they thread it.
     SUPPORTS_LORA = True
-
-    def __init__(self, config: LlamaConfig):
-        self.config = config
-        # set by ModelRunner for tp>1 so the Pallas decode kernel can run
-        # under shard_map (GSPMD cannot partition a pallas_call)
-        self.attn_mesh = None
 
     @property
     def kv_folded(self) -> bool:
@@ -375,10 +374,8 @@ class LlamaModel:
     # ---------------- disagg / offload wire format ----------------
     # The wire layout is the model's canonical block serialization for DCN
     # transfer and host offload; flat_ids is [L, n] (per-layer flat page ids).
-
-    # axis of the per-page (n) dimension in the wire arrays below — batched
-    # host-tier restores concatenate single-page blocks along it
-    wire_n_axis = 2
+    # `wire_n_axis` (2, the contract's default) is the axis of the per-page (n)
+    # dimension in the wire arrays below.
 
     def gather_pages_wire(self, kv: dict, flat_ids: jnp.ndarray):
         """-> [L, 2, n, page_size, Hkv, D] ([..., Hkv*D] when kv_folded;
@@ -641,12 +638,7 @@ class LlamaModel:
         """
 
         def make_attn_fn(off):
-            def attn_fn(q, k_new, v_new, kp_, vp_):
-                return dispatch_paged_prefill_attention(
-                    q, kp_, vp_, off + page_table, positions, mesh=self.attn_mesh
-                )
-
-            return attn_fn
+            return _on_pools(prefill_attend(page_table, positions, self.attn_mesh, off))
 
         return self._prefill_common(
             params, kv_cache, tokens, positions, page_table, valid, last_idx, make_attn_fn,
@@ -684,8 +676,7 @@ class LlamaModel:
             params, kv_cache, tokens, positions, page_tables, valid,
             lora=lora, lora_ids=lora_ids,
         )
-        rows = hidden[jnp.arange(N) * T + last_idx]  # [N, D]
-        logits = self._unembed(params, rows)  # [N, V]
+        logits = self._unembed(params, last_rows(hidden, T, last_idx))  # [N, V]
         return logits, kv_cache
 
     def _packed_forward(
@@ -710,28 +701,9 @@ class LlamaModel:
         Returns (hidden [N*T, D], updated kv_cache)."""
         c = self.config
         k_pool, v_pool = kv_cache["k"], kv_cache["v"]
-        page_size = k_pool.shape[1]
         N, T = tokens.shape
-        lane = jnp.arange(N)
-        with jax.named_scope("attn_kv"):
-            phys = jnp.where(valid, page_tables[lane[:, None], positions // page_size], 0)
-            offsets = jnp.where(valid, positions % page_size, 0)
-        pos_flat = positions.reshape(N * T)
-
-        def make_attn_fn(off):
-            def attn_fn(q, k_new, v_new, kp_, vp_):
-                qs = q.reshape(N, T, *q.shape[1:])
-                outs = [
-                    dispatch_paged_prefill_attention(
-                        qs[j], kp_, vp_, off + page_tables[j], positions[j],
-                        mesh=self.attn_mesh,
-                    )
-                    for j in range(N)
-                ]
-                return jnp.concatenate(outs, axis=0)
-
-            return attn_fn
-
+        pack = Pack(page_tables, positions, valid, k_pool.shape[1], self.attn_mesh, flat=())
+        pos_flat = pack.flat_positions
         num_pages = k_pool.shape[0] // c.num_layers
         with jax.named_scope("embed"):
             hidden = params["embed"][tokens.reshape(N * T)].astype(c.dtype)
@@ -754,8 +726,8 @@ class LlamaModel:
                 )
             h, kp, vp = self._layer(
                 lp, h, kp, vp, pos_flat,
-                off + phys.reshape(N * T), offsets.reshape(N * T),
-                make_attn_fn(off), **lkw,
+                off + pack.phys.reshape(N * T), pack.offsets.reshape(N * T),
+                _on_pools(pack.attend(off)), **lkw,
             )
             return (h, kp, vp), None
 
@@ -855,15 +827,10 @@ class LlamaModel:
         image grids introduced during prefill."""
         c = self.config
         k_pool, v_pool = kv_cache["k"], kv_cache["v"]
-        page_size = k_pool.shape[1]
         num_pages = k_pool.shape[0] // c.num_layers
         B = tokens.shape[0]
-        with jax.named_scope("attn_kv"):
-            logical = positions // page_size
-            phys = jnp.where(active, page_tables[jnp.arange(B), logical], 0)
-            offsets = jnp.where(active, positions % page_size, 0)
-        live = live_rows(active)  # once a step, for every layer's kernel
-        runs = decode_tile_runs(page_tables, k_pool, c.head_dim, self.attn_mesh)  # likewise
+        step = DecodeStep(page_tables, positions, active, k_pool, c.head_dim, self.attn_mesh,
+                          logical_first=True)
 
         with jax.named_scope("embed"):
             hidden = params["embed"][tokens].astype(c.dtype)
@@ -875,13 +842,6 @@ class LlamaModel:
         def body(carry, xs):
             h, kp, vp = carry
             lp, off = xs[0], xs[1]
-
-            def attn_fn(q, k_new, v_new, kp_, vp_):
-                return dispatch_paged_decode_attention(
-                    q, kp_, vp_, off + page_tables, positions, mesh=self.attn_mesh, live=live,
-                    runs=runs,
-                )
-
             lkw = {}
             if lora is not None:
                 lkw = dict(
@@ -892,8 +852,8 @@ class LlamaModel:
                     lora_scales=lora["scales"],
                 )
             h, kp, vp = self._layer(
-                lp, h, kp, vp, positions, off + phys, offsets, attn_fn,
-                rope_positions=rope_pos3, **lkw,
+                lp, h, kp, vp, positions, off + step.phys, step.offsets,
+                _on_pools(step.attend(off)), rope_positions=rope_pos3, **lkw,
             )
             return (h, kp, vp), None
 

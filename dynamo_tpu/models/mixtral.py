@@ -62,11 +62,8 @@ class MixtralModel(LlamaModel):
         {"wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down"}
     )
 
-    def __init__(self, config: MixtralConfig):
-        super().__init__(config)
-        # set by ModelRunner where the engine's mesh has several devices: the
-        # grouped product is then XLA's (ops/moe.grouped_matmul)
-        self.expert_mesh = None
+    # `expert_mesh`: set by ModelRunner where the engine's mesh has several
+    # devices; the grouped product is then XLA's (ops/moe.grouped_matmul)
 
     def _init_raw_params(self, rng: jax.Array) -> dict:
         c = self.config

@@ -33,18 +33,19 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dynamo_tpu.models.llama import parse_dtype
-from dynamo_tpu.ops.attention import (
-    decode_tile_runs,
-    dispatch_paged_decode_attention,
-    dispatch_paged_prefill_attention,
-    scatter_kv,
+from dynamo_tpu.models.paged import (
+    DecodeStep,
+    ExpertCounts,
+    Pack,
+    PackedPrefillModel,
+    route,
+    state_rows,
 )
+from dynamo_tpu.ops.attention import scatter_kv
 from dynamo_tpu.ops.moe import grouped_matmul, moe_dispatch, relu2, sigmoid_topk_routing
 from dynamo_tpu.ops.norms import rms_norm
-from dynamo_tpu.ops.live_rows import live_rows
 from dynamo_tpu.ops.ssm import causal_conv, ssd_chunked, ssm_state_update
 
 
@@ -156,19 +157,13 @@ class NemotronHConfig:
         return replace(base, **overrides)
 
 
-class NemotronHModel:
-    """Stateless forward functions over a params pytree (models/llama.py's
-    contract, plus the per-slot state: `state_slot(s)` on the prefills)."""
+class NemotronHModel(PackedPrefillModel):
+    """Stateless forward functions over a params pytree (models/paged.py's
+    contract; `prefill` and `prefill_packed` are `PackedPrefillModel`'s over
+    `_packed_forward`, with the per-slot state: `state_slot(s)`). One chip:
+    expert parallelism across chips is not built, so `attn_mesh` stays None."""
 
-    #: the engine keeps a per-slot state cache beside the paged KV, matches no
-    #: prefix for this model, and refuses what would need the state copied
     recurrent = True
-    SUPPORTS_LORA = False
-    SUPPORTS_KV_INT8 = False
-
-    def __init__(self, config: NemotronHConfig):
-        self.config = config
-        self.attn_mesh = None  # one chip: expert parallelism across chips is not built
 
     # ---------------- params ----------------
 
@@ -227,44 +222,19 @@ class NemotronHModel:
             "lm_head": dense((c.vocab_size, D), 1),
         }
 
-    def param_shardings(self, mesh: Mesh, tp_axis: str = "tp") -> dict:
-        shapes = jax.eval_shape(self.init_params, jax.random.key(0))
-        return jax.tree.map(lambda _: NamedSharding(mesh, P()), shapes)
-
     # ---------------- the paged KV pool (attention blocks only) ----------------
-
-    kv_folded = False
 
     def kv_cache_shape(self, num_pages: int, page_size: int) -> tuple[int, ...]:
         c = self.config
         return (c.count("*") * num_pages, page_size, c.num_kv_heads, c.head_dim)
 
-    def init_kv_cache(self, num_pages: int, page_size: int) -> dict:
-        shape = self.kv_cache_shape(num_pages, page_size)
-        return {"k": jnp.zeros(shape, self.config.dtype), "v": jnp.zeros(shape, self.config.dtype)}
-
-    def kv_page_bytes(self, page_size: int) -> int:
-        c = self.config
-        return (2 * c.count("*") * page_size * c.num_kv_heads * c.head_dim
-                * jnp.dtype(c.dtype).itemsize)
-
-    def kv_cache_sharding(self, mesh: Mesh, tp_axis: str = "tp") -> dict:
-        ns = NamedSharding(mesh, P())
-        return {"k": ns, "v": ns}
-
     # ---------------- the per-slot state cache (Mamba blocks) ----------------
 
-    #: the state-cache leaves a decode window zeroes, adds to and hands back
-    window_counters = ("moe_counts", "moe_touched")
+    window_counters = ExpertCounts.NAMES
 
     def init_state_cache(self, max_seqs: int) -> dict:
-        """The leaves the engine keeps beside the KV pools, in the same
-        donated bundle: `ssm` and `conv`, a row per (Mamba block, slot) plus
-        each block's trash row, and the `window_counters`: `moe_counts`, where
-        decode steps add the assignments each held expert received, and
-        `moe_touched`, where they add the number of (expert block, held
-        expert) pairs that received a row (the engine zeroes both at the start
-        of a decode window and reads them at the end)."""
+        """`ssm` and `conv`, a row per (Mamba block, slot) plus each block's
+        trash row, and the expert counters."""
         c = self.config
         rows = c.count("M") * (max_seqs + 1)
         return {
@@ -272,20 +242,8 @@ class NemotronHModel:
                 (rows, c.mamba_num_heads, c.mamba_head_dim, c.ssm_state_size), jnp.float32
             ),
             "conv": jnp.zeros((rows, c.conv_kernel - 1, c.conv_dim), c.dtype),
-            "moe_counts": jnp.zeros((c.n_routed_experts,), jnp.int32),
-            "moe_touched": jnp.zeros((1,), jnp.int32),
+            **ExpertCounts.leaves(c.n_routed_experts),
         }
-
-    def state_cache_sharding(self, mesh: Mesh) -> dict:
-        ns = NamedSharding(mesh, P())
-        return {"ssm": ns, "conv": ns, "moe_counts": ns, "moe_touched": ns}
-
-    def state_bytes(self, max_seqs: int) -> int:
-        """Device bytes of the state cache at this many slots."""
-        c = self.config
-        per_row = (c.mamba_num_heads * c.mamba_head_dim * c.ssm_state_size * 4
-                   + (c.conv_kernel - 1) * c.conv_dim * jnp.dtype(c.dtype).itemsize)
-        return c.count("M") * (max_seqs + 1) * per_row
 
     # ---------------- blocks ----------------
 
@@ -386,17 +344,12 @@ class NemotronHModel:
         """h [T, D] -> (out [T, D], the held experts' assignment counts over
         the rows of `count_rows` (all rows when None))."""
         c = self.config
-        with jax.named_scope("moe_router"):
-            # the router: float32 on the full hidden state, at full precision
-            # (a bf16 pass would move the choice of expert, not just a weight)
-            logits = jnp.dot(
-                h.astype(jnp.float32), bp["router"], precision=jax.lax.Precision.HIGHEST
-            )
-            weights, idx = sigmoid_topk_routing(
-                logits, bp["router_bias"], c.num_experts_per_tok, c.routed_scaling_factor
-            )
-            if count_rows is not None:
-                idx = jnp.where(count_rows[:, None], idx, -1)  # held nowhere
+        weights, idx = route(
+            h, bp["router"],
+            lambda logits: sigmoid_topk_routing(
+                logits, bp["router_bias"], c.num_experts_per_tok, c.routed_scaling_factor),
+            count_rows,
+        )
 
         def ffn(rows, group_sizes):  # `moe_dispatch` calls it under `moe_experts`
             mid = relu2(grouped_matmul(rows, bp["w1"], group_sizes))
@@ -429,18 +382,10 @@ class NemotronHModel:
         Returns (hidden [N*T, D], cache)."""
         c = self.config
         N, T = tokens.shape
-        page_size = cache["k"].shape[1]
         num_pages = cache["k"].shape[0] // max(1, c.count("*"))
         slot_rows = cache["ssm"].shape[0] // max(1, c.count("M"))
-        lane = jnp.arange(N)
-        with jax.named_scope("attn_kv"):  # where each row's K and V go
-            phys = jnp.where(valid, page_tables[lane[:, None], positions // page_size], 0)
-            offsets = jnp.where(valid, positions % page_size, 0).reshape(N * T)
-        with jax.named_scope("ssm"):  # which state row each lane continues
-            fresh = positions[:, 0] == 0
-            # a slot the engine does not name (padding lanes, warm-up) is the trash row
-            slots = jnp.where((state_slots >= 0) & (state_slots < slot_rows - 1),
-                              state_slots, slot_rows - 1)
+        pack = Pack(page_tables, positions, valid, cache["k"].shape[1], self.attn_mesh)
+        fresh, slots = state_rows(state_slots, slot_rows, positions)
 
         with jax.named_scope("embed"):
             hidden = params["embed"][tokens.reshape(N * T)].astype(c.dtype)
@@ -455,19 +400,8 @@ class NemotronHModel:
                 m += 1
             elif kind == "*":
                 off = a * num_pages
-
-                def attn_fn(q, k_pool, v_pool, off=off):
-                    qs = q.reshape(N, T, *q.shape[1:])
-                    return jnp.concatenate([
-                        dispatch_paged_prefill_attention(
-                            qs[j], k_pool, v_pool, off + page_tables[j], positions[j],
-                            mesh=self.attn_mesh,
-                        )
-                        for j in range(N)
-                    ], axis=0)
-
                 out, cache = self._attention(
-                    bp, h, cache, off + phys.reshape(N * T), offsets, attn_fn
+                    bp, h, cache, off + pack.phys.reshape(N * T), pack.offsets, pack.attend(off)
                 )
                 a += 1
             else:
@@ -476,72 +410,33 @@ class NemotronHModel:
                 hidden = hidden + out
         return hidden, cache
 
-    def prefill_packed(self, params, kv_cache, tokens, positions, page_tables, valid,
-                       last_idx, state_slots=None):
-        """models/llama.py's `prefill_packed`, plus `state_slots` [N]: the
-        decode slot whose state each lane continues (or, from position 0,
-        starts). Returns (logits [N, V], cache)."""
-        N, T = tokens.shape
-        if state_slots is None:
-            state_slots = jnp.full((N,), -1, jnp.int32)
-        hidden, kv_cache = self._packed_forward(
-            params, kv_cache, tokens, positions, page_tables, valid, state_slots
-        )
-        rows = hidden[jnp.arange(N) * T + last_idx]
-        return self._unembed(params, rows), kv_cache
-
-    def prefill(self, params, kv_cache, tokens, positions, page_table, valid, last_idx,
-                input_embeds=None, embeds_mask=None, rope_positions=None, state_slot=None):
-        """One chunk of one sequence: a pack of one lane."""
-        if input_embeds is not None or rope_positions is not None:
-            raise ValueError("nemotron_h is text-only")
-        slots = None if state_slot is None else jnp.reshape(state_slot, (1,))
-        logits, kv_cache = self.prefill_packed(
-            params, kv_cache, tokens[None], positions[None], page_table[None],
-            valid[None], jnp.reshape(last_idx, (1,)), state_slots=slots,
-        )
-        return logits[0], kv_cache
-
     def decode(self, params, kv_cache, tokens, positions, page_tables, active,
                rope_deltas=None):
         """One decode step for the whole batch; batch row b is decode slot b.
         Returns (logits [B, V], cache)."""
         c = self.config
         cache = kv_cache
-        page_size = cache["k"].shape[1]
         num_pages = cache["k"].shape[0] // max(1, c.count("*"))
         slot_rows = cache["ssm"].shape[0] // max(1, c.count("M"))
-        B = tokens.shape[0]
-        with jax.named_scope("attn_kv"):
-            phys = jnp.where(active, page_tables[jnp.arange(B), positions // page_size], 0)
-            offsets = jnp.where(active, positions % page_size, 0)
-        live = live_rows(active)  # once a step, for every layer's kernel
-        runs = decode_tile_runs(page_tables, cache["k"], c.head_dim, self.attn_mesh)  # likewise
+        step = DecodeStep(page_tables, positions, active, cache["k"], c.head_dim, self.attn_mesh)
 
         with jax.named_scope("embed"):
             hidden = params["embed"][tokens].astype(c.dtype)
-        counts, touched = cache["moe_counts"], cache["moe_touched"]
+        routed = ExpertCounts(cache)
         m = a = 0
         for kind, bp in zip(c.pattern, params["blocks"]):
             h = rms_norm(hidden, bp["norm"], c.rms_norm_eps)
             if kind == "M":
-                out, cache = self._mamba_decode(bp, h, cache, m * slot_rows, live)
+                out, cache = self._mamba_decode(bp, h, cache, m * slot_rows, step.live)
                 m += 1
             elif kind == "*":
                 off = a * num_pages
-
-                def attn_fn(q, k_pool, v_pool, off=off):
-                    return dispatch_paged_decode_attention(
-                        q, k_pool, v_pool, off + page_tables, positions, mesh=self.attn_mesh,
-                        live=live, runs=runs,
-                    )
-
-                out, cache = self._attention(bp, h, cache, off + phys, offsets, attn_fn)
+                out, cache = self._attention(
+                    bp, h, cache, off + step.phys, step.offsets, step.attend(off))
                 a += 1
             else:
                 out, n = self._experts(bp, h, count_rows=active)
-                counts = counts + n
-                touched = touched + jnp.sum(n > 0, dtype=jnp.int32)
+                routed.add(n)
             with jax.named_scope(self.RESIDUAL_PART[kind]):
                 hidden = hidden + out
-        return self._unembed(params, hidden), dict(cache, moe_counts=counts, moe_touched=touched)
+        return self._unembed(params, hidden), routed.into(cache)

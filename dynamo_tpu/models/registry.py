@@ -118,7 +118,7 @@ def load_model(model_id: str, seed: int = 0, quantize: str | None = None,
         model_cls, cfg, params = entry[1]
         return model_cls(cfg), params  # fresh model object: attn_mesh is per-engine
     model, params = _load_model_uncached(model_id, seed, quantize, kv_cache_dtype)
-    if kv_cache_dtype and not getattr(model, "SUPPORTS_KV_INT8", False):
+    if kv_cache_dtype and not model.SUPPORTS_KV_INT8:
         raise ValueError(
             f"kv_cache_dtype={kv_cache_dtype!r} is not supported by "
             f"{type(model).__name__} (the MLA latent cache is its own "

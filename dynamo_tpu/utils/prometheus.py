@@ -543,6 +543,7 @@ def _sample_surfaces() -> list[tuple[str, str]]:
         draft = _DraftPool()
         lora_store = _LoraStore()
         model = None
+        recurrent = False
         compile_monitor = _CompileMonitor()
 
         def hbm_stats(self):
@@ -643,7 +644,7 @@ def _sample_surfaces() -> list[tuple[str, str]]:
     surfaces.append(("disagg.prefix_fetch.client", pf.render_metrics()))
 
     class _Eng:
-        config = None
+        config = model = None  # what PrefillWorker reads of an engine at start-up
 
     surfaces.append(("disagg.prefill_worker", PrefillWorker(_Eng(), None, "ns", "m").render_metrics()))
 

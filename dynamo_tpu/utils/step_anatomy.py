@@ -32,9 +32,8 @@ own (``engine.step``, ``engine.post``, ``engine.wait_for_work`` in
 ``engine/engine.py``), and a profiler trace says how much of the thread no
 phase covers. ``host_frac`` (everything except
 device_wait, over the total) is the fraction of a serving step the host
-spends NOT waiting on the chip — the overhead the planned multi-step fused
-decode (ROADMAP item 3) must drive down, and this plane is its before/after
-instrument.
+spends NOT waiting on the chip: the overhead the multi-step decode window
+drives down, and this plane is its before/after instrument.
 
 The roofline estimator prices the bytes-moved floor of a decode step from
 live state: every step re-reads the full parameter set plus each live
@@ -240,7 +239,7 @@ def roofline_for_runner(runner, config) -> Optional[RooflineModel]:
     runner/model can't price pages (external engines, test fakes)."""
     model = getattr(runner, "model", None)
     params = getattr(runner, "params", None)
-    if model is None or params is None or not hasattr(model, "kv_page_bytes"):
+    if model is None or params is None:
         return None
     try:
         import jax

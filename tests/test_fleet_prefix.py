@@ -259,8 +259,11 @@ def test_offload_drop_publishes_removed_once_gone_from_all_tiers():
     block is still pullable from the host pool); only the host-LRU drop —
     the block leaving its last tier — emits `removed`."""
     from dynamo_tpu.engine.page_table import PageAllocator
+    from dynamo_tpu.models.paged import PagedModel
 
     class _Runner:  # host-pool transfers without a device
+        model = PagedModel(None)  # the contract's defaults: `wire_n_axis`
+
         def extract_pages(self, ids):
             import numpy as np
 

@@ -8,7 +8,6 @@ same checkpoint files the program loads.
 """
 
 import asyncio
-import importlib.util
 import json
 from pathlib import Path
 
@@ -19,23 +18,18 @@ import pytest
 
 from dynamo_tpu.engine.config import EngineConfig
 from dynamo_tpu.engine.engine import AsyncJaxEngine
-from dynamo_tpu.engine.sampling import SamplingParams
-from dynamo_tpu.engine.scheduler import EngineRequest
 from dynamo_tpu.models.lfm2_moe import ROUTING_EPS, Lfm2MoeConfig, Lfm2MoeModel
 from dynamo_tpu.models.registry import load_model
 from dynamo_tpu.ops import attention as attn_ops
 from dynamo_tpu.ops.moe import sigmoid_topk_routing
 
-ROOT = Path(__file__).resolve().parents[1]
-
-
-def _bench_module(kind: str, name: str):
-    path = ROOT / "benchmark" / kind / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_{kind}_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
+from hybrid_helpers import (
+    Driver as _Driver,
+    bench_module as _bench_module,
+    generate as _generate,
+    tokens as _tokens,
+    window_off_by_one as _window_off_by_one,
+)
 
 reference = _bench_module("reference", "lfm2_moe")
 plan = _bench_module("checkpoints", "lfm2_moe")
@@ -94,10 +88,6 @@ def loaded(ckpt):
     return load_model(str(ckpt))
 
 
-def _tokens(seed: int, n: int) -> list:
-    return [int(t) for t in np.random.default_rng(seed).integers(3, HF_TINY["vocab_size"], n)]
-
-
 def _ref_logits(ckpt, tokens, options=None):
     return reference.forward_logits(ckpt, [tokens], [(0, len(tokens))], options)[0]
 
@@ -110,54 +100,6 @@ def _ref_logits(ckpt, tokens, options=None):
 #: file, PR 42, CPU); 1e-4 leaves an order of magnitude, and a wrong window,
 #: norm, expert weight or mask moves logits by 1e-2 to 1 (the controls below).
 LOGIT_ATOL = 1e-4
-
-
-class _Driver:
-    """The model's own prefill and decode functions over hand-made caches:
-    what the runner's jitted steps call, without the scheduler."""
-
-    def __init__(self, model, params, max_seqs=3, num_pages=32, page_size=16):
-        self.model, self.params = model, params
-        self.ps, self.max_seqs = page_size, max_seqs
-        self.cache = {**model.init_kv_cache(num_pages, page_size),
-                      **model.init_state_cache(max_seqs)}
-        self.tables = np.zeros((max_seqs, 8), np.int32)
-        for s in range(max_seqs):  # pages 1.. (0 is the null page), 8 a slot
-            self.tables[s] = 1 + s * 8 + np.arange(8)
-
-    def prefill(self, lanes, T):
-        """lanes: [(slot, tokens, start)]; one packed call at bucket T.
-        Returns logits [len(lanes), V] at each lane's last real token."""
-        N = len(lanes)
-        toks, pos = np.zeros((N, T), np.int32), np.zeros((N, T), np.int32)
-        valid, last = np.zeros((N, T), bool), np.zeros(N, np.int32)
-        slots, pts = np.zeros(N, np.int32), np.zeros((N, 8), np.int32)
-        for j, (slot, tokens, start) in enumerate(lanes):
-            n = len(tokens)
-            toks[j, :n] = tokens
-            pos[j] = start + np.arange(T)
-            valid[j, :n] = True
-            last[j] = max(0, n - 1)
-            slots[j] = slot
-            if slot >= 0:
-                pts[j] = self.tables[slot]
-        logits, self.cache = jax.jit(self.model.prefill_packed)(
-            self.params, self.cache, jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(pts),
-            jnp.asarray(valid), jnp.asarray(last), state_slots=jnp.asarray(slots),
-        )
-        return np.asarray(logits)
-
-    def decode(self, fed: dict):
-        """fed: {slot: (token, position)}; the other slots are not active."""
-        B = self.max_seqs
-        toks, pos, act = np.zeros(B, np.int32), np.zeros(B, np.int32), np.zeros(B, bool)
-        for slot, (t, p) in fed.items():
-            toks[slot], pos[slot], act[slot] = t, p, True
-        logits, self.cache = jax.jit(self.model.decode)(
-            self.params, self.cache, jnp.asarray(toks), jnp.asarray(pos),
-            jnp.asarray(self.tables), jnp.asarray(act),
-        )
-        return np.asarray(logits)
 
 
 def test_the_full_forward_matches_the_reference(ckpt, loaded):
@@ -226,12 +168,6 @@ def test_a_slot_used_again_starts_from_zeros_and_padding_writes_the_trash_row(ck
     for r in set(range(after.shape[0])) - {m * slot_rows + 1 for m in range(3)}:
         if r not in trash:
             np.testing.assert_array_equal(after[r], before[r])
-
-
-def _window_off_by_one(conv):
-    """The window one position late: every entry moved back by one, the newest
-    input lost (what a hand-off that stops one token early leaves)."""
-    return jnp.roll(conv, 1, axis=1).at[:, 0].set(0)
 
 
 @pytest.mark.parametrize("fault", ["zeroed_window", "shifted_window"])
@@ -427,17 +363,6 @@ def test_the_folded_dispatch_takes_a_kernel_and_agrees_with_the_gather(name, Hq,
 #: logprobs of the tokens the engine chose, float32 on both sides (see
 #: LOGIT_ATOL: a logprob is a logit minus a log-sum-exp of logits)
 LOGPROB_ATOL = 1e-4
-
-
-async def _generate(eng, rid, prompt, max_tokens):
-    toks, lps = [], []
-    req = EngineRequest(request_id=rid, token_ids=list(prompt), logprobs=1,
-                        sampling=SamplingParams(temperature=0.0, max_tokens=max_tokens))
-    async for out in eng.generate(req):
-        if out.token is not None:
-            toks.append(out.token)
-            lps.append(out.logprob)
-    return toks, lps
 
 
 ENGINE_CASES = {

@@ -172,8 +172,11 @@ def test_host_pool_eviction_carries_owner_to_disk():
     int8-compressed bytes under the same tenant."""
     from dynamo_tpu.engine.kv_store import DiskKvStore
     from dynamo_tpu.engine.offload import HostKvPool
+    from dynamo_tpu.models.paged import PagedModel
 
     class _Runner:
+        model = PagedModel(None)  # the contract's defaults: `wire_n_axis`
+
         def extract_pages(self, ids):
             return np.zeros((2, 2, len(ids), 4, 2, 2), np.float32)
 

@@ -7,7 +7,6 @@ which reads the same checkpoint files the program loads.
 """
 
 import asyncio
-import importlib.util
 import json
 import sys
 from pathlib import Path
@@ -19,20 +18,16 @@ import pytest
 
 from dynamo_tpu.engine.config import EngineConfig
 from dynamo_tpu.engine.engine import AsyncJaxEngine
-from dynamo_tpu.engine.sampling import SamplingParams
-from dynamo_tpu.engine.scheduler import EngineRequest
 from dynamo_tpu.models.registry import load_model
 
-ROOT = Path(__file__).resolve().parents[1]
-
-
-def _bench_module(kind: str, name: str):
-    path = ROOT / "benchmark" / kind / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_{kind}_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
+from hybrid_helpers import (
+    ROOT,
+    Driver as _Driver,
+    bench_module as _bench_module,
+    generate as _generate,
+    tokens as _tokens,
+    window_off_by_one as _window_off_by_one,
+)
 
 reference = _bench_module("reference", "nemotron_h")
 plan = _bench_module("checkpoints", "nemotron_h")
@@ -92,10 +87,6 @@ def loaded(ckpt):
     return load_model(str(ckpt))
 
 
-def _tokens(seed: int, n: int) -> list:
-    return [int(t) for t in np.random.default_rng(seed).integers(3, HF_TINY["vocab_size"], n)]
-
-
 # ---------------------------------------------------------------- the model, on logits
 
 #: float32 on both sides: the program's chunked scan, grouped products and
@@ -104,57 +95,6 @@ def _tokens(seed: int, n: int) -> list:
 #: (this file, PR 29); 5e-4 leaves an order of magnitude, and a wrong window,
 #: state, expert weight or mask moves logits by 1e-2 to 1 (the controls below).
 LOGIT_ATOL = 5e-4
-
-
-class _Driver:
-    """The model's own prefill and decode functions over hand-made caches:
-    what the runner's jitted steps call, without the scheduler."""
-
-    def __init__(self, model, params, max_seqs=3, num_pages=32, page_size=16):
-        self.model, self.params = model, params
-        self.ps, self.max_seqs = page_size, max_seqs
-        self.cache = {**model.init_kv_cache(num_pages, page_size),
-                      **model.init_state_cache(max_seqs)}
-        self.tables = np.zeros((max_seqs, 8), np.int32)
-        for s in range(max_seqs):  # pages 1.. (0 is the null page), 8 a slot
-            self.tables[s] = 1 + s * 8 + np.arange(8)
-
-    def prefill(self, lanes, T):
-        """lanes: [(slot, tokens, start)]; one packed call at bucket T.
-        Returns logits [len(lanes), V] at each lane's last real token."""
-        N = len(lanes)
-        toks = np.zeros((N, T), np.int32)
-        pos = np.zeros((N, T), np.int32)
-        valid = np.zeros((N, T), bool)
-        last = np.zeros(N, np.int32)
-        slots = np.zeros(N, np.int32)
-        pts = np.zeros((N, 8), np.int32)
-        for j, (slot, tokens, start) in enumerate(lanes):
-            n = len(tokens)
-            toks[j, :n] = tokens
-            pos[j] = start + np.arange(T)
-            valid[j, :n] = True
-            last[j] = n - 1
-            slots[j] = slot
-            if slot >= 0:
-                pts[j] = self.tables[slot]
-        logits, self.cache = jax.jit(self.model.prefill_packed)(
-            self.params, self.cache, jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(pts),
-            jnp.asarray(valid), jnp.asarray(last), state_slots=jnp.asarray(slots),
-        )
-        return np.asarray(logits)
-
-    def decode(self, fed: dict):
-        """fed: {slot: (token, position)}; the other slots are not active."""
-        B = self.max_seqs
-        toks, pos, act = np.zeros(B, np.int32), np.zeros(B, np.int32), np.zeros(B, bool)
-        for slot, (t, p) in fed.items():
-            toks[slot], pos[slot], act[slot] = t, p, True
-        logits, self.cache = jax.jit(self.model.decode)(
-            self.params, self.cache, jnp.asarray(toks), jnp.asarray(pos),
-            jnp.asarray(self.tables), jnp.asarray(act),
-        )
-        return np.asarray(logits)
 
 
 def test_prefill_chunks_packs_and_decode_match_the_reference_logits(ckpt, loaded):
@@ -183,12 +123,6 @@ def test_prefill_chunks_packs_and_decode_match_the_reference_logits(ckpt, loaded
             np.testing.assert_allclose(got[0], ref[1, 18 + step], atol=LOGIT_ATOL)
     assert not np.asarray(d.cache["ssm"][1]).any(), "an inactive slot's state was touched"
     assert not np.asarray(d.cache["ssm"][d.max_seqs]).any(), "the trash row moved"
-
-
-def _window_off_by_one(conv):
-    """The window one position late: every entry moved back by one, the newest
-    input lost (what a hand-off that stops one token early leaves)."""
-    return jnp.roll(conv, 1, axis=1).at[:, 0].set(0)
 
 
 @pytest.mark.parametrize("fault", ["zeroed_state", "shifted_window"])
@@ -222,17 +156,6 @@ def _engine(ckpt, **kw):
     return AsyncJaxEngine(EngineConfig(**{**base, **kw}))
 
 
-async def _generate(eng, rid, prompt, max_tokens):
-    toks, lps = [], []
-    req = EngineRequest(request_id=rid, token_ids=list(prompt), logprobs=1,
-                        sampling=SamplingParams(temperature=0.0, max_tokens=max_tokens))
-    async for out in eng.generate(req):
-        if out.token is not None:
-            toks.append(out.token)
-            lps.append(out.logprob)
-    return toks, lps
-
-
 def _check_against_reference(ckpt, prompts, results):
     probes = [{"tokens": list(p) + toks, "prompt_len": len(p)}
               for p, (toks, _) in zip(prompts, results)]
@@ -250,19 +173,19 @@ def test_a_row_that_freezes_inside_a_window_is_skipped_from_the_next_step_on(
     step's live rows, made on the device, lose it from the next step on, and
     both requests' tokens and logprobs are the reference's. With the state
     kernel in interpret mode and without."""
-    from dynamo_tpu.models import nemotron_h
+    from dynamo_tpu.models import paged
 
     if kernels == "interpret":
         monkeypatch.setenv("DYNTPU_PALLAS", "1")
     counts = []
 
     def spy(active):
-        live = nemotron_h_live_rows(active)
+        live = live_rows(active)
         jax.debug.callback(lambda n: counts.append(int(n[0])), live.count, ordered=True)
         return live
 
-    nemotron_h_live_rows = nemotron_h.live_rows
-    monkeypatch.setattr(nemotron_h, "live_rows", spy)
+    live_rows = paged.live_rows  # where every model's decode step makes them
+    monkeypatch.setattr(paged, "live_rows", spy)
     prompts, lengths = [_tokens(30, 20), _tokens(31, 9)], [6, 11]
 
     async def body():

@@ -264,9 +264,10 @@ def test_the_host_tier_restore_still_gets_its_pages():
     import numpy as np
 
     from dynamo_tpu.engine.offload import HostKvPool
+    from dynamo_tpu.models.paged import PagedModel
 
     class _Runner:  # host-pool transfers without a device
-        model = None
+        model = PagedModel(None)  # the contract's defaults: `wire_n_axis`
 
         def extract_pages(self, ids):
             return np.zeros((1, 2, len(ids), PS, 1, 2), np.float32)
